@@ -298,7 +298,6 @@ def _run_partition_fleet(cfg: PartitionDrillConfig,
             n_partitions=cfg.n_partitions,
             ack_timeout_s=cfg.ack_timeout_s,
             session_timeout_s=cfg.session_timeout_s,
-            spawn_env={**os.environ, "JAX_PLATFORMS": "cpu"},
             worker_spec={
                 "batch": cfg.batch, "max_delay_ms": cfg.max_delay_ms,
                 "checkpoint_every": cfg.checkpoint_every,

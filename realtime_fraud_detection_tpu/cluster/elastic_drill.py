@@ -273,9 +273,9 @@ def _run_elastic_fleet(cfg: ElasticDrillConfig,
             f"127.0.0.1:{handoff_srv.port}",
             n_partitions=cfg.n_partitions,
             ack_timeout_s=cfg.ack_timeout_s,
-            # workers are pure host arithmetic: pin them to the CPU
-            # platform so a drill on a TPU host never touches the chips
-            spawn_env={**os.environ, "JAX_PLATFORMS": "cpu"},
+            # workers are pure host arithmetic on the CPU platform (the
+            # fleet's default spawn_env), so a drill on a TPU host never
+            # touches the chips
             worker_spec={
                 "batch": cfg.batch, "max_delay_ms": cfg.max_delay_ms,
                 "checkpoint_every": cfg.checkpoint_every,
@@ -703,8 +703,7 @@ def run_elastic_scaling(seed: int = 7,
         fleet = ProcessFleet(
             f"127.0.0.1:{broker_srv.port}",
             f"127.0.0.1:{handoff_srv.port}",
-            n_partitions=cfg.n_partitions, worker_spec=spec,
-            spawn_env={**os.environ, "JAX_PLATFORMS": "cpu"})
+            n_partitions=cfg.n_partitions, worker_spec=spec)
         try:
             fleet.start(n_workers)
             t0 = _wall()

@@ -90,6 +90,14 @@ class ProcessFleet:
     scoring state — the broker log, the handoff server, and the workers'
     own stores are the only state planes, which is what makes a worker's
     SIGKILL recoverable and the coordinator restartable.
+
+    One process per chip: an accelerator belongs to the first process that
+    initialises JAX on it, and a second one fails or hangs. So unless a
+    ``spawn_env`` says otherwise every worker is pinned to the CPU backend
+    (``JAX_PLATFORMS=cpu``) — it never asks for a chip the coordinator's
+    process or a sibling holds. On a one-chip host at most ONE worker may
+    own the device (a fleet of its own with an explicit ``spawn_env``);
+    the rest stay on the CPU.
     """
 
     def __init__(self, broker_addr: str, handoff_addr: str,
@@ -112,7 +120,8 @@ class ProcessFleet:
         self.topic = topic
         self.python = python
         self.ack_timeout_s = float(ack_timeout_s)
-        self.spawn_env = spawn_env
+        self.spawn_env = (dict(spawn_env) if spawn_env is not None
+                          else {**os.environ, "JAX_PLATFORMS": "cpu"})
         from realtime_fraud_detection_tpu.obs.fleetmetrics import (
             FleetMetrics,
             FleetTraceStore,

@@ -22,6 +22,7 @@ device sees only dense matmuls and masked means (MXU + VPU, no scatter).
 
 from __future__ import annotations
 
+import functools
 from typing import Dict
 
 import jax
@@ -137,6 +138,25 @@ def _sage(w, b, self_feat, neigh_feat, neigh_mask):
     return jax.nn.relu(z @ w + b)
 
 
+def _true_f32_matmuls(fn):
+    """Trace ``fn`` with its f32 matmuls really in f32.
+
+    The branch multiplies f32 params by RAW node/transaction features
+    (amounts and velocity sums reach 1e4). A TPU's default matmul
+    precision feeds f32 operands to the MXU as single bf16 passes, which
+    rounds those features to three digits: on the v5e the branch's
+    probability moved by up to 0.35 against the same program on the CPU
+    backend (chip_smoke parity, PR 21), and by 4e-8 at "highest". The
+    matmuls are tiny, so the extra passes cost nothing that shows."""
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+
+    return wrapped
+
+
+@_true_f32_matmuls
 def gnn_logits(
     params: Dict[str, jax.Array],
     txn_features: jax.Array,     # f32[B, 64]

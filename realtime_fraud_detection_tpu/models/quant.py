@@ -1,8 +1,7 @@
 """Weight-only int8 quantization for the BERT branch.
 
-The fused program's latency is dominated by the text encoder (BENCH_r04:
-the BERT branch is the largest per-branch slice of the batch-256 program),
-and ``DevicePool`` replicates FULL f32 params onto every chip — so BERT
+The text encoder is by far the largest branch of the fused program (its
+analytic matmul FLOPs dwarf the other four branches'), and ``DevicePool`` replicates FULL f32 params onto every chip — so BERT
 bytes are both the HBM cap on model size and the bulk of the hot-swap /
 replication payload. Per the reduced-precision serving result in the 300M
 predictions/sec paper (arXiv:2109.09541) and the repo's own precision

@@ -99,6 +99,9 @@ def gather_leaf_values(leaf: jax.Array, leaf_idx: jax.Array) -> jax.Array:
 # by `rtfd quant-drill`).
 # --------------------------------------------------------------------------
 
+_EXACT = jax.lax.Precision.HIGHEST
+
+
 @lru_cache(maxsize=None)
 def _complete_tree_paths(depth: int) -> tuple:
     """Structure constants of a complete binary tree of ``depth``:
@@ -143,6 +146,14 @@ def gemm_leaf_onehot(
     feature contract; a non-finite feature would poison the selection
     contraction, where the gather path localizes it). All count
     arithmetic involves small integers (<= depth), exact in f32.
+
+    Every contraction asks for ``Precision.HIGHEST``: the selection must
+    hand each node its feature value EXACTLY, and a TPU's default matmul
+    precision passes f32 operands to the MXU as single bf16 passes — on
+    the v5e that rounded the features before the threshold compare and
+    sent 86 of 25,600 (row, tree) pairs to a different leaf than the
+    gather path (logits up to 0.48 apart; 0 and 5e-7 at HIGHEST; my chip
+    run, PR 21). The CPU backend ignores the setting.
     """
     t, n_internal = feature.shape
     depth = int(np.log2(n_internal + 1))
@@ -153,10 +164,11 @@ def gemm_leaf_onehot(
     c, d = _complete_tree_paths(depth) if paths is None else paths
     sel = (feature[:, :, None]
            == jnp.arange(f_dim, dtype=feature.dtype)[None, None, :])
-    xv = jnp.einsum("bf,tif->bti", x, sel.astype(x.dtype))     # [B, T, I]
+    xv = jnp.einsum("bf,tif->bti", x, sel.astype(x.dtype),
+                    precision=_EXACT)                          # [B, T, I]
     left = 1.0 - (xv >= threshold[None, :, :]).astype(x.dtype)
-    reach = jnp.einsum("bti,il->btl", left,
-                       jnp.asarray(c, x.dtype))                # [B, T, L]
+    reach = jnp.einsum("bti,il->btl", left, jnp.asarray(c, x.dtype),
+                       precision=_EXACT)                       # [B, T, L]
     return (reach == jnp.asarray(d, x.dtype)[None, None, :]).astype(x.dtype)
 
 
@@ -178,7 +190,7 @@ def gemm_leaf_contract(
     -> f32[B, T]: the GEMM-form replacement for descend+gather, shared by
     the GBDT (leaf log-odds) and the isolation forest (path lengths)."""
     onehot = gemm_leaf_onehot(feature, threshold, x, paths=paths)
-    return jnp.einsum("btl,tl->bt", onehot, values)
+    return jnp.einsum("btl,tl->bt", onehot, values, precision=_EXACT)
 
 
 def tree_ensemble_logits(ensemble: TreeEnsemble, x: jax.Array,

@@ -2,13 +2,17 @@
 
 Builds ``microbatcher.cpp`` on demand with g++ (pybind11 is not in this
 image; the C ABI + ctypes keeps the dependency surface at zero). The build
-is cached next to the source keyed on its mtime; set
+is cached next to the source under a name keyed on the source's CONTENT
+(``_<stem>.<sha256[:16]>.so``, git-ignored), so a binary can only ever
+serve the source it was built from — a copied tree, a fresh checkout and
+an edited file all resolve correctly without trusting mtimes. Set
 ``RTFD_DISABLE_NATIVE=1`` to force the pure-Python assembler.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -17,25 +21,34 @@ from typing import List, Optional
 
 _DIR = Path(__file__).resolve().parent
 _SRC = _DIR / "microbatcher.cpp"
-_LIB = _DIR / "_microbatcher.so"
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_error: Optional[str] = None
 
 
-def _compile_native(src: Path, lib_path: Path) -> tuple[Optional[ctypes.CDLL], Optional[str]]:
-    """Shared on-demand g++ build: env-var gate, mtime cache, one compiler
-    recipe for every native kernel in this package. Returns (lib, error)."""
+def _compile_native(src: Path) -> tuple[Optional[ctypes.CDLL], Optional[str]]:
+    """Shared on-demand g++ build: env-var gate, content-keyed cache, one
+    compiler recipe for every native kernel in this package. Returns
+    (lib, error)."""
     if os.environ.get("RTFD_DISABLE_NATIVE") == "1":
         return None, "disabled via RTFD_DISABLE_NATIVE"
     try:
-        if not lib_path.exists() or lib_path.stat().st_mtime < src.stat().st_mtime:
+        digest = hashlib.sha256(src.read_bytes()).hexdigest()[:16]
+        lib_path = src.with_name(f"_{src.stem}.{digest}.so")
+        if not lib_path.exists():
+            # build to a private name, then rename: concurrent worker
+            # processes never load a half-written library
+            tmp = lib_path.with_suffix(f".{os.getpid()}.tmp")
             cmd = [
                 "g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-                str(src), "-o", str(lib_path),
+                str(src), "-o", str(tmp),
             ]
             subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+            os.replace(tmp, lib_path)
+            for stale in src.parent.glob(f"_{src.stem}.*.so"):
+                if stale != lib_path:
+                    stale.unlink(missing_ok=True)
         return ctypes.CDLL(str(lib_path)), None
     except (OSError, subprocess.SubprocessError) as e:
         return None, str(e)
@@ -43,7 +56,7 @@ def _compile_native(src: Path, lib_path: Path) -> tuple[Optional[ctypes.CDLL], O
 
 def _build() -> Optional[ctypes.CDLL]:
     global _build_error
-    lib, _build_error = _compile_native(_SRC, _LIB)
+    lib, _build_error = _compile_native(_SRC)
     if lib is None:
         return None
 
@@ -162,14 +175,13 @@ class NativeMicrobatchQueue:
 
 # ------------------------------------------------------------------- trees
 _TREES_SRC = _DIR / "trees.cpp"
-_TREES_LIB = _DIR / "_trees.so"
 _trees_lib: Optional[ctypes.CDLL] = None
 _trees_error: Optional[str] = None
 
 
 def _build_trees() -> Optional[ctypes.CDLL]:
     global _trees_error
-    lib, _trees_error = _compile_native(_TREES_SRC, _TREES_LIB)
+    lib, _trees_error = _compile_native(_TREES_SRC)
     if lib is None:
         return None
     import numpy as np

@@ -251,7 +251,6 @@ def _run_obs_fleet(cfg: ObsDrillConfig,
             f"127.0.0.1:{handoff_srv.port}",
             n_partitions=cfg.n_partitions,
             ack_timeout_s=cfg.ack_timeout_s,
-            spawn_env={**os.environ, "JAX_PLATFORMS": "cpu"},
             worker_spec=worker_spec,
             per_worker_spec=per_worker)
         fleet.start(cfg.n_workers, now=0.0)

@@ -63,6 +63,23 @@ MEGA_BLOCK_CANDIDATES: Tuple[int, ...] = (128, 64, 32, 16, 8)
 # — bucket 1 stays on the per-site kernel path (an honest fallback).
 MEGA_MIN_BATCH = 8
 
+# What the TPU compiler says to this kernel (Mosaic, JAX 0.9.0 / libtpu
+# 0.0.34, described v5e:2x2 topology — tests/test_aot_tpu.py re-asks on
+# every run). With the rank-1 batch operands restaged as [B, 1] the block
+# specs pass and the BODY is refused at its first branch, the GEMM-form
+# tree contraction; the three constructs behind it are each refused alone
+# as well. None of these is a restaging: the body inlines whole branch
+# programs Mosaic has no lowering for, so on a TPU mesh the kernel is
+# interpret-only (CPU) and FraudScorer refuses ``megakernel="pallas"``.
+MEGA_TPU_REFUSAL = (
+    "Mosaic cannot lower the kernel body: the tree/iforest contraction "
+    "einsum('btl,tl->bt') has no TPU dot_dimension_numbers form "
+    "(\"Unable to parse attribute ... lhs_non_contracting_dims\"); behind "
+    "it the in-kernel embedding gather (\"Shape mismatch in input, "
+    "indices and output\"), the LSTM lax.scan (NotImplementedError) and "
+    "the 4-D attention einsums (\"'tpu.matmul' op Not implemented: Up to "
+    "1 batch dim supported\") are refused too")
+
 
 def _unwrap(fn):
     """The traceable body of a jitted branch function: calling the jit
@@ -291,15 +308,18 @@ def _mega_call(models, batch, w2, cm2, *, mega_valid, bert_config, block,
              jnp.asarray(ic), jnp.asarray(idx)]
     n_extra = len(extra)
 
-    # Pallas operand staging: bools ride as i32 (restored inside), 0-d
+    # Pallas operand staging: bools ride as i32 (restored inside), rank-1
+    # batch leaves ride as [B, 1] (Mosaic refuses a row-blocked rank-1
+    # operand; same restaging as ops/dequant_matmul.py's scale/bias), 0-d
     # param leaves (tree base_score, iforest c_psi) ride as shape-(1,).
-    batch_dtypes = []
+    batch_meta = []
     staged_batch = []
     for leaf in batch_leaves:
         arr = jnp.asarray(leaf)
-        batch_dtypes.append(arr.dtype)
-        staged_batch.append(
-            arr.astype(jnp.int32) if arr.dtype == jnp.bool_ else arr)
+        batch_meta.append((arr.dtype, arr.ndim == 1))
+        if arr.dtype == jnp.bool_:
+            arr = arr.astype(jnp.int32)
+        staged_batch.append(arr[:, None] if arr.ndim == 1 else arr)
     param_meta = []
     staged_params = []
     for leaf in list(model_leaves) + extra:
@@ -319,8 +339,10 @@ def _mega_call(models, batch, w2, cm2, *, mega_valid, bert_config, block,
         b_refs, p_refs = refs[:nb], refs[nb:nb + npar]
         o_ref, preds_ref = refs[nb + npar], refs[nb + npar + 1]
         bl = []
-        for ref, dt in zip(b_refs, batch_dtypes):
+        for ref, (dt, was_vector) in zip(b_refs, batch_meta):
             v = ref[...]
+            if was_vector:
+                v = v[:, 0]
             bl.append(v != 0 if dt == jnp.bool_ else v)
         blk_batch = jax.tree_util.tree_unflatten(batch_def, bl)
         pv = []

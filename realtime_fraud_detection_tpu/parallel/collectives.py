@@ -72,26 +72,10 @@ def seq_size():
 
 
 def shard_map_over(mesh: Mesh, fn, in_specs, out_specs, check_rep: bool = False):
-    """``shard_map`` pinned to this framework's mesh axis names.
-
-    Version shim: newer JAX exposes ``jax.shard_map`` with the
-    ``check_vma`` keyword; 0.4.x has it at ``jax.experimental.shard_map``
-    with ``check_rep``. Resolve whichever this interpreter ships — every
-    collective call site goes through here, so the compatibility decision
-    lives in exactly one place.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=check_rep)
-        except TypeError:
-            return sm(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_rep)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_rep)
+    """``jax.shard_map`` pinned to this framework's mesh axis names — every
+    collective call site goes through here."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_rep)
 
 
 def identity_spec() -> P:

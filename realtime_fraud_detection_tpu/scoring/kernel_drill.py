@@ -151,12 +151,14 @@ def _score_stream(cfg: KernelDrillConfig, gen, scorer, ts: float,
     }, ts
 
 
-def _noise_floor(cfg: KernelDrillConfig, scorer,
+def noise_floor(cfg: KernelDrillConfig, scorer,
                  tokens) -> Dict[str, float]:
     """The calibration-noise bound: how far the committed bf16 compute
     policy already moves the ensemble score vs full f32 compute, measured
     on this drill's own token stream with the SERVED weights, scaled by
-    the text branch's blend weight (quant-drill recipe)."""
+    the text branch's blend weight (quant-drill recipe). The f32 side
+    runs at "highest" matmul precision — a TPU's default would compute it
+    in bf16 passes too, and the floor would be noise against noise."""
     import jax
     import jax.numpy as jnp
 
@@ -169,7 +171,8 @@ def _noise_floor(cfg: KernelDrillConfig, scorer,
     branch_delta = 0.0
     for ids, mask in tokens:
         a = bf16(scorer.models.bert, ids, mask)
-        b = f32(scorer.models.bert, ids, mask)
+        with jax.default_matmul_precision("highest"):
+            b = f32(scorer.models.bert, ids, mask)
         branch_delta = max(branch_delta,
                            float(jnp.max(jnp.abs(a - b))))
     weights = np.asarray(scorer.ensemble_params.weights, np.float64)
@@ -388,7 +391,7 @@ def _run_once(cfg: KernelDrillConfig) -> Dict[str, Any]:
     div = np.abs(side_a["probs"] - side_b["probs"])
     flips = sum(a != b for a, b in zip(side_a["decisions"],
                                        side_b["decisions"]))
-    noise = _noise_floor(cfg, scorer_a, side_a["tokens"])
+    noise = noise_floor(cfg, scorer_a, side_a["tokens"])
     bound = cfg.noise_scale * noise["bound"]
     summary["divergence"] = {
         "max": float(div.max()),

@@ -163,7 +163,7 @@ def _score_fused_impl(
     iforest_kernel: str = "gather",  # gather oracle | Hummingbird GEMM form
     dequant_kernel: str = "off",     # kernel plane (KernelSettings): Pallas
     epilogue_kernel: str = "off",    # fused dequant-matmul / score-blend
-    kernel_interpret: bool = False,  # Pallas interpreter (non-TPU hosts)
+    kernel_interpret: bool = False,  # Pallas interpreter (CPU meshes)
 ) -> Dict[str, jax.Array]:
     """Score one microbatch through the full 5-model ensemble.
 
@@ -277,15 +277,14 @@ def _score_fused_packed_impl(
     megakernel: str = "off",         # persistent whole-batch program
     mega_valid: Optional[tuple] = None,  # QoS rung as static branch mask
 ) -> jax.Array:
-    """Transfer-optimal fused scorer: packed blobs in, one matrix out.
+    """Packed fused scorer: packed blobs in, one matrix out.
 
-    The streaming hot path on a remote TPU is bounded by transport round
-    trips, not FLOPs (bench r4: ~85 ms null RTT vs ~25 ms compute per
-    256-batch). This entry takes the microbatch as the three packed buffers
-    from ``core.packing.pack_tree`` (one h2d payload) and returns the §2.7
+    This entry takes the microbatch as the three packed buffers from
+    ``core.packing.pack_tree`` (one h2d payload) and returns the §2.7
     response fields as ONE f32[B, 8+M] matrix (one d2h payload) laid out per
     ``OUT_COLUMNS`` + model_predictions. XLA fuses the unpack slices into
-    the branch consumers, so the repack costs nothing on-device.
+    the branch consumers, so the repack costs nothing on-device. What the
+    transfer count is worth on local hardware is not measured.
     """
     from realtime_fraud_detection_tpu.core.packing import unpack_tree
 
@@ -343,33 +342,23 @@ def _score_fused_packed_impl(
     return jnp.concatenate(parts, axis=1)
 
 
+_PACKED_STATIC = ("spec", "bert_config", "use_pallas", "tree_kernel",
+                  "iforest_kernel", "dequant_kernel", "epilogue_kernel",
+                  "kernel_interpret", "megakernel", "mega_valid")
+
 score_fused_packed = partial(
-    jax.jit, static_argnames=("spec", "bert_config", "use_pallas",
-                              "tree_kernel", "iforest_kernel",
-                              "dequant_kernel", "epilogue_kernel",
-                              "kernel_interpret", "megakernel",
-                              "mega_valid"),
-)(_score_fused_packed_impl)
+    jax.jit, static_argnames=_PACKED_STATIC)(_score_fused_packed_impl)
 
 # Donated-input variant for the device pool's per-replica dispatch
 # (scoring/device_pool.py): the packed blobs are throwaway H2D staging —
 # fresh per dispatch, never read back — so donating them lets XLA reuse
-# the buffers instead of holding depth x 3 blobs per replica alive, which
-# is what cuts the batch-256 h2d p99 tail (BENCH_r05). The host keeps its
-# own numpy copy for the retry-on-replica-failure path, so donation never
-# loses data. Fall back to the plain entry on jax builds without
-# donate_argnames.
-try:
-    score_fused_packed_donated = partial(
-        jax.jit, static_argnames=("spec", "bert_config", "use_pallas",
-                                  "tree_kernel", "iforest_kernel",
-                                  "dequant_kernel", "epilogue_kernel",
-                                  "kernel_interpret", "megakernel",
-                                  "mega_valid"),
-        donate_argnames=("blob_f32", "blob_i32", "blob_u8", "blob_bf16"),
-    )(_score_fused_packed_impl)
-except TypeError:  # pragma: no cover - older jax
-    score_fused_packed_donated = score_fused_packed
+# the buffers instead of holding depth x 3 blobs per replica alive. The
+# host keeps its own numpy copy for the retry-on-replica-failure path, so
+# donation never loses data.
+score_fused_packed_donated = partial(
+    jax.jit, static_argnames=_PACKED_STATIC,
+    donate_argnames=("blob_f32", "blob_i32", "blob_u8", "blob_bf16"),
+)(_score_fused_packed_impl)
 
 
 @dataclasses.dataclass
@@ -403,8 +392,7 @@ class ScorerConfig:
     token_cache_entries: int = 65_536
     use_pallas: bool = False   # Pallas flash attention (TPU only)
     # start the result's device->host copy at dispatch time so the transfer
-    # overlaps the next batch's host work (scorer.dispatch). Tunable because
-    # transport backends differ in how they handle outstanding async copies.
+    # overlaps the next batch's host work (scorer.dispatch).
     async_d2h: bool = True
     # ship the bulky float tensors (LSTM history + GNN node/neighbor
     # features, ~45% of the microbatch bytes) as bf16 on the wire; widened
@@ -436,8 +424,8 @@ def make_example_batch(
     b, c = batch_size, config
     return ScoreBatch(
         txn=txn,
-        # host-backend extraction: benches/examples must not trigger a
-        # device->host pull at staging time (see extract_features_host)
+        # host-backend extraction, as the scorer's assemble does (see
+        # extract_features_host)
         features=extract_features_host(txn),
         history=rng.standard_normal((b, c.seq_len, c.feature_dim)).astype(np.float32),
         history_len=np.full((b,), c.seq_len, np.int32),

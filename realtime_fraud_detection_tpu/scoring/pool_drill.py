@@ -56,7 +56,8 @@ class PoolDrillConfig:
     seed: int = 7
     # nominal per-batch costs for the virtual-time schedule replay:
     # ~5 ms host assemble+pack+dispatch (PR-2 columnar at batch 256) and
-    # ~25 ms device compute (BENCH_r04 on-chip capture shape)
+    # ~25 ms device compute (nominal; the schedule replay needs a ratio,
+    # not a measurement)
     host_ms: float = 5.0
     device_ms: float = 25.0
     min_scaling: float = 3.0

@@ -266,7 +266,7 @@ class ServingApp:
         The score lock is held for host-state mutation only (assembly at
         dispatch; write-back inside finalize) — NOT across the device wait,
         so a concurrent caller assembles its batch while this one's compute
-        is in flight (the double-buffered serving path, VERDICT r1 item 6).
+        is in flight (the double-buffered serving path).
         """
         return self._finalize_batch_sync(self._dispatch_batch_sync(txns,
                                                                    trace))
@@ -786,7 +786,7 @@ class ServingApp:
                     # that record DIFFERENT text-encoder architectures —
                     # the blend was measured with one model, the params are
                     # another; serving that pair silently mixes quality
-                    # claims (VERDICT Weak #5). Checked BEFORE the restore
+                    # claims. Checked BEFORE the restore
                     # so a refusal leaves the live deployment untouched;
                     # {"allow_arch_mismatch": true} overrides explicitly.
                     art_tm = Config.load_artifact_text_model(

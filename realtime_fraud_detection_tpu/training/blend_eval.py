@@ -105,7 +105,7 @@ class BlendEvalConfig:
     # saving into a checkpoint_dir whose latest step records a DIFFERENT
     # text-encoder architecture is refused unless explicitly allowed —
     # mixing architectures across steps makes "restore latest + apply
-    # artifact" quietly incoherent (VERDICT Weak #5)
+    # artifact" quietly incoherent
     allow_arch_mismatch: bool = False
 
 

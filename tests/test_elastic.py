@@ -528,7 +528,6 @@ def _one_worker_fleet(tmp_path, tag):
     fleet = ProcessFleet(
         f"127.0.0.1:{broker.port}", f"127.0.0.1:{handoff.port}",
         n_partitions=12,
-        spawn_env={**os.environ, "JAX_PLATFORMS": "cpu"},
         worker_spec={"batch": 32, "max_delay_ms": 5.0,
                      "checkpoint_every": 6, "base_ms": 5.0,
                      "per_txn_ms": 1.5})

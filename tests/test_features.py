@@ -459,3 +459,24 @@ class TestIngestFuzz:
             assert float(batch.amount[0]) >= 0.0
 
         check()
+
+
+def test_missing_cpu_backend_fails_with_a_message_naming_the_fix(monkeypatch):
+    """extract_features_host runs on JAX's CPU backend; a process whose
+    JAX_PLATFORMS lists only the accelerator must fail with a clear
+    message (FraudScorer asks at construction), not as ERROR results."""
+    import jax
+
+    from realtime_fraud_detection_tpu.features import extract
+
+    def no_cpu(backend=None):
+        raise RuntimeError("Unknown backend cpu")
+
+    extract.host_cpu_device.cache_clear()
+    monkeypatch.setattr(jax, "local_devices", no_cpu)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    try:
+        with pytest.raises(RuntimeError, match="JAX_PLATFORMS='tpu'.*tpu,cpu"):
+            extract.host_cpu_device()
+    finally:
+        extract.host_cpu_device.cache_clear()

@@ -562,3 +562,42 @@ def test_kernel_drill_fast_smoke():
         full["divergence"]["noise_floor"]["bound"]
     assert full["modes"]["off"]["epilogue"] == "off"
     assert full["modes"]["on"]["epilogue"] == "pallas"
+
+
+# ---------------------------------------------------------------------------
+# which platform the kernels lower for is decided by the scorer's mesh, and
+# nothing is interpreted or swapped silently
+# ---------------------------------------------------------------------------
+
+def _mesh_on(platform: str):
+    """A stand-in mesh whose devices report ``platform`` (the scorer reads
+    only that before the kernel-plane checks)."""
+    import types
+
+    devices = np.empty((1,), object)
+    devices[0] = types.SimpleNamespace(platform=platform)
+    return types.SimpleNamespace(devices=devices)
+
+
+def test_cpu_mesh_serves_kernels_interpreted_and_says_so():
+    scorer = FraudScorer(Config(kernels=KernelSettings.full()),
+                         scorer_config=ScorerConfig(text_len=32))
+    assert scorer.kernel_snapshot()["interpret"] is True
+    assert scorer.kernel_static()["kernel_interpret"] is True
+
+
+def test_kernels_refuse_a_platform_they_neither_compile_nor_interpret_for():
+    with pytest.raises(ValueError, match="'gpu'"):
+        FraudScorer(Config(kernels=KernelSettings.full()),
+                    mesh=_mesh_on("gpu"))
+
+
+def test_megakernel_is_refused_on_a_tpu_mesh_with_the_compilers_message():
+    from realtime_fraud_detection_tpu.ops.megakernel import MEGA_TPU_REFUSAL
+
+    with pytest.raises(ValueError) as err:
+        FraudScorer(Config(kernels=KernelSettings.mega()),
+                    mesh=_mesh_on("tpu"))
+    assert MEGA_TPU_REFUSAL in str(err.value)
+    # the per-site plane is NOT refused there: it compiles (test_aot_tpu)
+    assert "megakernel" in str(err.value)

@@ -267,3 +267,29 @@ class TestIngressGateway:
         gw = IngressGateway(InMemoryBroker(), T.TRANSACTIONS)
         assert gw.native == native_available()
         gw.close()
+
+
+def test_build_is_keyed_on_source_content(_native, tmp_path):
+    """A binary can only serve the source it was built from: the library
+    name carries the source's digest, an edit builds a new one and drops
+    the stale one — no mtime involved (a copied tree keeps binaries but
+    not necessarily their timestamps)."""
+    import hashlib
+
+    from realtime_fraud_detection_tpu import native
+
+    src = tmp_path / "trees.cpp"
+    shutil.copy(native._TREES_SRC, src)
+
+    def built():
+        return sorted(p.name for p in tmp_path.glob("_trees.*.so"))
+
+    lib, err = native._compile_native(src)
+    assert lib is not None and err is None
+    first = hashlib.sha256(src.read_bytes()).hexdigest()[:16]
+    assert built() == [f"_trees.{first}.so"]
+    src.write_text(src.read_text() + "\n// edited\n")
+    lib, err = native._compile_native(src)
+    assert lib is not None and err is None
+    second = hashlib.sha256(src.read_bytes()).hexdigest()[:16]
+    assert second != first and built() == [f"_trees.{second}.so"]

@@ -449,7 +449,6 @@ class TestSessionEvictionRejoin:
             fleet = ProcessFleet(
                 f"127.0.0.1:{srv.port}", f"127.0.0.1:{handoff.port}",
                 n_partitions=8, session_timeout_s=1.5,
-                spawn_env={**os.environ, "JAX_PLATFORMS": "cpu"},
                 worker_spec={"batch": 32, "max_delay_ms": 10.0,
                              "checkpoint_every": 4, "seq_len": 4,
                              "feature_dim": 4, "heartbeat_s": 0.3})

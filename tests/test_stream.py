@@ -498,3 +498,35 @@ def test_job_topics_configurable_default_contract():
     assert job.run_until_drained(now=1000.0) == 8
     assert len(broker.consumer(["shadow-preds"], "c").poll(100)) == 8
     assert broker.consumer([T.PREDICTIONS], "c2").poll(100) == []
+
+
+def test_whole_batch_degradation_logs_its_cause(caplog):
+    """A scorer failure degrades the batch (0.5 / REVIEW / ERROR, stream
+    alive) — and the exception is readable from the log: first occurrence
+    with its traceback, later ones a line each."""
+    import logging
+
+    gen = TransactionGenerator(num_users=20, num_merchants=10, seed=29)
+    broker = InMemoryBroker()
+    scorer = FraudScorer(scorer_config=ScorerConfig(text_len=32))
+    scorer.seed_profiles(gen.users.profiles(), gen.merchants.profiles())
+    job = StreamJob(broker, scorer, JobConfig(max_batch=4))
+
+    def refuse(*a, **k):
+        raise ValueError("Mosaic failed to compile TPU kernel")
+
+    scorer.dispatch = refuse
+    broker.produce_batch(T.TRANSACTIONS, gen.generate_batch(8),
+                         key_fn=lambda r: str(r["user_id"]))
+    with caplog.at_level(logging.ERROR,
+                         logger="realtime_fraud_detection_tpu.stream.job"):
+        job.run_until_drained()
+    assert job.counters["errors"] == 8 and job.counters["scored"] == 8
+    preds = broker.consumer([T.PREDICTIONS], "t").poll(100)
+    assert all(p.value["risk_level"] == "ERROR" for p in preds)
+    records = [r for r in caplog.records if "dispatch failed" in r.message]
+    assert len(records) >= 2
+    assert all("ValueError: Mosaic failed to compile" in r.getMessage()
+               for r in records)
+    assert records[0].exc_info is not None
+    assert all(r.exc_info is None for r in records[1:])
