@@ -1,0 +1,260 @@
+"""Plain float32 reference of the five-branch ensemble: NumPy and SciPy's
+``erf``, no JAX.
+
+What the three ``distilbert-*`` configurations are held to. It computes,
+from the same weights and the same assembled inputs, what the served
+program computes — every branch's probability, the rule score, the blend,
+its confidence and the decision — in the textbook form of each model and
+in float32 throughout, sharing no line with ``models/``, ``ensemble/`` or
+``features/rules.py``. It reads the weights and the batch by the field
+names of the program's containers (``ScoringModels``, ``ScoreBatch``):
+those are the data format, not the arithmetic.
+
+- text branch: DistilBERT as published (post-layer-norm blocks, learned
+  positions, exact erf GELU, [CLS] -> pre_classifier -> ReLU -> classifier
+  -> softmax[:, 1]); Sanh et al. 2019, ``modeling_distilbert.py``
+- sequence branch: one LSTM layer (gates i, f, g, o from [x, h] @ W + b),
+  front-padded steps skipped, ReLU head
+- graph branch: two GraphSAGE-mean layers over the bipartite one-hop
+  neighbourhoods, ReLU head on [user, merchant, transaction features]
+- trees: complete binary trees, ``x >= threshold`` goes right, sigmoid of
+  base score + sum of leaves; isolation forest: s = 2^(-mean path / c),
+  p = 1 / (1 + exp(0.5 - s))
+- rules and blend: the reference system's rule table and weighted average
+  (TransactionProcessor.java:327-439, ensemble_predictor.py:252-369)
+
+Covered: the deployed settings (weighted-average blend, bipartite graph).
+Anything else raises, so a configuration that changes them brings its own
+reference file.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Dict
+
+import numpy as np
+from scipy.special import erf, expit      # SciPy comes with JAX
+
+F32 = np.float32
+BRANCHES = ("xgboost_primary", "lstm_sequential", "bert_text", "graph_neural",
+            "isolation_forest")
+DECISIONS = ("APPROVE", "APPROVE_WITH_MONITORING", "REVIEW", "DECLINE")
+
+
+def _a(x, dtype=F32) -> np.ndarray:
+    return np.asarray(x, dtype)
+
+
+def _sigmoid(x: np.ndarray) -> np.ndarray:
+    return expit(x).astype(F32)
+
+
+def _softmax(x: np.ndarray) -> np.ndarray:
+    e = np.exp(x - x.max(axis=-1, keepdims=True))
+    return e / e.sum(axis=-1, keepdims=True)
+
+
+def _gelu(x: np.ndarray) -> np.ndarray:
+    """x * Phi(x), exact (the published activation)."""
+    return x * F32(0.5) * (F32(1.0) + erf(x / F32(math.sqrt(2.0))))
+
+
+def _layer_norm(x: np.ndarray, p: Dict[str, Any], eps: float) -> np.ndarray:
+    mu = x.mean(axis=-1, keepdims=True)
+    var = ((x - mu) ** 2).mean(axis=-1, keepdims=True)
+    return (x - mu) / np.sqrt(var + F32(eps)) * _a(p["scale"]) + _a(p["bias"])
+
+
+def _linear(x: np.ndarray, p: Dict[str, Any]) -> np.ndarray:
+    if "w" not in p:
+        raise ValueError("the reference holds float32 weights; this layer "
+                         f"has {sorted(p)}")
+    return x @ _a(p["w"]) + _a(p["b"])
+
+
+# ------------------------------------------------------------------ branches
+def text_branch(bert: Dict[str, Any], token_ids, token_mask, *,
+                n_heads: int, eps: float = 1e-12) -> np.ndarray:
+    ids, mask = np.asarray(token_ids), np.asarray(token_mask, bool)
+    b, s = ids.shape
+    x = _a(bert["word_emb"])[ids] + _a(bert["pos_emb"])[:s][None]
+    x = _layer_norm(x, bert["emb_ln"], eps)
+    h = x.shape[-1]
+    d = h // n_heads
+    for layer in bert["layers"]:
+        def heads(t):
+            return t.reshape(b, s, n_heads, d).transpose(0, 2, 1, 3)
+
+        q, k, v = (heads(_linear(x, layer[n])) for n in ("q", "k", "v"))
+        scores = q @ k.transpose(0, 1, 3, 2) / F32(math.sqrt(d))
+        scores = np.where(mask[:, None, None, :], scores, F32(-1e30))
+        ctx = (_softmax(scores) @ v).transpose(0, 2, 1, 3).reshape(b, s, h)
+        x = _layer_norm(x + _linear(ctx, layer["o"]), layer["attn_ln"], eps)
+        ffn = _linear(_gelu(_linear(x, layer["ffn1"])), layer["ffn2"])
+        x = _layer_norm(x + ffn, layer["ffn_ln"], eps)
+    z = np.maximum(_linear(x[:, 0, :], bert["pre_classifier"]), 0.0)
+    return _softmax(_linear(z, bert["classifier"]))[:, 1].astype(F32)
+
+
+def sequence_branch(lstm: Dict[str, Any], history, history_len) -> np.ndarray:
+    seq, length = _a(history), np.asarray(history_len)
+    b, t, _ = seq.shape
+    w, bias = _a(lstm["w_gates"]), _a(lstm["b_gates"])
+    n = w.shape[1] // 4
+    h = np.zeros((b, n), F32)
+    c = np.zeros((b, n), F32)
+    for step in range(t):
+        z = np.concatenate([seq[:, step], h], axis=-1) @ w + bias
+        i, f, o = (_sigmoid(z[:, j * n:(j + 1) * n]) for j in (0, 1, 3))
+        g = np.tanh(z[:, 2 * n:3 * n])
+        c_new = f * c + i * g
+        h_new = o * np.tanh(c_new)
+        live = (step >= t - length)[:, None]       # front-padded history
+        h, c = np.where(live, h_new, h), np.where(live, c_new, c)
+    z = np.maximum(h @ _a(lstm["w_head1"]) + _a(lstm["b_head1"]), 0.0)
+    return _sigmoid((z @ _a(lstm["w_head2"]) + _a(lstm["b_head2"]))[:, 0])
+
+
+def _masked_mean(x: np.ndarray, mask: np.ndarray) -> np.ndarray:
+    m = mask[..., None].astype(F32)
+    return (x * m).sum(axis=-2) / np.maximum(m.sum(axis=-2), 1.0)
+
+
+def graph_branch(gnn: Dict[str, Any], batch) -> np.ndarray:
+    if "w_node_user" in gnn or batch.user_neigh2_feat is not None:
+        raise ValueError("the reference covers the bipartite one-hop graph "
+                         "branch; this configuration runs the typed one")
+
+    def sage(w, b, own, around):
+        return np.maximum(
+            np.concatenate([own, around], axis=-1) @ _a(gnn[w]) + _a(gnn[b]),
+            0.0)
+
+    def centre(own, neigh, mask):
+        neigh, mask = _a(neigh), np.asarray(mask, bool)
+        # a one-hop neighbour has no sampled neighbourhood of its own
+        frontier = sage("w_sage1", "b_sage1", neigh, np.zeros_like(neigh))
+        return sage("w_sage2", "b_sage2", _a(own),
+                    _masked_mean(frontier, mask))
+
+    z = np.concatenate([
+        centre(batch.user_feat, batch.user_neigh_feat, batch.user_neigh_mask),
+        centre(batch.merchant_feat, batch.merch_neigh_feat,
+               batch.merch_neigh_mask),
+        _a(batch.features)], axis=-1)
+    z = np.maximum(z @ _a(gnn["w_head1"]) + _a(gnn["b_head1"]), 0.0)
+    return _sigmoid((z @ _a(gnn["w_head2"]) + _a(gnn["b_head2"]))[:, 0])
+
+
+def _leaf_values(feature, threshold, leaf, x: np.ndarray) -> np.ndarray:
+    """Value of the leaf each row reaches in each complete tree: [B, T]."""
+    feature, threshold, leaf = (np.asarray(feature), _a(threshold), _a(leaf))
+    n_trees, n_internal = feature.shape
+    rows = np.arange(len(x))[:, None]
+    trees = np.arange(n_trees)[None, :]
+    node = np.zeros((len(x), n_trees), np.int64)
+    while (node < n_internal).all():
+        right = x[rows, feature[trees, node]] >= threshold[trees, node]
+        node = 2 * node + 1 + right
+    return leaf[trees, node - n_internal]
+
+
+def trees_branch(trees, features) -> np.ndarray:
+    x = _a(features)
+    return _sigmoid(_a(trees.base_score) + _leaf_values(
+        trees.feature, trees.threshold, trees.leaf, x).sum(axis=1))
+
+
+def isolation_branch(forest, features) -> np.ndarray:
+    x = _a(features)
+    path = _leaf_values(forest.feature, forest.threshold,
+                        forest.path_length, x).mean(axis=1)
+    s = np.exp2(-path / _a(forest.c_psi))
+    return (1.0 / (1.0 + np.exp(0.5 - s))).astype(F32)
+
+
+# ------------------------------------------------------------- rules, blend
+def rule_score(t) -> np.ndarray:
+    """The reference system's rule table; ``t`` is the encoded transaction
+    batch (``TransactionBatch``: profiles already joined)."""
+    def f(name, dtype=F32):
+        return np.asarray(getattr(t, name), dtype)
+
+    has_user, has_merchant = f("has_user", bool), f("has_merchant", bool)
+    hour = f("hour_of_day", np.int64)
+    score = 0.5 * f("prior_fraud_score")
+    score = score + np.where(
+        has_user,
+        0.2 * f("user_risk_score") + 0.1 * (f("account_age_days") < 30)
+        + 0.15 * ~f("user_verified", bool),
+        0.35)                  # unknown user: risk 0.5, new, unverified
+    risk, rate = f("merchant_risk_code", np.int64), f("merchant_fraud_rate")
+    score = score + np.where(
+        has_merchant,
+        0.2 * (risk == 2) + 0.1 * (risk == 1)
+        + 0.4 * f("merchant_blacklisted", bool)
+        + np.where(rate > 0.05, rate * 2.0, 0.0)
+        + 0.15 * f("merchant_high_risk_category", bool),
+        0.1)                   # unknown merchant: "medium"
+    avg = f("user_avg_amount")
+    large = has_user & (avg > 0) & (f("amount") / np.maximum(avg, 1e-9) > 5.0)
+    new_device = (f("has_txn_fingerprint", bool) & has_user
+                  & f("has_device_list", bool) & ~f("known_device", bool))
+    odd_hour = (hour <= 5) | (hour >= 23)
+    closed = has_merchant & f("has_op_hours", bool) & ~(
+        (hour >= f("merchant_op_start", np.int64))
+        & (hour <= f("merchant_op_end", np.int64)))
+    score = (score + 0.15 * large + 0.1 * new_device + 0.05 * odd_hour
+             + 0.1 * closed)
+    return np.clip(score, 0.0, 1.0).astype(F32)
+
+
+def blend(preds: np.ndarray, valid: np.ndarray, params) -> Dict[str, Any]:
+    """Weighted average over the valid branches, its confidence, and the
+    decision ladder."""
+    if params.strategy != 0:
+        raise ValueError("the reference covers the weighted-average blend")
+    v = valid.astype(F32)
+    w = _a(params.weights)[None, :] * v
+    conf = np.minimum(1.0, np.abs(preds - 0.5) * 2.0
+                      * _a(params.confidence_multipliers)[None, :]) * v
+    total = w.sum(axis=1)
+    some = total > 0
+    prob = np.where(some, (preds * w).sum(axis=1) / np.maximum(total, 1e-12),
+                    0.5).astype(F32)
+    confidence = np.where(
+        some, (conf * w).sum(axis=1) / np.maximum(total, 1e-12),
+        0.0).astype(F32)
+    rungs = {"decline": params.decline_threshold,
+             "review": params.review_threshold,
+             "monitor": params.monitor_threshold,
+             "confidence": params.confidence_threshold}
+    decision = np.where(
+        prob >= rungs["decline"], 3,
+        np.where(prob >= rungs["review"], 2,
+                 np.where(prob >= rungs["monitor"], 1, 0)))
+    decision = np.where(confidence < rungs["confidence"], 2, decision)
+    return {"fraud_probability": prob, "confidence": confidence,
+            "decision": decision, "rungs": rungs}
+
+
+def score(models, batch, params, model_valid, *, n_heads: int
+          ) -> Dict[str, Any]:
+    """Everything the served program returns for ``batch`` (host NumPy
+    copies of the program's containers). ``branches`` is [B, 5] in
+    ``BRANCHES`` order."""
+    preds = np.stack([
+        trees_branch(models.trees, batch.features),
+        sequence_branch(models.lstm, batch.history, batch.history_len),
+        text_branch(models.bert, batch.token_ids, batch.token_mask,
+                    n_heads=n_heads),
+        graph_branch(models.gnn, batch),
+        isolation_branch(models.iforest, batch.features),
+    ], axis=1)
+    valid = (np.asarray(model_valid, bool)[None, :]
+             & np.asarray(batch.valid, bool)[:, None])
+    out = blend(preds, valid, params)
+    out["branches"] = preds
+    out["rule_score"] = rule_score(batch.txn)
+    return out
