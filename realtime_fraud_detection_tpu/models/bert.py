@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from realtime_fraud_detection_tpu.obs import scopes
 from realtime_fraud_detection_tpu.ops.attention import (
     attention_reference,
     flash_attention,
@@ -179,12 +180,14 @@ def bert_encode(
     x = bert_embed(params, input_ids, config,
                    dequant_kernel=dequant_kernel,
                    kernel_interpret=kernel_interpret)
-    for layer in params["layers"]:
-        x = bert_layer(layer, x, attention_mask, config,
-                       use_pallas=use_pallas, compute_dtype=compute_dtype,
-                       attention_fn=attention_fn,
-                       dequant_kernel=dequant_kernel,
-                       kernel_interpret=kernel_interpret)
+    for i, layer in enumerate(params["layers"]):
+        with jax.named_scope(scopes.layer_scope(i)):
+            x = bert_layer(layer, x, attention_mask, config,
+                           use_pallas=use_pallas,
+                           compute_dtype=compute_dtype,
+                           attention_fn=attention_fn,
+                           dequant_kernel=dequant_kernel,
+                           kernel_interpret=kernel_interpret)
     return x
 
 
@@ -194,13 +197,14 @@ def bert_embed(params: Dict, input_ids: jax.Array,
     """Token + position embeddings with the embedding layer norm — shared
     by the sequential and pipeline-parallel encoders."""
     s = input_ids.shape[1]
-    x = (_embedding_rows(params["word_emb"], idx=input_ids,
-                         dequant_kernel=dequant_kernel,
-                         kernel_interpret=kernel_interpret)
-         + _embedding_rows(params["pos_emb"], length=s,
-                           dequant_kernel=dequant_kernel,
-                           kernel_interpret=kernel_interpret)[None, :, :])
-    return _layer_norm(x, params["emb_ln"], config.layer_norm_eps)
+    with jax.named_scope(scopes.EMBED):
+        x = (_embedding_rows(params["word_emb"], idx=input_ids,
+                             dequant_kernel=dequant_kernel,
+                             kernel_interpret=kernel_interpret)
+             + _embedding_rows(params["pos_emb"], length=s,
+                               dequant_kernel=dequant_kernel,
+                               kernel_interpret=kernel_interpret)[None, :, :])
+        return _layer_norm(x, params["emb_ln"], config.layer_norm_eps)
 
 
 def bert_layer(
@@ -218,29 +222,37 @@ def bert_layer(
     schedule (parallel/pipeline.bert_pipeline_encode) spans over stages."""
     b, s = x.shape[:2]
     dk = dict(dequant_kernel=dequant_kernel, kernel_interpret=kernel_interpret)
-    q = _dense(x, layer["q"], compute_dtype, **dk)
-    k = _dense(x, layer["k"], compute_dtype, **dk)
-    v = _dense(x, layer["v"], compute_dtype, **dk)
 
     def split(t):
         return t.reshape(b, s, config.num_heads,
                          config.head_dim).transpose(0, 2, 1, 3)
 
-    qh, kh, vh = split(q), split(k), split(v)
-    if attention_fn is not None:
-        ctx = attention_fn(qh, kh, vh, attention_mask)
-    elif use_pallas:
-        ctx = flash_attention(qh, kh, vh, attention_mask,
-                              interpret=kernel_interpret)
-    else:
-        ctx = attention_reference(qh, kh, vh, attention_mask)
-    ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, config.hidden_size)
-    attn_out = _dense(ctx, layer["o"], compute_dtype, **dk)
-    x = _layer_norm(x + attn_out, layer["attn_ln"], config.layer_norm_eps)
-
-    ffn = _dense(jax.nn.gelu(_dense(x, layer["ffn1"], compute_dtype, **dk)),
-                 layer["ffn2"], compute_dtype, **dk)
-    return _layer_norm(x + ffn, layer["ffn_ln"], config.layer_norm_eps)
+    # the four kernel scopes of a layer (obs/scopes.py): metadata only
+    with jax.named_scope(scopes.ATTN_PROJ):
+        q = _dense(x, layer["q"], compute_dtype, **dk)
+        k = _dense(x, layer["k"], compute_dtype, **dk)
+        v = _dense(x, layer["v"], compute_dtype, **dk)
+        qh, kh, vh = split(q), split(k), split(v)
+    with jax.named_scope(scopes.ATTN_CORE):
+        if attention_fn is not None:
+            ctx = attention_fn(qh, kh, vh, attention_mask)
+        elif use_pallas:
+            ctx = flash_attention(qh, kh, vh, attention_mask,
+                                  interpret=kernel_interpret)
+        else:
+            ctx = attention_reference(qh, kh, vh, attention_mask)
+    with jax.named_scope(scopes.ATTN_PROJ):
+        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, config.hidden_size)
+        attn_out = _dense(ctx, layer["o"], compute_dtype, **dk)
+    with jax.named_scope(scopes.LN):
+        x = _layer_norm(x + attn_out, layer["attn_ln"],
+                        config.layer_norm_eps)
+    with jax.named_scope(scopes.FFN):
+        ffn = _dense(
+            jax.nn.gelu(_dense(x, layer["ffn1"], compute_dtype, **dk)),
+            layer["ffn2"], compute_dtype, **dk)
+    with jax.named_scope(scopes.LN):
+        return _layer_norm(x + ffn, layer["ffn_ln"], config.layer_norm_eps)
 
 
 def bert_logits(
@@ -260,9 +272,11 @@ def bert_logits(
                          attention_fn=attention_fn,
                          dequant_kernel=dequant_kernel,
                          kernel_interpret=kernel_interpret)
-    cls = hidden[:, 0, :]
-    z = jax.nn.relu(cls @ params["pre_classifier"]["w"] + params["pre_classifier"]["b"])
-    return z @ params["classifier"]["w"] + params["classifier"]["b"]
+    with jax.named_scope(scopes.HEAD):
+        cls = hidden[:, 0, :]
+        z = jax.nn.relu(cls @ params["pre_classifier"]["w"]
+                        + params["pre_classifier"]["b"])
+        return z @ params["classifier"]["w"] + params["classifier"]["b"]
 
 
 def bert_predict(

@@ -26,9 +26,8 @@ from realtime_fraud_detection_tpu.obs.metrics import (
     Registry,
 )
 from realtime_fraud_detection_tpu.obs.profiling import (
+    GcSpans,
     SpanTimer,
-    annotate,
-    device_trace,
 )
 from realtime_fraud_detection_tpu.obs.tracing import (
     SloTracker,
@@ -43,6 +42,7 @@ __all__ = [
     "DriftReport",
     "FeatureDriftMonitor",
     "Gauge",
+    "GcSpans",
     "Histogram",
     "JsonFormatter",
     "MetricsCollector",
@@ -52,8 +52,6 @@ __all__ = [
     "TraceBatch",
     "TraceContext",
     "Tracer",
-    "annotate",
-    "device_trace",
     "log_batch_scored",
     "log_model_event",
     "log_prediction_result",

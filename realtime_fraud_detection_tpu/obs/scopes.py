@@ -1,0 +1,91 @@
+"""The names the program gives its own work, in one place.
+
+Device side: ``jax.named_scope`` names on the fused scoring program's
+branches and on the text branch's kernels. They are HLO metadata only
+(``op_name`` of each instruction; the compiled program is the same with
+and without them, ``tests/test_scopes.py``) and reach a profiler trace as
+the operation's name path, ``jit(...)/text/layer0/ffn/dot_general``.
+
+Host side: the ``SpanTimer`` span names of one microbatch, outermost
+first, as ``FraudScorer`` and ``StreamJob`` open them
+(``obs/profiling.SpanTimer.span``). In a profiler session each is a
+``TraceAnnotation`` named ``rtfd:<name>`` carrying ``batch=<n>``.
+
+``benchmarks/harness/scopes.py`` matches on these strings; ``PERF.md`` §3
+says which metric reads which.
+"""
+
+from __future__ import annotations
+
+from typing import Tuple
+
+# ---- device: branches of scoring/pipeline._score_fused_impl
+TREES = "trees"
+LSTM = "lstm"
+TEXT = "text"
+GNN = "gnn"
+IFOREST = "iforest"
+RULES = "rules"
+BLEND = "blend"
+# ---- device: the packed entry's own work (_score_fused_packed_impl)
+UNPACK = "unpack"
+REPACK = "repack"
+BRANCH_SCOPES: Tuple[str, ...] = (TREES, LSTM, TEXT, GNN, IFOREST, RULES,
+                                  BLEND, UNPACK, REPACK)
+
+# ---- device: under ``text`` (models/bert.py)
+EMBED = "embed"
+LAYER = "layer"              # ``layer<i>``, i from 0
+ATTN_PROJ = "attn_proj"      # q, k, v and o projections
+ATTN_CORE = "attn_core"      # scores, mask, softmax, weighted sum
+FFN = "ffn"                  # ffn1, GELU, ffn2
+LN = "ln"                    # both layer norms with their residual adds
+HEAD = "head"
+LAYER_SCOPES: Tuple[str, ...] = (ATTN_PROJ, ATTN_CORE, FFN, LN)
+
+
+def layer_scope(i: int) -> str:
+    return f"{LAYER}{i}"
+
+
+# ---- host: prefix of every span's TraceAnnotation
+ANNOTATION_PREFIX = "rtfd:"
+HOST_GC = "host.gc"          # one annotation per collection (tracing on)
+
+# ---- host: the spans of one microbatch; (name, parent)
+JOB_POLL = "job.poll"
+JOB_DISPATCH = "job.dispatch_batch"
+JOB_ADMIT = "job.admit"
+ASSEMBLE = "assemble"
+ASSEMBLE_ENCODE = "assemble.encode"
+ASSEMBLE_FEATURES = "assemble.features"
+ASSEMBLE_HISTORY = "assemble.history"
+GRAPH = "graph"
+ASSEMBLE_TOKENIZE = "assemble.tokenize"
+PACK = "pack"
+DISPATCH = "dispatch"
+JOB_COMPLETE = "job.complete_batch"
+DEVICE_WAIT = "device_wait"
+FINALIZE_RESPONSES = "finalize.responses"
+FINALIZE_WRITE_BACK = "finalize.write_back"
+JOB_FAN_OUT = "job.fan_out"
+JOB_COMMIT = "job.commit"
+BATCH_SPANS: Tuple[Tuple[str, str], ...] = (
+    (JOB_POLL, ""),
+    (JOB_DISPATCH, ""),
+    (JOB_ADMIT, JOB_DISPATCH),
+    (ASSEMBLE, JOB_DISPATCH),
+    (ASSEMBLE_ENCODE, ASSEMBLE),
+    (ASSEMBLE_FEATURES, ASSEMBLE),
+    (ASSEMBLE_HISTORY, ASSEMBLE),
+    (GRAPH, ASSEMBLE),
+    (ASSEMBLE_TOKENIZE, ASSEMBLE),
+    (PACK, JOB_DISPATCH),
+    (DISPATCH, JOB_DISPATCH),
+    (JOB_COMPLETE, ""),
+    (DEVICE_WAIT, JOB_COMPLETE),
+    (FINALIZE_RESPONSES, JOB_COMPLETE),
+    (FINALIZE_WRITE_BACK, JOB_COMPLETE),
+    (JOB_FAN_OUT, JOB_COMPLETE),
+    (JOB_COMMIT, JOB_COMPLETE),
+)

@@ -432,6 +432,9 @@ class Tracer:
         # ``meta["fault"]``, so a flight-recorder window spanning an
         # injected outage separates in-fault tails from steady state
         self.fault_context: str = ""
+        # the owner's collector watch (obs/profiling.GcSpans; StreamJob
+        # sets it): count / seconds / longest pause ride the snapshot
+        self.host_gc: Optional[Any] = None
         self.slo = SloTracker(
             objective_ms=s.slo_objective_ms,
             objective_frac=s.slo_objective_frac,
@@ -782,13 +785,16 @@ class Tracer:
                 for name, agg in self._stage_agg.items()
             }
             counters = dict(self.counters)
-        return {
+        out = {
             "enabled": self.enabled,
             "buckets_ms": list(TRACE_STAGE_BUCKETS_MS),
             "stages": stages,
             "counters": counters,
             "slo": self.slo.snapshot(),
         }
+        if self.host_gc is not None:
+            out["host_gc"] = self.host_gc.snapshot()
+        return out
 
     def reset(self) -> None:
         """Drop the captured window (testing/drills); cumulative counters

@@ -157,11 +157,12 @@ class AssemblerStage:
             t0 = time.perf_counter()
             try:
                 with self.lock:
-                    if trace is not None:
-                        trace.mark("assemble")
-                    batch = self.scorer.assemble(records, now)
+                    # the trace kwarg only when tracing is live (drills
+                    # drive this stage with duck-typed scorer stand-ins)
+                    kw = {"trace": trace} if trace is not None else {}
+                    batch = self.scorer.assemble(records, now, **kw)
                     pending = self.scorer.dispatch_assembled(
-                        batch, records, t0=t0, trace=trace)
+                        batch, records, t0=t0, **kw)
             except BaseException as e:  # noqa: BLE001 — surfaces at result()
                 # account busy time BEFORE resolving the handle: a caller
                 # that reads busy_s right after the last result() must see

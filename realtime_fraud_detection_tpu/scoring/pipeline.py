@@ -49,6 +49,7 @@ from realtime_fraud_detection_tpu.models.trees import (
     TreeEnsemble,
     tree_ensemble_predict,
 )
+from realtime_fraud_detection_tpu.obs import scopes
 from realtime_fraud_detection_tpu.ops.epilogue import (
     epilogue_supported,
     fused_epilogue,
@@ -176,50 +177,56 @@ def _score_fused_impl(
     """
     features = batch.features                                   # f32[B, 64]
 
-    preds = jnp.stack(
-        [
-            tree_ensemble_predict(models.trees, features,
-                                  kernel=tree_kernel),
-            jax.nn.sigmoid(
-                lstm_logits(models.lstm, batch.history, batch.history_len)
-            ),
-            bert_predict(
-                models.bert, batch.token_ids, batch.token_mask,
-                bert_config, use_pallas=use_pallas,
-                dequant_kernel=dequant_kernel,
-                kernel_interpret=kernel_interpret,
-            ),
-            jax.nn.sigmoid(
-                gnn_logits(
-                    models.gnn, features,
-                    batch.user_feat, batch.merchant_feat,
-                    batch.user_neigh_feat, batch.user_neigh_mask,
-                    batch.merch_neigh_feat, batch.merch_neigh_mask,
-                    user_neigh2_feat=batch.user_neigh2_feat,
-                    user_neigh2_mask=batch.user_neigh2_mask,
-                    merch_neigh2_feat=batch.merch_neigh2_feat,
-                    merch_neigh2_mask=batch.merch_neigh2_mask,
-                )
-            ),
-            iforest_predict(models.iforest, features,
-                            kernel=iforest_kernel),
-        ],
-        axis=1,
-    )                                                            # f32[B, M]
-
-    valid = jnp.broadcast_to(model_valid[None, :], preds.shape) & batch.valid[:, None]
-    rule = rule_score(batch.txn)
-    if (epilogue_kernel == "pallas"
-            and epilogue_supported(preds.shape[0], preds.shape[1])):
-        # fused score-and-blend (ops/epilogue.py): combine + decision/risk
-        # ladders + the finalize-derived columns (explanation contributions,
-        # rules-only ladder) run on-chip in one kernel
-        out = dict(fused_epilogue(preds, valid, rule, params,
-                                  interpret=kernel_interpret))
-    else:
-        out = dict(combine_predictions(preds, valid, params))
-    out["rule_score"] = rule
-    out.update(_key_factors(batch.txn))
+    # one named scope per branch (obs/scopes.py): HLO metadata only, so a
+    # device trace can say which branch an operation belongs to
+    with jax.named_scope(scopes.TREES):
+        p_trees = tree_ensemble_predict(models.trees, features,
+                                        kernel=tree_kernel)
+    with jax.named_scope(scopes.LSTM):
+        p_lstm = jax.nn.sigmoid(
+            lstm_logits(models.lstm, batch.history, batch.history_len))
+    with jax.named_scope(scopes.TEXT):
+        p_text = bert_predict(
+            models.bert, batch.token_ids, batch.token_mask,
+            bert_config, use_pallas=use_pallas,
+            dequant_kernel=dequant_kernel,
+            kernel_interpret=kernel_interpret,
+        )
+    with jax.named_scope(scopes.GNN):
+        p_gnn = jax.nn.sigmoid(
+            gnn_logits(
+                models.gnn, features,
+                batch.user_feat, batch.merchant_feat,
+                batch.user_neigh_feat, batch.user_neigh_mask,
+                batch.merch_neigh_feat, batch.merch_neigh_mask,
+                user_neigh2_feat=batch.user_neigh2_feat,
+                user_neigh2_mask=batch.user_neigh2_mask,
+                merch_neigh2_feat=batch.merch_neigh2_feat,
+                merch_neigh2_mask=batch.merch_neigh2_mask,
+            )
+        )
+    with jax.named_scope(scopes.IFOREST):
+        p_iforest = iforest_predict(models.iforest, features,
+                                    kernel=iforest_kernel)
+    with jax.named_scope(scopes.BLEND):
+        preds = jnp.stack([p_trees, p_lstm, p_text, p_gnn, p_iforest],
+                          axis=1)                                # f32[B, M]
+        valid = (jnp.broadcast_to(model_valid[None, :], preds.shape)
+                 & batch.valid[:, None])
+    with jax.named_scope(scopes.RULES):
+        rule = rule_score(batch.txn)
+    with jax.named_scope(scopes.BLEND):
+        if (epilogue_kernel == "pallas"
+                and epilogue_supported(preds.shape[0], preds.shape[1])):
+            # fused score-and-blend (ops/epilogue.py): combine + decision/
+            # risk ladders + the finalize-derived columns (explanation
+            # contributions, rules-only ladder) run on-chip in one kernel
+            out = dict(fused_epilogue(preds, valid, rule, params,
+                                      interpret=kernel_interpret))
+        else:
+            out = dict(combine_predictions(preds, valid, params))
+        out["rule_score"] = rule
+        out.update(_key_factors(batch.txn))
     if with_model_preds:
         out["model_predictions"] = preds
     return out
@@ -291,12 +298,14 @@ def _score_fused_packed_impl(
     blobs = {"f32": blob_f32, "i32": blob_i32, "u8": blob_u8}
     if blob_bf16 is not None:
         blobs["bf16"] = blob_bf16
-    batch = unpack_tree(blobs, spec)
-    # bf16 was a wire format: widen back to f32 before the branches (the
-    # cast fuses into the first consumer, costing no extra HBM traffic)
-    batch = jax.tree.map(
-        lambda x: x.astype(jnp.float32) if x.dtype == jnp.bfloat16 else x,
-        batch)
+    with jax.named_scope(scopes.UNPACK):
+        batch = unpack_tree(blobs, spec)
+        # bf16 was a wire format: widen back to f32 before the branches
+        # (the cast fuses into the first consumer, costing no extra HBM
+        # traffic)
+        batch = jax.tree.map(
+            lambda x: x.astype(jnp.float32) if x.dtype == jnp.bfloat16
+            else x, batch)
     if megakernel == "pallas" and mega_valid is not None:
         # persistent megakernel (ops/megakernel.py): score the whole
         # microbatch in ONE Pallas program whose output IS the extended
@@ -330,16 +339,17 @@ def _score_fused_packed_impl(
         dequant_kernel=dequant_kernel, epilogue_kernel=epilogue_kernel,
         kernel_interpret=kernel_interpret,
     )
-    cols = [out[name].astype(jnp.float32) for name in OUT_COLUMNS]
-    parts = [jnp.stack(cols, axis=1), out["model_predictions"]]
-    if "model_contributions" in out:
-        # fused-epilogue extension (EXT_COLUMNS): finalize's derived
-        # columns come back in the same single d2h matrix
-        parts.append(out["model_contributions"].astype(jnp.float32))
-        parts.append(jnp.stack(
-            [out["rule_decision"].astype(jnp.float32),
-             out["rule_risk"].astype(jnp.float32)], axis=1))
-    return jnp.concatenate(parts, axis=1)
+    with jax.named_scope(scopes.REPACK):
+        cols = [out[name].astype(jnp.float32) for name in OUT_COLUMNS]
+        parts = [jnp.stack(cols, axis=1), out["model_predictions"]]
+        if "model_contributions" in out:
+            # fused-epilogue extension (EXT_COLUMNS): finalize's derived
+            # columns come back in the same single d2h matrix
+            parts.append(out["model_contributions"].astype(jnp.float32))
+            parts.append(jnp.stack(
+                [out["rule_decision"].astype(jnp.float32),
+                 out["rule_risk"].astype(jnp.float32)], axis=1))
+        return jnp.concatenate(parts, axis=1)
 
 
 _PACKED_STATIC = ("spec", "bert_config", "use_pallas", "tree_kernel",

@@ -41,6 +41,7 @@ from realtime_fraud_detection_tpu.features.schema import encode_transactions
 from realtime_fraud_detection_tpu.models.bert import BertConfig, TINY_CONFIG
 from realtime_fraud_detection_tpu.models.text import combined_text
 from realtime_fraud_detection_tpu.models.tokenizer import FraudTokenizer
+from realtime_fraud_detection_tpu.obs import scopes
 from realtime_fraud_detection_tpu.core.packing import pack_tree
 from realtime_fraud_detection_tpu.scoring.pipeline import (
     MODEL_NAMES,
@@ -105,6 +106,14 @@ class PendingScore:
     # the owner (stream job / serving app) finishes it after fan-out.
     # None = tracing off (the default no-op fast path).
     trace: Optional[Any] = None
+    # What the text branch was launched with, counted at dispatch (exact
+    # integers, no clock): bucket rows x padded text_len, bucket rows x
+    # text_len^2 (what attention's cost follows), and the real tokens
+    # (token_mask.sum() over the real rows). StreamJob sums them into its
+    # counters beside ``batches``.
+    token_slots: int = 0
+    token_slots_sq: int = 0
+    real_tokens: int = 0
 
 
 class _EntityIndex:
@@ -893,7 +902,8 @@ class FraudScorer:
 
     # ---------------------------------------------------------------- assembly
     def assemble(self, records: Sequence[Mapping[str, Any]],
-                 now: Optional[float] = None) -> ScoreBatch:
+                 now: Optional[float] = None,
+                 trace: Optional[Any] = None) -> ScoreBatch:
         """Join state + encode one dense ScoreBatch (host side of the seam).
 
         Columnar: profile/velocity joins gather through the generation-
@@ -904,56 +914,60 @@ class FraudScorer:
         fields. Bit-identical to ``assemble_serial`` (the record-at-a-time
         reference path) by construction and by test.
         """
-        # rtfd-lint: allow[wall-clock] span diagnostics (host_stats), not scoring control flow
-        t0 = time.perf_counter()
-        user_ids = [str(r.get("user_id", "")) for r in records]
-        merchant_ids = [str(r.get("merchant_id", "")) for r in records]
-        uprofs = {u: p for u in user_ids
-                  if (p := self.profiles.get_user(u)) is not None}
-        mprofs = {m: p for m in merchant_ids
-                  if (p := self.profiles.get_merchant(m)) is not None}
-        velocities = {u: self.velocity.get_all(u, now) for u in set(user_ids)}
-
+        from realtime_fraud_detection_tpu.features.extract import (
+            extract_features_host,
+        )
         from realtime_fraud_detection_tpu.features.schema import (
             encode_transactions_columnar,
         )
 
-        self._join_cache.sync(self.profiles)
-        txn = encode_transactions_columnar(records, uprofs, mprofs,
-                                           velocities,
-                                           cache=self._join_cache)
+        span = self.spans.span
+        with span(scopes.ASSEMBLE, trace=trace):
+            with span(scopes.ASSEMBLE_ENCODE):
+                user_ids = [str(r.get("user_id", "")) for r in records]
+                merchant_ids = [str(r.get("merchant_id", ""))
+                                for r in records]
+                uprofs = {u: p for u in user_ids
+                          if (p := self.profiles.get_user(u)) is not None}
+                mprofs = {m: p for m in merchant_ids
+                          if (p := self.profiles.get_merchant(m)) is not None}
+                velocities = {u: self.velocity.get_all(u, now)
+                              for u in set(user_ids)}
+                self._join_cache.sync(self.profiles)
+                txn = encode_transactions_columnar(records, uprofs, mprofs,
+                                                   velocities,
+                                                   cache=self._join_cache)
 
-        # feature history for the LSTM branch: append-then-gather semantics.
-        # Extraction runs on the HOST backend: the rows are needed host-side
-        # regardless (see extract_features_host).
-        from realtime_fraud_detection_tpu.features.extract import (
-            extract_features_host,
-        )
-        feats = extract_features_host(txn)
-        self.last_features = feats  # host copy for feature-topic fan-out
-        history, history_len = self.history.append_and_gather(user_ids, feats)
+            # feature history for the LSTM branch: append-then-gather
+            # semantics. Extraction runs on the HOST backend: the rows are
+            # needed host-side regardless (see extract_features_host).
+            with span(scopes.ASSEMBLE_FEATURES):
+                feats = extract_features_host(txn)
+                self.last_features = feats  # host copy: feature-topic fan-out
+            with span(scopes.ASSEMBLE_HISTORY):
+                history, history_len = self.history.append_and_gather(
+                    user_ids, feats)
 
-        # entity graph for the GNN branch (ONE seam for both assemble paths)
-        u_idx = self._users.lookup_batch(user_ids, uprofs, False)
-        m_idx = self._merchants.lookup_batch(merchant_ids, mprofs, True)
-        graph_t = self._graph_join(user_ids, merchant_ids, u_idx, m_idx)
+            # entity graph for the GNN branch (ONE seam for both assemble
+            # paths; ``graph`` is its own span, inside ``_graph_join``)
+            u_idx = self._users.lookup_batch(user_ids, uprofs, False)
+            m_idx = self._merchants.lookup_batch(merchant_ids, mprofs, True)
+            graph_t = self._graph_join(user_ids, merchant_ids, u_idx, m_idx)
 
-        token_ids, token_mask = self.tokenizer.encode_batch(
-            self._texts_for(records, merchant_ids, mprofs))
+            with span(scopes.ASSEMBLE_TOKENIZE):
+                token_ids, token_mask = self.tokenizer.encode_batch(
+                    self._texts_for(records, merchant_ids, mprofs))
 
-        batch = ScoreBatch(
-            txn=txn,
-            features=feats,
-            history=history,
-            history_len=history_len,
-            token_ids=token_ids.astype(np.int32),
-            token_mask=token_mask.astype(bool),
-            valid=np.ones((len(records),), bool),
-            **graph_t,
-        )
-        # rtfd-lint: allow[wall-clock] span diagnostics (host_stats), not scoring control flow
-        self.spans.record("assemble", time.perf_counter() - t0)
-        return batch
+            return ScoreBatch(
+                txn=txn,
+                features=feats,
+                history=history,
+                history_len=history_len,
+                token_ids=token_ids.astype(np.int32),
+                token_mask=token_mask.astype(bool),
+                valid=np.ones((len(records),), bool),
+                **graph_t,
+            )
 
     def _graph_join(self, user_ids: Sequence[str],
                     merchant_ids: Sequence[str],
@@ -971,28 +985,25 @@ class FraudScorer:
         (``_write_back`` → ``TypedEntityGraph.add_batch``): the typed
         store lives in the partition bundle, and write-back is where
         every other partition-owned store mutates."""
-        # rtfd-lint: allow[wall-clock] span diagnostics (host_stats), not scoring control flow
-        t0 = time.perf_counter()
-        utable, mtable = self._users.table(), self._merchants.table()
-        out: Dict[str, np.ndarray] = {
-            "user_feat": utable[u_idx],
-            "merchant_feat": mtable[m_idx],
-        }
-        if self._sampler is not None:
-            out.update(self._sampler.sample(user_ids, merchant_ids))
-        else:
-            un_idx, un_mask = self.graph.user_neighbors(u_idx)
-            mn_idx, mn_mask = self.graph.merchant_neighbors(m_idx)
-            out.update(
-                user_neigh_feat=mtable[np.where(un_mask, un_idx, 0)],
-                user_neigh_mask=un_mask,
-                merch_neigh_feat=utable[np.where(mn_mask, mn_idx, 0)],
-                merch_neigh_mask=mn_mask,
-            )
-            self.graph.add_edges(u_idx, m_idx)
-        # rtfd-lint: allow[wall-clock] span diagnostics (host_stats), not scoring control flow
-        self.spans.record("graph", time.perf_counter() - t0)
-        return out
+        with self.spans.span(scopes.GRAPH):
+            utable, mtable = self._users.table(), self._merchants.table()
+            out: Dict[str, np.ndarray] = {
+                "user_feat": utable[u_idx],
+                "merchant_feat": mtable[m_idx],
+            }
+            if self._sampler is not None:
+                out.update(self._sampler.sample(user_ids, merchant_ids))
+            else:
+                un_idx, un_mask = self.graph.user_neighbors(u_idx)
+                mn_idx, mn_mask = self.graph.merchant_neighbors(m_idx)
+                out.update(
+                    user_neigh_feat=mtable[np.where(un_mask, un_idx, 0)],
+                    user_neigh_mask=un_mask,
+                    merch_neigh_feat=utable[np.where(mn_mask, mn_idx, 0)],
+                    merch_neigh_mask=mn_mask,
+                )
+                self.graph.add_edges(u_idx, m_idx)
+            return out
 
     def _texts_for(self, records, merchant_ids, mprofs) -> List[str]:
         """Combined text per record for the text branch (models/text.py)."""
@@ -1081,10 +1092,12 @@ class FraudScorer:
         )
 
     def host_stats(self) -> Dict[str, Any]:
-        """Host-assembly observability payload: per-stage span stats
-        (assemble/pack/dispatch/device_wait) and cache hit/miss counters —
-        the source obs/metrics.MetricsCollector.sync_host_stats exports as
-        Prometheus series."""
+        """Host-assembly observability payload: per-stage span stats (every
+        name of obs/scopes.BATCH_SPANS that ran, a StreamJob's ``job.*``
+        spans included; each with ``total_s``, ``self_s`` and ``parent``)
+        and cache hit/miss counters — the source
+        obs/metrics.MetricsCollector.sync_host_stats exports as Prometheus
+        series."""
         caches: Dict[str, Any] = {"entity_rows": self._join_cache.stats()}
         cache_stats = getattr(self.tokenizer, "cache_stats", None)
         if cache_stats is not None:
@@ -1106,18 +1119,17 @@ class FraudScorer:
         (SURVEY.md §2.8).
 
         ``trace`` (an obs.tracing.TraceBatch) collects batch-granular
-        stage marks; None — the default — costs one branch per stage.
+        stage marks from the same spans (``SpanTimer.span``); None is the
+        default.
         """
-        # rtfd-lint: allow[wall-clock] span diagnostics (host_stats), not scoring control flow
+        # rtfd-lint: allow[wall-clock] dispatch_ms / processing_time_ms of the §2.7 response, not scoring control flow
         t0 = time.perf_counter()
         n = len(records)
         if n == 0:
             return PendingScore(records=[], n=0, out=None,
                                 features=self.last_features[:0],
                                 dispatch_ms=0.0)
-        if trace is not None:
-            trace.mark("assemble")
-        batch = self.assemble(records, now)
+        batch = self.assemble(records, now, trace=trace)
         return self.dispatch_assembled(batch, records, t0=t0, trace=trace)
 
     def dispatch_assembled(self, batch: ScoreBatch,
@@ -1129,84 +1141,83 @@ class FraudScorer:
         (scoring/host_pipeline.py) can run ``assemble`` on its own thread
         and hand the result here."""
         if t0 is None:
-            # rtfd-lint: allow[wall-clock] span diagnostics (host_stats), not scoring control flow
+            # rtfd-lint: allow[wall-clock] dispatch_ms / processing_time_ms of the §2.7 response, not scoring control flow
             t0 = time.perf_counter()
-        if trace is not None:
-            trace.mark("pack")
-        # rtfd-lint: allow[wall-clock] span diagnostics (host_stats), not scoring control flow
-        t_pack = time.perf_counter()
         n = len(records)
-        # an attached mesh executor (scoring/mesh_executor.py) shards the
-        # batch over ITS data axis, which may differ from this scorer's
-        # own mesh (e.g. a 1-device reference scorer driving a 4x2
-        # executor) — pad to whichever seam the batch will actually cross
-        multiple = (getattr(self._pool, "batch_multiple", None)
-                    or local_mesh_size(self.mesh))
-        size = bucket_for(n, BATCH_BUCKETS, multiple_of=multiple)
-        # write-into staging: pad rows replicate row 0, the real validity
-        # is the staging mask (same contract as pad_to_bucket)
-        padded, mask = self._staging.pad(batch, n, size)
-        padded = padded.replace(valid=mask)
-        # Packed seam (core/packing.py): the 65-leaf ScoreBatch collapses
-        # to 3 dense blobs (one h2d payload), the program returns ONE f32
-        # matrix (one d2h payload).
-        if self.sc.transfer_bf16:
-            padded = _stage_bf16(padded)
-        blobs, spec = pack_tree(padded)
-        # rtfd-lint: allow[wall-clock] span diagnostics (host_stats), not scoring control flow
-        self.spans.record("pack", time.perf_counter() - t_pack)
-        if trace is not None:
-            trace.mark("dispatch")
-        # rtfd-lint: allow[wall-clock] span diagnostics (host_stats), not scoring control flow
-        t_disp = time.perf_counter()
+        with self.spans.span(scopes.PACK, trace=trace):
+            # an attached mesh executor (scoring/mesh_executor.py) shards
+            # the batch over ITS data axis, which may differ from this
+            # scorer's own mesh (e.g. a 1-device reference scorer driving a
+            # 4x2 executor) — pad to whichever seam the batch will cross
+            multiple = (getattr(self._pool, "batch_multiple", None)
+                        or local_mesh_size(self.mesh))
+            size = bucket_for(n, BATCH_BUCKETS, multiple_of=multiple)
+            # write-into staging: pad rows replicate row 0, the real
+            # validity is the staging mask (same contract as pad_to_bucket)
+            padded, mask = self._staging.pad(batch, n, size)
+            padded = padded.replace(valid=mask)
+            # Packed seam (core/packing.py): the 65-leaf ScoreBatch
+            # collapses to 3 dense blobs (one h2d payload), the program
+            # returns ONE f32 matrix (one d2h payload).
+            if self.sc.transfer_bf16:
+                padded = _stage_bf16(padded)
+            blobs, spec = pack_tree(padded)
+        # what the text branch is launched with (PendingScore.token_slots)
+        text_len = int(batch.token_ids.shape[1])
+        real_tokens = int(np.count_nonzero(batch.token_mask))
 
-        mv = self.effective_model_valid()
-        rules_only = self._qos_rules_only
-        self._record_kernel_dispatch(size)
-        token = None
-        if self._pool is not None:
-            # pooled mode: the whole microbatch runs on ONE replica (model
-            # replication, not batch sharding) picked round-robin by the
-            # pool; in-flight depth and retry live there
-            token = self._pool.dispatch_packed(
-                blobs, spec, self.ensemble_params, mv)
-            out = token.out
-            if trace is not None:
-                # which replica got the batch, and how deep its queue was
-                # at dispatch — the tail-attribution metadata the ISSUE's
-                # "where did the p99 go" question needs
-                trace.annotate(replica=token.replica_idx,
-                               inflight_depth=token.inflight_at_dispatch)
-        else:
-            sharded = shard_batch(self.mesh, blobs)
-            out = score_fused_packed(
-                self.models, sharded["f32"], sharded["i32"], sharded["u8"],
-                spec=spec, params=self.ensemble_params,
-                model_valid=self._model_valid_dev(mv),
-                blob_bf16=sharded["bf16"],
-                bert_config=self.bert_config,
-                use_pallas=self.effective_use_pallas(),
-                **self.quant_static(), **self.kernel_static(mv),
-            )
-        # Start the device->host copy NOW (it queues behind the compute):
-        # by the time finalize() calls device_get, the transfer is already
-        # in flight or done, so the d2h RTT overlaps the next batch's
-        # assemble instead of serializing after it.
-        if self.sc.async_d2h:
-            out.copy_to_host_async()
-        # rtfd-lint: allow[wall-clock] span diagnostics (host_stats), not scoring control flow
-        self.spans.record("dispatch", time.perf_counter() - t_disp)
-        if trace is not None:
-            # launch returned: from the transaction's point of view the
-            # device residency (compute + any pipeline dwell) starts here
-            trace.mark("device_wait")
+        # the tracer's ``device_wait`` stage begins where the launch
+        # returns: from the transaction's point of view the device
+        # residency (compute + any pipeline dwell) starts there
+        with self.spans.span(scopes.DISPATCH, trace=trace,
+                             then=scopes.DEVICE_WAIT):
+            mv = self.effective_model_valid()
+            rules_only = self._qos_rules_only
+            self._record_kernel_dispatch(size)
+            token = None
+            if self._pool is not None:
+                # pooled mode: the whole microbatch runs on ONE replica
+                # (model replication, not batch sharding) picked
+                # round-robin by the pool; in-flight depth and retry live
+                # there
+                token = self._pool.dispatch_packed(
+                    blobs, spec, self.ensemble_params, mv)
+                out = token.out
+                if trace is not None:
+                    # which replica got the batch, and how deep its queue
+                    # was at dispatch — the tail-attribution metadata the
+                    # ISSUE's "where did the p99 go" question needs
+                    trace.annotate(replica=token.replica_idx,
+                                   inflight_depth=token.inflight_at_dispatch)
+            else:
+                sharded = shard_batch(self.mesh, blobs)
+                out = score_fused_packed(
+                    self.models, sharded["f32"], sharded["i32"],
+                    sharded["u8"],
+                    spec=spec, params=self.ensemble_params,
+                    model_valid=self._model_valid_dev(mv),
+                    blob_bf16=sharded["bf16"],
+                    bert_config=self.bert_config,
+                    use_pallas=self.effective_use_pallas(),
+                    **self.quant_static(), **self.kernel_static(mv),
+                )
+            # Start the device->host copy NOW (it queues behind the
+            # compute): by the time finalize() calls device_get, the
+            # transfer is already in flight or done, so the d2h RTT
+            # overlaps the next batch's assemble instead of serializing
+            # after it.
+            if self.sc.async_d2h:
+                out.copy_to_host_async()
         return PendingScore(records=list(records), n=n, out=out,
                             # rtfd-lint: allow[d2h] batch.features is a host-assembled ndarray
                             features=np.asarray(batch.features),
-                            # rtfd-lint: allow[wall-clock] span diagnostics (host_stats), not scoring control flow
+                            # rtfd-lint: allow[wall-clock] dispatch_ms / processing_time_ms of the §2.7 response, not scoring control flow
                             dispatch_ms=(time.perf_counter() - t0) * 1000.0,
                             model_valid=mv, rules_only=rules_only,
-                            pool_token=token, trace=trace)
+                            pool_token=token, trace=trace,
+                            token_slots=size * text_len,
+                            token_slots_sq=size * text_len * text_len,
+                            real_tokens=real_tokens)
 
     def finalize(self, pending: "PendingScore", now: Optional[float] = None,
                  lock=None) -> List[Dict[str, Any]]:
@@ -1220,34 +1231,38 @@ class FraudScorer:
 
         if pending.n == 0:
             return []
-        # rtfd-lint: allow[wall-clock] span diagnostics (host_stats), not scoring control flow
+        span, token = self.spans.span, pending.pool_token
+        # pooled path: the span says which replica it waited for
+        ids = {"replica": token.replica_idx} if token is not None else {}
+        # rtfd-lint: allow[wall-clock] dispatch_ms / processing_time_ms of the §2.7 response, not scoring control flow
         t_fin = time.perf_counter()
-        if pending.pool_token is not None:
-            # pooled completion: DevicePool.wait retries the batch on a
-            # healthy replica if this one's result fetch fails
-            out = self._pool.wait(pending.pool_token)
-        else:
-            out = jax.device_get(pending.out)  # blocks until device is done
-        # rtfd-lint: allow[wall-clock] span diagnostics (host_stats), not scoring control flow
-        self.spans.record("device_wait", time.perf_counter() - t_fin)
-        if pending.trace is not None:
-            # result in hand: everything after this mark (response build,
-            # state write-back, the owner's fan-out) is the finalize stage
-            pending.trace.mark("finalize")
+        # result in hand: everything after this span (response build,
+        # state write-back, the owner's fan-out) is the tracer's
+        # ``finalize`` stage
+        with span(scopes.DEVICE_WAIT, trace=pending.trace, then="finalize",
+                  **ids):
+            if token is not None:
+                # pooled completion: DevicePool.wait retries the batch on
+                # a healthy replica if this one's result fetch fails
+                out = self._pool.wait(token)
+            else:
+                out = jax.device_get(pending.out)  # blocks until done
         # processing time = assemble/dispatch + device wait; excludes any
         # pipeline queue wait between dispatch() returning and this call
         elapsed_ms = (pending.dispatch_ms
-                      # rtfd-lint: allow[wall-clock] span diagnostics (host_stats), not scoring control flow
+                      # rtfd-lint: allow[wall-clock] dispatch_ms / processing_time_ms of the §2.7 response, not scoring control flow
                       + (time.perf_counter() - t_fin) * 1000.0)
-        results = self._build_responses(pending.records, out, pending.n,
-                                        elapsed_ms,
-                                        model_valid=pending.model_valid,
-                                        rules_only=pending.rules_only)
-        with (lock if lock is not None else contextlib.nullcontext()):
-            self._write_back(pending.records, results, now)
-            self.stats["scored"] += pending.n
-            self.stats["batches"] += 1
-            self.stats["total_time_s"] += elapsed_ms / 1000.0
+        with span(scopes.FINALIZE_RESPONSES):
+            results = self._build_responses(pending.records, out, pending.n,
+                                            elapsed_ms,
+                                            model_valid=pending.model_valid,
+                                            rules_only=pending.rules_only)
+        with span(scopes.FINALIZE_WRITE_BACK):
+            with (lock if lock is not None else contextlib.nullcontext()):
+                self._write_back(pending.records, results, now)
+                self.stats["scored"] += pending.n
+                self.stats["batches"] += 1
+                self.stats["total_time_s"] += elapsed_ms / 1000.0
         return results
 
     def score_batch(self, records: Sequence[Mapping[str, Any]],

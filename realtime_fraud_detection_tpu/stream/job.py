@@ -19,11 +19,14 @@ once scoring).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import logging
 import time
 from typing import Any, Dict, List, Optional
 
+from realtime_fraud_detection_tpu.obs import scopes
+from realtime_fraud_detection_tpu.obs.profiling import GcSpans, SpanTimer
 from realtime_fraud_detection_tpu.scoring.scorer import FraudScorer
 from realtime_fraud_detection_tpu.serving.validation import sanitize_for_stream
 from realtime_fraud_detection_tpu.state.stores import _event_time_ms
@@ -165,6 +168,9 @@ class _BatchCtx:
     # production, virtual in drills): the tuning plane's service-time
     # observation is completion minus this
     t_dispatch: float = 0.0
+    # the job's batch sequence number: the ``batch=`` every span of this
+    # microbatch carries (obs/profiling.SpanTimer)
+    seq: int = 0
 
 
 class StreamJob:
@@ -261,10 +267,25 @@ class StreamJob:
                 self.tracer = tr if tr.enabled else None
             elif getattr(tr, "enabled", False):
                 self.tracer = Tracer(tr)
+        # host spans: one timer per scoring path, the scorer's (so
+        # ``host_stats()["stages"]`` holds the job's spans too); a stand-in
+        # scorer without one gets the job's own
+        self.spans = getattr(scorer, "spans", None) or SpanTimer()
+        # collections as ``rtfd:host.gc`` annotations while a run loop is
+        # live, where tracing is on; their count and pauses ride the
+        # tracer's snapshot
+        self._host_gc = contextlib.nullcontext()
+        if self.tracer is not None:
+            self._host_gc = self.tracer.host_gc = GcSpans()
         self.counters: Dict[str, int] = {
             "scored": 0, "alerts": 0, "batches": 0, "duplicates_skipped": 0,
             "errors": 0, "shed": 0,
+            # what the text branch was launched with, summed where
+            # ``batches`` is (PendingScore.token_slots): padded slots,
+            # rows x text_len^2, and the real tokens among the slots
+            "token_slots": 0, "token_slots_sq": 0, "real_tokens": 0,
         }
+        self._batch_seq = 0
         # transaction_ids dispatched but not yet written back: the pipelined
         # loop dedupes batch N+1 against these before batch N lands in the
         # txn cache (keeps effectively-once scoring under pipelining)
@@ -332,6 +353,55 @@ class StreamJob:
         """
         if not records:
             return None
+        self._batch_seq += 1
+        with self.spans.span(scopes.JOB_DISPATCH, batch=self._batch_seq):
+            return self._dispatch_batch(records, now, self._batch_seq)
+
+    def _dispatch_batch(self, records: List[Record], now: Optional[float],
+                        seq: int) -> "_BatchCtx":
+        with self.spans.span(scopes.JOB_ADMIT):
+            admitted = self._admit(records, now)
+        (fresh, invalid, cached_dups, shed, trace_ctxs, batch_ids,
+         positions, t_adm) = admitted
+        tracer = self.tracer
+        if not fresh:
+            return _BatchCtx([], set(), None, positions, now, invalid,
+                             cached_dups, shed, seq=seq)
+        trace = None
+        if tracer is not None:
+            trace = tracer.batch(
+                trace_ctxs, batch_size=len(fresh),
+                close_reason=self.assembler.last_close_reason)
+        pending = None
+        try:
+            # the trace kwarg is passed ONLY when tracing is live: drills
+            # and tests drive this job with duck-typed scorer stand-ins
+            # whose dispatch() may not know the parameter, and an
+            # unexpected-kwarg TypeError here would silently take the
+            # whole-batch degradation path
+            kw = {"trace": trace} if trace is not None else {}
+            if self._stage is not None:
+                # background assembly: the handle resolves to a
+                # PendingScore at completion; errors surface there and take
+                # the same whole-batch degradation path. The trace rides
+                # the queue item, so the stage thread's marks land on the
+                # batch they belong to (identity, not timing).
+                pending = self._stage.submit([r.value for r in fresh],
+                                             now=now, **kw)
+            else:
+                pending = self.scorer.dispatch([r.value for r in fresh],
+                                               now=now, **kw)
+        except Exception as e:  # noqa: BLE001 — boundary: keep streaming
+            # whole-batch degradation fallback: score 0.5, REVIEW, keep the
+            # stream alive; counted at completion
+            self._log_batch_error("dispatch", len(fresh), e)
+        self._inflight_ids |= batch_ids
+        return _BatchCtx(fresh, batch_ids, pending, positions, now, invalid,
+                         cached_dups, shed, trace, t_adm, seq)
+
+    def _admit(self, records: List[Record], now: Optional[float]) -> tuple:
+        """Sanitize, dedupe, QoS admission and trace begin for one polled
+        batch (the ``job.admit`` span); offsets are snapshotted here."""
         fresh: List[Record] = []
         invalid: List[tuple] = []
         cached_dups: List[tuple] = []
@@ -442,40 +512,8 @@ class StreamJob:
             else:
                 # rtfd-lint: allow[lock-order] stream job is single-writer: consume, score, QoS share one thread
                 self.qos.apply_degradation(self.scorer)
-        if not fresh:
-            return _BatchCtx([], set(), None, positions, now, invalid,
-                             cached_dups, shed)
-        trace = None
-        if tracer is not None:
-            trace = tracer.batch(
-                trace_ctxs, batch_size=len(fresh),
-                close_reason=self.assembler.last_close_reason)
-        pending = None
-        try:
-            # the trace kwarg is passed ONLY when tracing is live: drills
-            # and tests drive this job with duck-typed scorer stand-ins
-            # whose dispatch() may not know the parameter, and an
-            # unexpected-kwarg TypeError here would silently take the
-            # whole-batch degradation path
-            kw = {"trace": trace} if trace is not None else {}
-            if self._stage is not None:
-                # background assembly: the handle resolves to a
-                # PendingScore at completion; errors surface there and take
-                # the same whole-batch degradation path. The trace rides
-                # the queue item, so the stage thread's marks land on the
-                # batch they belong to (identity, not timing).
-                pending = self._stage.submit([r.value for r in fresh],
-                                             now=now, **kw)
-            else:
-                pending = self.scorer.dispatch([r.value for r in fresh],
-                                               now=now, **kw)
-        except Exception as e:  # noqa: BLE001 — boundary: keep streaming
-            # whole-batch degradation fallback: score 0.5, REVIEW, keep the
-            # stream alive; counted at completion
-            self._log_batch_error("dispatch", len(fresh), e)
-        self._inflight_ids |= batch_ids
-        return _BatchCtx(fresh, batch_ids, pending, positions, now, invalid,
-                         cached_dups, shed, trace, t_adm)
+        return (fresh, invalid, cached_dups, shed, trace_ctxs, batch_ids,
+                positions, t_adm)
 
     def complete_batch(self, ctx: "_BatchCtx",
                        now: Optional[float] = None) -> List[Dict[str, Any]]:
@@ -485,7 +523,11 @@ class StreamJob:
         drill's virtual clock); ``ctx.now`` remains the dispatch-time
         event clock for state TTLs. Default None = wall clock.
         """
-        cfg = self.config
+        with self.spans.span(scopes.JOB_COMPLETE, batch=ctx.seq):
+            return self._complete_batch(ctx, now)
+
+    def _complete_batch(self, ctx: "_BatchCtx",
+                        now: Optional[float]) -> List[Dict[str, Any]]:
         fresh = ctx.fresh
         t_done = now if now is not None else (
             # rtfd-lint: allow[wall-clock] production default time base; drills pass now
@@ -513,6 +555,9 @@ class StreamJob:
                     else None)
                 feats = pending.features
                 scored_ok = True
+                for key in ("token_slots", "token_slots_sq", "real_tokens"):
+                    # 0 from a stand-in scorer's pending without them
+                    self.counters[key] += getattr(pending, key, 0)
             except Exception as e:  # noqa: BLE001 — boundary: keep streaming
                 self._log_batch_error("finalize", len(fresh), e)
                 results = None
@@ -681,6 +726,16 @@ class StreamJob:
                  results: List[Dict[str, Any]], feats, scored_ok: bool,
                  now: Optional[float]) -> List[Dict[str, Any]]:
         """Enrich + produce to output topics + commit (stage-2 tail)."""
+        with self.spans.span(scopes.JOB_FAN_OUT):
+            self._produce(ctx, fresh, results, feats, scored_ok, now)
+        # commit AFTER fan-out + scorer write-back: at-least-once
+        with self.spans.span(scopes.JOB_COMMIT):
+            self.consumer.commit(ctx.positions)
+        return results
+
+    def _produce(self, ctx: "_BatchCtx", fresh: List[Record],
+                 results: List[Dict[str, Any]], feats, scored_ok: bool,
+                 now: Optional[float]) -> None:
         cfg = self.config
         enriched_scores = None
         wants_enriched = cfg.emit_enriched or self.analytics is not None
@@ -758,9 +813,6 @@ class StreamJob:
             self.broker.produce_batch_keyed(cfg.features_topic, out_features)
         self.counters["scored"] += len(fresh)
         self.counters["batches"] += 1
-        # commit AFTER fan-out + scorer write-back: at-least-once
-        self.consumer.commit(ctx.positions)
-        return results
 
     @staticmethod
     def _to_alert(txn: Dict[str, Any], res: Dict[str, Any]) -> Dict[str, Any]:
@@ -796,6 +848,17 @@ class StreamJob:
     def run_until_drained(self, max_batches: int = 10_000,
                           now: Optional[float] = None) -> int:
         """Process until the input topic is fully consumed. Returns #scored."""
+        with self._host_gc:
+            return self._run_until_drained(max_batches, now)
+
+    def _poll(self, **kw) -> List[Record]:
+        """``assembler.next_batch`` as the ``job.poll`` span: the consumer
+        poll and the wait for rows, up to the batch's close."""
+        with self.spans.span(scopes.JOB_POLL):
+            return self.assembler.next_batch(**kw)
+
+    def _run_until_drained(self, max_batches: int,
+                           now: Optional[float]) -> int:
         from collections import deque
 
         start_scored = self.counters["scored"]
@@ -813,7 +876,7 @@ class StreamJob:
                     in_flight.append(self.dispatch_batch(tail, now=now))
                     tail = self.assembler.flush()
                 break
-            batch = self.assembler.next_batch(block=False)
+            batch = self._poll(block=False)
             if not batch:
                 batch = self.assembler.flush()
             if not batch:
@@ -843,6 +906,10 @@ class StreamJob:
 
     def run_for(self, duration_s: float) -> int:
         """Process the stream for a wall-clock window (soak-test entry)."""
+        with self._host_gc:
+            return self._run_for(duration_s)
+
+    def _run_for(self, duration_s: float) -> int:
         from collections import deque
 
         # rtfd-lint: allow[wall-clock] consume-only slice duration is wall-bound by definition
@@ -852,7 +919,7 @@ class StreamJob:
         in_flight: deque = deque()
         # rtfd-lint: allow[wall-clock] consume-only slice duration is wall-bound by definition
         while time.monotonic() < t_end and not self.stop_requested:
-            batch = self.assembler.next_batch(block=True, timeout_s=0.05)
+            batch = self._poll(block=True, timeout_s=0.05)
             if batch:
                 in_flight.append(self.dispatch_batch(batch))
             if in_flight and (len(in_flight) >= depth or not batch):

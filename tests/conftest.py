@@ -26,6 +26,12 @@ if "xla_force_host_platform_device_count" not in _flags:
 # inherit; min-compile-time 0 because the suite is dominated by many
 # sub-second compiles, not a few large ones.
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+# The entry points key the cache on each program's metadata too
+# (utils/compile_cache.py): right where a profile is read by scope name,
+# costly here, where the xdist workers and the drill subprocesses reach the
+# same program from different call stacks and should share one entry. No
+# test reads a cached program's metadata, so keep JAX's default.
+os.environ.setdefault("JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY", "0")
 
 from realtime_fraud_detection_tpu.utils.compile_cache import (  # noqa: E402
     configure_compile_cache,

@@ -83,6 +83,29 @@ def test_cache_dir_from_outside_wins(monkeypatch, tmp_path,
     assert os.environ[CACHE_DIR_ENV] == str(tmp_path)
 
 
+def test_cache_key_covers_metadata_unless_set_from_outside(
+        monkeypatch, _restore_cache_config):
+    """A cached program must carry THIS source's op_names (the device
+    program is read by its named scopes), so the entry points put the
+    metadata in the key; an outside setting wins, as conftest's does."""
+    from realtime_fraud_detection_tpu.utils.compile_cache import (
+        METADATA_IN_KEY_ENV,
+    )
+
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, flag)
+    try:
+        assert os.environ[METADATA_IN_KEY_ENV] == "0"      # conftest.py
+        configure_compile_cache()
+        assert getattr(jax.config, flag) is False
+        monkeypatch.delenv(METADATA_IN_KEY_ENV)
+        configure_compile_cache()
+        assert os.environ[METADATA_IN_KEY_ENV] == "1"
+        assert getattr(jax.config, flag) is True
+    finally:
+        jax.config.update(flag, before)
+
+
 def test_cache_dir_default_is_fixed_inside_the_checkout(
         monkeypatch, _restore_cache_config):
     monkeypatch.delenv(CACHE_DIR_ENV, raising=False)

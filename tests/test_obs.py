@@ -267,6 +267,191 @@ class TestSpanTimer:
             np.percentile([1.0, 2.0, 100.0], 99))
 
 
+    # ---- the one span primitive (ISSUE 23) -------------------------------
+    def test_spans_nest_and_report_parent_and_self_time(self):
+        t = [0.0]
+        timer = SpanTimer(clock=lambda: t[0], annotation=_RecordingAnnotation)
+        for _ in range(3):
+            with timer.span("job.dispatch_batch"):
+                t[0] += 0.001                    # own work
+                with timer.span("assemble"):
+                    t[0] += 0.002
+                    with timer.span("graph"):
+                        t[0] += 0.004
+                    with timer.span("assemble.tokenize"):
+                        t[0] += 0.008
+                with timer.span("pack"):
+                    t[0] += 0.016
+        st = timer.stats()
+        assert {n: s["parent"] for n, s in st.items()} == {
+            "job.dispatch_batch": "", "assemble": "job.dispatch_batch",
+            "graph": "assemble", "assemble.tokenize": "assemble",
+            "pack": "job.dispatch_batch"}
+        assert st["job.dispatch_batch"]["total_s"] == pytest.approx(0.093)
+        assert st["job.dispatch_batch"]["self_s"] == pytest.approx(0.003)
+        assert st["assemble"]["total_s"] == pytest.approx(0.042)
+        assert st["assemble"]["self_s"] == pytest.approx(0.006)
+        assert st["graph"]["self_s"] == st["graph"]["total_s"] \
+            == pytest.approx(0.012)
+        # self times partition the root's total
+        assert sum(s["self_s"] for s in st.values()) == pytest.approx(
+            st["job.dispatch_batch"]["total_s"])
+        assert all(s["count"] == 3 for s in st.values())
+
+    def test_running_totals_are_exact_past_the_sample_cap(self):
+        """count / total_s are running totals; only the percentiles are
+        over the newest ``max_samples`` (total_s used to be their sum)."""
+        t = [0.0]
+        timer = SpanTimer(clock=lambda: t[0], max_samples=100,
+                          annotation=_RecordingAnnotation)
+        for _ in range(10_050):
+            with timer.span("s"):
+                t[0] += 0.001
+        for _ in range(50):
+            with timer.span("s"):
+                t[0] += 0.003
+        st = timer.stats("s")["s"]
+        assert st["count"] == 10_100
+        assert st["total_s"] == pytest.approx(10_050 * 0.001 + 50 * 0.003)
+        assert st["mean_ms"] == pytest.approx(1e3 * st["total_s"] / 10_100)
+        assert st["p50_ms"] == pytest.approx(2.0)    # newest 100: 50 + 50
+        timer.reset()
+        assert timer.stats() == {}
+
+    def test_span_marks_the_trace_once_per_stage_in_order(self):
+        """The tracer's batch-granular marks come from the same call: a
+        span marks its own name where it opens and ``then`` where it
+        closes; a stage a closing span opened is not opened twice."""
+        from realtime_fraud_detection_tpu.obs.tracing import TRACE_STAGES
+
+        clock = [0.0]
+        tracer = _vclock_tracer(clock)
+        timer = SpanTimer(clock=lambda: clock[0],
+                          annotation=_RecordingAnnotation)
+        tb = tracer.batch([tracer.begin("a")], batch_size=1)
+        costs = {"assemble": 3.0, "pack": 0.5, "dispatch": 0.5,
+                 "device_wait": 5.0}
+        for name, then in (("assemble", None), ("pack", None),
+                           ("dispatch", "device_wait")):
+            with timer.span(name, trace=tb, then=then):
+                clock[0] += costs[name] / 1e3
+        clock[0] += 0.004        # pipeline dwell: the tracer's device_wait
+        with timer.span("device_wait", trace=tb, then="finalize"):
+            clock[0] += 0.001
+        with timer.span("finalize.responses"):       # no trace: no mark
+            clock[0] += 0.002
+        marked = [m for m, _ in tb.marks]
+        assert marked == ["assemble", "pack", "dispatch", "device_wait",
+                          "finalize"]
+        assert marked == [s for s in TRACE_STAGES if s in marked]
+        tracer.finish_batch(tb)
+        stages = tracer.traces(terminal="scored")[0].stages
+        assert stages["device_wait"] == pytest.approx(5.0)   # dwell + wait
+        assert stages["finalize"] == pytest.approx(2.0)
+        assert timer.stats("device_wait")["device_wait"]["total_s"] \
+            == pytest.approx(0.001)                  # the span: the wait
+
+    def test_spans_annotate_with_the_batch_id_of_their_root(self):
+        _RecordingAnnotation.seen.clear()
+        timer = SpanTimer(annotation=_RecordingAnnotation)
+        with timer.span("job.complete_batch", batch=41):
+            with timer.span("device_wait", replica=2):
+                pass
+            with timer.span("job.fan_out"):
+                pass
+        assert _RecordingAnnotation.seen == [
+            ("rtfd:job.complete_batch", {"batch": 41}),
+            ("rtfd:device_wait", {"batch": 41, "replica": 2}),
+            ("rtfd:job.fan_out", {"batch": 41})]
+
+    def test_spans_are_profiler_annotations_on_the_cpu_backend(
+            self, tmp_path):
+        """The default annotation is ``jax.profiler.TraceAnnotation``: a
+        short profiler session records each span as ``rtfd:<name>`` with
+        ``batch=`` among its arguments."""
+        import glob
+
+        import jax
+
+        timer = SpanTimer()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with timer.span("job.dispatch_batch", batch=7):
+                with timer.span("assemble"):
+                    jax.numpy.ones(4).block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+        path = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))[0]
+        seen = {}
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("rtfd:"):
+                        seen[ev.name] = dict(ev.stats)
+        assert seen["rtfd:job.dispatch_batch"]["batch"] == 7
+        assert seen["rtfd:assemble"]["batch"] == 7
+
+    def test_threads_keep_their_own_stacks_and_totals_merge(self):
+        import threading
+
+        timer = SpanTimer(annotation=_RecordingAnnotation)
+        barrier = threading.Barrier(8, timeout=30)
+
+        def work():
+            barrier.wait()
+            for _ in range(2_000):
+                with timer.span("outer"):
+                    with timer.span("inner"):
+                        pass
+
+        threads = [threading.Thread(target=work) for _ in range(8)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=60)
+        assert not any(th.is_alive() for th in threads)
+        st = timer.stats()
+        assert st["outer"]["count"] == st["inner"]["count"] == 16_000
+        assert st["inner"]["parent"] == "outer"
+        assert st["outer"]["parent"] == ""
+
+    def test_collections_become_annotations_and_counts(self):
+        import gc
+
+        from realtime_fraud_detection_tpu.obs import GcSpans
+
+        _RecordingAnnotation.seen.clear()
+        watch = GcSpans(annotation=_RecordingAnnotation)
+        before = len(gc.callbacks)
+        with watch:
+            gc.collect(0)
+            gc.collect(2)
+        assert len(gc.callbacks) == before           # the hook is gone
+        gc.collect()
+        assert watch.snapshot()["count"] == 2
+        assert watch.snapshot()["seconds"] >= watch.longest_s > 0.0
+        assert _RecordingAnnotation.seen == [
+            ("rtfd:host.gc", {"generation": 0}),
+            ("rtfd:host.gc", {"generation": 2})]
+
+
+class _RecordingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: same call shape."""
+
+    seen: list = []
+
+    def __init__(self, name, **ids):
+        self.seen.append((name, ids))
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return None
+
+
 class TestTracer:
     def _scored_batch(self, tracer, clock, txn_ids, stage_costs_ms,
                       ingest_lag_s=0.0):

@@ -1,0 +1,163 @@
+"""The device program's named scopes (obs/scopes.py) are metadata only:
+every scope name reaches some instruction's ``op_name``, and the optimised
+program is the same with and without them."""
+
+import contextlib
+import re
+
+import jax
+import numpy as np
+import pytest
+
+from realtime_fraud_detection_tpu.core.packing import pack_tree
+from realtime_fraud_detection_tpu.ensemble.combine import EnsembleParams
+from realtime_fraud_detection_tpu.models.bert import TINY_CONFIG
+from realtime_fraud_detection_tpu.obs import scopes
+from realtime_fraud_detection_tpu.scoring.pipeline import (
+    MODEL_NAMES,
+    ScorerConfig,
+    _score_fused_packed_impl,
+    _PACKED_STATIC,
+    init_scoring_models,
+    make_example_batch,
+)
+from realtime_fraud_detection_tpu.utils.config import Config
+
+
+def _compile_packed():
+    """A fresh jit each time (the module's own would answer the second
+    lowering from its trace cache, scopes and all)."""
+    models = init_scoring_models(jax.random.PRNGKey(0))
+    blobs, spec = pack_tree(
+        make_example_batch(8, ScorerConfig(), rng=np.random.default_rng(7)))
+    fn = jax.jit(lambda *a, **k: _score_fused_packed_impl(*a, **k),
+                 static_argnames=_PACKED_STATIC)
+    lowered = fn.lower(
+        models, blobs["f32"], blobs["i32"], blobs["u8"], spec=spec,
+        params=EnsembleParams.from_config(Config(), list(MODEL_NAMES)),
+        model_valid=jax.numpy.ones((len(MODEL_NAMES),), bool),
+        bert_config=TINY_CONFIG)
+    return lowered, lowered.compile().as_text()
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _compile_cache_off():
+    """The persistent cache keys a program without its metadata, so the
+    second compile would be answered with the first one's text."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def scoped():
+    return _compile_packed()
+
+
+def _expected_paths():
+    text = [f"{scopes.TEXT}/{scopes.EMBED}", f"{scopes.TEXT}/{scopes.HEAD}"]
+    for i in range(TINY_CONFIG.num_layers):
+        text += [f"{scopes.TEXT}/{scopes.layer_scope(i)}/{k}"
+                 for k in scopes.LAYER_SCOPES]
+    return [s for s in scopes.BRANCH_SCOPES if s != scopes.TEXT] + text
+
+
+@pytest.mark.parametrize("path", _expected_paths())
+def test_scope_reaches_the_lowered_module(scoped, path):
+    """Every scope constant names at least one operation of the lowered
+    module (its location metadata is what becomes ``op_name``)."""
+    lowered, _ = scoped
+    asm = lowered.compiler_ir().operation.get_asm(enable_debug_info=True)
+    assert re.search(rf'"jit\([^"]*\)/{re.escape(path)}/', asm), path
+
+
+@pytest.mark.parametrize("path", [
+    scopes.TREES, scopes.LSTM, scopes.GNN, scopes.IFOREST,
+    f"{scopes.TEXT}/{scopes.layer_scope(0)}/{scopes.FFN}",
+    f"{scopes.TEXT}/{scopes.layer_scope(1)}/{scopes.ATTN_CORE}",
+    f"{scopes.TEXT}/{scopes.layer_scope(1)}/{scopes.ATTN_PROJ}",
+])
+def test_scope_survives_optimisation(scoped, path):
+    """The scopes that own real work still name an instruction of the
+    OPTIMISED program (``op_name`` in its text): what a trace reads."""
+    _, text = scoped
+    assert re.search(rf'op_name="jit\([^"]*\)/{re.escape(path)}/', text), path
+
+
+def _program_body(text):
+    """The optimised module's computations without what only describes
+    where a line came from: the stack-frame tables at the top and each
+    instruction's ``metadata={...}``."""
+    start = re.search(r"^(?:ENTRY )?%\S+ \(", text, re.M).start()
+    return re.sub(r", metadata=\{[^}]*\}", "", text[start:])
+
+
+def test_scopes_do_not_change_the_compiled_program(scoped, monkeypatch):
+    _, with_scopes = scoped
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    _, without = _compile_packed()
+    assert f"/{scopes.TEXT}/" not in without        # the patch took
+    assert f"/{scopes.TEXT}/" in with_scopes
+    a, b = _program_body(with_scopes), _program_body(without)
+    for counted in (" = ", " fusion(", " dot(", "\n}\n"):
+        assert a.count(counted) == b.count(counted), counted
+    assert a.count(" fusion(") > 10
+    assert a == b
+
+
+def test_the_benchmark_matches_on_the_same_strings():
+    """``benchmarks/harness/scopes.py`` writes the names again (it has to
+    run against a program without them); they are the program's."""
+    import sys
+    from pathlib import Path
+
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.harness import scopes as bench
+
+    assert bench.BRANCHES == scopes.BRANCH_SCOPES
+    assert bench.TEXT == scopes.TEXT
+    assert bench.TEXT_PARTS == (scopes.EMBED, scopes.HEAD)
+    assert bench.LAYER_PARTS == scopes.LAYER_SCOPES
+    assert bench.LAYER_RE.match(scopes.layer_scope(11))
+    assert bench.PREFIX == scopes.ANNOTATION_PREFIX
+    assert bench.GC_SPAN == scopes.HOST_GC
+    assert bench.scope_path(
+        f"jit(f)/{scopes.TEXT}/{scopes.layer_scope(2)}/{scopes.FFN}/dot"
+    ) == f"{scopes.TEXT}/{scopes.layer_scope(2)}/{scopes.FFN}"
+
+
+@pytest.mark.parametrize("metadata_in_key", [False, True])
+def test_a_cached_program_carries_the_scopes_of_its_key(
+        tmp_path, monkeypatch, metadata_in_key):
+    """Why ``configure_compile_cache`` puts the metadata in the cache key:
+    with JAX's default an unscoped program cached first answers the scoped
+    one, and a trace of it names no scope (seen on the v5e, PR 23)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    before = (jax.config.jax_compilation_cache_dir, getattr(jax.config, flag))
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update(flag, metadata_in_key)
+        jax.config.update("jax_enable_compilation_cache", True)
+        cc.reset_cache()
+        real = jax.named_scope
+        monkeypatch.setattr(jax, "named_scope",
+                            lambda name: contextlib.nullcontext())
+        _, unscoped = _compile_packed()
+        monkeypatch.setattr(jax, "named_scope", real)
+        _, scoped = _compile_packed()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", False)
+        jax.config.update("jax_compilation_cache_dir", before[0])
+        jax.config.update(flag, before[1])
+        cc.reset_cache()
+    assert f"/{scopes.TEXT}/" not in unscoped
+    assert (f"/{scopes.TEXT}/" in scoped) is metadata_in_key

@@ -530,3 +530,81 @@ def test_whole_batch_degradation_logs_its_cause(caplog):
                for r in records)
     assert records[0].exc_info is not None
     assert all(r.exc_info is None for r in records[1:])
+
+
+# ---- the program's own spans and counters (ISSUE 23) ----------------------
+
+@pytest.fixture(scope="module")
+def spanned_run():
+    """Five full batches of 8 through a traced job; the hand counts the
+    span and counter tests compare with."""
+    from realtime_fraud_detection_tpu.utils.config import TracingSettings
+
+    gen = TransactionGenerator(num_users=30, num_merchants=10, seed=41)
+    broker = InMemoryBroker()
+    scorer = FraudScorer(scorer_config=ScorerConfig(text_len=32))
+    scorer.seed_profiles(gen.users.profiles(), gen.merchants.profiles())
+    job = StreamJob(broker, scorer, JobConfig(
+        max_batch=8, max_delay_ms=1.0, tracing=TracingSettings(enabled=True)))
+    records = gen.generate_batch(40)
+    for i, r in enumerate(records):
+        r["description"] = " ".join(["invoice"] * (1 + i % 7))
+    broker.produce_batch(T.TRANSACTIONS, records,
+                         key_fn=lambda r: str(r["user_id"]))
+    # what the tokenizer makes of the same records, through a scorer of
+    # its own (assembling touches history and graph state)
+    ref = FraudScorer(scorer_config=ScorerConfig(text_len=32))
+    ref.seed_profiles(gen.users.profiles(), gen.merchants.profiles())
+    masks = np.asarray(ref.assemble(records, now=1000.0).token_mask)
+    scored = job.run_until_drained(now=1000.0)
+    return job, scorer, records, masks, scored
+
+
+def test_every_span_of_a_microbatch_once_per_batch(spanned_run):
+    from realtime_fraud_detection_tpu.obs import scopes
+
+    job, scorer, _, _, scored = spanned_run
+    assert scored == 40 and job.counters["batches"] == 5
+    stages = scorer.host_stats()["stages"]
+    assert set(stages) == {name for name, _ in scopes.BATCH_SPANS}
+    for name, parent in scopes.BATCH_SPANS:
+        if name == scopes.JOB_POLL:
+            # the loop also polls when nothing is there (drained input)
+            assert stages[name]["count"] >= 5
+        else:
+            assert stages[name]["count"] == 5, name
+        assert stages[name]["parent"] == parent, name
+        assert 0.0 <= stages[name]["self_s"] <= stages[name]["total_s"]
+    # a parent's self time is its total minus its children's
+    for parent in (scopes.ASSEMBLE, scopes.JOB_DISPATCH, scopes.JOB_COMPLETE):
+        children = sum(stages[n]["total_s"] for n, p in scopes.BATCH_SPANS
+                       if p == parent)
+        assert stages[parent]["self_s"] == pytest.approx(
+            stages[parent]["total_s"] - children, abs=1e-9)
+    # the five names the benchmark has read since PR 22
+    assert {"assemble", "graph", "pack", "dispatch",
+            "device_wait"} <= set(stages)
+
+
+def test_token_counters_equal_a_hand_count(spanned_run):
+    job, _, records, masks, _ = spanned_run
+    text_len, bucket = 32, 8
+    assert masks.shape == (len(records), text_len)
+    assert len({int(n) for n in masks.sum(axis=1)}) > 3   # lengths vary
+    assert job.counters["token_slots"] == 5 * bucket * text_len
+    assert job.counters["token_slots_sq"] == 5 * bucket * text_len ** 2
+    real = int(masks.sum())
+    assert 0 < real < job.counters["token_slots"]
+    assert job.counters["real_tokens"] == real
+
+
+def test_traced_job_times_collections_into_the_tracer_snapshot(spanned_run):
+    import gc
+
+    job = spanned_run[0]
+    before = len(gc.callbacks)
+    job.run_for(0.05)                      # hook live only inside a loop
+    assert len(gc.callbacks) == before
+    snap = job.tracer.snapshot()["host_gc"]
+    assert set(snap) == {"count", "seconds", "longest_ms"}
+    assert snap["count"] >= 0 and snap["seconds"] >= 0.0
