@@ -487,7 +487,10 @@ def run_bench() -> int:
 
     # ---------------------------------------------------- pallas vs XLA (BERT)
     # The repo's custom kernel (ops/attention.py) measured head-to-head on
-    # this chip; the winner runs in the headline ensemble program.
+    # this chip at the headline's text length; the winner runs in the
+    # headline ensemble program. (At a length flash_supported declines both
+    # sides are the reference. Scorers built below choose for themselves:
+    # FraudScorer.effective_use_pallas.)
     _log(f'batches staged on device; null round trip {rtt}')
     tokm = dev_batches[256].token_mask
     bert_times = {}
@@ -647,14 +650,14 @@ def run_bench() -> int:
     # is a 1-replica measurement; the virtual-device CPU bar lives in
     # `rtfd pool-drill`.
     run_stage("pool_scaling", 60, _pool_scaling_stage, models, sc,
-              bert_config, use_pallas, snapshot)
+              bert_config, snapshot)
     # mesh_scaling — GSPMD data x model serving (scoring/mesh_executor.py):
     # replicated vs data-sharded vs data x model txn/s + per-chip param
     # bytes from the committed shardings. Opt-in via --mesh so the chip
     # budget stays the operator's choice.
     if os.environ.get("RTFD_BENCH_MESH") == "1":
         run_stage("mesh_scaling", 60, _mesh_scaling_stage, models, sc,
-                  bert_config, use_pallas, snapshot)
+                  bert_config, snapshot)
     # host_assembly — columnar vs record-at-a-time assemble throughput +
     # cache hit rates + the assembler-stage overlap soak.
     run_stage("host_assembly", 45, _host_assembly_stage, remaining, snapshot)
@@ -889,7 +892,7 @@ def run_bench() -> int:
     # this is a measured number on a stream with a known injected fraud mix.
     if remaining() > 150.0:
         run_stage("e2e_stream", 150.0, _e2e_soak, models, sc, bert_config,
-                  use_pallas, remaining, snapshot)
+                  remaining, snapshot)
     else:
         result["e2e_stream"] = {
             "skipped": f"budget ({remaining():.0f}s left < 150s soak "
@@ -908,7 +911,7 @@ def run_bench() -> int:
 
 
 def _pool_scaling_stage(result: dict, models, sc, bert_config,
-                        use_pallas: bool, snapshot) -> None:
+                        snapshot) -> None:
     """Replicated-dispatch scaling across all addressable devices.
 
     Measures aggregate pooled txn/s (round-robin, in-flight depth 2 per
@@ -969,7 +972,6 @@ def _pool_scaling_stage(result: dict, models, sc, bert_config,
     else:
         scorer = FraudScorer(models=models, scorer_config=sc,
                              bert_config=bert_config)
-    scorer.sc.use_pallas = use_pallas
     f32 = blobs["f32"]
 
     def blob_variant(i: int) -> dict:
@@ -1049,7 +1051,7 @@ def _pool_scaling_stage(result: dict, models, sc, bert_config,
 
 
 def _mesh_scaling_stage(result: dict, models, sc, bert_config,
-                        use_pallas: bool, snapshot) -> None:
+                        snapshot) -> None:
     """GSPMD mesh-sharded serving throughput (scoring/mesh_executor.py).
 
     Three placements over the same packed microbatch stream (slots drain
@@ -1097,7 +1099,6 @@ def _mesh_scaling_stage(result: dict, models, sc, bert_config,
     else:
         scorer = FraudScorer(models=models, scorer_config=sc,
                              bert_config=bert_config)
-    scorer.sc.use_pallas = use_pallas
     f32 = blobs["f32"]
 
     def blob_variant(i: int) -> dict:
@@ -1892,7 +1893,7 @@ def _kernel_fusion_stage(result: dict, models, sc, bert_config,
     snapshot("kernel_fusion")
 
 
-def _e2e_soak(result: dict, models, sc, bert_config, use_pallas: bool,
+def _e2e_soak(result: dict, models, sc, bert_config,
               remaining, snapshot) -> None:
     """The whole-framework StreamJob soak + measured detection quality."""
     import numpy as np
@@ -1920,7 +1921,6 @@ def _e2e_soak(result: dict, models, sc, bert_config, use_pallas: bool,
     broker = InMemoryBroker()
     scorer = FraudScorer(
         models=models, scorer_config=sc, bert_config=bert_config)
-    scorer.sc.use_pallas = use_pallas
     scorer.seed_profiles(gen.users.profiles(), gen.merchants.profiles())
 
     # Train on STREAMED features: run the training transactions through

@@ -307,6 +307,7 @@ def expected_site_counts(scorer, sizes: Sequence[int]) -> Dict[str, Dict]:
     scorer's dispatch/fallback counters."""
     from realtime_fraud_detection_tpu.ops import (
         epilogue_supported,
+        flash_supported,
         matmul_supported,
         rows_supported,
     )
@@ -317,18 +318,19 @@ def expected_site_counts(scorer, sizes: Sequence[int]) -> Dict[str, Dict]:
     disp = {"dequant_matmul": 0, "epilogue": 0, "attention": 0,
             "megakernel": 0}
     fall = dict(disp)
+    fused = flash_supported(s, cfg.head_dim, cfg.num_heads)
     for b in sizes:
         m = b * s
-        for site in ("dequant_matmul", "epilogue", "attention"):
+        for site in ("dequant_matmul", "epilogue"):
             disp[site] += 1
+        # the attention site counts a launch once: fused core or reference
+        (disp if fused else fall)["attention"] += 1
         if not (matmul_supported(m, h, h) and matmul_supported(m, h, ffn)
                 and matmul_supported(m, ffn, h) and rows_supported(m, h)
                 and rows_supported(s, h)):
             fall["dequant_matmul"] += 1
         if not epilogue_supported(b, NUM_MODELS):
             fall["epilogue"] += 1
-        if s % min(128, s):
-            fall["attention"] += 1
     return {"dispatch": disp, "fallback": fall}
 
 
@@ -338,6 +340,7 @@ def expected_custom_calls(scorer, b: int) -> int:
     the two embedding-row widens, the epilogue."""
     from realtime_fraud_detection_tpu.ops import (
         epilogue_supported,
+        flash_supported,
         matmul_supported,
         rows_supported,
     )
@@ -346,7 +349,8 @@ def expected_custom_calls(scorer, b: int) -> int:
     cfg, s = scorer.bert_config, scorer.sc.text_len
     h, ffn, m = cfg.hidden_size, cfg.intermediate_size, b * s
     per_layer = (4 * matmul_supported(m, h, h) + matmul_supported(m, h, ffn)
-                 + matmul_supported(m, ffn, h) + (s % min(128, s) == 0))
+                 + matmul_supported(m, ffn, h)
+                 + flash_supported(s, cfg.head_dim, cfg.num_heads))
     return (cfg.num_layers * per_layer + rows_supported(m, h)
             + rows_supported(s, h) + epilogue_supported(b, NUM_MODELS))
 

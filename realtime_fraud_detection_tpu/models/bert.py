@@ -27,6 +27,9 @@ from realtime_fraud_detection_tpu.obs import scopes
 from realtime_fraud_detection_tpu.ops.attention import (
     attention_reference,
     flash_attention,
+    flash_supported,
+    merge_heads,
+    split_heads,
 )
 from realtime_fraud_detection_tpu.ops.dequant_matmul import (
     dequant_matmul,
@@ -220,29 +223,39 @@ def bert_layer(
 ) -> jax.Array:
     """One post-LN transformer block — the unit the pipeline-parallel
     schedule (parallel/pipeline.bert_pipeline_encode) spans over stages."""
-    b, s = x.shape[:2]
+    s = x.shape[1]
     dk = dict(dequant_kernel=dequant_kernel, kernel_interpret=kernel_interpret)
 
-    def split(t):
-        return t.reshape(b, s, config.num_heads,
-                         config.head_dim).transpose(0, 2, 1, 3)
+    # the fused core takes the projections' own [B, S, H*D] layout (the head
+    # split happens in VMEM) at the shapes flash_supported admits; every
+    # other shape, and every attention_fn, runs on the split layout
+    fused = (use_pallas and attention_fn is None
+             and flash_supported(s, config.head_dim, config.num_heads))
 
     # the four kernel scopes of a layer (obs/scopes.py): metadata only
     with jax.named_scope(scopes.ATTN_PROJ):
         q = _dense(x, layer["q"], compute_dtype, **dk)
         k = _dense(x, layer["k"], compute_dtype, **dk)
         v = _dense(x, layer["v"], compute_dtype, **dk)
-        qh, kh, vh = split(q), split(k), split(v)
+        if fused:
+            # the MXU rounds its operands to compute_dtype either way;
+            # rounding here halves what crosses HBM
+            q, k, v = (t.astype(compute_dtype) for t in (q, k, v))
+        else:
+            qh, kh, vh = (split_heads(t, config.num_heads)
+                          for t in (q, k, v))
     with jax.named_scope(scopes.ATTN_CORE):
-        if attention_fn is not None:
-            ctx = attention_fn(qh, kh, vh, attention_mask)
-        elif use_pallas:
-            ctx = flash_attention(qh, kh, vh, attention_mask,
+        if fused:
+            ctx = flash_attention(q, k, v, attention_mask,
+                                  num_heads=config.num_heads,
                                   interpret=kernel_interpret)
+        elif attention_fn is not None:
+            ctx = attention_fn(qh, kh, vh, attention_mask)
         else:
             ctx = attention_reference(qh, kh, vh, attention_mask)
     with jax.named_scope(scopes.ATTN_PROJ):
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, config.hidden_size)
+        if not fused:
+            ctx = merge_heads(ctx)
         attn_out = _dense(ctx, layer["o"], compute_dtype, **dk)
     with jax.named_scope(scopes.LN):
         x = _layer_norm(x + attn_out, layer["attn_ln"],

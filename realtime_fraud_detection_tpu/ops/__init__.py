@@ -1,6 +1,9 @@
 from realtime_fraud_detection_tpu.ops.attention import (  # noqa: F401
-    flash_attention,
     attention_reference,
+    flash_attention,
+    flash_supported,
+    merge_heads,
+    split_heads,
 )
 from realtime_fraud_detection_tpu.ops.dequant_matmul import (  # noqa: F401
     dequant_matmul,

@@ -128,6 +128,9 @@ class DevicePool:
         if not devs:
             raise ValueError("device pool needs at least one device")
         self.inflight_depth = max(1, int(inflight_depth))
+        # each replica's program is one device's (what the scorer's
+        # attention selector asks: FraudScorer.effective_use_pallas)
+        self.program_devices = 1
         # donation needs accelerator buffer aliasing; the CPU backend only
         # warns and ignores it, so default it off there to keep logs clean
         self.donate = (devs[0].platform != "cpu" if donate is None
@@ -209,7 +212,8 @@ class DevicePool:
                   spec=spec, params=params, model_valid=mv_dev,
                   blob_bf16=staged.get("bf16"),
                   bert_config=self.scorer.bert_config,
-                  use_pallas=self.scorer.effective_use_pallas(),
+                  use_pallas=self.scorer.effective_use_pallas(
+                      self.program_devices),
                   # quant + kernel planes: same static kernel selection on
                   # every replica (the scorer's params are already
                   # quantized, so replication/hot-swap carries the int8

@@ -26,7 +26,8 @@ verdict as the final stdout line):
    reference, on the drill's REAL served params: fused dequant-matmul
    (f32 compute near-exact, bf16 compute within rounding scale), per-row
    embedding dequant exact, fused epilogue exact decisions across all
-   three strategies, flash attention within f32 softmax slack.
+   three strategies, the fused attention core within the bf16 rounding of
+   its softmax weights.
 5. **Replay.** A second full run must be bit-identical (sha256 over every
    gate-read number).
 
@@ -71,7 +72,12 @@ class KernelDrillConfig:
     matmul_f32_tol: float = 1e-5    # f32 compute: summation-order slack only
     rows_tol: float = 0.0           # per-row dequant: one widen+mul, exact
     epilogue_prob_tol: float = 1e-6
-    attention_tol: float = 5e-5     # online-vs-full softmax f32 slack
+    attention_tol: float = 1e-2     # bf16 rounding of the softmax weights
+    #                                 (operands bf16-representable, N(0, 1))
+    # the shortest text window the fused attention core takes
+    # (ops.attention.flash_supported): at ScorerConfig's 64 the attention
+    # site would be a counted fallback and the drill would gate nothing
+    text_len: int = 128
     replay: bool = True
     # megakernel mode: the kernel side serves ops/megakernel.py's ONE
     # persistent program (KernelSettings.mega()) instead of the per-site
@@ -118,7 +124,8 @@ def _make_side(cfg: KernelDrillConfig, kernels_on: bool):
                                num_merchants=cfg.num_merchants,
                                seed=cfg.seed)
     scorer = FraudScorer(Config(quant=QuantSettings.full(), kernels=kernels),
-                         scorer_config=ScorerConfig(), seed=cfg.seed)
+                         scorer_config=ScorerConfig(text_len=cfg.text_len),
+                         seed=cfg.seed)
     scorer.seed_profiles(gen.users.profiles(), gen.merchants.profiles())
     return gen, scorer
 
@@ -238,6 +245,8 @@ def _kernel_oracle(cfg: KernelDrillConfig, scorer) -> Dict[str, Any]:
         epilogue_reference,
         flash_attention,
         fused_epilogue,
+        merge_heads,
+        split_heads,
     )
 
     rng = np.random.default_rng(cfg.seed + 23)
@@ -295,16 +304,19 @@ def _kernel_oracle(cfg: KernelDrillConfig, scorer) -> Dict[str, Any]:
                        "ok": bool(ep_exact
                                   and ep_delta <= cfg.epilogue_prob_tol)}
 
-    # --- flash attention vs reference (f32 operands, drill text shape)
+    # --- fused attention core vs reference (drill text shape; operands
+    # bf16-representable, so both sides multiply the same numbers and what
+    # is left is where each rounds the softmax weights)
     b, heads, seq = 4, int(scorer.bert_config.num_heads), int(
         scorer.sc.text_len)
     d = int(scorer.bert_config.head_dim)
-    qkv = [jnp.asarray(rng.standard_normal((b, heads, seq, d)), jnp.float32)
-           for _ in range(3)]
+    qkv = [jnp.asarray(rng.standard_normal((b, seq, heads * d)),
+                       jnp.bfloat16).astype(jnp.float32) for _ in range(3)]
     mask = jnp.asarray(rng.uniform(0, 1, (b, seq)) > 0.1)
-    att_delta = float(jnp.abs(
-        flash_attention(*qkv, mask, interpret=True)
-        - attention_reference(*qkv, mask)).max())
+    ref = merge_heads(attention_reference(
+        *(split_heads(t, heads) for t in qkv), mask))
+    att_delta = float(jnp.abs(flash_attention(
+        *qkv, mask, num_heads=heads, interpret=True) - ref).max())
     out["attention"] = {"max_delta": att_delta,
                         "ok": att_delta <= cfg.attention_tol}
     return out

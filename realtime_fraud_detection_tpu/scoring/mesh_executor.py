@@ -294,6 +294,9 @@ class MeshExecutor:
         # the scorer pads every microbatch to a multiple of this so the
         # data-axis split is always even (FraudScorer.dispatch_assembled)
         self.batch_multiple = self.data_axis
+        # devices one replica's program spans (what the scorer's attention
+        # selector asks: FraudScorer.effective_use_pallas)
+        self.program_devices = self.data_axis * model_axis
         self.inflight_depth = max(1, int(inflight_depth))
         # donation needs accelerator buffer aliasing; the CPU backend only
         # warns and ignores it (same default rule as DevicePool)
@@ -421,7 +424,8 @@ class MeshExecutor:
                      spec=spec, params=params, model_valid=mv_dev,
                      blob_bf16=staged.get("bf16"),
                      bert_config=self.scorer.bert_config,
-                     use_pallas=self.scorer.effective_use_pallas(),
+                     use_pallas=self.scorer.effective_use_pallas(
+                         self.program_devices),
                      gather_fields=self._gather_fields,
                      mesh=rep.mesh,
                      # quant + kernel planes: same static kernel selection
@@ -548,7 +552,8 @@ class MeshExecutor:
             spec=spec, params=params, model_valid=rep.mv_dev(mv),
             blob_bf16=staged.get("bf16"),
             bert_config=self.scorer.bert_config,
-            use_pallas=self.scorer.effective_use_pallas(),
+            use_pallas=self.scorer.effective_use_pallas(
+                self.program_devices),
             gather_fields=self._gather_fields, mesh=rep.mesh,
             **self.scorer.quant_static(),
             **self.scorer.kernel_static(mv)).as_text()

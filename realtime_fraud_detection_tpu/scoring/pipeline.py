@@ -400,7 +400,6 @@ class ScorerConfig:
     # texts repeat heavily, so the default keeps every live merchant string
     # resident; shrink for memory-tight hosts
     token_cache_entries: int = 65_536
-    use_pallas: bool = False   # Pallas flash attention (TPU only)
     # start the result's device->host copy at dispatch time so the transfer
     # overlaps the next batch's host work (scorer.dispatch).
     async_d2h: bool = True

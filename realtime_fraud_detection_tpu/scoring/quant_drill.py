@@ -172,11 +172,12 @@ def _noise_floor(cfg: QuantDrillConfig, scorer,
 
     from realtime_fraud_detection_tpu.models.bert import bert_predict
 
+    attn = dict(use_pallas=scorer.effective_use_pallas(),
+                kernel_interpret=scorer.kernel_static()["kernel_interpret"])
     bf16 = jax.jit(lambda p, i, m: bert_predict(
-        p, i, m, scorer.bert_config, use_pallas=scorer.sc.use_pallas))
+        p, i, m, scorer.bert_config, **attn))
     f32 = jax.jit(lambda p, i, m: bert_predict(
-        p, i, m, scorer.bert_config, use_pallas=scorer.sc.use_pallas,
-        compute_dtype=jnp.float32))
+        p, i, m, scorer.bert_config, compute_dtype=jnp.float32, **attn))
     branch_delta = 0.0
     for ids, mask in tokens:
         a = bf16(scorer.models.bert, ids, mask)

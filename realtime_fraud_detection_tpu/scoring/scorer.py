@@ -380,6 +380,7 @@ class FraudScorer:
             raise ValueError(
                 "KernelSettings.megakernel='pallas' does not compile for "
                 f"the TPU: {MEGA_TPU_REFUSAL}")
+        self._platform = platform
         self._kernel_interpret = platform == "cpu"
         self._kernel_counts: Dict[str, Dict[str, int]] = {
             "dispatch": {s: 0 for s in VALID_KERNEL_SITES},
@@ -799,13 +800,31 @@ class FraudScorer:
             self._static_cache[key] = cached
         return cached
 
-    def effective_use_pallas(self) -> bool:
-        """Attention implementation selection: with the kernel plane on,
-        KernelSettings.attention decides (the tune_tpu.py-driven flip);
-        otherwise the legacy ScorerConfig.use_pallas flag stands."""
+    def effective_use_pallas(self, devices: Optional[int] = None) -> bool:
+        """Whether the text branch's attention core is ASKED to be the
+        fused Pallas kernel (``bert_layer``'s traced guard still sends a
+        shape ``flash_supported`` declines to the reference, and the
+        engagement counters say so). With the kernel plane on,
+        ``KernelSettings.attention`` decides — how a drill or an A/B forces
+        either side. With it off, nothing a user sets does: the kernel runs
+        where the devices are TPUs, the shape is one it takes
+        (``ops.attention.flash_supported``) and the program is one
+        device's — XLA cannot partition a Mosaic call, so a program whose
+        batch is sharded over a mesh keeps the reference. ``devices`` is how
+        many the program spans: the scorer's own mesh by default, one for
+        a ``DevicePool`` replica, a ``MeshExecutor`` replica's sub-mesh."""
         if self.kernels.enabled:
             return self.kernels.attention == "flash"
-        return bool(self.sc.use_pallas)
+        if devices is None:
+            devices = self.mesh.devices.size
+        return (self._platform == "tpu" and devices == 1
+                and self._flash_shape_ok())
+
+    def _flash_shape_ok(self) -> bool:
+        from realtime_fraud_detection_tpu.ops import flash_supported
+
+        return flash_supported(self.sc.text_len, self.bert_config.head_dim,
+                               self.bert_config.num_heads)
 
     def _record_kernel_dispatch(self, size: int) -> None:
         """Host-side mirror of the per-site kernel engagement for one
@@ -814,23 +833,13 @@ class FraudScorer:
         guard the TRACED code consults (the shared supports() predicates)
         routes it back to the XLA path — so ``kernel_fallback_total``
         reports exactly what the compiled program did, without a device
-        readback."""
-        if not self.kernels.enabled:
-            return
-        from realtime_fraud_detection_tpu.models.quant import (
-            is_quantized_bert,
-        )
-        from realtime_fraud_detection_tpu.ops import (
-            epilogue_supported,
-            matmul_supported,
-            mega_launch_accounting,
-            rows_supported,
-        )
-
-        modes = self.kernels.site_modes()
+        readback. The attention site counts EVERY launch, plane on or off:
+        dispatched where the program holds the fused core, a fallback
+        where the selector or the guard sent it to the reference."""
         disp, fall = (self._kernel_counts["dispatch"],
                       self._kernel_counts["fallback"])
-        if modes.get("megakernel") == "pallas":
+        enabled = self.kernels.enabled
+        if enabled and self.kernels.megakernel == "pallas":
             # the persistent whole-batch program (ops/megakernel.py). When
             # its shared shape plan admits the dispatch, ONE program runs
             # and the per-site kernels below never launch — so their
@@ -843,6 +852,25 @@ class FraudScorer:
                 self._last_launches_per_batch = 1
                 return
             fall["megakernel"] += 1
+        asked = self.effective_use_pallas(
+            getattr(self._pool, "program_devices", None))
+        if asked and self._flash_shape_ok():
+            disp["attention"] += 1
+        else:
+            fall["attention"] += 1
+        if not enabled:
+            return
+        from realtime_fraud_detection_tpu.models.quant import (
+            is_quantized_bert,
+        )
+        from realtime_fraud_detection_tpu.ops import (
+            epilogue_supported,
+            matmul_supported,
+            mega_launch_accounting,
+            rows_supported,
+        )
+
+        modes = self.kernels.site_modes()
         self._last_launches_per_batch = mega_launch_accounting(
             size, NUM_MODELS,
             mega_valid=tuple(bool(v) for v in self.effective_model_valid()),
@@ -866,10 +894,6 @@ class FraudScorer:
             disp["epilogue"] += 1
             if not epilogue_supported(size, NUM_MODELS):
                 fall["epilogue"] += 1
-        if modes["attention"] == "flash":
-            disp["attention"] += 1
-            if s % min(128, s):
-                fall["attention"] += 1
 
     def _mega_plan(self, size: int) -> Dict[str, Any]:
         """Host mirror of the trace-time megakernel shape plan for a

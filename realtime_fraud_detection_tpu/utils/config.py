@@ -738,9 +738,12 @@ class KernelSettings:
       matrix grows the per-model contribution + rules-only ladder columns
       so ``FraudScorer.finalize`` does pure column reads instead of
       per-record host blend math.
-    - ``attention``: flash (blockwise Pallas) vs reference attention for
-      the text encoder — the default flip is DRIVEN by the tune_tpu.py
-      sweep, never hardcoded.
+    - ``attention``: the fused Pallas core (ops/attention.py) vs the XLA
+      reference for the text encoder. Only consulted while the plane is
+      ``enabled`` — it is how a drill or an A/B forces either side. With
+      the plane off nothing here decides: the scorer picks the fused core
+      on a TPU at the shapes ``flash_supported`` admits
+      (``FraudScorer.effective_use_pallas``).
 
     Off by default: the plane is opt-in (config/JSON overlay, or the
     bench/tune/soak ``--kernels`` switches) until a chip run proves the

@@ -22,6 +22,7 @@ from realtime_fraud_detection_tpu.ops.megakernel import MEGA_TPU_REFUSAL
 
 FULL = BertConfig()           # DistilBERT-base widths: 6 x 768, FFN 3072
 TEXT_LEN = 64
+DEPLOYED_TEXT_LEN = 512       # benchmarks/configs/distilbert-s512.json
 BUCKET = 256
 CUSTOM_CALL = 'custom_call_target="tpu_custom_call"'
 
@@ -96,15 +97,24 @@ def test_dequant_rows_declines_the_word_gather_above_bucket_32():
     assert not rows_supported(BUCKET * TEXT_LEN, h)
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_compiles_at_bucket_256(one_chip, dtype):
-    from realtime_fraud_detection_tpu.ops import flash_attention
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("bucket,text_len", [
+    (1, DEPLOYED_TEXT_LEN), (8, DEPLOYED_TEXT_LEN),
+    (BUCKET, DEPLOYED_TEXT_LEN),    # the bucket programs the cells warm
+    (BUCKET, 128),                  # the shortest window the core takes
+])
+def test_flash_attention_compiles(one_chip, bucket, text_len, dtype):
+    from realtime_fraud_detection_tpu.ops import (
+        flash_attention,
+        flash_supported,
+    )
 
-    qkv = _sds((BUCKET, FULL.num_heads, TEXT_LEN, FULL.head_dim), dtype,
-               one_chip)
+    assert flash_supported(text_len, FULL.head_dim, FULL.num_heads)
+    qkv = _sds((bucket, text_len, FULL.hidden_size), dtype, one_chip)
     text = flash_attention.lower(
-        qkv, qkv, qkv, _sds((BUCKET, TEXT_LEN), jnp.bool_, one_chip),
-    ).compile().as_text()
+        qkv, qkv, qkv, _sds((bucket, text_len), jnp.bool_, one_chip),
+        num_heads=FULL.num_heads).compile().as_text()
     assert CUSTOM_CALL in text
 
 
@@ -217,7 +227,39 @@ def test_whole_program_with_kernels_compiles_at_bucket_256(one_chip):
         blob_bf16=_shapes_of(blobs["bf16"], one_chip), bert_config=FULL,
         use_pallas=True, tree_kernel="gemm", iforest_kernel="gemm",
         dequant_kernel="pallas", epilogue_kernel="pallas").compile()
-    # six dense sites + attention per layer, the position-row widen, the
-    # epilogue; the word-row widen is declined at this bucket (see above)
-    assert compiled.as_text().count(CUSTOM_CALL) == FULL.num_layers * 7 + 2
+    # six dense sites per layer (at 64 tokens the attention site is the
+    # reference: flash_supported), the position-row widen, the epilogue;
+    # the word-row widen is declined at this bucket (see above)
+    assert compiled.as_text().count(CUSTOM_CALL) == FULL.num_layers * 6 + 2
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+def test_deployed_program_holds_the_fused_core_at_bucket_256(one_chip):
+    """What the benchmark's cells launch — f32 weights, kernel plane off,
+    512 tokens, the selector's choice on a TPU: one Mosaic call a layer,
+    and the f32 score tensor (4.23 GB of temporaries with the reference
+    core) no longer reserved."""
+    from realtime_fraud_detection_tpu.core.packing import pack_tree
+    from realtime_fraud_detection_tpu.ensemble.combine import EnsembleParams
+    from realtime_fraud_detection_tpu.scoring.pipeline import (
+        MODEL_NAMES,
+        ScorerConfig,
+        init_scoring_models,
+        make_example_batch,
+        score_fused_packed,
+    )
+    from realtime_fraud_detection_tpu.utils.config import Config
+
+    sc = ScorerConfig(text_len=DEPLOYED_TEXT_LEN)
+    models = init_scoring_models(jax.random.PRNGKey(0), bert_config=FULL)
+    blobs, spec = pack_tree(make_example_batch(BUCKET, sc))
+    compiled = score_fused_packed.lower(
+        _shapes_of(models, one_chip),
+        *(_shapes_of(blobs[k], one_chip) for k in ("f32", "i32", "u8")),
+        spec=spec,
+        params=EnsembleParams.from_config(Config(), list(MODEL_NAMES)),
+        model_valid=_sds((len(MODEL_NAMES),), jnp.bool_, one_chip),
+        blob_bf16=_shapes_of(blobs["bf16"], one_chip), bert_config=FULL,
+        use_pallas=True).compile()
+    assert compiled.as_text().count(CUSTOM_CALL) == FULL.num_layers
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30

@@ -34,9 +34,12 @@ from realtime_fraud_detection_tpu.ops import (
     epilogue_reference,
     epilogue_supported,
     flash_attention,
+    flash_supported,
     fused_epilogue,
     matmul_supported,
+    merge_heads,
     rows_supported,
+    split_heads,
 )
 from realtime_fraud_detection_tpu.qos.ladder import LADDER_LEVELS
 from realtime_fraud_detection_tpu.scoring import (
@@ -62,12 +65,18 @@ def _kernel_config(kernels=True, quant=True) -> Config:
         kernels=KernelSettings.full() if kernels else KernelSettings())
 
 
+# the shortest text window the fused attention core takes: the plane-on
+# scorers below run it (interpreted) instead of counting a fallback
+TEXT_LEN = 128
+
+
 def _scorer(kernels=True, quant=True, seed=0, gen_seed=7, one_device=False):
     gen = TransactionGenerator(num_users=150, num_merchants=40,
                                seed=gen_seed)
     mesh = build_mesh(devices=jax.devices()[:1]) if one_device else None
     s = FraudScorer(_kernel_config(kernels, quant),
-                    scorer_config=ScorerConfig(), mesh=mesh, seed=seed)
+                    scorer_config=ScorerConfig(text_len=TEXT_LEN), mesh=mesh,
+                    seed=seed)
     s.seed_profiles(gen.users.profiles(), gen.merchants.profiles())
     return gen, s
 
@@ -227,20 +236,82 @@ class TestFusedEpilogue:
 
 
 # ---------------------------------------------------------- flash attention
-class TestFlashAttention:
-    def test_parity_masked(self, rng):
-        q, k, v = (jnp.asarray(rng.standard_normal((2, 4, 128, 64)),
-                               jnp.float32) for _ in range(3))
-        mask = jnp.asarray(rng.uniform(0, 1, (2, 128)) > 0.1)
-        got = flash_attention(q, k, v, mask, interpret=True)
-        ref = attention_reference(q, k, v, mask)
-        assert float(jnp.abs(got - ref).max()) <= 5e-5
+def _longtail_case(rng, t, dtype, h=4, d=64):
+    """Four rows of [T, H*D] with the benchmark's long-tail lengths: only
+    [CLS] real (every key beyond it masked), 20, 207 and the full window."""
+    q, k, v = (jnp.asarray(rng.standard_normal((4, t, h * d)),
+                           jnp.float32).astype(dtype) for _ in range(3))
+    lens = np.minimum([1, 20, 207, t], t)
+    mask = jnp.asarray(np.arange(t)[None, :] < lens[:, None])
+    return q, k, v, mask
 
-    def test_indivisible_blocks_raise(self, rng):
-        q, k, v = (jnp.asarray(rng.standard_normal((1, 2, 120, 32)),
-                               jnp.float32) for _ in range(3))
-        with pytest.raises(ValueError, match="divisible"):
-            flash_attention(q, k, v, block_q=64, interpret=True)
+
+def _stated_precision_oracle(q, k, v, mask, h):
+    """attention_reference's mathematics with the roundings written out
+    where the configuration states them and a TPU's default-precision
+    einsum makes them: bf16 MXU operands, f32 everything else."""
+    def bf(x):
+        return x.astype(jnp.bfloat16).astype(jnp.float32)
+
+    qh, kh, vh = (split_heads(bf(x.astype(jnp.float32)), h) for x in (q, k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", qh, kh,
+                   precision="highest") / np.sqrt(qh.shape[-1])
+    s = jnp.where(mask[:, None, None, :], s, -1e30)
+    p = jnp.exp(s - s.max(axis=-1, keepdims=True))
+    out = jnp.einsum("bhqk,bhkd->bhqd", bf(p), vh, precision="highest")
+    return merge_heads(out / p.sum(axis=-1, keepdims=True))
+
+
+class TestFlashAttention:
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("t", [128, 512])
+    def test_parity_longtail_mask(self, rng, t, dtype):
+        h = 4
+        q, k, v, mask = _longtail_case(rng, t, dtype, h=h)
+        got = flash_attention(q, k, v, mask, num_heads=h, interpret=True)
+        assert got.shape == q.shape and got.dtype == dtype
+        got = got.astype(jnp.float32)
+        assert bool(jnp.isfinite(got).all())
+        # (a) the kernel's own mathematics, rounding for rounding: today's
+        # f32 slack (bf16 outputs carry their own last-place rounding)
+        oracle = _stated_precision_oracle(q, k, v, mask, h)
+        tight = 1e-3 if dtype == jnp.float32 else 2e-2
+        assert float(jnp.abs(got - oracle).max()) <= tight
+        # (b) the XLA reference, which on this CPU multiplies in f32: the
+        # gap is bf16 operand rounding on N(0, 1) data, nothing more
+        ref = merge_heads(attention_reference(
+            split_heads(q, h), split_heads(k, h), split_heads(v, h), mask))
+        assert float(jnp.abs(got - ref.astype(jnp.float32)).max()) <= 3e-2
+
+    def test_fully_masked_row_is_uniform_like_the_reference(self, rng):
+        q, k, v, _ = _longtail_case(rng, 128, jnp.float32, h=2)
+        mask = jnp.zeros((4, 128), bool)
+        got = flash_attention(q, k, v, mask, num_heads=2, interpret=True)
+        mean_v = jnp.broadcast_to(
+            v.astype(jnp.bfloat16).astype(jnp.float32).mean(
+                axis=1, keepdims=True), v.shape)
+        assert float(jnp.abs(got - mean_v).max()) <= 2e-3
+
+    @pytest.mark.parametrize("t,d,h,ok", [
+        (64, 64, 12, False),    # parked s64 configurations: XLA keeps up
+        (96, 64, 12, False),    # not whole lane tiles of keys
+        (128, 64, 12, True),
+        (512, 64, 12, True),    # the deployed shape
+        (512, 64, 2, True),     # TINY_CONFIG's heads
+        (512, 32, 12, False),   # the lane pairing is for 64-wide heads
+        (512, 64, 3, False),    # an odd head has no partner
+    ])
+    def test_flash_supported_truth_table(self, t, d, h, ok):
+        assert flash_supported(t, d, h) is ok
+
+    def test_unsupported_shapes_raise(self, rng):
+        q = jnp.zeros((1, 96, 128), jnp.float32)
+        with pytest.raises(ValueError, match="multiple of 128"):
+            flash_attention(q, q, q, num_heads=2, interpret=True)
+        q = jnp.zeros((1, 128, 96), jnp.float32)
+        with pytest.raises(ValueError, match="64-wide heads"):
+            flash_attention(q, q, q, num_heads=3, interpret=True)
 
 
 # ----------------------------------------------------------- config surface
@@ -284,10 +355,64 @@ class TestScorerKernelPlane:
                                      "kernel_interpret": False,
                                      "megakernel": "off",
                                      "mega_valid": None}
-        assert s.effective_use_pallas() == bool(s.sc.use_pallas)
+        assert not hasattr(s.sc, "use_pallas")      # nothing a user sets
         assert s.kernel_snapshot()["dispatch"] == {
             "dequant_matmul": 0, "epilogue": 0, "attention": 0,
             "megakernel": 0}
+
+    def test_selector_picks_the_reference_on_cpu(self):
+        """Plane off, CPU devices: the selector never asks for the kernel,
+        whatever the shape — tier-1 programs stay the reference's."""
+        _, s = _scorer(kernels=False, quant=False)
+        assert flash_supported(s.sc.text_len, s.bert_config.head_dim,
+                               s.bert_config.num_heads)
+        assert s.effective_use_pallas() is False
+
+    @pytest.mark.parametrize("text_len,want", [(64, False), (128, True),
+                                               (512, True)])
+    def test_selector_follows_platform_and_shape(self, text_len, want):
+        """Plane off: on TPU devices the predicate alone decides. The test
+        stands in for the platform the scorer read from its mesh."""
+        s = FraudScorer(_kernel_config(kernels=False, quant=False),
+                        scorer_config=ScorerConfig(text_len=text_len))
+        s._platform = "tpu"
+        assert s.effective_use_pallas(devices=1) is want
+        # XLA cannot partition a Mosaic call: a program whose batch is
+        # sharded over a mesh keeps the reference
+        assert s.effective_use_pallas(devices=4) is False
+        assert s.effective_use_pallas() is (want and s.mesh.devices.size == 1)
+
+    def test_plane_forces_either_side(self):
+        cfg = _kernel_config(kernels=True, quant=False)
+        cfg.kernels.attention = "reference"
+        s = FraudScorer(cfg, scorer_config=ScorerConfig(text_len=TEXT_LEN))
+        s._platform = "tpu"
+        assert s.effective_use_pallas() is False    # forced off on a TPU
+        _, on = _scorer()
+        assert on.effective_use_pallas() is True    # forced on on a CPU
+
+    def test_attention_engagement_counts_with_plane_off(self):
+        """Every launch lands on the attention site's counters, plane or no
+        plane: here the selector sent both launches to the reference."""
+        gen, s = _scorer(kernels=False, quant=False)
+        s.score_batch(gen.generate_batch(BATCH), now=1000.0)
+        s.score_batch(gen.generate_batch(BATCH), now=1000.0)
+        snap = s.kernel_snapshot()
+        assert snap["dispatch"]["attention"] == 0
+        assert snap["fallback"]["attention"] == 2
+        assert snap["modes"]["attention"] == "reference"
+        assert snap["interpret"] is False
+
+    def test_forced_flash_at_an_unsupported_length_counts_fallbacks(self):
+        gen = TransactionGenerator(num_users=150, num_merchants=40, seed=7)
+        s = FraudScorer(_kernel_config(kernels=True, quant=False),
+                        scorer_config=ScorerConfig(text_len=64))
+        s.seed_profiles(gen.users.profiles(), gen.merchants.profiles())
+        assert s.effective_use_pallas()             # the plane asks ...
+        s.score_batch(gen.generate_batch(BATCH), now=1000.0)
+        snap = s.kernel_snapshot()                  # ... the guard declines
+        assert (snap["dispatch"]["attention"],
+                snap["fallback"]["attention"]) == (0, 1)
 
     def test_kernel_statics_on(self):
         _, s = _scorer()

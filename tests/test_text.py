@@ -25,6 +25,9 @@ from realtime_fraud_detection_tpu.models.tokenizer import (
 from realtime_fraud_detection_tpu.ops.attention import (
     attention_reference,
     flash_attention,
+    flash_supported,
+    merge_heads,
+    split_heads,
 )
 
 
@@ -58,34 +61,63 @@ class TestTokenizer:
 
 
 class TestFlashAttention:
-    @pytest.mark.parametrize("s,block", [(128, 128), (256, 128), (64, 32)])
-    def test_matches_reference(self, s, block):
-        rng = np.random.default_rng(0)
-        b, h, d = 2, 3, 32
-        q = rng.normal(size=(b, h, s, d)).astype(np.float32)
-        k = rng.normal(size=(b, h, s, d)).astype(np.float32)
-        v = rng.normal(size=(b, h, s, d)).astype(np.float32)
-        mask = rng.random((b, s)) > 0.3
-        mask[:, 0] = True
-        ours = np.asarray(flash_attention(q, k, v, mask, block_q=block,
-                                          block_k=block, interpret=True))
-        ref = np.asarray(attention_reference(q, k, v, mask))
-        np.testing.assert_allclose(ours, ref, rtol=2e-4, atol=2e-4)
+    """The fused core inside the text branch: ``bert_predict`` with the
+    kernel asked for (interpreted here) against the reference path."""
+
+    @staticmethod
+    def _both_ways(t, lens, seed=0):
+        cfg = TINY_CONFIG
+        params = init_bert_params(jax.random.PRNGKey(seed), cfg)
+        rng = np.random.default_rng(seed)
+        ids = rng.integers(0, cfg.vocab_size, (len(lens), t)).astype(np.int32)
+        mask = np.arange(t)[None, :] < np.asarray(lens)[:, None]
+        ref = bert_predict(params, ids, mask, cfg, use_pallas=False)
+        got = bert_predict(params, ids, mask, cfg, use_pallas=True,
+                           kernel_interpret=True)
+        return np.asarray(got), np.asarray(ref)
+
+    @pytest.mark.parametrize("t", [128, 256, 512])
+    def test_branch_matches_reference(self, t):
+        # one real token (all keys beyond [CLS] masked), the long tail's
+        # median and p99, the full window
+        got, ref = self._both_ways(t, [1, 20, min(207, t), t])
+        np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-4)
 
     def test_fully_masked_rows_no_nan(self):
-        rng = np.random.default_rng(1)
-        q = rng.normal(size=(1, 1, 64, 16)).astype(np.float32)
-        k = rng.normal(size=(1, 1, 64, 16)).astype(np.float32)
-        v = rng.normal(size=(1, 1, 64, 16)).astype(np.float32)
-        mask = np.zeros((1, 64), bool)  # nothing valid
-        out = np.asarray(flash_attention(q, k, v, mask, block_q=32,
-                                         block_k=32, interpret=True))
-        assert np.isfinite(out).all()
+        got, ref = self._both_ways(128, [0, 0])
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-4)
+
+    def test_unsupported_length_runs_the_reference(self):
+        """The traced guard: at a length ``flash_supported`` declines the
+        program asked for the kernel IS the reference program."""
+        assert not flash_supported(64, TINY_CONFIG.head_dim,
+                                   TINY_CONFIG.num_heads)
+        got, ref = self._both_ways(64, [1, 20, 64])
+        np.testing.assert_array_equal(got, ref)
 
     def test_indivisible_seq_rejected(self):
-        q = np.zeros((1, 1, 100, 16), np.float32)
-        with pytest.raises(ValueError, match="divisible"):
-            flash_attention(q, q, q, block_q=64, block_k=64, interpret=True)
+        q = np.zeros((1, 100, 128), np.float32)
+        with pytest.raises(ValueError, match="multiple of 128"):
+            flash_attention(q, q, q, num_heads=2, interpret=True)
+
+    def test_fused_core_matches_split_reference(self):
+        rng = np.random.default_rng(3)
+        b, h, t, d = 2, 2, 128, 64
+        # bf16-representable inputs: both sides multiply the same numbers
+        q, k, v = (np.asarray(jax.numpy.asarray(
+            rng.normal(size=(b, t, h * d)), jax.numpy.bfloat16), np.float32)
+            for _ in range(3))
+        mask = rng.random((b, t)) > 0.3
+        mask[:, 0] = True
+
+        ours = np.asarray(flash_attention(q, k, v, mask, num_heads=h,
+                                          interpret=True))
+        ref = np.asarray(merge_heads(attention_reference(
+            *(split_heads(jax.numpy.asarray(x), h) for x in (q, k, v)),
+            mask)))
+        # what is left is the bf16 rounding of the softmax weights
+        np.testing.assert_allclose(ours, ref, rtol=1e-2, atol=1e-2)
 
 
 class TestBert:

@@ -4,8 +4,8 @@ Complements bench.py (the fixed-format benchmark) with the sweeps needed
 to CHOOSE the production constants (drive p99 under the 20 ms budget with
 measured numbers):
 
-1. Pallas flash-attention block sizes (block_q x block_k) at seq 64/128/512
-   vs plain XLA attention — picks ops/attention.py defaults.
+1. The fused attention core vs plain XLA attention at seq 128/256/512,
+   buckets 8 and 256 — the crossover ops.attention.flash_supported states.
 2. score_fused bucket-size sweep (64..1024): per-bucket device latency and
    txn/s so BATCH_BUCKETS reflects the chip's actual knee.
 3. Per-branch device timings at the chosen bucket — where the p99 goes.
@@ -59,6 +59,9 @@ def main() -> int:
     from realtime_fraud_detection_tpu.ops.attention import (
         attention_reference,
         flash_attention,
+        flash_supported,
+        merge_heads,
+        split_heads,
     )
     from realtime_fraud_detection_tpu.scoring import (
         MODEL_NAMES,
@@ -100,47 +103,31 @@ def main() -> int:
           quantized=quant, kernels=kernels)
     rng = np.random.default_rng(0)
 
-    # 1 ------------------------------------------------- pallas block sweep
-    # This sweep is the flash-attention DEFAULT driver: the attn_verdict
-    # line below says whether flash beats plain XLA at the production
-    # sequence length, which is what justifies KernelSettings.full()
-    # flipping attention to "flash" (ops/attention.py block defaults).
-    attn_best: dict = {}
-    for seq in (64, 128, 512):
-        b, h, d = 64, 12, 64
-        k, v = (jnp.asarray(rng.standard_normal((b, h, seq, d)),
-                            jnp.float32) for _ in range(2))
-        qs = [jnp.asarray(rng.standard_normal((b, h, seq, d)), jnp.float32)
-              for _ in range(8)]
-        mask = jnp.ones((b, seq), bool)
-        ref = jax.jit(lambda q, k, v, m: attention_reference(q, k, v, m))
-        base = _time_blocked(lambda i: ref(qs[i % 8], k, v, mask), 30)
-        _emit(stage="attn", seq=seq, impl="xla", **base)
-        attn_best[seq] = {"xla_p50_ms": base["p50_ms"], "flash": None}
-        for bq in (64, 128, 256):
-            for bk in (64, 128, 256):
-                if seq % bq or seq % bk:
-                    continue
-                try:
-                    t = _time_blocked(
-                        lambda i: flash_attention(qs[i % 8], k, v, mask,
-                                                  block_q=bq, block_k=bk), 30)
-                except Exception as e:  # noqa: BLE001
-                    _emit(stage="attn", seq=seq, impl="pallas", block_q=bq,
-                          block_k=bk, error=str(e)[:120])
-                    continue
-                _emit(stage="attn", seq=seq, impl="pallas", block_q=bq,
-                      block_k=bk, **t)
-                fl = attn_best[seq]["flash"]
-                if fl is None or t["p50_ms"] < fl["p50_ms"]:
-                    attn_best[seq]["flash"] = {"block_q": bq, "block_k": bk,
-                                               "p50_ms": t["p50_ms"]}
-    for seq, rec in attn_best.items():
-        fl = rec["flash"]
-        _emit(stage="attn_verdict", seq=seq,
-              flash_wins=bool(fl and fl["p50_ms"] < rec["xla_p50_ms"]),
-              best_flash=fl, xla_p50_ms=rec["xla_p50_ms"],
-              drives="KernelSettings.full() attention default")
+    # 1 ------------------------------------- fused attention core vs XLA
+    # The crossover behind ops.attention.flash_supported: the fused core
+    # (one program a batch row, scores in VMEM) against the reference as
+    # bert_layer runs each (bf16 [B, T, H*D] in and out; f32 with the head
+    # split and merge). attn_verdict says whether the predicate still
+    # agrees with this chip at each length the kernel lowers for.
+    h, d = 12, 64
+    ref = jax.jit(lambda q, k, v, m: merge_heads(attention_reference(
+        split_heads(q, h), split_heads(k, h), split_heads(v, h), m)))
+    for seq in (128, 256, 512):
+        for b in (8, 256):
+            k, v = (jnp.asarray(rng.standard_normal((b, seq, h * d)),
+                                jnp.float32) for _ in range(2))
+            qs = [jnp.asarray(rng.standard_normal((b, seq, h * d)),
+                              jnp.float32) for _ in range(8)]
+            kb, vb = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
+            qb = [q.astype(jnp.bfloat16) for q in qs]
+            mask = jnp.ones((b, seq), bool)
+            base = _time_blocked(lambda i: ref(qs[i % 8], k, v, mask), 30)
+            fused = _time_blocked(lambda i: flash_attention(
+                qb[i % 8], kb, vb, mask, num_heads=h), 30)
+            _emit(stage="attn_verdict", seq=seq, bucket=b,
+                  xla_p50_ms=base["p50_ms"], flash_p50_ms=fused["p50_ms"],
+                  flash_wins=bool(fused["p50_ms"] < base["p50_ms"]),
+                  flash_supported=flash_supported(seq, d, h))
 
     # 2 ---------------------------------------------------- bucket sweep
     bert_config = BertConfig()
@@ -205,8 +192,12 @@ def main() -> int:
     # kernel-plane statics (rtfd kernel-drill gated): flash attention +
     # fused dequant-matmul (engages on the int8 params under --quant) +
     # fused epilogue, compiled for real on the chip (interpret=False)
+    # Without the plane the attention core is what a FraudScorer on this
+    # chip would pick (FraudScorer.effective_use_pallas): the predicate.
     kern = (dict(use_pallas=True, dequant_kernel="pallas",
-                 epilogue_kernel="pallas") if kernels else {})
+                 epilogue_kernel="pallas") if kernels else
+            dict(use_pallas=flash_supported(
+                sc.text_len, bert_config.head_dim, bert_config.num_heads)))
     if mesh is None:
         models = jax.device_put(models)
         fused = jax.jit(lambda m, b, p, v: score_fused(
