@@ -239,16 +239,17 @@ def blend(preds: np.ndarray, valid: np.ndarray, params) -> Dict[str, Any]:
             "decision": decision, "rungs": rungs}
 
 
-def score(models, batch, params, model_valid, *, n_heads: int
+def score(models, batch, params, model_valid, cfg: Dict[str, Any]
           ) -> Dict[str, Any]:
     """Everything the served program returns for ``batch`` (host NumPy
     copies of the program's containers). ``branches`` is [B, 5] in
-    ``BRANCHES`` order."""
+    ``BRANCHES`` order. ``cfg`` is the configuration file: of the sizes the
+    weights' shapes do not carry, this architecture needs ``n_heads``."""
     preds = np.stack([
         trees_branch(models.trees, batch.features),
         sequence_branch(models.lstm, batch.history, batch.history_len),
         text_branch(models.bert, batch.token_ids, batch.token_mask,
-                    n_heads=n_heads),
+                    n_heads=cfg["n_heads"]),
         graph_branch(models.gnn, batch),
         isolation_branch(models.iforest, batch.features),
     ], axis=1)

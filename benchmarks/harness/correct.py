@@ -41,8 +41,7 @@ def parity(scorer, events: Sequence[Dict[str, Any]], cfg: Dict[str, Any]
     scorer.finalize(pending)
     models, host_batch = jax.device_get((scorer.models, batch))
     ref = reference.score(models, host_batch, scorer.ensemble_params,
-                          scorer.effective_model_valid(),
-                          n_heads=cfg["n_heads"])
+                          scorer.effective_model_valid(), cfg)
     cols = {name: j for j, name in enumerate(OUT_COLUMNS)}
     ok = (on_device.shape[1] == len(OUT_COLUMNS) + len(reference.BRANCHES)
           and bool(np.isfinite(on_device).all()))

@@ -11,7 +11,9 @@ JSON transaction, simulator.py:78-101) extended for a benchmark:
 - the length of the combined text (``models/text.combined_text``), counted
   as the scorer's tokenizer counts it, follows the traffic file's
   distribution;
-- merchants are drawn Zipf(s), users uniformly;
+- merchants are drawn Zipf(s); users uniformly, or Zipf(``user_zipf_s``)
+  where the traffic file has that key (returning users: entity state,
+  history windows);
 - a pool of distinct events is built once and replayed with a fresh
   ``transaction_id``, reference and event time per pass, so a run of any
   length costs a few seconds of generation.
@@ -230,11 +232,16 @@ def seq_of(transaction_id: str, id_prefix: str = "b") -> int:
 def build_pool(pop: Population, traffic: Dict[str, Any],
                rng: np.random.Generator) -> EventPool:
     n = int(traffic["pool_events"])
-    u = rng.integers(0, pop.num_users, n)
-    # Zipf(s) over merchant rank: p(rank r) ~ r^-s
-    s = float(traffic["merchant_zipf_s"])
-    p = np.arange(1, pop.num_merchants + 1, dtype=np.float64) ** -s
-    m = rng.choice(pop.num_merchants, n, p=p / p.sum())
+
+    def zipf(population: int, s: float) -> np.ndarray:
+        # Zipf(s) over rank: p(rank r) ~ r^-s
+        p = np.arange(1, population + 1, dtype=np.float64) ** -s
+        return rng.choice(population, n, p=p / p.sum())
+
+    # without the key, the draw every stream made before it existed
+    u = (zipf(pop.num_users, float(traffic["user_zipf_s"]))
+         if "user_zipf_s" in traffic else rng.integers(0, pop.num_users, n))
+    m = zipf(pop.num_merchants, float(traffic["merchant_zipf_s"]))
     amount = np.maximum(1.0, np.round(
         pop.avg_amount[u] * rng.normal(1.0, 0.3, n) * rng.normal(1.0, 0.2, n),
         2))

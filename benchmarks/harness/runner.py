@@ -17,7 +17,6 @@ from benchmarks.harness import (
     annotate,
     correct,
     events as ev_mod,
-    flops,
     load,
     spec,
     system,
@@ -120,6 +119,7 @@ def run_cell(args: argparse.Namespace, t_process: float) -> Dict[str, Any]:
                for kind, defs in (("end_to_end", e2e_defs),
                                   ("per_layer", layer_defs))
                for m in defs}          # every name resolves before any work
+    builder = spec.builder(cfg)
 
     from realtime_fraud_detection_tpu.utils.compile_cache import (
         configure_compile_cache,
@@ -148,7 +148,8 @@ def run_cell(args: argparse.Namespace, t_process: float) -> Dict[str, Any]:
     try:
         return _measure(args, t_process, cell, remote, types.SimpleNamespace(
             readers=readers, e2e_defs=e2e_defs, layer_defs=layer_defs,
-            devices=devices, device=device, compiles=compiles))
+            builder=builder, devices=devices, device=device,
+            compiles=compiles))
     finally:
         if remote is not None:
             remote.close()
@@ -162,6 +163,7 @@ def _measure(args: argparse.Namespace, t_process: float,
     cfg, traffic = cell["config_data"], cell["traffic_data"]
     traced = bool(args.trace)
     readers, e2e_defs, layer_defs = env.readers, env.e2e_defs, env.layer_defs
+    builder = env.builder
     devices, device, compiles = env.devices, env.device, env.compiles
     warmup_s, grace_s = float(traffic["warmup_s"]), float(traffic["grace_s"])
     timings: Dict[str, float] = {"imports": time.time() - t_process}
@@ -191,9 +193,9 @@ def _measure(args: argparse.Namespace, t_process: float,
     # ---- the system under test
     t0 = time.perf_counter()
     sample = pool.materialize(range(512), np.zeros(512), "q")
-    models = system.make_models(
+    models = builder.make_models(
         cfg, args.seed, system.event_features(sample, users, merchants))
-    scorer = system.make_scorer(cfg, args.seed, models, users, merchants)
+    scorer = builder.make_scorer(cfg, args.seed, models, users, merchants)
     broker, job = system.make_job(
         cfg, scorer, traced,
         broker=remote.server.broker if remote is not None else None)
@@ -232,10 +234,8 @@ def _measure(args: argparse.Namespace, t_process: float,
     compiled_in_window = run.extra["compiled_in_window"]
     run.extra.update(
         setup_s=state["setup_s"], cfg=cfg, traffic=traffic, device=device,
-        flops_per_batch=flops.ensemble_matmul_flops(
-            hidden=cfg["dim"], intermediate=cfg["hidden_dim"],
-            layers=cfg["n_layers"], text_len=cfg["text_len"],
-            batch=cfg["job"]["max_batch"])["total"])
+        vocabulary=builder.VOCABULARY,
+        flops_per_batch=builder.matmul_flops_per_batch(cfg))
     fullest = max((d.memory_stats() or {} for d in devices),
                   key=memory_peak_bytes)
     run.extra["memory_peak_bytes"] = memory_peak_bytes(fullest)

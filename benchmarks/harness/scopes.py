@@ -4,10 +4,16 @@ and to the program's own host spans.
 The program names its work itself: ``jax.named_scope`` on every branch of
 the fused scoring program and on the text branch's kernels, and a
 ``TraceAnnotation`` named ``rtfd:<span>`` around every host stage of a
-microbatch (``realtime_fraud_detection_tpu/obs/scopes.py`` holds both lists;
-``BRANCHES`` ... ``PREFIX`` below are the same strings, written here again so
-that this file also runs against a program that has neither — it then finds
-nothing, says so, and every reader built on it returns ``None``).
+microbatch (``realtime_fraud_detection_tpu/obs/scopes.py`` holds both lists).
+Which device scopes a configuration's program writes is its builder's
+``VOCABULARY`` (``configs/<builder>.py``): a nested mapping, branch ->
+parts -> their parts, to whatever depth the program nests them, in which a
+``*`` in a name stands for one component's digits (``layer*``). The strings
+are written again on this side so that this file also runs against a
+program that has none of them — it then finds nothing, says so, and every
+reader built on it returns ``None``. ``BRANCHES`` ... ``LAYER_PARTS`` below
+are the five-branch ensemble's, which ``ENSEMBLE_VOCABULARY`` nests and the
+default builder names.
 
 Where the names are in a v5e trace under JAX 0.9.0 (looked at by hand, PR
 23): each event of a device plane's ``XLA Ops`` line is named by its whole
@@ -28,7 +34,7 @@ against ``tests/fixtures/scope_events.json``:
   operations (``XLA Ops`` line) clipped to the window, summed over device
   planes. A path's time includes its children's (``text`` holds
   ``text/layer0/ffn``); ``unscoped`` is the operations whose ``op_name``
-  starts with none of ``BRANCHES``;
+  starts with no branch of the configuration's vocabulary;
 - the window is the ``bench:slice`` annotation the harness writes;
 - host spans: every ``rtfd:`` annotation inside the window, with its self
   time (duration minus what the spans nested in it on the same thread
@@ -42,17 +48,19 @@ against ``tests/fixtures/scope_events.json``:
 
 from __future__ import annotations
 
+import functools
 import re
-from typing import Any, Dict, Iterable, Iterator, List, Optional, Sequence, \
-    Tuple
+from typing import Any, Dict, Iterable, Iterator, List, Mapping, Optional, \
+    Sequence, Tuple
 
 from benchmarks.harness import trace as trace_mod
 
-# scope names, as the program writes them (obs/scopes.py)
+# the five-branch ensemble's scope names, as the program writes them
+# (obs/scopes.py)
 BRANCHES: Tuple[str, ...] = ("trees", "lstm", "text", "gnn", "iforest",
                              "rules", "blend", "unpack", "repack")
 TEXT = "text"
-LAYER_RE = re.compile(r"layer\d+$")
+LAYER = "layer*"
 TEXT_PARTS: Tuple[str, ...] = ("embed", "head")
 LAYER_PARTS: Tuple[str, ...] = ("attn_proj", "attn_core", "ffn", "ln")
 UNSCOPED = "unscoped"
@@ -64,24 +72,55 @@ WINDOW = trace_mod.ANNOTATION_PREFIX + trace_mod.WINDOW_ANNOTATION
 
 # (plane, line, name, start_ns, duration_ns, scope path or "")
 Event = Tuple[str, str, str, float, float, str]
+# {scope name: {the scope names written directly under it: {...}}}
+Vocabulary = Mapping[str, "Vocabulary"]
 
 
-def scope_path(op_name: str) -> str:
-    """``jit(f)/text/layer0/ffn/dot_general:`` -> ``text/layer0/ffn``; ""
-    where the name starts with no branch scope. ``jit(...)`` components
-    (the program's own name, and inner jitted functions) are skipped."""
-    parts = [p for p in op_name.rstrip(":").split("/")
-             if p and not p.startswith("jit(")]
-    if not parts or parts[0] not in BRANCHES:
-        return ""
-    path = [parts[0]]
-    if parts[0] == TEXT and len(parts) > 1:
-        if parts[1] in TEXT_PARTS:
-            path.append(parts[1])
-        elif LAYER_RE.match(parts[1]):
-            path.append(parts[1])
-            if len(parts) > 2 and parts[2] in LAYER_PARTS:
-                path.append(parts[2])
+@functools.lru_cache(maxsize=None)
+def digits_re(pattern: str) -> "re.Pattern[str]":
+    """``text/layer*/ffn`` -> a whole-string match in which ``*`` is one
+    component's digits."""
+    return re.compile(
+        "^" + re.escape(pattern).replace(r"\*", r"\d+") + "$")
+
+
+# tier-1 tests/test_scopes.py holds it to the program's ``layer<i>``
+LAYER_RE = digits_re(LAYER)
+
+ENSEMBLE_VOCABULARY: Vocabulary = {
+    **{branch: {} for branch in BRANCHES},
+    TEXT: {**{part: {} for part in TEXT_PARTS},
+           LAYER: {part: {} for part in LAYER_PARTS}},
+}
+
+
+def _below(level: Vocabulary, name: str) -> Optional[Vocabulary]:
+    """The vocabulary under ``name`` at this level; ``None`` where the
+    level does not know the name."""
+    if name in level:
+        return level[name]
+    for key, sub in level.items():
+        if "*" in key and digits_re(key).match(name):
+            return sub
+    return None
+
+
+def scope_path(op_name: str,
+               vocabulary: Vocabulary = ENSEMBLE_VOCABULARY) -> str:
+    """``jit(f)/text/layer0/ffn/dot_general:`` -> ``text/layer0/ffn``: every
+    leading component that ``vocabulary`` knows at its depth, to whatever
+    depth it goes; "" where the name starts with no branch of it.
+    ``jit(...)`` components (the program's own name, and inner jitted
+    functions) are skipped."""
+    path: List[str] = []
+    level: Optional[Vocabulary] = vocabulary
+    for part in op_name.rstrip(":").split("/"):
+        if not part or part.startswith("jit("):
+            continue
+        level = _below(level, part)
+        if level is None:
+            break
+        path.append(part)
     return "/".join(path)
 
 
@@ -185,18 +224,22 @@ def op_names(path: str) -> Dict[str, Dict[str, str]]:
     return out
 
 
-def read_xplane(path: str) -> List[Event]:
-    """Device planes' ``XLA Ops`` events with their scope path, and the
-    host planes' ``rtfd:`` annotations and ``bench:slice``."""
+def read_xplane(path: str, vocabulary: Vocabulary = ENSEMBLE_VOCABULARY
+                ) -> List[Event]:
+    """Device planes' ``XLA Ops`` events with their scope path under
+    ``vocabulary``, and the host planes' ``rtfd:`` annotations and
+    ``bench:slice``."""
     import jax
 
-    ops = op_names(path)
+    ops = {plane: {name: scope_path(op, vocabulary)
+                   for name, op in names.items()}
+           for plane, names in op_names(path).items()}
     out: List[Event] = []
     for plane in jax.profiler.ProfileData.from_file(path).planes:
         device = plane.name.startswith(trace_mod.DEVICE_PLANE_PREFIX)
         if not device and not plane.name.startswith("/host:"):
             continue
-        names = ops.get(plane.name, {})
+        paths = ops.get(plane.name, {})
         for line in plane.lines:
             if device and line.name != trace_mod.OPS_LINE:
                 continue
@@ -206,7 +249,7 @@ def read_xplane(path: str) -> List[Event]:
                     # named by the instruction alone: the scope says the rest
                     out.append((plane.name, line.name, name.split(" ", 1)[0],
                                 float(ev.start_ns), float(ev.duration_ns),
-                                scope_path(names.get(name, ""))))
+                                paths.get(name, "")))
                 elif name.startswith(PREFIX) or name == WINDOW:
                     out.append((plane.name, line.name, name,
                                 float(ev.start_ns), float(ev.duration_ns),
@@ -335,7 +378,7 @@ def matching(scope_s: Dict[str, float], pattern: str) -> Optional[float]:
     """Seconds under the scope paths matching ``pattern`` (``*`` stands for
     one component's digits, as in ``text/layer*/ffn``); ``None`` where no
     operation carried such a path."""
-    rx = re.compile("^" + re.escape(pattern).replace(r"\*", r"\d+") + "$")
+    rx = digits_re(pattern)
     found = [s for p, s in scope_s.items() if rx.match(p)]
     return sum(found) if found else None
 
@@ -354,7 +397,8 @@ def for_run(run: Any) -> Optional[Dict[str, Any]]:
         from benchmarks.harness import spec
 
         path = trace_mod.newest_xplane(str(spec.ROOT / ".bench_trace"))
-        out = reduce(read_xplane(path))
+        out = reduce(read_xplane(path, run.extra.get(
+            "vocabulary", ENSEMBLE_VOCABULARY)))
         if out is None:
             print("[bench] scopes: the trace has no device operation; the "
                   "device_trace readers of the named scopes are left out",

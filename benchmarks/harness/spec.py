@@ -1,8 +1,9 @@
 """Names in ``BENCHMARK.json`` -> the files that define them.
 
-Nothing about one cell, configuration, traffic mix, arrival kind or metric
-is written in the harness's code: each is a file found by its name, and a
-name with no file stops the run with the path that was looked for.
+Nothing about one cell, configuration, architecture, kernel, traffic mix,
+arrival kind or metric is written in the harness's code: each is a file
+found by its name, and a name with no file stops the run with the path that
+was looked for.
 """
 
 from __future__ import annotations
@@ -87,15 +88,41 @@ def reader_for(metric: str, kind: str) -> Callable[[Any], Any]:
     mod = _load_module(BENCH / "readers" / f"{d['reader']}.py",
                        f"reader {d['reader']!r} of metric {metric!r}")
     args = d.get("args", {})
+    if "kernel" in args:        # a name like any other: no file, no run
+        kernel(args["kernel"])
     return lambda run: mod.read(run, **args)
 
 
 def reference(name: str):
     """``configs/<name>.py``: a configuration's plain reference, with
-    ``score(models, batch, params, model_valid, n_heads=...)`` and
-    ``BRANCHES``."""
+    ``score(models, batch, params, model_valid, cfg)`` and ``BRANCHES``."""
     return _load_module(BENCH / "configs" / f"{name}.py",
                         f"reference {name!r}")
+
+
+# the five-branch ensemble with a DistilBERT-keyed text branch: what every
+# configuration file written before builders were named resolves to
+DEFAULT_BUILDER = "ensemble_builder"
+
+
+def builder(cfg: Dict[str, Any]):
+    """``configs/<cfg["builder"]>.py``: the architecture a configuration's
+    keys are written for. ``make_models(cfg, seed, sample_features)``,
+    ``make_scorer(cfg, seed, models, users, merchants)``,
+    ``matmul_flops_per_batch(cfg)``, ``VOCABULARY`` (the device scopes its
+    program writes, nested: ``harness/scopes.py``) and ``TINY`` (the data
+    overrides of a CPU rehearsal)."""
+    name = cfg.get("builder", DEFAULT_BUILDER)
+    return _load_module(BENCH / "configs" / f"{name}.py",
+                        f"builder {name!r}")
+
+
+def kernel(name: str):
+    """``kernels/<name>.py``: ``work(counters, cfg) -> {"flops": ...,
+    "hbm_bytes": ...}``, what the algorithm needs for the launches the
+    program counted."""
+    return _load_module(BENCH / "kernels" / f"{name}.py",
+                        f"kernel {name!r}")
 
 
 def arrival(kind: str):

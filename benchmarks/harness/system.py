@@ -1,17 +1,18 @@
-"""Build the system under test from a configuration file.
+"""What every architecture's system under test shares.
 
-The scorer is built through the seam ``rtfd serve`` and
-``chip_smoke.make_scorer`` use, the job with the ``JobConfig`` the file
-spells out (what ``rtfd run-job`` builds with no flags, plus the pool
-switches for the four-chip deployment). The only things made here are the
-weights: ``init_scoring_models`` leaves trees and isolation forest at zero
-(every row in one leaf), so a seeded ensemble split at feature quantiles of
-this run's own events stands in, as ``chip_smoke.check_gemm_trees`` does.
+Models and scorer are made by the configuration's builder
+(``configs/<builder>.py``, ``spec.builder``); here is what is the same for
+all of them: the job with the ``JobConfig`` the file spells out (what ``rtfd
+run-job`` builds with no flags, plus the pool switches for the four-chip
+deployment), the buckets a cell's traffic reaches, the features of a sample
+of events, and the tree weights: ``init_scoring_models`` leaves trees and
+isolation forest at zero (every row in one leaf), so a seeded ensemble
+split at feature quantiles of this run's own events stands in, as
+``chip_smoke.check_gemm_trees`` does.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Any, Dict, List, Sequence
 
 import numpy as np
@@ -19,17 +20,6 @@ import numpy as np
 # the tracer keeps its newest completed traces; large enough that a stage
 # median is over several seconds of the window, not its last batches
 TRACER_RING = 32768
-
-
-def bert_config(cfg: Dict[str, Any]):
-    """``BertConfig`` from the published ``config.json`` keys of the file."""
-    from realtime_fraud_detection_tpu.models.bert import BertConfig
-
-    return BertConfig(
-        vocab_size=cfg["vocab_size"], hidden_size=cfg["dim"],
-        num_layers=cfg["n_layers"], num_heads=cfg["n_heads"],
-        intermediate_size=cfg["hidden_dim"],
-        max_position_embeddings=cfg["max_position_embeddings"])
 
 
 def event_features(events: Sequence[Dict[str, Any]], users, merchants
@@ -47,29 +37,20 @@ def event_features(events: Sequence[Dict[str, Any]], users, merchants
         encode_transactions(list(events), users, merchants)), np.float32)
 
 
-def make_models(cfg: Dict[str, Any], seed: int, sample_features: np.ndarray):
-    """All five branches, made on the device in one jitted call from the
-    seed; trees and isolation forest then replaced by seeded ensembles of
-    the same sizes split at quantiles of ``sample_features``."""
-    import jax
+def seeded_forests(models, cfg: Dict[str, Any], seed: int,
+                   sample_features: np.ndarray):
+    """``models`` with trees and isolation forest replaced by seeded
+    ensembles of the configuration's ``assumed`` sizes, split at quantiles
+    of ``sample_features``. Every builder calls this: the two branches are
+    the same whatever the text or sequence architecture."""
     import jax.numpy as jnp
 
     from realtime_fraud_detection_tpu.models.isolation_forest import (
         IsolationForest,
     )
     from realtime_fraud_detection_tpu.models.trees import TreeEnsemble
-    from realtime_fraud_detection_tpu.scoring import ScorerConfig
-    from realtime_fraud_detection_tpu.scoring.pipeline import (
-        init_scoring_models,
-    )
 
-    sc = ScorerConfig()
     a = cfg["assumed"]
-    init = jax.jit(functools.partial(
-        init_scoring_models, bert_config=bert_config(cfg),
-        feature_dim=sc.feature_dim, node_dim=sc.node_dim,
-        n_trees=a["n_trees"], tree_depth=a["tree_depth"]))
-    models = init(jax.random.PRNGKey(seed))
     if models.iforest.feature.shape != (a["n_trees"],
                                         2 ** a["iforest_depth"] - 1):
         raise ValueError(
@@ -99,23 +80,6 @@ def make_models(cfg: Dict[str, Any], seed: int, sample_features: np.ndarray):
             a["n_trees"], 2 ** a["iforest_depth"])).astype(np.float32)),
         c_psi=models.iforest.c_psi)
     return models.replace(trees=trees, iforest=iforest)
-
-
-def make_scorer(cfg: Dict[str, Any], seed: int, models, users, merchants):
-    import jax
-
-    from realtime_fraud_detection_tpu.core.mesh import build_mesh
-    from realtime_fraud_detection_tpu.scoring import FraudScorer, ScorerConfig
-    from realtime_fraud_detection_tpu.utils.config import Config
-
-    config = Config()
-    config.monitoring.prometheus_port = 0   # no fixed-port listener
-    scorer = FraudScorer(
-        config, models=models, bert_config=bert_config(cfg),
-        scorer_config=ScorerConfig(text_len=cfg["text_len"]), seed=seed,
-        mesh=build_mesh(devices=jax.devices()[:1]))
-    scorer.seed_profiles(users, merchants)
-    return scorer
 
 
 def make_job(cfg: Dict[str, Any], scorer, traced: bool, broker=None):

@@ -110,6 +110,46 @@ def test_merchants_are_zipf_users_uniform():
     assert len(users) > 0.9 * 3000 * (1 - np.exp(-8192 / 3000))
 
 
+# sha256 of the first 1,000 events (JSON, sorted keys) of seed 7 over 3,000
+# users / 400 merchants and a 1,024-event pool, made by the generator as it
+# stood before ``user_zipf_s`` existed (commit 5359c62)
+STREAMS_BEFORE_USER_ZIPF = {
+    "s512-longtail-saturated":
+        "c39c66ad600b0219c33564867016faef7c5002e79e2608a7d467a0893f11cb9e",
+    "s512-fulltext-saturated":
+        "bcd08c45a092664fb622b6a120177dc2abb4e28ad1963d63ad2801b13bbd1903",
+}
+
+
+@pytest.mark.parametrize("name", sorted(STREAMS_BEFORE_USER_ZIPF))
+def test_a_traffic_file_without_user_zipf_s_streams_what_it_always_did(name):
+    import hashlib
+
+    _, pool, traffic = _pool(7, name, n=1024)
+    assert "user_zipf_s" not in traffic
+    events = pool.materialize(range(1000), np.zeros(1000))
+    assert hashlib.sha256(json.dumps(events, sort_keys=True).encode()
+                          ).hexdigest() == STREAMS_BEFORE_USER_ZIPF[name]
+
+
+def test_user_zipf_s_makes_users_return():
+    traffic = json.loads(
+        (spec.BENCH / "traffic" / "s64-saturated.json").read_text())
+    traffic.update(pool_events=8192, user_zipf_s=1.0)
+    rng = np.random.default_rng(5)
+    pop = E.Population(3000, 400, rng)
+    pool = E.build_pool(pop, traffic, rng)
+    ranks = np.array([int(e["user_id"].split("_")[1], 16)
+                      for e in pool.events])
+    # Zipf(1) over 3,000 users: rank 0 draws ~12%, the top 10 about 34%
+    assert 0.08 < np.mean(ranks == 0) < 0.16
+    assert 0.27 < np.mean(ranks < 10) < 0.41
+    # merchants keep their own skew
+    m = np.array([int(e["merchant_id"].split("_")[1], 16)
+                  for e in pool.events])
+    assert 0.10 < np.mean(m == 0) < 0.20
+
+
 def test_events_pass_the_stream_sanitizer_unchanged_in_count():
     from realtime_fraud_detection_tpu.serving.validation import (
         sanitize_for_stream,

@@ -182,3 +182,51 @@ def test_rule_table_is_the_programs_on_generated_events():
     got = ref.rule_score(jax.device_get(txn))
     assert float(np.std(got)) > 0.02
     assert np.abs(got - np.asarray(rule_score(txn))).max() < 1e-6
+
+
+def test_score_reads_its_sizes_from_the_configuration_file():
+    """``score(..., cfg)`` returns what composing the branches by hand with
+    ``n_heads`` passed in returns (the signature it had while the harness
+    read the widths): on a TINY scorer made by the default builder, from
+    the assembled inputs of generated events."""
+    import json
+
+    from benchmarks.harness import events as E
+    from benchmarks.harness import system
+
+    cfg = json.loads(
+        (spec.BENCH / "configs" / "distilbert-s64.json").read_text())
+    builder = spec.builder(cfg)
+    cfg.update(builder.TINY)
+    traffic = json.loads(
+        (spec.BENCH / "traffic" / "s64-saturated.json").read_text())
+    traffic["pool_events"] = 64
+    rng = _rng(11)
+    pop = E.Population(300, 40, rng)
+    pool = E.build_pool(pop, traffic, rng)
+    users, merchants = pop.user_profiles(), pop.merchant_profiles()
+    recs = pool.materialize(range(16), np.zeros(16))
+    models = builder.make_models(
+        cfg, 11, system.event_features(recs, users, merchants))
+    scorer = builder.make_scorer(cfg, 11, models, users, merchants)
+    models, batch = jax.device_get((scorer.models, scorer.assemble(recs)))
+    params, valid = scorer.ensemble_params, scorer.effective_model_valid()
+
+    got = ref.score(models, batch, params, valid, cfg)
+    preds = np.stack([
+        ref.trees_branch(models.trees, batch.features),
+        ref.sequence_branch(models.lstm, batch.history, batch.history_len),
+        ref.text_branch(models.bert, batch.token_ids, batch.token_mask,
+                        n_heads=cfg["n_heads"]),
+        ref.graph_branch(models.gnn, batch),
+        ref.isolation_branch(models.iforest, batch.features)], axis=1)
+    want = ref.blend(preds, np.asarray(valid, bool)[None, :]
+                     & np.asarray(batch.valid, bool)[:, None], params)
+    assert set(got) == {"fraud_probability", "confidence", "decision",
+                        "rungs", "branches", "rule_score"}
+    np.testing.assert_array_equal(got["branches"], preds)
+    for name in ("fraud_probability", "confidence", "decision"):
+        np.testing.assert_array_equal(got[name], want[name])
+    assert got["branches"][:, 2].std() > 0.0     # the text rows differ
+    with pytest.raises(KeyError):
+        ref.score(models, batch, params, valid, {"num_attention_heads": 2})

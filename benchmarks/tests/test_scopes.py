@@ -9,7 +9,7 @@ from pathlib import Path
 
 import pytest
 
-from benchmarks.harness import kernel_flops, scopes as S, spec
+from benchmarks.harness import scopes as S, spec
 from benchmarks.harness import trace as T
 
 FIXTURE = Path(__file__).parent / "fixtures" / "scope_events.json"
@@ -72,6 +72,84 @@ def hand_made():
 ])
 def test_scope_path_of_an_op_name(op_name, path):
     assert S.scope_path(op_name) == path
+
+
+# another architecture's program: a second sequence branch, and experts
+# nested two levels below a layer
+MOE = {"text": {"embed": {}, "layer*": {
+           "attn_core": {}, "router": {},
+           "experts": {"expert*": {"up": {}, "down": {}}}}},
+       "events": {"block*": {"scan": {}}}, "trees": {}}
+
+
+@pytest.mark.parametrize("op_name,path", [
+    (JIT + "text/layer3/experts/expert17/down/dot_general:",
+     "text/layer3/experts/expert17/down"),
+    (JIT + "text/layer3/experts/expert17/silu/mul",
+     "text/layer3/experts/expert17"),
+    (JIT + "text/layer3/experts/jit(grouped)/expert2/up/dot_general",
+     "text/layer3/experts/expert2/up"),
+    (JIT + "text/layer3/router/top_k", "text/layer3/router"),
+    (JIT + "text/layer3/ffn/dot_general", "text/layer3"),   # not its part
+    (JIT + "text/head/dot_general", "text"),
+    (JIT + "events/block39/scan/while", "events/block39/scan"),
+    (JIT + "events/blockx/scan", "events"),
+    (JIT + "lstm/while", ""),                # no branch of THIS vocabulary
+    (JIT + "expert3/up/dot_general", ""),
+])
+def test_scope_path_keeps_every_declared_part_at_any_depth(op_name, path):
+    assert S.scope_path(op_name, MOE) == path
+
+
+def test_a_deeper_vocabulary_reduces_to_deeper_paths():
+    us = 1000.0
+    events = [
+        span("main", "bench:slice", 0, 1000 * us),
+        (D, T.OPS_LINE, "fusion", 100 * us, 100 * us, S.scope_path(
+            JIT + "text/layer0/experts/expert1/up/dot_general", MOE)),
+        (D, T.OPS_LINE, "fusion", 200 * us, 50 * us, S.scope_path(
+            JIT + "text/layer0/experts/expert2/up/dot_general", MOE)),
+        (D, T.OPS_LINE, "fusion", 300 * us, 25 * us, S.scope_path(
+            JIT + "lstm/while", MOE)),
+    ]
+    s = S.reduce(events)["scope_s"]
+    assert s["text/layer0/experts"] == pytest.approx(150e-6)
+    assert s["text/layer0/experts/expert2/up"] == pytest.approx(50e-6)
+    assert S.matching(s, "text/layer*/experts/expert*/up") == \
+        pytest.approx(150e-6)
+    assert s[S.UNSCOPED] == pytest.approx(25e-6)
+
+
+def _names(vocabulary):
+    for name, below in vocabulary.items():
+        yield name
+        yield from _names(below)
+
+
+@pytest.mark.parametrize("builder", sorted(
+    p.stem for p in (spec.BENCH / "configs").glob("*_builder.py")))
+def test_a_builders_vocabulary_is_names_the_program_writes(builder):
+    """Subset, not equality: the program may name more than a builder
+    reads. (What tier-1 ``tests/test_scopes.py`` should hold per builder,
+    in place of its equality with the ensemble's tuples.)"""
+    from realtime_fraud_detection_tpu.obs import scopes as program
+
+    written = {v for v in vars(program).values() if isinstance(v, str)}
+    written |= set(program.BRANCH_SCOPES) | set(program.LAYER_SCOPES)
+    vocabulary = spec.builder({"builder": builder}).VOCABULARY
+    assert set(vocabulary) <= set(program.BRANCH_SCOPES)
+    for name in _names(vocabulary):
+        assert name.replace("*", "") in written, name
+    assert S.digits_re(S.LAYER).match(program.layer_scope(11))
+
+
+def test_the_default_vocabulary_is_the_ensembles_tuples():
+    v = spec.builder({}).VOCABULARY
+    assert v is S.ENSEMBLE_VOCABULARY
+    assert tuple(v) == S.BRANCHES
+    assert set(v[S.TEXT]) == set(S.TEXT_PARTS) | {S.LAYER}
+    assert tuple(v[S.TEXT][S.LAYER]) == S.LAYER_PARTS
+    assert all(not v[b] for b in S.BRANCHES if b != S.TEXT)
 
 
 def test_scope_sums_are_unions_and_parents_hold_children():
@@ -315,12 +393,13 @@ def test_a_scope_with_no_operation_leaves_the_metric_out(capsys):
 
 def test_roofline_readers_charge_the_flops_the_program_counted():
     run = fake_run()
-    ffn = kernel_flops.ffn(2 * 256 * 512, dim=768, hidden_dim=3072, layers=6)
+    ffn = spec.kernel("ffn").flops(2 * 256 * 512, dim=768, hidden_dim=3072,
+                                   layers=6)
     assert ffn == 2 * 2 * 2 * 256 * 512 * 768 * 3072 * 6
     assert metric("ffn_roofline_pct", run) == pytest.approx(
         100 * ffn / 197e12 / 0.08)
-    attn = kernel_flops.attn_core(2 * 256 * 512 ** 2, heads=12, head_dim=64,
-                                  layers=6)
+    attn = spec.kernel("attn_core").flops(2 * 256 * 512 ** 2, heads=12,
+                                          head_dim=64, layers=6)
     assert attn == 2 * 2 * 12 * 2 * 256 * 512 ** 2 * 64 * 6
     assert metric("attn_core_roofline_pct", run) == pytest.approx(
         100 * attn / 197e12 / 0.18)
@@ -337,8 +416,49 @@ def test_roofline_readers_charge_the_flops_the_program_counted():
         del run.counters_slice[key]
     assert metric("ffn_roofline_pct", run) is None
     assert metric("attn_core_roofline_pct", run) is None
-    with pytest.raises(ValueError, match="no FLOP count"):
-        kernel_flops.issued("softmax", {}, CFG)
+
+
+def test_a_kernel_without_a_file_stops_with_the_path_looked_for():
+    read = spec._load_module(spec.BENCH / "readers" / "scope_roofline.py",
+                             "reader").read
+    with pytest.raises(SystemExit,
+                       match="no file benchmarks/kernels/softmax.py"):
+        read(fake_run(), scope="text/layer*/ffn", kernel="softmax")
+
+
+def test_a_metric_files_kernel_resolves_before_any_work(tmp_path,
+                                                        monkeypatch):
+    bench = tmp_path / "benchmarks"
+    (bench / "layer_metrics").mkdir(parents=True)
+    (bench / "layer_metrics" / "softmax_roofline_pct.json").write_text(
+        json.dumps({"reader": "scope_roofline", "args": {
+            "scope": "text/layer*/attn_core", "kernel": "softmax"}}))
+    (bench / "readers").symlink_to(spec.BENCH / "readers")
+    monkeypatch.setattr(spec, "BENCH", bench)
+    monkeypatch.setattr(spec, "ROOT", tmp_path)
+    with pytest.raises(SystemExit,
+                       match="no file benchmarks/kernels/softmax.py"):
+        spec.reader_for("softmax_roofline_pct", "per_layer")
+
+
+def test_a_byte_bound_kernel_reports_against_the_bandwidth_roofline():
+    read = spec._load_module(spec.BENCH / "readers" / "scope_roofline.py",
+                             "reader").read
+    run = fake_run()
+    needs = spec.kernel("attn_core").work(run.counters_slice, CFG)
+    # q, k, v read and the context written, bf16, six layers
+    assert needs["hbm_bytes"] == 4 * 2 * 256 * 512 * 768 * 2 * 6
+    assert read(run, scope="text/layer*/attn_core", kernel="attn_core",
+                peak="hbm_bytes_per_s") == pytest.approx(
+        100 * needs["hbm_bytes"] / 819e9 / 0.18)
+    assert read(run, scope="text/layer*/attn_core", kernel="attn_core",
+                peak="int8_ops_per_s") == pytest.approx(
+        100 * needs["flops"] / 393e12 / 0.18)
+    ffn = spec.kernel("ffn").work(run.counters_slice, CFG)
+    assert ffn["hbm_bytes"] == 6 * (2 * 2 * 256 * 512 * 768 * 2
+                                    + 2 * 2 * 768 * 3072 * 4)
+    with pytest.raises(ValueError, match="no published"):
+        read(run, scope="text/layer*/ffn", kernel="ffn", peak="fp4_per_s")
 
 
 def test_counter_and_span_readers():
@@ -374,7 +494,7 @@ def test_for_run_says_once_why_it_found_nothing(capsys, monkeypatch):
     del run.extra["scope_trace"]
     no_scope = [(p, l, n, a, d, "") for p, l, n, a, d, _ in hand_made()]
     monkeypatch.setattr(T, "newest_xplane", lambda d: "x")
-    monkeypatch.setattr(S, "read_xplane", lambda p: no_scope)
+    monkeypatch.setattr(S, "read_xplane", lambda p, vocabulary: no_scope)
     assert S.for_run(run) is None and S.for_run(run) is None
     out = capsys.readouterr().out
     assert out.count("no device operation carries a named scope") == 1

@@ -82,13 +82,20 @@ def test_a_name_without_a_file_stops_the_run():
         spec.reader_for("no_such_metric", "per_layer")
     with pytest.raises(SystemExit, match="no file"):
         spec.arrival("no-such-kind")
+    for resolve, path in (
+            (lambda: spec.builder({"builder": "no_such_builder"}),
+             "benchmarks/configs/no_such_builder.py"),
+            (lambda: spec.reference("no_such_reference"),
+             "benchmarks/configs/no_such_reference.py"),
+            (lambda: spec.kernel("no_such_kernel"),
+             "benchmarks/kernels/no_such_kernel.py")):
+        with pytest.raises(SystemExit, match=f"no file {path}"):
+            resolve()
 
 
 def test_config_files_are_used_once_hold_the_published_sizes_and_defaults():
     from realtime_fraud_detection_tpu.models.bert import BertConfig
     from realtime_fraud_detection_tpu.stream import JobConfig
-
-    from benchmarks.harness import system
 
     for bm in (BM, ALL):
         files = [c["file"] for c in bm["configs"]]
@@ -97,10 +104,24 @@ def test_config_files_are_used_once_hold_the_published_sizes_and_defaults():
         assert used == {c["name"] for c in bm["configs"]}
     for c in ALL["configs"]:
         cfg = json.loads((spec.ROOT / c["file"]).read_text())
-        assert c["reduced"] == [] == cfg["reduced"]
+        assert c["reduced"] == cfg["reduced"]
         assert cfg["source"] == c["source"]
-        # distilbert-base-uncased's config.json is what BertConfig() holds
-        assert system.bert_config(cfg) == BertConfig()
+        # the file runs the source's sizes (it lists them under
+        # ``published``), but for the keys it says it reduced
+        assert cfg["published"], c["name"]
+        for key, value in cfg["published"].items():
+            if key in cfg["reduced"]:
+                assert cfg[key] != value, key
+            else:
+                assert cfg[key] == value, key
+        assert set(cfg["reduced"]) <= set(cfg["published"])
+        builder = spec.builder(cfg)
+        for name in ("make_models", "make_scorer", "matmul_flops_per_batch"):
+            assert callable(getattr(builder, name)), name
+        assert set(builder.TINY) <= set(cfg)
+        if cfg.get("builder", spec.DEFAULT_BUILDER) == spec.DEFAULT_BUILDER:
+            # distilbert-base-uncased's config.json is what BertConfig() holds
+            assert builder.bert_config(cfg) == BertConfig()
         # what `rtfd run-job` builds with no flags, plus the pool switches
         default = JobConfig()
         for key, value in cfg["job"].items():
