@@ -85,7 +85,8 @@ def _derive_model_shapes(params: Any) -> Optional[Dict[str, Any]]:
         # the word embedding is a bare f32 table, or the weight-only int8
         # form {"qe": i8[rows, h], "scale": f32[rows]} (models/quant.py) —
         # the hidden size lives in the table either way
-        word_emb = params.bert["word_emb"]
+        # (the MoE text encoder, models/olmoe.py, calls it embed_tokens)
+        word_emb = params.bert.get("word_emb", params.bert.get("embed_tokens"))
         if isinstance(word_emb, dict):
             word_emb = word_emb["qe"]
         return {
@@ -277,8 +278,10 @@ class CheckpointManager:
         want = {
             "bert_hidden": None if bert_config is None
             else bert_config.hidden_size,
+            # an OlmoeConfig text branch spells its depth the source's way
             "bert_layers": None if bert_config is None
-            else bert_config.num_layers,
+            else getattr(bert_config, "num_layers", None)
+            or bert_config.num_hidden_layers,
             "feature_dim": feature_dim,
             "node_dim": node_dim,
         }

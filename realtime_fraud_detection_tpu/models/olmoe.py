@@ -1,0 +1,318 @@
+"""OLMoE-1B-7B's transformer block as a text encoder, in pure JAX.
+
+The layer equations are those of ``allenai/OLMoE-1B-7B-0125-Instruct``'s
+``config.json`` and of the Hugging Face ``modeling_olmoe.py`` it names
+(``OlmoeConfig`` keeps the source's key names):
+
+- pre-norm residual blocks with RMSNorm (float32, ``rms_norm_eps``);
+- attention: bias-free q/k/v/o projections, RMSNorm on q and on k over the
+  whole hidden width before the head split (QK-norm), rotary positions
+  (rotate-half, ``inv_freq = rope_theta ** (-2i / head_dim)``, positions
+  0..T-1), causal mask AND key mask, ``softmax(q k^T / sqrt(head_dim)) v``;
+- a sparse mixture of experts in place of the dense FFN: ``p = softmax(x
+  W_router)`` over all ``num_experts`` in float32, the ``num_experts_per_tok``
+  largest taken WITHOUT renormalising (``norm_topk_prob`` false), each a
+  SwiGLU expert ``down(silu(gate(x)) * up(x))`` of width
+  ``intermediate_size``. Every token reaches all of its experts: there is no
+  capacity and no token is dropped;
+- after the last layer the final RMSNorm; the text is right-padded
+  (``models/tokenizer.py``), so the pooled position is the last real token;
+  a bias-free ``Linear(hidden -> 2)`` head, ``p_text = softmax(logits)[1]``
+  (the family's sequence-classification convention; the LM head is not held:
+  the branch emits a class probability, not tokens).
+
+The expert layer is computed the dropless way: the (token, expert) pairs are
+sorted by expert, the tokens' rows gathered into that order, three grouped
+matmuls (``ops/grouped_matmul.py``) run over the ragged groups, and the rows
+are gathered home and summed with their router weights. ``route`` and
+``apply_experts`` are the two halves, held separately by the tests.
+
+Precision: weights stored bfloat16 (the checkpoint's dtype), bfloat16 matmul
+operands with float32 accumulation, float32 norms, softmaxes, RoPE and
+residual stream; the router matmul in float32 at ``Precision.HIGHEST`` (a
+fifth of a percent of the arithmetic; top-k is a discrete choice that
+rounding flips).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from realtime_fraud_detection_tpu.obs import scopes
+from realtime_fraud_detection_tpu.ops.attention import (
+    attention_reference,
+    merge_heads,
+    split_heads,
+)
+from realtime_fraud_detection_tpu.ops.grouped_matmul import grouped_matmul
+
+
+@dataclasses.dataclass(frozen=True)
+class OlmoeConfig:
+    """``config.json`` of OLMoE-1B-7B-0125-Instruct, under its own keys."""
+
+    vocab_size: int = 50304
+    hidden_size: int = 2048
+    intermediate_size: int = 1024       # width of ONE expert
+    num_hidden_layers: int = 16
+    num_attention_heads: int = 16
+    num_key_value_heads: int = 16
+    num_experts: int = 64
+    num_experts_per_tok: int = 8
+    norm_topk_prob: bool = False
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 10000.0
+    max_position_embeddings: int = 4096
+    initializer_range: float = 0.02
+    num_labels: int = 2
+
+    def __post_init__(self) -> None:
+        if self.num_key_value_heads != self.num_attention_heads:
+            raise ValueError(
+                "OlmoeConfig: grouped-query attention is not implemented "
+                f"(num_key_value_heads {self.num_key_value_heads} != "
+                f"num_attention_heads {self.num_attention_heads})")
+        if self.norm_topk_prob:
+            raise ValueError("OlmoeConfig: norm_topk_prob true is not the "
+                             "published model and is not implemented")
+        if self.hidden_size % self.num_attention_heads:
+            raise ValueError("hidden_size must divide into the heads")
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+
+TINY_OLMOE = OlmoeConfig(
+    vocab_size=30522, hidden_size=128, intermediate_size=64,
+    num_hidden_layers=2, num_attention_heads=2, num_key_value_heads=2,
+    num_experts=8, num_experts_per_tok=2)
+
+
+def init_olmoe_params(key: jax.Array, config: OlmoeConfig) -> Dict:
+    """Normal(``initializer_range``) weights drawn directly in bfloat16, one
+    tensor at a time: traced into one jitted init, no float32 copy of the
+    expert weights ever exists. Norm weights are ones (float32); the head is
+    float32 (two columns)."""
+    h, i_, e = (config.hidden_size, config.intermediate_size,
+                config.num_experts)
+    std = config.initializer_range
+
+    def w(k, shape, dtype=jnp.bfloat16):
+        return (jax.random.normal(k, shape, jnp.float32) * std).astype(dtype)
+
+    def ones():
+        return jnp.ones((h,), jnp.float32)
+
+    k_emb, k_head, k_layers = jax.random.split(key, 3)
+    layers = []
+    for lk in jax.random.split(k_layers, config.num_hidden_layers):
+        k = jax.random.split(lk, 8)
+        layers.append({
+            "input_layernorm": ones(),
+            "q_proj": w(k[0], (h, h)), "k_proj": w(k[1], (h, h)),
+            "v_proj": w(k[2], (h, h)), "o_proj": w(k[3], (h, h)),
+            "q_norm": ones(), "k_norm": ones(),
+            "post_attention_layernorm": ones(),
+            "router": w(k[4], (h, e)),
+            "gate_proj": w(k[5], (e, h, i_)),
+            "up_proj": w(k[6], (e, h, i_)),
+            "down_proj": w(k[7], (e, i_, h)),
+        })
+    return {
+        "embed_tokens": w(k_emb, (config.vocab_size, h)),
+        "layers": layers,
+        "norm": ones(),
+        "score": w(k_head, (h, config.num_labels), jnp.float32),
+    }
+
+
+def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
+    x32 = x.astype(jnp.float32)
+    return x32 * jax.lax.rsqrt(
+        jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps) * weight
+
+
+def _proj(x: jax.Array, w: jax.Array) -> jax.Array:
+    """Bias-free projection: bf16 operands, f32 accumulation and result."""
+    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
+
+
+def rope_tables(seq_len: int, head_dim: int, theta: float
+                ) -> Tuple[np.ndarray, np.ndarray]:
+    """cos and sin ``f32[T, head_dim]`` of positions 0..T-1: the half-width
+    frequencies repeated over both halves (rotate-half layout). Constants of
+    the program, computed on the host in float64."""
+    inv_freq = theta ** (-np.arange(0, head_dim, 2, dtype=np.float64)
+                         / head_dim)
+    angles = np.arange(seq_len, dtype=np.float64)[:, None] * inv_freq[None]
+    angles = np.concatenate([angles, angles], axis=-1)
+    return (np.cos(angles).astype(np.float32),
+            np.sin(angles).astype(np.float32))
+
+
+def apply_rope(x: jax.Array, cos, sin) -> jax.Array:
+    """``x * cos + rotate_half(x) * sin`` on ``[B, heads, T, head_dim]``."""
+    half = x.shape[-1] // 2
+    rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+    return x * cos + rotated * sin
+
+
+def router_probs(x: jax.Array, w_router: jax.Array) -> jax.Array:
+    """``softmax(x W_router)`` over ALL experts, float32 at the highest
+    matmul precision: ``f32[N, num_experts]``."""
+    logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    return jax.nn.softmax(logits, axis=-1)
+
+
+def route(x: jax.Array, w_router: jax.Array, top_k: int
+          ) -> Tuple[jax.Array, jax.Array]:
+    """``(experts i32[N, top_k], weights f32[N, top_k])``: each token's
+    ``top_k`` largest router probabilities, NOT renormalised."""
+    weights, experts = jax.lax.top_k(router_probs(x, w_router), top_k)
+    return experts.astype(jnp.int32), weights
+
+
+def apply_experts(layer: Dict, x: jax.Array, experts: jax.Array,
+                  weights: jax.Array, *, use_pallas: bool = False,
+                  kernel_interpret: bool = False
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """``sum_e weights[n, e] * expert_e(x[n])`` for the routed ``experts``:
+    ``(f32[N, hidden], group_sizes i32[num_experts])``. ``x`` is ``[N,
+    hidden]``. No capacity: every (token, expert) pair is computed."""
+    n, top_k = experts.shape
+    num_experts = layer["gate_proj"].shape[0]
+    with jax.named_scope(scopes.ROUTER):
+        # the (token, expert) pairs in expert order; a stable sort keeps a
+        # group's rows in token order
+        flat = experts.reshape(-1)
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+        group_sizes = jnp.sum(
+            flat[:, None] == jnp.arange(num_experts, dtype=jnp.int32)[None],
+            axis=0, dtype=jnp.int32)
+        # where each pair's row went: the inverse permutation
+        home = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=jnp.int32))
+    with jax.named_scope(scopes.EXPERTS_DISPATCH):
+        # matmul operands take the stored dtype of the weights (bfloat16
+        # as deployed; float32 weights make a float32 program, for tests)
+        rows = x.astype(layer["gate_proj"].dtype)[order // top_k]  # [N*k, H]
+    with jax.named_scope(scopes.EXPERTS_MATMUL):
+        gm = dict(use_pallas=use_pallas, interpret=kernel_interpret)
+        gate = grouped_matmul(rows, layer["gate_proj"], group_sizes, **gm)
+        up = grouped_matmul(rows, layer["up_proj"], group_sizes, **gm)
+        act = (jax.nn.silu(gate) * up).astype(layer["down_proj"].dtype)
+        out = grouped_matmul(act, layer["down_proj"], group_sizes, **gm)
+    with jax.named_scope(scopes.EXPERTS_COMBINE):
+        back = out[home].reshape(n, top_k, -1)                 # token order
+        y = jnp.sum(back * weights[:, :, None], axis=1)
+    return y, group_sizes
+
+
+def olmoe_attention(layer: Dict, h: jax.Array, attention_mask: jax.Array,
+                    config: OlmoeConfig, cos, sin) -> jax.Array:
+    """``h + o_proj(attn(...))`` on ``h`` ``f32[B, T, hidden]``: the first
+    half of a block."""
+    eps, heads = config.rms_norm_eps, config.num_attention_heads
+    with jax.named_scope(scopes.LN):
+        x = rms_norm(h, layer["input_layernorm"], eps)
+    with jax.named_scope(scopes.ATTN_PROJ):
+        q = rms_norm(_proj(x, layer["q_proj"]), layer["q_norm"], eps)
+        k = rms_norm(_proj(x, layer["k_proj"]), layer["k_norm"], eps)
+        v = _proj(x, layer["v_proj"])
+        # the barrier changes no value. Without it the TPU compiler folds the
+        # norm's last multiply into a layout fusion of its own ahead of the
+        # head split, which carries no op_name: 0.8 ms a layer that a device
+        # trace can give to no scope (2.5% of the busy time; PERF.md, PR 26).
+        # With it the same pass is a fusion rooted at this scope's multiply.
+        q, k = jax.lax.optimization_barrier((q, k))
+        qh = apply_rope(split_heads(q, heads), cos, sin)
+        kh = apply_rope(split_heads(k, heads), cos, sin)
+        vh = split_heads(v, heads)
+    with jax.named_scope(scopes.ATTN_CORE):
+        ctx = attention_reference(qh, kh, vh, attention_mask, causal=True)
+    with jax.named_scope(scopes.ATTN_PROJ):
+        attn_out = _proj(merge_heads(ctx), layer["o_proj"])
+    with jax.named_scope(scopes.LN):
+        return h + attn_out
+
+
+def olmoe_layer(layer: Dict, h: jax.Array, attention_mask: jax.Array,
+                config: OlmoeConfig, cos, sin, *, use_pallas: bool = False,
+                kernel_interpret: bool = False
+                ) -> Tuple[jax.Array, jax.Array]:
+    """One pre-norm block on ``h`` ``f32[B, T, hidden]``; also the largest
+    expert group of the layer (``i32[]``)."""
+    b, t, width = h.shape
+    h = olmoe_attention(layer, h, attention_mask, config, cos, sin)
+    with jax.named_scope(scopes.LN):
+        x = rms_norm(h, layer["post_attention_layernorm"],
+                     config.rms_norm_eps).reshape(b * t, width)
+    with jax.named_scope(scopes.ROUTER):
+        experts, weights = route(x, layer["router"],
+                                 config.num_experts_per_tok)
+    y, group_sizes = apply_experts(
+        layer, x, experts, weights, use_pallas=use_pallas,
+        kernel_interpret=kernel_interpret)
+    with jax.named_scope(scopes.LN):
+        h = h + y.reshape(b, t, width)
+    return h, jnp.max(group_sizes)
+
+
+def olmoe_encode(params: Dict, input_ids: jax.Array,
+                 attention_mask: jax.Array, config: OlmoeConfig, *,
+                 use_pallas: bool = False, kernel_interpret: bool = False
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """Hidden states before the final norm ``f32[B, T, hidden]`` and the
+    largest expert group of each layer ``i32[layers]``."""
+    t = input_ids.shape[1]
+    cos, sin = rope_tables(t, config.head_dim, config.rope_theta)
+    with jax.named_scope(scopes.EMBED):
+        h = params["embed_tokens"][input_ids].astype(jnp.float32)
+    peaks = []
+    for i, layer in enumerate(params["layers"]):
+        with jax.named_scope(scopes.layer_scope(i)):
+            h, peak = olmoe_layer(layer, h, attention_mask, config, cos, sin,
+                                  use_pallas=use_pallas,
+                                  kernel_interpret=kernel_interpret)
+        peaks.append(peak)
+    return h, jnp.stack(peaks)
+
+
+def olmoe_logits(params: Dict, input_ids: jax.Array,
+                 attention_mask: jax.Array, config: OlmoeConfig, *,
+                 use_pallas: bool = False, kernel_interpret: bool = False
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """Sequence-classification logits ``f32[B, num_labels]`` from the last
+    real token, and ``i32[layers]`` largest expert group per layer."""
+    hidden, peaks = olmoe_encode(params, input_ids, attention_mask, config,
+                                 use_pallas=use_pallas,
+                                 kernel_interpret=kernel_interpret)
+    with jax.named_scope(scopes.HEAD):
+        last = jnp.maximum(
+            jnp.sum(attention_mask.astype(jnp.int32), axis=-1) - 1, 0)
+        pooled = jnp.take_along_axis(hidden, last[:, None, None], axis=1)[:, 0]
+        pooled = rms_norm(pooled, params["norm"], config.rms_norm_eps)
+        logits = jnp.dot(pooled, params["score"],
+                         precision=jax.lax.Precision.HIGHEST)
+    return logits, peaks
+
+
+def olmoe_predict(params: Dict, input_ids: jax.Array,
+                  attention_mask: jax.Array, config: OlmoeConfig, *,
+                  use_pallas: bool = False, kernel_interpret: bool = False,
+                  with_stats: bool = False):
+    """Fraud probability ``f32[B]`` = ``softmax(logits)[:, 1]``; with
+    ``with_stats`` also the ``i32[layers]`` largest expert group per layer
+    (what ``StreamJob.counters['expert_peak_rows']`` sums)."""
+    logits, peaks = olmoe_logits(params, input_ids, attention_mask, config,
+                                 use_pallas=use_pallas,
+                                 kernel_interpret=kernel_interpret)
+    p = jax.nn.softmax(logits, axis=-1)[:, 1]
+    return (p, peaks) if with_stats else p

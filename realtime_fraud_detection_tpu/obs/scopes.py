@@ -43,6 +43,22 @@ LN = "ln"                    # both layer norms with their residual adds
 HEAD = "head"
 LAYER_SCOPES: Tuple[str, ...] = (ATTN_PROJ, ATTN_CORE, FFN, LN)
 
+# ---- device: under ``text`` where the encoder is models/olmoe.py. ``embed``,
+# ``head``, ``layer<i>`` and ``attn_core`` as above; ``attn_proj`` also holds
+# QK-norm and RoPE, ``ln`` the RMSNorms and residual adds; the sparse block
+# stands where ``ffn`` does
+ROUTER = "router"            # gate matmul, softmax, top-k, sort and offsets
+EXPERTS = "experts"
+EXPERTS_DISPATCH_PART = "dispatch"   # rows gathered into expert order
+EXPERTS_MATMUL_PART = "matmul"       # grouped gate, up, SiLU*, down
+EXPERTS_COMBINE_PART = "combine"     # rows home, weighted, summed
+EXPERTS_PARTS: Tuple[str, ...] = (EXPERTS_DISPATCH_PART, EXPERTS_MATMUL_PART,
+                                  EXPERTS_COMBINE_PART)
+EXPERTS_DISPATCH, EXPERTS_MATMUL, EXPERTS_COMBINE = (
+    f"{EXPERTS}/{part}" for part in EXPERTS_PARTS)
+MOE_LAYER_SCOPES: Tuple[str, ...] = (ATTN_PROJ, ATTN_CORE, ROUTER, EXPERTS,
+                                     LN)
+
 
 def layer_scope(i: int) -> str:
     return f"{LAYER}{i}"

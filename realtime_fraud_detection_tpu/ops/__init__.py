@@ -19,6 +19,11 @@ from realtime_fraud_detection_tpu.ops.epilogue import (  # noqa: F401
     epilogue_supported,
     fused_epilogue,
 )
+from realtime_fraud_detection_tpu.ops.grouped_matmul import (  # noqa: F401
+    grouped_matmul,
+    grouped_matmul_reference,
+    grouped_matmul_supported,
+)
 from realtime_fraud_detection_tpu.ops.megakernel import (  # noqa: F401
     fused_megakernel,
     mega_launch_accounting,

@@ -39,13 +39,19 @@ LANES = 128
 
 
 def attention_reference(
-    q: jax.Array, k: jax.Array, v: jax.Array, key_mask: jax.Array | None = None
+    q: jax.Array, k: jax.Array, v: jax.Array,
+    key_mask: jax.Array | None = None, causal: bool = False,
 ) -> jax.Array:
-    """Plain XLA attention (numerics oracle + CPU fallback). [B,H,S,D]."""
+    """Plain XLA attention (numerics oracle + CPU fallback). [B,H,S,D].
+    ``causal``: a query also sees no key after its own position."""
     d = q.shape[-1]
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) / np.sqrt(d)
     if key_mask is not None:
         scores = jnp.where(key_mask[:, None, None, :], scores, NEG_INF)
+    if causal:
+        t_q, t_k = scores.shape[-2:]
+        scores = jnp.where(np.tril(np.ones((t_q, t_k), bool)), scores,
+                           NEG_INF)
     weights = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", weights.astype(v.dtype), v)
 

@@ -139,6 +139,13 @@ def bert_context_parallel_predict(
     """
     from jax.sharding import NamedSharding
 
+    from realtime_fraud_detection_tpu.models.bert import BertConfig
+
+    if not isinstance(config, BertConfig):
+        raise ValueError(
+            "parallel/context.bert_context_parallel_predict rings "
+            "DistilBERT's bidirectional attention; it does not hold a "
+            f"{type(config).__name__} encoder (causal, RoPE)")
     ids = jax.device_put(input_ids, NamedSharding(mesh, P(DATA_AXIS, SEQ_AXIS)))
     mask = jax.device_put(
         attention_mask, NamedSharding(mesh, P(DATA_AXIS, SEQ_AXIS)))

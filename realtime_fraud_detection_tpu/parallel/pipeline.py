@@ -161,10 +161,16 @@ def bert_pipeline_encode(
     (tests/test_parallel.py pins it).
     """
     from realtime_fraud_detection_tpu.models.bert import (
+        BertConfig,
         bert_embed,
         bert_layer,
     )
 
+    if not isinstance(config, BertConfig):
+        raise ValueError(
+            "parallel/pipeline.bert_pipeline_encode pipelines DistilBERT's "
+            f"post-LN blocks; it does not hold a {type(config).__name__} "
+            "encoder")
     n_stages = mesh.shape[axis]
     if config.num_layers % n_stages:
         raise ValueError(

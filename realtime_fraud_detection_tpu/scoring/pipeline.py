@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +45,11 @@ from realtime_fraud_detection_tpu.models.isolation_forest import (
     iforest_predict,
 )
 from realtime_fraud_detection_tpu.models.lstm import init_lstm_params, lstm_logits
+from realtime_fraud_detection_tpu.models.olmoe import (
+    OlmoeConfig,
+    init_olmoe_params,
+    olmoe_predict,
+)
 from realtime_fraud_detection_tpu.models.trees import (
     TreeEnsemble,
     tree_ensemble_predict,
@@ -64,6 +69,42 @@ MODEL_NAMES: tuple[str, ...] = (
     "isolation_forest",
 )
 NUM_MODELS = len(MODEL_NAMES)
+
+# The text branch's configuration picks its encoder: a ``BertConfig`` the
+# dense DistilBERT-style one (models/bert.py), an ``OlmoeConfig`` the sparse
+# mixture-of-experts one (models/olmoe.py). The argument, the static jit
+# argument and the ``ScoringModels`` field keep the name ``bert``:
+# checkpoints, ``MODEL_NAMES`` and the benchmark's references read them.
+TextConfig = Union[BertConfig, OlmoeConfig]
+
+
+def init_text_params(key: jax.Array, config: TextConfig) -> Dict[str, Any]:
+    if isinstance(config, OlmoeConfig):
+        return init_olmoe_params(key, config)
+    return init_bert_params(key, config)
+
+
+def text_predict(params: Dict[str, Any], input_ids: jax.Array,
+                 attention_mask: jax.Array, config: TextConfig, *,
+                 use_pallas: bool = False, dequant_kernel: str = "off",
+                 kernel_interpret: bool = False
+                 ) -> Tuple[jax.Array, Optional[jax.Array]]:
+    """The text branch's probability ``f32[B]`` from the encoder
+    ``config``'s class names, and that encoder's per-launch statistics:
+    ``i32[layers]`` largest expert group for the MoE encoder, ``None`` for
+    the dense one (whose program is then what it was)."""
+    if isinstance(config, OlmoeConfig):
+        if dequant_kernel != "off":
+            raise ValueError(
+                "KernelSettings.dequant_matmul is DistilBERT's int8 plane; "
+                "the OLMoE encoder has no quantized form")
+        return olmoe_predict(params, input_ids, attention_mask, config,
+                             use_pallas=use_pallas,
+                             kernel_interpret=kernel_interpret,
+                             with_stats=True)
+    return bert_predict(params, input_ids, attention_mask, config,
+                        use_pallas=use_pallas, dequant_kernel=dequant_kernel,
+                        kernel_interpret=kernel_interpret), None
 
 
 @struct.dataclass
@@ -116,7 +157,7 @@ class ScoreBatch:
 
 def init_scoring_models(
     key: jax.Array,
-    bert_config: BertConfig = TINY_CONFIG,
+    bert_config: TextConfig = TINY_CONFIG,
     feature_dim: int = 64,
     node_dim: int = 16,
     n_trees: int = 100,
@@ -139,7 +180,7 @@ def init_scoring_models(
         lstm=init_lstm_params(k_lstm, feature_dim=feature_dim),
         gnn=init_gnn_params(k_gnn, node_dim=node_dim, txn_dim=feature_dim,
                             typed=gnn_typed),
-        bert=init_bert_params(k_bert, bert_config),
+        bert=init_text_params(k_bert, bert_config),
     )
 
 
@@ -157,7 +198,7 @@ def _score_fused_impl(
     batch: ScoreBatch,
     params: EnsembleParams,
     model_valid: jax.Array,          # bool[M] — branch failure mask (§2.2)
-    bert_config: BertConfig = TINY_CONFIG,
+    bert_config: TextConfig = TINY_CONFIG,
     use_pallas: bool = False,
     with_model_preds: bool = True,
     tree_kernel: str = "gather",     # quantized plane (QuantSettings):
@@ -186,7 +227,7 @@ def _score_fused_impl(
         p_lstm = jax.nn.sigmoid(
             lstm_logits(models.lstm, batch.history, batch.history_len))
     with jax.named_scope(scopes.TEXT):
-        p_text = bert_predict(
+        p_text, text_stats = text_predict(
             models.bert, batch.token_ids, batch.token_mask,
             bert_config, use_pallas=use_pallas,
             dequant_kernel=dequant_kernel,
@@ -229,6 +270,8 @@ def _score_fused_impl(
         out.update(_key_factors(batch.txn))
     if with_model_preds:
         out["model_predictions"] = preds
+    if text_stats is not None:
+        out["text_stats"] = text_stats
     return out
 
 
@@ -274,7 +317,7 @@ def _score_fused_packed_impl(
     params: EnsembleParams,
     model_valid: jax.Array,
     blob_bf16: Optional[jax.Array] = None,  # bf16[B, Wh] — half-width leaves
-    bert_config: BertConfig = TINY_CONFIG,
+    bert_config: TextConfig = TINY_CONFIG,
     use_pallas: bool = False,
     tree_kernel: str = "gather",
     iforest_kernel: str = "gather",
@@ -289,7 +332,9 @@ def _score_fused_packed_impl(
     This entry takes the microbatch as the three packed buffers from
     ``core.packing.pack_tree`` (one h2d payload) and returns the §2.7
     response fields as ONE f32[B, 8+M] matrix (one d2h payload) laid out per
-    ``OUT_COLUMNS`` + model_predictions. XLA fuses the unpack slices into
+    ``OUT_COLUMNS`` + model_predictions — and, with an ``OlmoeConfig`` only,
+    a second small output beside it, ``(matrix, i32[layers])``: the largest
+    expert group of each layer. XLA fuses the unpack slices into
     the branch consumers, so the repack costs nothing on-device. What the
     transfer count is worth on local hardware is not measured.
     """
@@ -306,6 +351,10 @@ def _score_fused_packed_impl(
         batch = jax.tree.map(
             lambda x: x.astype(jnp.float32) if x.dtype == jnp.bfloat16
             else x, batch)
+    if megakernel == "pallas" and isinstance(bert_config, OlmoeConfig):
+        raise ValueError(
+            "KernelSettings.megakernel fuses the DistilBERT text branch; it "
+            "does not hold the OLMoE encoder")
     if megakernel == "pallas" and mega_valid is not None:
         # persistent megakernel (ops/megakernel.py): score the whole
         # microbatch in ONE Pallas program whose output IS the extended
@@ -349,7 +398,10 @@ def _score_fused_packed_impl(
             parts.append(jnp.stack(
                 [out["rule_decision"].astype(jnp.float32),
                  out["rule_risk"].astype(jnp.float32)], axis=1))
-        return jnp.concatenate(parts, axis=1)
+        packed = jnp.concatenate(parts, axis=1)
+    if "text_stats" in out:
+        return packed, out["text_stats"]
+    return packed
 
 
 _PACKED_STATIC = ("spec", "bert_config", "use_pallas", "tree_kernel",

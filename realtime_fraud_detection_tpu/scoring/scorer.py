@@ -38,7 +38,8 @@ from realtime_fraud_detection_tpu.features.rules import (
     RISK_LEVEL_NAMES,
 )
 from realtime_fraud_detection_tpu.features.schema import encode_transactions
-from realtime_fraud_detection_tpu.models.bert import BertConfig, TINY_CONFIG
+from realtime_fraud_detection_tpu.models.bert import TINY_CONFIG
+from realtime_fraud_detection_tpu.models.olmoe import OlmoeConfig
 from realtime_fraud_detection_tpu.models.text import combined_text
 from realtime_fraud_detection_tpu.models.tokenizer import FraudTokenizer
 from realtime_fraud_detection_tpu.obs import scopes
@@ -50,6 +51,7 @@ from realtime_fraud_detection_tpu.scoring.pipeline import (
     ScoreBatch,
     ScorerConfig,
     ScoringModels,
+    TextConfig,
     init_scoring_models,
     score_fused,
     score_fused_packed,
@@ -114,6 +116,17 @@ class PendingScore:
     token_slots: int = 0
     token_slots_sq: int = 0
     real_tokens: int = 0
+    # The MoE text encoder only (models/olmoe.py; 0 / None otherwise):
+    # ``expert_rows`` = rows launched into the grouped expert matmuls
+    # (slots x experts per token x layers, counted at dispatch);
+    # ``text_stats`` = the program's second output, i32[layers] largest
+    # expert group, read at finalize into ``expert_peak_rows`` (sum over
+    # layers of largest group x num_experts: what the launch would cost if
+    # every group were as large as the largest). Their ratio is 1.0 under
+    # even routing.
+    expert_rows: int = 0
+    expert_peak_rows: int = 0
+    text_stats: Optional[Any] = None
 
 
 class _EntityIndex:
@@ -324,15 +337,19 @@ class FraudScorer:
         models: Optional[ScoringModels] = None,
         mesh=None,
         scorer_config: Optional[ScorerConfig] = None,
-        bert_config: BertConfig = TINY_CONFIG,
+        bert_config: TextConfig = TINY_CONFIG,
         seed: int = 0,
         state_client=None,
         stores=None,
     ):
         self.config = config or Config()
         self.sc = scorer_config or ScorerConfig()
+        # the text branch's configuration picks its encoder: a BertConfig
+        # or an OlmoeConfig (scoring/pipeline.text_predict)
         self.bert_config = bert_config
+        self._moe_text = isinstance(bert_config, OlmoeConfig)
         self.mesh = mesh if mesh is not None else build_mesh()
+        self._refuse_bert_only_planes()
         # feature extraction needs JAX's CPU backend next to the accelerator:
         # a process without one fails HERE, with a message, not as all-ERROR
         # results inside the stream job's degradation path
@@ -579,12 +596,50 @@ class FraudScorer:
             self._mv_cache = (mv.copy(), jax.device_put(mv))
         return self._mv_cache[1]
 
+    def _refuse_bert_only_planes(self) -> None:
+        """The planes written for the DistilBERT branch's parameter layout
+        refuse an ``OlmoeConfig`` by name instead of miscomputing."""
+        if not self._moe_text:
+            return
+        quant = self.config.quant
+        kernels = getattr(self.config, "kernels", None) or KernelSettings()
+        refused = None
+        if quant.bert_mode() == "int8":
+            refused = ("QuantSettings(bert_weights='int8') quantizes "
+                       "DistilBERT's dense layers (models/quant.py)")
+        elif kernels.enabled and kernels.dequant_matmul == "pallas":
+            refused = ("KernelSettings.dequant_matmul is the int8 "
+                       "DistilBERT branch's kernel (ops/dequant_matmul.py)")
+        elif kernels.enabled and kernels.megakernel == "pallas":
+            refused = ("KernelSettings.megakernel fuses the DistilBERT "
+                       "branch (ops/megakernel.py)")
+        elif self.mesh.devices.size > 1:
+            refused = (f"a sharded mesh of {self.mesh.devices.size} devices "
+                       "would split the batch under the grouped expert "
+                       "matmul; the OLMoE encoder runs on one device "
+                       "(build_mesh(devices=jax.devices()[:1]))")
+        if refused:
+            raise ValueError(
+                f"{refused}: not available with an OlmoeConfig text branch")
+
     # ------------------------------------------------------------- pooling
     def attach_pool(self, pool) -> None:
         """Adopt a DevicePool: subsequent dispatches route through it.
         Called by DevicePool.__init__ — construct the scorer first, then
         the pool around it."""
+        self.require_dense_text(type(pool).__name__)
         self._pool = pool
+
+    def require_dense_text(self, plane: str) -> None:
+        """Raise where ``plane`` (a pool class's name) is asked of a scorer
+        whose text branch is the MoE encoder: the pools dispatch the dense
+        program's single result, over devices the MoE program is not split
+        across."""
+        if self._moe_text:
+            raise ValueError(
+                f"{plane} (DevicePool / MeshExecutor) dispatches the "
+                "DistilBERT program's single result; not available with an "
+                "OlmoeConfig text branch, which runs on one chip")
 
     # --------------------------------------------------------- graph plane
     def attach_graph_fetch(self, client) -> None:
@@ -801,10 +856,12 @@ class FraudScorer:
         return cached
 
     def effective_use_pallas(self, devices: Optional[int] = None) -> bool:
-        """Whether the text branch's attention core is ASKED to be the
-        fused Pallas kernel (``bert_layer``'s traced guard still sends a
-        shape ``flash_supported`` declines to the reference, and the
-        engagement counters say so). With the kernel plane on,
+        """Whether the text branch is ASKED to run its Pallas kernel: the
+        fused attention core of the dense encoder (``bert_layer``'s traced
+        guard still sends a shape ``flash_supported`` declines to the
+        reference, and the engagement counters say so), the grouped expert
+        matmul of the MoE encoder (``ops.grouped_matmul``, same pattern).
+        With the kernel plane on,
         ``KernelSettings.attention`` decides — how a drill or an A/B forces
         either side. With it off, nothing a user sets does: the kernel runs
         where the devices are TPUs, the shape is one it takes
@@ -818,13 +875,34 @@ class FraudScorer:
         if devices is None:
             devices = self.mesh.devices.size
         return (self._platform == "tpu" and devices == 1
-                and self._flash_shape_ok())
+                and self._text_kernel_shape_ok())
 
     def _flash_shape_ok(self) -> bool:
+        """Whether the program holds the fused ATTENTION core where asked:
+        never for the MoE encoder (head_dim 128, causal), which keeps the
+        reference core."""
         from realtime_fraud_detection_tpu.ops import flash_supported
 
+        if self._moe_text:
+            return False
         return flash_supported(self.sc.text_len, self.bert_config.head_dim,
                                self.bert_config.num_heads)
+
+    def _text_kernel_shape_ok(self) -> bool:
+        """Whether the text branch has a Pallas kernel for its shapes: the
+        fused attention core for the dense encoder, the grouped expert
+        matmul for the MoE one (the smallest bucket's rows decide: every
+        larger bucket is a multiple of them)."""
+        if not self._moe_text:
+            return self._flash_shape_ok()
+        from realtime_fraud_detection_tpu.ops import grouped_matmul_supported
+
+        c = self.bert_config
+        rows = self.sc.text_len * c.num_experts_per_tok
+        return (grouped_matmul_supported(rows, c.hidden_size,
+                                         c.intermediate_size)
+                and grouped_matmul_supported(rows, c.intermediate_size,
+                                             c.hidden_size))
 
     def _record_kernel_dispatch(self, size: int) -> None:
         """Host-side mirror of the per-site kernel engagement for one
@@ -1230,8 +1308,14 @@ class FraudScorer:
             # transfer is already in flight or done, so the d2h RTT
             # overlaps the next batch's assemble instead of serializing
             # after it.
+            text_stats = None
+            if self._moe_text:
+                # the MoE program's second, small output (pipeline.py)
+                out, text_stats = out
             if self.sc.async_d2h:
                 out.copy_to_host_async()
+                if text_stats is not None:
+                    text_stats.copy_to_host_async()
         return PendingScore(records=list(records), n=n, out=out,
                             # rtfd-lint: allow[d2h] batch.features is a host-assembled ndarray
                             features=np.asarray(batch.features),
@@ -1241,7 +1325,16 @@ class FraudScorer:
                             pool_token=token, trace=trace,
                             token_slots=size * text_len,
                             token_slots_sq=size * text_len * text_len,
-                            real_tokens=real_tokens)
+                            real_tokens=real_tokens,
+                            expert_rows=self._expert_rows(size * text_len),
+                            text_stats=text_stats)
+
+    def _expert_rows(self, token_slots: int) -> int:
+        """Rows one launch sends into the grouped expert matmuls."""
+        if not self._moe_text:
+            return 0
+        c = self.bert_config
+        return token_slots * c.num_experts_per_tok * c.num_hidden_layers
 
     def finalize(self, pending: "PendingScore", now: Optional[float] = None,
                  lock=None) -> List[Dict[str, Any]]:
@@ -1271,6 +1364,10 @@ class FraudScorer:
                 out = self._pool.wait(token)
             else:
                 out = jax.device_get(pending.out)  # blocks until done
+            if pending.text_stats is not None:
+                pending.expert_peak_rows = (
+                    int(np.sum(jax.device_get(pending.text_stats)))
+                    * self.bert_config.num_experts)
         # processing time = assemble/dispatch + device wait; excludes any
         # pipeline queue wait between dispatch() returning and this call
         elapsed_ms = (pending.dispatch_ms

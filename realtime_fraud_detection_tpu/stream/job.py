@@ -284,6 +284,11 @@ class StreamJob:
             # ``batches`` is (PendingScore.token_slots): padded slots,
             # rows x text_len^2, and the real tokens among the slots
             "token_slots": 0, "token_slots_sq": 0, "real_tokens": 0,
+            # the MoE text encoder only (0 otherwise): rows launched into
+            # the grouped expert matmuls, and what they would be if every
+            # expert's group were as large as the layer's largest
+            # (PendingScore.expert_rows / expert_peak_rows)
+            "expert_rows": 0, "expert_peak_rows": 0,
         }
         self._batch_seq = 0
         # transaction_ids dispatched but not yet written back: the pipelined
@@ -555,7 +560,8 @@ class StreamJob:
                     else None)
                 feats = pending.features
                 scored_ok = True
-                for key in ("token_slots", "token_slots_sq", "real_tokens"):
+                for key in ("token_slots", "token_slots_sq", "real_tokens",
+                            "expert_rows", "expert_peak_rows"):
                     # 0 from a stand-in scorer's pending without them
                     self.counters[key] += getattr(pending, key, 0)
             except Exception as e:  # noqa: BLE001 — boundary: keep streaming

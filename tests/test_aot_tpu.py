@@ -15,6 +15,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from realtime_fraud_detection_tpu.models.bert import BertConfig, TINY_CONFIG
@@ -263,3 +264,87 @@ def test_deployed_program_holds_the_fused_core_at_bucket_256(one_chip):
         use_pallas=True).compile()
     assert compiled.as_text().count(CUSTOM_CALL) == FULL.num_layers
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+# ---- the OLMoE text encoder (models/olmoe.py) at published widths
+OLMOE_ROWS = BUCKET * 128 * 8      # bucket 256 x 128 tokens x 8 experts each
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)])
+def test_grouped_matmul_compiles_at_olmoe_widths(one_chip, k, n):
+    """The Pallas grouped matmul at the tilings ``gmm_tiling`` picks for the
+    expert FFN's two shapes: 64 ragged groups, 262,144 rows. Mosaic refuses
+    (512, 2048, 1024) here for its VMEM; what is picked has to fit."""
+    from realtime_fraud_detection_tpu.ops.grouped_matmul import (
+        gmm_tiling,
+        grouped_matmul,
+    )
+
+    assert gmm_tiling(OLMOE_ROWS, k, n)[1] * gmm_tiling(
+        OLMOE_ROWS, k, n)[2] == 1024 * 1024
+    fn = jax.jit(lambda a, b, g: grouped_matmul(a, b, g, use_pallas=True))
+    compiled = fn.lower(_sds((OLMOE_ROWS, k), jnp.bfloat16, one_chip),
+                        _sds((64, k, n), jnp.bfloat16, one_chip),
+                        _sds((64,), jnp.int32, one_chip)).compile()
+    assert compiled.as_text().count(CUSTOM_CALL) == 1
+    # the XLA form lowers to the compiler's own grouped kernel, whose custom
+    # calls carry no scope in their op_name: why the chip runs the Pallas one
+    xla = jax.jit(lambda a, b, g: grouped_matmul(a, b, g)).lower(
+        _sds((OLMOE_ROWS, k), jnp.bfloat16, one_chip),
+        _sds((64, k, n), jnp.bfloat16, one_chip),
+        _sds((64,), jnp.int32, one_chip)).compile().as_text()
+    assert 'op_name="ragged-dot' in xla
+
+
+def test_olmoe_program_compiles_with_every_large_pass_under_a_scope(one_chip):
+    """The served packed program with an ``OlmoeConfig`` (two of the
+    published layers, every width as published, bucket 256 x 128 tokens):
+    three Mosaic calls a layer, a second small output, temporaries that
+    leave room for 8 layers of weights in 16 GB — and no instruction that
+    writes 64 MB or more without a named scope in its ``op_name`` (what a
+    device trace would count as ``unscoped``)."""
+    import re
+
+    from realtime_fraud_detection_tpu.core.packing import pack_tree
+    from realtime_fraud_detection_tpu.ensemble.combine import EnsembleParams
+    from realtime_fraud_detection_tpu.models.olmoe import OlmoeConfig
+    from realtime_fraud_detection_tpu.scoring.pipeline import (
+        MODEL_NAMES,
+        ScorerConfig,
+        init_scoring_models,
+        make_example_batch,
+        score_fused_packed,
+    )
+    from realtime_fraud_detection_tpu.utils.config import Config
+
+    config = OlmoeConfig(num_hidden_layers=2)
+    models = jax.eval_shape(
+        lambda key: init_scoring_models(key, bert_config=config),
+        jax.random.PRNGKey(0))
+    blobs, spec = pack_tree(make_example_batch(
+        BUCKET, ScorerConfig(text_len=128)))
+    compiled = score_fused_packed.lower(
+        _shapes_of(models, one_chip),
+        *(_shapes_of(blobs[k], one_chip) for k in ("f32", "i32", "u8")),
+        spec=spec,
+        params=EnsembleParams.from_config(Config(), list(MODEL_NAMES)),
+        model_valid=_sds((len(MODEL_NAMES),), jnp.bool_, one_chip),
+        blob_bf16=_shapes_of(blobs["bf16"], one_chip), bert_config=config,
+        use_pallas=True).compile()
+    text = compiled.as_text()
+    assert text.count(CUSTOM_CALL) == 3 * config.num_hidden_layers
+    assert compiled.memory_analysis().temp_size_in_bytes < 5 << 30
+    entry = text[text.index("ENTRY "):]
+    sizes = {"f32": 4, "bf16": 2, "s32": 4, "u32": 4, "pred": 1}
+    unnamed = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%\S+) = (\w+)\[([\d,]+)\]\S* "
+                     r"(fusion|copy|custom-call|transpose)\(", line)
+        if not m or m.group(2) not in sizes:
+            continue
+        nbytes = sizes[m.group(2)] * int(np.prod(
+            [int(d) for d in m.group(3).split(",")]))
+        op = re.search(r'op_name="jit\([^"]*?\)/([^"]*)"', line)
+        if nbytes >= 64e6 and not (op and op.group(1).startswith("text/")):
+            unnamed.append((m.group(1), nbytes))
+    assert not unnamed, unnamed

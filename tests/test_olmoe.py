@@ -1,0 +1,593 @@
+"""The OLMoE text encoder (models/olmoe.py) against an independent plain
+``jax.numpy`` float32 reference kept in this file, its two halves and its
+kernel held separately, and the seam it enters the scorer through.
+
+The reference shares no line with the program: it computes EVERY expert for
+every token densely and masks (no sort, no groups), RoPE by the explicit
+pair formula, and runs under ``jax.default_matmul_precision("highest")``.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from realtime_fraud_detection_tpu.core.mesh import build_mesh
+from realtime_fraud_detection_tpu.models import olmoe
+from realtime_fraud_detection_tpu.models.olmoe import (
+    TINY_OLMOE,
+    OlmoeConfig,
+    apply_experts,
+    init_olmoe_params,
+    olmoe_encode,
+    olmoe_logits,
+    olmoe_predict,
+    route,
+    router_probs,
+)
+from realtime_fraud_detection_tpu.ops import (
+    attention_reference,
+    grouped_matmul,
+    grouped_matmul_supported,
+)
+from realtime_fraud_detection_tpu.ops.grouped_matmul import gmm_tiling
+
+F32 = jnp.float32
+# hidden 128, 2 layers, 2 heads of 64, 8 experts of width 64, 2 per token
+CFG = TINY_OLMOE
+B, T = 4, 16
+LENGTHS = (16, 5, 1, 9)
+
+
+# ----------------------------------------------------------- the reference
+def _ref_rms(x, w, eps):
+    return x / jnp.sqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
+
+
+def _ref_rope(x, theta):
+    """[B, heads, T, D]: pairs (i, i + D/2) rotated by pos * theta^(-2i/D)."""
+    t, d = x.shape[-2:]
+    freq = theta ** (-jnp.arange(0, d, 2, dtype=F32) / d)
+    angle = jnp.arange(t, dtype=F32)[:, None] * freq[None, :]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    a, b = x[..., :d // 2], x[..., d // 2:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+def _ref_moe(layer, x, top_k, routing=None):
+    """Dense over ALL experts, then masked: ``(y, probs, chosen mask)``."""
+    p = jax.nn.softmax(x @ layer["router"].astype(F32), axis=-1)
+    if routing is None:
+        routing = jnp.argsort(-p, axis=-1)[:, :top_k]
+    chosen = jnp.zeros_like(p).at[
+        jnp.arange(p.shape[0])[:, None], routing].set(1.0)
+    gate = jnp.einsum("nh,ehi->nei", x, layer["gate_proj"].astype(F32))
+    up = jnp.einsum("nh,ehi->nei", x, layer["up_proj"].astype(F32))
+    out = jnp.einsum("nei,eih->neh", gate * jax.nn.sigmoid(gate) * up,
+                     layer["down_proj"].astype(F32))
+    return jnp.sum((p * chosen)[:, :, None] * out, axis=1), p, chosen
+
+
+def ref_hidden(params, ids, mask, cfg, routing=None):
+    """Hidden states before the final norm and each layer's chosen-expert
+    mask; ``routing`` (one ``[tokens, top_k]`` per layer) overrides top-k."""
+    with jax.default_matmul_precision("highest"):
+        b, t = ids.shape
+        heads, eps = cfg.num_attention_heads, cfg.rms_norm_eps
+        h = params["embed_tokens"].astype(F32)[ids]
+        d = h.shape[-1] // heads
+        see = jnp.tril(jnp.ones((t, t), bool))[None, None] \
+            & jnp.asarray(mask)[:, None, None, :]
+        masks = []
+        for i, layer in enumerate(params["layers"]):
+            def split(x):
+                return x.reshape(b, t, heads, d).transpose(0, 2, 1, 3)
+
+            x = _ref_rms(h, layer["input_layernorm"], eps)
+            q = _ref_rms(x @ layer["q_proj"].astype(F32), layer["q_norm"], eps)
+            k = _ref_rms(x @ layer["k_proj"].astype(F32), layer["k_norm"], eps)
+            v = split(x @ layer["v_proj"].astype(F32))
+            q = _ref_rope(split(q), cfg.rope_theta)
+            k = _ref_rope(split(k), cfg.rope_theta)
+            s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(d)
+            w = jax.nn.softmax(jnp.where(see, s, -1e30), axis=-1)
+            ctx = jnp.einsum("bhqk,bhkd->bhqd", w, v)
+            ctx = ctx.transpose(0, 2, 1, 3).reshape(b, t, -1)
+            h = h + ctx @ layer["o_proj"].astype(F32)
+            x = _ref_rms(h, layer["post_attention_layernorm"], eps)
+            y, _, chosen = _ref_moe(
+                layer, x.reshape(b * t, -1), cfg.num_experts_per_tok,
+                None if routing is None else routing[i])
+            masks.append(chosen)
+            h = h + y.reshape(b, t, -1)
+        return h, masks
+
+
+def ref_logits(params, ids, mask, cfg, routing=None):
+    with jax.default_matmul_precision("highest"):
+        h, _ = ref_hidden(params, ids, mask, cfg, routing)
+        last = jnp.maximum(jnp.asarray(mask).sum(-1) - 1, 0)
+        pooled = _ref_rms(h[jnp.arange(h.shape[0]), last], params["norm"],
+                          cfg.rms_norm_eps)
+        return pooled @ params["score"]
+
+
+# ---------------------------------------------------------------- fixtures
+@pytest.fixture(scope="module")
+def params():
+    return jax.jit(lambda k: init_olmoe_params(k, CFG))(jax.random.PRNGKey(3))
+
+
+@pytest.fixture(scope="module")
+def params32(params):
+    """The same values, stored float32: the program's matmul operands take
+    the stored dtype, so this is the program in float32."""
+    return jax.tree.map(lambda a: a.astype(F32), params)
+
+
+@pytest.fixture(scope="module")
+def text():
+    rng = np.random.default_rng(5)
+    ids = rng.integers(0, CFG.vocab_size, (B, T)).astype(np.int32)
+    mask = np.arange(T)[None, :] < np.asarray(LENGTHS)[:, None]
+    return ids, mask
+
+
+def _program_routing(params, ids, mask, cfg):
+    """Each layer's top-k as the PROGRAM chooses it on its own hidden
+    stream, from the public pieces."""
+    cos, sin = olmoe.rope_tables(ids.shape[1], cfg.head_dim, cfg.rope_theta)
+    h = params["embed_tokens"][ids].astype(F32)
+    chosen = []
+    for layer in params["layers"]:
+        h = olmoe.olmoe_attention(layer, h, mask, cfg, cos, sin)
+        x = olmoe.rms_norm(h, layer["post_attention_layernorm"],
+                           cfg.rms_norm_eps).reshape(-1, h.shape[-1])
+        experts, weights = route(x, layer["router"], cfg.num_experts_per_tok)
+        chosen.append(experts)
+        y, _ = apply_experts(layer, x, experts, weights)
+        h = h + y.reshape(h.shape)
+    return chosen
+
+
+# ------------------------------------------------- the whole encoder, logits
+def test_stored_dtypes_are_the_checkpoints(params):
+    big = [a for a in jax.tree.leaves(params) if a.ndim >= 2]
+    assert all(a.dtype == jnp.bfloat16 for a in big
+               if a.shape != params["score"].shape)
+    assert params["score"].dtype == F32
+    assert params["layers"][0]["gate_proj"].shape == (
+        CFG.num_experts, CFG.hidden_size, CFG.intermediate_size)
+    assert params["layers"][0]["q_norm"].shape == (CFG.hidden_size,)
+
+
+@pytest.mark.parametrize("stored,atol", [
+    # float32 program against the float32 reference: only the order of the
+    # sums differs (sorted groups against dense-and-mask), a few ulps of
+    # logits of order 0.3 (measured 1.5e-7 at most)
+    ("float32", 2e-5),
+    # as deployed, bfloat16 operands with float32 accumulation: operands
+    # rounded to 8 bits of mantissa (2^-9 relative each) through 2 layers of
+    # 128- and 64-term sums move logits of order 0.3 by ~1e-3 (measured
+    # here 1.3e-3 at most over the seeds below, the routing given). The same
+    # reference with float8 operands is 2.3e-2 to 4.4e-2 away
+    # (test_a_lower_precision_would_fail): the limit sits between
+    ("bfloat16", 6e-3),
+])
+@pytest.mark.parametrize("seed", [3, 11, 2600000007])
+def test_logits_match_the_plain_reference(stored, atol, seed, text):
+    ids, mask = text
+    p = jax.jit(lambda k: init_olmoe_params(k, CFG))(jax.random.PRNGKey(seed))
+    if stored == "float32":
+        p = jax.tree.map(lambda a: a.astype(F32), p)
+    # top-k is a discrete choice: where rounding moves a logit across rank
+    # k the two sides compute different (equally valid) sums. The reference
+    # is given the program's routing, and the choice itself is held by
+    # test_route_*; in float32 the two agree on every token anyway
+    routing = _program_routing(p, ids, mask, CFG)
+    got, peaks = olmoe_logits(p, ids, mask, CFG)
+    want = ref_logits(p, ids, mask, CFG, routing)
+    assert got.shape == (B, CFG.num_labels) and peaks.shape == (
+        CFG.num_hidden_layers,)
+    np.testing.assert_allclose(got, want, atol=atol, rtol=0)
+    if stored == "float32":
+        free = ref_logits(p, ids, mask, CFG)          # its own top-k
+        np.testing.assert_allclose(got, free, atol=atol, rtol=0)
+
+
+def test_a_lower_precision_would_fail(params, text):
+    """The tolerance above is tight enough: the reference with float8
+    (e4m3) weights and activations-at-the-embedding is several limits away."""
+    import ml_dtypes
+
+    ids, mask = text
+    fp8 = jax.tree.map(
+        lambda a: np.asarray(a, np.float32).astype(
+            ml_dtypes.float8_e4m3fn).astype(np.float32) if a.ndim >= 2 else a,
+        params)
+    routing = _program_routing(params, ids, mask, CFG)
+    gap = np.abs(np.asarray(ref_logits(fp8, ids, mask, CFG, routing))
+                 - np.asarray(ref_logits(params, ids, mask, CFG, routing)))
+    assert gap.max() > 2 * 6e-3, gap.max()
+
+
+def test_predict_is_the_softmax_of_the_logits(params, text):
+    ids, mask = text
+    logits, peaks = olmoe_logits(params, ids, mask, CFG)
+    p, stats = olmoe_predict(params, ids, mask, CFG, with_stats=True)
+    np.testing.assert_allclose(p, jax.nn.softmax(logits, -1)[:, 1], atol=1e-7)
+    np.testing.assert_array_equal(stats, peaks)
+    assert olmoe_predict(params, ids, mask, CFG).shape == (B,)
+
+
+# ------------------------------------------------------- the two halves
+def test_route_probabilities_and_choice(params32):
+    layer = params32["layers"][0]
+    x = jax.random.normal(jax.random.PRNGKey(1), (64, CFG.hidden_size), F32)
+    with jax.default_matmul_precision("highest"):
+        want = jax.nn.softmax(x @ layer["router"], axis=-1)
+    np.testing.assert_allclose(router_probs(x, layer["router"]), want,
+                               atol=1e-6, rtol=0)
+    experts, weights = route(x, layer["router"], CFG.num_experts_per_tok)
+    assert experts.dtype == jnp.int32 and experts.shape == (64, 2)
+    top = np.argsort(-np.asarray(want), axis=-1)[:, :2]
+    np.testing.assert_array_equal(np.sort(experts, -1), np.sort(top, -1))
+    np.testing.assert_allclose(
+        weights, np.take_along_axis(np.asarray(want), np.asarray(experts), -1),
+        atol=1e-6)
+
+
+def test_weights_are_not_renormalised(params32):
+    """``norm_topk_prob`` false: the top-k weights are the softmax over ALL
+    experts, so they sum to less than one."""
+    layer = params32["layers"][1]
+    x = jax.random.normal(jax.random.PRNGKey(2), (32, CFG.hidden_size), F32)
+    _, weights = route(x, layer["router"], CFG.num_experts_per_tok)
+    total = np.asarray(weights).sum(-1)
+    assert (total < 0.999).all() and (total > 2.0 / CFG.num_experts).all()
+
+
+@pytest.mark.parametrize("stored,atol", [("float32", 1e-5),
+                                         ("bfloat16", 2e-3)])
+def test_apply_experts_given_the_references_routing(params, params32, stored,
+                                                    atol):
+    p = params32 if stored == "float32" else params
+    layer = p["layers"][0]
+    x = jax.random.normal(jax.random.PRNGKey(4), (96, CFG.hidden_size), F32)
+    with jax.default_matmul_precision("highest"):
+        want, probs, _ = _ref_moe(layer, x, CFG.num_experts_per_tok)
+    experts = jnp.argsort(-probs, axis=-1)[:, :2].astype(jnp.int32)
+    weights = jnp.take_along_axis(probs, experts, axis=-1)
+    got, sizes = apply_experts(layer, x, experts, weights)
+    np.testing.assert_allclose(got, want, atol=atol, rtol=0)
+    assert int(sizes.sum()) == 96 * 2          # every pair computed: no drop
+    np.testing.assert_array_equal(
+        sizes, np.bincount(np.asarray(experts).ravel(),
+                           minlength=CFG.num_experts))
+
+
+# ---------------------------------------------------- the grouped matmul
+def _loop_over_experts(lhs, rhs, sizes):
+    out, start = [], 0
+    for g, n in enumerate(sizes):
+        out.append(np.asarray(lhs[start:start + n], np.float32)
+                   @ np.asarray(rhs[g], np.float32))
+        start += n
+    return np.concatenate(out, axis=0)
+
+
+GROUPS = {
+    "even": [64, 64, 64, 64],
+    "empty_groups": [0, 200, 0, 56],
+    "one_holds_every_row": [0, 0, 256, 0],
+    "off_the_tile": [1, 127, 3, 125],
+    "first_and_last_empty": [0, 129, 127, 0],
+}
+
+
+@pytest.mark.parametrize("form", ["xla", "pallas_interpret"])
+@pytest.mark.parametrize("case", sorted(GROUPS))
+def test_grouped_matmul_against_a_loop_over_experts(case, form):
+    sizes = GROUPS[case]
+    m, k, n = sum(sizes), 128, 256
+    key = jax.random.PRNGKey(len(case))
+    lhs = jax.random.normal(key, (m, k), F32).astype(jnp.bfloat16)
+    rhs = (jax.random.normal(jax.random.fold_in(key, 1), (len(sizes), k, n),
+                             F32) * 0.1).astype(jnp.bfloat16)
+    assert grouped_matmul_supported(m, k, n)
+    got = grouped_matmul(lhs, rhs, jnp.asarray(sizes, jnp.int32),
+                         use_pallas=form != "xla", interpret=True)
+    assert got.dtype == F32 and got.shape == (m, n)
+    # bf16 operands are exact in f32; only the order of 128 f32 adds differs
+    np.testing.assert_allclose(got, _loop_over_experts(lhs, rhs, sizes),
+                               atol=1e-4, rtol=0)
+
+
+def test_the_kernel_declines_what_it_cannot_tile():
+    assert not grouped_matmul_supported(100, 128, 128)     # rows off a tile
+    assert not grouped_matmul_supported(256, 128, 64)      # N under a lane
+    assert grouped_matmul_supported(262144, 2048, 1024)
+    # the published shapes get the tilings measured on the v5e
+    assert gmm_tiling(262144, 2048, 1024) == (512, 2048, 512)
+    assert gmm_tiling(262144, 1024, 2048) == (512, 1024, 1024)
+    assert gmm_tiling(384, 128, 384) == (128, 128, 128)
+    # an unsupported shape asked for the kernel runs the XLA form
+    lhs = jnp.ones((100, 128), jnp.bfloat16)
+    rhs = jnp.ones((2, 128, 64), jnp.bfloat16)
+    out = grouped_matmul(lhs, rhs, jnp.asarray([40, 60], jnp.int32),
+                         use_pallas=True, interpret=True)
+    np.testing.assert_allclose(out, 128.0)
+
+
+def test_the_encoder_is_the_same_through_the_kernel(text):
+    """Widths the kernel tiles (hidden 128, experts of 128): the Pallas form
+    (interpreted) and the XLA form give the same encoder."""
+    cfg = dataclasses.replace(CFG, intermediate_size=128, num_hidden_layers=1)
+    p = jax.jit(lambda k: init_olmoe_params(k, cfg))(jax.random.PRNGKey(9))
+    rng = np.random.default_rng(1)
+    ids = rng.integers(0, cfg.vocab_size, (2, 64)).astype(np.int32)
+    mask = np.arange(64)[None, :] < np.asarray([64, 17])[:, None]
+    a, _ = olmoe_logits(p, ids, mask, cfg)
+    b, _ = olmoe_logits(p, ids, mask, cfg, use_pallas=True,
+                        kernel_interpret=True)
+    np.testing.assert_allclose(a, b, atol=1e-5, rtol=0)
+
+
+# ------------------------------------------------------ masks and pooling
+def test_causality_a_later_token_moves_no_earlier_position(params, text):
+    ids, mask = text
+    full = np.ones_like(mask)
+    base, _ = olmoe_encode(params, ids, full, CFG)
+    changed = ids.copy()
+    changed[:, 10] = (changed[:, 10] + 1) % CFG.vocab_size
+    moved, _ = olmoe_encode(params, changed, full, CFG)
+    np.testing.assert_allclose(moved[:, :10], base[:, :10], atol=1e-6, rtol=0)
+    assert np.abs(np.asarray(moved[:, 10:] - base[:, 10:])).max() > 1e-3
+
+
+def test_padding_moves_nothing(params, text):
+    ids, mask = text
+    base = olmoe_predict(params, ids, mask, CFG)
+    noisy = np.where(mask, ids, (ids + 7) % CFG.vocab_size)
+    np.testing.assert_allclose(olmoe_predict(params, noisy, mask, CFG), base,
+                               atol=1e-6, rtol=0)
+    # and the pooled position is the last REAL token: changing it moves the row
+    last = ids.copy()
+    last[1, LENGTHS[1] - 1] = (last[1, LENGTHS[1] - 1] + 1) % CFG.vocab_size
+    assert abs(float(olmoe_predict(params, last, mask, CFG)[1] - base[1])) > 1e-4
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_attention_reference_causal_argument(causal):
+    key = jax.random.PRNGKey(0)
+    q, k, v = (jax.random.normal(jax.random.fold_in(key, i), (1, 2, 6, 8))
+               for i in range(3))
+    out = attention_reference(q, k, v, None, causal=causal)
+    # position 0 sees only key 0 when causal, all keys otherwise
+    if causal:
+        np.testing.assert_allclose(out[:, :, 0], v[:, :, 0], atol=1e-6)
+    else:
+        assert np.abs(np.asarray(out[:, :, 0] - v[:, :, 0])).max() > 1e-3
+
+
+@pytest.mark.parametrize("change,message", [
+    (dict(num_key_value_heads=1), "grouped-query"),
+    (dict(norm_topk_prob=True), "norm_topk_prob"),
+    (dict(num_attention_heads=3, num_key_value_heads=3), "divide"),
+])
+def test_config_refuses_what_is_not_implemented(change, message):
+    with pytest.raises(ValueError, match=message):
+        dataclasses.replace(CFG, **change)
+
+
+def test_published_config_is_the_default():
+    c = OlmoeConfig()
+    assert (c.hidden_size, c.intermediate_size, c.num_experts,
+            c.num_experts_per_tok, c.num_attention_heads, c.head_dim,
+            c.num_hidden_layers, c.vocab_size) == (
+        2048, 1024, 64, 8, 16, 128, 16, 50304)
+    assert c.norm_topk_prob is False and c.rms_norm_eps == 1e-5
+
+
+# ------------------------------------------------- the seam into the scorer
+def _one_device_mesh():
+    return build_mesh(devices=jax.devices()[:1])
+
+
+def _scorer(**kw):
+    from realtime_fraud_detection_tpu.scoring import FraudScorer, ScorerConfig
+
+    kw.setdefault("mesh", _one_device_mesh())
+    return FraudScorer(bert_config=CFG,
+                       scorer_config=ScorerConfig(text_len=32), **kw)
+
+
+def test_fused_program_through_scorer_and_job_one_prediction_each():
+    from realtime_fraud_detection_tpu.sim.simulator import (
+        TransactionGenerator,
+    )
+    from realtime_fraud_detection_tpu.stream import (
+        InMemoryBroker,
+        JobConfig,
+        StreamJob,
+    )
+
+    scorer = _scorer()
+    broker = InMemoryBroker()
+    cfg = JobConfig(max_batch=32)
+    job = StreamJob(broker, scorer, cfg)
+    gen = TransactionGenerator(num_users=64, num_merchants=16)
+    recs = gen.generate_batch(40)
+    broker.produce_batch_keyed(
+        cfg.transactions_topic, [(r["user_id"], r) for r in recs])
+    job.run_until_drained()
+    job.close()
+    out = [r.value for r in broker.consumer(
+        [cfg.predictions_topic], "check").poll(100_000)]
+    assert sorted(o["transaction_id"] for o in out) == sorted(
+        r["transaction_id"] for r in recs)
+    for o in out:
+        assert np.isfinite(o["fraud_probability"])
+        assert 0.0 < o["model_predictions"]["bert_text"] < 1.0
+        assert o["risk_level"] != "ERROR"
+    c = job.counters
+    assert c["errors"] == 0 and c["scored"] == 40
+    k, layers = CFG.num_experts_per_tok, CFG.num_hidden_layers
+    assert c["expert_rows"] == c["token_slots"] * k * layers
+    # largest group x experts >= all rows; equal only under even routing
+    assert c["expert_peak_rows"] >= c["expert_rows"] > 0
+    assert scorer.kernel_snapshot()["fallback"]["attention"] == c["batches"]
+
+
+def test_the_dense_program_has_no_second_output_and_no_expert_rows():
+    from realtime_fraud_detection_tpu.scoring import FraudScorer
+    from realtime_fraud_detection_tpu.sim.simulator import (
+        TransactionGenerator,
+    )
+
+    scorer = FraudScorer(mesh=_one_device_mesh())
+    recs = TransactionGenerator(num_users=8, num_merchants=4).generate_batch(3)
+    pending = scorer.dispatch(recs)
+    assert pending.text_stats is None and pending.expert_rows == 0
+    assert not isinstance(pending.out, tuple)
+    assert len(scorer.finalize(pending)) == 3
+    assert pending.expert_peak_rows == 0
+
+
+def test_the_seam_leaves_the_dense_program_as_it_was(monkeypatch):
+    """The DistilBERT program through ``text_predict`` is instruction for
+    instruction the program with the parent's direct ``bert_predict`` call
+    in its place (and ``attention_reference`` without its new argument)."""
+    import re
+
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from realtime_fraud_detection_tpu.core.packing import pack_tree
+    from realtime_fraud_detection_tpu.ensemble.combine import EnsembleParams
+    from realtime_fraud_detection_tpu.models import bert
+    from realtime_fraud_detection_tpu.scoring import pipeline
+    from realtime_fraud_detection_tpu.utils.config import Config
+
+    def compile_packed():
+        models = pipeline.init_scoring_models(jax.random.PRNGKey(0))
+        blobs, spec = pack_tree(pipeline.make_example_batch(
+            8, pipeline.ScorerConfig(), rng=np.random.default_rng(7)))
+        fn = jax.jit(lambda *a, **k: pipeline._score_fused_packed_impl(
+            *a, **k), static_argnames=pipeline._PACKED_STATIC)
+        text = fn.lower(
+            models, blobs["f32"], blobs["i32"], blobs["u8"], spec=spec,
+            params=EnsembleParams.from_config(
+                Config(), list(pipeline.MODEL_NAMES)),
+            model_valid=jnp.ones((len(pipeline.MODEL_NAMES),), bool),
+            bert_config=bert.TINY_CONFIG).compile().as_text()
+        start = re.search(r"^(?:ENTRY )?%\S+ \(", text, re.M).start()
+        return re.sub(r", metadata=\{[^}]*\}", "", text[start:])
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        through_the_seam = compile_packed()
+
+        def parents_call(params, ids, mask, config, **static):
+            return bert.bert_predict(params, ids, mask, config, **static), None
+
+        def parents_attention(q, k, v, key_mask=None):
+            d = q.shape[-1]
+            s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(F32) / np.sqrt(d)
+            if key_mask is not None:
+                s = jnp.where(key_mask[:, None, None, :], s, -1e30)
+            return jnp.einsum("bhqk,bhkd->bhqd",
+                              jax.nn.softmax(s, axis=-1).astype(v.dtype), v)
+
+        monkeypatch.setattr(pipeline, "text_predict", parents_call)
+        monkeypatch.setattr(bert, "attention_reference", parents_attention)
+        as_the_parent = compile_packed()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        cc.reset_cache()
+    assert through_the_seam.count(" fusion(") > 10
+    assert through_the_seam == as_the_parent
+
+
+# -------------------------------------- the DistilBERT-only planes refuse it
+def _config(**planes):
+    from realtime_fraud_detection_tpu.utils.config import Config
+
+    config = Config()
+    for name, value in planes.items():
+        setattr(config, name, value)
+    return config
+
+
+def _refusals():
+    from realtime_fraud_detection_tpu.utils.config import (
+        KernelSettings,
+        QuantSettings,
+    )
+
+    def quant():
+        _scorer(config=_config(quant=QuantSettings(enabled=True,
+                                                   bert_weights="int8")))
+
+    def dequant():
+        _scorer(config=_config(kernels=KernelSettings(
+            enabled=True, dequant_matmul="pallas")))
+
+    def megakernel():
+        _scorer(config=_config(kernels=KernelSettings(
+            enabled=True, megakernel="pallas")))
+
+    def sharded_mesh():
+        _scorer(mesh=build_mesh())             # the suite's 8 virtual devices
+
+    def device_pool():
+        from realtime_fraud_detection_tpu.scoring.device_pool import (
+            DevicePool,
+        )
+
+        DevicePool(_scorer(), devices=jax.devices()[:2])
+
+    def mesh_executor():
+        from realtime_fraud_detection_tpu.scoring.mesh_executor import (
+            MeshExecutor,
+        )
+
+        MeshExecutor(_scorer(), devices=jax.devices()[:2])
+
+    def pipeline_parallel():
+        from realtime_fraud_detection_tpu.parallel.pipeline import (
+            bert_pipeline_encode,
+        )
+
+        bert_pipeline_encode(None, {}, None, None, CFG)
+
+    def context_parallel():
+        from realtime_fraud_detection_tpu.parallel.context import (
+            bert_context_parallel_predict,
+        )
+
+        bert_context_parallel_predict(None, {}, None, None, CFG)
+
+    return [(quant, "QuantSettings"), (dequant, "dequant_matmul"),
+            (megakernel, "megakernel"), (sharded_mesh, "sharded mesh"),
+            (device_pool, "DevicePool"), (mesh_executor, "MeshExecutor"),
+            (pipeline_parallel, "parallel/pipeline"),
+            (context_parallel, "parallel/context")]
+
+
+@pytest.mark.parametrize("attempt,named", _refusals(),
+                         ids=[n for _, n in _refusals()])
+def test_a_distilbert_only_plane_refuses_an_olmoe_config(attempt, named):
+    with pytest.raises(ValueError, match=named) as err:
+        attempt()
+    assert "Olmoe" in str(err.value)
+
+
+def test_the_traced_guards_refuse_too(params, text):
+    from realtime_fraud_detection_tpu.scoring.pipeline import text_predict
+
+    ids, mask = text
+    with pytest.raises(ValueError, match="dequant_matmul"):
+        text_predict(params, ids, mask, CFG, dequant_kernel="pallas")

@@ -110,9 +110,36 @@ def test_scopes_do_not_change_the_compiled_program(scoped, monkeypatch):
     assert a == b
 
 
-def test_the_benchmark_matches_on_the_same_strings():
-    """``benchmarks/harness/scopes.py`` writes the names again (it has to
-    run against a program without them); they are the program's."""
+def _vocabulary_paths(level, prefix=""):
+    """Every path of a builder's nested ``VOCABULARY``, ``*`` as layer 0."""
+    for name, below in level.items():
+        path = prefix + name.replace("*", "0")
+        yield path
+        yield from _vocabulary_paths(below, path + "/")
+
+
+def _lowered_asm(bert_config):
+    models = init_scoring_models(jax.random.PRNGKey(0),
+                                 bert_config=bert_config)
+    blobs, spec = pack_tree(
+        make_example_batch(8, ScorerConfig(), rng=np.random.default_rng(7)))
+    fn = jax.jit(lambda *a, **k: _score_fused_packed_impl(*a, **k),
+                 static_argnames=_PACKED_STATIC)
+    return fn.lower(
+        models, blobs["f32"], blobs["i32"], blobs["u8"], spec=spec,
+        params=EnsembleParams.from_config(Config(), list(MODEL_NAMES)),
+        model_valid=jax.numpy.ones((len(MODEL_NAMES),), bool),
+        bert_config=bert_config,
+    ).compiler_ir().operation.get_asm(enable_debug_info=True)
+
+
+@pytest.mark.parametrize("builder", ["ensemble_builder", "olmoe_builder"])
+def test_every_name_in_a_builders_vocabulary_is_one_the_program_writes(
+        builder):
+    """A builder (``benchmarks/configs/<builder>.py``) writes the device
+    scopes again (it has to load against a program without them); every path
+    of its ``VOCABULARY`` names an operation of the program it builds, and
+    the program's own layer scopes are all in it."""
     import sys
     from pathlib import Path
 
@@ -120,17 +147,40 @@ def test_the_benchmark_matches_on_the_same_strings():
     if root not in sys.path:
         sys.path.insert(0, root)
     from benchmarks.harness import scopes as bench
+    from benchmarks.harness import spec
 
-    assert bench.BRANCHES == scopes.BRANCH_SCOPES
-    assert bench.TEXT == scopes.TEXT
-    assert bench.TEXT_PARTS == (scopes.EMBED, scopes.HEAD)
-    assert bench.LAYER_PARTS == scopes.LAYER_SCOPES
-    assert bench.LAYER_RE.match(scopes.layer_scope(11))
-    assert bench.PREFIX == scopes.ANNOTATION_PREFIX
-    assert bench.GC_SPAN == scopes.HOST_GC
-    assert bench.scope_path(
-        f"jit(f)/{scopes.TEXT}/{scopes.layer_scope(2)}/{scopes.FFN}/dot"
-    ) == f"{scopes.TEXT}/{scopes.layer_scope(2)}/{scopes.FFN}"
+    vocabulary = spec.builder({"builder": builder}).VOCABULARY
+    if builder == "ensemble_builder":
+        config, layer_parts = TINY_CONFIG, scopes.LAYER_SCOPES
+        assert vocabulary is bench.ENSEMBLE_VOCABULARY
+        assert bench.BRANCHES == scopes.BRANCH_SCOPES
+        assert bench.TEXT == scopes.TEXT
+        assert bench.TEXT_PARTS == (scopes.EMBED, scopes.HEAD)
+        assert bench.LAYER_PARTS == scopes.LAYER_SCOPES
+        assert bench.LAYER_RE.match(scopes.layer_scope(11))
+        assert bench.PREFIX == scopes.ANNOTATION_PREFIX
+        assert bench.GC_SPAN == scopes.HOST_GC
+        assert bench.scope_path(
+            f"jit(f)/{scopes.TEXT}/{scopes.layer_scope(2)}/{scopes.FFN}/dot"
+        ) == f"{scopes.TEXT}/{scopes.layer_scope(2)}/{scopes.FFN}"
+    else:
+        from realtime_fraud_detection_tpu.models.olmoe import TINY_OLMOE
+
+        config, layer_parts = TINY_OLMOE, scopes.MOE_LAYER_SCOPES
+        assert set(vocabulary[scopes.TEXT]["layer*"][scopes.EXPERTS]) == set(
+            scopes.EXPERTS_PARTS)
+        for part in scopes.EXPERTS_PARTS:
+            assert getattr(scopes, f"EXPERTS_{part.upper()}") == (
+                f"{scopes.EXPERTS}/{part}")
+        assert bench.scope_path(
+            f"jit(f)/{scopes.TEXT}/{scopes.layer_scope(3)}/"
+            f"{scopes.EXPERTS_MATMUL}/jit(gmm)/pallas_call", vocabulary
+        ) == f"{scopes.TEXT}/{scopes.layer_scope(3)}/{scopes.EXPERTS_MATMUL}"
+    assert set(vocabulary) == set(scopes.BRANCH_SCOPES)
+    assert set(vocabulary[scopes.TEXT]["layer*"]) == set(layer_parts)
+    asm = _lowered_asm(config)
+    for path in _vocabulary_paths(vocabulary):
+        assert re.search(rf'"jit\([^"]*\)/{re.escape(path)}/', asm), path
 
 
 @pytest.mark.parametrize("metadata_in_key", [False, True])
