@@ -3,6 +3,7 @@ from realtime_fraud_detection_tpu.ops.attention import (  # noqa: F401
     flash_attention,
     flash_supported,
     merge_heads,
+    narrowest_supported_len,
     split_heads,
 )
 from realtime_fraud_detection_tpu.ops.dequant_matmul import (  # noqa: F401

@@ -83,6 +83,15 @@ def flash_supported(seq_len: int, head_dim: int, num_heads: int) -> bool:
             and seq_len >= LANES and seq_len % LANES == 0)
 
 
+def narrowest_supported_len(head_dim: int, num_heads: int) -> int | None:
+    """The shortest sequence ``flash_attention`` takes for this head layout
+    (one lane tile of keys), or None where it takes none: the width at
+    which rows whose text fits it are launched apart from the long ones
+    (``scoring/text_split.py``). Asked of the predicate, not written down
+    anywhere else."""
+    return LANES if flash_supported(LANES, head_dim, num_heads) else None
+
+
 def _block_q(seq_len: int) -> int:
     """Query rows per inner step: the most that divide the sequence, up to
     512 — the whole deployed window in one step (2.54 ms a layer against

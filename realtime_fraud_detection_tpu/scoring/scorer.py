@@ -44,6 +44,7 @@ from realtime_fraud_detection_tpu.models.text import combined_text
 from realtime_fraud_detection_tpu.models.tokenizer import FraudTokenizer
 from realtime_fraud_detection_tpu.obs import scopes
 from realtime_fraud_detection_tpu.core.packing import pack_tree
+from realtime_fraud_detection_tpu.scoring import text_split
 from realtime_fraud_detection_tpu.scoring.pipeline import (
     MODEL_NAMES,
     NUM_MODELS,
@@ -109,10 +110,11 @@ class PendingScore:
     # None = tracing off (the default no-op fast path).
     trace: Optional[Any] = None
     # What the text branch was launched with, counted at dispatch (exact
-    # integers, no clock): bucket rows x padded text_len, bucket rows x
-    # text_len^2 (what attention's cost follows), and the real tokens
-    # (token_mask.sum() over the real rows). StreamJob sums them into its
-    # counters beside ``batches``.
+    # integers, no clock), summed over the launches made for the batch:
+    # bucket rows x launched width, bucket rows x width^2 (what
+    # attention's cost follows), and the real tokens (token_mask.sum()
+    # over the real rows). StreamJob sums them into its counters beside
+    # ``batches``.
     token_slots: int = 0
     token_slots_sq: int = 0
     real_tokens: int = 0
@@ -127,6 +129,51 @@ class PendingScore:
     expert_rows: int = 0
     expert_peak_rows: int = 0
     text_stats: Optional[Any] = None
+    # How the rows were launched (scoring/text_split.py): real rows in a
+    # program narrower than ``text_len``, real rows at ``text_len`` (their
+    # sum is ``n``), and 1 where the batch took two launches.
+    short_text_rows: int = 0
+    long_text_rows: int = 0
+    split_batches: int = 0
+
+
+@dataclasses.dataclass
+class _Launch:
+    """One call of the fused program for (a part of) a microbatch."""
+
+    rows: Optional[np.ndarray]      # record indices held; None = all, in order
+    n: int                          # real rows
+    size: int                       # bucket rows
+    width: int                      # text positions
+    blobs: Any = None               # core.packing.pack_tree's output
+    spec: Any = None
+
+
+class _SplitResult:
+    """The result matrices of a batch launched as two programs, read as the
+    one matrix the unsplit launch returns: bucket rows, record order. The
+    narrow launch held every row (the long ones cut short: those answers
+    are dropped), so its matrix is the base and the long launch's rows go
+    over it. The merge is the host's (``[long rows, 13]`` f32), made where
+    the matrix is read: ``np.asarray(pending.out)`` and
+    ``jax.device_get(pending.out)`` both come through ``__array__``."""
+
+    def __init__(self, base: Any, long_out: Any, long_rows: np.ndarray):
+        self._base, self._long, self._rows = base, long_out, long_rows
+
+    def copy_to_host_async(self) -> None:
+        self._base.copy_to_host_async()
+        self._long.copy_to_host_async()
+
+    def block_until_ready(self) -> "_SplitResult":
+        self._base.block_until_ready()
+        self._long.block_until_ready()
+        return self
+
+    def __array__(self, dtype=None, copy=None) -> np.ndarray:
+        merged = np.array(self._base, dtype=dtype)   # blocks until done
+        merged[self._rows] = np.asarray(self._long)[:len(self._rows)]
+        return merged
 
 
 class _EntityIndex:
@@ -274,24 +321,31 @@ class _StagingBuffers:
     """
 
     def __init__(self) -> None:
-        self._bufs: Dict[int, List[np.ndarray]] = {}
+        self._bufs: Dict[tuple, List[np.ndarray]] = {}
         self._masks: Dict[int, np.ndarray] = {}
 
-    def pad(self, tree: Any, n: int, size: int) -> tuple:
+    def pad(self, tree: Any, n: int, size: int,
+            rows: Optional[np.ndarray] = None) -> tuple:
+        """``rows`` (indices, ``n`` of them) picks the rows to stage where
+        the launch holds a part of the batch; None stages the first ``n``.
+        Buffers are kept per bucket shape, the leaves' trailing shapes
+        included: a batch launched at two text widths has one set each."""
         import jax
 
         leaves, treedef = jax.tree_util.tree_flatten(tree)
-        bufs = self._bufs.get(size)
-        shapes = [((size,) + np.shape(lf)[1:], np.asarray(lf).dtype)
-                  for lf in leaves]
-        if bufs is None or [(b.shape, b.dtype) for b in bufs] != shapes:
+        leaves = [np.asarray(lf) for lf in leaves]
+        shapes = tuple(((size,) + lf.shape[1:], lf.dtype) for lf in leaves)
+        bufs = self._bufs.get(shapes)
+        if bufs is None:
             bufs = [np.empty(shape, dtype) for shape, dtype in shapes]
-            self._bufs[size] = bufs
-        for buf, leaf in zip(bufs, leaves):
-            arr = np.asarray(leaf)
-            buf[:n] = arr
+            self._bufs[shapes] = bufs
+        for buf, arr in zip(bufs, leaves):
+            if rows is None:
+                buf[:n] = arr
+            else:
+                np.take(arr, rows, axis=0, out=buf[:n], mode="clip")
             if n < size:
-                buf[n:] = arr[:1]          # replicate row 0 (pad_to_bucket)
+                buf[n:] = buf[:1]          # replicate row 0 (pad_to_bucket)
         mask = self._masks.get(size)
         if mask is None:
             self._masks[size] = mask = np.zeros((size,), bool)
@@ -567,6 +621,12 @@ class FraudScorer:
 
         self._join_cache = EntityRowCache()
         self._staging = _StagingBuffers()
+        # text widths (scoring/text_split.py): the programs compiled for
+        # each row bucket that has left the unsplit launch, and how the
+        # rows have been launched since construction
+        self._text_families: Dict[int, tuple] = {}
+        self._text_split_counts: Dict[str, int] = {
+            "short_text_rows": 0, "long_text_rows": 0, "split_batches": 0}
         self.spans = SpanTimer()
         # device-pool scoring plane (scoring/device_pool.py): when attached,
         # dispatch_assembled routes whole microbatches round-robin across
@@ -855,7 +915,8 @@ class FraudScorer:
             self._static_cache[key] = cached
         return cached
 
-    def effective_use_pallas(self, devices: Optional[int] = None) -> bool:
+    def effective_use_pallas(self, devices: Optional[int] = None,
+                             text_len: Optional[int] = None) -> bool:
         """Whether the text branch is ASKED to run its Pallas kernel: the
         fused attention core of the dense encoder (``bert_layer``'s traced
         guard still sends a shape ``flash_supported`` declines to the
@@ -869,44 +930,49 @@ class FraudScorer:
         device's — XLA cannot partition a Mosaic call, so a program whose
         batch is sharded over a mesh keeps the reference. ``devices`` is how
         many the program spans: the scorer's own mesh by default, one for
-        a ``DevicePool`` replica, a ``MeshExecutor`` replica's sub-mesh."""
+        a ``DevicePool`` replica, a ``MeshExecutor`` replica's sub-mesh.
+        ``text_len`` is the width the program is launched at:
+        ``ScorerConfig.text_len`` by default, the narrower one for the
+        short rows of a split batch (``scoring/text_split.py``)."""
         if self.kernels.enabled:
             return self.kernels.attention == "flash"
         if devices is None:
             devices = self.mesh.devices.size
         return (self._platform == "tpu" and devices == 1
-                and self._text_kernel_shape_ok())
+                and self._text_kernel_shape_ok(text_len))
 
-    def _flash_shape_ok(self) -> bool:
-        """Whether the program holds the fused ATTENTION core where asked:
-        never for the MoE encoder (head_dim 128, causal), which keeps the
-        reference core."""
+    def _flash_shape_ok(self, text_len: Optional[int] = None) -> bool:
+        """Whether a program launched at ``text_len`` holds the fused
+        ATTENTION core where asked: never for the MoE encoder (head_dim
+        128, causal), which keeps the reference core."""
         from realtime_fraud_detection_tpu.ops import flash_supported
 
         if self._moe_text:
             return False
-        return flash_supported(self.sc.text_len, self.bert_config.head_dim,
+        return flash_supported(text_len or self.sc.text_len,
+                               self.bert_config.head_dim,
                                self.bert_config.num_heads)
 
-    def _text_kernel_shape_ok(self) -> bool:
+    def _text_kernel_shape_ok(self, text_len: Optional[int] = None) -> bool:
         """Whether the text branch has a Pallas kernel for its shapes: the
         fused attention core for the dense encoder, the grouped expert
         matmul for the MoE one (the smallest bucket's rows decide: every
         larger bucket is a multiple of them)."""
         if not self._moe_text:
-            return self._flash_shape_ok()
+            return self._flash_shape_ok(text_len)
         from realtime_fraud_detection_tpu.ops import grouped_matmul_supported
 
         c = self.bert_config
-        rows = self.sc.text_len * c.num_experts_per_tok
+        rows = (text_len or self.sc.text_len) * c.num_experts_per_tok
         return (grouped_matmul_supported(rows, c.hidden_size,
                                          c.intermediate_size)
                 and grouped_matmul_supported(rows, c.intermediate_size,
                                              c.hidden_size))
 
-    def _record_kernel_dispatch(self, size: int) -> None:
+    def _record_kernel_dispatch(self, size: int, text_len: int) -> None:
         """Host-side mirror of the per-site kernel engagement for one
-        microbatch launch. A site counts as dispatched when its mode asks
+        launch of ``size`` rows at ``text_len`` positions (a split batch
+        records each of its two). A site counts as dispatched when its mode asks
         for the Pallas kernel, and as a fallback when the shape/layout
         guard the TRACED code consults (the shared supports() predicates)
         routes it back to the XLA path — so ``kernel_fallback_total``
@@ -926,13 +992,13 @@ class FraudScorer:
             # megakernel fallback AND the per-site chain is accounted as
             # usual, because that is exactly what the traced guard runs.
             disp["megakernel"] += 1
-            if self._mega_plan(size)["supported"]:
+            if self._mega_plan(size, text_len)["supported"]:
                 self._last_launches_per_batch = 1
                 return
             fall["megakernel"] += 1
         asked = self.effective_use_pallas(
-            getattr(self._pool, "program_devices", None))
-        if asked and self._flash_shape_ok():
+            getattr(self._pool, "program_devices", None), text_len)
+        if asked and self._flash_shape_ok(text_len):
             disp["attention"] += 1
         else:
             fall["attention"] += 1
@@ -955,7 +1021,7 @@ class FraudScorer:
         )["launches_per_batch_chain"]
         h = self.bert_config.hidden_size
         ffn = self.bert_config.intermediate_size
-        s = self.sc.text_len
+        s = text_len
         m = size * s
         if modes["dequant_matmul"] == "pallas":
             disp["dequant_matmul"] += 1
@@ -973,16 +1039,16 @@ class FraudScorer:
             if not epilogue_supported(size, NUM_MODELS):
                 fall["epilogue"] += 1
 
-    def _mega_plan(self, size: int) -> Dict[str, Any]:
+    def _mega_plan(self, size: int, text_len: int) -> Dict[str, Any]:
         """Host mirror of the trace-time megakernel shape plan for a
-        ``size``-row microbatch — the SAME ``mega_plan`` the traced
+        ``size``-row launch at ``text_len`` — the SAME ``mega_plan`` the traced
         dispatch consults, so ``kernel_fallback_total{site="megakernel"}``
         equals the compiled program's actual fallback behaviour."""
         from realtime_fraud_detection_tpu.ops import mega_plan
 
         return mega_plan(
             self.models, self.bert_config, b=size,
-            text_len=self.sc.text_len, seq_len=self.sc.seq_len,
+            text_len=text_len, seq_len=self.sc.seq_len,
             feature_dim=self.sc.feature_dim,
             has_two_hop=self._sampler is not None,
         )
@@ -1199,12 +1265,22 @@ class FraudScorer:
         spans included; each with ``total_s``, ``self_s`` and ``parent``)
         and cache hit/miss counters — the source
         obs/metrics.MetricsCollector.sync_host_stats exports as Prometheus
-        series."""
+        series — and ``text_split``: the narrower width short rows are
+        launched at (None where there is none), why it is refused if it
+        is, rows launched at either width, batches that took two launches,
+        and the programs compiled per bucket."""
         caches: Dict[str, Any] = {"entity_rows": self._join_cache.stats()}
         cache_stats = getattr(self.tokenizer, "cache_stats", None)
         if cache_stats is not None:
             caches["tokens"] = cache_stats()
-        return {"stages": self.spans.stats(), "caches": caches}
+        full = self.sc.text_len
+        text_split_stats = dict(
+            self._text_split_counts, width=self._narrow_text_len(full),
+            refused=self.text_split_refusal(),
+            families={size: list(programs) for size, programs
+                      in self._text_families.items()})
+        return {"stages": self.spans.stats(), "caches": caches,
+                "text_split": text_split_stats}
 
     # ----------------------------------------------------------------- scoring
     def dispatch(self, records: Sequence[Mapping[str, Any]],
@@ -1253,19 +1329,17 @@ class FraudScorer:
             # 4x2 executor) — pad to whichever seam the batch will cross
             multiple = (getattr(self._pool, "batch_multiple", None)
                         or local_mesh_size(self.mesh))
-            size = bucket_for(n, BATCH_BUCKETS, multiple_of=multiple)
-            # write-into staging: pad rows replicate row 0, the real
-            # validity is the staging mask (same contract as pad_to_bucket)
-            padded, mask = self._staging.pad(batch, n, size)
-            padded = padded.replace(valid=mask)
-            # Packed seam (core/packing.py): the 65-leaf ScoreBatch
-            # collapses to 3 dense blobs (one h2d payload), the program
-            # returns ONE f32 matrix (one d2h payload).
-            if self.sc.transfer_bf16:
-                padded = _stage_bf16(padded)
-            blobs, spec = pack_tree(padded)
-        # what the text branch is launched with (PendingScore.token_slots)
-        text_len = int(batch.token_ids.shape[1])
+
+            def bucket_of(rows: int) -> int:
+                return bucket_for(rows, BATCH_BUCKETS, multiple_of=multiple)
+
+            size = bucket_of(n)
+            full = int(batch.token_ids.shape[1])
+            # one launch at ``text_len``, or the rows whose text fits a
+            # narrower program apart from the long ones (text_split.py)
+            launches = self._text_launches(batch, n, size, full, bucket_of)
+            for launch in launches:
+                self._pack_launch(batch, launch)
         real_tokens = int(np.count_nonzero(batch.token_mask))
 
         # the tracer's ``device_wait`` stage begins where the launch
@@ -1275,15 +1349,17 @@ class FraudScorer:
                              then=scopes.DEVICE_WAIT):
             mv = self.effective_model_valid()
             rules_only = self._qos_rules_only
-            self._record_kernel_dispatch(size)
+            for launch in launches:
+                self._record_kernel_dispatch(launch.size, launch.width)
             token = None
             if self._pool is not None:
                 # pooled mode: the whole microbatch runs on ONE replica
                 # (model replication, not batch sharding) picked
                 # round-robin by the pool; in-flight depth and retry live
-                # there
+                # there. A pool keeps the one launch (text_split_refusal).
                 token = self._pool.dispatch_packed(
-                    blobs, spec, self.ensemble_params, mv)
+                    launches[0].blobs, launches[0].spec,
+                    self.ensemble_params, mv)
                 out = token.out
                 if trace is not None:
                     # which replica got the batch, and how deep its queue
@@ -1291,18 +1367,13 @@ class FraudScorer:
                     # ISSUE's "where did the p99 go" question needs
                     trace.annotate(replica=token.replica_idx,
                                    inflight_depth=token.inflight_at_dispatch)
+            elif len(launches) == 1:
+                out = self._launch_packed(launches[0], mv)
             else:
-                sharded = shard_batch(self.mesh, blobs)
-                out = score_fused_packed(
-                    self.models, sharded["f32"], sharded["i32"],
-                    sharded["u8"],
-                    spec=spec, params=self.ensemble_params,
-                    model_valid=self._model_valid_dev(mv),
-                    blob_bf16=sharded["bf16"],
-                    bert_config=self.bert_config,
-                    use_pallas=self.effective_use_pallas(),
-                    **self.quant_static(), **self.kernel_static(mv),
-                )
+                narrow, long_part = launches
+                out = _SplitResult(self._launch_packed(narrow, mv),
+                                   self._launch_packed(long_part, mv),
+                                   long_part.rows)
             # Start the device->host copy NOW (it queues behind the
             # compute): by the time finalize() calls device_get, the
             # transfer is already in flight or done, so the d2h RTT
@@ -1316,6 +1387,13 @@ class FraudScorer:
                 out.copy_to_host_async()
                 if text_stats is not None:
                     text_stats.copy_to_host_async()
+        token_slots = sum(la.size * la.width for la in launches)
+        short_rows = n - sum(la.n for la in launches if la.width == full)
+        split = int(len(launches) > 1)
+        counts = self._text_split_counts
+        counts["short_text_rows"] += short_rows
+        counts["long_text_rows"] += n - short_rows
+        counts["split_batches"] += split
         return PendingScore(records=list(records), n=n, out=out,
                             # rtfd-lint: allow[d2h] batch.features is a host-assembled ndarray
                             features=np.asarray(batch.features),
@@ -1323,11 +1401,116 @@ class FraudScorer:
                             dispatch_ms=(time.perf_counter() - t0) * 1000.0,
                             model_valid=mv, rules_only=rules_only,
                             pool_token=token, trace=trace,
-                            token_slots=size * text_len,
-                            token_slots_sq=size * text_len * text_len,
+                            token_slots=token_slots,
+                            token_slots_sq=sum(la.size * la.width * la.width
+                                               for la in launches),
                             real_tokens=real_tokens,
-                            expert_rows=self._expert_rows(size * text_len),
-                            text_stats=text_stats)
+                            expert_rows=self._expert_rows(token_slots),
+                            text_stats=text_stats,
+                            short_text_rows=short_rows,
+                            long_text_rows=n - short_rows,
+                            split_batches=split)
+
+    # ------------------------------------------------ text widths of a batch
+    def text_split_refusal(self) -> Optional[str]:
+        """Why this scorer keeps every batch in the one launch at
+        ``text_len`` though its text kernel takes a narrower width, or
+        None. A plane that cannot take a second width says so by name."""
+        if self._pool is not None:
+            return (f"{type(self._pool).__name__}: every replica would "
+                    "compile each bucket's family of programs")
+        if self.kernels.enabled and self.kernels.megakernel == "pallas":
+            return ("KernelSettings.megakernel: its shape plan "
+                    "(ops.mega_plan) is made for one text_len")
+        return None
+
+    def _narrow_text_len(self, full: int) -> Optional[int]:
+        """The width short rows are launched at: the narrowest the dense
+        encoder's attention kernel admits, where that is under the
+        ``full`` width the batch was tokenised to; else None."""
+        from realtime_fraud_detection_tpu.ops import narrowest_supported_len
+
+        if self._moe_text or self.text_split_refusal() is not None:
+            return None
+        narrow = narrowest_supported_len(self.bert_config.head_dim,
+                                         self.bert_config.num_heads)
+        return narrow if narrow is not None and narrow < full else None
+
+    def _text_launches(self, batch: ScoreBatch, n: int, size: int,
+                       full: int, bucket_of) -> List["_Launch"]:
+        """The launches of an assembled batch (``text_split.plan``): rows
+        with no real token past the narrow width are short. The first time
+        a bucket leaves the unsplit launch, every program of its family is
+        compiled and run here, so that none first appears under load (each
+        costs 0.6-1.1 s with a warm compile cache, 4-10 s cold, nearly all
+        of it the interpreter's: a thread made it slower on the v5e)."""
+        narrow = self._narrow_text_len(full)
+        if narrow is None:
+            return [_Launch(None, n, size, full)]
+        is_long = np.asarray(batch.token_mask)[:, narrow:].any(axis=1)
+        n_long = int(np.count_nonzero(is_long))
+        launches = []
+        for which, rows, width in text_split.plan(
+                n - n_long, n_long, size, narrow, full, bucket_of):
+            if which == text_split.LONG:
+                launches.append(
+                    _Launch(np.flatnonzero(is_long), n_long, rows, width))
+            else:
+                # a narrow launch holds the long rows too, cut short, in
+                # the places its bucket would pad anyway: no row is
+                # gathered, and the long launch's answers replace theirs
+                launches.append(_Launch(None, n, rows, width))
+        if launches[0].width != full and size not in self._text_families:
+            # every member here, from this one place, whichever of them the
+            # batch needs itself: a program's entry in the persistent
+            # compile cache follows the call stack it was traced under
+            # (utils/compile_cache.py), and which members a bucket's first
+            # batch launches differs from run to run
+            programs = text_split.family(size, narrow, full, bucket_of)
+            mv = self.effective_model_valid()
+            for rows, width in programs:
+                # the batch's first rows stand in: the results are dropped
+                k = min(n, rows)
+                warm = _Launch(np.arange(k), k, rows, width)
+                self._pack_launch(batch, warm)
+                jax.block_until_ready(self._launch_packed(warm, mv))
+            self._text_families[size] = programs
+        return launches
+
+    def _pack_launch(self, batch: ScoreBatch, launch: "_Launch") -> None:
+        """Pad ``launch``'s rows of ``batch`` to its bucket at its text
+        width and pack them: the tokenizer pads on the right and [CLS] is
+        position 0, so a row with no real token past the width loses
+        nothing to the cut (a long row in a narrow launch does, and its
+        answer there is not used)."""
+        if launch.width != batch.token_ids.shape[1]:
+            batch = batch.replace(
+                token_ids=np.asarray(batch.token_ids)[:, :launch.width],
+                token_mask=np.asarray(batch.token_mask)[:, :launch.width])
+        # write-into staging: pad rows replicate row 0, the real
+        # validity is the staging mask (same contract as pad_to_bucket)
+        padded, mask = self._staging.pad(batch, launch.n, launch.size,
+                                         rows=launch.rows)
+        padded = padded.replace(valid=mask)
+        # Packed seam (core/packing.py): the 65-leaf ScoreBatch
+        # collapses to 3 dense blobs (one h2d payload), the program
+        # returns ONE f32 matrix (one d2h payload).
+        if self.sc.transfer_bf16:
+            padded = _stage_bf16(padded)
+        launch.blobs, launch.spec = pack_tree(padded)
+
+    def _launch_packed(self, launch: "_Launch", mv: np.ndarray):
+        """One call of the fused program on this scorer's own mesh."""
+        sharded = shard_batch(self.mesh, launch.blobs)
+        return score_fused_packed(
+            self.models, sharded["f32"], sharded["i32"], sharded["u8"],
+            spec=launch.spec, params=self.ensemble_params,
+            model_valid=self._model_valid_dev(mv),
+            blob_bf16=sharded["bf16"],
+            bert_config=self.bert_config,
+            use_pallas=self.effective_use_pallas(text_len=launch.width),
+            **self.quant_static(), **self.kernel_static(mv),
+        )
 
     def _expert_rows(self, token_slots: int) -> int:
         """Rows one launch sends into the grouped expert matmuls."""

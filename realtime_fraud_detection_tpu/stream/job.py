@@ -289,6 +289,10 @@ class StreamJob:
             # expert's group were as large as the layer's largest
             # (PendingScore.expert_rows / expert_peak_rows)
             "expert_rows": 0, "expert_peak_rows": 0,
+            # how the rows were launched (scoring/text_split.py): real rows
+            # in a program narrower than ``text_len``, real rows at
+            # ``text_len``, batches that took two launches
+            "short_text_rows": 0, "long_text_rows": 0, "split_batches": 0,
         }
         self._batch_seq = 0
         # transaction_ids dispatched but not yet written back: the pipelined
@@ -561,7 +565,9 @@ class StreamJob:
                 feats = pending.features
                 scored_ok = True
                 for key in ("token_slots", "token_slots_sq", "real_tokens",
-                            "expert_rows", "expert_peak_rows"):
+                            "expert_rows", "expert_peak_rows",
+                            "short_text_rows", "long_text_rows",
+                            "split_batches"):
                     # 0 from a stand-in scorer's pending without them
                     self.counters[key] += getattr(pending, key, 0)
             except Exception as e:  # noqa: BLE001 — boundary: keep streaming

@@ -235,11 +235,31 @@ def test_whole_program_with_kernels_compiles_at_bucket_256(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
 
 
-def test_deployed_program_holds_the_fused_core_at_bucket_256(one_chip):
+# text_split.family(256, 128, 512, bucket_for): the programs bucket 256 of
+# the 512-token deployment launches once its batches split; a test below
+# holds the list to the rule
+BUCKET_256_FAMILY = [(256, 512), (256, 128), (8, 512), (32, 512)]
+
+
+def test_the_aot_family_is_the_rules_own():
+    from realtime_fraud_detection_tpu.core.batching import bucket_for
+    from realtime_fraud_detection_tpu.ops import narrowest_supported_len
+    from realtime_fraud_detection_tpu.scoring import text_split
+
+    narrow = narrowest_supported_len(FULL.head_dim, FULL.num_heads)
+    assert list(text_split.family(BUCKET, narrow, DEPLOYED_TEXT_LEN,
+                                  bucket_for)) == BUCKET_256_FAMILY
+
+
+@pytest.mark.parametrize("rows,text_len", BUCKET_256_FAMILY)
+def test_deployed_program_holds_the_fused_core_at_bucket_256(
+        one_chip, rows, text_len):
     """What the benchmark's cells launch — f32 weights, kernel plane off,
-    512 tokens, the selector's choice on a TPU: one Mosaic call a layer,
-    and the f32 score tensor (4.23 GB of temporaries with the reference
-    core) no longer reserved."""
+    the selector's choice on a TPU — for every member of bucket 256's
+    family at 512 tokens (the unsplit program, the 128-wide one the short
+    rows run in, the long rows' 8 and 32): one Mosaic call a layer, and
+    the f32 score tensor (4.23 GB of temporaries with the reference core
+    at 256 x 512) not reserved."""
     from realtime_fraud_detection_tpu.core.packing import pack_tree
     from realtime_fraud_detection_tpu.ensemble.combine import EnsembleParams
     from realtime_fraud_detection_tpu.scoring.pipeline import (
@@ -251,9 +271,9 @@ def test_deployed_program_holds_the_fused_core_at_bucket_256(one_chip):
     )
     from realtime_fraud_detection_tpu.utils.config import Config
 
-    sc = ScorerConfig(text_len=DEPLOYED_TEXT_LEN)
+    sc = ScorerConfig(text_len=text_len)
     models = init_scoring_models(jax.random.PRNGKey(0), bert_config=FULL)
-    blobs, spec = pack_tree(make_example_batch(BUCKET, sc))
+    blobs, spec = pack_tree(make_example_batch(rows, sc))
     compiled = score_fused_packed.lower(
         _shapes_of(models, one_chip),
         *(_shapes_of(blobs[k], one_chip) for k in ("f32", "i32", "u8")),

@@ -141,3 +141,160 @@ def test_enable_explanation_config_gates_explanations():
     res = s.score_batch(gen.generate_batch(4))
     assert all(r["explanation"] == {} for r in res)
     assert all("fraud_probability" in r for r in res)
+
+
+# ---- text widths of a batch (scoring/text_split.py, ISSUE 27) -------------
+
+NARROW = 128      # ops.attention.narrowest_supported_len at TINY's heads
+
+
+def _records_of_lengths(gen, probe, lengths):
+    """Records whose combined text tokenises to exactly ``lengths`` real
+    tokens ([CLS] and [SEP] included), measured through ``probe`` (a scorer
+    of its own: assembling touches history and graph state)."""
+    recs = gen.generate_batch(len(lengths))
+    for r in recs:
+        r["description"] = "x"
+    base = probe.assemble(recs, now=1000.0).token_mask.sum(axis=1)
+    for r, have, want in zip(recs, base, lengths):
+        assert want >= have, (want, have)
+        r["description"] = " ".join(["x"] * (1 + want - have))
+    return recs
+
+
+def _unsplit_reference(scorer, batch, n):
+    """The same assembled batch through ``score_fused_packed`` called
+    directly, one launch at the full ``text_len``: what the parent did."""
+    from realtime_fraud_detection_tpu.core.batching import pad_to_bucket
+    from realtime_fraud_detection_tpu.core.mesh import local_mesh_size
+    from realtime_fraud_detection_tpu.core.packing import pack_tree
+    from realtime_fraud_detection_tpu.scoring.pipeline import (
+        score_fused_packed,
+    )
+
+    padded, mask, _ = pad_to_bucket(
+        batch, n, multiple_of=local_mesh_size(scorer.mesh))
+    blobs, spec = pack_tree(padded.replace(valid=mask))
+    out = score_fused_packed(
+        scorer.models, blobs["f32"], blobs["i32"], blobs["u8"], spec=spec,
+        params=scorer.ensemble_params,
+        model_valid=jnp.asarray(scorer.effective_model_valid()),
+        blob_bf16=blobs["bf16"], bert_config=scorer.bert_config,
+        use_pallas=False, **scorer.quant_static(), **scorer.kernel_static())
+    return np.asarray(out)[:n]
+
+
+# (text_len, real tokens per row, launches as (bucket of n rows?, width)):
+# "b" stands for the batch's own bucket, a number for a long part's bucket
+SPLIT_CASES = {
+    "all-short": (256, [20, 128, 64, 19, 33, 127, 22, 40, 25, 18, 90, 21],
+                  [("b", NARROW)]),
+    "all-long": (256, [129, 200, 256, 130, 180], [("b", 256)]),
+    "one-long-row": (256, [18] * 7 + [200] + [22] * 12,
+                     [("b", NARROW), (8, 256)]),
+    "threshold": (256, [NARROW - 1, NARROW, NARROW + 1] + [30] * 9,
+                  [("b", NARROW), (8, 256)]),
+    "too-many-long": (256, [200] * 5 + [30] * 7, [("b", 256)]),
+    "n=1-short": (256, [NARROW], [("b", NARROW)]),
+    "n=1-long": (256, [NARROW + 1], [("b", 256)]),
+    "text_len-64": (64, [20, 64, 33, 19, 50], [("b", 64)]),
+    "text_len-128": (128, [20, 128, 33, 19, 127], [("b", 128)]),
+}
+
+
+@pytest.mark.parametrize("case", list(SPLIT_CASES))
+def test_split_launches_equal_the_unsplit_program(models, case):
+    """A batch launched at two text widths answers what one launch at
+    ``text_len`` answers, row for record; where no narrower width exists
+    (``text_len`` <= 128) the launch and ``PendingScore.out`` are the
+    parent's."""
+    from realtime_fraud_detection_tpu.core.batching import bucket_for
+    from realtime_fraud_detection_tpu.core.mesh import local_mesh_size
+    from realtime_fraud_detection_tpu.scoring.pipeline import OUT_COLUMNS
+
+    text_len, lengths, want_launches = SPLIT_CASES[case]
+    gen = TransactionGenerator(num_users=40, num_merchants=15, seed=27)
+
+    def scorer():
+        s = FraudScorer(models=models,
+                        scorer_config=ScorerConfig(text_len=text_len))
+        s.seed_profiles(gen.users.profiles(), gen.merchants.profiles())
+        return s
+
+    recs = _records_of_lengths(gen, scorer(), lengths)
+    n = len(recs)
+    ref_scorer, s = scorer(), scorer()
+    batch = ref_scorer.assemble(recs, now=1000.0)
+    assert batch.token_mask.sum(axis=1).tolist() == lengths
+    want = _unsplit_reference(ref_scorer, batch, n)
+
+    pending = s.dispatch(recs, now=1000.0)
+    multiple = local_mesh_size(s.mesh)
+    b = bucket_for(n, multiple_of=multiple)
+    launches = [(b if rows == "b" else bucket_for(rows, multiple_of=multiple),
+                 width) for rows, width in want_launches]
+    assert pending.token_slots == sum(r * w for r, w in launches)
+    assert pending.token_slots_sq == sum(r * w * w for r, w in launches)
+    assert pending.real_tokens == sum(lengths)
+    short = sum(1 for t in lengths if t <= NARROW) \
+        if launches[0][1] < text_len else 0
+    assert (pending.short_text_rows, pending.long_text_rows,
+            pending.split_batches) == (short, n - short,
+                                       int(len(launches) > 1))
+    if len(launches) == 1:
+        assert isinstance(pending.out, jax.Array)     # the parent's output
+        assert pending.out.shape[0] == b
+    # what benchmarks/harness/correct.parity reads, before finalize
+    got = np.asarray(pending.out)[:n]
+    assert got.shape == want.shape and got.dtype == np.float32
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-5)
+    j = OUT_COLUMNS.index("decision")
+    assert got[:, j].tolist() == want[:, j].tolist()
+
+    results = s.finalize(pending, now=1000.0)
+    assert [r["transaction_id"] for r in results] == \
+        [str(r["transaction_id"]) for r in recs]
+    p = OUT_COLUMNS.index("fraud_probability")
+    np.testing.assert_allclose([r["fraud_probability"] for r in results],
+                               got[:, p], rtol=0, atol=1e-6)
+    assert [r["decision"] for r in results] == \
+        [DECISIONS[int(d)] for d in got[:, j]]
+    snap = s.kernel_snapshot()
+    # the attention site once per launch, at the launched width (a CPU
+    # scorer asks for no kernel: every launch is a counted fallback)
+    assert snap["fallback"]["attention"] == len(launches)
+    fam = s.host_stats()["text_split"]["families"]
+    if launches[0][1] == text_len:
+        assert fam == {}                  # nothing but the parent's program
+    else:
+        assert set(launches) <= set(fam[b])
+
+
+def test_planes_that_cannot_take_a_second_width_keep_the_unsplit_launch(
+        models):
+    from realtime_fraud_detection_tpu.utils.config import KernelSettings
+
+    s = FraudScorer(models=models, scorer_config=ScorerConfig(text_len=256))
+    assert s.text_split_refusal() is None
+    assert s.host_stats()["text_split"]["width"] == NARROW
+    s.kernels = KernelSettings(enabled=True, megakernel="pallas")
+    assert "megakernel" in s.text_split_refusal()
+    assert s._narrow_text_len(256) is None
+    s.kernels = KernelSettings()
+
+    class StandInPool:
+        batch_multiple = None
+
+    s._pool = StandInPool()
+    assert "StandInPool" in s.text_split_refusal()
+    assert s.host_stats()["text_split"]["width"] is None
+    s._pool = None
+    # at or under the narrowest width there is nothing narrower
+    assert s._narrow_text_len(NARROW) is None
+    assert s._narrow_text_len(64) is None
+    # a head layout the kernel does not take has no narrow width at all
+    from realtime_fraud_detection_tpu.ops import narrowest_supported_len
+
+    assert narrowest_supported_len(64, 12) == NARROW
+    assert narrowest_supported_len(128, 16) is None
+    assert narrowest_supported_len(32, 4) is None

@@ -598,6 +598,82 @@ def test_token_counters_equal_a_hand_count(spanned_run):
     assert job.counters["real_tokens"] == real
 
 
+@pytest.fixture(scope="module")
+def split_run():
+    """Three full batches of 32 at ``text_len`` 256, one partition so that
+    they hold the records in order: two long rows (two launches), none
+    (one narrow launch), six (the unsplit launch)."""
+    text_len, rows = 256, 32
+    gen = TransactionGenerator(num_users=30, num_merchants=10, seed=43)
+    broker = InMemoryBroker()
+    scorer = FraudScorer(scorer_config=ScorerConfig(text_len=text_len))
+    scorer.seed_profiles(gen.users.profiles(), gen.merchants.profiles())
+    job = StreamJob(broker, scorer, JobConfig(max_batch=rows,
+                                              max_delay_ms=1.0))
+    records = gen.generate_batch(3 * rows)
+    long_rows = {3, 17} | {2 * rows + i for i in range(6)}
+    for i, r in enumerate(records):
+        r["description"] = " ".join(
+            ["invoice"] * (200 if i in long_rows else 5 + i % 40))
+    broker.produce_batch(T.TRANSACTIONS, records, key_fn=lambda r: "one")
+    ref = FraudScorer(scorer_config=ScorerConfig(text_len=text_len))
+    ref.seed_profiles(gen.users.profiles(), gen.merchants.profiles())
+    masks = np.asarray(ref.assemble(records, now=1000.0).token_mask)
+    scored = job.run_until_drained(now=1000.0)
+    return job, scorer, masks, scored
+
+
+def test_token_counters_sum_over_both_launches_of_a_split_batch(split_run):
+    job, scorer, masks, scored = split_run
+    narrow, full, rows, long_bucket = 128, 256, 32, 8
+    lengths = masks.sum(axis=1)
+    assert [int((lengths[i:i + rows] > narrow).sum())
+            for i in (0, rows, 2 * rows)] == [2, 0, 6]
+    c = job.counters
+    assert scored == 3 * rows == c["scored"] and c["batches"] == 3
+    # (32, 128) + (8, 256); (32, 128); (32, 256)
+    launches = [(rows, narrow), (long_bucket, full), (rows, narrow),
+                (rows, full)]
+    assert c["token_slots"] == sum(b * w for b, w in launches)
+    assert c["token_slots_sq"] == sum(b * w * w for b, w in launches)
+    assert c["real_tokens"] == int(masks.sum())
+    assert c["split_batches"] == 1
+    assert c["short_text_rows"] == (rows - 2) + rows
+    assert c["long_text_rows"] == 2 + rows        # the unsplit batch's rows
+    assert c["short_text_rows"] + c["long_text_rows"] == c["scored"]
+    stats = scorer.host_stats()["text_split"]
+    assert {k: stats[k] for k in ("short_text_rows", "long_text_rows",
+                                  "split_batches")} == {
+        k: c[k] for k in ("short_text_rows", "long_text_rows",
+                          "split_batches")}
+    # the attention site once per launch; a family's compile-time launches
+    # are not the traffic's and are not counted
+    snap = scorer.kernel_snapshot()
+    assert snap["dispatch"]["attention"] + snap["fallback"]["attention"] \
+        == len(launches)
+    # pack and dispatch stay one span each per job batch
+    stages = scorer.host_stats()["stages"]
+    assert stages["pack"]["count"] == stages["dispatch"]["count"] == 3
+
+
+def test_the_attention_site_is_counted_at_the_launched_width():
+    """192 positions are not a shape the fused core takes, 128 are: a
+    split batch's narrow launch is a dispatch, its long one a fallback."""
+    from realtime_fraud_detection_tpu.core.mesh import build_mesh
+    import jax
+
+    scorer = FraudScorer(scorer_config=ScorerConfig(text_len=192),
+                         mesh=build_mesh(devices=jax.devices()[:1]))
+    scorer._platform = "tpu"      # the selector's view of a one-chip mesh
+    assert not scorer.effective_use_pallas()
+    assert scorer.effective_use_pallas(text_len=128)
+    scorer._record_kernel_dispatch(32, 128)
+    scorer._record_kernel_dispatch(8, 192)
+    snap = scorer.kernel_snapshot()
+    assert snap["dispatch"]["attention"] == 1
+    assert snap["fallback"]["attention"] == 1
+
+
 def test_traced_job_times_collections_into_the_tracer_snapshot(spanned_run):
     import gc
 
