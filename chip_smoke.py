@@ -315,8 +315,7 @@ def expected_site_counts(scorer, sizes: Sequence[int]) -> Dict[str, Dict]:
 
     cfg, s = scorer.bert_config, scorer.sc.text_len
     h, ffn = cfg.hidden_size, cfg.intermediate_size
-    disp = {"dequant_matmul": 0, "epilogue": 0, "attention": 0,
-            "megakernel": 0}
+    disp = {"dequant_matmul": 0, "epilogue": 0, "attention": 0}
     fall = dict(disp)
     fused = flash_supported(s, cfg.head_dim, cfg.num_heads)
     for b in sizes:
@@ -379,7 +378,7 @@ def packed_call(scorer, batch, *, lower: bool = False, device=None):
                  params=params, model_valid=mv, blob_bf16=blobs["bf16"],
                  bert_config=scorer.bert_config,
                  use_pallas=scorer.effective_use_pallas(),
-                 **scorer.quant_static(), **scorer.kernel_static(mv))
+                 **scorer.quant_static(), **scorer.kernel_static())
     return out if lower else np.asarray(out)
 
 

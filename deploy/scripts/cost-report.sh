@@ -10,8 +10,10 @@
 # as the rtfd-cost-monitor CronJob (deploy/k8s/cost-monitor.yaml) or ad hoc.
 set -uo pipefail
 HOSTS="${RTFD_SCORER_HOSTS:-127.0.0.1:8080}"   # comma-separated host:port
-# measured per-chip capacity (bench.py headline on v5e-1); override per fleet
-CAPACITY="${RTFD_CHIP_CAPACITY_TPS:-9973}"
+# measured per-chip capacity: txn/s of one v5e in the benchmark's device-bound
+# cell s512-fulltext-saturated (PERF_LEDGER.jsonl, PR 27; 6094 in
+# s512-longtail-saturated, 469 with the OLMoE encoder); override per fleet
+CAPACITY="${RTFD_CHIP_CAPACITY_TPS:-2407}"
 python - "$HOSTS" "$CAPACITY" <<'EOF'
 import json, socket, sys, urllib.request
 raw, capacity = sys.argv[1].split(","), float(sys.argv[2])

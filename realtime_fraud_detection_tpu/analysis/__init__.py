@@ -1,7 +1,7 @@
 """Invariant guard plane: repo-native static checks + dynamic lock watcher.
 
-The system's correctness invariants — virtual-clock determinism, the two
-device-timing rules (utils/timing.py), honest counter-delta Prometheus
+The system's correctness invariants — virtual-clock determinism, no
+device-to-host pull on the dispatch path, honest counter-delta Prometheus
 mirrors, score-lock discipline around param swaps — lived only in
 docstrings until this package. ``rtfd lint`` (analysis/lint.py) machine-
 checks them over the AST; ``analysis/lockwatch.py`` watches real lock

@@ -3,8 +3,7 @@
 Generic linters cannot see that ``time.monotonic()`` inside ``qos/`` breaks
 ``rtfd qos-drill``'s bit-identical virtual-clock replay, or that one
 ``np.asarray`` on a device array inside the dispatch path blocks the host
-until the device finishes (utils/timing.py rule 2). These rules encode
-exactly those contracts:
+until the device finishes. These rules encode exactly those contracts:
 
 ``wall-clock``
     No bare ``time.time()/monotonic()/perf_counter()`` (or
@@ -15,8 +14,8 @@ exactly those contracts:
 
 ``d2h``
     No ``np.asarray`` / ``jax.device_get`` / ``.item()`` /
-    ``float(<non-literal>)`` in the dispatch-path and timed bench
-    scopes (D2H_MODULES / D2H_FUNCTIONS) — a pull waits for the device
+    ``float(<non-literal>)`` in the dispatch-path scopes
+    (D2H_MODULES / D2H_FUNCTIONS) — a pull waits for the device
     and copies, so it serialises host and device where they should
     overlap; only ``block_until_ready`` belongs inside timed sections. Host-array conversions that can never see
     a device array are annotated, which doubles as documentation of WHY
@@ -94,9 +93,8 @@ CLOCK_SUBSYSTEMS = frozenset(
      "sim", "cluster", "chaos", "graph"})
 
 # Whole modules under the pre-pull-safe / dispatch-path d2h contract
-# (utils/timing.py rule 2: only block_until_ready inside timed sections).
+# (only block_until_ready inside timed sections).
 D2H_MODULES = frozenset({
-    "utils/timing.py",
     "scoring/device_pool.py",
     "scoring/host_pipeline.py",
     "scoring/pool_drill.py",
@@ -110,8 +108,7 @@ D2H_MODULES = frozenset({
     "models/quant.py",
     # mesh-sharded serving plane (ISSUE 11): the executor's dispatch path
     # is under the same pre-pull contract as the pool's — wait() is the
-    # designated pull, complete_no_fetch drains via block_until_ready
-    # (scoring/mesh_drill.py rides the *drill* determinism convention and
+    # designated pull (scoring/mesh_drill.py rides the *drill* determinism convention and
     # is an oracle harness like pool_drill, which is already here).
     "scoring/mesh_executor.py",
     "scoring/mesh_drill.py",
@@ -123,9 +120,6 @@ D2H_MODULES = frozenset({
     "ops/attention.py",
     "ops/dequant_matmul.py",
     "ops/epilogue.py",
-    # persistent megakernel (ISSUE 19): the whole-batch program IS the
-    # dispatch — a host pull anywhere in it would serialize every launch
-    "ops/megakernel.py",
 })
 # Function-scoped d2h contract: the scorer's dispatch half must stay
 # pull-free (finalize is the designated pull point).
@@ -382,7 +376,7 @@ def _rule_wall_clock(ctx: "Context") -> List[Finding]:
 
 def _d2h_scopes(mod: Module) -> List[Tuple[ast.AST, str]]:
     """(scope node, label) pairs the d2h rule checks in this module."""
-    if mod.relpath in D2H_MODULES or mod.relpath == "bench.py":
+    if mod.relpath in D2H_MODULES:
         return [(mod.tree, mod.relpath)]
     wanted = D2H_FUNCTIONS.get(mod.relpath)
     if not wanted:
@@ -420,19 +414,15 @@ def _rule_d2h(ctx: "Context") -> List[Finding]:
                     msg = f".item() in pre-pull-safe scope '{label}'"
                 elif isinstance(f, ast.Name) and f.id == "float" \
                         and node.args \
-                        and not isinstance(node.args[0], ast.Constant) \
-                        and mod.relpath != "bench.py":
-                    # bench.py builds large host-float report dicts; the
-                    # float() heuristic would drown the real signal there
-                    # (its asarray/device_get sites stay checked)
+                        and not isinstance(node.args[0], ast.Constant):
                     msg = (f"float() on a non-literal in pre-pull-safe "
                            f"scope '{label}'")
                 if msg:
                     out.append(Finding(
                         "d2h", mod.path, node.lineno, node.col_offset,
                         f"{msg}: a device->host pull here breaks the "
-                        f"timing discipline (utils/timing.py rule 2 — "
-                        f"only block_until_ready is safe); move the pull "
+                        f"timing discipline (only block_until_ready is "
+                        f"safe); move the pull "
                         f"past the timed/dispatch section or annotate a "
                         f"provably-host value with "
                         f"`# rtfd-lint: allow[d2h] <why>`"))
@@ -901,22 +891,15 @@ def lint_paths(paths: Optional[Sequence[str]] = None) -> List[Finding]:
 
     The cross-module rules (metrics one-writer, the lock-order call-graph)
     and the subsystem scoping are only correct with the whole package in
-    context, so the full tree (+ repo-root bench.py) is always loaded and
-    analyzed; explicit files/directories merely restrict which findings
-    are returned. A path outside the package tree (other than bench.py)
-    contributes nothing — in-memory corpus linting goes through
-    :func:`lint_source` instead.
+    context, so the full tree is always loaded and analyzed; explicit
+    files/directories merely restrict which findings are returned. A path
+    outside the package tree contributes nothing — in-memory corpus linting
+    goes through :func:`lint_source` instead.
     """
     root = _package_root()
     modules: List[Module] = []
     for full, rel in _iter_package_files(root):
         m = _load_module(full, rel)
-        if m is not None:
-            modules.append(m)
-    # the repo-root pre-pull-safe bench module rides along when present
-    bench = os.path.join(os.path.dirname(root), "bench.py")
-    if os.path.exists(bench):
-        m = _load_module(bench, "bench.py")
         if m is not None:
             modules.append(m)
     findings = _run(Context(modules=modules))

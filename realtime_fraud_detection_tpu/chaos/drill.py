@@ -769,7 +769,7 @@ def _run_once(cfg: ChaosDrillConfig, devices) -> Dict[str, Any]:
                 for name in str(f).split(","):
                     fault_traces[name] = fault_traces.get(name, 0) + 1
 
-        # degraded-mode service quality (the bench `chaos` stage's numbers):
+        # degraded-mode service quality:
         # e2e p99 + virtual throughput of SCORED traffic inside any fault
         # window vs in the post-fault recovery phase, straight off the
         # fault-attributed flight recorder
@@ -943,8 +943,8 @@ def run_chaos_drill(config: Optional[ChaosDrillConfig] = None,
 
 
 def compact_chaos_summary(summary: Dict[str, Any]) -> Dict[str, Any]:
-    """The <2 KB final-stdout-line digest (bench.py convention: full
-    result on the preceding line, compact parseable verdict last)."""
+    """The <2 KB final-stdout-line digest (full result on the
+    preceding line, compact parseable verdict last)."""
     compact = {
         "metric": "chaos_drill",
         "passed": summary.get("passed"),

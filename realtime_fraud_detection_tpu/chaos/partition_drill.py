@@ -19,7 +19,7 @@ the network they live on:
 2. **slow link under load** at a second worker: per-frame latency (+
    seeded jitter) on every broker op — scored-traffic p99 inside the
    window vs the same worker's healthy p99 is the drill's
-   ``degraded_network`` report (and the bench stage of the same name).
+   ``degraded_network`` report.
 3. **full partition that heals** at a third worker: every broker op
    fails; the worker errors into its bounded ``DeterministicBackoff``
    loop (never crashes, never wedges — the socket-deadline hardening),
@@ -509,7 +509,7 @@ def run_partition_drill(config: Optional[PartitionDrillConfig] = None,
     fenced_commits = int(out["broker_status"].get("fenced_commits", 0))
 
     # --- degraded_network: the slow-link victim's own healthy-vs-window
-    # scored-traffic latency + throughput (the bench stage's payload) -----
+    # scored-traffic latency + throughput ---------------------------------
     phases = s_bye.get("latency_phases") or {}
     healthy = phases.get("healthy") or {}
     slow = phases.get("slow_link") or {}
@@ -620,8 +620,8 @@ def run_partition_drill(config: Optional[PartitionDrillConfig] = None,
 
 
 def compact_partition_summary(summary: Dict[str, Any]) -> Dict[str, Any]:
-    """The <2 KB final-stdout-line verdict (bench.py convention: full
-    result on the preceding line, compact parseable verdict last)."""
+    """The <2 KB final-stdout-line verdict (full result on the
+    preceding line, compact parseable verdict last)."""
     deg = summary.get("degraded_network") or {}
     compact = {
         "metric": "partition_drill",

@@ -1,4 +1,4 @@
-"""Command-line entry points: simulate / run-job / serve / train / bench /
+"""Command-line entry points: simulate / run-job / serve / train /
 health-check / topics.
 
 The reference's operational surface is a pile of shell scripts and service
@@ -11,7 +11,6 @@ typed CLI over the framework:
     python -m realtime_fraud_detection_tpu run-job --count 10000 --analytics
     python -m realtime_fraud_detection_tpu serve --port 8000
     python -m realtime_fraud_detection_tpu train --rows 20000 --out ./ckpt
-    python -m realtime_fraud_detection_tpu bench
     python -m realtime_fraud_detection_tpu health-check --url http://...
     python -m realtime_fraud_detection_tpu topics
 """
@@ -145,8 +144,7 @@ def cmd_run_job(args: argparse.Namespace) -> int:
         shost, sport = _addr(args.state, 6379)
         state_client = RespClient(host=shost, port=sport)
     job_config_obj = None
-    if (getattr(args, "quant", False) or getattr(args, "kernels", False)
-            or getattr(args, "mega", False)):
+    if getattr(args, "quant", False) or getattr(args, "kernels", False):
         from realtime_fraud_detection_tpu.utils.config import (
             Config,
             KernelSettings,
@@ -159,15 +157,11 @@ def cmd_run_job(args: argparse.Namespace) -> int:
             # + GEMM-form tree kernels, the configuration rtfd quant-drill
             # gates
             job_config_obj.quant = QuantSettings.full()
-        if getattr(args, "kernels", False) or getattr(args, "mega", False):
+        if getattr(args, "kernels", False):
             # Pallas kernel plane (ops/): fused dequant-matmul + fused
             # score-and-blend epilogue + flash attention, the
-            # configuration rtfd kernel-drill gates; --mega swaps in the
-            # persistent megakernel (one program per microbatch, the
-            # kernel-drill --mega gated configuration)
-            job_config_obj.kernels = (
-                KernelSettings.mega() if getattr(args, "mega", False)
-                else KernelSettings.full())
+            # configuration rtfd kernel-drill gates
+            job_config_obj.kernels = KernelSettings.full()
     scorer = FraudScorer(job_config_obj, scorer_config=ScorerConfig(),
                          state_client=state_client)
     scorer.seed_profiles(gen.users.profiles(), gen.merchants.profiles())
@@ -392,12 +386,10 @@ def cmd_serve(args: argparse.Namespace) -> int:
         from realtime_fraud_detection_tpu.utils.config import QuantSettings
 
         config.quant = QuantSettings.full()
-    if getattr(args, "kernels", False) or getattr(args, "mega", False):
+    if getattr(args, "kernels", False):
         from realtime_fraud_detection_tpu.utils.config import KernelSettings
 
-        config.kernels = (KernelSettings.mega()
-                          if getattr(args, "mega", False)
-                          else KernelSettings.full())
+        config.kernels = KernelSettings.full()
     if getattr(args, "autotune", False):
         config.tuning.enabled = True
         # clamp the tuner's deadline search space to the budget's
@@ -682,24 +674,6 @@ def cmd_validate(args: argparse.Namespace) -> int:
     return 0 if report["passed"] else 1
 
 
-def cmd_bench(args: argparse.Namespace) -> int:
-    import importlib.util
-    from pathlib import Path
-
-    # bench.py lives at the repo root (driver contract), outside the
-    # package — load it by path so the command works from any cwd
-    bench_path = Path(__file__).resolve().parent.parent / "bench.py"
-    spec = importlib.util.spec_from_file_location("bench", bench_path)
-    if spec is None or spec.loader is None:
-        print(f"bench.py not found at {bench_path}", file=sys.stderr)
-        return 1
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    return bench.main([f"--{name}" for name in ("quant", "mesh", "kernels",
-                                                "mega")
-                       if getattr(args, name, False)])
-
-
 def cmd_broker(args: argparse.Namespace) -> int:
     """Run the standalone durable log broker (the Kafka-role process of a
     multi-service deployment; stream/netbroker.py). Blocks until SIGINT."""
@@ -740,7 +714,7 @@ def cmd_broker(args: argparse.Namespace) -> int:
 def cmd_cluster_worker(args: argparse.Namespace) -> int:
     """One partition-scoped fleet worker PROCESS (cluster/procfleet.py):
     spawned by the elastic coordinator (``ProcessFleet`` — the elastic
-    drill, the bench elastic_scaling stage) with a JSON spec naming the
+    drill) with a JSON spec naming the
     broker, the handoff server, and this worker's identity. Consumes its
     assigned partitions over the TCP netbroker, checkpoints into the
     network handoff store, drains gracefully on SIGTERM/shutdown, and
@@ -928,7 +902,7 @@ def cmd_feedback_drill(args: argparse.Namespace) -> int:
     """Deterministic closed-loop continuous-learning demo (feedback/
     drill.py): virtual clock, real scorer + retraining. Prints the full
     summary, then a compact (<2 KB) parseable verdict as the FINAL stdout
-    line (the bench.py convention). Exit 1 unless the whole loop passed:
+    line. Exit 1 unless the whole loop passed:
     drift injected -> prequential AUC dip -> retrain trigger -> gate
     rejects the negative control bit-identically -> genuine candidate
     promoted only on gate-pass -> AUC recovers."""
@@ -960,7 +934,7 @@ def cmd_quant_drill(args: argparse.Namespace) -> int:
     operating point, quality-protocol AUC unchanged, exact GEMM-vs-gather
     leaf equality, >= 3.5x smaller BERT param bytes, and a bit-identical
     second run. Prints the full summary, then a compact (<2 KB) verdict
-    as the FINAL stdout line (bench.py convention). Exit 1 unless every
+    as the FINAL stdout line. Exit 1 unless every
     check passed."""
     import dataclasses as _dc
 
@@ -989,13 +963,8 @@ def cmd_kernel_drill(args: argparse.Namespace) -> int:
     calibration-noise floor, zero decision flips, exact masked-blend
     equality at every QoS ladder rung, per-kernel interpret-vs-reference
     parity on the served params, zero guard fallbacks, and a bit-identical
-    second run. ``--mega`` swaps the kernel side onto the persistent
-    megakernel (ops/megakernel.py) and adds its oracle section: fused
-    program vs verbatim reference, GEMM-tree leaves exactly equal to the
-    pointer-chase descent, per-site counters subsumed to zero, launch
-    count collapsed to 1. Prints the full summary, then a compact (<2 KB)
-    verdict as the FINAL stdout line (bench.py convention). Exit 1 unless
-    every check passed."""
+    second run. Prints the full summary, then a compact (<2 KB)
+    verdict as the FINAL stdout line. Exit 1 unless every check passed."""
     import dataclasses as _dc
 
     from realtime_fraud_detection_tpu.scoring.kernel_drill import (
@@ -1006,7 +975,6 @@ def cmd_kernel_drill(args: argparse.Namespace) -> int:
 
     cfg = KernelDrillConfig.fast() if args.fast else KernelDrillConfig()
     cfg = _dc.replace(cfg, seed=args.seed,
-                      mega=bool(getattr(args, "mega", False)),
                       replay=not getattr(args, "no_replay", False))
     summary = run_kernel_drill(cfg)
     print(json.dumps(summary), flush=True)
@@ -1024,7 +992,7 @@ def cmd_trace_drill(args: argparse.Namespace) -> int:
     QoS gate), that FIFO/shed behavior is identical with tracing on, and
     that per-txn tracing overhead stays under the pinned bound. Prints
     the full summary, then a compact (<2 KB) verdict as the FINAL stdout
-    line (bench.py convention). Exit 1 unless every check passed."""
+    line. Exit 1 unless every check passed."""
     import dataclasses as _dc
 
     from realtime_fraud_detection_tpu.obs.trace_drill import (
@@ -1051,7 +1019,7 @@ def cmd_autotune_drill(args: argparse.Namespace) -> int:
     equal-or-better throughput, never sheds high-value traffic, respects
     the QoS budget floor, and that its decisions replay bit-identically.
     Prints the full summary, then a compact (<2 KB) verdict as the FINAL
-    stdout line (bench.py convention). Exit 1 unless every check passed."""
+    stdout line. Exit 1 unless every check passed."""
     import dataclasses as _dc
 
     from realtime_fraud_detection_tpu.tuning.drill import (
@@ -1166,13 +1134,13 @@ def cmd_pool_drill(args: argparse.Namespace) -> int:
     bit-equality with single-device scoring, FIFO completion, full
     utilization, hot-swap purity, and the scheduler's >= 3x virtual-time
     scaling. Prints the full summary, then a compact (<2 KB) verdict as
-    the FINAL stdout line (bench.py convention). Exit 1 unless every
+    the FINAL stdout line. Exit 1 unless every
     check passed.
 
     Always re-execs onto a virtual N-device CPU host platform
     (``virtual_cpu_env``: the parent never initializes a backend, and
-    the verdict is identical on every box). The measured-on-chip scaling
-    bar lives in bench.py's pool_scaling stage instead.
+    the verdict is identical on every box). Scaling on four chips is not
+    measured: no admitted cell of the benchmark runs the pool.
     """
     import subprocess
 
@@ -1221,15 +1189,14 @@ def cmd_mesh_drill(args: argparse.Namespace) -> int:
     no-mixed-params hot swap under the same placement, donated staging
     actually consumed, per-chip BERT bytes <= 60% of replicated at
     model_axis=2, and a bit-identical second pass. Prints the full
-    summary, then a compact (<2 KB) verdict as the FINAL stdout line
-    (bench.py convention). Exit 1 unless every check passed.
+    summary, then a compact (<2 KB) verdict as the FINAL stdout line.
+    Exit 1 unless every check passed.
 
     Always re-execs onto a virtual N-device CPU host platform
     (``virtual_cpu_env``: the parent never initializes a backend, and
-    the verdict is identical on every box). The measured throughput
-    story lives in bench.py's mesh_scaling stage — model-sharding is an
-    HBM bet that may LOSE on CPU, and the drill refuses to pretend
-    otherwise.
+    the verdict is identical on every box). Throughput is not gated
+    here — model-sharding is an HBM bet that may LOSE on CPU, and the
+    drill refuses to pretend otherwise.
     """
     import subprocess
 
@@ -1285,7 +1252,7 @@ def cmd_chaos_drill(args: argparse.Namespace) -> int:
     ladder + SLO burn recovery, pool retries with FIFO intact, ring AUC
     retrained back past baseline via a gate-passed promotion, and a second
     run replaying bit-identically. Prints the full summary, then a compact
-    (<2 KB) verdict as the FINAL stdout line (bench.py convention). Exit 1
+    (<2 KB) verdict as the FINAL stdout line. Exit 1
     unless every check passed.
 
     Always re-execs onto a virtual N-device CPU host platform
@@ -1357,7 +1324,7 @@ def cmd_shard_drill(args: argparse.Namespace) -> int:
     oracle run, consistent-hash router agreement with fleet ownership
     (only the dead worker's partitions move), and a bit-identical second
     run. Prints the full summary, then a compact (<2 KB) verdict as the
-    FINAL stdout line (bench.py convention). Exit 1 unless every check
+    FINAL stdout line. Exit 1 unless every check
     passed. Pure host arithmetic on a virtual clock — no device needed."""
     import dataclasses as _dc
 
@@ -1390,9 +1357,8 @@ def cmd_elastic_drill(args: argparse.Namespace) -> int:
     equal to a single-process oracle), returncode -9 from the kill,
     bounded consistent-hash movement, and a digest-identical second
     fresh run (host-timing fields excluded). Prints the full summary,
-    then a compact (<2 KB) verdict as the FINAL stdout line (bench.py
-    convention). Exit 1 unless every check passed. Pure host arithmetic
-    in the workers — no device needed, but REAL processes, REAL TCP,
+    then a compact (<2 KB) verdict as the FINAL stdout line. Exit 1
+    unless every check passed. Pure host arithmetic in the workers — no device needed, but REAL processes, REAL TCP,
     REAL signals."""
     import dataclasses as _dc
 
@@ -1426,8 +1392,8 @@ def cmd_partition_drill(args: argparse.Namespace) -> int:
     state equality, detection inside the session-timeout bound, both
     rejoins with no double-ownership interval, bounded byte-identical
     duplicates, and a digest-identical second fresh run. Prints the full
-    summary, then a compact (<2 KB) verdict as the FINAL stdout line
-    (bench.py convention). Exit 1 unless every check passed. Pure host
+    summary, then a compact (<2 KB) verdict as the FINAL stdout line.
+    Exit 1 unless every check passed. Pure host
     arithmetic in the workers — no device needed, but REAL processes,
     REAL TCP, REAL link faults."""
     import dataclasses as _dc
@@ -1466,7 +1432,7 @@ def cmd_obs_drill(args: argparse.Namespace) -> int:
     arrow per stitched trace, traced-vs-untraced makespan ratio under
     the pinned bound, and a digest-identical second fresh run. Prints
     the full summary, then a compact (<2 KB) verdict as the FINAL
-    stdout line (bench.py convention). Exit 1 unless every check
+    stdout line. Exit 1 unless every check
     passed."""
     import dataclasses as _dc
 
@@ -1502,7 +1468,7 @@ def cmd_graph_drill(args: argparse.Namespace) -> int:
     injected netfault partition window, columnar == serial bit-exact
     with graph sampling on, and a digest-identical fresh second run.
     Prints the full summary, then a compact (<2 KB) verdict as the FINAL
-    stdout line (bench.py convention). Exit 1 unless every check passed.
+    stdout line. Exit 1 unless every check passed.
     Real fused-program scoring on whatever backend is live (CPU-sized by
     default), REAL TCP between the workers' graph-fetch planes."""
     import dataclasses as _dc
@@ -1562,7 +1528,7 @@ def _lockwatch_all_drills(args: argparse.Namespace) -> int:
     """Parent mode: one child process per drill (pool-drill needs the
     virtual multi-device platform set before jax initializes; the others
     inherit the session platform). Prints a per-drill table plus a final
-    compact JSON verdict line (bench.py convention)."""
+    compact JSON verdict line."""
     import subprocess
 
     from realtime_fraud_detection_tpu.analysis.lockwatch import (
@@ -1764,11 +1730,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "matmul + fused score-and-blend epilogue + flash "
                          "attention (the rtfd kernel-drill gated "
                          "configuration)")
-    sp.add_argument("--mega", action="store_true",
-                    help="persistent megakernel (ops/megakernel.py): one "
-                         "Pallas program scores the whole packed "
-                         "microbatch (implies --kernels; the rtfd "
-                         "kernel-drill --mega gated configuration)")
     sp.set_defaults(fn=cmd_run_job)
 
     sp = sub.add_parser("serve", help="run the scoring HTTP service")
@@ -1817,11 +1778,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "matmul + fused score-and-blend epilogue + flash "
                          "attention (the rtfd kernel-drill gated "
                          "configuration)")
-    sp.add_argument("--mega", action="store_true",
-                    help="persistent megakernel (ops/megakernel.py): one "
-                         "Pallas program scores the whole packed "
-                         "microbatch (implies --kernels; the rtfd "
-                         "kernel-drill --mega gated configuration)")
     sp.add_argument("--trace", action="store_true",
                     help="enable the per-transaction tracing plane: "
                          "GET /latency/breakdown, GET /slo, trace_* "
@@ -2006,8 +1962,8 @@ def build_parser() -> argparse.ArgumentParser:
                     help="tier-1 sizes (the CI smoke configuration)")
     sp.add_argument("--seed", type=int, default=11)
     sp.add_argument("--no-replay", action="store_true",
-                    help="skip the bit-identical second run (bench "
-                         "stage mode; the replay gate is waived)")
+                    help="skip the bit-identical second run (the "
+                         "replay gate is waived)")
     sp.set_defaults(fn=cmd_quant_drill)
 
     sp = sub.add_parser("kernel-drill",
@@ -2021,13 +1977,9 @@ def build_parser() -> argparse.ArgumentParser:
     sp.add_argument("--fast", action="store_true",
                     help="tier-1 sizes (the CI smoke configuration)")
     sp.add_argument("--seed", type=int, default=13)
-    sp.add_argument("--mega", action="store_true",
-                    help="serve the kernel side through the persistent "
-                         "megakernel (ops/megakernel.py: one program per "
-                         "microbatch) and add its oracle section")
     sp.add_argument("--no-replay", action="store_true",
-                    help="skip the bit-identical second run (bench "
-                         "stage mode; the replay gate is waived)")
+                    help="skip the bit-identical second run (the "
+                         "replay gate is waived)")
     sp.set_defaults(fn=cmd_kernel_drill)
 
     sp = sub.add_parser("trace-export",
@@ -2184,8 +2136,7 @@ def build_parser() -> argparse.ArgumentParser:
                         help="repo-native invariant checker (static rules "
                              "+ --lockwatch dynamic lock-order watcher)")
     sp.add_argument("paths", nargs="*",
-                    help="files/dirs to lint (default: the package tree "
-                         "+ bench.py)")
+                    help="files/dirs to lint (default: the package tree)")
     sp.add_argument("--format", choices=("text", "json"), default="text")
     sp.add_argument("--lockwatch", action="store_true",
                     help="run the thirteen deterministic drills under the "
@@ -2197,28 +2148,6 @@ def build_parser() -> argparse.ArgumentParser:
                     help="drill fast configs (the CI smoke sizes)")
     sp.add_argument("--seed", type=int, default=7)
     sp.set_defaults(fn=cmd_lint)
-
-    sp = sub.add_parser("bench", help="run the TPU benchmark")
-    sp.add_argument("--quant", action="store_true",
-                    help="measure the pool_scaling stage on the "
-                         "quantized scoring plane (int8 BERT + GEMM-form "
-                         "tree kernels); the int8 calibration pulls the "
-                         "f32 weights host-side once at scorer build")
-    sp.add_argument("--mesh", action="store_true",
-                    help="run the mesh_scaling stage too (replicated vs "
-                         "data-sharded vs data x model + per-chip param "
-                         "bytes)")
-    sp.add_argument("--kernels", action="store_true",
-                    help="measure the pool_scaling stage on the Pallas "
-                         "kernel plane too (fused dequant-matmul + fused "
-                         "epilogue + flash attention; labels suffixed "
-                         "-kern)")
-    sp.add_argument("--mega", action="store_true",
-                    help="measure the pool_scaling stage on the "
-                         "persistent megakernel too (one program per "
-                         "microbatch; implies --kernels, labels suffixed "
-                         "-mega)")
-    sp.set_defaults(fn=cmd_bench)
 
     sp = sub.add_parser("health-check", help="probe a running service")
     sp.add_argument("--url", default="http://127.0.0.1:8000")

@@ -52,7 +52,7 @@ from realtime_fraud_detection_tpu.cluster.partition import PartitionedStore
 from realtime_fraud_detection_tpu.stream import topics as T
 
 __all__ = ["ShardDrillConfig", "ShardScorer", "run_shard_drill",
-           "run_shard_scaling", "compact_shard_summary"]
+           "compact_shard_summary"]
 
 
 @dataclasses.dataclass
@@ -559,8 +559,8 @@ def run_shard_drill(config: Optional[ShardDrillConfig] = None,
 
 
 def compact_shard_summary(summary: Dict[str, Any]) -> Dict[str, Any]:
-    """The <2 KB final-stdout-line digest (bench.py convention: full
-    result on the preceding line, compact parseable verdict last)."""
+    """The <2 KB final-stdout-line digest (full result on the
+    preceding line, compact parseable verdict last)."""
     compact = {
         "metric": "shard_drill",
         "passed": summary.get("passed"),
@@ -592,48 +592,3 @@ def compact_shard_summary(summary: Dict[str, Any]) -> Dict[str, Any]:
                        "passed": summary.get("passed")}
         line = json.dumps(compact, separators=(",", ":"))
     return compact
-
-
-# ------------------------------------------------------------- bench hook
-
-
-def run_shard_scaling(seed: int = 7,
-                      workers: Tuple[int, ...] = (1, 2, 4),
-                      ) -> Dict[str, Any]:
-    """The ``bench.py shard_scaling`` stage: aggregate virtual txn/s at
-    1/2/4 workers over one saturating schedule (offered load ≥ the
-    4-worker capacity, so every fleet is compute-bound and the makespan
-    ratio IS the scaling), plus the kill run's handoff pause."""
-    base = ShardDrillConfig.fast()
-    cfg = dataclasses.replace(
-        base, seed=seed, replay_check=False,
-        tps=max(workers) * 1.5 * base.capacity_tps())
-    sched = _build_schedule(cfg)
-    per_w: Dict[int, Dict[str, Any]] = {}
-    for w in sorted(workers):
-        run_cfg = dataclasses.replace(cfg, n_workers=w)
-        out = _run_fleet(run_cfg, sched, w, kill=False)
-        per_w[w] = {
-            "makespan_s": out["makespan_s"],
-            "txn_per_s": round(len(sched) / max(out["makespan_s"], 1e-9),
-                               1),
-        }
-    kill_out = _run_fleet(cfg, sched, max(workers), kill=True)
-    w1 = per_w[min(workers)]["txn_per_s"]
-    wmax = max(workers)
-    return {
-        "n_txns": len(sched),
-        "n_partitions": cfg.n_partitions,
-        "workers": {str(w): v for w, v in per_w.items()},
-        "single_worker_txn_per_s": w1,
-        "aggregate_txn_per_s": per_w[wmax]["txn_per_s"],
-        "scaling_vs_single": round(per_w[wmax]["txn_per_s"]
-                                   / max(w1, 1e-9), 3),
-        "scaling_efficiency": round(
-            per_w[wmax]["txn_per_s"] / max(w1, 1e-9) / wmax, 3),
-        "handoff": {
-            "pause_s": kill_out["handoff_pause_s"],
-            "replayed": kill_out["fleet"]["replayed_total"],
-            "moved_partitions": len(kill_out["moved_partitions"]),
-        },
-    }

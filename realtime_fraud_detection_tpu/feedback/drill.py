@@ -21,7 +21,7 @@ policy → promotion gate → the /reload-models promotion recipe:
    recovers to the baseline band.
 
 ``rtfd feedback-drill`` prints the full summary then a compact (<2 KB)
-parseable verdict as the FINAL stdout line (the bench.py convention);
+parseable verdict as the FINAL stdout line;
 tier-1 pins the whole loop via ``--fast`` sizes.
 """
 
@@ -353,8 +353,8 @@ def run_feedback_drill(config: Optional[FeedbackDrillConfig] = None,
 
 
 def compact_drill_summary(summary: Dict[str, Any]) -> Dict[str, Any]:
-    """The <2 KB final-stdout-line digest (bench.py convention: full result
-    on the preceding line, compact parseable verdict last)."""
+    """The <2 KB final-stdout-line digest (full result on the
+    preceding line, compact parseable verdict last)."""
     import json
 
     compact = {

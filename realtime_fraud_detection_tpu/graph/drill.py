@@ -701,8 +701,8 @@ def run_graph_drill(config: Optional[GraphDrillConfig] = None,
 
 
 def compact_graph_summary(summary: Dict[str, Any]) -> Dict[str, Any]:
-    """The <2 KB final-stdout-line digest (bench.py convention: full
-    result on the preceding line, compact parseable verdict last)."""
+    """The <2 KB final-stdout-line digest (full result on the
+    preceding line, compact parseable verdict last)."""
     auc = summary.get("auc") or {}
     compact = {
         "metric": "graph_drill",
@@ -732,78 +732,3 @@ def compact_graph_summary(summary: Dict[str, Any]) -> Dict[str, Any]:
                        "passed": summary.get("passed")}
         line = json.dumps(compact, separators=(",", ":"))
     return compact
-
-
-# ------------------------------------------------------------- bench hook
-
-
-def run_graph_sampling_bench(seed: int = 7) -> Dict[str, Any]:
-    """The ``bench.py graph_sampling`` micro half: per-txn sampler cost
-    cold vs cached on a seeded synthetic graph, and remote-fetch
-    amortization (per-node one-at-a-time vs one batched request) against
-    a live local fetch server. Pure host work — safe on any backend."""
-    import time
-
-    from realtime_fraud_detection_tpu.graph.fetch import (
-        GraphFetchClient,
-        GraphFetchServer,
-    )
-    from realtime_fraud_detection_tpu.graph.sampler import NeighborSampler
-    from realtime_fraud_detection_tpu.graph.store import TypedEntityGraph
-
-    rng = np.random.default_rng(seed)
-    node_dim, fanout = 16, 8
-    n_users, n_devices, n_merchants = 4_096, 1_024, 256
-    graph = TypedEntityGraph(fanout=fanout)
-    users = [f"u{i}" for i in range(n_users)]
-    for start in range(0, n_users, 512):
-        chunk = users[start:start + 512]
-        graph.add_batch(
-            chunk,
-            [f"m{int(i)}" for i in rng.integers(0, n_merchants,
-                                                len(chunk))],
-            [f"d{int(i)}" for i in rng.integers(0, n_devices, len(chunk))],
-            [f"ip{int(i)}" for i in rng.integers(0, 2_048, len(chunk))])
-
-    zeros = lambda ids: np.zeros((len(ids), node_dim), np.float32)  # noqa: E731
-    sampler = NeighborSampler(graph, node_dim, fanout, fanout,
-                              user_rows=zeros, merchant_rows=zeros)
-    batch_u = [f"u{int(i)}" for i in rng.integers(0, n_users, 256)]
-    batch_m = [f"m{int(i)}" for i in rng.integers(0, n_merchants, 256)]
-    t0 = time.perf_counter()  # rtfd-lint: allow[wall-clock] bench timing: real host microseconds
-    sampler.sample(batch_u, batch_m)
-    cold_us = (time.perf_counter() - t0) / len(batch_u) * 1e6  # rtfd-lint: allow[wall-clock] bench timing: real host microseconds
-    t0 = time.perf_counter()  # rtfd-lint: allow[wall-clock] bench timing: real host microseconds
-    sampler.sample(batch_u, batch_m)
-    cached_us = (time.perf_counter() - t0) / len(batch_u) * 1e6  # rtfd-lint: allow[wall-clock] bench timing: real host microseconds
-
-    server = GraphFetchServer(lambda: graph, worker_id="bench").start()
-    try:
-        client = GraphFetchClient({"peer": ("127.0.0.1", server.port)},
-                                  deadline_ms=5_000.0, node_budget=10_000)
-        dev_ids = [f"d{int(i)}" for i in rng.integers(0, n_devices, 128)]
-        client.begin_batch()
-        t0 = time.perf_counter()  # rtfd-lint: allow[wall-clock] bench timing: real host microseconds
-        for d in dev_ids:
-            client.fetch("device->user", [d], fanout)
-        per_node_us = (time.perf_counter() - t0) / len(dev_ids) * 1e6  # rtfd-lint: allow[wall-clock] bench timing: real host microseconds
-        client.end_batch()
-        client.begin_batch()
-        t0 = time.perf_counter()  # rtfd-lint: allow[wall-clock] bench timing: real host microseconds
-        client.fetch("device->user", dev_ids, fanout)
-        batched_us = (time.perf_counter() - t0) / len(dev_ids) * 1e6  # rtfd-lint: allow[wall-clock] bench timing: real host microseconds
-        client.end_batch()
-        client.close()
-    finally:
-        server.stop()
-    return {
-        "graph_nodes": graph.stats()["nodes"],
-        "sampler_cold_us_per_txn": round(cold_us, 2),
-        "sampler_cached_us_per_txn": round(cached_us, 2),
-        "cache_speedup": round(cold_us / max(cached_us, 1e-9), 2),
-        "remote_per_node_us": round(per_node_us, 1),
-        "remote_batched_us_per_node": round(batched_us, 1),
-        "remote_batch_amortization": round(
-            per_node_us / max(batched_us, 1e-9), 2),
-        "sampler": sampler.stats(),
-    }

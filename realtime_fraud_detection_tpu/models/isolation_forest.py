@@ -46,7 +46,7 @@ class IsolationForest:
 
 
 def iforest_scores(forest: IsolationForest, x: jax.Array,
-                   kernel: str = "gather", paths=None) -> jax.Array:
+                   kernel: str = "gather") -> jax.Array:
     """Anomaly score s in (0, 1]; higher = more anomalous. f32[B].
 
     ``kernel`` selects the traversal (models/trees.py): ``"gather"`` (the
@@ -62,7 +62,7 @@ def iforest_scores(forest: IsolationForest, x: jax.Array,
 
     if kernel == "gemm":
         h = gemm_leaf_contract(forest.feature, forest.threshold,
-                               forest.path_length, x, paths=paths)  # [B, T]
+                               forest.path_length, x)  # [B, T]
     elif kernel == "gather":
         leaf_idx = descend_complete_trees(forest.feature, forest.threshold, x)
         h = gather_leaf_values(forest.path_length, leaf_idx)  # [B, T]
@@ -75,13 +75,13 @@ def iforest_scores(forest: IsolationForest, x: jax.Array,
 
 @partial(jax.jit, static_argnames=("kernel",))
 def iforest_predict(forest: IsolationForest, x: jax.Array,
-                    kernel: str = "gather", paths=None) -> jax.Array:
+                    kernel: str = "gather") -> jax.Array:
     """Fraud probability via the reference mapping (model_manager.py:338-346).
 
     decision_function = 0.5 - s (sklearn offset convention), then
     p = 1/(1+exp(decision)).
     """
-    decision = 0.5 - iforest_scores(forest, x, kernel=kernel, paths=paths)
+    decision = 0.5 - iforest_scores(forest, x, kernel=kernel)
     return 1.0 / (1.0 + jnp.exp(decision))
 
 

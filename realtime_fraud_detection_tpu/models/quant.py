@@ -128,8 +128,8 @@ def is_quantized_bert(params: Any) -> bool:
 
 def bert_param_bytes(params: Any) -> int:
     """Total serialized parameter bytes of a (plain or quantized) BERT
-    pytree — the number the ``quantization`` bench stage and the
-    ``quant_param_bytes`` Prometheus series report. Uses leaf ``nbytes``
+    pytree — the number the ``quant_param_bytes`` Prometheus series and
+    ``rtfd quant-drill`` report. Uses leaf ``nbytes``
     metadata only; never pulls device buffers."""
     import jax
 
@@ -144,8 +144,8 @@ def bert_param_bytes(params: Any) -> int:
 
 def quant_error_bound(params: Dict[str, Any]) -> float:
     """Max absolute weight reconstruction error across quantized leaves —
-    half an LSB per channel by construction; reported (not gated) by the
-    bench stage as a sanity number."""
+    half an LSB per channel by construction; reported (not gated) by
+    ``rtfd quant-drill`` as a sanity number."""
     if not is_quantized_bert(params):
         return 0.0
     scales = [params["word_emb"]["scale"], params["pos_emb"]["scale"]]

@@ -130,7 +130,6 @@ def _complete_tree_paths(depth: int) -> tuple:
 
 def gemm_leaf_onehot(
     feature: jax.Array, threshold: jax.Array, x: jax.Array,
-    paths=None,
 ) -> jax.Array:
     """One-hot leaf selection as batched matmuls. f32[B, T, L].
 
@@ -158,10 +157,7 @@ def gemm_leaf_onehot(
     t, n_internal = feature.shape
     depth = int(np.log2(n_internal + 1))
     f_dim = x.shape[1]
-    # ``paths`` lets a Pallas caller (ops/megakernel.py) ride the ancestor
-    # constants in as kernel operands — a kernel body cannot close over
-    # concrete arrays. Default: the lru_cached compile-time constants.
-    c, d = _complete_tree_paths(depth) if paths is None else paths
+    c, d = _complete_tree_paths(depth)
     sel = (feature[:, :, None]
            == jnp.arange(f_dim, dtype=feature.dtype)[None, None, :])
     xv = jnp.einsum("bf,tif->bti", x, sel.astype(x.dtype),
@@ -174,27 +170,26 @@ def gemm_leaf_onehot(
 
 def gemm_leaf_index(
     feature: jax.Array, threshold: jax.Array, x: jax.Array,
-    paths=None,
 ) -> jax.Array:
     """GEMM-path leaf indices i32[B, T] — the oracle-comparison hook:
     equal to ``descend_complete_trees`` on every input, by test."""
-    onehot = gemm_leaf_onehot(feature, threshold, x, paths=paths)
+    onehot = gemm_leaf_onehot(feature, threshold, x)
     return jnp.argmax(onehot, axis=2).astype(jnp.int32)
 
 
 def gemm_leaf_contract(
     feature: jax.Array, threshold: jax.Array, values: jax.Array,
-    x: jax.Array, paths=None,
+    x: jax.Array,
 ) -> jax.Array:
     """One-hot leaf selection contracted with per-leaf ``values`` [T, L]
     -> f32[B, T]: the GEMM-form replacement for descend+gather, shared by
     the GBDT (leaf log-odds) and the isolation forest (path lengths)."""
-    onehot = gemm_leaf_onehot(feature, threshold, x, paths=paths)
+    onehot = gemm_leaf_onehot(feature, threshold, x)
     return jnp.einsum("btl,tl->bt", onehot, values, precision=_EXACT)
 
 
 def tree_ensemble_logits(ensemble: TreeEnsemble, x: jax.Array,
-                         kernel: str = "gather", paths=None) -> jax.Array:
+                         kernel: str = "gather") -> jax.Array:
     """Raw log-odds for a feature batch. x: f32[B, F] -> f32[B].
 
     ``kernel`` selects the traversal: ``"gather"`` (the D-step gather
@@ -204,7 +199,7 @@ def tree_ensemble_logits(ensemble: TreeEnsemble, x: jax.Array,
     """
     if kernel == "gemm":
         values = gemm_leaf_contract(ensemble.feature, ensemble.threshold,
-                                    ensemble.leaf, x, paths=paths)
+                                    ensemble.leaf, x)
     elif kernel == "gather":
         leaf_idx = descend_complete_trees(ensemble.feature,
                                           ensemble.threshold, x)
@@ -217,7 +212,7 @@ def tree_ensemble_logits(ensemble: TreeEnsemble, x: jax.Array,
 
 @partial(jax.jit, static_argnames=("kernel",))
 def tree_ensemble_predict(ensemble: TreeEnsemble, x: jax.Array,
-                          kernel: str = "gather", paths=None) -> jax.Array:
+                          kernel: str = "gather") -> jax.Array:
     """Fraud probability, the predict_proba[:, 1] equivalent. f32[B]."""
     return jax.nn.sigmoid(
-        tree_ensemble_logits(ensemble, x, kernel=kernel, paths=paths))
+        tree_ensemble_logits(ensemble, x, kernel=kernel))

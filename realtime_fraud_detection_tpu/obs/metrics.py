@@ -601,27 +601,6 @@ class MetricsCollector:
             ("site",))
         self._kernel_seen: Dict[str, Dict[str, float]] = {
             "dispatch": {}, "fallback": {}}
-        # megakernel plane (ops/megakernel.py): the persistent whole-batch
-        # program gets dedicated counters beside its generic site series
-        # (kernel_dispatch_total{site="megakernel"} carries the same
-        # number — these exist so dashboards can alert on the ONE site
-        # that collapses the launch chain without a label join), plus the
-        # launch-count gauge the fusion claim is measured by
-        self.kernel_mega_dispatch = r.counter(
-            "kernel_mega_dispatch_total",
-            "Batches dispatched with the persistent megakernel engaged "
-            "(one program serving every branch plus the epilogue)")
-        self.kernel_mega_fallback = r.counter(
-            "kernel_mega_fallback_total",
-            "Batches where the megakernel was requested but its shape/"
-            "VMEM plan declined and the per-site kernel chain served "
-            "instead")
-        self.kernel_launches_per_batch = r.gauge(
-            "kernel_launches_per_batch",
-            "Device programs launched for the most recent scoring "
-            "microbatch (1 when the megakernel served it; the per-site "
-            "chain length otherwise)")
-        self._mega_seen: Dict[str, float] = {}
         # partition-parallel worker plane (cluster/): fleet membership,
         # partition ownership, checkpointed-handoff accounting, and the
         # serving router's key-movement ledger — mirrored from
@@ -1111,18 +1090,6 @@ class MetricsCollector:
                 if delta > 0:
                     counter.inc(delta, site=str(site))
                 seen[site] = float(total)
-        # dedicated megakernel series: same snapshot numbers, own deltas
-        # (so a dashboard alerting on the launch-collapsing site never
-        # needs a label join), plus the launch-count gauge
-        for kind, counter in (("dispatch", self.kernel_mega_dispatch),
-                              ("fallback", self.kernel_mega_fallback)):
-            total = float((snapshot.get(kind) or {}).get("megakernel", 0.0))
-            delta = total - self._mega_seen.get(kind, 0.0)
-            if delta > 0:
-                counter.inc(delta)
-            self._mega_seen[kind] = total
-        self.kernel_launches_per_batch.set(
-            float(snapshot.get("launches_per_batch", 0)))
 
     def sync_mesh(self, snapshot: Mapping[str, Any]) -> None:
         """Mirror a ``MeshExecutor.mesh_snapshot()`` into the mesh_*

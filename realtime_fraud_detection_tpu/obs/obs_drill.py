@@ -595,8 +595,8 @@ def run_obs_drill(config: Optional[ObsDrillConfig] = None,
 
 
 def compact_obs_summary(summary: Dict[str, Any]) -> Dict[str, Any]:
-    """The <2 KB final-stdout-line verdict (bench.py convention: full
-    result on the preceding line, compact parseable verdict last)."""
+    """The <2 KB final-stdout-line verdict (full result on the
+    preceding line, compact parseable verdict last)."""
     wall = summary.get("wall") or {}
     stitch = summary.get("stitch") or {}
     compact = {

@@ -19,7 +19,7 @@ reproducible bit-for-bit on any CPU, and lets it INJECT a slow stage:
   tighter one — the measured no-op contract).
 
 Used by ``rtfd trace-drill`` (final stdout line: a compact <2 KB JSON
-verdict, the bench.py convention) and smoke-tested in tier-1.
+verdict) and smoke-tested in tier-1.
 """
 
 from __future__ import annotations
@@ -440,7 +440,7 @@ def run_trace_drill(cfg: Optional[TraceDrillConfig] = None) -> Dict[str, Any]:
 
 
 def compact_trace_summary(summary: Dict[str, Any]) -> Dict[str, Any]:
-    """The <2 KB final-stdout-line verdict (bench.py convention)."""
+    """The <2 KB final-stdout-line verdict."""
     oh = summary["overhead"]
     return {
         "drill": "trace",

@@ -25,10 +25,3 @@ from realtime_fraud_detection_tpu.ops.grouped_matmul import (  # noqa: F401
     grouped_matmul_reference,
     grouped_matmul_supported,
 )
-from realtime_fraud_detection_tpu.ops.megakernel import (  # noqa: F401
-    fused_megakernel,
-    mega_launch_accounting,
-    mega_plan,
-    mega_supported,
-    megakernel_reference,
-)

@@ -95,9 +95,6 @@ def combine_matrix(preds, vf, rule, wvec, cm, *,
                    decline, review, monitor):
     """On-chip ensemble combine -> the [B, M+6] epilogue matrix.
 
-    Shared by the standalone fused-epilogue kernel below and the
-    persistent megakernel (ops/megakernel.py), which inlines this as its
-    final stage — one definition of the blend/ladder math, two kernels.
     Operands: preds/vf f32[B, M], rule f32[B, 1], wvec/cm f32[1, M];
     statics are EnsembleParams' pytree_node=False fields.
     """

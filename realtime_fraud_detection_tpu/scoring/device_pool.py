@@ -27,7 +27,7 @@ only to keep the queues fed.
   batches in dispatch order, so FIFO per source holds by construction;
 - a replica whose result fetch fails is marked unhealthy and its batch
   is relaunched from the host-side blob copy on a healthy replica
-  (counted in stats — the bench refuses to headline a degraded run);
+  (counted in stats);
 - ``set_models`` swaps params replica-by-replica (callers hold the score
   lock); an in-flight batch keeps the reference it captured at launch,
   so no batch ever sees mixed params;
@@ -219,12 +219,9 @@ class DevicePool:
                   # every replica (the scorer's params are already
                   # quantized, so replication/hot-swap carries the int8
                   # form for free, and a kernel-on scorer never mixes
-                  # kernel modes within a batch). The dispatch-time rung
-                  # snapshot rides in model_valid so a retry relaunches the
-                  # SAME megakernel program, not the rung the ladder moved
-                  # to meanwhile.
+                  # kernel modes within a batch)
                   **self.scorer.quant_static(),
-                  **self.scorer.kernel_static(model_valid))
+                  **self.scorer.kernel_static())
 
     def dispatch_packed(self, blobs: Dict[str, np.ndarray], spec, params,
                         model_valid: np.ndarray) -> PoolToken:
@@ -265,8 +262,7 @@ class DevicePool:
     def wait(self, token: PoolToken) -> np.ndarray:
         """Block on a pooled batch's result; on a replica failure, relaunch
         the batch from its host blobs on a healthy replica (per-device
-        retry counters feed the metrics plane; the bench refuses to
-        headline a run that needed this path)."""
+        retry counters feed the metrics plane)."""
         import jax
 
         attempts = len(self.replicas) + 1
@@ -313,27 +309,6 @@ class DevicePool:
             self._release(rep)
             return out
         raise RuntimeError("device pool retry budget exhausted")
-
-    def complete_no_fetch(self, token: PoolToken) -> None:
-        """Block until a pooled batch's compute finishes and release its
-        slot WITHOUT pulling the result to the host — for throughput
-        measurement (bench.py pool_scaling times compute, not the d2h
-        copy). A failure marks the replica (no retry — a measurement run
-        that needed rescue is refused as a headline anyway)."""
-        import jax
-
-        rep = self.replicas[token.replica_idx]
-        self._maybe_slow(rep)
-        try:
-            if rep.fail_next > 0:
-                rep.fail_next -= 1
-                raise RuntimeError(
-                    f"injected device fault on replica {rep.idx}")
-            jax.block_until_ready(token.out)
-        except Exception:
-            self._mark_failed(rep)
-            raise
-        self._release(rep)
 
     # -------------------------------------------------------------- control
     def set_models(self, models) -> None:

@@ -97,10 +97,6 @@ class AssemblerStage:
         self._q: "queue.Queue" = queue.Queue(maxsize=max(1, int(depth)))
         self._thread: Optional[threading.Thread] = None
         self._closed = False
-        # cumulative seconds the stage spent assembling/dispatching — the
-        # numerator of the bench's overlap accounting
-        self.busy_s = 0.0
-        self.batches = 0
 
     # ------------------------------------------------------------ lifecycle
     def _ensure_started(self) -> None:
@@ -153,7 +149,7 @@ class AssemblerStage:
             if item is None:
                 return
             records, now, handle, trace = item
-            # rtfd-lint: allow[wall-clock] busy_s is real CPU accounting for the bench overlap ratio
+            # rtfd-lint: allow[wall-clock] dispatch-time diagnostics (dispatch_ms), not control flow
             t0 = time.perf_counter()
             try:
                 with self.lock:
@@ -164,15 +160,6 @@ class AssemblerStage:
                     pending = self.scorer.dispatch_assembled(
                         batch, records, t0=t0, **kw)
             except BaseException as e:  # noqa: BLE001 — surfaces at result()
-                # account busy time BEFORE resolving the handle: a caller
-                # that reads busy_s right after the last result() must see
-                # every batch counted
-                # rtfd-lint: allow[wall-clock] busy_s is real CPU accounting for the bench overlap ratio
-                self.busy_s += time.perf_counter() - t0
-                self.batches += 1
                 handle._set_exception(e)
             else:
-                # rtfd-lint: allow[wall-clock] busy_s is real CPU accounting for the bench overlap ratio
-                self.busy_s += time.perf_counter() - t0
-                self.batches += 1
                 handle._set(pending)

@@ -30,17 +30,6 @@ verdict as the final stdout line):
    its softmax weights.
 5. **Replay.** A second full run must be bit-identical (sha256 over every
    gate-read number).
-
-``--mega`` mode (ISSUE 19) swaps the kernel side onto the persistent
-megakernel (``KernelSettings.mega()`` — ONE Pallas program scoring the
-whole packed microbatch): phases 1-3 and 5 run unchanged against that
-program (divergence under the same measured bf16 noise bound, zero
-decision/risk flips at every rung with rules_only bit-exact, replay
-digest), and the oracle section gains the megakernel pins — the fused
-program against its verbatim-composition reference, GEMM-form tree leaf
-indices exactly equal to the pointer-chase descent on the SERVED params,
-per-site dispatch counters frozen at zero (the one program subsumes
-them), and ``launches_per_batch`` collapsed to 1.
 """
 
 from __future__ import annotations
@@ -79,12 +68,6 @@ class KernelDrillConfig:
     # site would be a counted fallback and the drill would gate nothing
     text_len: int = 128
     replay: bool = True
-    # megakernel mode: the kernel side serves ops/megakernel.py's ONE
-    # persistent program (KernelSettings.mega()) instead of the per-site
-    # kernel chain, and the oracle gains the megakernel-specific pins
-    mega: bool = False
-    mega_ref_tol: float = 1e-6      # fused program vs verbatim reference:
-    #                                 same ops, block-local summation only
     # QoS rung subset for phase 2 (None = every LADDER_LEVELS rung). Each
     # non-zero rung is a fresh static config — a full recompile of BOTH
     # sides, and the kernel side pays interpret-mode Pallas tracing per
@@ -116,10 +99,7 @@ def _make_side(cfg: KernelDrillConfig, kernels_on: bool):
         QuantSettings,
     )
 
-    kernels = KernelSettings()
-    if kernels_on:
-        kernels = (KernelSettings.mega() if cfg.mega
-                   else KernelSettings.full())
+    kernels = KernelSettings.full() if kernels_on else KernelSettings()
     gen = TransactionGenerator(num_users=cfg.num_users,
                                num_merchants=cfg.num_merchants,
                                seed=cfg.seed)
@@ -193,7 +173,7 @@ def noise_floor(cfg: KernelDrillConfig, scorer,
 
 
 def _rung_phase(cfg: KernelDrillConfig, gen_a, scorer_a, gen_b, scorer_b,
-                ts: float, bound: float) -> Tuple[Dict[str, Any], float]:
+                ts: float, bound: float) -> Dict[str, Any]:
     """Masked-blend equality at every QoS ladder rung: one batch per rung
     on both sides, decisions/risk exactly equal, probs within the noise
     bound — and the rules_only rung bit-exact (pure f32 ladder)."""
@@ -211,8 +191,7 @@ def _rung_phase(cfg: KernelDrillConfig, gen_a, scorer_a, gen_b, scorer_b,
             scorer.set_degradation(None if level == 0 else mask,
                                    rules_only=rung.rules_only, level=level)
         side_a, _ = _score_stream(cfg, gen_a, scorer_a, ts, 1)
-        side_b, ts2 = _score_stream(cfg, gen_b, scorer_b, ts, 1)
-        ts = ts2
+        side_b, ts = _score_stream(cfg, gen_b, scorer_b, ts, 1)
         div = float(np.abs(side_a["probs"] - side_b["probs"]).max())
         flips = sum(x != y for x, y in zip(side_a["decisions"],
                                            side_b["decisions"]))
@@ -227,7 +206,7 @@ def _rung_phase(cfg: KernelDrillConfig, gen_a, scorer_a, gen_b, scorer_b,
     for scorer in (scorer_a, scorer_b):
         # rtfd-lint: allow[lock-order] drill is single-threaded (no batch in flight during the reset)
         scorer.set_degradation(None, rules_only=False, level=0)
-    return rungs, ts
+    return rungs
 
 
 def _kernel_oracle(cfg: KernelDrillConfig, scorer) -> Dict[str, Any]:
@@ -322,71 +301,12 @@ def _kernel_oracle(cfg: KernelDrillConfig, scorer) -> Dict[str, Any]:
     return out
 
 
-def _mega_oracle(cfg: KernelDrillConfig, gen, scorer,
-                 ts: float) -> Dict[str, Any]:
-    """Megakernel section (``--mega``): the fused persistent program vs
-    its verbatim-composition reference on a REAL assembled batch of the
-    served params (decision/risk ladders exactly equal, probs within the
-    block-summation tolerance), and the GEMM-form tree contraction's leaf
-    indices exactly equal to the pointer-chase descent — the structural
-    pin that makes the in-kernel tree branches trustworthy."""
-    import jax.numpy as jnp
-
-    from realtime_fraud_detection_tpu.models.trees import (
-        descend_complete_trees,
-        gemm_leaf_index,
-    )
-    from realtime_fraud_detection_tpu.ops import (
-        fused_megakernel,
-        megakernel_reference,
-    )
-    from realtime_fraud_detection_tpu.scoring.pipeline import OUT_COLUMNS
-
-    out: Dict[str, Any] = {}
-    recs = gen.generate_batch(cfg.batch)
-    batch = scorer.assemble(recs, now=ts)
-    mv = tuple(bool(v) for v in scorer.effective_model_valid())
-    ref = np.asarray(megakernel_reference(
-        scorer.models, batch, scorer.ensemble_params, mega_valid=mv,
-        bert_config=scorer.bert_config), np.float64)
-    got = np.asarray(fused_megakernel(
-        scorer.models, batch, scorer.ensemble_params, mega_valid=mv,
-        bert_config=scorer.bert_config, interpret=True), np.float64)
-    prob_delta = float(np.abs(got[:, 0] - ref[:, 0]).max())
-    c_dec = OUT_COLUMNS.index("decision")
-    c_risk = OUT_COLUMNS.index("risk_level")
-    ladders_exact = bool(
-        np.array_equal(got[:, c_dec], ref[:, c_dec])
-        and np.array_equal(got[:, c_risk], ref[:, c_risk]))
-    out["reference"] = {
-        "max_prob_delta": prob_delta,
-        "ladders_exact": ladders_exact,
-        "ok": bool(ladders_exact and prob_delta <= cfg.mega_ref_tol),
-    }
-
-    rng = np.random.default_rng(cfg.seed + 31)
-    x = jnp.asarray(rng.standard_normal(
-        (cfg.batch, int(scorer.sc.feature_dim))), jnp.float32)
-    leaves: Dict[str, bool] = {}
-    for name, ens in (("trees", scorer.models.trees),
-                      ("iforest", scorer.models.iforest)):
-        gemm = np.asarray(gemm_leaf_index(ens.feature, ens.threshold, x))
-        ptr = np.asarray(descend_complete_trees(ens.feature, ens.threshold,
-                                                x))
-        leaves[name] = bool(np.array_equal(gemm, ptr))
-    out["gemm_tree_leaves"] = {**{f"{k}_exact": v
-                                  for k, v in leaves.items()},
-                               "ok": all(leaves.values())}
-    return out
-
-
 def _run_once(cfg: KernelDrillConfig) -> Dict[str, Any]:
     summary: Dict[str, Any] = {
         "drill": "kernels",
         "seed": cfg.seed,
         "batch": cfg.batch,
         "n_batches": cfg.n_batches,
-        "mega": cfg.mega,
         "checks": {},
     }
     checks = summary["checks"]
@@ -418,8 +338,7 @@ def _run_once(cfg: KernelDrillConfig) -> Dict[str, Any]:
     checks["zero_decision_flips"] = flips == 0
 
     # --------------------------------- phase 2: masked-rung (QoS) equality
-    rungs, ts = _rung_phase(cfg, gen_a, scorer_a, gen_b, scorer_b, ts,
-                            bound)
+    rungs = _rung_phase(cfg, gen_a, scorer_a, gen_b, scorer_b, ts, bound)
     summary["rungs"] = rungs
     checks["masked_rungs_equal"] = all(r["ok"] for r in rungs.values())
     checks["rules_only_exact"] = bool(rungs["rules_only"]["exact"])
@@ -432,37 +351,15 @@ def _run_once(cfg: KernelDrillConfig) -> Dict[str, Any]:
     checks["epilogue_parity"] = bool(oracle["epilogue"]["ok"])
     checks["attention_parity"] = bool(oracle["attention"]["ok"])
 
-    # --------------------------- phase 3b (--mega): megakernel oracle
-    if cfg.mega:
-        mega = _mega_oracle(cfg, gen_b, scorer_b, ts)
-        summary["mega_oracle"] = mega
-        checks["mega_reference_parity"] = bool(mega["reference"]["ok"])
-        checks["gemm_tree_leaves_exact"] = bool(
-            mega["gemm_tree_leaves"]["ok"])
-
     # served-mode truth + honest dispatch accounting: every launch on the
     # kernel side must have engaged every site with zero guard fallbacks
-    # (the drill's shapes are the production shapes). In --mega mode the
-    # evidence inverts: the megakernel site carries every dispatch, the
-    # per-site counters must sit frozen at zero (the one program subsumes
-    # them — a non-zero per-site count would mean a hidden chain launch),
-    # and the launch count per microbatch collapses to 1.
+    # (the drill's shapes are the production shapes)
     snap = scorer_b.kernel_snapshot()
     summary["kernel_snapshot"] = snap
     summary["modes"] = {"off": scorer_a.kernel_snapshot()["modes"],
                         "on": snap["modes"]}
-    if cfg.mega:
-        checks["mega_dispatched"] = snap["dispatch"].get(
-            "megakernel", 0) > 0
-        checks["per_site_subsumed"] = all(
-            v == 0 for s, v in snap["dispatch"].items()
-            if s != "megakernel")
-        checks["launches_collapsed_to_one"] = (
-            snap.get("launches_per_batch") == 1)
-    else:
-        checks["all_sites_dispatched"] = all(
-            v > 0 for s, v in snap["dispatch"].items()
-            if s != "megakernel")
+    checks["all_sites_dispatched"] = all(
+        v > 0 for v in snap["dispatch"].values())
     checks["zero_fallbacks"] = all(
         v == 0 for v in snap["fallback"].values())
 
@@ -474,8 +371,7 @@ def _digest(summary: Dict[str, Any]) -> str:
     """Replay fingerprint over every number the gates read."""
     payload = json.dumps(
         {k: summary.get(k) for k in ("divergence", "rungs", "kernel_oracle",
-                                     "mega_oracle", "kernel_snapshot",
-                                     "checks")},
+                                     "kernel_snapshot", "checks")},
         sort_keys=True, separators=(",", ":"))
     return hashlib.sha256(payload.encode()).hexdigest()
 
@@ -499,7 +395,7 @@ def run_kernel_drill(
 
 
 def compact_kernel_summary(summary: Dict[str, Any]) -> Dict[str, Any]:
-    """<2 KB single-line verdict (the bench.py final-stdout convention)."""
+    """<2 KB single-line verdict (the final stdout line)."""
     div = summary.get("divergence") or {}
     oracle = summary.get("kernel_oracle") or {}
     snap = summary.get("kernel_snapshot") or {}
@@ -517,11 +413,4 @@ def compact_kernel_summary(summary: Dict[str, Any]) -> Dict[str, Any]:
         "fallbacks": snap.get("fallback"),
         "digest": (summary.get("digest") or "")[:16],
     }
-    if summary.get("mega"):
-        mega = summary.get("mega_oracle") or {}
-        out["mega"] = {
-            "ref_delta": (mega.get("reference") or {}).get("max_prob_delta"),
-            "leaves_exact": (mega.get("gemm_tree_leaves") or {}).get("ok"),
-            "launches_per_batch": snap.get("launches_per_batch"),
-        }
     return out

@@ -32,8 +32,8 @@ verdict:
 
 Wall-clock scaling is deliberately NOT gated here: 8 virtual CPU devices
 timeslice one core budget (the pool-drill precedent), and model-sharding
-is an HBM bet that can LOSE on CPU — the honest throughput numbers live
-in bench.py's ``mesh_scaling`` stage. Convention matches the other seven
+is an HBM bet that can LOSE on CPU — throughput on a mesh of chips is not
+measured (the benchmark has no four-chip cell). Convention matches the other seven
 drills: full summary JSON, then a compact (<2 KB) verdict as the final
 stdout line (cli.cmd_mesh_drill).
 """
@@ -420,7 +420,7 @@ def run_mesh_drill(cfg: Optional[MeshDrillConfig] = None) -> Dict[str, Any]:
 
 
 def compact_mesh_summary(summary: Dict[str, Any]) -> Dict[str, Any]:
-    """<2 KB single-line verdict (the bench.py final-stdout convention)."""
+    """<2 KB single-line verdict (the final stdout line)."""
     placements = summary.get("placements") or {}
     return {
         "drill": "mesh",

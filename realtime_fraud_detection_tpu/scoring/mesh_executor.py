@@ -72,6 +72,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from realtime_fraud_detection_tpu.scoring.pipeline import (
+    _PACKED_STATIC,
     MODEL_NAMES,
     _score_fused_packed_impl,
 )
@@ -101,24 +102,19 @@ def _regather_models(models, gather_fields: Tuple[str, ...], mesh):
     return models.replace(**gathered)
 
 
-def _mesh_score_packed_impl(models, blob_f32, blob_i32, blob_u8, spec,
+# the mesh entry's static arguments: the packed program's own, forwarded
+# as they are, and the two the re-gather reads
+_MESH_STATIC = _PACKED_STATIC + ("gather_fields", "mesh")
+
+
+def _mesh_score_packed_impl(models, blob_f32, blob_i32, blob_u8, *,
                             params, model_valid, blob_bf16=None,
-                            bert_config=None, use_pallas=False,
-                            tree_kernel="gather", iforest_kernel="gather",
-                            dequant_kernel="off", epilogue_kernel="off",
-                            kernel_interpret=False,
-                            megakernel="off", mega_valid=None,
                             gather_fields: Tuple[str, ...] = (),
-                            mesh=None):
+                            mesh=None, **statics):
     models = _regather_models(models, gather_fields, mesh)
     return _score_fused_packed_impl(
-        models, blob_f32, blob_i32, blob_u8, spec=spec, params=params,
-        model_valid=model_valid, blob_bf16=blob_bf16,
-        bert_config=bert_config, use_pallas=use_pallas,
-        tree_kernel=tree_kernel, iforest_kernel=iforest_kernel,
-        dequant_kernel=dequant_kernel, epilogue_kernel=epilogue_kernel,
-        kernel_interpret=kernel_interpret,
-        megakernel=megakernel, mega_valid=mega_valid)
+        models, blob_f32, blob_i32, blob_u8, params=params,
+        model_valid=model_valid, blob_bf16=blob_bf16, **statics)
 
 
 def _jit_entries():
@@ -127,14 +123,10 @@ def _jit_entries():
     stay jax-free)."""
     import jax
 
-    statics = ("spec", "bert_config", "use_pallas", "tree_kernel",
-               "iforest_kernel", "dequant_kernel", "epilogue_kernel",
-               "kernel_interpret", "megakernel", "mega_valid",
-               "gather_fields", "mesh")
-    plain = partial(jax.jit, static_argnames=statics)(
+    plain = partial(jax.jit, static_argnames=_MESH_STATIC)(
         _mesh_score_packed_impl)
     donated = partial(
-        jax.jit, static_argnames=statics,
+        jax.jit, static_argnames=_MESH_STATIC,
         donate_argnames=("blob_f32", "blob_i32", "blob_u8", "blob_bf16"),
     )(_mesh_score_packed_impl)
     return plain, donated
@@ -432,11 +424,9 @@ class MeshExecutor:
                      # quant + kernel planes: same static kernel selection
                      # on every mesh replica (params are already quantized,
                      # so the sharded storage carries the int8 form for
-                     # free, and no batch ever mixes kernel modes). The
-                     # dispatch-time rung rides in model_valid so the
-                     # megakernel program matches the mask it serves.
+                     # free, and no batch ever mixes kernel modes)
                      **self.scorer.quant_static(),
-                     **self.scorer.kernel_static(mv))
+                     **self.scorer.kernel_static())
         except Exception:
             self._mark_failed(rep)
             raise
@@ -494,19 +484,6 @@ class MeshExecutor:
         self._release(rep)
         return out
 
-    def complete_no_fetch(self, token: MeshToken) -> None:
-        """Drain a slot via block_until_ready only — no result pull (the
-        bench's mesh_scaling stage times compute, not the d2h copy)."""
-        import jax
-
-        rep = self.replicas[token.replica_idx]
-        try:
-            jax.block_until_ready(token.out)
-        except Exception:
-            self._mark_failed(rep)
-            raise
-        self._release(rep)
-
     # -------------------------------------------------------------- control
     def set_models(self, models) -> None:
         """Re-shard a model swap replica-by-replica per the SAME placement
@@ -557,7 +534,7 @@ class MeshExecutor:
                 self.program_devices),
             gather_fields=self._gather_fields, mesh=rep.mesh,
             **self.scorer.quant_static(),
-            **self.scorer.kernel_static(mv)).as_text()
+            **self.scorer.kernel_static()).as_text()
 
     # ---------------------------------------------------------------- stats
     def _branch_fields(self) -> Dict[str, str]:

@@ -324,8 +324,6 @@ def _score_fused_packed_impl(
     dequant_kernel: str = "off",
     epilogue_kernel: str = "off",
     kernel_interpret: bool = False,
-    megakernel: str = "off",         # persistent whole-batch program
-    mega_valid: Optional[tuple] = None,  # QoS rung as static branch mask
 ) -> jax.Array:
     """Packed fused scorer: packed blobs in, one matrix out.
 
@@ -351,35 +349,6 @@ def _score_fused_packed_impl(
         batch = jax.tree.map(
             lambda x: x.astype(jnp.float32) if x.dtype == jnp.bfloat16
             else x, batch)
-    if megakernel == "pallas" and isinstance(bert_config, OlmoeConfig):
-        raise ValueError(
-            "KernelSettings.megakernel fuses the DistilBERT text branch; it "
-            "does not hold the OLMoE encoder")
-    if megakernel == "pallas" and mega_valid is not None:
-        # persistent megakernel (ops/megakernel.py): score the whole
-        # microbatch in ONE Pallas program whose output IS the extended
-        # packed matrix — no branch intermediates in HBM. The QoS rung
-        # rides in as the static ``mega_valid`` tuple (one cached program
-        # per rung). ``mega_plan`` is the same predicate the host-side
-        # fallback counters consult, so this trace-time guard and
-        # kernel_fallback_total always agree; unsupported shapes fall
-        # through to the per-site kernel chain below.
-        from realtime_fraud_detection_tpu.ops.megakernel import (
-            fused_megakernel,
-            mega_plan,
-        )
-
-        plan = mega_plan(
-            models, bert_config, b=int(batch.features.shape[0]),
-            text_len=int(batch.token_ids.shape[1]),
-            seq_len=int(batch.history.shape[1]),
-            feature_dim=int(batch.features.shape[1]),
-            has_two_hop=batch.user_neigh2_feat is not None)
-        if plan["supported"]:
-            return fused_megakernel(
-                models, batch, params, mega_valid=mega_valid,
-                bert_config=bert_config, interpret=kernel_interpret,
-                block=plan["block"])
     out = _score_fused_impl(
         models, batch, params, model_valid,
         bert_config=bert_config, use_pallas=use_pallas,
@@ -406,7 +375,7 @@ def _score_fused_packed_impl(
 
 _PACKED_STATIC = ("spec", "bert_config", "use_pallas", "tree_kernel",
                   "iforest_kernel", "dequant_kernel", "epilogue_kernel",
-                  "kernel_interpret", "megakernel", "mega_valid")
+                  "kernel_interpret")
 
 score_fused_packed = partial(
     jax.jit, static_argnames=_PACKED_STATIC)(_score_fused_packed_impl)

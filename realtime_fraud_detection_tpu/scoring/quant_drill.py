@@ -362,7 +362,7 @@ def run_quant_drill(cfg: Optional[QuantDrillConfig] = None) -> Dict[str, Any]:
 
 
 def compact_quant_summary(summary: Dict[str, Any]) -> Dict[str, Any]:
-    """<2 KB single-line verdict (the bench.py final-stdout convention)."""
+    """<2 KB single-line verdict (the final stdout line)."""
     div = summary.get("divergence") or {}
     q = summary.get("quality") or {}
     pb = summary.get("param_bytes") or {}

@@ -442,15 +442,6 @@ class FraudScorer:
             raise ValueError(
                 f"KernelSettings.enabled needs a TPU (compiled) or CPU "
                 f"(interpreted) mesh; this scorer's devices are {platform!r}")
-        if (self.kernels.enabled and self.kernels.megakernel == "pallas"
-                and platform == "tpu"):
-            from realtime_fraud_detection_tpu.ops.megakernel import (
-                MEGA_TPU_REFUSAL,
-            )
-
-            raise ValueError(
-                "KernelSettings.megakernel='pallas' does not compile for "
-                f"the TPU: {MEGA_TPU_REFUSAL}")
         self._platform = platform
         self._kernel_interpret = platform == "cpu"
         self._kernel_counts: Dict[str, Dict[str, int]] = {
@@ -459,14 +450,9 @@ class FraudScorer:
         }
         # memoized static-kwarg tuples (kernel_static/quant_static): the
         # hot dispatch path does a dict lookup instead of rebuilding the
-        # dicts per microbatch. Keyed by settings VALUES (+ the QoS rung
-        # for the megakernel), so mutating the settings or stepping the
-        # ladder lands on a different entry — never a stale one.
+        # dicts per microbatch. Keyed by settings VALUES, so mutating the
+        # settings lands on a different entry — never a stale one.
         self._static_cache: Dict[tuple, Dict[str, Any]] = {}
-        # programs-per-microbatch of the most recent dispatch (1 when the
-        # megakernel engages, the chain length otherwise); exported as the
-        # kernel_launches_per_batch gauge
-        self._last_launches_per_batch = 0
         self.ensemble_params = EnsembleParams.from_config(self.config, MODEL_NAMES)
         enabled = self.config.get_enabled_models()
         self.model_valid = np.asarray(
@@ -613,7 +599,7 @@ class FraudScorer:
         # host-assembly plane: cross-batch entity join-row cache
         # (generation-stamped against the profile store), reusable pad
         # staging per bucket, and per-stage wall-clock spans
-        # (assemble/pack/dispatch/device_wait) for the obs plane + bench
+        # (assemble/pack/dispatch/device_wait) for the obs plane
         from realtime_fraud_detection_tpu.features.schema import (
             EntityRowCache,
         )
@@ -670,9 +656,6 @@ class FraudScorer:
         elif kernels.enabled and kernels.dequant_matmul == "pallas":
             refused = ("KernelSettings.dequant_matmul is the int8 "
                        "DistilBERT branch's kernel (ops/dequant_matmul.py)")
-        elif kernels.enabled and kernels.megakernel == "pallas":
-            refused = ("KernelSettings.megakernel fuses the DistilBERT "
-                       "branch (ops/megakernel.py)")
         elif self.mesh.devices.size > 1:
             refused = (f"a sharded mesh of {self.mesh.devices.size} devices "
                        "would split the batch under the grouped expert "
@@ -874,44 +857,30 @@ class FraudScorer:
         }
 
     # ------------------------------------------------------------ kernel plane
-    def kernel_static(self, model_valid=None) -> Dict[str, Any]:
+    def kernel_static(self) -> Dict[str, Any]:
         """The kernel-plane static kwargs for the fused program — threaded
         into every dispatch next to ``quant_static()``. All-off while the
         plane is disabled, so the compiled program (and the packed result
-        layout) is byte-identical to the legacy one.
-
-        With the megakernel on, ``mega_valid`` carries the QoS rung as a
-        compile-time branch-validity tuple (``model_valid`` when given —
-        the pool/mesh retry paths pass their dispatch-time snapshot — else
-        the current effective mask). Each rung is its own jit cache entry:
-        the per-rung program cache. With the megakernel off the key stays
-        None, so stepping the ladder never churns the jit cache (the
-        runtime-mask zero-recompile discipline is untouched). Memoized by
-        settings values + rung — callers splat, never mutate."""
+        layout) is byte-identical to the legacy one. The QoS rung is a
+        runtime mask and never part of the key: stepping the ladder
+        compiles nothing. Memoized by settings values — callers splat,
+        never mutate."""
         k = self.kernels
         if not k.enabled:
             key = ("kernel", False)
             cached = self._static_cache.get(key)
             if cached is None:
                 cached = {"dequant_kernel": "off", "epilogue_kernel": "off",
-                          "kernel_interpret": False,
-                          "megakernel": "off", "mega_valid": None}
+                          "kernel_interpret": False}
                 self._static_cache[key] = cached
             return cached
-        mega_valid = None
-        if k.megakernel == "pallas":
-            mv = (self.effective_model_valid() if model_valid is None
-                  else np.asarray(model_valid))
-            mega_valid = tuple(bool(v) for v in mv)
         key = ("kernel", True, k.dequant_matmul, k.epilogue, k.attention,
-               k.megakernel, self._kernel_interpret, mega_valid)
+               self._kernel_interpret)
         cached = self._static_cache.get(key)
         if cached is None:
             cached = {"dequant_kernel": k.dequant_matmul,
                       "epilogue_kernel": k.epilogue,
-                      "kernel_interpret": self._kernel_interpret,
-                      "megakernel": k.megakernel,
-                      "mega_valid": mega_valid}
+                      "kernel_interpret": self._kernel_interpret}
             self._static_cache[key] = cached
         return cached
 
@@ -982,27 +951,13 @@ class FraudScorer:
         where the selector or the guard sent it to the reference."""
         disp, fall = (self._kernel_counts["dispatch"],
                       self._kernel_counts["fallback"])
-        enabled = self.kernels.enabled
-        if enabled and self.kernels.megakernel == "pallas":
-            # the persistent whole-batch program (ops/megakernel.py). When
-            # its shared shape plan admits the dispatch, ONE program runs
-            # and the per-site kernels below never launch — so their
-            # counters stay untouched (the megakernel subsumes them, it
-            # does not fall back from them). A declined plan counts as a
-            # megakernel fallback AND the per-site chain is accounted as
-            # usual, because that is exactly what the traced guard runs.
-            disp["megakernel"] += 1
-            if self._mega_plan(size, text_len)["supported"]:
-                self._last_launches_per_batch = 1
-                return
-            fall["megakernel"] += 1
         asked = self.effective_use_pallas(
             getattr(self._pool, "program_devices", None), text_len)
         if asked and self._flash_shape_ok(text_len):
             disp["attention"] += 1
         else:
             fall["attention"] += 1
-        if not enabled:
+        if not self.kernels.enabled:
             return
         from realtime_fraud_detection_tpu.models.quant import (
             is_quantized_bert,
@@ -1010,15 +965,10 @@ class FraudScorer:
         from realtime_fraud_detection_tpu.ops import (
             epilogue_supported,
             matmul_supported,
-            mega_launch_accounting,
             rows_supported,
         )
 
         modes = self.kernels.site_modes()
-        self._last_launches_per_batch = mega_launch_accounting(
-            size, NUM_MODELS,
-            mega_valid=tuple(bool(v) for v in self.effective_model_valid()),
-        )["launches_per_batch_chain"]
         h = self.bert_config.hidden_size
         ffn = self.bert_config.intermediate_size
         s = text_len
@@ -1039,33 +989,17 @@ class FraudScorer:
             if not epilogue_supported(size, NUM_MODELS):
                 fall["epilogue"] += 1
 
-    def _mega_plan(self, size: int, text_len: int) -> Dict[str, Any]:
-        """Host mirror of the trace-time megakernel shape plan for a
-        ``size``-row launch at ``text_len`` — the SAME ``mega_plan`` the traced
-        dispatch consults, so ``kernel_fallback_total{site="megakernel"}``
-        equals the compiled program's actual fallback behaviour."""
-        from realtime_fraud_detection_tpu.ops import mega_plan
-
-        return mega_plan(
-            self.models, self.bert_config, b=size,
-            text_len=text_len, seq_len=self.sc.seq_len,
-            feature_dim=self.sc.feature_dim,
-            has_two_hop=self._sampler is not None,
-        )
-
     def kernel_snapshot(self) -> Dict[str, Any]:
         """Kernel-plane observability payload (obs.metrics.sync_kernels):
         effective per-site modes, whether the Pallas interpreter is
-        serving (a CPU mesh), cumulative dispatch/fallback counts per
-        site, and the launch count of the most recent microbatch (1 when
-        the megakernel served it; the per-site chain length otherwise)."""
+        serving (a CPU mesh), and cumulative dispatch/fallback counts per
+        site."""
         return {
             "modes": self.kernels.site_modes(),
             "interpret": bool(self.kernels.enabled
                               and self._kernel_interpret),
             "dispatch": dict(self._kernel_counts["dispatch"]),
             "fallback": dict(self._kernel_counts["fallback"]),
-            "launches_per_batch": self._last_launches_per_batch,
         }
 
     # ---------------------------------------------------------------- assembly
@@ -1195,8 +1129,7 @@ class FraudScorer:
         tokenize) and the rows are stacked at the end — exactly the cost
         profile of the reference's per-request serving loop
         (main.py:235-248). Kept as the equivalence oracle for the columnar
-        path and as the baseline the bench's host-assembly stage measures
-        against. The one batch-level carve-out: graph neighbor sampling for
+        path. The one batch-level carve-out: graph neighbor sampling for
         ALL records precedes this batch's edge inserts, matching the batch
         path's sample-then-insert order (per-record interleaving would make
         row i+1 see row i's edge — a different, order-dependent batch).
@@ -1419,9 +1352,6 @@ class FraudScorer:
         if self._pool is not None:
             return (f"{type(self._pool).__name__}: every replica would "
                     "compile each bucket's family of programs")
-        if self.kernels.enabled and self.kernels.megakernel == "pallas":
-            return ("KernelSettings.megakernel: its shape plan "
-                    "(ops.mega_plan) is made for one text_len")
         return None
 
     def _narrow_text_len(self, full: int) -> Optional[int]:
@@ -1509,7 +1439,7 @@ class FraudScorer:
             blob_bf16=sharded["bf16"],
             bert_config=self.bert_config,
             use_pallas=self.effective_use_pallas(text_len=launch.width),
-            **self.quant_static(), **self.kernel_static(mv),
+            **self.quant_static(), **self.kernel_static(),
         )
 
     def _expert_rows(self, token_slots: int) -> int:

@@ -11,7 +11,7 @@ Two output paths:
   schema (simulator.py:78-101) with stateful fraud appliers — feeds the
   transport / serving / e2e tests.
 - ``generate_encoded(n)``: columns straight into a ``TransactionBatch`` +
-  labels, fully vectorized in NumPy — feeds training and the 50k-TPS bench
+  labels, fully vectorized in NumPy — feeds training
   (the reference's one-thread ``sleep(1/tps)`` pacing loop, simulator.py:437-449,
   tops out near 1k TPS; this path generates millions/min).
 """

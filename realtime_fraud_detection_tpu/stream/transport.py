@@ -6,7 +6,7 @@ FraudDetectionJob.java:141-213). This module provides the same *semantics*
 behind one interface:
 
 - ``InMemoryBroker`` — partitioned, offset-addressed, consumer-group topic
-  log entirely in process. This is the test/dev/bench transport and the
+  log entirely in process. This is the test/dev transport and the
   SURVEY.md §4 "fake in-process transport" testing strategy. Supports
   deterministic fault injection (drop/dup/delay) for failure-path tests.
 - ``KafkaBroker`` (stream/kafka.py) — a real Kafka wire-protocol client

@@ -23,7 +23,7 @@ just-in-time closer + online tuner). The acceptance bar (ISSUE 6):
   bit-identical verdict (p99, close-reason histogram, scored count).
 
 Used by ``rtfd autotune-drill [--fast]`` (final stdout line: a compact
-<2 KB JSON verdict, the bench.py convention) and smoke-tested in tier-1.
+<2 KB JSON verdict) and smoke-tested in tier-1.
 """
 
 from __future__ import annotations
@@ -397,7 +397,7 @@ def run_autotune_drill(
 
 
 def compact_autotune_summary(summary: Dict[str, Any]) -> Dict[str, Any]:
-    """The <2 KB final-stdout-line verdict (bench.py convention)."""
+    """The <2 KB final-stdout-line verdict."""
     ctrl = summary["controller"]
     return {
         "drill": "autotune",

@@ -5,10 +5,9 @@ from __future__ import annotations
 
 def require_tpu(who: str):
     """Return ``jax.devices()[0]`` if it is a TPU; otherwise exit non-zero
-    naming the platform JAX found. ``chip_smoke.py``, ``bench.py``,
-    ``soak_tpu.py`` and ``tune_tpu.py`` call this before anything else: a
-    number or a proof from them is about the chip, so a process that finds
-    no chip stops — it never carries on elsewhere."""
+    naming the platform JAX found. ``chip_smoke.py`` calls this before
+    anything else: a proof from it is about the chip, so a process that
+    finds no chip stops — it never carries on elsewhere."""
     import jax
 
     dev = jax.devices()[0]

@@ -1,7 +1,7 @@
 """Where every entry point keeps JAX's persistent compilation cache.
 
-One rule for the CLI, ``chip_smoke.py``, ``bench.py``/``soak_tpu.py``/
-``tune_tpu.py`` and ``tests/conftest.py``: a ``JAX_COMPILATION_CACHE_DIR``
+One rule for the CLI, ``chip_smoke.py``, ``benchmarks/run.py`` and
+``tests/conftest.py``: a ``JAX_COMPILATION_CACHE_DIR``
 set from outside wins and no other directory is set in code; otherwise the
 cache lives at the fixed ``<checkout>/.jax_cache``. The path is part of
 the cache key, so it never carries a pid, a timestamp or a temp dir.

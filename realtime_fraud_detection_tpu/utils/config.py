@@ -659,7 +659,7 @@ class QuantSettings:
     BRANCH.
 
     Disabled by default — the plane is opt-in per deployment (config/JSON
-    overlay, or the bench/tune/soak ``--quant`` switches). Branch modes
+    overlay, or the CLI's ``--quant`` switches). Branch modes
     are STATIC arguments to the fused program: changing them recompiles
     once (like a combine-strategy change), then every microbatch runs the
     new kernel. The quality gate is ``rtfd quant-drill``: divergence below
@@ -711,8 +711,7 @@ class QuantSettings:
         return {"bert_weights": self.bert_mode()}
 
 
-VALID_KERNEL_SITES = ("dequant_matmul", "epilogue", "attention",
-                      "megakernel")
+VALID_KERNEL_SITES = ("dequant_matmul", "epilogue", "attention")
 VALID_KERNEL_MODES = ("off", "pallas")
 VALID_ATTENTION_KERNELS = ("reference", "flash")
 
@@ -746,7 +745,7 @@ class KernelSettings:
       (``FraudScorer.effective_use_pallas``).
 
     Off by default: the plane is opt-in (config/JSON overlay, or the
-    bench/tune/soak ``--kernels`` switches) until a chip run proves the
+    CLI's ``--kernels`` switches) until a chip run proves the
     MXU bet. Kernel selection is RUNTIME config — never
     serialized into checkpoints, never part of the arch stamp — and the
     modes are STATIC arguments to the fused program (changing them
@@ -759,17 +758,10 @@ class KernelSettings:
     dequant_matmul: str = "off"     # off | pallas
     epilogue: str = "off"           # off | pallas
     attention: str = "reference"    # reference | flash
-    # the persistent whole-microbatch program (ops/megakernel.py). When it
-    # engages it SUBSUMES the three per-site kernels above: one Pallas
-    # program scores the batch end-to-end, and the per-site selections
-    # only matter on shapes the megakernel declines (mega_supported),
-    # which fall back to the per-site chain with honest fallback counts.
-    megakernel: str = "off"         # off | pallas
 
     def validate(self) -> None:
         for name, mode in (("dequant_matmul", self.dequant_matmul),
-                           ("epilogue", self.epilogue),
-                           ("megakernel", self.megakernel)):
+                           ("epilogue", self.epilogue)):
             if mode not in VALID_KERNEL_MODES:
                 raise ValueError(
                     f"kernels.{name} must be one of {VALID_KERNEL_MODES}, "
@@ -786,27 +778,16 @@ class KernelSettings:
         return cls(enabled=True, dequant_matmul="pallas",
                    epilogue="pallas", attention="flash")
 
-    @classmethod
-    def mega(cls) -> "KernelSettings":
-        """The ``--kernels --mega`` preset: the persistent megakernel on
-        top of the full per-site plane, which remains the fallback path
-        for shapes ``mega_supported`` declines (bucket 1, two-hop graph
-        batches, VMEM-oversized param sets)."""
-        return cls(enabled=True, dequant_matmul="pallas",
-                   epilogue="pallas", attention="flash",
-                   megakernel="pallas")
-
     def site_modes(self) -> Dict[str, str]:
         """Effective per-site modes (everything off while disabled) —
         the shape ``FraudScorer.kernel_snapshot`` and the kernel_*
         Prometheus series report."""
         if not self.enabled:
             return {"dequant_matmul": "off", "epilogue": "off",
-                    "attention": "reference", "megakernel": "off"}
+                    "attention": "reference"}
         return {"dequant_matmul": self.dequant_matmul,
                 "epilogue": self.epilogue,
-                "attention": self.attention,
-                "megakernel": self.megakernel}
+                "attention": self.attention}
 
 
 @dataclass

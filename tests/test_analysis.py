@@ -136,7 +136,7 @@ class TestD2hRule:
         src = ("import jax\n"
                "def f(x):\n"
                "    jax.block_until_ready(x)\n")
-        assert lint_source(src, "utils/timing.py") == []
+        assert lint_source(src, "scoring/device_pool.py") == []
 
 
 METRICS_SRC = (
@@ -452,58 +452,3 @@ class TestLockwatchUnderDrills:
         verdict = json.loads(last)
         assert verdict["passed"] is True, verdict
         assert set(verdict["lockwatch"]) == set(LOCKWATCH_DRILLS)
-
-
-# ---------------------------------------------------------------------------
-# bench satellite: the tuner's bucket set reconciles into the sweep
-# ---------------------------------------------------------------------------
-
-def _load_bench():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_under_test", REPO_ROOT / "bench.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-class TestBenchTunedBucketReconcile:
-    def test_autotune_stage_records_tuned_bucket_set(self):
-        from realtime_fraud_detection_tpu.core.batching import BATCH_BUCKETS
-
-        bench = _load_bench()
-        result = {}
-        bench._autotune_stage(result, lambda *a, **k: None)
-        at = result["autotune"]
-        assert at["passed"] is True
-        assert isinstance(at["tuned_bucket_set"], list)
-        assert at["tuned_bucket_set"] == sorted(at["tuned_bucket_set"])
-        assert at["tuned_bucket_set"]
-        assert set(at["tuned_bucket_set"]) <= set(BATCH_BUCKETS)
-
-    def test_compact_summary_carries_both_bucket_truths(self):
-        bench = _load_bench()
-        op = {"batch": 128, "txn_per_s": 9000.0, "p99_net_of_rtt_ms": 14.0}
-        result = {
-            "metric": "m", "value": 1.0, "device": "cpu",
-            "bucket_sweep": {
-                "passing": [64, 128],
-                "operating_point": op,
-                "tuned_set": [32, 128],
-                "tuned_set_passing": [128],
-                "operating_point_tuned": op,
-                "buckets": {},
-            },
-        }
-        compact = bench._compact_summary(result)
-        assert compact["sweep_passing"] == [64, 128]
-        assert compact["sweep_tuned"] == {
-            "set": [32, 128], "passing": [128], "operating_batch": 128}
-        assert len(json.dumps(compact, separators=(",", ":"))) < 2048
-
-    def test_compact_summary_omits_tuned_view_when_absent(self):
-        bench = _load_bench()
-        compact = bench._compact_summary(
-            {"metric": "m", "value": 1.0, "bucket_sweep": {"passing": []}})
-        assert compact["sweep_tuned"] is None

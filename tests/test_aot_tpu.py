@@ -18,8 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from realtime_fraud_detection_tpu.models.bert import BertConfig, TINY_CONFIG
-from realtime_fraud_detection_tpu.ops.megakernel import MEGA_TPU_REFUSAL
+from realtime_fraud_detection_tpu.models.bert import BertConfig
 
 FULL = BertConfig()           # DistilBERT-base widths: 6 x 768, FFN 3072
 TEXT_LEN = 64
@@ -146,59 +145,6 @@ def _quantized(models):
 
     return models.replace(
         bert=quantize_bert_params(jax.device_get(models.bert)))
-
-
-@pytest.fixture(scope="module")
-def mega_case():
-    """One shape ``mega_plan`` admits: int8 TINY dims, bucket 128."""
-    from realtime_fraud_detection_tpu.ops import mega_plan
-    from realtime_fraud_detection_tpu.scoring.pipeline import (
-        ScorerConfig,
-        init_scoring_models,
-        make_example_batch,
-    )
-
-    sc = ScorerConfig()
-    models = _quantized(init_scoring_models(jax.random.PRNGKey(0),
-                                            bert_config=TINY_CONFIG))
-    plan = mega_plan(models, TINY_CONFIG, b=128, text_len=sc.text_len,
-                     seq_len=sc.seq_len, feature_dim=sc.feature_dim,
-                     has_two_hop=False)
-    return models, make_example_batch(128, sc), plan
-
-
-def test_megakernel_plan_admits_the_aot_shape(mega_case):
-    assert mega_case[2]["supported"]
-
-
-def test_megakernel_block_specs_pass_mosaic(one_chip, mega_case):
-    """The rank-1 batch operands ride as [B, 1]: the lowering gets past the
-    block-spec check that refused a row-blocked rank-1 operand, and what
-    stops it now is in the kernel BODY (see the xfail below)."""
-    with pytest.raises(Exception) as err:
-        _compile_megakernel(one_chip, mega_case)
-    assert "rank 1 block shapes" not in str(err.value)
-
-
-def _compile_megakernel(one_chip, mega_case):
-    from realtime_fraud_detection_tpu.ensemble.combine import EnsembleParams
-    from realtime_fraud_detection_tpu.ops import fused_megakernel
-    from realtime_fraud_detection_tpu.scoring.pipeline import MODEL_NAMES
-    from realtime_fraud_detection_tpu.utils.config import Config
-
-    models, batch, plan = mega_case
-    params = EnsembleParams.from_config(Config(), list(MODEL_NAMES))
-    return jax.jit(lambda m, b: fused_megakernel(
-        m, b, params, mega_valid=(True,) * len(MODEL_NAMES),
-        bert_config=TINY_CONFIG, block=plan["block"])).lower(
-        _shapes_of(models, one_chip), _shapes_of(batch, one_chip)).compile()
-
-
-@pytest.mark.xfail(strict=True, reason=MEGA_TPU_REFUSAL)
-def test_megakernel_compiles_for_v5e(one_chip, mega_case):
-    """Strict: the day Mosaic accepts the body this turns red, and the
-    refusal in FraudScorer (and ROADMAP D3) is what has to change."""
-    _compile_megakernel(one_chip, mega_case)
 
 
 def test_whole_program_with_kernels_compiles_at_bucket_256(one_chip):

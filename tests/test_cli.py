@@ -1,4 +1,4 @@
-"""CLI tests. The heavyweight commands (run-job, serve, bench) are driven
+"""CLI tests. The heavyweight commands (run-job, serve) are driven
 in their own layers' tests; here the parser contract, simulate, train, and
 health-check paths are exercised in-process."""
 
@@ -19,7 +19,6 @@ class TestParser:
         ["run-job", "--count", "100", "--analytics"],
         ["serve", "--port", "9999"],
         ["train", "--rows", "500"],
-        ["bench"],
         ["lint", "--format", "json"],
         ["lint", "--lockwatch", "--fast"],
         ["health-check", "--url", "http://x"],
@@ -28,6 +27,21 @@ class TestParser:
     def test_all_subcommands_parse(self, argv):
         args = build_parser().parse_args(argv)
         assert callable(args.fn)
+
+    @pytest.mark.parametrize("argv", [
+        ["run-job", "--mega"],
+        ["serve", "--mega"],
+        ["kernel-drill", "--fast", "--mega"],
+        ["bench"],
+    ])
+    def test_what_was_deleted_is_a_usage_error(self, argv, capsys):
+        """The megakernel's switch and the pre-benchmark ``bench`` command
+        are gone (PR 28): asking for one is argparse's error, not a
+        silently ignored flag."""
+        with pytest.raises(SystemExit) as err:
+            build_parser().parse_args(argv)
+        assert err.value.code == 2
+        assert argv[-1].lstrip("-") in capsys.readouterr().err
 
 
 class TestSimulate:

@@ -6,6 +6,7 @@ kernel_* Prometheus mirror, checkpoint hygiene (kernel selection is
 runtime config, never serialized), device-pool/mesh composition, and the
 `rtfd kernel-drill --fast` tier-1 smoke."""
 
+import dataclasses
 import json
 
 import jax
@@ -51,6 +52,7 @@ from realtime_fraud_detection_tpu.scoring import (
 )
 from realtime_fraud_detection_tpu.sim.simulator import TransactionGenerator
 from realtime_fraud_detection_tpu.utils.config import (
+    VALID_KERNEL_SITES,
     Config,
     KernelSettings,
     QuantSettings,
@@ -328,14 +330,10 @@ class TestKernelSettings:
                            attention="flash")       # enabled=False gates all
         assert s.site_modes() == {"dequant_matmul": "off",
                                   "epilogue": "off",
-                                  "attention": "reference",
-                                  "megakernel": "off"}
+                                  "attention": "reference"}
         assert KernelSettings.full().site_modes() == {
             "dequant_matmul": "pallas", "epilogue": "pallas",
-            "attention": "flash", "megakernel": "off"}
-        assert KernelSettings.mega().site_modes() == {
-            "dequant_matmul": "pallas", "epilogue": "pallas",
-            "attention": "flash", "megakernel": "pallas"}
+            "attention": "flash"}
 
     def test_config_overlay_round_trip(self, tmp_path):
         p = tmp_path / "k.json"
@@ -345,6 +343,25 @@ class TestKernelSettings:
         assert loaded.enabled and loaded.attention == "flash"
         assert loaded.dequant_matmul == "off"       # per-site independence
 
+    def test_the_deleted_megakernel_site_is_not_a_field(self):
+        with pytest.raises(TypeError, match="megakernel"):
+            KernelSettings(enabled=True, megakernel="pallas")
+        assert [f.name for f in dataclasses.fields(KernelSettings)] == [
+            "enabled", "dequant_matmul", "epilogue", "attention"]
+        assert VALID_KERNEL_SITES == ("dequant_matmul", "epilogue",
+                                      "attention")
+
+    def test_an_overlay_that_still_names_it_is_warned_by_name(
+            self, tmp_path, caplog):
+        p = tmp_path / "k.json"
+        p.write_text(json.dumps({"kernels": {"enabled": True,
+                                             "megakernel": "pallas"}}))
+        with caplog.at_level("WARNING"):
+            loaded = Config.from_file(str(p)).kernels
+        assert loaded.enabled and not hasattr(loaded, "megakernel")
+        assert any("unknown key 'megakernel' on KernelSettings"
+                   in r.getMessage() for r in caplog.records)
+
 
 # --------------------------------------------------------- scorer threading
 class TestScorerKernelPlane:
@@ -352,13 +369,10 @@ class TestScorerKernelPlane:
         _, s = _scorer(kernels=False, quant=False)
         assert s.kernel_static() == {"dequant_kernel": "off",
                                      "epilogue_kernel": "off",
-                                     "kernel_interpret": False,
-                                     "megakernel": "off",
-                                     "mega_valid": None}
+                                     "kernel_interpret": False}
         assert not hasattr(s.sc, "use_pallas")      # nothing a user sets
         assert s.kernel_snapshot()["dispatch"] == {
-            "dequant_matmul": 0, "epilogue": 0, "attention": 0,
-            "megakernel": 0}
+            "dequant_matmul": 0, "epilogue": 0, "attention": 0}
 
     def test_selector_picks_the_reference_on_cpu(self):
         """Plane off, CPU devices: the selector never asks for the kernel,
@@ -440,11 +454,38 @@ class TestScorerKernelPlane:
         s.score_batch(gen.generate_batch(BATCH), now=1000.0)
         snap = s.kernel_snapshot()
         assert snap["interpret"] is True
-        # full() leaves the megakernel site off — the per-site chain runs
-        assert all(snap["dispatch"][site] == 2 for site in snap["dispatch"]
-                   if site != "megakernel")
-        assert snap["dispatch"]["megakernel"] == 0
+        assert all(v == 2 for v in snap["dispatch"].values())
         assert all(v == 0 for v in snap["fallback"].values())
+
+    @pytest.mark.parametrize("rung", range(len(LADDER_LEVELS)),
+                             ids=[r.name for r in LADDER_LEVELS])
+    @pytest.mark.parametrize("kernels", [False, True],
+                             ids=["plane-off", "plane-full"])
+    def test_more_batches_and_a_qos_rung_compile_nothing(self, kernels,
+                                                         rung):
+        """The ladder's mask is a runtime tensor: after the first batch,
+        further batches and a step to any rung reach the program the first
+        one compiled, through the same memoized static dicts."""
+        from realtime_fraud_detection_tpu.qos.ladder import DegradationLadder
+        from realtime_fraud_detection_tpu.scoring.pipeline import (
+            score_fused_packed,
+        )
+
+        gen, s = _scorer(kernels=kernels)
+        kernel_static, quant_static = s.kernel_static(), s.quant_static()
+        s.score_batch(gen.generate_batch(BATCH), now=1000.0)
+        compiled = score_fused_packed._cache_size()
+        for _ in range(3):
+            s.score_batch(gen.generate_batch(BATCH), now=1000.0)
+        level = LADDER_LEVELS[rung]
+        s.set_degradation(
+            DegradationLadder().level_mask(MODEL_NAMES, level=rung),
+            rules_only=level.rules_only, level=rung)
+        results = s.score_batch(gen.generate_batch(BATCH), now=1000.0)
+        assert len(results) == BATCH
+        assert score_fused_packed._cache_size() == compiled
+        assert s.kernel_static() is kernel_static
+        assert s.quant_static() is quant_static
 
     def test_f32_params_count_dequant_fallback(self):
         """Honesty pin: kernels on over an f32 (unquantized) scorer — the
@@ -634,7 +675,6 @@ class TestCliFlags:
         p = build_parser()
         assert p.parse_args(["run-job", "--kernels"]).kernels is True
         assert p.parse_args(["serve", "--kernels"]).kernels is True
-        assert p.parse_args(["bench", "--kernels"]).kernels is True
         args = p.parse_args(["kernel-drill", "--fast", "--no-replay",
                              "--seed", "5"])
         assert args.fast and args.no_replay and args.seed == 5
@@ -715,14 +755,3 @@ def test_kernels_refuse_a_platform_they_neither_compile_nor_interpret_for():
     with pytest.raises(ValueError, match="'gpu'"):
         FraudScorer(Config(kernels=KernelSettings.full()),
                     mesh=_mesh_on("gpu"))
-
-
-def test_megakernel_is_refused_on_a_tpu_mesh_with_the_compilers_message():
-    from realtime_fraud_detection_tpu.ops.megakernel import MEGA_TPU_REFUSAL
-
-    with pytest.raises(ValueError) as err:
-        FraudScorer(Config(kernels=KernelSettings.mega()),
-                    mesh=_mesh_on("tpu"))
-    assert MEGA_TPU_REFUSAL in str(err.value)
-    # the per-site plane is NOT refused there: it compiles (test_aot_tpu)
-    assert "megakernel" in str(err.value)

@@ -535,10 +535,6 @@ def _refusals():
         _scorer(config=_config(kernels=KernelSettings(
             enabled=True, dequant_matmul="pallas")))
 
-    def megakernel():
-        _scorer(config=_config(kernels=KernelSettings(
-            enabled=True, megakernel="pallas")))
-
     def sharded_mesh():
         _scorer(mesh=build_mesh())             # the suite's 8 virtual devices
 
@@ -571,7 +567,7 @@ def _refusals():
         bert_context_parallel_predict(None, {}, None, None, CFG)
 
     return [(quant, "QuantSettings"), (dequant, "dequant_matmul"),
-            (megakernel, "megakernel"), (sharded_mesh, "sharded mesh"),
+            (sharded_mesh, "sharded mesh"),
             (device_pool, "DevicePool"), (mesh_executor, "MeshExecutor"),
             (pipeline_parallel, "parallel/pipeline"),
             (context_parallel, "parallel/context")]

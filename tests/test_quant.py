@@ -319,7 +319,6 @@ class TestCliFlags:
         p = build_parser()
         assert p.parse_args(["run-job", "--quant"]).quant is True
         assert p.parse_args(["serve", "--quant"]).quant is True
-        assert p.parse_args(["bench", "--quant"]).quant is True
         args = p.parse_args(["quant-drill", "--fast", "--no-replay",
                              "--seed", "5"])
         assert args.fast and args.no_replay and args.seed == 5

@@ -272,21 +272,16 @@ def test_split_launches_equal_the_unsplit_program(models, case):
 
 def test_planes_that_cannot_take_a_second_width_keep_the_unsplit_launch(
         models):
-    from realtime_fraud_detection_tpu.utils.config import KernelSettings
-
     s = FraudScorer(models=models, scorer_config=ScorerConfig(text_len=256))
     assert s.text_split_refusal() is None
     assert s.host_stats()["text_split"]["width"] == NARROW
-    s.kernels = KernelSettings(enabled=True, megakernel="pallas")
-    assert "megakernel" in s.text_split_refusal()
-    assert s._narrow_text_len(256) is None
-    s.kernels = KernelSettings()
 
     class StandInPool:
         batch_multiple = None
 
     s._pool = StandInPool()
     assert "StandInPool" in s.text_split_refusal()
+    assert s._narrow_text_len(256) is None
     assert s.host_stats()["text_split"]["width"] is None
     s._pool = None
     # at or under the narrowest width there is nothing narrower
@@ -298,3 +293,60 @@ def test_planes_that_cannot_take_a_second_width_keep_the_unsplit_launch(
     assert narrowest_supported_len(64, 12) == NARROW
     assert narrowest_supported_len(128, 16) is None
     assert narrowest_supported_len(32, 4) is None
+
+
+# ------------------------------- one list of the program's static arguments
+@pytest.mark.parametrize("executor", ["scorer", "DevicePool", "MeshExecutor"])
+def test_every_launch_passes_the_one_list_of_static_arguments(
+        models, executor, monkeypatch):
+    """``pipeline._PACKED_STATIC`` is the program's static arguments, once:
+    what the impl's signature takes beside its arrays, what a launch passes
+    (``spec``, ``bert_config``, ``use_pallas`` and the two planes' memoized
+    dicts), and — plus the two the re-gather reads — the mesh entry's."""
+    import inspect
+
+    from realtime_fraud_detection_tpu.scoring import (
+        device_pool,
+        mesh_executor,
+        pipeline,
+        scorer as scorer_mod,
+    )
+
+    arrays = {"models", "blob_f32", "blob_i32", "blob_u8", "blob_bf16",
+              "params", "model_valid"}
+    static = pipeline._PACKED_STATIC
+    assert len(set(static)) == len(static)
+    assert set(static) == set(inspect.signature(
+        pipeline._score_fused_packed_impl).parameters) - arrays
+
+    s = FraudScorer(models=models, scorer_config=ScorerConfig())
+    assert set(static) == ({"spec", "bert_config", "use_pallas"}
+                           | set(s.quant_static()) | set(s.kernel_static()))
+    if executor == "scorer":
+        module, names, extra = scorer_mod, ("score_fused_packed",), ()
+    elif executor == "DevicePool":
+        device_pool.DevicePool(s, devices=jax.devices()[:2])
+        # the pool imports the two entries where it launches
+        module, extra = pipeline, ()
+        names = ("score_fused_packed", "score_fused_packed_donated")
+    else:
+        mesh_executor.MeshExecutor(s, devices=jax.devices()[:2])
+        module, extra = mesh_executor, ("gather_fields", "mesh")
+        names = ("mesh_score_packed", "mesh_score_packed_donated")
+        assert mesh_executor._MESH_STATIC == static + extra
+        assert set(inspect.signature(
+            mesh_executor._mesh_score_packed_impl).parameters) == (
+            arrays | set(extra) | {"statics"})
+    passed = []
+    for name in names:
+        real = getattr(module, name)
+
+        def spy(*args, _real=real, **kwargs):
+            passed.append(set(kwargs) - arrays)
+            return _real(*args, **kwargs)
+
+        monkeypatch.setattr(module, name, spy)
+    gen = TransactionGenerator(num_users=50, num_merchants=20, seed=3)
+    s.seed_profiles(gen.users.profiles(), gen.merchants.profiles())
+    assert len(s.score_batch(gen.generate_batch(8), now=1000.0)) == 8
+    assert passed and all(p == set(static + extra) for p in passed)
