@@ -13,8 +13,8 @@ The layer equations are those of ``allenai/OLMoE-1B-7B-0125-Instruct``'s
   W_router)`` over all ``num_experts`` in float32, the ``num_experts_per_tok``
   largest taken WITHOUT renormalising (``norm_topk_prob`` false), each a
   SwiGLU expert ``down(silu(gate(x)) * up(x))`` of width
-  ``intermediate_size``. Every token reaches all of its experts: there is no
-  capacity and no token is dropped;
+  ``intermediate_size``. Every token reaches all of its experts: no token
+  is dropped (a *capacity*, below, is a shape, not a limit);
 - after the last layer the final RMSNorm; the text is right-padded
   (``models/tokenizer.py``), so the pooled position is the last real token;
   a bias-free ``Linear(hidden -> 2)`` head, ``p_text = softmax(logits)[1]``
@@ -27,6 +27,22 @@ matmuls (``ops/grouped_matmul.py``) run over the ragged groups, and the rows
 are gathered home and summed with their router weights. ``route`` and
 ``apply_experts`` are the two halves, held separately by the tests.
 
+Only the batch's REAL tokens are routed. Nothing a padding position computes
+reaches an answer (right-padded text, causal and key-masked attention, the
+head reads the last real token), so its (token, expert) pairs belong to no
+expert group: they sort last, ``group_sizes`` sums to real tokens x
+``num_experts_per_tok``, the grouped matmuls never visit their rows, and
+the block adds zero to the residual stream there. A **capacity** C is how
+many token slots the routed block is compiled for: with C under the
+launch's ``B x T`` slots the real slots are compacted, in slot order, into
+``[C, hidden]`` ahead of the router (``token_slots``), the router, sort,
+gatherings and matmuls run on C rows, and the result is scattered home.
+The caller picks C, a static argument, and answers for C holding every
+real token of the launch (``scoring/text_split.capacity`` picks it on the
+host from the mask it counts; ``FraudScorer`` refuses a launch that would
+not fit): tokens past C would be left out without a sign. None is every
+slot: nothing is gathered or scattered. There is no branch in the program.
+
 Precision: weights stored bfloat16 (the checkpoint's dtype), bfloat16 matmul
 operands with float32 accumulation, float32 norms, softmaxes, RoPE and
 residual stream; the router matmul in float32 at ``Precision.HIGHEST`` (a
@@ -37,7 +53,7 @@ rounding flips).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -179,19 +195,46 @@ def route(x: jax.Array, w_router: jax.Array, top_k: int
     return experts.astype(jnp.int32), weights
 
 
+def token_slots(attention_mask: jax.Array, capacity: Optional[int]
+                ) -> Tuple[Optional[jax.Array], jax.Array]:
+    """What the routed block of a launch runs on: ``(idx, real)``. With
+    ``capacity`` C under the ``B x T`` slots, ``idx i32[C]`` holds the real
+    slots in slot order, then fillers past the last slot (each its own, so
+    the indices stay sorted and unique and a scatter drops them), and
+    ``real bool[C]`` tells them apart. With None (or every slot) nothing is
+    compacted: ``idx`` is None and ``real`` is the mask itself. Once a
+    launch: the layers share it."""
+    flat = attention_mask.reshape(-1).astype(bool)
+    n = flat.shape[0]
+    if capacity is None or capacity == n:
+        return None, flat
+    if not 0 < capacity < n:
+        raise ValueError(f"capacity {capacity} of a launch of {n} slots")
+    idx = jnp.nonzero(flat, size=capacity, fill_value=n)[0].astype(jnp.int32)
+    real = idx < n
+    return jnp.where(real, idx, n + jnp.arange(capacity, dtype=jnp.int32)
+                     ), real
+
+
 def apply_experts(layer: Dict, x: jax.Array, experts: jax.Array,
-                  weights: jax.Array, *, use_pallas: bool = False,
-                  kernel_interpret: bool = False
+                  weights: jax.Array, *, real: Optional[jax.Array] = None,
+                  use_pallas: bool = False, kernel_interpret: bool = False
                   ) -> Tuple[jax.Array, jax.Array]:
     """``sum_e weights[n, e] * expert_e(x[n])`` for the routed ``experts``:
     ``(f32[N, hidden], group_sizes i32[num_experts])``. ``x`` is ``[N,
-    hidden]``. No capacity: every (token, expert) pair is computed."""
+    hidden]``. Every (token, expert) pair of a row that ``real`` (``bool[N]``;
+    None: every row) admits is computed; the other rows' pairs enter no
+    group and their result is zero."""
     n, top_k = experts.shape
     num_experts = layer["gate_proj"].shape[0]
     with jax.named_scope(scopes.ROUTER):
         # the (token, expert) pairs in expert order; a stable sort keeps a
         # group's rows in token order
         flat = experts.reshape(-1)
+        if real is not None:
+            # keyed past the last expert, the other rows' pairs sort last
+            # and are counted in no group
+            flat = jnp.where(jnp.repeat(real, top_k), flat, num_experts)
         order = jnp.argsort(flat, stable=True).astype(jnp.int32)
         group_sizes = jnp.sum(
             flat[:, None] == jnp.arange(num_experts, dtype=jnp.int32)[None],
@@ -212,6 +255,10 @@ def apply_experts(layer: Dict, x: jax.Array, experts: jax.Array,
     with jax.named_scope(scopes.EXPERTS_COMBINE):
         back = out[home].reshape(n, top_k, -1)                 # token order
         y = jnp.sum(back * weights[:, :, None], axis=1)
+        if real is not None:
+            # the kernel never wrote the rows past the last group
+            # (ops/grouped_matmul.py): whatever they hold, it stops here
+            y = jnp.where(real[:, None], y, 0.0)
     return y, group_sizes
 
 
@@ -244,22 +291,34 @@ def olmoe_attention(layer: Dict, h: jax.Array, attention_mask: jax.Array,
 
 
 def olmoe_layer(layer: Dict, h: jax.Array, attention_mask: jax.Array,
-                config: OlmoeConfig, cos, sin, *, use_pallas: bool = False,
-                kernel_interpret: bool = False
+                config: OlmoeConfig, cos, sin, *,
+                slots: Optional[Tuple[Optional[jax.Array], jax.Array]] = None,
+                use_pallas: bool = False, kernel_interpret: bool = False
                 ) -> Tuple[jax.Array, jax.Array]:
     """One pre-norm block on ``h`` ``f32[B, T, hidden]``; also the largest
-    expert group of the layer (``i32[]``)."""
+    expert group of the layer (``i32[]``). ``slots`` is the launch's
+    ``token_slots`` (None: every real slot, uncompacted)."""
     b, t, width = h.shape
+    idx, real = slots if slots is not None else token_slots(attention_mask,
+                                                            None)
     h = olmoe_attention(layer, h, attention_mask, config, cos, sin)
     with jax.named_scope(scopes.LN):
         x = rms_norm(h, layer["post_attention_layernorm"],
                      config.rms_norm_eps).reshape(b * t, width)
+    if idx is not None:
+        with jax.named_scope(scopes.EXPERTS_DISPATCH):
+            x = x.at[idx].get(mode="fill", fill_value=0.0)     # [C, width]
     with jax.named_scope(scopes.ROUTER):
         experts, weights = route(x, layer["router"],
                                  config.num_experts_per_tok)
     y, group_sizes = apply_experts(
-        layer, x, experts, weights, use_pallas=use_pallas,
+        layer, x, experts, weights, real=real, use_pallas=use_pallas,
         kernel_interpret=kernel_interpret)
+    if idx is not None:
+        with jax.named_scope(scopes.EXPERTS_COMBINE):
+            # home: the fillers' indices lie past the last slot and drop
+            y = jnp.zeros((b * t, width), y.dtype).at[idx].set(
+                y, mode="drop", indices_are_sorted=True, unique_indices=True)
     with jax.named_scope(scopes.LN):
         h = h + y.reshape(b, t, width)
     return h, jnp.max(group_sizes)
@@ -267,19 +326,23 @@ def olmoe_layer(layer: Dict, h: jax.Array, attention_mask: jax.Array,
 
 def olmoe_encode(params: Dict, input_ids: jax.Array,
                  attention_mask: jax.Array, config: OlmoeConfig, *,
+                 capacity: Optional[int] = None,
                  use_pallas: bool = False, kernel_interpret: bool = False
                  ) -> Tuple[jax.Array, jax.Array]:
     """Hidden states before the final norm ``f32[B, T, hidden]`` and the
-    largest expert group of each layer ``i32[layers]``."""
+    largest expert group of each layer ``i32[layers]``. ``capacity``: the
+    token slots the routed blocks are compiled for (the module's
+    docstring)."""
     t = input_ids.shape[1]
     cos, sin = rope_tables(t, config.head_dim, config.rope_theta)
+    slots = token_slots(attention_mask, capacity)
     with jax.named_scope(scopes.EMBED):
         h = params["embed_tokens"][input_ids].astype(jnp.float32)
     peaks = []
     for i, layer in enumerate(params["layers"]):
         with jax.named_scope(scopes.layer_scope(i)):
             h, peak = olmoe_layer(layer, h, attention_mask, config, cos, sin,
-                                  use_pallas=use_pallas,
+                                  slots=slots, use_pallas=use_pallas,
                                   kernel_interpret=kernel_interpret)
         peaks.append(peak)
     return h, jnp.stack(peaks)
@@ -287,12 +350,13 @@ def olmoe_encode(params: Dict, input_ids: jax.Array,
 
 def olmoe_logits(params: Dict, input_ids: jax.Array,
                  attention_mask: jax.Array, config: OlmoeConfig, *,
+                 capacity: Optional[int] = None,
                  use_pallas: bool = False, kernel_interpret: bool = False
                  ) -> Tuple[jax.Array, jax.Array]:
     """Sequence-classification logits ``f32[B, num_labels]`` from the last
     real token, and ``i32[layers]`` largest expert group per layer."""
     hidden, peaks = olmoe_encode(params, input_ids, attention_mask, config,
-                                 use_pallas=use_pallas,
+                                 capacity=capacity, use_pallas=use_pallas,
                                  kernel_interpret=kernel_interpret)
     with jax.named_scope(scopes.HEAD):
         last = jnp.maximum(
@@ -306,13 +370,14 @@ def olmoe_logits(params: Dict, input_ids: jax.Array,
 
 def olmoe_predict(params: Dict, input_ids: jax.Array,
                   attention_mask: jax.Array, config: OlmoeConfig, *,
+                  capacity: Optional[int] = None,
                   use_pallas: bool = False, kernel_interpret: bool = False,
                   with_stats: bool = False):
     """Fraud probability ``f32[B]`` = ``softmax(logits)[:, 1]``; with
     ``with_stats`` also the ``i32[layers]`` largest expert group per layer
     (what ``StreamJob.counters['expert_peak_rows']`` sums)."""
     logits, peaks = olmoe_logits(params, input_ids, attention_mask, config,
-                                 use_pallas=use_pallas,
+                                 capacity=capacity, use_pallas=use_pallas,
                                  kernel_interpret=kernel_interpret)
     p = jax.nn.softmax(logits, axis=-1)[:, 1]
     return (p, peaks) if with_stats else p

@@ -87,21 +87,28 @@ def init_text_params(key: jax.Array, config: TextConfig) -> Dict[str, Any]:
 def text_predict(params: Dict[str, Any], input_ids: jax.Array,
                  attention_mask: jax.Array, config: TextConfig, *,
                  use_pallas: bool = False, dequant_kernel: str = "off",
-                 kernel_interpret: bool = False
+                 kernel_interpret: bool = False,
+                 capacity: Optional[int] = None
                  ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """The text branch's probability ``f32[B]`` from the encoder
     ``config``'s class names, and that encoder's per-launch statistics:
     ``i32[layers]`` largest expert group for the MoE encoder, ``None`` for
-    the dense one (whose program is then what it was)."""
+    the dense one (whose program is then what it was). ``capacity`` is the
+    MoE encoder's (``models/olmoe.py``): the token slots its routed blocks
+    are compiled for."""
     if isinstance(config, OlmoeConfig):
         if dequant_kernel != "off":
             raise ValueError(
                 "KernelSettings.dequant_matmul is DistilBERT's int8 plane; "
                 "the OLMoE encoder has no quantized form")
         return olmoe_predict(params, input_ids, attention_mask, config,
-                             use_pallas=use_pallas,
+                             capacity=capacity, use_pallas=use_pallas,
                              kernel_interpret=kernel_interpret,
                              with_stats=True)
+    if capacity is not None:
+        raise ValueError(
+            "text_capacity is the OLMoE encoder's routed block's; the dense "
+            "encoder has nothing to compact")
     return bert_predict(params, input_ids, attention_mask, config,
                         use_pallas=use_pallas, dequant_kernel=dequant_kernel,
                         kernel_interpret=kernel_interpret), None
@@ -206,6 +213,7 @@ def _score_fused_impl(
     dequant_kernel: str = "off",     # kernel plane (KernelSettings): Pallas
     epilogue_kernel: str = "off",    # fused dequant-matmul / score-blend
     kernel_interpret: bool = False,  # Pallas interpreter (CPU meshes)
+    text_capacity: Optional[int] = None,  # MoE text encoder: slots routed
 ) -> Dict[str, jax.Array]:
     """Score one microbatch through the full 5-model ensemble.
 
@@ -231,7 +239,7 @@ def _score_fused_impl(
             models.bert, batch.token_ids, batch.token_mask,
             bert_config, use_pallas=use_pallas,
             dequant_kernel=dequant_kernel,
-            kernel_interpret=kernel_interpret,
+            kernel_interpret=kernel_interpret, capacity=text_capacity,
         )
     with jax.named_scope(scopes.GNN):
         p_gnn = jax.nn.sigmoid(
@@ -279,7 +287,7 @@ score_fused = partial(
     jax.jit,
     static_argnames=("bert_config", "use_pallas", "with_model_preds",
                      "tree_kernel", "iforest_kernel", "dequant_kernel",
-                     "epilogue_kernel", "kernel_interpret"),
+                     "epilogue_kernel", "kernel_interpret", "text_capacity"),
 )(_score_fused_impl)
 
 
@@ -324,6 +332,7 @@ def _score_fused_packed_impl(
     dequant_kernel: str = "off",
     epilogue_kernel: str = "off",
     kernel_interpret: bool = False,
+    text_capacity: Optional[int] = None,
 ) -> jax.Array:
     """Packed fused scorer: packed blobs in, one matrix out.
 
@@ -332,7 +341,9 @@ def _score_fused_packed_impl(
     response fields as ONE f32[B, 8+M] matrix (one d2h payload) laid out per
     ``OUT_COLUMNS`` + model_predictions — and, with an ``OlmoeConfig`` only,
     a second small output beside it, ``(matrix, i32[layers])``: the largest
-    expert group of each layer. XLA fuses the unpack slices into
+    expert group of each layer; ``text_capacity`` is that encoder's too
+    (how many token slots its routed blocks run on: ``models/olmoe.py``;
+    absent from a dense launch). XLA fuses the unpack slices into
     the branch consumers, so the repack costs nothing on-device. What the
     transfer count is worth on local hardware is not measured.
     """
@@ -355,7 +366,7 @@ def _score_fused_packed_impl(
         with_model_preds=True,
         tree_kernel=tree_kernel, iforest_kernel=iforest_kernel,
         dequant_kernel=dequant_kernel, epilogue_kernel=epilogue_kernel,
-        kernel_interpret=kernel_interpret,
+        kernel_interpret=kernel_interpret, text_capacity=text_capacity,
     )
     with jax.named_scope(scopes.REPACK):
         cols = [out[name].astype(jnp.float32) for name in OUT_COLUMNS]
@@ -375,7 +386,7 @@ def _score_fused_packed_impl(
 
 _PACKED_STATIC = ("spec", "bert_config", "use_pallas", "tree_kernel",
                   "iforest_kernel", "dequant_kernel", "epilogue_kernel",
-                  "kernel_interpret")
+                  "kernel_interpret", "text_capacity")
 
 score_fused_packed = partial(
     jax.jit, static_argnames=_PACKED_STATIC)(_score_fused_packed_impl)

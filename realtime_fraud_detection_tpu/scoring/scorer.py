@@ -119,16 +119,21 @@ class PendingScore:
     token_slots_sq: int = 0
     real_tokens: int = 0
     # The MoE text encoder only (models/olmoe.py; 0 / None otherwise):
-    # ``expert_rows`` = rows launched into the grouped expert matmuls
-    # (slots x experts per token x layers, counted at dispatch);
+    # ``expert_rows`` = the (token, expert) pairs that entered the grouped
+    # expert matmuls (the launch's real tokens x experts per token x
+    # layers, counted at dispatch: padding is not routed);
     # ``text_stats`` = the program's second output, i32[layers] largest
     # expert group, read at finalize into ``expert_peak_rows`` (sum over
     # layers of largest group x num_experts: what the launch would cost if
     # every group were as large as the largest). Their ratio is 1.0 under
-    # even routing.
+    # even routing. ``expert_token_slots`` = the capacity the routed blocks
+    # ran at (scoring/text_split.py; at most ``token_slots``), and
+    # ``compact_batches`` is 1 where that was a narrow rung.
     expert_rows: int = 0
     expert_peak_rows: int = 0
     text_stats: Optional[Any] = None
+    expert_token_slots: int = 0
+    compact_batches: int = 0
     # How the rows were launched (scoring/text_split.py): real rows in a
     # program narrower than ``text_len``, real rows at ``text_len`` (their
     # sum is ``n``), and 1 where the batch took two launches.
@@ -147,6 +152,10 @@ class _Launch:
     width: int                      # text positions
     blobs: Any = None               # core.packing.pack_tree's output
     spec: Any = None
+    # the MoE text encoder only: the token slots its routed blocks are
+    # compiled for (text_split.capacity), and the real tokens it holds
+    capacity: Optional[int] = None
+    tokens: int = 0
 
 
 class _SplitResult:
@@ -612,7 +621,8 @@ class FraudScorer:
         # rows have been launched since construction
         self._text_families: Dict[int, tuple] = {}
         self._text_split_counts: Dict[str, int] = {
-            "short_text_rows": 0, "long_text_rows": 0, "split_batches": 0}
+            "short_text_rows": 0, "long_text_rows": 0, "split_batches": 0,
+            "expert_token_slots": 0, "compact_batches": 0}
         self.spans = SpanTimer()
         # device-pool scoring plane (scoring/device_pool.py): when attached,
         # dispatch_assembled routes whole microbatches round-robin across
@@ -926,7 +936,8 @@ class FraudScorer:
         """Whether the text branch has a Pallas kernel for its shapes: the
         fused attention core for the dense encoder, the grouped expert
         matmul for the MoE one (the smallest bucket's rows decide: every
-        larger bucket is a multiple of them)."""
+        larger bucket is a multiple of them, and a narrow capacity of the
+        routed blocks is whole tiles by ``text_split.CAPACITY_MULTIPLE``)."""
         if not self._moe_text:
             return self._flash_shape_ok(text_len)
         from realtime_fraud_detection_tpu.ops import grouped_matmul_supported
@@ -1201,7 +1212,10 @@ class FraudScorer:
         series — and ``text_split``: the narrower width short rows are
         launched at (None where there is none), why it is refused if it
         is, rows launched at either width, batches that took two launches,
-        and the programs compiled per bucket."""
+        the programs compiled per bucket (``(rows, width)``; with the MoE
+        encoder ``(rows, width, capacity)``), and that encoder's
+        ``expert_token_slots`` (the capacities launched, summed) and
+        ``compact_batches`` (launches at a narrow one)."""
         caches: Dict[str, Any] = {"entity_rows": self._join_cache.stats()}
         cache_stats = getattr(self.tokenizer, "cache_stats", None)
         if cache_stats is not None:
@@ -1255,6 +1269,7 @@ class FraudScorer:
             # rtfd-lint: allow[wall-clock] dispatch_ms / processing_time_ms of the §2.7 response, not scoring control flow
             t0 = time.perf_counter()
         n = len(records)
+        real_tokens = int(np.count_nonzero(batch.token_mask))
         with self.spans.span(scopes.PACK, trace=trace):
             # an attached mesh executor (scoring/mesh_executor.py) shards
             # the batch over ITS data axis, which may differ from this
@@ -1270,10 +1285,16 @@ class FraudScorer:
             full = int(batch.token_ids.shape[1])
             # one launch at ``text_len``, or the rows whose text fits a
             # narrower program apart from the long ones (text_split.py)
-            launches = self._text_launches(batch, n, size, full, bucket_of)
+            if self._moe_text:
+                # one launch; its routed blocks at the capacity that holds
+                # the batch's real tokens (text_split.capacity)
+                launches = [self._routed_launch(batch, n, size, full,
+                                                real_tokens)]
+            else:
+                launches = self._text_launches(batch, n, size, full,
+                                               bucket_of)
             for launch in launches:
                 self._pack_launch(batch, launch)
-        real_tokens = int(np.count_nonzero(batch.token_mask))
 
         # the tracer's ``device_wait`` stage begins where the launch
         # returns: from the transaction's point of view the device
@@ -1323,10 +1344,14 @@ class FraudScorer:
         token_slots = sum(la.size * la.width for la in launches)
         short_rows = n - sum(la.n for la in launches if la.width == full)
         split = int(len(launches) > 1)
+        expert_slots = launches[0].capacity or 0
+        compact = int(0 < expert_slots < token_slots)
         counts = self._text_split_counts
         counts["short_text_rows"] += short_rows
         counts["long_text_rows"] += n - short_rows
         counts["split_batches"] += split
+        counts["expert_token_slots"] += expert_slots
+        counts["compact_batches"] += compact
         return PendingScore(records=list(records), n=n, out=out,
                             # rtfd-lint: allow[d2h] batch.features is a host-assembled ndarray
                             features=np.asarray(batch.features),
@@ -1338,8 +1363,10 @@ class FraudScorer:
                             token_slots_sq=sum(la.size * la.width * la.width
                                                for la in launches),
                             real_tokens=real_tokens,
-                            expert_rows=self._expert_rows(token_slots),
+                            expert_rows=self._expert_rows(launches[0].tokens),
                             text_stats=text_stats,
+                            expert_token_slots=expert_slots,
+                            compact_batches=compact,
                             short_text_rows=short_rows,
                             long_text_rows=n - short_rows,
                             split_batches=split)
@@ -1407,6 +1434,33 @@ class FraudScorer:
             self._text_families[size] = programs
         return launches
 
+    def _routed_launch(self, batch: ScoreBatch, n: int, size: int,
+                       width: int, real_tokens: int) -> "_Launch":
+        """The one launch of a batch under the MoE text encoder, its routed
+        blocks compiled for the narrowest capacity that holds the batch's
+        ``real_tokens`` (``text_split.capacity``: a shape of the program,
+        every real token is routed at any rung). The first time a bucket is
+        launched, the program of each of its rungs is compiled and run
+        here, as a split bucket's family is, so that none first appears
+        under load."""
+        slots = size * width
+        if size not in self._text_families:
+            mv = self.effective_model_valid()
+            # the batch's rows with no token stand in (nothing to hold, so
+            # every rung takes them): the results are dropped
+            empty = batch.replace(
+                token_mask=np.zeros_like(np.asarray(batch.token_mask)))
+            rungs = text_split.capacities(slots)
+            for rung in rungs:
+                warm = _Launch(None, n, size, width, capacity=rung)
+                self._pack_launch(empty, warm)
+                jax.block_until_ready(self._launch_packed(warm, mv))
+            self._text_families[size] = tuple(
+                (size, width, rung) for rung in rungs)
+        return _Launch(None, n, size, width,
+                       capacity=text_split.capacity(real_tokens, slots),
+                       tokens=real_tokens)
+
     def _pack_launch(self, batch: ScoreBatch, launch: "_Launch") -> None:
         """Pad ``launch``'s rows of ``batch`` to its bucket at its text
         width and pack them: the tokenizer pads on the right and [CLS] is
@@ -1422,6 +1476,10 @@ class FraudScorer:
         padded, mask = self._staging.pad(batch, launch.n, launch.size,
                                          rows=launch.rows)
         padded = padded.replace(valid=mask)
+        if launch.capacity is not None and launch.n < launch.size:
+            # the routed blocks run on real tokens: the bucket's filler rows
+            # hold none (the staging buffer is this launch's to write)
+            padded.token_mask[launch.n:] = False
         # Packed seam (core/packing.py): the 65-leaf ScoreBatch
         # collapses to 3 dense blobs (one h2d payload), the program
         # returns ONE f32 matrix (one d2h payload).
@@ -1431,6 +1489,14 @@ class FraudScorer:
 
     def _launch_packed(self, launch: "_Launch", mv: np.ndarray):
         """One call of the fused program on this scorer's own mesh."""
+        routed = {}
+        if launch.capacity is not None:
+            if launch.tokens > launch.capacity:
+                raise ValueError(
+                    f"text_capacity {launch.capacity} cannot hold the "
+                    f"launch's {launch.tokens} real tokens: "
+                    "text_split.capacity picks one that does")
+            routed["text_capacity"] = launch.capacity
         sharded = shard_batch(self.mesh, launch.blobs)
         return score_fused_packed(
             self.models, sharded["f32"], sharded["i32"], sharded["u8"],
@@ -1439,15 +1505,16 @@ class FraudScorer:
             blob_bf16=sharded["bf16"],
             bert_config=self.bert_config,
             use_pallas=self.effective_use_pallas(text_len=launch.width),
-            **self.quant_static(), **self.kernel_static(),
+            **self.quant_static(), **self.kernel_static(), **routed,
         )
 
-    def _expert_rows(self, token_slots: int) -> int:
-        """Rows one launch sends into the grouped expert matmuls."""
+    def _expert_rows(self, tokens: int) -> int:
+        """The (token, expert) pairs a launch of ``tokens`` real tokens
+        sends into the grouped expert matmuls, all layers."""
         if not self._moe_text:
             return 0
         c = self.bert_config
-        return token_slots * c.num_experts_per_tok * c.num_hidden_layers
+        return tokens * c.num_experts_per_tok * c.num_hidden_layers
 
     def finalize(self, pending: "PendingScore", now: Optional[float] = None,
                  lock=None) -> List[Dict[str, Any]]:

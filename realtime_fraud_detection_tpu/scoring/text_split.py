@@ -14,6 +14,16 @@ The rule is decided from shapes alone. A batch is split only where its
 launches together hold fewer (row, position) slots than the one launch at
 ``text_len`` — fewer slots is less work in every kernel of the text branch,
 all of them linear or quadratic in the padded length.
+
+A sparse encoder's routed block (``models/olmoe.py``) has a second such
+shape, its **capacity**: how many of a launch's slots the router, the row
+gatherings and the grouped expert matmuls are compiled for. The real tokens
+of the launch are compacted into it, so it has to hold them all; who holds
+the mask picks it: the host, per batch, from the count it already makes
+(``capacity``), out of the few rungs a launch's slots admit (``capacities``:
+what the scorer compiles the first time a bucket is launched). A rung is a
+shape of the program, never a limit on the tokens: a batch no narrow rung
+holds runs at every slot.
 """
 
 from __future__ import annotations
@@ -31,6 +41,20 @@ LONG_BUCKET_SHARE = 8
 # row on bucket 1 would be a program almost no batch needs (and each costs
 # seconds of set-up), while 8 rows at ``text_len`` cost little more than 1
 MIN_LONG_ROWS = 8
+
+# the narrow rung of a routed block: this share of the launch's slots. The
+# deployed mix fills 65% of its slots (+-1.3 a batch of 256 rows), so three
+# quarters holds every batch of it with room; a rung nearer the mix would
+# send a batch to the full program for one long row too many
+CAPACITY_SHARE = (3, 4)
+# capacities are whole multiples of this many tokens: times 8 experts a
+# token that is whole 128-row tiles (ops.grouped_matmul_supported)
+CAPACITY_MULTIPLE = 16
+# the smallest launch that gets a narrow rung. Under it an expert's group is
+# tens of rows, its matmuls are paced by reading the expert's weights
+# whatever the rows, and a second program (seconds of set-up each) buys
+# nothing
+MIN_COMPACT_SLOTS = 4096
 
 # (which rows, bucket rows, text width)
 Launch = Tuple[str, int, int]
@@ -71,3 +95,25 @@ def family(size: int, narrow: Optional[int], full: int,
             if (rows, width) not in programs:
                 programs.append((rows, width))
     return tuple(programs)
+
+
+def capacities(slots: int) -> Tuple[int, ...]:
+    """The capacities a routed block is compiled at for a launch of
+    ``slots`` (rows x width) slots, narrowest first; the last is every
+    slot."""
+    if slots < MIN_COMPACT_SLOTS:
+        return (slots,)
+    num, den = CAPACITY_SHARE
+    narrow = slots * num // den // CAPACITY_MULTIPLE * CAPACITY_MULTIPLE
+    return (narrow, slots)
+
+
+def capacity(real_tokens: int, slots: int) -> int:
+    """The narrowest rung of ``capacities(slots)`` that holds a launch's
+    ``real_tokens``."""
+    for rung in capacities(slots):
+        if real_tokens <= rung:
+            return rung
+    raise ValueError(
+        f"text_split.capacity: {real_tokens} real tokens in a launch of "
+        f"{slots} slots")

@@ -284,11 +284,15 @@ class StreamJob:
             # ``batches`` is (PendingScore.token_slots): padded slots,
             # rows x text_len^2, and the real tokens among the slots
             "token_slots": 0, "token_slots_sq": 0, "real_tokens": 0,
-            # the MoE text encoder only (0 otherwise): rows launched into
-            # the grouped expert matmuls, and what they would be if every
-            # expert's group were as large as the layer's largest
-            # (PendingScore.expert_rows / expert_peak_rows)
+            # the MoE text encoder only (0 otherwise): the (token, expert)
+            # pairs that entered the grouped expert matmuls (real tokens:
+            # padding is not routed), what they would be if every expert's
+            # group were as large as the layer's largest, the capacities
+            # the routed blocks ran at (at most ``token_slots``) and the
+            # batches that took a narrow one (PendingScore.expert_rows /
+            # expert_peak_rows / expert_token_slots / compact_batches)
             "expert_rows": 0, "expert_peak_rows": 0,
+            "expert_token_slots": 0, "compact_batches": 0,
             # how the rows were launched (scoring/text_split.py): real rows
             # in a program narrower than ``text_len``, real rows at
             # ``text_len``, batches that took two launches
@@ -566,6 +570,7 @@ class StreamJob:
                 scored_ok = True
                 for key in ("token_slots", "token_slots_sq", "real_tokens",
                             "expert_rows", "expert_peak_rows",
+                            "expert_token_slots", "compact_batches",
                             "short_text_rows", "long_text_rows",
                             "split_batches"):
                     # 0 from a stand-in scorer's pending without them

@@ -262,13 +262,20 @@ def test_grouped_matmul_compiles_at_olmoe_widths(one_chip, k, n):
     assert 'op_name="ragged-dot' in xla
 
 
-def test_olmoe_program_compiles_with_every_large_pass_under_a_scope(one_chip):
+@pytest.mark.parametrize("capacity", [None, 24576],
+                         ids=["every_slot", "three_quarters"])
+def test_olmoe_program_compiles_with_every_large_pass_under_a_scope(
+        one_chip, capacity):
     """The served packed program with an ``OlmoeConfig`` (two of the
-    published layers, every width as published, bucket 256 x 128 tokens):
+    published layers, every width as published, bucket 256 x 128 tokens),
+    at both capacities of that bucket's routed blocks
+    (``scoring/text_split.capacities``):
     three Mosaic calls a layer, a second small output, temporaries that
     leave room for 8 layers of weights in 16 GB — and no instruction that
     writes 64 MB or more without a named scope in its ``op_name`` (what a
-    device trace would count as ``unscoped``)."""
+    device trace would count as ``unscoped``), and no conditional: a
+    ``cond`` ahead of ``experts/`` in an ``op_name`` would hide the block
+    from the trace's attribution."""
     import re
 
     from realtime_fraud_detection_tpu.core.packing import pack_tree
@@ -296,9 +303,10 @@ def test_olmoe_program_compiles_with_every_large_pass_under_a_scope(one_chip):
         params=EnsembleParams.from_config(Config(), list(MODEL_NAMES)),
         model_valid=_sds((len(MODEL_NAMES),), jnp.bool_, one_chip),
         blob_bf16=_shapes_of(blobs["bf16"], one_chip), bert_config=config,
-        use_pallas=True).compile()
+        use_pallas=True, text_capacity=capacity).compile()
     text = compiled.as_text()
     assert text.count(CUSTOM_CALL) == 3 * config.num_hidden_layers
+    assert " conditional(" not in text and "cond/branch_" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 5 << 30
     entry = text[text.index("ENTRY "):]
     sizes = {"f32": 4, "bf16": 2, "s32": 4, "u32": 4, "pred": 1}

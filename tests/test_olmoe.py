@@ -135,9 +135,10 @@ def text():
     return ids, mask
 
 
-def _program_routing(params, ids, mask, cfg):
-    """Each layer's top-k as the PROGRAM chooses it on its own hidden
-    stream, from the public pieces."""
+def _every_slot_routed(params, ids, mask, cfg):
+    """The encoder as it was before padding left the routed block: every
+    (row, position) slot through the router and its experts, from the public
+    pieces. ``(hidden before the final norm, each layer's top-k)``."""
     cos, sin = olmoe.rope_tables(ids.shape[1], cfg.head_dim, cfg.rope_theta)
     h = params["embed_tokens"][ids].astype(F32)
     chosen = []
@@ -149,7 +150,14 @@ def _program_routing(params, ids, mask, cfg):
         chosen.append(experts)
         y, _ = apply_experts(layer, x, experts, weights)
         h = h + y.reshape(h.shape)
-    return chosen
+    return h, chosen
+
+
+def _program_routing(params, ids, mask, cfg):
+    """Each layer's top-k as the PROGRAM chooses it on its own hidden
+    stream (at a real position the stream is the same with and without the
+    padding routed: test_only_the_real_tokens_are_routed)."""
+    return _every_slot_routed(params, ids, mask, cfg)[1]
 
 
 # ------------------------------------------------- the whole encoder, logits
@@ -335,6 +343,150 @@ def test_the_encoder_is_the_same_through_the_kernel(text):
     np.testing.assert_allclose(a, b, atol=1e-5, rtol=0)
 
 
+# ------------------------------------- only the real tokens are routed
+# rows of 12 positions: full, ragged, one token, and a bucket's filler row
+# that holds none: 48 slots, 27 real tokens
+RAGGED = (12, 5, 1, 9, 0)
+CAPACITIES = {"every_slot_by_default": None, "every_slot": 60,
+              "three_quarters": 45, "the_smallest_that_fits": 27}
+
+
+@pytest.fixture(scope="module")
+def ragged():
+    rng = np.random.default_rng(29)
+    ids = rng.integers(0, CFG.vocab_size, (len(RAGGED), 12)).astype(np.int32)
+    return ids, np.arange(12)[None, :] < np.asarray(RAGGED)[:, None]
+
+
+@pytest.mark.parametrize("case", sorted(CAPACITIES))
+def test_only_the_real_tokens_are_routed(params32, ragged, case):
+    """At any capacity that holds them, the real positions' hidden states
+    and every row's answer are what routing every slot gave."""
+    ids, mask = ragged
+    capacity = CAPACITIES[case]
+    assert mask.sum() == 27 and mask.size == 60
+    want_hidden, _ = _every_slot_routed(params32, ids, mask, CFG)
+    hidden, peaks = olmoe_encode(params32, ids, mask, CFG, capacity=capacity)
+    np.testing.assert_allclose(np.asarray(hidden)[mask],
+                               np.asarray(want_hidden)[mask],
+                               atol=1e-5, rtol=0)
+    # a padding position gets the attention half of the block and nothing
+    # from the experts; the groups hold the real tokens' pairs alone
+    assert np.isfinite(np.asarray(hidden)).all()
+    assert (np.asarray(peaks) <= 27 * CFG.num_experts_per_tok).all()
+    got = olmoe_predict(params32, ids, mask, CFG, capacity=capacity)
+    with jax.default_matmul_precision("highest"):
+        want = jax.nn.softmax(ref_logits(params32, ids, mask, CFG), -1)[:, 1]
+    # every row that holds a token (a transaction's text has [CLS] and
+    # [SEP] at least); the empty row's answer is pooled from a padding
+    # position, belongs to no transaction, and is only finite
+    held = mask.any(axis=1)
+    np.testing.assert_allclose(got[held], want[held], atol=1e-5, rtol=0)
+    assert np.isfinite(np.asarray(got)).all()
+
+
+def test_token_slots_lists_the_real_slots_then_fillers_of_its_own(ragged):
+    _, mask = ragged
+    idx, real = olmoe.token_slots(mask, 32)
+    assert idx.shape == real.shape == (32,) and int(real.sum()) == 27
+    np.testing.assert_array_equal(idx[:27], np.flatnonzero(mask))
+    # sorted, unique, and every filler past the last slot: a scatter with
+    # mode="drop" leaves them out
+    assert (np.diff(np.asarray(idx)) > 0).all() and int(idx[27]) >= mask.size
+    for every_slot in (None, mask.size):
+        idx, real = olmoe.token_slots(mask, every_slot)
+        assert idx is None
+        np.testing.assert_array_equal(real, mask.reshape(-1))
+    for impossible in (0, mask.size + 16):
+        with pytest.raises(ValueError, match="capacity"):
+            olmoe.token_slots(mask, impossible)
+
+
+def test_unrouted_rows_enter_no_group_and_get_zero(params32):
+    layer = params32["layers"][0]
+    k = CFG.num_experts_per_tok
+    x = jax.random.normal(jax.random.PRNGKey(6), (48, CFG.hidden_size), F32)
+    experts, weights = route(x, layer["router"], k)
+    real = np.arange(48) % 3 != 1                          # 32 of 48
+    want, all_sizes = apply_experts(layer, x, experts, weights)
+    got, sizes = apply_experts(layer, x, experts, weights,
+                               real=jnp.asarray(real))
+    assert int(all_sizes.sum()) == 48 * k
+    assert int(sizes.sum()) == 32 * k                  # real tokens x top-k
+    np.testing.assert_array_equal(
+        sizes, np.bincount(np.asarray(experts)[real].ravel(),
+                           minlength=CFG.num_experts))
+    np.testing.assert_allclose(np.asarray(got)[real], np.asarray(want)[real],
+                               atol=1e-6, rtol=0)
+    assert not np.asarray(got)[~real].any()
+
+
+@pytest.mark.parametrize("capacity", [None, 45, 32])
+def test_rows_the_kernel_never_wrote_reach_nothing(params32, ragged,
+                                                   monkeypatch, capacity):
+    """``megablox.gmm`` visits only the tiles of a group: with the groups
+    summing to fewer rows than were launched, the rest of its result is
+    uninitialised. Poisoned here, it must change nothing."""
+    ids, mask = ragged
+    want_h, _ = olmoe_encode(params32, ids, mask, CFG, capacity=capacity)
+    want_p = olmoe_predict(params32, ids, mask, CFG, capacity=capacity)
+    real_pairs = int(mask.sum()) * CFG.num_experts_per_tok
+    poisoned = []
+
+    def tail_poisoned(lhs, rhs, group_sizes, **kw):
+        out = grouped_matmul(lhs, rhs, group_sizes, **kw)
+        written = jnp.arange(out.shape[0])[:, None] < jnp.sum(group_sizes)
+        poisoned.append(out.shape[0] - real_pairs)
+        return jnp.where(written, out, jnp.nan)
+
+    monkeypatch.setattr(olmoe, "grouped_matmul", tail_poisoned)
+    got_h, _ = olmoe_encode(params32, ids, mask, CFG, capacity=capacity)
+    got_p = olmoe_predict(params32, ids, mask, CFG, capacity=capacity)
+    assert poisoned and min(poisoned) >= 0 and max(poisoned) > 0
+    np.testing.assert_array_equal(got_h, want_h)
+    np.testing.assert_array_equal(got_p, want_p)
+
+
+@pytest.mark.parametrize("capacity", [None, 192, 128])
+def test_compacted_through_the_kernel(capacity):
+    """The Pallas form (interpreted), whose tail past the last group is
+    whatever the buffer held, against the XLA form with every slot routed."""
+    cfg = dataclasses.replace(CFG, intermediate_size=128, num_hidden_layers=1)
+    p = jax.tree.map(
+        lambda a: a.astype(F32),
+        jax.jit(lambda k: init_olmoe_params(k, cfg))(jax.random.PRNGKey(9)))
+    rng = np.random.default_rng(2)
+    ids = rng.integers(0, cfg.vocab_size, (4, 64)).astype(np.int32)
+    mask = np.arange(64)[None, :] < np.asarray([64, 17, 30, 5])[:, None]
+    rows = (capacity or mask.size) * cfg.num_experts_per_tok
+    assert grouped_matmul_supported(rows, cfg.hidden_size,
+                                    cfg.intermediate_size)
+    want_h, _ = _every_slot_routed(p, ids, mask, cfg)
+    got_h, _ = olmoe_encode(p, ids, mask, cfg, capacity=capacity,
+                            use_pallas=True, kernel_interpret=True)
+    np.testing.assert_allclose(np.asarray(got_h)[mask],
+                               np.asarray(want_h)[mask], atol=1e-5, rtol=0)
+    assert np.isfinite(np.asarray(got_h)).all()
+    got = olmoe_predict(p, ids, mask, cfg, capacity=capacity,
+                        use_pallas=True, kernel_interpret=True)
+    with jax.default_matmul_precision("highest"):
+        want = jax.nn.softmax(ref_logits(p, ids, mask, cfg), -1)[:, 1]
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=0)
+
+
+def test_empty_filler_rows_beside_real_ones_change_nothing(params32, ragged):
+    """A bucket's filler rows hold no token (``FraudScorer._pack_launch``):
+    the real rows' answers are those of the batch without them."""
+    ids, mask = ragged
+    alone = olmoe_predict(params32, ids[:4], mask[:4], CFG)
+    filled = np.concatenate([ids[:4], np.repeat(ids[:1], 4, axis=0)])
+    empty = np.concatenate([mask[:4], np.zeros((4, 12), bool)])
+    for capacity in (None, 48):
+        got = olmoe_predict(params32, filled, empty, CFG, capacity=capacity)
+        np.testing.assert_allclose(got[:4], alone, atol=1e-6, rtol=0)
+        assert np.isfinite(np.asarray(got)).all()
+
+
 # ------------------------------------------------------ masks and pooling
 def test_causality_a_later_token_moves_no_earlier_position(params, text):
     ids, mask = text
@@ -435,7 +587,14 @@ def test_fused_program_through_scorer_and_job_one_prediction_each():
     c = job.counters
     assert c["errors"] == 0 and c["scored"] == 40
     k, layers = CFG.num_experts_per_tok, CFG.num_hidden_layers
-    assert c["expert_rows"] == c["token_slots"] * k * layers
+    # the pairs that entered the grouped matmuls are the real tokens': the
+    # padding of a row and the filler rows of a bucket are not routed
+    assert c["expert_rows"] == c["real_tokens"] * k * layers
+    assert c["expert_rows"] <= c["expert_token_slots"] * k * layers \
+        <= c["token_slots"] * k * layers
+    # 32 x 32 slots: under the smallest launch that gets a narrow rung
+    assert c["expert_token_slots"] == c["token_slots"]
+    assert c["compact_batches"] == 0
     # largest group x experts >= all rows; equal only under even routing
     assert c["expert_peak_rows"] >= c["expert_rows"] > 0
     assert scorer.kernel_snapshot()["fallback"]["attention"] == c["batches"]
@@ -451,6 +610,7 @@ def test_the_dense_program_has_no_second_output_and_no_expert_rows():
     recs = TransactionGenerator(num_users=8, num_merchants=4).generate_batch(3)
     pending = scorer.dispatch(recs)
     assert pending.text_stats is None and pending.expert_rows == 0
+    assert pending.expert_token_slots == 0 and pending.compact_batches == 0
     assert not isinstance(pending.out, tuple)
     assert len(scorer.finalize(pending)) == 3
     assert pending.expert_peak_rows == 0
@@ -490,7 +650,8 @@ def test_the_seam_leaves_the_dense_program_as_it_was(monkeypatch):
     try:
         through_the_seam = compile_packed()
 
-        def parents_call(params, ids, mask, config, **static):
+        def parents_call(params, ids, mask, config, capacity=None, **static):
+            assert capacity is None       # a dense launch passes none
             return bert.bert_predict(params, ids, mask, config, **static), None
 
         def parents_attention(q, k, v, key_mask=None):
