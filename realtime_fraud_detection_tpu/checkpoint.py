@@ -85,7 +85,8 @@ def _derive_model_shapes(params: Any) -> Optional[Dict[str, Any]]:
         # the word embedding is a bare f32 table, or the weight-only int8
         # form {"qe": i8[rows, h], "scale": f32[rows]} (models/quant.py) —
         # the hidden size lives in the table either way
-        # (the MoE text encoder, models/olmoe.py, calls it embed_tokens)
+        # (the routed text encoders, models/olmoe.py and zaya.py, call it
+        # embed_tokens)
         word_emb = params.bert.get("word_emb", params.bert.get("embed_tokens"))
         if isinstance(word_emb, dict):
             word_emb = word_emb["qe"]
@@ -269,6 +270,7 @@ class CheckpointManager:
             IsolationForest,
         )
         from realtime_fraud_detection_tpu.scoring import init_scoring_models
+        from realtime_fraud_detection_tpu.scoring.pipeline import text_layers
 
         manifest = self.manifest(step)
         meta = manifest.get("metadata") or {}
@@ -278,10 +280,8 @@ class CheckpointManager:
         want = {
             "bert_hidden": None if bert_config is None
             else bert_config.hidden_size,
-            # an OlmoeConfig text branch spells its depth the source's way
             "bert_layers": None if bert_config is None
-            else getattr(bert_config, "num_layers", None)
-            or bert_config.num_hidden_layers,
+            else text_layers(bert_config),
             "feature_dim": feature_dim,
             "node_dim": node_dim,
         }

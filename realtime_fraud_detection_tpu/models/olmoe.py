@@ -187,12 +187,26 @@ def router_probs(x: jax.Array, w_router: jax.Array) -> jax.Array:
     return jax.nn.softmax(logits, axis=-1)
 
 
+def choose_experts(probs: jax.Array, top_k: int,
+                   bias: Optional[jax.Array] = None
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """``(experts i32[N, top_k], weights f32[N, top_k])`` of router
+    probabilities ``f32[N, num_experts]``, however the encoder's router made
+    them: each token's ``top_k`` largest, their probabilities NOT
+    renormalised. ``bias`` (``f32[num_experts]``) moves the choice alone:
+    the largest of ``probs + bias`` are taken and weighted by ``probs``."""
+    if bias is None:
+        weights, experts = jax.lax.top_k(probs, top_k)
+    else:
+        _, experts = jax.lax.top_k(probs + bias, top_k)
+        weights = jnp.take_along_axis(probs, experts, axis=-1)
+    return experts.astype(jnp.int32), weights
+
+
 def route(x: jax.Array, w_router: jax.Array, top_k: int
           ) -> Tuple[jax.Array, jax.Array]:
-    """``(experts i32[N, top_k], weights f32[N, top_k])``: each token's
-    ``top_k`` largest router probabilities, NOT renormalised."""
-    weights, experts = jax.lax.top_k(router_probs(x, w_router), top_k)
-    return experts.astype(jnp.int32), weights
+    """OLMoE's router, one matmul: ``choose_experts`` of ``router_probs``."""
+    return choose_experts(router_probs(x, w_router), top_k)
 
 
 def token_slots(attention_mask: jax.Array, capacity: Optional[int]
@@ -290,6 +304,35 @@ def olmoe_attention(layer: Dict, h: jax.Array, attention_mask: jax.Array,
         return h + attn_out
 
 
+def routed_block(layer: Dict, x: jax.Array,
+                 slots: Tuple[Optional[jax.Array], jax.Array], router, *,
+                 use_pallas: bool = False, kernel_interpret: bool = False):
+    """The routed half of a sparse block on the normed rows ``x`` ``f32[N,
+    hidden]`` of every slot of a launch: ``(y f32[N, hidden], group_sizes,
+    carry)``. ``slots`` is the launch's ``token_slots``; under a capacity
+    the real slots' rows are gathered into ``[C, hidden]`` first and the
+    result scattered home. ``router`` maps those rows to ``(experts,
+    weights, carry)`` under the ``router`` scope: the encoder's own (one
+    matmul for OLMoE, an MLP with state for ZAYA1), and ``carry`` is
+    whatever it hands its next layer, on the same C rows."""
+    n, width = x.shape
+    idx, real = slots
+    if idx is not None:
+        with jax.named_scope(scopes.EXPERTS_DISPATCH):
+            x = x.at[idx].get(mode="fill", fill_value=0.0)     # [C, width]
+    with jax.named_scope(scopes.ROUTER):
+        experts, weights, carry = router(x)
+    y, group_sizes = apply_experts(
+        layer, x, experts, weights, real=real, use_pallas=use_pallas,
+        kernel_interpret=kernel_interpret)
+    if idx is not None:
+        with jax.named_scope(scopes.EXPERTS_COMBINE):
+            # home: the fillers' indices lie past the last slot and drop
+            y = jnp.zeros((n, width), y.dtype).at[idx].set(
+                y, mode="drop", indices_are_sorted=True, unique_indices=True)
+    return y, group_sizes, carry
+
+
 def olmoe_layer(layer: Dict, h: jax.Array, attention_mask: jax.Array,
                 config: OlmoeConfig, cos, sin, *,
                 slots: Optional[Tuple[Optional[jax.Array], jax.Array]] = None,
@@ -299,26 +342,17 @@ def olmoe_layer(layer: Dict, h: jax.Array, attention_mask: jax.Array,
     expert group of the layer (``i32[]``). ``slots`` is the launch's
     ``token_slots`` (None: every real slot, uncompacted)."""
     b, t, width = h.shape
-    idx, real = slots if slots is not None else token_slots(attention_mask,
-                                                            None)
+    if slots is None:
+        slots = token_slots(attention_mask, None)
     h = olmoe_attention(layer, h, attention_mask, config, cos, sin)
     with jax.named_scope(scopes.LN):
         x = rms_norm(h, layer["post_attention_layernorm"],
                      config.rms_norm_eps).reshape(b * t, width)
-    if idx is not None:
-        with jax.named_scope(scopes.EXPERTS_DISPATCH):
-            x = x.at[idx].get(mode="fill", fill_value=0.0)     # [C, width]
-    with jax.named_scope(scopes.ROUTER):
-        experts, weights = route(x, layer["router"],
-                                 config.num_experts_per_tok)
-    y, group_sizes = apply_experts(
-        layer, x, experts, weights, real=real, use_pallas=use_pallas,
-        kernel_interpret=kernel_interpret)
-    if idx is not None:
-        with jax.named_scope(scopes.EXPERTS_COMBINE):
-            # home: the fillers' indices lie past the last slot and drop
-            y = jnp.zeros((b * t, width), y.dtype).at[idx].set(
-                y, mode="drop", indices_are_sorted=True, unique_indices=True)
+    y, group_sizes, _ = routed_block(
+        layer, x, slots,
+        lambda rows: (*route(rows, layer["router"],
+                             config.num_experts_per_tok), None),
+        use_pallas=use_pallas, kernel_interpret=kernel_interpret)
     with jax.named_scope(scopes.LN):
         h = h + y.reshape(b, t, width)
     return h, jnp.max(group_sizes)
@@ -348,6 +382,20 @@ def olmoe_encode(params: Dict, input_ids: jax.Array,
     return h, jnp.stack(peaks)
 
 
+def last_token_logits(params: Dict, hidden: jax.Array,
+                      attention_mask: jax.Array, eps: float) -> jax.Array:
+    """The sequence-classification head on ``hidden`` ``f32[B, T, hidden]``:
+    final RMSNorm of the last real token (right-padded text), bias-free
+    ``Linear(hidden -> num_labels)``."""
+    with jax.named_scope(scopes.HEAD):
+        last = jnp.maximum(
+            jnp.sum(attention_mask.astype(jnp.int32), axis=-1) - 1, 0)
+        pooled = jnp.take_along_axis(hidden, last[:, None, None], axis=1)[:, 0]
+        pooled = rms_norm(pooled, params["norm"], eps)
+        return jnp.dot(pooled, params["score"],
+                       precision=jax.lax.Precision.HIGHEST)
+
+
 def olmoe_logits(params: Dict, input_ids: jax.Array,
                  attention_mask: jax.Array, config: OlmoeConfig, *,
                  capacity: Optional[int] = None,
@@ -358,14 +406,8 @@ def olmoe_logits(params: Dict, input_ids: jax.Array,
     hidden, peaks = olmoe_encode(params, input_ids, attention_mask, config,
                                  capacity=capacity, use_pallas=use_pallas,
                                  kernel_interpret=kernel_interpret)
-    with jax.named_scope(scopes.HEAD):
-        last = jnp.maximum(
-            jnp.sum(attention_mask.astype(jnp.int32), axis=-1) - 1, 0)
-        pooled = jnp.take_along_axis(hidden, last[:, None, None], axis=1)[:, 0]
-        pooled = rms_norm(pooled, params["norm"], config.rms_norm_eps)
-        logits = jnp.dot(pooled, params["score"],
-                         precision=jax.lax.Precision.HIGHEST)
-    return logits, peaks
+    return last_token_logits(params, hidden, attention_mask,
+                             config.rms_norm_eps), peaks
 
 
 def olmoe_predict(params: Dict, input_ids: jax.Array,

@@ -59,6 +59,14 @@ EXPERTS_DISPATCH, EXPERTS_MATMUL, EXPERTS_COMBINE = (
 MOE_LAYER_SCOPES: Tuple[str, ...] = (ATTN_PROJ, ATTN_CORE, ROUTER, EXPERTS,
                                      LN)
 
+# ---- device: under ``text`` where the encoder is models/zaya.py: the MoE
+# names above (``attn_proj`` the four projections alone; ``router`` also
+# holds the router's down-projection, its state carry and its MLP) and
+ATTN_MIX = "attn_mix"        # between the latent projections and the core:
+                             # both convolutions, q-k mean, L2 norms and
+                             # temperature, RoPE, the value shift
+ZAYA_LAYER_SCOPES: Tuple[str, ...] = MOE_LAYER_SCOPES + (ATTN_MIX,)
+
 
 def layer_scope(i: int) -> str:
     return f"{LAYER}{i}"

@@ -43,17 +43,28 @@ def attention_reference(
     key_mask: jax.Array | None = None, causal: bool = False,
 ) -> jax.Array:
     """Plain XLA attention (numerics oracle + CPU fallback). [B,H,S,D].
-    ``causal``: a query also sees no key after its own position."""
-    d = q.shape[-1]
+    ``causal``: a query also sees no key after its own position.
+
+    Grouped-query attention: ``k`` and ``v`` may hold fewer heads than
+    ``q`` (``[B,Hkv,S,D]``, ``H`` a multiple of ``Hkv``); key head ``j``
+    serves query heads ``j*G .. j*G+G-1``. The G query heads of a group are
+    stacked along the query axis of their key head, so no key or value is
+    repeated in memory and the rest is the same two contractions."""
+    heads, t_q, d = q.shape[1:]
+    group = heads // k.shape[1]
+    if group != 1:
+        q = q.reshape(q.shape[0], k.shape[1], group * t_q, d)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) / np.sqrt(d)
     if key_mask is not None:
         scores = jnp.where(key_mask[:, None, None, :], scores, NEG_INF)
     if causal:
-        t_q, t_k = scores.shape[-2:]
-        scores = jnp.where(np.tril(np.ones((t_q, t_k), bool)), scores,
-                           NEG_INF)
+        t_k = scores.shape[-1]
+        scores = jnp.where(
+            np.tile(np.tril(np.ones((t_q, t_k), bool)), (group, 1)), scores,
+            NEG_INF)
     weights = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", weights.astype(v.dtype), v)
+    ctx = jnp.einsum("bhqk,bhkd->bhqd", weights.astype(v.dtype), v)
+    return ctx.reshape(ctx.shape[0], heads, t_q, d) if group != 1 else ctx
 
 
 def split_heads(x: jax.Array, num_heads: int) -> jax.Array:

@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Any, Dict, Optional, Tuple, Union
+from typing import Any, Callable, Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +54,11 @@ from realtime_fraud_detection_tpu.models.trees import (
     TreeEnsemble,
     tree_ensemble_predict,
 )
+from realtime_fraud_detection_tpu.models.zaya import (
+    ZayaConfig,
+    init_zaya_params,
+    zaya_predict,
+)
 from realtime_fraud_detection_tpu.obs import scopes
 from realtime_fraud_detection_tpu.ops.epilogue import (
     epilogue_supported,
@@ -70,18 +75,53 @@ MODEL_NAMES: tuple[str, ...] = (
 )
 NUM_MODELS = len(MODEL_NAMES)
 
-# The text branch's configuration picks its encoder: a ``BertConfig`` the
-# dense DistilBERT-style one (models/bert.py), an ``OlmoeConfig`` the sparse
-# mixture-of-experts one (models/olmoe.py). The argument, the static jit
-# argument and the ``ScoringModels`` field keep the name ``bert``:
-# checkpoints, ``MODEL_NAMES`` and the benchmark's references read them.
-TextConfig = Union[BertConfig, OlmoeConfig]
+# The text branch's configuration picks its encoder by its CLASS: a
+# ``BertConfig`` the dense DistilBERT-style one (models/bert.py), an
+# ``OlmoeConfig`` or a ``ZayaConfig`` a routed sparse-expert one
+# (models/olmoe.py, models/zaya.py). The argument, the static jit argument
+# and the ``ScoringModels`` field keep the name ``bert``: checkpoints,
+# ``MODEL_NAMES`` and the benchmark's references read them.
+TextConfig = Union[BertConfig, OlmoeConfig, ZayaConfig]
+
+
+@dataclasses.dataclass(frozen=True)
+class RoutedText:
+    """A routed (sparse-expert) text encoder as the scorer sees one — the
+    ONE description every site asks (``routed_text``). ``predict`` takes
+    ``capacity`` (the token slots its routed blocks are compiled for,
+    ``scoring/text_split.py``) and, with ``with_stats``, also returns the
+    largest expert group of each layer (``i32[layers]``). Its configuration
+    class spells, under the Hugging Face names, ``num_experts``,
+    ``num_experts_per_tok``, ``num_hidden_layers``, ``hidden_size`` and
+    ``intermediate_size`` (ONE expert's width): what the counters and the
+    grouped matmul's shape predicate read. A routed encoder runs on one
+    device, has no int8 or dequant plane and no fused attention core."""
+
+    init: Callable[..., Dict[str, Any]]
+    predict: Callable[..., Any]
+
+
+_ROUTED_TEXT = {
+    OlmoeConfig: RoutedText(init_olmoe_params, olmoe_predict),
+    ZayaConfig: RoutedText(init_zaya_params, zaya_predict),
+}
+
+
+def routed_text(config: TextConfig) -> Optional[RoutedText]:
+    """The routed encoder ``config``'s class names, or None (the dense
+    encoder)."""
+    return _ROUTED_TEXT.get(type(config))
+
+
+def text_layers(config: TextConfig) -> int:
+    """The text encoder's depth, however its source spells it."""
+    return (config.num_layers if routed_text(config) is None
+            else config.num_hidden_layers)
 
 
 def init_text_params(key: jax.Array, config: TextConfig) -> Dict[str, Any]:
-    if isinstance(config, OlmoeConfig):
-        return init_olmoe_params(key, config)
-    return init_bert_params(key, config)
+    routed = routed_text(config)
+    return (init_bert_params if routed is None else routed.init)(key, config)
 
 
 def text_predict(params: Dict[str, Any], input_ids: jax.Array,
@@ -92,22 +132,23 @@ def text_predict(params: Dict[str, Any], input_ids: jax.Array,
                  ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """The text branch's probability ``f32[B]`` from the encoder
     ``config``'s class names, and that encoder's per-launch statistics:
-    ``i32[layers]`` largest expert group for the MoE encoder, ``None`` for
-    the dense one (whose program is then what it was). ``capacity`` is the
-    MoE encoder's (``models/olmoe.py``): the token slots its routed blocks
-    are compiled for."""
-    if isinstance(config, OlmoeConfig):
+    ``i32[layers]`` largest expert group for a routed encoder, ``None`` for
+    the dense one (whose program is then what it was). ``capacity`` is a
+    routed encoder's: the token slots its routed blocks are compiled
+    for."""
+    routed = routed_text(config)
+    if routed is not None:
         if dequant_kernel != "off":
             raise ValueError(
                 "KernelSettings.dequant_matmul is DistilBERT's int8 plane; "
-                "the OLMoE encoder has no quantized form")
-        return olmoe_predict(params, input_ids, attention_mask, config,
-                             capacity=capacity, use_pallas=use_pallas,
-                             kernel_interpret=kernel_interpret,
-                             with_stats=True)
+                f"a {type(config).__name__} encoder has no quantized form")
+        return routed.predict(params, input_ids, attention_mask, config,
+                              capacity=capacity, use_pallas=use_pallas,
+                              kernel_interpret=kernel_interpret,
+                              with_stats=True)
     if capacity is not None:
         raise ValueError(
-            "text_capacity is the OLMoE encoder's routed block's; the dense "
+            "text_capacity is a routed encoder's block's; the dense "
             "encoder has nothing to compact")
     return bert_predict(params, input_ids, attention_mask, config,
                         use_pallas=use_pallas, dequant_kernel=dequant_kernel,
@@ -339,8 +380,8 @@ def _score_fused_packed_impl(
     This entry takes the microbatch as the three packed buffers from
     ``core.packing.pack_tree`` (one h2d payload) and returns the §2.7
     response fields as ONE f32[B, 8+M] matrix (one d2h payload) laid out per
-    ``OUT_COLUMNS`` + model_predictions — and, with an ``OlmoeConfig`` only,
-    a second small output beside it, ``(matrix, i32[layers])``: the largest
+    ``OUT_COLUMNS`` + model_predictions — and, with a routed text encoder
+    only, a second small output beside it, ``(matrix, i32[layers])``: the largest
     expert group of each layer; ``text_capacity`` is that encoder's too
     (how many token slots its routed blocks run on: ``models/olmoe.py``;
     absent from a dense launch). XLA fuses the unpack slices into

@@ -39,7 +39,6 @@ from realtime_fraud_detection_tpu.features.rules import (
 )
 from realtime_fraud_detection_tpu.features.schema import encode_transactions
 from realtime_fraud_detection_tpu.models.bert import TINY_CONFIG
-from realtime_fraud_detection_tpu.models.olmoe import OlmoeConfig
 from realtime_fraud_detection_tpu.models.text import combined_text
 from realtime_fraud_detection_tpu.models.tokenizer import FraudTokenizer
 from realtime_fraud_detection_tpu.obs import scopes
@@ -54,6 +53,7 @@ from realtime_fraud_detection_tpu.scoring.pipeline import (
     ScoringModels,
     TextConfig,
     init_scoring_models,
+    routed_text,
     score_fused,
     score_fused_packed,
 )
@@ -118,7 +118,7 @@ class PendingScore:
     token_slots: int = 0
     token_slots_sq: int = 0
     real_tokens: int = 0
-    # The MoE text encoder only (models/olmoe.py; 0 / None otherwise):
+    # A routed text encoder only (pipeline.routed_text; 0 / None otherwise):
     # ``expert_rows`` = the (token, expert) pairs that entered the grouped
     # expert matmuls (the launch's real tokens x experts per token x
     # layers, counted at dispatch: padding is not routed);
@@ -407,10 +407,11 @@ class FraudScorer:
     ):
         self.config = config or Config()
         self.sc = scorer_config or ScorerConfig()
-        # the text branch's configuration picks its encoder: a BertConfig
-        # or an OlmoeConfig (scoring/pipeline.text_predict)
+        # the text branch's configuration picks its encoder by its class
+        # (scoring/pipeline.text_predict); what this scorer asks of a routed
+        # one is pipeline.RoutedText's contract
         self.bert_config = bert_config
-        self._moe_text = isinstance(bert_config, OlmoeConfig)
+        self._moe_text = routed_text(bert_config) is not None
         self.mesh = mesh if mesh is not None else build_mesh()
         self._refuse_bert_only_planes()
         # feature extraction needs JAX's CPU backend next to the accelerator:
@@ -654,7 +655,8 @@ class FraudScorer:
 
     def _refuse_bert_only_planes(self) -> None:
         """The planes written for the DistilBERT branch's parameter layout
-        refuse an ``OlmoeConfig`` by name instead of miscomputing."""
+        refuse a routed encoder's configuration by name instead of
+        miscomputing."""
         if not self._moe_text:
             return
         quant = self.config.quant
@@ -669,11 +671,12 @@ class FraudScorer:
         elif self.mesh.devices.size > 1:
             refused = (f"a sharded mesh of {self.mesh.devices.size} devices "
                        "would split the batch under the grouped expert "
-                       "matmul; the OLMoE encoder runs on one device "
+                       "matmul; a routed encoder runs on one device "
                        "(build_mesh(devices=jax.devices()[:1]))")
         if refused:
             raise ValueError(
-                f"{refused}: not available with an OlmoeConfig text branch")
+                f"{refused}: not available with a "
+                f"{type(self.bert_config).__name__} text branch")
 
     # ------------------------------------------------------------- pooling
     def attach_pool(self, pool) -> None:
@@ -691,8 +694,9 @@ class FraudScorer:
         if self._moe_text:
             raise ValueError(
                 f"{plane} (DevicePool / MeshExecutor) dispatches the "
-                "DistilBERT program's single result; not available with an "
-                "OlmoeConfig text branch, which runs on one chip")
+                "DistilBERT program's single result; not available with a "
+                f"{type(self.bert_config).__name__} text branch, which runs "
+                "on one chip")
 
     # --------------------------------------------------------- graph plane
     def attach_graph_fetch(self, client) -> None:

@@ -15,7 +15,7 @@ launches together hold fewer (row, position) slots than the one launch at
 ``text_len`` — fewer slots is less work in every kernel of the text branch,
 all of them linear or quadratic in the padded length.
 
-A sparse encoder's routed block (``models/olmoe.py``) has a second such
+A sparse encoder's routed block (``models/olmoe.routed_block``) has a second such
 shape, its **capacity**: how many of a launch's slots the router, the row
 gatherings and the grouped expert matmuls are compiled for. The real tokens
 of the launch are compacted into it, so it has to hold them all; who holds
@@ -47,8 +47,11 @@ MIN_LONG_ROWS = 8
 # quarters holds every batch of it with room; a rung nearer the mix would
 # send a batch to the full program for one long row too many
 CAPACITY_SHARE = (3, 4)
-# capacities are whole multiples of this many tokens: times 8 experts a
-# token that is whole 128-row tiles (ops.grouped_matmul_supported)
+# capacities are whole multiples of this many tokens: times OLMoE's 8
+# experts a token that is whole 128-row tiles
+# (ops.grouped_matmul_supported). With one expert a token (ZAYA1) the rung
+# of every bucket that gets one at 128 positions (32 rows and up: 96 rows x
+# 32) is whole tiles too; a rung that were not would run ragged_dot
 CAPACITY_MULTIPLE = 16
 # the smallest launch that gets a narrow rung. Under it an expert's group is
 # tens of rows, its matmuls are paced by reading the expert's weights

@@ -133,7 +133,8 @@ def _lowered_asm(bert_config):
     ).compiler_ir().operation.get_asm(enable_debug_info=True)
 
 
-@pytest.mark.parametrize("builder", ["ensemble_builder", "olmoe_builder"])
+@pytest.mark.parametrize("builder", ["ensemble_builder", "olmoe_builder",
+                                     "zaya1_builder"])
 def test_every_name_in_a_builders_vocabulary_is_one_the_program_writes(
         builder):
     """A builder (``benchmarks/configs/<builder>.py``) writes the device
@@ -164,9 +165,17 @@ def test_every_name_in_a_builders_vocabulary_is_one_the_program_writes(
             f"jit(f)/{scopes.TEXT}/{scopes.layer_scope(2)}/{scopes.FFN}/dot"
         ) == f"{scopes.TEXT}/{scopes.layer_scope(2)}/{scopes.FFN}"
     else:
-        from realtime_fraud_detection_tpu.models.olmoe import TINY_OLMOE
+        if builder == "olmoe_builder":
+            from realtime_fraud_detection_tpu.models.olmoe import TINY_OLMOE
 
-        config, layer_parts = TINY_OLMOE, scopes.MOE_LAYER_SCOPES
+            config, layer_parts = TINY_OLMOE, scopes.MOE_LAYER_SCOPES
+        else:
+            from realtime_fraud_detection_tpu.models.zaya import TINY_ZAYA
+
+            config, layer_parts = TINY_ZAYA, scopes.ZAYA_LAYER_SCOPES
+            assert scopes.ATTN_MIX in vocabulary[scopes.TEXT]["layer*"]
+            assert set(layer_parts) == set(scopes.MOE_LAYER_SCOPES) | {
+                scopes.ATTN_MIX}
         assert set(vocabulary[scopes.TEXT]["layer*"][scopes.EXPERTS]) == set(
             scopes.EXPERTS_PARTS)
         for part in scopes.EXPERTS_PARTS:
