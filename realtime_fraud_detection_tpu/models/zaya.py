@@ -50,6 +50,13 @@ token, bias-free ``Linear(hidden -> 2)``, ``softmax[:, 1]``; the tied LM
 head is not held). The report's learned residual scaling (two vectors a
 sublayer that a random initialisation sets to one and zero) is left out.
 
+The steps from the convolutions to the value shift (scope ``attn_mix``) have
+two lowerings of one algorithm: the XLA form below (``cca_mix`` and the shift
+beside it: every platform, and the numerics oracle) and, where ``use_pallas``
+asks and ``ZayaConfig.mix_refusal`` has nothing against the shape, one Pallas
+kernel (``ops/cca_mix.py``) that reads the latents and the values once and
+writes q, k and v once.
+
 Precision: weights stored bfloat16, bfloat16 matmul operands with float32
 accumulation in the projections, the grouped convolution and the expert
 matmuls; float32 norms, softmaxes, RoPE, residual stream and depthwise
@@ -82,6 +89,10 @@ from realtime_fraud_detection_tpu.ops.attention import (
     attention_reference,
     merge_heads,
     split_heads,
+)
+from realtime_fraud_detection_tpu.ops.cca_mix import (
+    cca_mix_fused,
+    cca_mix_refusal,
 )
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -135,6 +146,14 @@ class ZayaConfig:
     def latent_heads(self) -> int:
         """Heads the convolutions mix: query and key heads side by side."""
         return self.num_attention_heads + self.num_key_value_heads
+
+    def mix_refusal(self, seq_len: int) -> Optional[str]:
+        """Why a program of ``seq_len`` positions keeps the XLA form of the
+        mixing where the fused kernel is asked for, or None where it holds
+        the kernel (``ops.cca_mix.cca_mix_refusal``: shapes alone)."""
+        return cca_mix_refusal(seq_len, self.head_dim,
+                               self.num_key_value_heads,
+                               (self.cca_time0, self.cca_time1))
 
 
 TINY_ZAYA = ZayaConfig(
@@ -270,9 +289,12 @@ def cca_mix(layer: Dict, latents: jax.Array, cos, sin, config: ZayaConfig
 
 
 def zaya_attention(layer: Dict, h: jax.Array, attention_mask: jax.Array,
-                   config: ZayaConfig, cos, sin) -> jax.Array:
+                   config: ZayaConfig, cos, sin, *, use_pallas: bool = False,
+                   kernel_interpret: bool = False) -> jax.Array:
     """``h + o_proj(cca(...))`` on ``h`` ``f32[B, T, hidden]``: the first
-    sublayer of a block."""
+    sublayer of a block. ``use_pallas`` asks for the fused mixing
+    (``ops/cca_mix.py``); a shape it does not take (``ZayaConfig.
+    mix_refusal``) runs the XLA form."""
     heads, kv, d = (config.num_attention_heads, config.num_key_value_heads,
                     config.head_dim)
     with jax.named_scope(scopes.LN):
@@ -287,12 +309,19 @@ def zaya_attention(layer: Dict, h: jax.Array, attention_mask: jax.Array,
             preferred_element_type=jnp.float32)
         v = _proj(x, layer["v_proj"])
     with jax.named_scope(scopes.ATTN_MIX):
-        qh, kh = cca_mix(layer, latents, cos, sin, config)
-        # no bias, so projecting the previous token is shifting its
-        # projection
-        now, before = jnp.split(v, 2, axis=-1)
-        vh = split_heads(
-            jnp.concatenate([now, shift_tokens(before)], axis=-1), kv)
+        if use_pallas and config.mix_refusal(h.shape[1]) is None:
+            qh, kh, vh = cca_mix_fused(
+                latents, v, layer["conv_depthwise"], layer["conv_grouped"],
+                layer["temperature"], cos, sin, num_heads=heads,
+                num_kv_heads=kv, eps=config.rms_norm_eps,
+                interpret=kernel_interpret)
+        else:
+            qh, kh = cca_mix(layer, latents, cos, sin, config)
+            # no bias, so projecting the previous token is shifting its
+            # projection
+            now, before = jnp.split(v, 2, axis=-1)
+            vh = split_heads(
+                jnp.concatenate([now, shift_tokens(before)], axis=-1), kv)
     with jax.named_scope(scopes.ATTN_CORE):
         ctx = attention_reference(qh, kh, vh, attention_mask, causal=True)
     with jax.named_scope(scopes.ATTN_PROJ):
@@ -345,7 +374,9 @@ def zaya_layer(layer: Dict, h: jax.Array, r: Optional[jax.Array],
     b, t, width = h.shape
     if slots is None:
         slots = token_slots(attention_mask, None)
-    h = zaya_attention(layer, h, attention_mask, config, cos, sin)
+    h = zaya_attention(layer, h, attention_mask, config, cos, sin,
+                       use_pallas=use_pallas,
+                       kernel_interpret=kernel_interpret)
     with jax.named_scope(scopes.LN):
         x = rms_norm(h, layer["post_attention_layernorm"],
                      config.rms_norm_eps).reshape(b * t, width)

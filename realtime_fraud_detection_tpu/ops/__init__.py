@@ -1,3 +1,22 @@
+"""The hand-written TPU kernels (Pallas), each beside its XLA form and the
+ONE predicate on shapes that the traced guard, the scorer's selector and the
+tests all ask. Nothing here is a setting: a one-device TPU program at a shape
+a predicate admits holds the kernel, everything else the XLA form.
+
+- ``attention``: ``flash_attention`` (the dense encoder's fused core, scores
+  in VMEM) against ``attention_reference``; ``flash_supported``.
+- ``cca_mix``: ``cca_mix_fused`` (ZAYA1's convolutional mixing between the
+  latent projections and the core as one pass: latents and values in, q, k
+  and the shifted v out) against ``models.zaya.cca_mix``;
+  ``cca_mix_refusal`` (None where the shape is taken, else why not), asked
+  through ``ZayaConfig.mix_refusal``.
+- ``grouped_matmul``: ``grouped_matmul`` (the routed encoders' expert
+  matmuls, ``megablox.gmm`` with per-shape tilings) against
+  ``jax.lax.ragged_dot``; ``grouped_matmul_supported``.
+- ``dequant_matmul``, ``epilogue``: the int8 text branch's fused
+  dequant-matmul and the score-and-blend epilogue, behind ``KernelSettings``.
+"""
+
 from realtime_fraud_detection_tpu.ops.attention import (  # noqa: F401
     attention_reference,
     flash_attention,
@@ -5,6 +24,10 @@ from realtime_fraud_detection_tpu.ops.attention import (  # noqa: F401
     merge_heads,
     narrowest_supported_len,
     split_heads,
+)
+from realtime_fraud_detection_tpu.ops.cca_mix import (  # noqa: F401
+    cca_mix_fused,
+    cca_mix_refusal,
 )
 from realtime_fraud_detection_tpu.ops.dequant_matmul import (  # noqa: F401
     dequant_matmul,

@@ -904,8 +904,9 @@ class FraudScorer:
         fused attention core of the dense encoder (``bert_layer``'s traced
         guard still sends a shape ``flash_supported`` declines to the
         reference, and the engagement counters say so), the grouped expert
-        matmul of the MoE encoder (``ops.grouped_matmul``, same pattern).
-        With the kernel plane on,
+        matmul of the MoE encoder (``ops.grouped_matmul``, same pattern)
+        and, with it, ZAYA1's fused mixing (``ops/cca_mix.py``, guarded by
+        ``ZayaConfig.mix_refusal``). With the kernel plane on,
         ``KernelSettings.attention`` decides — how a drill or an A/B forces
         either side. With it off, nothing a user sets does: the kernel runs
         where the devices are TPUs, the shape is one it takes
@@ -924,17 +925,54 @@ class FraudScorer:
         return (self._platform == "tpu" and devices == 1
                 and self._text_kernel_shape_ok(text_len))
 
-    def _flash_shape_ok(self, text_len: Optional[int] = None) -> bool:
-        """Whether a program launched at ``text_len`` holds the fused
-        ATTENTION core where asked: never for the MoE encoder (head_dim
-        128, causal), which keeps the reference core."""
+    def _attention_shape_refusal(self, text_len: Optional[int] = None
+                                 ) -> Optional[str]:
+        """Why a program launched at ``text_len`` holds no Pallas kernel at
+        its attention site even where asked, or None where it holds one:
+        the fused core for the dense encoder (``flash_supported``), the
+        fused mixing for a routed encoder whose configuration has one
+        (``ZayaConfig.mix_refusal``: the predicate of ``ops/cca_mix.py``);
+        a routed encoder without (OLMoE: head_dim 128, causal) keeps the
+        reference core. The same predicates the traced guards consult."""
         from realtime_fraud_detection_tpu.ops import flash_supported
 
-        if self._moe_text:
-            return False
-        return flash_supported(text_len or self.sc.text_len,
-                               self.bert_config.head_dim,
-                               self.bert_config.num_heads)
+        t = text_len or self.sc.text_len
+        c = self.bert_config
+        if not self._moe_text:
+            if flash_supported(t, c.head_dim, c.num_heads):
+                return None
+            return (f"flash_attention takes seq_len a multiple of 128 and "
+                    f"pairs of 64-wide heads: seq_len {t}, head_dim "
+                    f"{c.head_dim}")
+        mix_refusal = getattr(c, "mix_refusal", None)
+        if mix_refusal is None:
+            return (f"{type(c).__name__}: no fused kernel at the attention "
+                    "site of a causal core at head_dim 128")
+        return mix_refusal(t)
+
+    def _attention_shape_ok(self, text_len: Optional[int] = None) -> bool:
+        return self._attention_shape_refusal(text_len) is None
+
+    def attention_refusal(self, devices: Optional[int] = None,
+                          text_len: Optional[int] = None) -> Optional[str]:
+        """Why a launch at ``text_len`` runs the XLA form at its attention
+        site, by name, or None where the program holds the Pallas kernel:
+        the shape the kernel declines, else what kept the selector
+        (``effective_use_pallas``) from asking."""
+        refusal = self._attention_shape_refusal(text_len)
+        if refusal or self.effective_use_pallas(devices, text_len):
+            return refusal
+        if self.kernels.enabled:
+            return f"KernelSettings.attention is {self.kernels.attention!r}"
+        if self._platform != "tpu":
+            return (f"a {self._platform} mesh: the kernel is chosen on TPU "
+                    "devices")
+        if devices is None:
+            devices = self.mesh.devices.size
+        if devices != 1:
+            return (f"a program over {devices} devices: XLA cannot "
+                    "partition a Mosaic call")
+        return "the grouped expert matmul declines the launch's rows"
 
     def _text_kernel_shape_ok(self, text_len: Optional[int] = None) -> bool:
         """Whether the text branch has a Pallas kernel for its shapes: the
@@ -943,7 +981,7 @@ class FraudScorer:
         larger bucket is a multiple of them, and a narrow capacity of the
         routed blocks is whole tiles by ``text_split.CAPACITY_MULTIPLE``)."""
         if not self._moe_text:
-            return self._flash_shape_ok(text_len)
+            return self._attention_shape_ok(text_len)
         from realtime_fraud_detection_tpu.ops import grouped_matmul_supported
 
         c = self.bert_config
@@ -968,7 +1006,7 @@ class FraudScorer:
                       self._kernel_counts["fallback"])
         asked = self.effective_use_pallas(
             getattr(self._pool, "program_devices", None), text_len)
-        if asked and self._flash_shape_ok(text_len):
+        if asked and self._attention_shape_ok(text_len):
             disp["attention"] += 1
         else:
             fall["attention"] += 1
@@ -1007,14 +1045,17 @@ class FraudScorer:
     def kernel_snapshot(self) -> Dict[str, Any]:
         """Kernel-plane observability payload (obs.metrics.sync_kernels):
         effective per-site modes, whether the Pallas interpreter is
-        serving (a CPU mesh), and cumulative dispatch/fallback counts per
-        site."""
+        serving (a CPU mesh), cumulative dispatch/fallback counts per
+        site, and why a launch at ``text_len`` keeps the XLA form at its
+        attention site (None where it holds the kernel)."""
         return {
             "modes": self.kernels.site_modes(),
             "interpret": bool(self.kernels.enabled
                               and self._kernel_interpret),
             "dispatch": dict(self._kernel_counts["dispatch"]),
             "fallback": dict(self._kernel_counts["fallback"]),
+            "refused": {"attention": self.attention_refusal(
+                getattr(self._pool, "program_devices", None))},
         }
 
     # ---------------------------------------------------------------- assembly

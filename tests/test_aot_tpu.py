@@ -268,6 +268,36 @@ def test_grouped_matmul_compiles_at_published_widths(one_chip, rows, groups,
     assert 'op_name="ragged-dot' in xla
 
 
+@pytest.mark.parametrize("bucket,text_len", [
+    (BUCKET, 128), (8, 128), (1, 128), (32, DEPLOYED_TEXT_LEN)],
+    ids=["bucket256", "parity_bucket8", "bucket1", "halo_at_512"])
+def test_cca_mix_compiles_at_published_widths(one_chip, bucket, text_len):
+    """ZAYA1's fused mixing at 8 + 2 heads of 128: the cell's bucket (four
+    rows a grid step: 12.6 MB of double-buffered blocks beside the body's
+    temporaries, over Mosaic's default budget, so the call names its own),
+    the parity sample's bucket of 8 (the same lowering), one row, and a
+    window of four blocks a row (the halo inputs)."""
+    from realtime_fraud_detection_tpu.models.olmoe import rope_tables
+    from realtime_fraud_detection_tpu.models.zaya import ZayaConfig
+    from realtime_fraud_detection_tpu.ops import cca_mix_fused
+
+    cfg = ZayaConfig()
+    assert cfg.mix_refusal(text_len) is None
+    heads, kv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                    cfg.head_dim)
+    cos, sin = rope_tables(text_len, cfg.rotary_dim, cfg.rope_theta)
+    fn = jax.jit(lambda c, v, dw, gw, temp: cca_mix_fused(
+        c, v, dw, gw, temp, cos, sin, num_heads=heads, num_kv_heads=kv,
+        eps=cfg.rms_norm_eps))
+    compiled = fn.lower(
+        _sds((heads + kv, bucket, text_len, d), jnp.float32, one_chip),
+        _sds((bucket, text_len, kv * d), jnp.float32, one_chip),
+        _sds((2, (heads + kv) * d), jnp.float32, one_chip),
+        _sds((heads + kv, 2 * d, d), jnp.bfloat16, one_chip),
+        _sds((kv,), jnp.float32, one_chip)).compile()
+    assert compiled.as_text().count(CUSTOM_CALL) == 1
+
+
 def _two_layers(encoder):
     if encoder == "olmoe":
         from realtime_fraud_detection_tpu.models.olmoe import OlmoeConfig
@@ -287,7 +317,8 @@ def test_routed_program_compiles_with_every_large_pass_under_a_scope(
     (two of the published layers, every width as published, bucket 256 x 128
     tokens), at both capacities of that bucket's routed blocks
     (``scoring/text_split.capacities``):
-    three Mosaic calls a layer, a second small output, temporaries that
+    three Mosaic calls a layer (four with ZAYA1's fused mixing,
+    ``ops/cca_mix.py``), a second small output, temporaries that
     leave room for the cell's layers of weights in 16 GB — and no
     instruction that
     writes 64 MB or more without a named scope in its ``op_name`` (what a
@@ -322,7 +353,11 @@ def test_routed_program_compiles_with_every_large_pass_under_a_scope(
         blob_bf16=_shapes_of(blobs["bf16"], one_chip), bert_config=config,
         use_pallas=True, text_capacity=capacity).compile()
     text = compiled.as_text()
-    assert text.count(CUSTOM_CALL) == 3 * config.num_hidden_layers
+    # the three grouped expert matmuls, and ZAYA1's fused mixing
+    assert text.count(CUSTOM_CALL) == (
+        (4 if encoder == "zaya1" else 3) * config.num_hidden_layers)
+    assert len(re.findall(r"%cca_mix\S* = .*custom-call\(", text)) == (
+        config.num_hidden_layers if encoder == "zaya1" else 0)
     assert " conditional(" not in text and "cond/branch_" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 5 << 30
     entry = text[text.index("ENTRY "):]
