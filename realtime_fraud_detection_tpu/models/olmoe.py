@@ -92,16 +92,25 @@ class OlmoeConfig:
             raise ValueError(
                 "OlmoeConfig: grouped-query attention is not implemented "
                 f"(num_key_value_heads {self.num_key_value_heads} != "
-                f"num_attention_heads {self.num_attention_heads})")
+                f"num_attention_heads {self.num_attention_heads}); grouped "
+                "keys live in ops/attention.py (attention_reference, "
+                "windowed_attention) for models/zaya.py and models/laguna.py")
         if self.norm_topk_prob:
             raise ValueError("OlmoeConfig: norm_topk_prob true is not the "
-                             "published model and is not implemented")
+                             "published model and is not implemented; "
+                             "choose_experts(renormalise=True) is what "
+                             "models/laguna.py routes with")
         if self.hidden_size % self.num_attention_heads:
             raise ValueError("hidden_size must divide into the heads")
 
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_attention_heads
+
+    @property
+    def num_sparse_layers(self) -> int:
+        """Layers with a routed block (``scoring/pipeline.RoutedText``)."""
+        return self.num_hidden_layers
 
 
 TINY_OLMOE = OlmoeConfig(
@@ -188,18 +197,26 @@ def router_probs(x: jax.Array, w_router: jax.Array) -> jax.Array:
 
 
 def choose_experts(probs: jax.Array, top_k: int,
-                   bias: Optional[jax.Array] = None
+                   bias: Optional[jax.Array] = None, *,
+                   renormalise: bool = False, scale: float = 1.0
                    ) -> Tuple[jax.Array, jax.Array]:
     """``(experts i32[N, top_k], weights f32[N, top_k])`` of router
     probabilities ``f32[N, num_experts]``, however the encoder's router made
     them: each token's ``top_k`` largest, their probabilities NOT
-    renormalised. ``bias`` (``f32[num_experts]``) moves the choice alone:
-    the largest of ``probs + bias`` are taken and weighted by ``probs``."""
+    renormalised unless ``renormalise`` (``norm_topk_prob``: divided by
+    their sum over the chosen, all of them, wherever their experts live),
+    then times ``scale`` (``moe_routed_scaling_factor``). ``bias``
+    (``f32[num_experts]``) moves the choice alone: the largest of ``probs +
+    bias`` are taken and weighted by ``probs``."""
     if bias is None:
         weights, experts = jax.lax.top_k(probs, top_k)
     else:
         _, experts = jax.lax.top_k(probs + bias, top_k)
         weights = jnp.take_along_axis(probs, experts, axis=-1)
+    if renormalise:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
     return experts.astype(jnp.int32), weights
 
 
@@ -232,19 +249,39 @@ def token_slots(attention_mask: jax.Array, capacity: Optional[int]
 
 def apply_experts(layer: Dict, x: jax.Array, experts: jax.Array,
                   weights: jax.Array, *, real: Optional[jax.Array] = None,
+                  router_width: Optional[int] = None, expert_offset: int = 0,
                   use_pallas: bool = False, kernel_interpret: bool = False
                   ) -> Tuple[jax.Array, jax.Array]:
     """``sum_e weights[n, e] * expert_e(x[n])`` for the routed ``experts``:
-    ``(f32[N, hidden], group_sizes i32[num_experts])``. ``x`` is ``[N,
+    ``(f32[N, hidden], group_sizes i32[held experts])``. ``x`` is ``[N,
     hidden]``. Every (token, expert) pair of a row that ``real`` (``bool[N]``;
     None: every row) admits is computed; the other rows' pairs enter no
-    group and their result is zero."""
+    group and their result is zero.
+
+    **A share of the experts.** The layer holds the experts its stacked
+    weights hold: ``gate_proj.shape[0]`` of the ``router_width`` the router
+    chose among (None: all of them), those numbered ``expert_offset`` on.
+    ``experts`` counts in the router's numbers. Where the layer holds fewer
+    than the router's width, a pair whose expert lives elsewhere enters no
+    group, exactly as a filler's (keyed past the last held expert, sorted
+    last, never visited by the grouped matmul) and adds nothing: the result
+    is this chip's part of the sum, with ``weights`` as the router made them
+    over all of a token's experts. No pair of a held expert is ever left
+    out. A layer that holds every expert traces what it always did."""
     n, top_k = experts.shape
     num_experts = layer["gate_proj"].shape[0]
+    share = router_width is not None and (
+        router_width != num_experts or expert_offset != 0)
     with jax.named_scope(scopes.ROUTER):
         # the (token, expert) pairs in expert order; a stable sort keeps a
         # group's rows in token order
         flat = experts.reshape(-1)
+        if share:
+            # in the layer's own numbers; a pair of an expert that lives
+            # elsewhere is keyed past the last held one
+            flat = flat - expert_offset
+            mine = (flat >= 0) & (flat < num_experts)
+            flat = jnp.where(mine, flat, num_experts)
         if real is not None:
             # keyed past the last expert, the other rows' pairs sort last
             # and are counted in no group
@@ -267,8 +304,21 @@ def apply_experts(layer: Dict, x: jax.Array, experts: jax.Array,
         act = (jax.nn.silu(gate) * up).astype(layer["down_proj"].dtype)
         out = grouped_matmul(act, layer["down_proj"], group_sizes, **gm)
     with jax.named_scope(scopes.EXPERTS_COMBINE):
-        back = out[home].reshape(n, top_k, -1)                 # token order
-        y = jnp.sum(back * weights[:, :, None], axis=1)
+        if share:
+            # a token's experts outermost, [top_k, N, H]: splitting the
+            # LEADING axis of the gathered rows moves nothing, where [N,
+            # top_k, H] with top_k no multiple of a sublane tile is a copy
+            # of every row (5.7 ms a layer at ten experts a token on the
+            # v5e: PERF.md, PR 33). The kernel never wrote an absent pair's
+            # row either: a select, not a zero weight (0 x whatever the row
+            # holds is not 0)
+            back = out[home.reshape(n, top_k).T.reshape(-1)].reshape(
+                top_k, n, -1)
+            back = jnp.where(mine.reshape(n, top_k).T[:, :, None], back, 0.0)
+            y = jnp.sum(back * weights.T[:, :, None], axis=0)
+        else:
+            back = out[home].reshape(n, top_k, -1)             # token order
+            y = jnp.sum(back * weights[:, :, None], axis=1)
         if real is not None:
             # the kernel never wrote the rows past the last group
             # (ops/grouped_matmul.py): whatever they hold, it stops here
@@ -306,6 +356,8 @@ def olmoe_attention(layer: Dict, h: jax.Array, attention_mask: jax.Array,
 
 def routed_block(layer: Dict, x: jax.Array,
                  slots: Tuple[Optional[jax.Array], jax.Array], router, *,
+                 shared=None, router_width: Optional[int] = None,
+                 expert_offset: int = 0,
                  use_pallas: bool = False, kernel_interpret: bool = False):
     """The routed half of a sparse block on the normed rows ``x`` ``f32[N,
     hidden]`` of every slot of a launch: ``(y f32[N, hidden], group_sizes,
@@ -314,7 +366,12 @@ def routed_block(layer: Dict, x: jax.Array,
     result scattered home. ``router`` maps those rows to ``(experts,
     weights, carry)`` under the ``router`` scope: the encoder's own (one
     matmul for OLMoE, an MLP with state for ZAYA1), and ``carry`` is
-    whatever it hands its next layer, on the same C rows."""
+    whatever it hands its next layer, on the same C rows. ``shared``
+    (None: the encoder has none) maps the same rows to what an expert
+    every token passes through adds, under a scope of its own; it is added
+    on the real rows before the result goes home. ``router_width`` and
+    ``expert_offset`` are ``apply_experts``': which of the router's experts
+    this layer holds."""
     n, width = x.shape
     idx, real = slots
     if idx is not None:
@@ -323,8 +380,12 @@ def routed_block(layer: Dict, x: jax.Array,
     with jax.named_scope(scopes.ROUTER):
         experts, weights, carry = router(x)
     y, group_sizes = apply_experts(
-        layer, x, experts, weights, real=real, use_pallas=use_pallas,
+        layer, x, experts, weights, real=real, router_width=router_width,
+        expert_offset=expert_offset, use_pallas=use_pallas,
         kernel_interpret=kernel_interpret)
+    if shared is not None:
+        with jax.named_scope(scopes.SHARED_EXPERT):
+            y = y + jnp.where(real[:, None], shared(x), 0.0)
     if idx is not None:
         with jax.named_scope(scopes.EXPERTS_COMBINE):
             # home: the fillers' indices lie past the last slot and drop

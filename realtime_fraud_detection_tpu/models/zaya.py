@@ -139,6 +139,11 @@ class ZayaConfig:
         return self.moe_intermediate_size
 
     @property
+    def num_sparse_layers(self) -> int:
+        """Layers with a routed block (``scoring/pipeline.RoutedText``)."""
+        return self.num_hidden_layers
+
+    @property
     def rotary_dim(self) -> int:
         return int(self.head_dim * self.partial_rotary_factor)
 

@@ -68,6 +68,15 @@ ATTN_MIX = "attn_mix"        # between the latent projections and the core:
 ZAYA_LAYER_SCOPES: Tuple[str, ...] = MOE_LAYER_SCOPES + (ATTN_MIX,)
 
 
+# ---- device: under ``text`` where the encoder is models/laguna.py: the MoE
+# names above (``attn_proj`` holds q, k, v, the head gate, RoPE and o;
+# ``attn_core`` the windowed or full causal core; layer 0's dense MLP is
+# ``ffn``, the name DistilBERT's uses) and
+SHARED_EXPERT = "shared_expert"   # the expert every token passes through
+LAGUNA_LAYER_SCOPES: Tuple[str, ...] = MOE_LAYER_SCOPES + (FFN,
+                                                           SHARED_EXPERT)
+
+
 def layer_scope(i: int) -> str:
     return f"{LAYER}{i}"
 

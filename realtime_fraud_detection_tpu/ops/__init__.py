@@ -5,6 +5,11 @@ a predicate admits holds the kernel, everything else the XLA form.
 
 - ``attention``: ``flash_attention`` (the dense encoder's fused core, scores
   in VMEM) against ``attention_reference``; ``flash_supported``.
+  ``windowed_attention`` (the causal core at ``head_dim`` 128 with grouped
+  keys, an optional sliding window and the rows' lengths: online softmax
+  over the key blocks a query block sees) against
+  ``attention_reference(causal=True, window=...)``; ``windowed_refusal``,
+  asked through ``LagunaConfig.core_refusal``.
 - ``cca_mix``: ``cca_mix_fused`` (ZAYA1's convolutional mixing between the
   latent projections and the core as one pass: latents and values in, q, k
   and the shifted v out) against ``models.zaya.cca_mix``;
@@ -23,7 +28,10 @@ from realtime_fraud_detection_tpu.ops.attention import (  # noqa: F401
     flash_supported,
     merge_heads,
     narrowest_supported_len,
+    rope_lane_tables,
     split_heads,
+    windowed_attention,
+    windowed_refusal,
 )
 from realtime_fraud_detection_tpu.ops.cca_mix import (  # noqa: F401
     cca_mix_fused,

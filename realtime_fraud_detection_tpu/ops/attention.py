@@ -19,6 +19,15 @@ row's queries in blocks of ``block_q``; a block sees ALL ``T`` keys at once
 plain two-pass one, with no online rescaling. Every score of every launched
 row is computed, padding included.
 
+``windowed_attention`` is the causal core of the encoders whose heads are
+128 wide and share keys in groups (``models/laguna.py``): one program owns
+one query block of ONE key-value head's whole group of query heads, stacked
+along the query axis as ``attention_reference`` stacks them, so a key block
+is read once a group; it walks the key blocks a query block can see — from
+``q_start - window + 1`` (0 without a window) to its own diagonal — with
+the online softmax, and a query block past its row's last real token is
+skipped (the row lengths are scalar-prefetched). Scores never leave VMEM.
+
 Precision, as the configuration states it and as XLA's default-precision
 einsum runs the reference on a TPU: bf16 MXU operands with f32 accumulation;
 scale, mask, max, exp, sum and the normalisation in f32.
@@ -41,9 +50,13 @@ LANES = 128
 def attention_reference(
     q: jax.Array, k: jax.Array, v: jax.Array,
     key_mask: jax.Array | None = None, causal: bool = False,
+    window: int | None = None,
 ) -> jax.Array:
     """Plain XLA attention (numerics oracle + CPU fallback). [B,H,S,D].
     ``causal``: a query also sees no key after its own position.
+    ``window`` (with ``causal``): nor one ``window`` or more positions
+    before it — query ``i`` sees keys ``i - window < j <= i``, ``window`` of
+    them with its own (the Hugging Face sliding-window convention).
 
     Grouped-query attention: ``k`` and ``v`` may hold fewer heads than
     ``q`` (``[B,Hkv,S,D]``, ``H`` a multiple of ``Hkv``); key head ``j``
@@ -59,9 +72,12 @@ def attention_reference(
         scores = jnp.where(key_mask[:, None, None, :], scores, NEG_INF)
     if causal:
         t_k = scores.shape[-1]
-        scores = jnp.where(
-            np.tile(np.tril(np.ones((t_q, t_k), bool)), (group, 1)), scores,
-            NEG_INF)
+        visible = np.tril(np.ones((t_q, t_k), bool))
+        if window is not None:
+            visible &= ~np.tril(np.ones((t_q, t_k), bool), -window)
+        scores = jnp.where(np.tile(visible, (group, 1)), scores, NEG_INF)
+    elif window is not None:
+        raise ValueError("attention_reference: a window is a causal core's")
     weights = jax.nn.softmax(scores, axis=-1)
     ctx = jnp.einsum("bhqk,bhkd->bhqd", weights.astype(v.dtype), v)
     return ctx.reshape(ctx.shape[0], heads, t_q, d) if group != 1 else ctx
@@ -199,3 +215,250 @@ def flash_attention(
             dimension_semantics=("parallel",), vmem_limit_bytes=vmem),
         interpret=interpret,
     )(q, k, v, bias)
+
+
+# ---------------------------------------------------------------------------
+# the causal core at head_dim 128 with grouped keys and an optional window
+
+WINDOW_BLOCK = LANES     # queries and keys a block: one lane tile of scores
+
+
+def windowed_refusal(seq_len: int, head_dim: int, num_heads: int,
+                     num_kv_heads: int, window: int | None) -> str | None:
+    """Why ``windowed_attention`` does not take a shape, by name, or None
+    where it does. The ONE predicate: the traced guard in
+    ``models/laguna.py`` and the scorer's selector both ask it."""
+    if head_dim != LANES:
+        return (f"windowed_attention takes heads of {LANES} (one lane tile "
+                f"a head): head_dim {head_dim}")
+    if num_heads % num_kv_heads:
+        return (f"windowed_attention: {num_heads} query heads do not divide "
+                f"into {num_kv_heads} key-value heads")
+    if seq_len < WINDOW_BLOCK or seq_len % WINDOW_BLOCK:
+        return (f"windowed_attention takes whole blocks of {WINDOW_BLOCK} "
+                f"positions: seq_len {seq_len}")
+    if window is not None and (window < WINDOW_BLOCK
+                               or window % WINDOW_BLOCK):
+        return (f"windowed_attention takes a window of whole blocks of "
+                f"{WINDOW_BLOCK} positions: window {window}")
+    return None
+
+
+def rope_lane_tables(cos: np.ndarray, sin: np.ndarray, head_dim: int
+                     ) -> tuple[np.ndarray, np.ndarray, np.ndarray, int]:
+    """Rotate-half RoPE on the first ``rot = cos.shape[-1]`` dims of a head
+    as three per-lane tables and a lane shift, for a kernel that rotates in
+    VMEM: ``x * c + roll(x, +s) * up + roll(x, -s) * down`` with ``s = rot /
+    2`` — ``roll(x, +s)[l] = x[l - s]`` carries the pair's first half to its
+    second (times ``+sin``), ``roll(x, -s)[l] = x[l + s]`` the second to the
+    first (times ``-sin``); lanes past ``rot`` keep ``c = 1`` and nothing
+    else. ``cos`` and ``sin`` are ``f32[T, rot]`` in the rotate-half layout
+    (``models/olmoe.rope_tables``). Host constants of the program."""
+    t, rot = cos.shape
+    half = rot // 2
+    c = np.ones((t, head_dim), np.float32)
+    up = np.zeros((t, head_dim), np.float32)
+    down = np.zeros((t, head_dim), np.float32)
+    c[:, :rot] = cos
+    down[:, :half] = -sin[:, :half]
+    up[:, half:rot] = sin[:, half:]
+    return c, up, down, half
+
+
+def _windowed_kernel(lens_ref, *refs, group: int, window_blocks: int | None,
+                     scale: float, rope_shift: int | None, gated: bool):
+    block, d = WINDOW_BLOCK, LANES
+    # inputs: q, k, v, [this query block's three tables, the whole row's
+    # three], [the gates]; output; scratch: m, l, acc, [the rotated keys]
+    refs = list(refs)
+    q_ref, k_ref, v_ref = (refs.pop(0) for _ in range(3))
+    if rope_shift is not None:
+        c_ref, up_ref, down_ref, kc_ref, kup_ref, kdown_ref = (
+            refs.pop(0) for _ in range(6))
+        keys_ref = refs.pop()       # this (row, head)'s rotated keys
+    if gated:
+        gate_ref = refs.pop(0)
+    o_ref, m_ref, l_ref, acc_ref = refs
+    row, qi = pl.program_id(0), pl.program_id(2)
+
+    def rotated(x, c, up, down):
+        x = x.astype(jnp.float32)
+        return (x * c + pltpu.roll(x, rope_shift, 1) * up
+                + pltpu.roll(x, d - rope_shift, 1) * down)
+
+    if rope_shift is not None:
+        # a (row, head)'s query blocks go by in order: its keys are rotated
+        # once, ahead of the first, and kept in VMEM for the rest
+        @pl.when((qi == 0) & (lens_ref[row] > 0))
+        def _rotate_keys():
+            def one(kb, carry):
+                at = pl.ds(pl.multiple_of(kb * block, block), block)
+                keys_ref[at, :] = rotated(
+                    k_ref[0, at, :], kc_ref[at, :], kup_ref[at, :],
+                    kdown_ref[at, :]).astype(keys_ref.dtype)
+                return carry
+
+            jax.lax.fori_loop(0, k_ref.shape[1] // block, one, 0)
+
+    @pl.when(qi * block >= lens_ref[row])
+    def _past_the_text():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(qi * block < lens_ref[row])
+    def _real():
+        def head(j):
+            x = q_ref[0, :, j * d:(j + 1) * d]
+            if rope_shift is not None:
+                x = rotated(x, c_ref[...], up_ref[...], down_ref[...])
+            return x.astype(v_ref.dtype)
+
+        # the group's query heads, stacked along the query axis: [G*bq, D]
+        q = jnp.concatenate([head(j) for j in range(group)], axis=0)
+        # where a key stands against a query inside a block pair: the same
+        # pattern in every stacked head
+        rows_ = jax.lax.broadcasted_iota(
+            jnp.int32, (group * block, block), 0) & (block - 1)
+        cols = jax.lax.broadcasted_iota(
+            jnp.int32, (group * block, block), 1)
+
+        def scores(kb):
+            keys = pl.ds(pl.multiple_of(kb * block, block), block)
+            k = k_ref[0, keys, :] if rope_shift is None else keys_ref[keys, :]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)        # [G*bq, bk]
+            return s * scale, v_ref[0, keys, :]
+
+        def fold(s, v):
+            m_prev = m_ref[...]
+            m_next = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_next)
+            p = jnp.exp(s - m_next)
+            l_ref[...] = alpha * l_ref[...] + p.sum(axis=1, keepdims=True)
+            acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            m_ref[...] = m_next
+
+        # the diagonal block first: every query sees its own key there, so
+        # the running maximum is a real score before any wholly masked row
+        # of the window's edge arrives
+        s, v = scores(qi)
+        s = jnp.where(cols <= rows_, s, NEG_INF)
+        m = s.max(axis=1, keepdims=True)
+        p = jnp.exp(s - m)
+        m_ref[...] = jnp.broadcast_to(m, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(p.sum(axis=1, keepdims=True),
+                                      l_ref.shape)
+        acc_ref[...] = jnp.dot(p.astype(v.dtype), v,
+                               preferred_element_type=jnp.float32)
+
+        # the blocks every query of this one sees whole
+        first = 0 if window_blocks is None else jnp.maximum(
+            qi - window_blocks + 1, 0)
+
+        def whole(kb, carry):
+            fold(*scores(kb))
+            return carry
+
+        jax.lax.fori_loop(first, qi, whole, 0)
+
+        if window_blocks is not None:
+            # the window's edge: key j of that block is seen by the queries
+            # before position j of this one
+            @pl.when(qi >= window_blocks)
+            def _edge():
+                s, v = scores(qi - window_blocks)
+                fold(jnp.where(cols > rows_, s, NEG_INF), v)
+
+        out = acc_ref[...] / l_ref[...]
+        for j in range(group):
+            mine = out[j * block:(j + 1) * block]
+            if gated:
+                mine = mine * gate_ref[0, 0, :, j:j + 1]
+            o_ref[0, :, j * d:(j + 1) * d] = mine.astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "num_heads", "num_kv_heads", "window", "rope_shift", "out_dtype",
+    "interpret"))
+def windowed_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                       lengths: jax.Array, *, num_heads: int,
+                       num_kv_heads: int, window: int | None = None,
+                       rope: tuple | None = None,
+                       rope_shift: int | None = None,
+                       gate: jax.Array | None = None, out_dtype=None,
+                       interpret: bool = False) -> jax.Array:
+    """Fused causal core with grouped keys. ``q`` ``[B, T, H*128]``, ``k``
+    and ``v`` ``[B, T, Hkv*128]`` (heads side by side, as the projections
+    write them; query heads ``g*G .. g*G+G-1`` read key head ``g``) ->
+    ``[B, T, H*128]`` in ``out_dtype`` (q's by default). Query ``i`` sees
+    keys ``j <= i``, with ``window`` only ``j > i - window``. ``lengths``
+    ``i32[B]``: the real tokens of each right-padded row; positions from a
+    row's first wholly padded query block on come out zero, a padded
+    position inside its last real block holds nothing anyone may read
+    (``attention_reference`` masks padded keys: the two agree at every real
+    position).
+
+    Two things may ride the kernel so that no pass stands between the
+    projections and it. ``rope`` = ``rope_lane_tables``' three ``f32[T,
+    128]`` tables with ``rope_shift``: q and k arrive UNROTATED (float32, as
+    their projections wrote them) and are rotated in VMEM in float32 and
+    rounded once to v's dtype — a query block as it arrives, a (row, head)'s
+    keys once, ahead of its first query block, into a scratch that its
+    later blocks read. ``gate`` ``f32[B, T, H]``: head ``g``'s context is
+    multiplied by ``gate[:, :, g]`` in float32 before the one rounding to
+    ``out_dtype``.
+
+    ``interpret=True`` runs the kernel through the Pallas interpreter."""
+    b, t, width = q.shape
+    refusal = windowed_refusal(t, width // num_heads, num_heads,
+                               num_kv_heads, window)
+    if refusal or width % num_heads:
+        raise ValueError(refusal or "windowed_attention: ragged heads")
+    if (rope is None) != (rope_shift is None):
+        raise ValueError("windowed_attention: rope tables and rope_shift "
+                         "come together")
+    group, block = num_heads // num_kv_heads, WINDOW_BLOCK
+    kernel = functools.partial(
+        _windowed_kernel, group=group,
+        window_blocks=None if window is None else window // block,
+        scale=LANES ** -0.5, rope_shift=rope_shift,
+        gated=gate is not None)
+    stacked = (group * block, LANES)
+    heads_block = pl.BlockSpec((1, block, group * LANES),
+                               lambda i, g, qi, lens: (i, qi, g))
+    # a row's keys and values of one head stay put while its query blocks
+    # go by: fetched once a (row, head)
+    row_block = pl.BlockSpec((1, t, LANES), lambda i, g, qi, lens: (i, 0, g))
+    in_specs, operands = [heads_block, row_block, row_block], [q, k, v]
+    scratch = [pltpu.VMEM(stacked, jnp.float32)] * 3
+    if rope is not None:
+        tables = [jnp.asarray(x, jnp.float32) for x in rope]
+        table = pl.BlockSpec((block, LANES), lambda i, g, qi, lens: (qi, 0))
+        whole = pl.BlockSpec((t, LANES), lambda i, g, qi, lens: (0, 0))
+        in_specs += [table] * 3 + [whole] * 3
+        operands += tables + tables
+        scratch.append(pltpu.VMEM((t, LANES), v.dtype))
+    if gate is not None:
+        # [B, Hkv, T, G]: a group's gates are a block's last axis whole
+        in_specs.append(pl.BlockSpec((1, 1, block, group),
+                                     lambda i, g, qi, lens: (i, g, qi, 0)))
+        operands.append(gate.astype(jnp.float32).reshape(
+            b, t, num_kv_heads, group).transpose(0, 2, 1, 3))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, num_kv_heads, t // block),
+        in_specs=in_specs,
+        out_specs=heads_block,
+        scratch_shapes=scratch,
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, t, width), out_dtype or q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=48 << 20),
+        interpret=interpret,
+        name="windowed_attention",
+    )(lengths.astype(jnp.int32), *operands)

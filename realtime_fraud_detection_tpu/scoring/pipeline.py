@@ -44,6 +44,11 @@ from realtime_fraud_detection_tpu.models.isolation_forest import (
     IsolationForest,
     iforest_predict,
 )
+from realtime_fraud_detection_tpu.models.laguna import (
+    LagunaConfig,
+    init_laguna_params,
+    laguna_predict,
+)
 from realtime_fraud_detection_tpu.models.lstm import init_lstm_params, lstm_logits
 from realtime_fraud_detection_tpu.models.olmoe import (
     OlmoeConfig,
@@ -77,11 +82,12 @@ NUM_MODELS = len(MODEL_NAMES)
 
 # The text branch's configuration picks its encoder by its CLASS: a
 # ``BertConfig`` the dense DistilBERT-style one (models/bert.py), an
-# ``OlmoeConfig`` or a ``ZayaConfig`` a routed sparse-expert one
-# (models/olmoe.py, models/zaya.py). The argument, the static jit argument
-# and the ``ScoringModels`` field keep the name ``bert``: checkpoints,
-# ``MODEL_NAMES`` and the benchmark's references read them.
-TextConfig = Union[BertConfig, OlmoeConfig, ZayaConfig]
+# ``OlmoeConfig``, a ``ZayaConfig`` or a ``LagunaConfig`` a routed
+# sparse-expert one (models/olmoe.py, models/zaya.py, models/laguna.py). The
+# argument, the static jit argument and the ``ScoringModels`` field keep the
+# name ``bert``: checkpoints, ``MODEL_NAMES`` and the benchmark's references
+# read them.
+TextConfig = Union[BertConfig, OlmoeConfig, ZayaConfig, LagunaConfig]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -89,21 +95,35 @@ class RoutedText:
     """A routed (sparse-expert) text encoder as the scorer sees one — the
     ONE description every site asks (``routed_text``). ``predict`` takes
     ``capacity`` (the token slots its routed blocks are compiled for,
-    ``scoring/text_split.py``) and, with ``with_stats``, also returns the
-    largest expert group of each layer (``i32[layers]``). Its configuration
-    class spells, under the Hugging Face names, ``num_experts``,
+    ``scoring/text_split.py``) and, with ``with_stats``, also returns its
+    launch's statistics: ``i32[layers]``, the largest expert group of each
+    routed layer — or ``i32[2, layers]`` with, under it, the (token, expert)
+    pairs that entered a held expert's group, where a layer holds a share
+    of the experts its router chooses among (the scorer then counts
+    ``expert_rows`` from the device, not from the mask). Its configuration
+    class spells, under the Hugging Face names, ``num_experts`` (the experts
+    a layer HOLDS: the groups of the grouped matmul),
     ``num_experts_per_tok``, ``num_hidden_layers``, ``hidden_size`` and
-    ``intermediate_size`` (ONE expert's width): what the counters and the
-    grouped matmul's shape predicate read. A routed encoder runs on one
-    device, has no int8 or dequant plane and no fused attention core."""
+    ``intermediate_size`` (ONE expert's width) — what the counters and the
+    grouped matmul's shape predicate read — and ``num_sparse_layers`` (the
+    layers with a routed block). ``attention_refusal(config, seq_len)``
+    names why a launch of ``seq_len`` positions holds no Pallas kernel at
+    its attention site even where asked (None where it holds one: ZAYA1's
+    fused mixing, Laguna's fused causal core); an encoder without the
+    field has none (OLMoE keeps the XLA core). A routed encoder runs on one
+    device and has no int8 or dequant plane."""
 
     init: Callable[..., Dict[str, Any]]
     predict: Callable[..., Any]
+    attention_refusal: Optional[Callable[[Any, int], Optional[str]]] = None
 
 
 _ROUTED_TEXT = {
     OlmoeConfig: RoutedText(init_olmoe_params, olmoe_predict),
-    ZayaConfig: RoutedText(init_zaya_params, zaya_predict),
+    ZayaConfig: RoutedText(init_zaya_params, zaya_predict,
+                           ZayaConfig.mix_refusal),
+    LagunaConfig: RoutedText(init_laguna_params, laguna_predict,
+                             LagunaConfig.core_refusal),
 }
 
 
@@ -131,11 +151,12 @@ def text_predict(params: Dict[str, Any], input_ids: jax.Array,
                  capacity: Optional[int] = None
                  ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """The text branch's probability ``f32[B]`` from the encoder
-    ``config``'s class names, and that encoder's per-launch statistics:
-    ``i32[layers]`` largest expert group for a routed encoder, ``None`` for
-    the dense one (whose program is then what it was). ``capacity`` is a
-    routed encoder's: the token slots its routed blocks are compiled
-    for."""
+    ``config``'s class names, and that encoder's per-launch statistics
+    (``RoutedText``: ``i32[layers]`` largest expert group for a routed
+    encoder, ``i32[2, layers]`` where it holds a share of its experts),
+    ``None`` for the dense one (whose program is then what it was).
+    ``capacity`` is a routed encoder's: the token slots its routed blocks
+    are compiled for."""
     routed = routed_text(config)
     if routed is not None:
         if dequant_kernel != "off":
@@ -382,8 +403,9 @@ def _score_fused_packed_impl(
     response fields as ONE f32[B, 8+M] matrix (one d2h payload) laid out per
     ``OUT_COLUMNS`` + model_predictions — and, with a routed text encoder
     only, a second small output beside it, ``(matrix, i32[layers])``: the largest
-    expert group of each layer; ``text_capacity`` is that encoder's too
-    (how many token slots its routed blocks run on: ``models/olmoe.py``;
+    expert group of each layer (``RoutedText``: ``i32[2, layers]`` from an
+    encoder that holds a share of its experts); ``text_capacity`` is that
+    encoder's too (how many token slots its routed blocks run on: ``models/olmoe.py``;
     absent from a dense launch). XLA fuses the unpack slices into
     the branch consumers, so the repack costs nothing on-device. What the
     transfer count is worth on local hardware is not measured.

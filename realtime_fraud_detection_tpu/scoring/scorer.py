@@ -18,7 +18,7 @@ RMW races noted in SURVEY.md §5.2).
 from __future__ import annotations
 
 import time
-from typing import Any, Dict, List, Mapping, Optional, Sequence
+from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -119,18 +119,28 @@ class PendingScore:
     token_slots_sq: int = 0
     real_tokens: int = 0
     # A routed text encoder only (pipeline.routed_text; 0 / None otherwise):
-    # ``expert_rows`` = the (token, expert) pairs that entered the grouped
-    # expert matmuls (the launch's real tokens x experts per token x
-    # layers, counted at dispatch: padding is not routed);
+    # ``routed_pairs`` = the (token, expert) pairs the routers chose (the
+    # launch's real tokens x experts per token x sparse layers, counted at
+    # dispatch: padding is not routed); ``expert_rows`` = those that entered
+    # a held expert's group of the grouped matmuls: all of them where a
+    # layer holds every expert, else read from the device at finalize (the
+    # second row of ``text_stats``);
     # ``text_stats`` = the program's second output, i32[layers] largest
     # expert group, read at finalize into ``expert_peak_rows`` (sum over
     # layers of largest group x num_experts: what the launch would cost if
     # every group were as large as the largest). Their ratio is 1.0 under
-    # even routing. ``expert_token_slots`` = the capacity the routed blocks
+    # even routing. ``attn_visible_pairs_full`` / ``_sliding`` = the
+    # (query, key) pairs a real query sees in ONE causal layer of each kind,
+    # summed over the launched rows from their lengths L: L(L+1)/2, and
+    # sum_i min(i+1, window) where the encoder has a ``sliding_window``.
+    # ``expert_token_slots`` = the capacity the routed blocks
     # ran at (scoring/text_split.py; at most ``token_slots``), and
     # ``compact_batches`` is 1 where that was a narrow rung.
+    routed_pairs: int = 0
     expert_rows: int = 0
     expert_peak_rows: int = 0
+    attn_visible_pairs_full: int = 0
+    attn_visible_pairs_sliding: int = 0
     text_stats: Optional[Any] = None
     expert_token_slots: int = 0
     compact_batches: int = 0
@@ -906,7 +916,9 @@ class FraudScorer:
         reference, and the engagement counters say so), the grouped expert
         matmul of the MoE encoder (``ops.grouped_matmul``, same pattern)
         and, with it, ZAYA1's fused mixing (``ops/cca_mix.py``, guarded by
-        ``ZayaConfig.mix_refusal``). With the kernel plane on,
+        ``ZayaConfig.mix_refusal``) or Laguna's fused causal core
+        (``ops.attention.windowed_attention``, guarded by
+        ``LagunaConfig.core_refusal``). With the kernel plane on,
         ``KernelSettings.attention`` decides — how a drill or an A/B forces
         either side. With it off, nothing a user sets does: the kernel runs
         where the devices are TPUs, the shape is one it takes
@@ -930,10 +942,13 @@ class FraudScorer:
         """Why a program launched at ``text_len`` holds no Pallas kernel at
         its attention site even where asked, or None where it holds one:
         the fused core for the dense encoder (``flash_supported``), the
-        fused mixing for a routed encoder whose configuration has one
-        (``ZayaConfig.mix_refusal``: the predicate of ``ops/cca_mix.py``);
-        a routed encoder without (OLMoE: head_dim 128, causal) keeps the
-        reference core. The same predicates the traced guards consult."""
+        fused mixing or the fused causal core for a routed encoder whose
+        row of ``pipeline.RoutedText`` names one
+        (``ZayaConfig.mix_refusal``: the predicate of ``ops/cca_mix.py``;
+        ``LagunaConfig.core_refusal``: that of
+        ``ops.attention.windowed_attention``); a routed encoder without
+        (OLMoE) keeps the reference core. The same predicates the traced
+        guards consult."""
         from realtime_fraud_detection_tpu.ops import flash_supported
 
         t = text_len or self.sc.text_len
@@ -944,11 +959,11 @@ class FraudScorer:
             return (f"flash_attention takes seq_len a multiple of 128 and "
                     f"pairs of 64-wide heads: seq_len {t}, head_dim "
                     f"{c.head_dim}")
-        mix_refusal = getattr(c, "mix_refusal", None)
-        if mix_refusal is None:
+        refusal = routed_text(c).attention_refusal
+        if refusal is None:
             return (f"{type(c).__name__}: no fused kernel at the attention "
                     "site of a causal core at head_dim 128")
-        return mix_refusal(t)
+        return refusal(c, t)
 
     def _attention_shape_ok(self, text_len: Optional[int] = None) -> bool:
         return self._attention_shape_refusal(text_len) is None
@@ -1335,7 +1350,10 @@ class FraudScorer:
                 # the batch's real tokens (text_split.capacity)
                 launches = [self._routed_launch(batch, n, size, full,
                                                 real_tokens)]
+                visible_full, visible_sliding = self._visible_pairs(
+                    np.count_nonzero(batch.token_mask, axis=1))
             else:
+                visible_full = visible_sliding = 0
                 launches = self._text_launches(batch, n, size, full,
                                                bucket_of)
             for launch in launches:
@@ -1397,6 +1415,7 @@ class FraudScorer:
         counts["split_batches"] += split
         counts["expert_token_slots"] += expert_slots
         counts["compact_batches"] += compact
+        routed_pairs = self._routed_pairs(launches[0].tokens)
         return PendingScore(records=list(records), n=n, out=out,
                             # rtfd-lint: allow[d2h] batch.features is a host-assembled ndarray
                             features=np.asarray(batch.features),
@@ -1408,7 +1427,12 @@ class FraudScorer:
                             token_slots_sq=sum(la.size * la.width * la.width
                                                for la in launches),
                             real_tokens=real_tokens,
-                            expert_rows=self._expert_rows(launches[0].tokens),
+                            routed_pairs=routed_pairs,
+                            # where a layer holds a share of its experts,
+                            # finalize reads what it took from the device
+                            expert_rows=routed_pairs,
+                            attn_visible_pairs_full=visible_full,
+                            attn_visible_pairs_sliding=visible_sliding,
                             text_stats=text_stats,
                             expert_token_slots=expert_slots,
                             compact_batches=compact,
@@ -1553,13 +1577,28 @@ class FraudScorer:
             **self.quant_static(), **self.kernel_static(), **routed,
         )
 
-    def _expert_rows(self, tokens: int) -> int:
-        """The (token, expert) pairs a launch of ``tokens`` real tokens
-        sends into the grouped expert matmuls, all layers."""
+    def _routed_pairs(self, tokens: int) -> int:
+        """The (token, expert) pairs the routers of a launch of ``tokens``
+        real tokens choose, all sparse layers: what enters the grouped
+        expert matmuls where every layer holds every expert."""
         if not self._moe_text:
             return 0
         c = self.bert_config
-        return tokens * c.num_experts_per_tok * c.num_hidden_layers
+        return tokens * c.num_experts_per_tok * c.num_sparse_layers
+
+    def _visible_pairs(self, lengths: np.ndarray) -> Tuple[int, int]:
+        """The (query, key) pairs the real queries of rows of ``lengths``
+        real tokens see in one causal layer, ``L(L+1)/2`` a row, and in one
+        layer under the encoder's ``sliding_window`` W (0 where it has
+        none): ``sum_i min(i+1, W)`` = the same less the ``(L-W)(L-W+1)/2``
+        pairs further back than the window."""
+        lengths = lengths.astype(np.int64)
+        full = int(np.sum(lengths * (lengths + 1) // 2))
+        window = getattr(self.bert_config, "sliding_window", None)
+        if not window:
+            return full, 0
+        beyond = np.maximum(lengths - window, 0)
+        return full, full - int(np.sum(beyond * (beyond + 1) // 2))
 
     def finalize(self, pending: "PendingScore", now: Optional[float] = None,
                  lock=None) -> List[Dict[str, Any]]:
@@ -1590,9 +1629,14 @@ class FraudScorer:
             else:
                 out = jax.device_get(pending.out)  # blocks until done
             if pending.text_stats is not None:
+                peaks = jax.device_get(pending.text_stats)
+                if peaks.ndim == 2:
+                    # an encoder that holds a share of its experts
+                    # (pipeline.RoutedText): the held pairs under the peaks
+                    peaks, held = peaks
+                    pending.expert_rows = int(np.sum(held))
                 pending.expert_peak_rows = (
-                    int(np.sum(jax.device_get(pending.text_stats)))
-                    * self.bert_config.num_experts)
+                    int(np.sum(peaks)) * self.bert_config.num_experts)
         # processing time = assemble/dispatch + device wait; excludes any
         # pipeline queue wait between dispatch() returning and this call
         elapsed_ms = (pending.dispatch_ms

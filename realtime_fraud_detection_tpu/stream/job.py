@@ -293,6 +293,14 @@ class StreamJob:
             # expert_peak_rows / expert_token_slots / compact_batches)
             "expert_rows": 0, "expert_peak_rows": 0,
             "expert_token_slots": 0, "compact_batches": 0,
+            # the (token, expert) pairs the routers chose (``expert_rows``
+            # of them entered a held expert's group: all, unless a layer
+            # holds a share of its experts) and the (query, key) pairs the
+            # real queries saw in one causal layer, and in one layer under
+            # the encoder's sliding window (PendingScore.routed_pairs /
+            # attn_visible_pairs_full / attn_visible_pairs_sliding)
+            "routed_pairs": 0, "attn_visible_pairs_full": 0,
+            "attn_visible_pairs_sliding": 0,
             # how the rows were launched (scoring/text_split.py): real rows
             # in a program narrower than ``text_len``, real rows at
             # ``text_len``, batches that took two launches
@@ -571,6 +579,8 @@ class StreamJob:
                 for key in ("token_slots", "token_slots_sq", "real_tokens",
                             "expert_rows", "expert_peak_rows",
                             "expert_token_slots", "compact_batches",
+                            "routed_pairs", "attn_visible_pairs_full",
+                            "attn_visible_pairs_sliding",
                             "short_text_rows", "long_text_rows",
                             "split_batches"):
                     # 0 from a stand-in scorer's pending without them

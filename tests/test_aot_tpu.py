@@ -381,3 +381,89 @@ def test_routed_program_compiles_with_every_large_pass_under_a_scope(
         if nbytes >= 64e6 and not (op and op.group(1).startswith("text/")):
             unnamed.append((m.group(1), nbytes))
     assert not unnamed, unnamed
+
+
+# ----------------------------------------------------------------- Laguna
+@pytest.mark.parametrize("heads,window", [(72, 512), (48, None)],
+                         ids=["sliding_72_heads", "full_48_heads"])
+def test_windowed_attention_compiles_at_published_widths(one_chip, heads,
+                                                         window):
+    """Laguna's fused causal core as the cell launches it: 8 rows x 2,048
+    positions, groups of 9 or 6 query heads of 128 over 8 key-value heads,
+    q and k float32 and rotated in VMEM (the YaRN half-head tables on a
+    full layer, the default whole-head ones on a sliding one), the context
+    gated in the epilogue, bfloat16 out."""
+    from realtime_fraud_detection_tpu.models.laguna import (
+        LagunaConfig,
+        laguna_rope_tables,
+    )
+    from realtime_fraud_detection_tpu.ops import (
+        rope_lane_tables,
+        windowed_attention,
+    )
+
+    cfg, b, t, kv = LagunaConfig(), 8, 2048, 8
+    assert cfg.core_refusal(t) is None
+    rope = cfg.rope_sliding if window else cfg.rope_full
+    *tables, shift = rope_lane_tables(
+        *laguna_rope_tables(t, cfg.head_dim, rope), cfg.head_dim)
+    fn = jax.jit(lambda q, k, v, lens, gate: windowed_attention(
+        q, k, v, lens, num_heads=heads, num_kv_heads=kv, window=window,
+        rope=tuple(tables), rope_shift=shift, gate=gate,
+        out_dtype=jnp.bfloat16))
+    compiled = fn.lower(
+        _sds((b, t, heads * 128), jnp.float32, one_chip),
+        _sds((b, t, kv * 128), jnp.float32, one_chip),
+        _sds((b, t, kv * 128), jnp.bfloat16, one_chip),
+        _sds((b,), jnp.int32, one_chip),
+        _sds((b, t, heads), jnp.float32, one_chip)).compile()
+    assert compiled.as_text().count(CUSTOM_CALL) == 1
+
+
+def test_laguna_program_compiles_with_its_unlike_layers(one_chip):
+    """The served packed program with a ``LagunaConfig``: layer 0 (full
+    attention, the dense MLP) and one sliding sparse layer holding 64 of 256
+    experts, every width as published, bucket 8 x 2,048 tokens at the
+    three-quarters capacity: two fused cores and three grouped matmuls, a
+    second small output, no conditional, temporaries that leave room for the
+    cell's five layers of weights in 16 GB."""
+    from realtime_fraud_detection_tpu.core.packing import pack_tree
+    from realtime_fraud_detection_tpu.ensemble.combine import EnsembleParams
+    from realtime_fraud_detection_tpu.models.laguna import (
+        DENSE,
+        FULL,
+        SLIDING,
+        SPARSE,
+        LagunaConfig,
+    )
+    from realtime_fraud_detection_tpu.scoring.pipeline import (
+        MODEL_NAMES,
+        ScorerConfig,
+        init_scoring_models,
+        make_example_batch,
+        score_fused_packed,
+    )
+    from realtime_fraud_detection_tpu.utils.config import Config
+
+    config = LagunaConfig(
+        num_hidden_layers=2, layer_types=(FULL, SLIDING),
+        mlp_layer_types=(DENSE, SPARSE),
+        num_attention_heads_per_layer=(48, 72), num_experts=64)
+    models = jax.eval_shape(
+        lambda key: init_scoring_models(key, bert_config=config),
+        jax.random.PRNGKey(0))
+    blobs, spec = pack_tree(make_example_batch(
+        8, ScorerConfig(text_len=2048)))
+    compiled = score_fused_packed.lower(
+        _shapes_of(models, one_chip),
+        *(_shapes_of(blobs[k], one_chip) for k in ("f32", "i32", "u8")),
+        spec=spec,
+        params=EnsembleParams.from_config(Config(), list(MODEL_NAMES)),
+        model_valid=_sds((len(MODEL_NAMES),), jnp.bool_, one_chip),
+        blob_bf16=_shapes_of(blobs["bf16"], one_chip), bert_config=config,
+        use_pallas=True, text_capacity=12288).compile()
+    text = compiled.as_text()
+    assert text.count(CUSTOM_CALL) == 2 + 3
+    assert text.count("windowed_attention") >= 2
+    assert " conditional(" not in text and "cond/branch_" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 << 30

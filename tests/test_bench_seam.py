@@ -39,6 +39,11 @@ ZAYA_CELL = "zaya1-s128-memo-saturated"
 FULL_CELL = "olmoe-s128-fullwindow-saturated"
 ZAYA_CFG = json.loads(
     (ROOT / "benchmarks/configs/zaya1-8b-s128.json").read_text())
+# PR 33: Laguna's cell, and ZAYA1's every-slot control on OLMoE's mix
+LAGUNA_CELL = "laguna-s2048-remit-saturated"
+ZAYA_FULL_CELL = "zaya1-s128-fullwindow-saturated"
+LAGUNA_CFG = json.loads(
+    (ROOT / "benchmarks/configs/laguna-s-2.1-s2048.json").read_text())
 
 
 # ------------------------------------------------------ configuration files
@@ -60,7 +65,10 @@ def test_a_config_file_runs_the_published_sizes_but_for_reduced(config):
     assert callable(spec.reference(cfg["reference"]).score)
     default = JobConfig()
     for key, value in cfg["job"].items():
-        if key not in ("device_pool", "inflight_depth"):
+        if key == "max_batch" and "max_batch" in cfg.get("job_from", ""):
+            # a departure the file states with its reason (2,048-token rows)
+            assert value in (1, 8, 32, 128, 256)
+        elif key not in ("device_pool", "inflight_depth"):
             assert getattr(default, key) == value, key
 
 
@@ -128,9 +136,57 @@ def test_the_zaya1_file_is_the_sources_config_cut_in_depth_only():
         builder.zaya_config({**ZAYA_CFG, "layer_types": ["sliding"] * 40})
 
 
+def test_the_laguna_file_is_the_sources_config_cut_in_depth_and_share():
+    from realtime_fraud_detection_tpu.models.laguna import (
+        FULL,
+        SLIDING,
+        LagunaConfig,
+    )
+
+    builder = spec.builder(LAGUNA_CFG)
+    built = builder.laguna_config(LAGUNA_CFG)
+    assert LAGUNA_CFG["reduced"] == ["num_hidden_layers", "num_experts"]
+    published = LagunaConfig()                 # defaults: the source's
+    assert built == LagunaConfig(
+        num_hidden_layers=5, layer_types=published.layer_types[:5],
+        mlp_layer_types=published.mlp_layer_types[:5],
+        num_attention_heads_per_layer=(48, 72, 72, 72, 48), num_experts=64)
+    assert built.layer_types == (FULL, SLIDING, SLIDING, SLIDING, FULL)
+    assert LAGUNA_CFG["published"]["num_hidden_layers"] == 48
+    assert LAGUNA_CFG["published"]["num_experts"] == 256 \
+        == built.router_experts
+    assert LAGUNA_CFG["expert_share"] == {"chips": 4, "index": 0}
+    assert (built.num_experts, built.expert_offset) == (64, 0)
+    # the source's two names the routed-encoder seam reads its own way
+    assert LAGUNA_CFG["intermediate_size"] == 12288 \
+        == built.dense_intermediate_size
+    assert built.intermediate_size == LAGUNA_CFG["moe_intermediate_size"]
+    # the source's lists and nested groups are copied whole
+    for key in ("layer_types", "mlp_layer_types", "gating_types",
+                "num_attention_heads_per_layer"):
+        assert len(LAGUNA_CFG[key]) == 48, key
+    assert LAGUNA_CFG["rope_parameters"]["full_attention"]["factor"] == 128
+    assert LAGUNA_CFG["text_len"] == 2048 and LAGUNA_CFG["chips"] == 1
+    assert LAGUNA_CFG["job"]["max_batch"] == 8
+    for key in ("cut", "deployment", "assumed", "compute_dtype", "guarantee",
+                "parity_atol_from", "job_from"):
+        assert LAGUNA_CFG[key] and "TO BE WRITTEN" not in json.dumps(
+            LAGUNA_CFG[key]), key
+    for item in ("equations", "head", "weights", "tokenizer", "traffic"):
+        assert LAGUNA_CFG["assumed"][item], item
+    tiny = builder.laguna_config({**LAGUNA_CFG, **builder.TINY})
+    assert tiny.hidden_size < 512 and tiny.sliding_window == 32
+    assert (tiny.num_experts, tiny.router_experts) == (8, 32)
+    with pytest.raises(ValueError, match="per-head"):
+        builder.laguna_config({**LAGUNA_CFG, "gating": "per-channel"})
+    with pytest.raises(ValueError, match="mlp_only_layers"):
+        builder.laguna_config({**LAGUNA_CFG, "mlp_only_layers": [0, 1]})
+
+
 def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
     cells = [w["name"] for w in BM["workloads"]]
-    assert cells[2:] == [OLMOE_CELL, ZAYA_CELL, FULL_CELL]
+    assert cells[2:] == [OLMOE_CELL, ZAYA_CELL, FULL_CELL, LAGUNA_CELL,
+                         ZAYA_FULL_CELL]
     by_name = {w["name"]: w for w in BM["workloads"]}
     assert (by_name[OLMOE_CELL]["config"], by_name[OLMOE_CELL]["traffic"]
             ) == ("olmoe-1b-7b-s128", "s128-memo-saturated")
@@ -147,8 +203,34 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
     olmoe_only = {"expert_ffn_roofline_pct", "router_roofline_pct"}
     zaya_only = {"cca_mix_ms_per_batch", "cca_mix_roofline_pct",
                  "zaya1_expert_ffn_roofline_pct", "zaya1_router_roofline_pct"}
+    assert (by_name[LAGUNA_CELL]["config"], by_name[LAGUNA_CELL]["traffic"],
+            by_name[LAGUNA_CELL]["chips"]) == (
+        "laguna-s-2.1-s2048", "s2048-remit-saturated", 1)
+    assert (by_name[ZAYA_FULL_CELL]["config"],
+            by_name[ZAYA_FULL_CELL]["traffic"],
+            by_name[ZAYA_FULL_CELL]["chips"]) == (
+        "zaya1-8b-s128", "s128-fullwindow-saturated", 1)
+    laguna_only = {"attn_core_sliding_ms_per_batch",
+                   "attn_core_full_ms_per_batch",
+                   "laguna_attn_core_roofline_pct",
+                   "laguna_expert_ffn_roofline_pct",
+                   "shared_expert_ms_per_batch", "expert_local_share_pct"}
     reports = {cell: {m["name"] for m in spec.metrics_for(cell, "per_layer")}
-               for cell in (OLMOE_CELL, ZAYA_CELL, FULL_CELL)}
+               for cell in (OLMOE_CELL, ZAYA_CELL, FULL_CELL, LAGUNA_CELL,
+                            ZAYA_FULL_CELL)}
+    # ZAYA1's second cell reports exactly what its first does
+    assert reports[ZAYA_FULL_CELL] == reports[ZAYA_CELL]
+    # Laguna's: the shared names, its dense layer 0's, and its own six
+    assert reports[LAGUNA_CELL] == (
+        (reports[OLMOE_CELL] - {"expert_ffn_roofline_pct",
+                                "router_roofline_pct"})
+        | {"ffn_ms_per_batch"} | laguna_only)
+    laguna_reports = reports.pop(LAGUNA_CELL)
+    assert not {"ffn_roofline_pct", "attn_core_roofline_pct"} & laguna_reports
+    for m in BM["per_layer"][-6:]:
+        assert m["name"] in laguna_only
+        assert m["workloads"] == [LAGUNA_CELL] and m["moves"] == "txn_per_s"
+    earlier = BM["per_layer"][:-6]  # the checks below are on what PR 30 left
     # OLMoE's second cell reports exactly what its first does
     assert reports[FULL_CELL] == reports[OLMOE_CELL] >= common | olmoe_only
     # OLMoE's kernel files read OLMoE's keys and byte model
@@ -157,12 +239,15 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
     for cell in reports:
         assert not {"ffn_ms_per_batch", "ffn_roofline_pct",
                     "attn_core_roofline_pct"} & reports[cell]
-    for m in BM["per_layer"][-10:-4]:
-        assert m["workloads"][0] == OLMOE_CELL and m["workloads"][-1] \
-            == FULL_CELL and m["moves"] == "txn_per_s"
-    for m in BM["per_layer"][-4:]:
+    for m in earlier[-10:-4]:
+        # PR 33 appended its two cells' names behind what was there
+        assert m["workloads"][0] == OLMOE_CELL and m["moves"] == "txn_per_s"
+        assert [w for w in m["workloads"]
+                if w not in (LAGUNA_CELL, ZAYA_FULL_CELL)][-1] == FULL_CELL
+    for m in earlier[-4:]:
         assert m["name"] in zaya_only
-        assert m["workloads"] == [ZAYA_CELL] and m["moves"] == "txn_per_s"
+        assert m["workloads"] == [ZAYA_CELL, ZAYA_FULL_CELL] \
+            and m["moves"] == "txn_per_s"
     # the two mixes differ in how full the window is, and in nothing else
     a = spec.cell(OLMOE_CELL)["traffic_data"]
     b = spec.cell(FULL_CELL)["traffic_data"]
@@ -347,9 +432,108 @@ def test_the_new_metrics_on_a_hand_made_run():
         assert spec.reader_for(name, "per_layer")(old) is None, name
 
 
+def test_laguna_matmul_flops_charge_the_even_share_and_visible_pairs():
+    builder = spec.builder(LAGUNA_CFG)
+    parts = builder.text_matmul_flops_per_row(LAGUNA_CFG)
+    t, h = 2048, 3072
+    # q and o of 6,144 (two full layers) and 9,216 (three sliding), k, v, gate
+    assert parts["projections"] == 2.0 * t * h * (
+        2 * (2 * 48 + 3 * 72) * 128 + 5 * 2 * 1024 + 2 * 48 + 3 * 72)
+    assert parts["dense_mlp"] == 6.0 * t * h * 12288         # layer 0 alone
+    # four sparse layers; 2.5 of a token's ten experts live here
+    assert parts["experts"] == 4 * 6.0 * t * h * 1024 * 2.5
+    assert parts["shared_expert"] == 4 * 6.0 * t * h * 1024
+    assert parts["router"] == 4 * 2.0 * t * h * 256
+    full, sliding = builder.visible_pairs(t, None), builder.visible_pairs(
+        t, 512)
+    assert full == t * (t + 1) // 2
+    assert sliding == sum(min(i + 1, 512) for i in range(t))
+    assert parts["cores"] == 4.0 * 128 * (2 * 48 * full + 3 * 72 * sliding)
+    total = builder.matmul_flops_per_batch(LAGUNA_CFG)
+    assert 0.99 < 8 * sum(parts.values()) / total <= 1.0
+    assert "stale kind" in builder.matmul_flops_per_batch.__doc__
+
+
+def test_laguna_kernels_charge_what_the_program_counted():
+    counters = {"batches": 3, "token_slots": 3 * 8 * 2048,
+                "attn_visible_pairs_full": 7_000_000,
+                "attn_visible_pairs_sliding": 4_000_000,
+                "expert_rows": 300_000, "routed_pairs": 1_200_000}
+    core = spec.kernel("laguna_attn_core").work(counters, LAGUNA_CFG)
+    # visible pairs of real tokens, not padded squares: 4 x 128 FLOP a pair
+    # and query head, 96 heads over the two full layers, 216 over the three
+    # sliding ones
+    assert core["flops"] == 4 * 128 * (96 * 7_000_000 + 216 * 4_000_000)
+    assert core["hbm_bytes"] == (2 * 312 + 2 * 40) * 3 * 8 * 2048 * 128 * 2
+    ffn = spec.kernel("laguna_expert_ffn").work(counters, LAGUNA_CFG)
+    assert ffn["flops"] == 6 * 300_000 * 3072 * 1024
+    assert ffn["hbm_bytes"] > 3 * 4 * 64 * 3 * 3072 * 1024 * 2
+    for kernel in ("laguna_attn_core", "laguna_expert_ffn"):
+        none = spec.kernel(kernel).work({"batches": 3}, LAGUNA_CFG)
+        assert none == {"flops": 0.0, "hbm_bytes": 0.0}, kernel
+
+
+def test_the_laguna_metrics_on_a_hand_made_run():
+    real = 10_100
+    counters = {"batches": 2, "scored": 16, "token_slots": 2 * 8 * 2048,
+                "real_tokens": 2 * real, "expert_token_slots": 2 * 12288,
+                "routed_pairs": 2 * real * 10 * 4,
+                "expert_rows": 2 * real * 10, "expert_peak_rows": 3 * real * 10,
+                "attn_visible_pairs_full": 14_000_000,
+                "attn_visible_pairs_sliding": 8_000_000}
+    scope_s = {"text": 0.4}
+    for i in range(5):
+        scope_s[f"text/layer{i}/attn_core"] = 0.004 if i in (0, 4) else 0.003
+    scope_s["text/layer0/ffn"] = 0.04
+    for i in range(1, 5):
+        scope_s.update({
+            f"text/layer{i}/experts": 0.03,
+            f"text/layer{i}/experts/matmul": 0.01,
+            f"text/layer{i}/router": 0.002,
+            f"text/layer{i}/shared_expert": 0.0015})
+    run = _fake_run(scope_s, counters, LAGUNA_CFG)
+
+    def metric(name):
+        return spec.reader_for(name, "per_layer")(run)
+
+    assert metric("attn_core_sliding_ms_per_batch") == pytest.approx(4.5)
+    assert metric("attn_core_full_ms_per_batch") == pytest.approx(4.0)
+    assert metric("attn_core_ms_per_batch") == pytest.approx(8.5)
+    assert metric("ffn_ms_per_batch") == pytest.approx(20.0)
+    assert metric("shared_expert_ms_per_batch") == pytest.approx(3.0)
+    assert metric("expert_ffn_ms_per_batch") == pytest.approx(60.0)
+    assert metric("expert_matmul_ms_per_batch") == pytest.approx(20.0)
+    assert metric("router_ms_per_batch") == pytest.approx(4.0)
+    assert metric("expert_local_share_pct") == pytest.approx(25.0)
+    assert metric("expert_imbalance_x") == pytest.approx(1.5)
+    for name, kernel, seconds in (
+            ("laguna_attn_core_roofline_pct", "laguna_attn_core", 0.017),
+            ("laguna_expert_ffn_roofline_pct", "laguna_expert_ffn", 0.04)):
+        needs = spec.kernel(kernel).work(counters, LAGUNA_CFG)["flops"]
+        assert 0 < metric(name) < 100, name
+        assert metric(name) == pytest.approx(
+            100 * needs / 197e12 / seconds), name
+    # against a program without the scopes and counters (the parent has no
+    # shared_expert scope, no routed_pairs, no visible pairs) every new
+    # metric is left out and none raises
+    parent = _fake_run({"text": 0.9, "text/layer1/router": 0.1,
+                        "text/layer1/experts/matmul": 0.1},
+                       {"batches": 2, "scored": 16, "expert_rows": 5},
+                       LAGUNA_CFG)
+    for name in ("attn_core_sliding_ms_per_batch",
+                 "attn_core_full_ms_per_batch",
+                 "laguna_attn_core_roofline_pct", "shared_expert_ms_per_batch",
+                 "expert_local_share_pct"):
+        assert spec.reader_for(name, "per_layer")(parent) is None, name
+    older = _fake_run({"text": 0.9}, {"batches": 2, "scored": 16}, LAGUNA_CFG)
+    assert spec.reader_for("laguna_expert_ffn_roofline_pct",
+                           "per_layer")(older) is None
+
+
 # ------------------------------------------------ a program without the module
 @pytest.mark.parametrize("cfg,module", [(OLMOE_CFG, "olmoe"),
-                                        (ZAYA_CFG, "zaya")])
+                                        (ZAYA_CFG, "zaya"),
+                                        (LAGUNA_CFG, "laguna")])
 def test_the_builder_stops_at_once_on_a_program_without_the_encoder(
         monkeypatch, cfg, module):
     import importlib.util
@@ -364,7 +548,8 @@ def test_the_builder_stops_at_once_on_a_program_without_the_encoder(
 
 
 @pytest.mark.parametrize("cell,module", [(OLMOE_CELL, "olmoe"),
-                                         (ZAYA_CELL, "zaya")])
+                                         (ZAYA_CELL, "zaya"),
+                                         (LAGUNA_CELL, "laguna")])
 def test_the_parent_exits_non_zero_within_seconds(tmp_path, cell, module):
     """A checkout of the benchmark without the program's new module — what
     the driver's parent run of a new configuration's cell is — prints no
@@ -393,7 +578,8 @@ def tiny_copy(tmp_path_factory):
     copy = rehearsal.make_tiny_copy(tmp_path_factory.mktemp("bench_seam"))
     # the rehearsal sizes a mix it does not know for 300 txn/s; a backlog
     # has to outlast the window whatever this CPU completes
-    for mix in ("s128-memo-saturated", "s128-fullwindow-saturated"):
+    for mix in ("s128-memo-saturated", "s128-fullwindow-saturated",
+                "s2048-remit-saturated"):
         traffic = copy / "benchmarks" / "traffic" / f"{mix}.json"
         tr = json.loads(traffic.read_text())
         tr["rate_txn_per_s"] = 2000
@@ -403,7 +589,7 @@ def tiny_copy(tmp_path_factory):
 
 @pytest.mark.parametrize("cell,trace", [
     (OLMOE_CELL, 0), (OLMOE_CELL, 1), (ZAYA_CELL, 0), (ZAYA_CELL, 1),
-    (FULL_CELL, 1)])
+    (FULL_CELL, 1), (LAGUNA_CELL, 0), (LAGUNA_CELL, 1), (ZAYA_FULL_CELL, 1)])
 def test_tiny_rehearsal_of_a_routed_cell(tiny_copy, cell, trace):
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(ROOT),
                XLA_FLAGS="--xla_force_host_platform_device_count=1")
@@ -422,11 +608,20 @@ def test_tiny_rehearsal_of_a_routed_cell(tiny_copy, cell, trace):
         # counters are read on any backend; device scopes need the chip
         assert out["metrics"]["expert_imbalance_x"]["value"] >= 1.0
         padding = out["metrics"]["token_padding_pct"]["value"]
-        # the full-window mix leaves an eighth of the slots empty, the memo
-        # mix a third
-        assert (5 < padding < 25) if cell == FULL_CELL else (25 < padding < 60)
+        if cell == LAGUNA_CELL:
+            # the rehearsal's 128 positions are under the mix's shortest
+            # text: every slot real; a quarter of the pairs held here
+            assert padding == 0.0
+            share = out["metrics"]["expert_local_share_pct"]["value"]
+            assert 15 < share < 35
+        else:
+            # the full-window mix leaves an eighth of the slots empty, the
+            # memo mix a third
+            assert (5 < padding < 25) if cell in (FULL_CELL, ZAYA_FULL_CELL) \
+                else (25 < padding < 60)
         for name in ("expert_ffn_ms_per_batch", "cca_mix_ms_per_batch",
-                     "cca_mix_roofline_pct"):
+                     "cca_mix_roofline_pct", "laguna_attn_core_roofline_pct",
+                     "shared_expert_ms_per_batch"):
             assert name not in out["metrics"]
     else:
         assert set(out["metrics"]) == {"txn_per_s", "setup_s"}

@@ -111,9 +111,11 @@ def test_scopes_do_not_change_the_compiled_program(scoped, monkeypatch):
 
 
 def _vocabulary_paths(level, prefix=""):
-    """Every path of a builder's nested ``VOCABULARY``, ``*`` as layer 0."""
+    """Every path of a builder's nested ``VOCABULARY`` as a pattern, ``*``
+    as some layer's digits (an encoder's layers may be unlike: Laguna's
+    layer 0 has the ``ffn``, its later ones the experts)."""
     for name, below in level.items():
-        path = prefix + name.replace("*", "0")
+        path = prefix + re.escape(name).replace(r"\*", r"\d+")
         yield path
         yield from _vocabulary_paths(below, path + "/")
 
@@ -134,7 +136,7 @@ def _lowered_asm(bert_config):
 
 
 @pytest.mark.parametrize("builder", ["ensemble_builder", "olmoe_builder",
-                                     "zaya1_builder"])
+                                     "zaya1_builder", "laguna_builder"])
 def test_every_name_in_a_builders_vocabulary_is_one_the_program_writes(
         builder):
     """A builder (``benchmarks/configs/<builder>.py``) writes the device
@@ -169,6 +171,16 @@ def test_every_name_in_a_builders_vocabulary_is_one_the_program_writes(
             from realtime_fraud_detection_tpu.models.olmoe import TINY_OLMOE
 
             config, layer_parts = TINY_OLMOE, scopes.MOE_LAYER_SCOPES
+        elif builder == "laguna_builder":
+            from realtime_fraud_detection_tpu.models.laguna import (
+                TINY_LAGUNA,
+            )
+
+            # unlike layers: layer 0's dense MLP is ``ffn``, the sparse
+            # layers have the shared expert beside the routed ones
+            config, layer_parts = TINY_LAGUNA, scopes.LAGUNA_LAYER_SCOPES
+            assert set(layer_parts) == set(scopes.MOE_LAYER_SCOPES) | {
+                scopes.FFN, scopes.SHARED_EXPERT}
         else:
             from realtime_fraud_detection_tpu.models.zaya import TINY_ZAYA
 
@@ -189,7 +201,7 @@ def test_every_name_in_a_builders_vocabulary_is_one_the_program_writes(
     assert set(vocabulary[scopes.TEXT]["layer*"]) == set(layer_parts)
     asm = _lowered_asm(config)
     for path in _vocabulary_paths(vocabulary):
-        assert re.search(rf'"jit\([^"]*\)/{re.escape(path)}/', asm), path
+        assert re.search(rf'"jit\([^"]*\)/{path}/', asm), path
 
 
 @pytest.mark.parametrize("metadata_in_key", [False, True])
