@@ -63,7 +63,10 @@ from realtime_fraud_detection_tpu.obs import scopes
 from realtime_fraud_detection_tpu.ops.attention import (
     attention_reference,
     merge_heads,
+    rope_lane_tables,
     split_heads,
+    windowed_attention,
+    windowed_refusal,
 )
 from realtime_fraud_detection_tpu.ops.grouped_matmul import grouped_matmul
 
@@ -94,7 +97,9 @@ class OlmoeConfig:
                 f"(num_key_value_heads {self.num_key_value_heads} != "
                 f"num_attention_heads {self.num_attention_heads}); grouped "
                 "keys live in ops/attention.py (attention_reference, "
-                "windowed_attention) for models/zaya.py and models/laguna.py")
+                "windowed_attention, which this encoder's fused core calls "
+                "with one key head a query head) for models/zaya.py and "
+                "models/laguna.py")
         if self.norm_topk_prob:
             raise ValueError("OlmoeConfig: norm_topk_prob true is not the "
                              "published model and is not implemented; "
@@ -111,6 +116,15 @@ class OlmoeConfig:
     def num_sparse_layers(self) -> int:
         """Layers with a routed block (``scoring/pipeline.RoutedText``)."""
         return self.num_hidden_layers
+
+    def core_refusal(self, seq_len: int) -> Optional[str]:
+        """Why a program of ``seq_len`` positions keeps the XLA core where
+        the fused one is asked for, or None where it holds the kernel
+        (``ops.attention.windowed_refusal`` with the QK-norm riding it:
+        shapes alone)."""
+        return windowed_refusal(seq_len, self.head_dim,
+                                self.num_attention_heads,
+                                self.num_key_value_heads, None, qk_norm=True)
 
 
 TINY_OLMOE = OlmoeConfig(
@@ -327,29 +341,60 @@ def apply_experts(layer: Dict, x: jax.Array, experts: jax.Array,
 
 
 def olmoe_attention(layer: Dict, h: jax.Array, attention_mask: jax.Array,
-                    config: OlmoeConfig, cos, sin) -> jax.Array:
+                    config: OlmoeConfig, cos, sin, *,
+                    lengths: Optional[jax.Array] = None,
+                    use_pallas: bool = False,
+                    kernel_interpret: bool = False) -> jax.Array:
     """``h + o_proj(attn(...))`` on ``h`` ``f32[B, T, hidden]``: the first
-    half of a block."""
+    half of a block. ``use_pallas`` asks for the fused core
+    (``ops.attention.windowed_attention``, which norms q and k over all
+    heads, rotates them and splits the heads in VMEM: q and k go to it as
+    their projections wrote them); a shape it does not take
+    (``OlmoeConfig.core_refusal``) runs the XLA form. ``lengths``
+    ``i32[B]``: the real tokens of each row (None: the mask's row sum)."""
     eps, heads = config.rms_norm_eps, config.num_attention_heads
     with jax.named_scope(scopes.LN):
         x = rms_norm(h, layer["input_layernorm"], eps)
+    if use_pallas and config.core_refusal(h.shape[1]) is None:
+        operand = layer["q_proj"].dtype
+        if lengths is None:
+            lengths = jnp.sum(attention_mask.astype(jnp.int32), axis=-1)
+        with jax.named_scope(scopes.ATTN_PROJ):
+            q = _proj(x, layer["q_proj"])
+            k = _proj(x, layer["k_proj"])
+            v = _proj(x, layer["v_proj"]).astype(operand)
+        # q and k are normed over all heads, rotated and split inside the
+        # kernel: no pass stands between the projections and it
+        *tables, shift = rope_lane_tables(cos, sin, config.head_dim)
+        with jax.named_scope(scopes.ATTN_CORE):
+            ctx = windowed_attention(
+                q, k, v, lengths, num_heads=heads,
+                num_kv_heads=config.num_key_value_heads,
+                rope=tuple(tables), rope_shift=shift,
+                norm=(layer["q_norm"], layer["k_norm"]), norm_eps=eps,
+                out_dtype=operand, interpret=kernel_interpret)
+    else:
+        with jax.named_scope(scopes.ATTN_PROJ):
+            q = rms_norm(_proj(x, layer["q_proj"]), layer["q_norm"], eps)
+            k = rms_norm(_proj(x, layer["k_proj"]), layer["k_norm"], eps)
+            v = _proj(x, layer["v_proj"])
+            # the barrier changes no value. Without it the TPU compiler
+            # folds the norm's last multiply into a layout fusion of its own
+            # ahead of the head split, which carries no op_name: 0.8 ms a
+            # layer that a device trace can give to no scope (2.5% of the
+            # busy time; PERF.md, PR 26). With it the same pass is a fusion
+            # rooted at this scope's multiply. (The fused core norms in
+            # VMEM: the barrier guards this form alone.)
+            q, k = jax.lax.optimization_barrier((q, k))
+            qh = apply_rope(split_heads(q, heads), cos, sin)
+            kh = apply_rope(split_heads(k, heads), cos, sin)
+            vh = split_heads(v, heads)
+        with jax.named_scope(scopes.ATTN_CORE):
+            ctx = attention_reference(qh, kh, vh, attention_mask, causal=True)
+        with jax.named_scope(scopes.ATTN_PROJ):
+            ctx = merge_heads(ctx)
     with jax.named_scope(scopes.ATTN_PROJ):
-        q = rms_norm(_proj(x, layer["q_proj"]), layer["q_norm"], eps)
-        k = rms_norm(_proj(x, layer["k_proj"]), layer["k_norm"], eps)
-        v = _proj(x, layer["v_proj"])
-        # the barrier changes no value. Without it the TPU compiler folds the
-        # norm's last multiply into a layout fusion of its own ahead of the
-        # head split, which carries no op_name: 0.8 ms a layer that a device
-        # trace can give to no scope (2.5% of the busy time; PERF.md, PR 26).
-        # With it the same pass is a fusion rooted at this scope's multiply.
-        q, k = jax.lax.optimization_barrier((q, k))
-        qh = apply_rope(split_heads(q, heads), cos, sin)
-        kh = apply_rope(split_heads(k, heads), cos, sin)
-        vh = split_heads(v, heads)
-    with jax.named_scope(scopes.ATTN_CORE):
-        ctx = attention_reference(qh, kh, vh, attention_mask, causal=True)
-    with jax.named_scope(scopes.ATTN_PROJ):
-        attn_out = _proj(merge_heads(ctx), layer["o_proj"])
+        attn_out = _proj(ctx, layer["o_proj"])
     with jax.named_scope(scopes.LN):
         return h + attn_out
 
@@ -397,15 +442,19 @@ def routed_block(layer: Dict, x: jax.Array,
 def olmoe_layer(layer: Dict, h: jax.Array, attention_mask: jax.Array,
                 config: OlmoeConfig, cos, sin, *,
                 slots: Optional[Tuple[Optional[jax.Array], jax.Array]] = None,
+                lengths: Optional[jax.Array] = None,
                 use_pallas: bool = False, kernel_interpret: bool = False
                 ) -> Tuple[jax.Array, jax.Array]:
     """One pre-norm block on ``h`` ``f32[B, T, hidden]``; also the largest
     expert group of the layer (``i32[]``). ``slots`` is the launch's
-    ``token_slots`` (None: every real slot, uncompacted)."""
+    ``token_slots`` (None: every real slot, uncompacted), ``lengths`` its
+    rows' real tokens (None: the mask's row sum)."""
     b, t, width = h.shape
     if slots is None:
         slots = token_slots(attention_mask, None)
-    h = olmoe_attention(layer, h, attention_mask, config, cos, sin)
+    h = olmoe_attention(layer, h, attention_mask, config, cos, sin,
+                        lengths=lengths, use_pallas=use_pallas,
+                        kernel_interpret=kernel_interpret)
     with jax.named_scope(scopes.LN):
         x = rms_norm(h, layer["post_attention_layernorm"],
                      config.rms_norm_eps).reshape(b * t, width)
@@ -431,13 +480,15 @@ def olmoe_encode(params: Dict, input_ids: jax.Array,
     t = input_ids.shape[1]
     cos, sin = rope_tables(t, config.head_dim, config.rope_theta)
     slots = token_slots(attention_mask, capacity)
+    lengths = jnp.sum(attention_mask.astype(jnp.int32), axis=-1)
     with jax.named_scope(scopes.EMBED):
         h = params["embed_tokens"][input_ids].astype(jnp.float32)
     peaks = []
     for i, layer in enumerate(params["layers"]):
         with jax.named_scope(scopes.layer_scope(i)):
             h, peak = olmoe_layer(layer, h, attention_mask, config, cos, sin,
-                                  slots=slots, use_pallas=use_pallas,
+                                  slots=slots, lengths=lengths,
+                                  use_pallas=use_pallas,
                                   kernel_interpret=kernel_interpret)
         peaks.append(peak)
     return h, jnp.stack(peaks)

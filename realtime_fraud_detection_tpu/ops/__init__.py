@@ -7,9 +7,11 @@ a predicate admits holds the kernel, everything else the XLA form.
   in VMEM) against ``attention_reference``; ``flash_supported``.
   ``windowed_attention`` (the causal core at ``head_dim`` 128 with grouped
   keys, an optional sliding window and the rows' lengths: online softmax
-  over the key blocks a query block sees) against
-  ``attention_reference(causal=True, window=...)``; ``windowed_refusal``,
-  asked through ``LagunaConfig.core_refusal``.
+  over the key blocks a query block sees; handed the weights of a QK-norm
+  over all heads, its one-block form, every head of a row a program)
+  against ``attention_reference(causal=True, window=...)``;
+  ``windowed_refusal``, asked through ``LagunaConfig.core_refusal`` and
+  ``OlmoeConfig.core_refusal``.
 - ``cca_mix``: ``cca_mix_fused`` (ZAYA1's convolutional mixing between the
   latent projections and the core as one pass: latents and values in, q, k
   and the shifted v out) against ``models.zaya.cca_mix``;

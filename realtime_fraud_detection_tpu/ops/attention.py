@@ -27,6 +27,12 @@ is read once a group; it walks the key blocks a query block can see — from
 ``q_start - window + 1`` (0 without a window) to its own diagonal — with
 the online softmax, and a query block past its row's last real token is
 skipped (the row lengths are scalar-prefetched). Scores never leave VMEM.
+Where the encoder norms q and k over ALL heads before the split (OLMoE's
+QK-norm, ``models/olmoe.py``) no program that holds one head can form the
+statistic: handed the norm's weights, the same entry runs its one-block form
+(``_whole_row_kernel``), in which a program owns every head of a row of one
+block of positions, forms both statistics, scales, rotates and rounds in
+VMEM, and has no key blocks to walk.
 
 Precision, as the configuration states it and as XLA's default-precision
 einsum runs the reference on a TPU: bf16 MXU operands with f32 accumulation;
@@ -224,10 +230,13 @@ WINDOW_BLOCK = LANES     # queries and keys a block: one lane tile of scores
 
 
 def windowed_refusal(seq_len: int, head_dim: int, num_heads: int,
-                     num_kv_heads: int, window: int | None) -> str | None:
+                     num_kv_heads: int, window: int | None,
+                     qk_norm: bool = False) -> str | None:
     """Why ``windowed_attention`` does not take a shape, by name, or None
-    where it does. The ONE predicate: the traced guard in
-    ``models/laguna.py`` and the scorer's selector both ask it."""
+    where it does. The ONE predicate: the traced guards in
+    ``models/laguna.py`` and ``models/olmoe.py`` and the scorer's selector
+    all ask it. ``qk_norm``: the call hands it a norm over the whole
+    projection (the one-block form)."""
     if head_dim != LANES:
         return (f"windowed_attention takes heads of {LANES} (one lane tile "
                 f"a head): head_dim {head_dim}")
@@ -241,6 +250,10 @@ def windowed_refusal(seq_len: int, head_dim: int, num_heads: int,
                                or window % WINDOW_BLOCK):
         return (f"windowed_attention takes a window of whole blocks of "
                 f"{WINDOW_BLOCK} positions: window {window}")
+    if qk_norm and (seq_len != WINDOW_BLOCK or window is not None):
+        return (f"windowed_attention norms q and k over all heads in a step "
+                f"that holds a row's one block of {WINDOW_BLOCK} positions, "
+                f"with no window: seq_len {seq_len}, window {window}")
     return None
 
 
@@ -378,15 +391,127 @@ def _windowed_kernel(lens_ref, *refs, group: int, window_blocks: int | None,
             o_ref[0, :, j * d:(j + 1) * d] = mine.astype(o_ref.dtype)
 
 
+def _whole_row_kernel(lens_ref, q_ref, k_ref, v_ref, qw_ref, kw_ref, c_ref,
+                      up_ref, down_ref, o_ref, *, group: int, eps: float,
+                      scale: float, rope_shift: int):
+    """The one-block form: a program owns EVERY head of one row of one block
+    of positions, so it can norm q and k over the whole projection (``x *
+    rsqrt(mean(x^2) + eps) * weight`` in float32, the statistic over all
+    heads), rotate them and round them once, and then run each head's
+    causal softmax on one block of scores: no key blocks to walk, no
+    running maximum. A row of no real token comes out zero."""
+    block, d = WINDOW_BLOCK, LANES
+    length = lens_ref[pl.program_id(0)]
+    c, up, down = c_ref[...], up_ref[...], down_ref[...]
+    visible = (jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
+               <= jax.lax.broadcasted_iota(jnp.int32, (block, block), 0))
+
+    def lanes_of(j):
+        return pl.ds(pl.multiple_of(j * d, d), d)
+
+    # the loops over heads are unrolled where they are lowered, not in
+    # Python: the body is traced once, not once a head (1.4 s a program
+    # shape on the chip's host at sixteen heads), and Mosaic still sees
+    # straight code (a rolled loop read 2.20 ms a layer against 1.49)
+    def inverse_rms(x_ref):
+        width = x_ref.shape[2]
+
+        def add(j, squares):
+            x = x_ref[0, :, lanes_of(j)].astype(jnp.float32)
+            return squares + x * x
+
+        squares = jax.lax.fori_loop(
+            0, width // d, add, jnp.zeros((block, d), jnp.float32),
+            unroll=True)
+        return jax.lax.rsqrt(
+            squares.sum(axis=1, keepdims=True) * (1.0 / width) + eps)
+
+    def head(x_ref, w_ref, inv, j):
+        lanes = lanes_of(j)
+        x = x_ref[0, :, lanes].astype(jnp.float32) * inv * w_ref[:, lanes]
+        x = (x * c + pltpu.roll(x, rope_shift, 1) * up
+             + pltpu.roll(x, d - rope_shift, 1) * down)
+        return x.astype(v_ref.dtype)
+
+    @pl.when(length == 0)
+    def _no_text():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(length > 0)
+    def _real():
+        inv_q, inv_k = inverse_rms(q_ref), inverse_rms(k_ref)
+
+        def key_head(g, carry):
+            k = head(k_ref, kw_ref, inv_k, g)
+            v = v_ref[0, :, lanes_of(g)]
+            for i in range(group):
+                j = g * group + i
+                s = jax.lax.dot_general(
+                    head(q_ref, qw_ref, inv_q, j), k,
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                s = jnp.where(visible, s, NEG_INF)
+                p = jnp.exp(s - s.max(axis=1, keepdims=True))
+                out = jnp.dot(p.astype(v.dtype), v,
+                              preferred_element_type=jnp.float32)
+                o_ref[0, :, lanes_of(j)] = (
+                    out / p.sum(axis=1, keepdims=True)).astype(o_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, k_ref.shape[2] // d, key_head, 0, unroll=True)
+
+
+def _whole_row_call(lengths, q, k, v, norm, rope, *, group: int, eps: float,
+                    rope_shift: int, out_dtype, interpret: bool):
+    """``_whole_row_kernel`` over ``(rows,)``: a row's 3 MB of blocks at
+    sixteen heads already hide a step's overhead (1.49 ms a layer at bucket
+    256 on the v5e against 1.53 at two or four rows a step: PERF.md, PR 34)."""
+    b, block, width = q.shape
+    kv_width = k.shape[2]
+
+    def whole_row(w):
+        return pl.BlockSpec((1, block, w), lambda i, lens: (i, 0, 0))
+
+    def constant(*shape):
+        return pl.BlockSpec(shape, lambda i, lens: (0, 0))
+
+    # q, k, v and the context of a step, double-buffered, and room for the
+    # tables, one head's scores and Mosaic's own temporaries
+    vmem = 2 * block * (
+        width * (q.dtype.itemsize + np.dtype(out_dtype).itemsize)
+        + kv_width * (k.dtype.itemsize + v.dtype.itemsize)) + (16 << 20)
+    return pl.pallas_call(
+        functools.partial(_whole_row_kernel, group=group, eps=eps,
+                          scale=LANES ** -0.5, rope_shift=rope_shift),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b,),
+            in_specs=[whole_row(width), whole_row(kv_width),
+                      whole_row(kv_width), constant(1, width),
+                      constant(1, kv_width)] + [constant(block, LANES)] * 3,
+            out_specs=whole_row(width),
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=vmem),
+        interpret=interpret,
+        name="windowed_attention",
+    )(lengths.astype(jnp.int32), q, k, v,
+      *(jnp.asarray(w, jnp.float32).reshape(1, -1) for w in norm),
+      *(jnp.asarray(x, jnp.float32) for x in rope))
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "num_heads", "num_kv_heads", "window", "rope_shift", "out_dtype",
-    "interpret"))
+    "num_heads", "num_kv_heads", "window", "rope_shift", "norm_eps",
+    "out_dtype", "interpret"))
 def windowed_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                        lengths: jax.Array, *, num_heads: int,
                        num_kv_heads: int, window: int | None = None,
                        rope: tuple | None = None,
                        rope_shift: int | None = None,
-                       gate: jax.Array | None = None, out_dtype=None,
+                       gate: jax.Array | None = None,
+                       norm: tuple | None = None,
+                       norm_eps: float | None = None, out_dtype=None,
                        interpret: bool = False) -> jax.Array:
     """Fused causal core with grouped keys. ``q`` ``[B, T, H*128]``, ``k``
     and ``v`` ``[B, T, Hkv*128]`` (heads side by side, as the projections
@@ -399,7 +524,7 @@ def windowed_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     (``attention_reference`` masks padded keys: the two agree at every real
     position).
 
-    Two things may ride the kernel so that no pass stands between the
+    Three things may ride the kernel so that no pass stands between the
     projections and it. ``rope`` = ``rope_lane_tables``' three ``f32[T,
     128]`` tables with ``rope_shift``: q and k arrive UNROTATED (float32, as
     their projections wrote them) and are rotated in VMEM in float32 and
@@ -407,18 +532,34 @@ def windowed_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     keys once, ahead of its first query block, into a scratch that its
     later blocks read. ``gate`` ``f32[B, T, H]``: head ``g``'s context is
     multiplied by ``gate[:, :, g]`` in float32 before the one rounding to
-    ``out_dtype``.
+    ``out_dtype``. ``norm`` = ``(f32[H*128], f32[Hkv*128])`` with
+    ``norm_eps``: the weights of an RMS norm of q and of k over their WHOLE
+    projection, all heads, ahead of the rotation (OLMoE's QK-norm). That
+    statistic spans the heads, so the call runs the one-block form
+    (``_whole_row_kernel``: every head of a row a program, ``seq_len`` one
+    block, no window; it rotates too, and has no gate).
 
     ``interpret=True`` runs the kernel through the Pallas interpreter."""
     b, t, width = q.shape
     refusal = windowed_refusal(t, width // num_heads, num_heads,
-                               num_kv_heads, window)
+                               num_kv_heads, window, qk_norm=norm is not None)
     if refusal or width % num_heads:
         raise ValueError(refusal or "windowed_attention: ragged heads")
     if (rope is None) != (rope_shift is None):
         raise ValueError("windowed_attention: rope tables and rope_shift "
                          "come together")
+    if (norm is None) != (norm_eps is None):
+        raise ValueError("windowed_attention: norm weights and norm_eps "
+                         "come together")
     group, block = num_heads // num_kv_heads, WINDOW_BLOCK
+    if norm is not None:
+        if rope is None or gate is not None:
+            raise ValueError("windowed_attention: the one-block form norms "
+                             "AND rotates, and has no gate")
+        return _whole_row_call(
+            lengths, q, k, v, norm, rope, group=group, eps=norm_eps,
+            rope_shift=rope_shift, out_dtype=out_dtype or q.dtype,
+            interpret=interpret)
     kernel = functools.partial(
         _windowed_kernel, group=group,
         window_blocks=None if window is None else window // block,

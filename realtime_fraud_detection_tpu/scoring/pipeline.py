@@ -108,18 +108,18 @@ class RoutedText:
     grouped matmul's shape predicate read — and ``num_sparse_layers`` (the
     layers with a routed block). ``attention_refusal(config, seq_len)``
     names why a launch of ``seq_len`` positions holds no Pallas kernel at
-    its attention site even where asked (None where it holds one: ZAYA1's
-    fused mixing, Laguna's fused causal core); an encoder without the
-    field has none (OLMoE keeps the XLA core). A routed encoder runs on one
-    device and has no int8 or dequant plane."""
+    its attention site even where asked (None where it holds one: OLMoE's
+    and Laguna's fused causal core, ZAYA1's fused mixing). A routed encoder
+    runs on one device and has no int8 or dequant plane."""
 
     init: Callable[..., Dict[str, Any]]
     predict: Callable[..., Any]
-    attention_refusal: Optional[Callable[[Any, int], Optional[str]]] = None
+    attention_refusal: Callable[[Any, int], Optional[str]]
 
 
 _ROUTED_TEXT = {
-    OlmoeConfig: RoutedText(init_olmoe_params, olmoe_predict),
+    OlmoeConfig: RoutedText(init_olmoe_params, olmoe_predict,
+                            OlmoeConfig.core_refusal),
     ZayaConfig: RoutedText(init_zaya_params, zaya_predict,
                            ZayaConfig.mix_refusal),
     LagunaConfig: RoutedText(init_laguna_params, laguna_predict,

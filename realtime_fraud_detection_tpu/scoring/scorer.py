@@ -916,9 +916,10 @@ class FraudScorer:
         reference, and the engagement counters say so), the grouped expert
         matmul of the MoE encoder (``ops.grouped_matmul``, same pattern)
         and, with it, ZAYA1's fused mixing (``ops/cca_mix.py``, guarded by
-        ``ZayaConfig.mix_refusal``) or Laguna's fused causal core
-        (``ops.attention.windowed_attention``, guarded by
-        ``LagunaConfig.core_refusal``). With the kernel plane on,
+        ``ZayaConfig.mix_refusal``) or OLMoE's and Laguna's fused causal
+        core (``ops.attention.windowed_attention``, guarded by
+        ``OlmoeConfig.core_refusal`` / ``LagunaConfig.core_refusal``). With
+        the kernel plane on,
         ``KernelSettings.attention`` decides — how a drill or an A/B forces
         either side. With it off, nothing a user sets does: the kernel runs
         where the devices are TPUs, the shape is one it takes
@@ -941,29 +942,24 @@ class FraudScorer:
                                  ) -> Optional[str]:
         """Why a program launched at ``text_len`` holds no Pallas kernel at
         its attention site even where asked, or None where it holds one:
-        the fused core for the dense encoder (``flash_supported``), the
-        fused mixing or the fused causal core for a routed encoder whose
-        row of ``pipeline.RoutedText`` names one
-        (``ZayaConfig.mix_refusal``: the predicate of ``ops/cca_mix.py``;
-        ``LagunaConfig.core_refusal``: that of
-        ``ops.attention.windowed_attention``); a routed encoder without
-        (OLMoE) keeps the reference core. The same predicates the traced
-        guards consult."""
+        the fused core for the dense encoder (``flash_supported``), for a
+        routed encoder what its row of ``pipeline.RoutedText`` names
+        (``ZayaConfig.mix_refusal``: the predicate of ``ops/cca_mix.py``'s
+        fused mixing; ``OlmoeConfig.core_refusal`` and
+        ``LagunaConfig.core_refusal``: that of the fused causal core,
+        ``ops.attention.windowed_attention``). The same predicates the
+        traced guards consult."""
         from realtime_fraud_detection_tpu.ops import flash_supported
 
         t = text_len or self.sc.text_len
         c = self.bert_config
-        if not self._moe_text:
-            if flash_supported(t, c.head_dim, c.num_heads):
-                return None
-            return (f"flash_attention takes seq_len a multiple of 128 and "
-                    f"pairs of 64-wide heads: seq_len {t}, head_dim "
-                    f"{c.head_dim}")
-        refusal = routed_text(c).attention_refusal
-        if refusal is None:
-            return (f"{type(c).__name__}: no fused kernel at the attention "
-                    "site of a causal core at head_dim 128")
-        return refusal(c, t)
+        if self._moe_text:
+            return routed_text(c).attention_refusal(c, t)
+        if flash_supported(t, c.head_dim, c.num_heads):
+            return None
+        return (f"flash_attention takes seq_len a multiple of 128 and "
+                f"pairs of 64-wide heads: seq_len {t}, head_dim "
+                f"{c.head_dim}")
 
     def _attention_shape_ok(self, text_len: Optional[int] = None) -> bool:
         return self._attention_shape_refusal(text_len) is None

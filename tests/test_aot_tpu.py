@@ -317,14 +317,20 @@ def test_routed_program_compiles_with_every_large_pass_under_a_scope(
     (two of the published layers, every width as published, bucket 256 x 128
     tokens), at both capacities of that bucket's routed blocks
     (``scoring/text_split.capacities``):
-    three Mosaic calls a layer (four with ZAYA1's fused mixing,
-    ``ops/cca_mix.py``), a second small output, temporaries that
+    four Mosaic calls a layer (the three grouped expert matmuls and, at the
+    attention site, OLMoE's fused causal core ``windowed_attention`` or
+    ZAYA1's fused mixing ``ops/cca_mix.py``), a second small output,
+    temporaries that
     leave room for the cell's layers of weights in 16 GB — and no
     instruction that
     writes 64 MB or more without a named scope in its ``op_name`` (what a
     device trace would count as ``unscoped``), and no conditional: a
     ``cond`` ahead of ``experts/`` in an ``op_name`` would hide the block
-    from the trace's attribution."""
+    from the trace's attribution. With OLMoE, nothing under ``attn_proj``
+    but the four projections (and the one rounding of the normed input that
+    three of them read) writes an array of a launch's ``[256, 128, 2048]``
+    elements: QK-norm, RoPE, the head split and the merge left with the XLA
+    core (PERF.md, PR 34)."""
     import re
 
     from realtime_fraud_detection_tpu.core.packing import pack_tree
@@ -353,11 +359,11 @@ def test_routed_program_compiles_with_every_large_pass_under_a_scope(
         blob_bf16=_shapes_of(blobs["bf16"], one_chip), bert_config=config,
         use_pallas=True, text_capacity=capacity).compile()
     text = compiled.as_text()
-    # the three grouped expert matmuls, and ZAYA1's fused mixing
-    assert text.count(CUSTOM_CALL) == (
-        (4 if encoder == "zaya1" else 3) * config.num_hidden_layers)
-    assert len(re.findall(r"%cca_mix\S* = .*custom-call\(", text)) == (
-        config.num_hidden_layers if encoder == "zaya1" else 0)
+    # the three grouped expert matmuls, and the attention site's kernel
+    assert text.count(CUSTOM_CALL) == 4 * config.num_hidden_layers
+    site = "cca_mix" if encoder == "zaya1" else "windowed_attention"
+    assert len(re.findall(rf"%{site}\S* = .*custom-call\(", text)) == (
+        config.num_hidden_layers)
     assert " conditional(" not in text and "cond/branch_" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 5 << 30
     entry = text[text.index("ENTRY "):]
@@ -381,6 +387,21 @@ def test_routed_program_compiles_with_every_large_pass_under_a_scope(
         if nbytes >= 64e6 and not (op and op.group(1).startswith("text/")):
             unnamed.append((m.group(1), nbytes))
     assert not unnamed, unnamed
+    if encoder == "olmoe":
+        slots = BUCKET * 128 * config.hidden_size
+        passes = []
+        for line in entry.splitlines():
+            m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) "
+                         r"(?:fusion|copy|custom-call|transpose|convolution)"
+                         r"\(", line)
+            op = re.search(r'op_name="[^"]*/attn_proj/([^"/]*)"', line)
+            if m and op and any(
+                    int(np.prod([int(d) for d in dims.split(",")])) >= slots
+                    for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1))):
+                passes.append(op.group(1))
+        assert sorted(passes) == sorted(
+            (["dot_general"] * 4 + ["convert_element_type"])
+            * config.num_hidden_layers), passes
 
 
 # ----------------------------------------------------------------- Laguna
@@ -417,6 +438,41 @@ def test_windowed_attention_compiles_at_published_widths(one_chip, heads,
         _sds((b, t, kv * 128), jnp.bfloat16, one_chip),
         _sds((b,), jnp.int32, one_chip),
         _sds((b, t, heads), jnp.float32, one_chip)).compile()
+    assert compiled.as_text().count(CUSTOM_CALL) == 1
+
+
+@pytest.mark.parametrize("bucket", [256, 8, 1])
+def test_windowed_attention_compiles_with_olmoes_operands(one_chip, bucket):
+    """OLMoE's fused core as its cells launch it (bucket 256; 8 is the parity
+    sample's, 1 the smallest): rows of one block of 128 positions, 16 heads
+    of 128, q and k float32 as their projections wrote them, normed over all
+    16 heads, rotated and split in VMEM (the one-block form), v and the
+    context bfloat16."""
+    from realtime_fraud_detection_tpu.models.olmoe import (
+        OlmoeConfig,
+        rope_tables,
+    )
+    from realtime_fraud_detection_tpu.ops import (
+        rope_lane_tables,
+        windowed_attention,
+    )
+
+    cfg, t = OlmoeConfig(), 128
+    assert cfg.core_refusal(t) is None
+    heads, width = cfg.num_attention_heads, cfg.hidden_size
+    *tables, shift = rope_lane_tables(
+        *rope_tables(t, cfg.head_dim, cfg.rope_theta), cfg.head_dim)
+    fn = jax.jit(lambda q, k, v, lens, qw, kw: windowed_attention(
+        q, k, v, lens, num_heads=heads, num_kv_heads=heads,
+        rope=tuple(tables), rope_shift=shift, norm=(qw, kw),
+        norm_eps=cfg.rms_norm_eps, out_dtype=jnp.bfloat16))
+    compiled = fn.lower(
+        _sds((bucket, t, width), jnp.float32, one_chip),
+        _sds((bucket, t, width), jnp.float32, one_chip),
+        _sds((bucket, t, width), jnp.bfloat16, one_chip),
+        _sds((bucket,), jnp.int32, one_chip),
+        _sds((width,), jnp.float32, one_chip),
+        _sds((width,), jnp.float32, one_chip)).compile()
     assert compiled.as_text().count(CUSTOM_CALL) == 1
 
 

@@ -295,6 +295,13 @@ LANE_HEADS = ZayaConfig(
     num_experts=4, moe_intermediate_size=128, router_hidden_size=32)
 
 
+# OLMoE at heads of 128: what ``OlmoeConfig.core_refusal`` takes
+LANE_OLMOE = olmoe.OlmoeConfig(
+    vocab_size=30522, hidden_size=256, intermediate_size=128,
+    num_hidden_layers=1, num_attention_heads=2, num_key_value_heads=2,
+    num_experts=4, num_experts_per_tok=2)
+
+
 def _attention(cfg, t, **asked):
     params = init_zaya_params(jax.random.PRNGKey(2), cfg)
     h = jax.random.normal(jax.random.PRNGKey(3), (2, t, cfg.hidden_size))
@@ -385,13 +392,15 @@ def _bert():
     (lambda: LANE_HEADS, 128, {}, "a cpu mesh"),
     (lambda: LANE_HEADS, 64, {}, "seq_len 64 is not a multiple"),
     (lambda: TINY_ZAYA, 128, {}, "head_dim 16 is not one lane tile"),
-    (lambda: olmoe.TINY_OLMOE, 128, {}, "OlmoeConfig: no fused kernel"),
+    (lambda: olmoe.TINY_OLMOE, 128, {}, "one lane tile a head): head_dim 64"),
     (_bert, 64, {}, "flash_attention takes seq_len a multiple of 128"),
     (_bert, 128, {}, "a cpu mesh"),
     (lambda: LANE_HEADS, 128, {"kernels": _flash_plane}, None),
     (lambda: LANE_HEADS, 64, {"kernels": _flash_plane}, "seq_len 64"),
+    (lambda: LANE_OLMOE, 128, {}, "a cpu mesh"),
+    (lambda: LANE_OLMOE, 128, {"kernels": _flash_plane}, None),
 ], ids=["cpu", "short", "tiny_heads", "olmoe", "bert_short", "bert_cpu",
-        "asked", "asked_short"])
+        "asked", "asked_short", "olmoe_lane_cpu", "olmoe_lane_asked"])
 def test_the_snapshot_names_why_the_xla_form_runs(cfg, text_len, planes,
                                                   named):
     """Where ``flash_attention``'s fallbacks are counted
@@ -424,6 +433,30 @@ def test_a_scorer_asked_holds_the_kernel_and_counts_it():
     assert snap["dispatch"]["attention"] == 1
     assert snap["fallback"]["attention"] == 0 and snap["interpret"]
     assert plain.kernel_snapshot()["fallback"]["attention"] == 1
+    np.testing.assert_allclose(
+        [r["model_predictions"]["bert_text"] for r in got],
+        [r["model_predictions"]["bert_text"] for r in want], atol=2e-3)
+
+
+def test_an_olmoe_launch_asked_counts_under_dispatch():
+    """OLMoE's attention site is ``windowed_attention`` where the plane asks
+    (interpreted on a CPU mesh): its launch counts as dispatched, not as a
+    fallback, nothing is refused, and the answers are the XLA program's."""
+    from realtime_fraud_detection_tpu.sim.simulator import (
+        TransactionGenerator,
+    )
+
+    recs = TransactionGenerator(num_users=8, num_merchants=4).generate_batch(5)
+    plain = _scorer(LANE_OLMOE, 128)
+    asked = _scorer(LANE_OLMOE, 128, kernels=_flash_plane())
+    want = plain.finalize(plain.dispatch(recs))
+    got = asked.finalize(asked.dispatch(recs))
+    snap = asked.kernel_snapshot()
+    assert snap["dispatch"]["attention"] == 1
+    assert snap["fallback"]["attention"] == 0
+    assert snap["refused"]["attention"] is None and snap["interpret"]
+    assert plain.kernel_snapshot()["fallback"]["attention"] == 1
+    assert plain.kernel_snapshot()["dispatch"]["attention"] == 0
     np.testing.assert_allclose(
         [r["model_predictions"]["bert_text"] for r in got],
         [r["model_predictions"]["bert_text"] for r in want], atol=2e-3)

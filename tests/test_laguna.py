@@ -398,6 +398,83 @@ def test_fused_core_rotates_q_and_k_and_gates_the_context_in_the_kernel(
                            num_kv_heads=kv, rope=tuple(tables))
 
 
+@pytest.mark.parametrize("heads,kv", [(4, 4), (4, 2), (8, 1)],
+                         ids=["G1", "G2", "G8"])
+def test_fused_core_norms_q_and_k_over_all_heads_in_the_kernel(heads, kv):
+    """The one-block form (OLMoE's QK-norm riding the kernel): q and k
+    float32 as projected, RMS-normed over their WHOLE width with a weight,
+    rotated and rounded in VMEM, against ``attention_reference`` on inputs
+    normed by ``rms_norm`` and rotated in XLA. Rows that end inside the
+    block, on its edge, at one token, and an empty one."""
+    t, eps = 128, 1e-5
+    lengths = jnp.array([128, 77, 1, 0, 128], jnp.int32)
+    b = lengths.shape[0]
+    q, _, v, _ = _core_inputs(b, t, heads, kv, seed=5)
+    k = 3.0 * jax.random.normal(jax.random.PRNGKey(11), (b, t, kv * 128))
+    qw = 1 + 0.2 * jax.random.normal(jax.random.PRNGKey(12), (heads * 128,))
+    kw = 1 + 0.2 * jax.random.normal(jax.random.PRNGKey(13), (kv * 128,))
+    cos, sin = olmoe.rope_tables(t, 128, 10000.0)
+    *tables, shift = rope_lane_tables(cos, sin, 128)
+    got = windowed_attention(
+        q, k, v, lengths, num_heads=heads, num_kv_heads=kv,
+        rope=tuple(tables), rope_shift=shift, norm=(qw, kw), norm_eps=eps,
+        out_dtype=F32, interpret=True)
+
+    def prepared(x, w, n):
+        x = olmoe.apply_rope(split_heads(olmoe.rms_norm(x, w, eps), n),
+                             cos, sin)
+        return merge_heads(x).astype(jnp.bfloat16)
+
+    mask = jnp.arange(t)[None, :] < lengths[:, None]
+    want = _core_oracle(prepared(q, qw, heads), prepared(k, kw, kv), v, mask,
+                        heads, kv, None)
+    err = np.abs(np.asarray(got) - np.asarray(want))
+    assert err[np.asarray(mask)].max() < 0.02
+    assert (np.asarray(got)[3] == 0).all()
+    assert np.isfinite(np.asarray(got)).all()
+
+
+@pytest.mark.parametrize("wrong,named", [
+    (dict(norm_eps=None), "come together"),
+    (dict(rope=None, rope_shift=None), "norms AND rotates"),
+    (dict(gate=jnp.ones((2, 128, 2))), "no gate"),
+    (dict(window=128), "with no window"),
+])
+def test_the_one_block_form_refuses_what_it_does_not_do(wrong, named):
+    q = jnp.ones((2, 128, 256))
+    *tables, shift = rope_lane_tables(*olmoe.rope_tables(128, 128, 1e4), 128)
+    call = dict(num_heads=2, num_kv_heads=2, rope=tuple(tables),
+                rope_shift=shift, norm=(jnp.ones(256), jnp.ones(256)),
+                norm_eps=1e-5, interpret=True)
+    with pytest.raises(ValueError, match=named):
+        windowed_attention(q, q, q, jnp.array([128, 3]), **{**call, **wrong})
+    with pytest.raises(ValueError, match="seq_len 256"):
+        windowed_attention(jnp.ones((2, 256, 256)), jnp.ones((2, 256, 256)),
+                           jnp.ones((2, 256, 256)), jnp.array([128, 3]),
+                           **call)
+
+
+def test_a_call_without_a_norm_traces_the_blocked_kernel_it_always_did():
+    """Laguna's call (no ``norm``) lowers to the same jaxpr whether or not
+    the new keywords are spelled, over the blocked grid ``(rows, key heads,
+    query blocks)``; handed a norm, the same entry is the one-block form
+    over ``(rows,)``."""
+    q = jnp.ones((2, 128, 256))
+    v = q.astype(jnp.bfloat16)
+    lengths = jnp.array([128, 3])
+    *tables, shift = rope_lane_tables(*olmoe.rope_tables(128, 128, 1e4), 128)
+
+    def run(**kw):
+        return str(jax.make_jaxpr(lambda *a: windowed_attention(
+            *a, num_heads=2, num_kv_heads=2, rope=tuple(tables),
+            rope_shift=shift, interpret=True, **kw))(q, q, v, lengths))
+
+    assert run() == run(norm=None, norm_eps=None)
+    assert "grid=(2, 2, 1)" in run()
+    normed = run(norm=(jnp.ones(256), jnp.ones(256)), norm_eps=1e-5)
+    assert "grid=(2,)" in normed and "grid=(2, 2, 1)" not in normed
+
+
 def test_window_of_the_reference_is_the_hugging_face_convention():
     """Query i sees keys i - window < j <= i: ``window`` with its own."""
     t, window = 12, 4
@@ -417,11 +494,14 @@ def test_window_of_the_reference_is_the_hugging_face_convention():
     ((2048, 128, 70, 8, 512), "do not divide"),
     ((100, 128, 72, 8, None), "seq_len 100"),
     ((2048, 128, 72, 8, 500), "window 500"),
+    ((256, 128, 16, 16, None, True), "seq_len 256"),
+    ((128, 128, 16, 16, 128, True), "with no window"),
 ])
 def test_the_core_refuses_a_shape_by_name(shape, named):
     assert named in windowed_refusal(*shape)
     assert windowed_refusal(2048, 128, 72, 8, 512) is None
     assert windowed_refusal(2048, 128, 48, 8, None) is None
+    assert windowed_refusal(128, 128, 16, 16, None, True) is None
 
 
 KERNEL_CFG = LagunaConfig(
@@ -574,7 +654,8 @@ def test_one_description_of_a_routed_encoder_serves_all_three():
     assert CFG.num_sparse_layers == 4 and CFG.intermediate_size == 64
     assert olmoe.TINY_OLMOE.num_sparse_layers == 2
     # the attention site's refusal comes from the table, for every encoder
-    assert pipeline.routed_text(olmoe.TINY_OLMOE).attention_refusal is None
+    assert pipeline.routed_text(olmoe.TINY_OLMOE).attention_refusal \
+        is olmoe.OlmoeConfig.core_refusal
     assert pipeline.routed_text(TINY_ZAYA).attention_refusal \
         is ZayaConfig.mix_refusal
     assert routed.attention_refusal is LagunaConfig.core_refusal

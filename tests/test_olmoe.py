@@ -135,15 +135,16 @@ def text():
     return ids, mask
 
 
-def _every_slot_routed(params, ids, mask, cfg):
+def _every_slot_routed(params, ids, mask, cfg, **site):
     """The encoder as it was before padding left the routed block: every
     (row, position) slot through the router and its experts, from the public
-    pieces. ``(hidden before the final norm, each layer's top-k)``."""
+    pieces. ``(hidden before the final norm, each layer's top-k)``.
+    ``site``: ``olmoe_attention``'s keywords (the fused core)."""
     cos, sin = olmoe.rope_tables(ids.shape[1], cfg.head_dim, cfg.rope_theta)
     h = params["embed_tokens"][ids].astype(F32)
     chosen = []
     for layer in params["layers"]:
-        h = olmoe.olmoe_attention(layer, h, mask, cfg, cos, sin)
+        h = olmoe.olmoe_attention(layer, h, mask, cfg, cos, sin, **site)
         x = olmoe.rms_norm(h, layer["post_attention_layernorm"],
                            cfg.rms_norm_eps).reshape(-1, h.shape[-1])
         experts, weights = route(x, layer["router"], cfg.num_experts_per_tok)
@@ -487,28 +488,165 @@ def test_empty_filler_rows_beside_real_ones_change_nothing(params32, ragged):
         assert np.isfinite(np.asarray(got)).all()
 
 
+# ----------------------------- the fused core at the attention site
+# Heads of 128 (one lane tile) and one block of 128 positions: the shape
+# ops.attention.windowed_attention takes with the QK-norm riding it. TINY's
+# head_dim 64 it declines by name. Rows: full, ragged, one token, and a
+# bucket's filler row that holds none.
+LANE_CFG = OlmoeConfig(
+    vocab_size=512, hidden_size=256, intermediate_size=128,
+    num_hidden_layers=2, num_attention_heads=2, num_key_value_heads=2,
+    num_experts=8, num_experts_per_tok=2)
+LANE_LENGTHS = (128, 37, 1, 0)
+KERNELS = dict(use_pallas=True, kernel_interpret=True)
+
+
+@pytest.fixture(scope="module")
+def lane():
+    """``(params as stored, the same in float32, ids, mask)``."""
+    p = jax.jit(lambda k: init_olmoe_params(k, LANE_CFG))(
+        jax.random.PRNGKey(34))
+    rng = np.random.default_rng(34)
+    ids = rng.integers(0, LANE_CFG.vocab_size, (4, 128)).astype(np.int32)
+    mask = np.arange(128)[None, :] < np.asarray(LANE_LENGTHS)[:, None]
+    return p, jax.tree.map(lambda a: a.astype(F32), p), ids, mask
+
+
+def test_the_core_is_asked_of_the_one_predicate():
+    assert LANE_CFG.core_refusal(128) is None
+    assert OlmoeConfig().core_refusal(128) is None
+    assert "head_dim 64" in CFG.core_refusal(128)
+    assert "seq_len 64" in LANE_CFG.core_refusal(64)
+    # the norm over all heads rides the one-block form alone
+    assert "seq_len 256" in LANE_CFG.core_refusal(256)
+
+
+@pytest.mark.parametrize("stored,atol", [("float32", 1e-5),
+                                         ("bfloat16", 1e-2)])
+def test_the_encoder_is_the_same_through_the_fused_core(lane, stored, atol):
+    """Every REAL position of ragged rows: the encoder whose attention site
+    is ``windowed_attention`` (interpreted: QK-norm over all heads, RoPE and
+    the head split inside it) against the XLA form; in float32 the routing
+    and the groups are the same too. With bfloat16 weights the kernel rounds
+    q, k, v and the softmax weights to bfloat16, as the chip's default
+    precision does and the CPU's float32 einsum does not: the tolerance is
+    that rounding's."""
+    p16, p32, ids, mask = lane
+    p = p32 if stored == "float32" else p16
+    want, want_peaks = olmoe_encode(p, ids, mask, LANE_CFG)
+    got, peaks = olmoe_encode(p, ids, mask, LANE_CFG, **KERNELS)
+    np.testing.assert_allclose(np.asarray(got)[mask], np.asarray(want)[mask],
+                               atol=atol, rtol=0)
+    assert np.isfinite(np.asarray(got)).all()
+    if stored == "float32":
+        np.testing.assert_array_equal(peaks, want_peaks)
+        for a, b in zip(_every_slot_routed(p, ids, mask, LANE_CFG)[1],
+                        _every_slot_routed(p, ids, mask, LANE_CFG,
+                                           **KERNELS)[1]):
+            np.testing.assert_array_equal(
+                np.asarray(a).reshape(4, 128, -1)[mask],
+                np.asarray(b).reshape(4, 128, -1)[mask])
+        a, _ = olmoe_logits(p, ids, mask, LANE_CFG)
+        b, _ = olmoe_logits(p, ids, mask, LANE_CFG, **KERNELS)
+        held = mask.any(axis=1)
+        np.testing.assert_allclose(np.asarray(b)[held], np.asarray(a)[held],
+                                   atol=1e-5, rtol=0)
+
+
+def test_the_fused_core_under_a_narrow_capacity(lane):
+    """The compacted program (166 real tokens of 512 slots at a capacity of
+    192) with the fused core, against the XLA form with every slot routed."""
+    _, p32, ids, mask = lane
+    assert mask.sum() == 166
+    want, _ = _every_slot_routed(p32, ids, mask, LANE_CFG)
+    got, peaks = olmoe_encode(p32, ids, mask, LANE_CFG, capacity=192,
+                              **KERNELS)
+    np.testing.assert_allclose(np.asarray(got)[mask], np.asarray(want)[mask],
+                               atol=1e-5, rtol=0)
+    assert np.isfinite(np.asarray(got)).all()
+    assert (np.asarray(peaks) <= 166 * LANE_CFG.num_experts_per_tok).all()
+
+
+def test_the_qk_norm_statistic_is_taken_over_all_heads():
+    """Two heads a hundred times apart in scale: the RMS over the whole
+    projection leaves the small head small (its scores nearly flat), a norm
+    a head would bring it to unit scale. The kernel is the former."""
+    from realtime_fraud_detection_tpu.ops import (
+        merge_heads,
+        rope_lane_tables,
+        split_heads,
+        windowed_attention,
+    )
+
+    b, t, heads, eps = 2, 128, 2, LANE_CFG.rms_norm_eps
+    key = jax.random.PRNGKey(7)
+    scale = jnp.repeat(jnp.asarray([100.0, 1.0]), 128)
+    q = jax.random.normal(jax.random.fold_in(key, 1), (b, t, 256)) * scale
+    k = jax.random.normal(jax.random.fold_in(key, 2), (b, t, 256)) * scale
+    v = jax.random.normal(jax.random.fold_in(key, 3), (b, t, 256))
+    qw = 1 + 0.1 * jax.random.normal(jax.random.fold_in(key, 4), (256,))
+    kw = 1 + 0.1 * jax.random.normal(jax.random.fold_in(key, 5), (256,))
+    lengths = jnp.asarray([128, 50], jnp.int32)
+    mask = np.arange(t)[None, :] < np.asarray(lengths)[:, None]
+    cos, sin = olmoe.rope_tables(t, 128, LANE_CFG.rope_theta)
+    *tables, shift = rope_lane_tables(cos, sin, 128)
+    got = windowed_attention(
+        q, k, v, lengths, num_heads=heads, num_kv_heads=heads,
+        rope=tuple(tables), rope_shift=shift, norm=(qw, kw), norm_eps=eps,
+        interpret=True)
+
+    def oracle(norm):
+        qh = olmoe.apply_rope(split_heads(norm(q, qw), heads), cos, sin)
+        kh = olmoe.apply_rope(split_heads(norm(k, kw), heads), cos, sin)
+        return np.asarray(merge_heads(attention_reference(
+            qh, kh, split_heads(v, heads), mask, causal=True)))
+
+    def a_head_at_a_time(x, w):
+        return olmoe.rms_norm(x.reshape(b, t, heads, 128),
+                              w.reshape(heads, 128), eps).reshape(b, t, -1)
+
+    whole = oracle(lambda x, w: olmoe.rms_norm(x, w, eps))
+    np.testing.assert_allclose(np.asarray(got)[mask], whole[mask], atol=2e-5)
+    assert np.abs(oracle(a_head_at_a_time) - whole)[mask].max() > 0.1
+
+
 # ------------------------------------------------------ masks and pooling
-def test_causality_a_later_token_moves_no_earlier_position(params, text):
-    ids, mask = text
+@pytest.fixture(params=["xla", "fused_core"])
+def site(request, params, text, lane):
+    """``(config, params, ids, mask, keywords)`` of each form of the
+    attention site: TINY through XLA, the lane-tile configuration through
+    the interpreted kernels."""
+    if request.param == "xla":
+        return (CFG, params, *text, {})
+    p16, _, ids, mask = lane
+    return LANE_CFG, p16, ids, mask, KERNELS
+
+
+def test_causality_a_later_token_moves_no_earlier_position(site):
+    cfg, params, ids, mask, kw = site
     full = np.ones_like(mask)
-    base, _ = olmoe_encode(params, ids, full, CFG)
+    base, _ = olmoe_encode(params, ids, full, cfg, **kw)
     changed = ids.copy()
-    changed[:, 10] = (changed[:, 10] + 1) % CFG.vocab_size
-    moved, _ = olmoe_encode(params, changed, full, CFG)
+    changed[:, 10] = (changed[:, 10] + 1) % cfg.vocab_size
+    moved, _ = olmoe_encode(params, changed, full, cfg, **kw)
     np.testing.assert_allclose(moved[:, :10], base[:, :10], atol=1e-6, rtol=0)
     assert np.abs(np.asarray(moved[:, 10:] - base[:, 10:])).max() > 1e-3
 
 
-def test_padding_moves_nothing(params, text):
-    ids, mask = text
-    base = olmoe_predict(params, ids, mask, CFG)
-    noisy = np.where(mask, ids, (ids + 7) % CFG.vocab_size)
-    np.testing.assert_allclose(olmoe_predict(params, noisy, mask, CFG), base,
-                               atol=1e-6, rtol=0)
+def test_padding_moves_nothing(site):
+    cfg, params, ids, mask, kw = site
+    held = mask.any(axis=1)     # a row of no token answers for no one
+    base = olmoe_predict(params, ids, mask, cfg, **kw)
+    noisy = np.where(mask, ids, (ids + 7) % cfg.vocab_size)
+    np.testing.assert_allclose(
+        olmoe_predict(params, noisy, mask, cfg, **kw)[held], base[held],
+        atol=1e-6, rtol=0)
     # and the pooled position is the last REAL token: changing it moves the row
+    at = int(mask[1].sum()) - 1
     last = ids.copy()
-    last[1, LENGTHS[1] - 1] = (last[1, LENGTHS[1] - 1] + 1) % CFG.vocab_size
-    assert abs(float(olmoe_predict(params, last, mask, CFG)[1] - base[1])) > 1e-4
+    last[1, at] = (last[1, at] + 1) % cfg.vocab_size
+    assert abs(float(olmoe_predict(params, last, mask, cfg, **kw)[1]
+                     - base[1])) > 1e-4
 
 
 @pytest.mark.parametrize("causal", [False, True])
