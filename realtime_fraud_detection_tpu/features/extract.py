@@ -24,6 +24,12 @@ import jax
 import jax.numpy as jnp
 
 from realtime_fraud_detection_tpu.features.schema import TransactionBatch
+from realtime_fraud_detection_tpu.obs.profiling import compile_ledger
+
+# the host feature program is the first an entry point compiles: the
+# process's compile ledger listens from here (scoring/pipeline.py says the
+# same for the device programs)
+compile_ledger()
 
 # Canonical feature ordering — 8 categories, 64 names, matching the union of
 # FeatureExtractor.java:92-382 emissions (amount 12, temporal 8, geographic 8,

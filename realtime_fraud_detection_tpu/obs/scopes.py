@@ -96,6 +96,8 @@ ASSEMBLE_HISTORY = "assemble.history"
 GRAPH = "graph"
 ASSEMBLE_TOKENIZE = "assemble.tokenize"
 PACK = "pack"
+BUILD_PROGRAMS = "build_programs"    # a bucket's family compiled and run, on
+                                     # its first split or routed batch only
 DISPATCH = "dispatch"
 JOB_COMPLETE = "job.complete_batch"
 DEVICE_WAIT = "device_wait"
@@ -114,6 +116,7 @@ BATCH_SPANS: Tuple[Tuple[str, str], ...] = (
     (GRAPH, ASSEMBLE),
     (ASSEMBLE_TOKENIZE, ASSEMBLE),
     (PACK, JOB_DISPATCH),
+    (BUILD_PROGRAMS, PACK),
     (DISPATCH, JOB_DISPATCH),
     (JOB_COMPLETE, ""),
     (DEVICE_WAIT, JOB_COMPLETE),

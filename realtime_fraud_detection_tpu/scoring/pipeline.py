@@ -65,10 +65,16 @@ from realtime_fraud_detection_tpu.models.zaya import (
     zaya_predict,
 )
 from realtime_fraud_detection_tpu.obs import scopes
+from realtime_fraud_detection_tpu.obs.profiling import compile_ledger
 from realtime_fraud_detection_tpu.ops.epilogue import (
     epilogue_supported,
     fused_epilogue,
 )
+
+# every program defined here, and the init program a builder jits from
+# ``init_scoring_models``, is in the process's compile ledger from its
+# first compilation: the ledger listens from this import, whoever asks first
+compile_ledger()
 
 # Registry order (reference config.py:126-199). Index into the (B, M) matrix.
 MODEL_NAMES: tuple[str, ...] = (

@@ -1271,7 +1271,13 @@ class FraudScorer:
         the programs compiled per bucket (``(rows, width)``; with the MoE
         encoder ``(rows, width, capacity)``), and that encoder's
         ``expert_token_slots`` (the capacities launched, summed) and
-        ``compact_batches`` (launches at a narrow one)."""
+        ``compact_batches`` (launches at a narrow one) — and ``compile``:
+        the process's compile ledger (``obs/profiling.CompileLedger``) as
+        totals by phase (``trace``, ``lower``, ``compile``: records and
+        self seconds), programs compiled and persistent-cache hits and
+        misses, since the process started and ``since_reset`` (the last
+        ``spans.reset()``), and the newest ``records``, each with the span
+        it was ``caused_by``."""
         caches: Dict[str, Any] = {"entity_rows": self._join_cache.stats()}
         cache_stats = getattr(self.tokenizer, "cache_stats", None)
         if cache_stats is not None:
@@ -1283,7 +1289,8 @@ class FraudScorer:
             families={size: list(programs) for size, programs
                       in self._text_families.items()})
         return {"stages": self.spans.stats(), "caches": caches,
-                "text_split": text_split_stats}
+                "text_split": text_split_stats,
+                "compile": self.spans.compile_stats()}
 
     # ----------------------------------------------------------------- scoring
     def dispatch(self, records: Sequence[Mapping[str, Any]],
@@ -1463,9 +1470,11 @@ class FraudScorer:
         """The launches of an assembled batch (``text_split.plan``): rows
         with no real token past the narrow width are short. The first time
         a bucket leaves the unsplit launch, every program of its family is
-        compiled and run here, so that none first appears under load (each
-        costs 0.6-1.1 s with a warm compile cache, 4-10 s cold, nearly all
-        of it the interpreter's: a thread made it slower on the v5e)."""
+        compiled and run here, under span ``build_programs`` (``rows=``,
+        ``programs=``), so that none first appears under load (each costs
+        0.6-1.1 s with a warm compile cache, 4-10 s cold, nearly all of it
+        the interpreter's: a thread made it slower on the v5e; the compile
+        ledger has each phase, ``host_stats()["compile"]``)."""
         narrow = self._narrow_text_len(full)
         if narrow is None:
             return [_Launch(None, n, size, full)]
@@ -1490,12 +1499,14 @@ class FraudScorer:
             # batch launches differs from run to run
             programs = text_split.family(size, narrow, full, bucket_of)
             mv = self.effective_model_valid()
-            for rows, width in programs:
-                # the batch's first rows stand in: the results are dropped
-                k = min(n, rows)
-                warm = _Launch(np.arange(k), k, rows, width)
-                self._pack_launch(batch, warm)
-                jax.block_until_ready(self._launch_packed(warm, mv))
+            with self.spans.span(scopes.BUILD_PROGRAMS, rows=size,
+                                 programs=len(programs)):
+                for rows, width in programs:
+                    # the batch's first rows stand in; results are dropped
+                    k = min(n, rows)
+                    warm = _Launch(np.arange(k), k, rows, width)
+                    self._pack_launch(batch, warm)
+                    jax.block_until_ready(self._launch_packed(warm, mv))
             self._text_families[size] = programs
         return launches
 
@@ -1506,8 +1517,8 @@ class FraudScorer:
         ``real_tokens`` (``text_split.capacity``: a shape of the program,
         every real token is routed at any rung). The first time a bucket is
         launched, the program of each of its rungs is compiled and run
-        here, as a split bucket's family is, so that none first appears
-        under load."""
+        here, as a split bucket's family is and under the same span
+        (``build_programs``), so that none first appears under load."""
         slots = size * width
         if size not in self._text_families:
             mv = self.effective_model_valid()
@@ -1516,10 +1527,12 @@ class FraudScorer:
             empty = batch.replace(
                 token_mask=np.zeros_like(np.asarray(batch.token_mask)))
             rungs = text_split.capacities(slots)
-            for rung in rungs:
-                warm = _Launch(None, n, size, width, capacity=rung)
-                self._pack_launch(empty, warm)
-                jax.block_until_ready(self._launch_packed(warm, mv))
+            with self.spans.span(scopes.BUILD_PROGRAMS, rows=size,
+                                 programs=len(rungs)):
+                for rung in rungs:
+                    warm = _Launch(None, n, size, width, capacity=rung)
+                    self._pack_launch(empty, warm)
+                    jax.block_until_ready(self._launch_packed(warm, mv))
             self._text_families[size] = tuple(
                 (size, width, rung) for rung in rungs)
         return _Launch(None, n, size, width,

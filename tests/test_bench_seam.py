@@ -28,6 +28,14 @@ for p in (str(ROOT), str(ROOT / "benchmarks" / "tests")):
 
 import rehearsal  # noqa: E402  (benchmarks/tests/rehearsal.py)
 from benchmarks.harness import spec  # noqa: E402
+# PR 36's readers on hand-made runs: collected here as they stand there
+from test_setup_metrics import (  # noqa: E402,F401
+    test_a_ledger_that_let_records_go_reads_none,
+    test_a_program_without_the_ledger_reads_none,
+    test_setup_records_are_counted_and_the_windows_are_not,
+    test_the_scope_and_counter_metrics_on_a_hand_made_run,
+    test_two_threads_compiling_at_once_are_counted_once,
+)
 
 BM = spec.benchmark()
 ALL = rehearsal.with_parked()
@@ -227,10 +235,25 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
         | {"ffn_ms_per_batch"} | laguna_only)
     laguna_reports = reports.pop(LAGUNA_CELL)
     assert not {"ffn_roofline_pct", "attn_core_roofline_pct"} & laguna_reports
-    for m in BM["per_layer"][-6:]:
+    # PR 36 appended seven behind them: the first per-layer metrics under
+    # setup_s, two scopes no metric read, and the two launch-rule counters
+    assert [(m["name"], m["moves"], len(m["workloads"]))
+            for m in BM["per_layer"][-7:]] == [
+        ("compile_setup_s", "setup_s", 7),
+        ("trace_lower_setup_s", "setup_s", 7),
+        ("setup_programs", "setup_s", 7),
+        ("attn_proj_ms_per_batch", "txn_per_s", 7),
+        ("ln_ms_per_batch", "txn_per_s", 7),
+        ("split_batches_pct", "txn_per_s", 2),
+        ("compact_batches_pct", "txn_per_s", 5)]
+    assert [m["name"] for m in BM["per_layer"] if m["moves"] == "setup_s"
+            ] == ["compile_setup_s", "trace_lower_setup_s", "setup_programs"]
+    assert "compact_batches_pct" in reports[OLMOE_CELL] \
+        and "split_batches_pct" not in reports[OLMOE_CELL]
+    for m in BM["per_layer"][-13:-7]:
         assert m["name"] in laguna_only
         assert m["workloads"] == [LAGUNA_CELL] and m["moves"] == "txn_per_s"
-    earlier = BM["per_layer"][:-6]  # the checks below are on what PR 30 left
+    earlier = BM["per_layer"][:-13]  # the checks below: what PR 30 left
     # OLMoE's second cell reports exactly what its first does
     assert reports[FULL_CELL] == reports[OLMOE_CELL] >= common | olmoe_only
     # OLMoE's kernel files read OLMoE's keys and byte model

@@ -246,9 +246,11 @@ class TestSpanTimer:
     def test_percentiles_interpolate(self):
         """Satellite: p50/p99 interpolate between order statistics —
         raw index selection made p99 on small n simply the max."""
-        timer = SpanTimer()
+        t = [0.0]
+        timer = SpanTimer(clock=lambda: t[0])
         for ms in range(1, 101):            # 1..100 ms
-            timer.record("s", ms / 1e3)
+            with timer.span("s"):
+                t[0] += ms / 1e3
         st = timer.stats("s")["s"]
         assert st["p50_ms"] == pytest.approx(50.5)       # numpy default
         assert st["p99_ms"] == pytest.approx(99.01)
@@ -258,9 +260,11 @@ class TestSpanTimer:
             np.percentile(np.arange(1.0, 101.0), [50, 99]))
 
     def test_small_n_p99_not_max(self):
-        timer = SpanTimer()
+        t = [0.0]
+        timer = SpanTimer(clock=lambda: t[0])
         for ms in (1.0, 2.0, 100.0):
-            timer.record("s", ms / 1e3)
+            with timer.span("s"):
+                t[0] += ms / 1e3
         st = timer.stats("s")["s"]
         assert st["p99_ms"] < 100.0
         assert st["p99_ms"] == pytest.approx(
@@ -435,6 +439,250 @@ class TestSpanTimer:
         assert _RecordingAnnotation.seen == [
             ("rtfd:host.gc", {"generation": 0}),
             ("rtfd:host.gc", {"generation": 2})]
+
+
+# ---- the compile ledger (ISSUE 36) ------------------------------------------
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+def _fresh_jit(tag, inner=None):
+    """A function no test has jitted, named ``ledger_<tag>``."""
+    import jax
+
+    def f(x):
+        return (inner(x) + inner(x + 1.0) if inner is not None else x) * 3.0
+
+    f.__name__ = f.__qualname__ = f"ledger_{tag}"
+    return jax.jit(f)
+
+
+def _records_of(tag, since=0.0):
+    from realtime_fraud_detection_tpu.obs.profiling import compile_ledger
+
+    return [r for r in compile_ledger().records()
+            if f"ledger_{tag}" in r["program"] and r["start"] >= since]
+
+
+def _feed(ledger, event, program, start, end, inside=()):
+    """One phase as JAX reports it: the scalar where it opens, whatever
+    closes inside it, the time span where it closes."""
+    ledger.on_open(event, start, fun_name=program)
+    for args in inside:
+        _feed(ledger, *args)
+    ledger.on_close(event, start, end, fun_name=program)
+
+
+class TestCompileLedger:
+    def test_a_fresh_jit_leaves_a_record_a_phase_under_the_open_span(self):
+        import jax.numpy as jnp
+
+        timer = SpanTimer(annotation=_RecordingAnnotation)
+        f = _fresh_jit("spanned")
+        with timer.span("job.dispatch_batch", batch=7):
+            with timer.span("pack"):
+                f(jnp.ones((3,)))
+        records = _records_of("spanned")
+        assert [r["phase"] for r in records] == ["trace", "lower", "compile"]
+        assert [r["program"] for r in records] == [
+            "ledger_spanned", "jit(ledger_spanned)", "jit(ledger_spanned)"]
+        for a, b in zip(records, records[1:]):
+            assert a["start"] <= a["end"] <= b["start"] <= b["end"]
+        assert {r["caused_by"] for r in records} == {"pack batch=7"}
+        # the same shape again runs the compiled program: nothing is told
+        before = timer.compile_stats(newest=0)
+        f(jnp.ones((3,)))
+        assert timer.compile_stats(newest=0) == before
+        assert len(_records_of("spanned")) == 3
+
+    def test_outside_any_span_nothing_is_named_as_the_cause(self):
+        import jax.numpy as jnp
+
+        timer = SpanTimer(annotation=_RecordingAnnotation)
+        with timer.span("pack", batch=3):
+            pass                                   # closed: not the cause
+        _fresh_jit("bare")(jnp.ones((3,)))
+        assert [r["caused_by"] for r in _records_of("bare")] == ["", "", ""]
+
+    def test_a_jit_inside_a_jit_is_counted_under_its_root_and_timed_once(
+            self):
+        import jax.numpy as jnp
+
+        from realtime_fraud_detection_tpu.obs.profiling import compile_ledger
+
+        inner = _fresh_jit("inner")
+        outer = _fresh_jit("outer", inner=inner)
+        before = compile_ledger().totals()
+        outer(jnp.ones((3,)))
+        after = compile_ledger().totals()
+        trace, lower, compile_ = _records_of("outer")
+        # entered twice inside the root and each time told of (the second
+        # answered from the trace cache: its seconds say so)
+        assert trace["nested"]["ledger_inner"][0] == 2
+        assert 0.0 < trace["nested"]["ledger_inner"][1] \
+            <= trace["end"] - trace["start"]
+        assert _records_of("inner") == []          # no program of its own
+        assert after["compile_n"] - before["compile_n"] == 1
+        assert after["trace_n"] - before["trace_n"] == 1
+        # the inner traces lie inside the root's interval: counted once
+        assert after["trace_s"] - before["trace_s"] == pytest.approx(
+            trace["end"] - trace["start"])
+        spent = sum(after[p + "_s"] - before[p + "_s"]
+                    for p in ("trace", "lower", "compile"))
+        assert spent <= compile_["end"] - trace["start"]
+
+    def test_a_compilation_inside_a_trace_keeps_its_record_and_its_seconds(
+            self):
+        """An eager operation on a constant, met while tracing: a program
+        like any other, and its seconds are not the root's too."""
+        from realtime_fraud_detection_tpu.obs.profiling import CompileLedger
+
+        ledger = CompileLedger()
+        _feed(ledger, _TRACE, "root", 0.0, 10.0, inside=[
+            (_TRACE, "kernel", 1.0, 2.0, [(_TRACE, "add", 1.2, 1.4)]),
+            (_TRACE, "kernel", 2.0, 2.5),
+            (_TRACE, "add", 3.0, 3.1),
+            (_LOWER, "jit(add)", 3.1, 3.6),
+            (_COMPILE, "jit(add)", 3.6, 5.6)])
+        _feed(ledger, _LOWER, "jit(root)", 10.0, 11.0)
+        ledger.on_cache("/jax/compilation_cache/cache_misses")   # stale
+        ledger.on_open(_COMPILE, 11.0, fun_name="jit(root)")
+        ledger.on_cache("/jax/compilation_cache/cache_hits")
+        ledger.on_close(_COMPILE, 11.0, 11.5, fun_name="jit(root)")
+        assert [(r["program"], r["phase"], r["cache"])
+                for r in ledger.records()] == [
+            ("jit(add)", "lower", None), ("jit(add)", "compile", None),
+            ("root", "trace", None), ("jit(root)", "lower", None),
+            ("jit(root)", "compile", "hit")]
+        root = ledger.records()[2]
+        assert root["nested"] == {"kernel": [2, pytest.approx(1.5)],
+                                  "add": [2, pytest.approx(0.3)]}
+        totals = ledger.totals()
+        assert (totals["trace_n"], totals["lower_n"],
+                totals["compile_n"]) == (1, 2, 2)
+        assert totals["trace_s"] == pytest.approx(10.0 - 0.5 - 2.0)
+        assert totals["lower_s"] == pytest.approx(1.5)
+        assert totals["compile_s"] == pytest.approx(2.5)
+        assert (totals["hits"], totals["misses"]) == (1, 0)
+        # the union of every interval, nothing twice
+        assert sum(totals[p + "_s"] for p in ("trace", "lower",
+                                              "compile")) == pytest.approx(11.5)
+
+    def test_the_persistent_cache_says_hit_or_miss(self):
+        import time
+
+        import jax
+        import jax.numpy as jnp
+
+        salt = float(time.time_ns() % 1_000_003)   # this run's own program
+
+        def build():
+            def f(x):
+                return x * salt
+
+            f.__name__ = f.__qualname__ = "ledger_cached"
+            return jax.jit(f)
+
+        def compiles():
+            return [r["cache"] for r in _records_of("cached", since=t0)
+                    if r["phase"] == "compile"]
+
+        name = "jax_persistent_cache_min_entry_size_bytes"
+        size = getattr(jax.config, name)
+        jax.config.update(name, -1)        # a program this small is kept
+        t0 = time.time()
+        try:
+            build()(jnp.ones((3,)))
+            if compiles() != ["miss"]:
+                pytest.skip("this backend's programs are not written to "
+                            f"the persistent cache: {compiles()}")
+            build()(jnp.ones((3,)))        # the same module, built anew
+        finally:
+            jax.config.update(name, size)
+        assert compiles() == ["miss", "hit"]
+
+    def test_reset_moves_nothing_out_of_the_totals_since_start(self):
+        import jax.numpy as jnp
+
+        timer = SpanTimer(annotation=_RecordingAnnotation)
+        _fresh_jit("before_reset")(jnp.ones((3,)))
+        before = timer.compile_stats()
+        assert before["programs"] >= 1
+        timer.reset()
+        after = timer.compile_stats()
+        since = after.pop("since_reset")
+        before.pop("since_reset")
+        assert after == before
+        assert since["programs"] == 0 and since["phases"]["trace"] == {
+            "count": 0, "seconds": 0.0}
+        _fresh_jit("after_reset")(jnp.ones((3,)))
+        now = timer.compile_stats()
+        assert now["since_reset"]["programs"] == 1
+        assert now["programs"] == before["programs"] + 1
+        assert now["records"][-1]["program"] == "jit(ledger_after_reset)"
+
+    def test_fifty_timers_leave_one_listener(self):
+        from jax._src import monitoring
+
+        from realtime_fraud_detection_tpu.obs.profiling import compile_ledger
+
+        timers = [SpanTimer(annotation=_RecordingAnnotation)
+                  for _ in range(50)]
+        ledger = compile_ledger()
+        assert len(timers) == 50
+        for listeners, ours in (
+                (monitoring.get_scalar_listeners(), ledger.on_open),
+                (monitoring.get_event_listeners(), ledger.on_cache),
+                (monitoring.get_event_time_span_listeners(),
+                 ledger.on_close)):
+            assert sum(1 for cb in listeners if cb == ours) == 1
+            assert sum(1 for cb in listeners if getattr(
+                cb, "__self__", None).__class__ is type(ledger)) == 1
+        # and none on the one event list a listener of ours is not for
+        assert not any(getattr(cb, "__self__", None) is ledger
+                       for cb in monitoring.get_event_duration_listeners())
+
+    def test_the_record_cap_keeps_the_totals_exact(self):
+        from realtime_fraud_detection_tpu.obs.profiling import CompileLedger
+
+        ledger = CompileLedger(max_records=8)
+        for i in range(100):
+            _feed(ledger, _COMPILE, f"jit(p{i})", float(i), i + 0.25)
+        totals = ledger.totals()
+        assert totals["compile_n"] == 100
+        assert totals["compile_s"] == pytest.approx(25.0)
+        assert totals["dropped"] == 92
+        assert [r["program"] for r in ledger.records()] == [
+            f"jit(p{i})" for i in range(92, 100)]
+
+    @pytest.mark.parametrize("how", ["reset", "job.complete_batch"])
+    def test_a_compilation_under_traffic_is_logged_once_at_warning(
+            self, how, caplog):
+        import jax.numpy as jnp
+
+        timer = SpanTimer(annotation=_RecordingAnnotation)
+        where = "realtime_fraud_detection_tpu.obs.profiling"
+        with caplog.at_level(logging.WARNING, logger=where):
+            with timer.span("pack", batch=1):          # still warming up
+                _fresh_jit(f"warm_{how[:3]}")(jnp.ones((3,)))
+            assert not [r for r in caplog.records if r.name == where]
+            if how == "reset":
+                timer.reset()
+            else:
+                with timer.span("job.complete_batch", batch=1):
+                    pass
+            assert timer.under_traffic()
+            with timer.span("job.dispatch_batch", batch=2):
+                with timer.span("dispatch"):
+                    _fresh_jit(f"live_{how[:3]}")(jnp.ones((3,)))
+        lines = [r.getMessage() for r in caplog.records if r.name == where]
+        assert len(lines) == 1, lines
+        assert f"jit(ledger_live_{how[:3]})" in lines[0]
+        assert "caused by dispatch batch=2" in lines[0]
+        for phase in ("trace", "lower", "compile"):
+            assert f"{phase} 0." in lines[0]
+        assert "persistent cache" in lines[0]
 
 
 class _RecordingAnnotation:

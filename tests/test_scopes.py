@@ -204,6 +204,16 @@ def test_every_name_in_a_builders_vocabulary_is_one_the_program_writes(
         assert re.search(rf'"jit\([^"]*\)/{path}/', asm), path
 
 
+def test_the_family_build_is_a_span_of_the_microbatch_under_pack():
+    """``build_programs`` (ISSUE 36) is one of the host spans, a child of
+    ``pack``; every span is named once and after its parent."""
+    names = [name for name, _ in scopes.BATCH_SPANS]
+    assert len(names) == len(set(names))
+    assert dict(scopes.BATCH_SPANS)[scopes.BUILD_PROGRAMS] == scopes.PACK
+    for i, (name, parent) in enumerate(scopes.BATCH_SPANS):
+        assert parent == "" or parent in names[:i], name
+
+
 @pytest.mark.parametrize("metadata_in_key", [False, True])
 def test_a_cached_program_carries_the_scopes_of_its_key(
         tmp_path, monkeypatch, metadata_in_key):

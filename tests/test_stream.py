@@ -566,8 +566,11 @@ def test_every_span_of_a_microbatch_once_per_batch(spanned_run):
     job, scorer, _, _, scored = spanned_run
     assert scored == 40 and job.counters["batches"] == 5
     stages = scorer.host_stats()["stages"]
-    assert set(stages) == {name for name, _ in scopes.BATCH_SPANS}
-    for name, parent in scopes.BATCH_SPANS:
+    # 32 positions is one width: no bucket builds a family of programs
+    every_batch = [(name, parent) for name, parent in scopes.BATCH_SPANS
+                   if name != scopes.BUILD_PROGRAMS]
+    assert set(stages) == {name for name, _ in every_batch}
+    for name, parent in every_batch:
         if name == scopes.JOB_POLL:
             # the loop also polls when nothing is there (drained input)
             assert stages[name]["count"] >= 5
@@ -577,7 +580,7 @@ def test_every_span_of_a_microbatch_once_per_batch(spanned_run):
         assert 0.0 <= stages[name]["self_s"] <= stages[name]["total_s"]
     # a parent's self time is its total minus its children's
     for parent in (scopes.ASSEMBLE, scopes.JOB_DISPATCH, scopes.JOB_COMPLETE):
-        children = sum(stages[n]["total_s"] for n, p in scopes.BATCH_SPANS
+        children = sum(stages[n]["total_s"] for n, p in every_batch
                        if p == parent)
         assert stages[parent]["self_s"] == pytest.approx(
             stages[parent]["total_s"] - children, abs=1e-9)

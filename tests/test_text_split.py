@@ -261,6 +261,79 @@ def test_both_rungs_are_compiled_by_a_buckets_first_batch(monkeypatch):
     assert s.host_stats()["text_split"]["families"] == fam
 
 
+@pytest.mark.parametrize("kind", ["split", "routed"])
+def test_a_buckets_first_batch_builds_its_family_under_one_span(kind):
+    """The first batch of a bucket that splits, and the first routed batch
+    of a bucket, open ``build_programs`` once, round the whole family: the
+    compile ledger saw as many fused programs compiled under it as
+    ``host_stats()["text_split"]["families"]`` holds, ``pack``'s own time
+    leaves the build out, and the next batch builds and compiles nothing."""
+    import time
+
+    from realtime_fraud_detection_tpu.obs import scopes
+    from realtime_fraud_detection_tpu.obs.profiling import compile_ledger
+    from realtime_fraud_detection_tpu.scoring import FraudScorer, ScorerConfig
+    from realtime_fraud_detection_tpu.scoring.pipeline import (
+        score_fused_packed,
+    )
+    from realtime_fraud_detection_tpu.sim.simulator import (
+        TransactionGenerator,
+    )
+
+    gen = TransactionGenerator(num_users=200, num_merchants=40, seed=36)
+    if kind == "split":
+        rows = 32
+        s = FraudScorer(scorer_config=ScorerConfig(text_len=256))
+    else:
+        rows = 128               # the smallest launch with two rungs
+        s = _moe_scorer(32)
+    s.seed_profiles(gen.users.profiles(), gen.merchants.profiles())
+
+    def batch():
+        recs = _worded(gen, rows, 3)
+        recs[5]["description"] = " ".join(["x"] * 200)     # one long row
+        return recs
+
+    def fused_programs(since):
+        return [r for r in compile_ledger().records()
+                if r["phase"] == "compile" and r["start"] >= since
+                and "score_fused_packed" in r["program"]]
+
+    # another test file on this worker may have launched the same programs
+    score_fused_packed.clear_cache()
+    t0 = time.time()
+    compiled0 = s.host_stats()["compile"]["programs"]
+    s.finalize(s.dispatch(batch(), now=1000.0), now=1000.0)
+    stats = s.host_stats()
+    (size, family), = stats["text_split"]["families"].items()
+    assert len(family) == (3 if kind == "split" else 2)
+    stages = stats["stages"]
+    build, pack = stages[scopes.BUILD_PROGRAMS], stages[scopes.PACK]
+    assert build["count"] == 1 and build["parent"] == scopes.PACK
+    assert pack["self_s"] == pytest.approx(
+        pack["total_s"] - build["total_s"], abs=1e-9)
+    assert pack["self_s"] < build["total_s"]
+    under = [r for r in fused_programs(t0)
+             if r["caused_by"].startswith(scopes.BUILD_PROGRAMS + " ")]
+    assert len(under) == len(family)
+    assert {r["caused_by"] for r in under} == {
+        f"{scopes.BUILD_PROGRAMS} rows={size} programs={len(family)}"}
+    # nothing of the bucket is left for its own launch to compile
+    assert len(fused_programs(t0)) == len(family)
+    assert stats["compile"]["programs"] - compiled0 >= len(family)
+    assert any(r in stats["compile"]["records"] for r in under)
+
+    t1 = time.time()
+    compiled1 = stats["compile"]["programs"]
+    s.finalize(s.dispatch(batch(), now=1001.0), now=1001.0)
+    stats = s.host_stats()
+    assert stats["stages"][scopes.BUILD_PROGRAMS]["count"] == 1
+    assert stats["stages"][scopes.PACK]["count"] == 2
+    assert fused_programs(t1) == []
+    assert stats["compile"]["programs"] == compiled1
+    assert stats["text_split"]["families"] == {size: family}
+
+
 def test_a_short_bucket_has_empty_filler_rows_and_the_same_answers(
         monkeypatch):
     """90 full rows on the bucket of 128: the 38 filler rows hold no token,
