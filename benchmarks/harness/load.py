@@ -212,7 +212,9 @@ def collector_off():
 
 class GcClock:
     """Times the cyclic collector's runs in this process (``gc.callbacks``):
-    seconds, runs per generation and the longest pause since ``reset``."""
+    seconds, runs per generation and the longest pause since ``reset``, and
+    for each full collection (generation 2: a dozen in a window) the
+    ``time.time()`` it ended at and the seconds it took."""
 
     def __init__(self) -> None:
         self._t0 = 0.0
@@ -221,6 +223,7 @@ class GcClock:
 
     def reset(self) -> None:
         self.seconds, self.longest_s, self.runs = 0.0, 0.0, [0, 0, 0]
+        self.full: List[tuple] = []
 
     def _on(self, phase: str, info: Dict[str, int]) -> None:
         if phase == "start":
@@ -230,10 +233,12 @@ class GcClock:
         self.seconds += took
         self.longest_s = max(self.longest_s, took)
         self.runs[info["generation"]] += 1
+        if info["generation"] == 2:
+            self.full.append((time.time(), took))
 
     def read(self) -> Dict[str, Any]:
         return {"seconds": self.seconds, "longest_s": self.longest_s,
-                "runs": list(self.runs)}
+                "runs": list(self.runs), "full": list(self.full)}
 
     def close(self) -> None:
         gc.callbacks.remove(self._on)

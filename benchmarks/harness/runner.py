@@ -285,7 +285,10 @@ def _measure(args: argparse.Namespace, t_process: float,
     log(f"cyclic collector in the job's process, counted part of the "
         f"window: {gcs['seconds']:.3f} s of {run.counted_s:.1f} s, longest "
         f"pause {gcs['longest_s'] * 1e3:.1f} ms, runs per generation "
-        f"{gcs['runs']}")
+        f"{gcs['runs']}; full collections as (end, seconds) from the "
+        f"window's opening: "
+        + str([(round(end - run.t_open, 3), round(took, 3))
+               for end, took in gcs["full"]]))
     if run.pool_completed is not None:
         log(f"pool completed per device: {run.pool_completed}")
     if mode == "open_loop":

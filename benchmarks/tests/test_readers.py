@@ -124,3 +124,28 @@ def test_collector_clock_times_collections_and_the_reader_takes_the_share():
             "collector": {"seconds": 3.0, "longest_s": 0.5, "runs": [9, 2, 1]}})
         assert read(run) == pytest.approx(15.0)
         assert read(types.SimpleNamespace(counted_s=20.0, extra={})) is None
+
+
+def test_collector_clock_keeps_end_and_seconds_of_full_collections_only():
+    import gc
+    import time
+
+    from benchmarks.harness import load
+
+    clock = load.GcClock()
+    try:
+        t0 = time.time()
+        gc.collect(0)
+        gc.collect(2)
+        gc.collect(1)
+        gc.collect(2)
+        t1 = time.time()
+        seen = clock.read()
+    finally:
+        clock.close()
+    assert seen["runs"][2] == len(seen["full"]) >= 2
+    (e1, s1), (e2, s2) = seen["full"][-2:]
+    assert t0 <= e1 <= e2 <= t1 and 0 < s1 <= seen["longest_s"]
+    assert s1 + s2 < seen["seconds"]        # the young ones are timed too
+    clock.reset()
+    assert clock.read()["full"] == []
