@@ -212,23 +212,28 @@ def router_probs(x: jax.Array, w_router: jax.Array) -> jax.Array:
 
 def choose_experts(probs: jax.Array, top_k: int,
                    bias: Optional[jax.Array] = None, *,
-                   renormalise: bool = False, scale: float = 1.0
-                   ) -> Tuple[jax.Array, jax.Array]:
+                   renormalise: bool = False, renormalise_eps: float = 0.0,
+                   scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
     """``(experts i32[N, top_k], weights f32[N, top_k])`` of router
     probabilities ``f32[N, num_experts]``, however the encoder's router made
     them: each token's ``top_k`` largest, their probabilities NOT
     renormalised unless ``renormalise`` (``norm_topk_prob``: divided by
-    their sum over the chosen, all of them, wherever their experts live),
-    then times ``scale`` (``moe_routed_scaling_factor``). ``bias``
-    (``f32[num_experts]``) moves the choice alone: the largest of ``probs +
-    bias`` are taken and weighted by ``probs``."""
+    their sum over the chosen, all of them, wherever their experts live —
+    plus ``renormalise_eps`` where the source guards the division, as
+    ``models/joyai.py``'s does), then times ``scale``
+    (``moe_routed_scaling_factor``). ``bias`` (``f32[num_experts]``) moves
+    the choice alone: the largest of ``probs + bias`` are taken and weighted
+    by ``probs`` (a sigmoid router's ``e_score_correction_bias``)."""
     if bias is None:
         weights, experts = jax.lax.top_k(probs, top_k)
     else:
         _, experts = jax.lax.top_k(probs + bias, top_k)
         weights = jnp.take_along_axis(probs, experts, axis=-1)
     if renormalise:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        total = jnp.sum(weights, axis=-1, keepdims=True)
+        if renormalise_eps:
+            total = total + renormalise_eps
+        weights = weights / total
     if scale != 1.0:
         weights = weights * scale
     return experts.astype(jnp.int32), weights

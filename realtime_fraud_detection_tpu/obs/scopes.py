@@ -76,6 +76,15 @@ SHARED_EXPERT = "shared_expert"   # the expert every token passes through
 LAGUNA_LAYER_SCOPES: Tuple[str, ...] = MOE_LAYER_SCOPES + (FFN,
                                                            SHARED_EXPERT)
 
+# ---- device: under ``text`` where the encoder is models/joyai.py: Laguna's
+# names (``attn_proj`` holds the two up-projections out of the latents, by
+# part, and o; ``attn_core`` the latent causal core) and
+ATTN_LATENT = "attn_latent"  # what latent attention puts in front of the
+                             # projections: the two down-projections into
+                             # the query and key-value latents, their
+                             # RMSNorms, the shared key's split
+JOYAI_LAYER_SCOPES: Tuple[str, ...] = LAGUNA_LAYER_SCOPES + (ATTN_LATENT,)
+
 
 def layer_scope(i: int) -> str:
     return f"{LAYER}{i}"

@@ -8,10 +8,13 @@ a predicate admits holds the kernel, everything else the XLA form.
   ``windowed_attention`` (the causal core at ``head_dim`` 128 with grouped
   keys, an optional sliding window and the rows' lengths: online softmax
   over the key blocks a query block sees; handed the weights of a QK-norm
-  over all heads, its one-block form, every head of a row a program)
-  against ``attention_reference(causal=True, window=...)``;
-  ``windowed_refusal``, asked through ``LagunaConfig.core_refusal`` and
-  ``OlmoeConfig.core_refusal``.
+  over all heads, its one-block form, every head of a row a program;
+  handed a shared key, its latent form: a second score term from ONE
+  rotated key every head shares, ``models/joyai.py``) against
+  ``attention_reference(causal=True, window=...)``; ``windowed_refusal``,
+  asked through
+  ``LagunaConfig.core_refusal``, ``OlmoeConfig.core_refusal`` and
+  ``JoyaiConfig.core_refusal``.
 - ``cca_mix``: ``cca_mix_fused`` (ZAYA1's convolutional mixing between the
   latent projections and the core as one pass: latents and values in, q, k
   and the shifted v out) against ``models.zaya.cca_mix``;
@@ -31,6 +34,7 @@ from realtime_fraud_detection_tpu.ops.attention import (  # noqa: F401
     merge_heads,
     narrowest_supported_len,
     rope_lane_tables,
+    rope_pair_tables,
     split_heads,
     windowed_attention,
     windowed_refusal,

@@ -32,7 +32,13 @@ QK-norm, ``models/olmoe.py``) no program that holds one head can form the
 statistic: handed the norm's weights, the same entry runs its one-block form
 (``_whole_row_kernel``), in which a program owns every head of a row of one
 block of positions, forms both statistics, scales, rotates and rounds in
-VMEM, and has no key blocks to walk.
+VMEM, and has no key blocks to walk. Where a head's score has a second term
+against ONE key every head shares (latent attention, ``models/joyai.py``),
+handed that term the same kernel runs its latent form: the heads whose
+shared parts fill a lane tile go a step together, each with keys and values
+of its own, the shared term alone is rotated, and a step takes several
+blocks of queries (a head's keys serve no other head, so that is how its
+matmuls see more rows).
 
 Precision, as the configuration states it and as XLA's default-precision
 einsum runs the reference on a TPU: bf16 MXU operands with f32 accumulation;
@@ -68,7 +74,10 @@ def attention_reference(
     ``q`` (``[B,Hkv,S,D]``, ``H`` a multiple of ``Hkv``); key head ``j``
     serves query heads ``j*G .. j*G+G-1``. The G query heads of a group are
     stacked along the query axis of their key head, so no key or value is
-    repeated in memory and the rest is the same two contractions."""
+    repeated in memory and the rest is the same two contractions. ``v`` may
+    be of another width than ``q`` and ``k`` (latent attention: scores over
+    192, values of 128): the scale is the score width's, the result ``v``'s
+    wide."""
     heads, t_q, d = q.shape[1:]
     group = heads // k.shape[1]
     if group != 1:
@@ -86,7 +95,8 @@ def attention_reference(
         raise ValueError("attention_reference: a window is a causal core's")
     weights = jax.nn.softmax(scores, axis=-1)
     ctx = jnp.einsum("bhqk,bhkd->bhqd", weights.astype(v.dtype), v)
-    return ctx.reshape(ctx.shape[0], heads, t_q, d) if group != 1 else ctx
+    return (ctx.reshape(ctx.shape[0], heads, t_q, ctx.shape[-1])
+            if group != 1 else ctx)
 
 
 def split_heads(x: jax.Array, num_heads: int) -> jax.Array:
@@ -231,15 +241,21 @@ WINDOW_BLOCK = LANES     # queries and keys a block: one lane tile of scores
 
 def windowed_refusal(seq_len: int, head_dim: int, num_heads: int,
                      num_kv_heads: int, window: int | None,
-                     qk_norm: bool = False) -> str | None:
+                     qk_norm: bool = False, shared_key_dim: int | None = None,
+                     value_dim: int | None = None) -> str | None:
     """Why ``windowed_attention`` does not take a shape, by name, or None
     where it does. The ONE predicate: the traced guards in
-    ``models/laguna.py`` and ``models/olmoe.py`` and the scorer's selector
-    all ask it. ``qk_norm``: the call hands it a norm over the whole
-    projection (the one-block form)."""
-    if head_dim != LANES:
+    ``models/laguna.py``, ``models/olmoe.py`` and ``models/joyai.py`` and
+    the scorer's selector all ask it. ``qk_norm``: the call hands it a norm
+    over the whole projection (the one-block form). ``shared_key_dim``: the
+    call hands it a second score term that wide from ONE key head all query
+    heads share (the latent form: ``head_dim`` is then the per-head part of
+    a score, ``value_dim`` the values' width where it is not
+    ``head_dim``)."""
+    if head_dim != LANES or (value_dim or head_dim) != LANES:
         return (f"windowed_attention takes heads of {LANES} (one lane tile "
-                f"a head): head_dim {head_dim}")
+                f"a head): head_dim {head_dim}"
+                + (f", value_dim {value_dim}" if value_dim else ""))
     if num_heads % num_kv_heads:
         return (f"windowed_attention: {num_heads} query heads do not divide "
                 f"into {num_kv_heads} key-value heads")
@@ -254,6 +270,18 @@ def windowed_refusal(seq_len: int, head_dim: int, num_heads: int,
         return (f"windowed_attention norms q and k over all heads in a step "
                 f"that holds a row's one block of {WINDOW_BLOCK} positions, "
                 f"with no window: seq_len {seq_len}, window {window}")
+    if shared_key_dim is not None:
+        if (shared_key_dim < 2 or shared_key_dim % 2
+                or LANES % shared_key_dim):
+            return (f"windowed_attention takes a shared key whose pairs of "
+                    f"dims tile a lane tile of {LANES}: shared_key_dim "
+                    f"{shared_key_dim}")
+        if (num_heads != num_kv_heads or window is not None or qk_norm
+                or num_heads % (LANES // shared_key_dim)):
+            return (f"windowed_attention takes a shared key beside one key "
+                    f"head a query head, {LANES // shared_key_dim} heads a "
+                    f"step, with no window and no QK-norm: {num_heads} query "
+                    f"heads, {num_kv_heads} key-value heads, window {window}")
     return None
 
 
@@ -278,13 +306,57 @@ def rope_lane_tables(cos: np.ndarray, sin: np.ndarray, head_dim: int
     return c, up, down, half
 
 
+def rope_pair_tables(cos: np.ndarray, sin: np.ndarray
+                     ) -> tuple[np.ndarray, np.ndarray, np.ndarray, int]:
+    """INTERLEAVED RoPE (the rotated pairs are the adjacent dims ``2i``,
+    ``2i + 1``) of ``rot``-wide heads side by side in one lane tile, in
+    ``rope_lane_tables``' form — three per-lane tables and a lane shift of
+    1: ``roll(x, +1)[l] = x[l - 1]`` carries a pair's even dim to its odd
+    one (times ``+sin``), ``roll(x, -1)`` the odd to the even (times
+    ``-sin``). ``cos`` and ``sin`` are ``f32[T, rot / 2]``, one column a
+    pair; the ``LANES // rot`` heads of a tile read the same tables."""
+    t, pairs = cos.shape
+    repeat = LANES // (2 * pairs)
+    c = np.tile(np.repeat(cos, 2, axis=1), (1, repeat)).astype(np.float32)
+    s = np.tile(np.repeat(sin, 2, axis=1), (1, repeat)).astype(np.float32)
+    odd = (np.arange(LANES) % 2).astype(bool)[None]
+    return c, np.where(odd, s, 0.0), np.where(odd, 0.0, -s), 1
+
+
+# the widest query step the latent form takes. A head's keys serve no other
+# head (group 1), so its matmuls see only as many rows as a step holds
+# queries: alone on the v5e at the cell's shape (8 rows x 2,048, 32 heads,
+# the mix's lengths) a layer took 4.63 ms at one block of 128, 3.75 at two,
+# 3.43 at four and 3.89 at eight, whose steps waste more of a short row
+# (every slot real: 8.69 / 6.12 / 4.90 / 4.84; PERF.md, PR 43)
+LATENT_QUERY_BLOCKS = 4
+
+
+def latent_query_blocks(seq_len: int) -> int:
+    """Blocks of 128 queries a step of the latent form: the most, up to
+    ``LATENT_QUERY_BLOCKS``, that tile the sequence."""
+    span = LATENT_QUERY_BLOCKS
+    while seq_len % (span * WINDOW_BLOCK):
+        span //= 2
+    return span
+
+
 def _windowed_kernel(lens_ref, *refs, group: int, window_blocks: int | None,
-                     scale: float, rope_shift: int | None, gated: bool):
+                     scale: float, rope_shift: int | None, gated: bool,
+                     shared_key: bool = False, span: int = 1):
+    # ``span`` key blocks a query block: queries go by ``span * block`` a
+    # step against keys ``block`` at a time (1 but for the latent form)
     block, d = WINDOW_BLOCK, LANES
-    # inputs: q, k, v, [this query block's three tables, the whole row's
-    # three], [the gates]; output; scratch: m, l, acc, [the rotated keys]
+    block_q = span * block
+    # inputs: q, k, v, [the queries' and the keys' shared score term], [this
+    # query block's three tables, the whole row's three], [the gates];
+    # output; scratch: m, l, acc, [the rotated keys]
     refs = list(refs)
     q_ref, k_ref, v_ref = (refs.pop(0) for _ in range(3))
+    if shared_key:
+        # the latent form: ``group`` heads a step, each with keys and values
+        # of its own, and the ONE rotated key they all share
+        qs_ref, ks_ref = refs.pop(0), refs.pop(0)
     if rope_shift is not None:
         c_ref, up_ref, down_ref, kc_ref, kup_ref, kdown_ref = (
             refs.pop(0) for _ in range(6))
@@ -307,40 +379,76 @@ def _windowed_kernel(lens_ref, *refs, group: int, window_blocks: int | None,
             def one(kb, carry):
                 at = pl.ds(pl.multiple_of(kb * block, block), block)
                 keys_ref[at, :] = rotated(
-                    k_ref[0, at, :], kc_ref[at, :], kup_ref[at, :],
+                    (ks_ref if shared_key else k_ref)[0, at, :],
+                    kc_ref[at, :], kup_ref[at, :],
                     kdown_ref[at, :]).astype(keys_ref.dtype)
                 return carry
 
             jax.lax.fori_loop(0, k_ref.shape[1] // block, one, 0)
 
-    @pl.when(qi * block >= lens_ref[row])
+    @pl.when(qi * block_q >= lens_ref[row])
     def _past_the_text():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(qi * block < lens_ref[row])
+    @pl.when(qi * block_q < lens_ref[row])
     def _real():
         def head(j):
             x = q_ref[0, :, j * d:(j + 1) * d]
-            if rope_shift is not None:
+            if rope_shift is not None and not shared_key:
                 x = rotated(x, c_ref[...], up_ref[...], down_ref[...])
             return x.astype(v_ref.dtype)
 
-        # the group's query heads, stacked along the query axis: [G*bq, D]
-        q = jnp.concatenate([head(j) for j in range(group)], axis=0)
+        if shared_key:
+            # each head's own part of q as it arrives, and its lanes of the
+            # tile of shared parts, rotated once a block (the other heads'
+            # lanes zero: the contraction runs over the whole tile)
+            q = [head(j) for j in range(group)]
+            tile = rotated(qs_ref[0], c_ref[...], up_ref[...], down_ref[...])
+            lane = jax.lax.broadcasted_iota(jnp.int32, (block_q, d), 1)
+            wide = d // group
+            q_shared = [jnp.where((lane >= j * wide) & (lane < (j + 1) * wide),
+                                  tile, 0.0).astype(v_ref.dtype)
+                        for j in range(group)]
+        else:
+            # the group's query heads, stacked along the query axis:
+            # [G*bq, D]
+            q = jnp.concatenate([head(j) for j in range(group)], axis=0)
         # where a key stands against a query inside a block pair: the same
         # pattern in every stacked head
         rows_ = jax.lax.broadcasted_iota(
-            jnp.int32, (group * block, block), 0) & (block - 1)
+            jnp.int32, (group * block_q, block), 0) & (block_q - 1)
         cols = jax.lax.broadcasted_iota(
-            jnp.int32, (group * block, block), 1)
+            jnp.int32, (group * block_q, block), 1)
+
+        def against(x, k):
+            return jax.lax.dot_general(
+                x, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
         def scores(kb):
             keys = pl.ds(pl.multiple_of(kb * block, block), block)
+            if shared_key:
+                # a head's scores are its own term and the shared one's;
+                # the heads stacked along the query axis as a group's are
+                shared = keys_ref[keys, :]
+                s = jnp.concatenate([
+                    against(q[j], k_ref[0, keys, j * d:(j + 1) * d])
+                    + against(q_shared[j], shared)
+                    for j in range(group)], axis=0)
+                return s * scale, v_ref[0, keys, :]
             k = k_ref[0, keys, :] if rope_shift is None else keys_ref[keys, :]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)        # [G*bq, bk]
-            return s * scale, v_ref[0, keys, :]
+            return against(q, k) * scale, v_ref[0, keys, :]  # [G*bq, bk]
+
+        def weighted(p, v):
+            p = p.astype(v.dtype)
+            if shared_key:
+                # each stacked head against its own values
+                return jnp.concatenate([
+                    jnp.dot(p[j * block_q:(j + 1) * block_q],
+                            v[:, j * d:(j + 1) * d],
+                            preferred_element_type=jnp.float32)
+                    for j in range(group)], axis=0)
+            return jnp.dot(p, v, preferred_element_type=jnp.float32)
 
         def fold(s, v):
             m_prev = m_ref[...]
@@ -348,22 +456,23 @@ def _windowed_kernel(lens_ref, *refs, group: int, window_blocks: int | None,
             alpha = jnp.exp(m_prev - m_next)
             p = jnp.exp(s - m_next)
             l_ref[...] = alpha * l_ref[...] + p.sum(axis=1, keepdims=True)
-            acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
-                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            acc_ref[...] = alpha * acc_ref[...] + weighted(p, v)
             m_ref[...] = m_next
 
         # the diagonal block first: every query sees its own key there, so
         # the running maximum is a real score before any wholly masked row
         # of the window's edge arrives
-        s, v = scores(qi)
+        # (one block a step: ``qi`` itself, so that the form traces what it
+        # always did)
+        diagonal = qi if span == 1 else qi * span
+        s, v = scores(diagonal)
         s = jnp.where(cols <= rows_, s, NEG_INF)
         m = s.max(axis=1, keepdims=True)
         p = jnp.exp(s - m)
         m_ref[...] = jnp.broadcast_to(m, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(p.sum(axis=1, keepdims=True),
                                       l_ref.shape)
-        acc_ref[...] = jnp.dot(p.astype(v.dtype), v,
-                               preferred_element_type=jnp.float32)
+        acc_ref[...] = weighted(p, v)
 
         # the blocks every query of this one sees whole
         first = 0 if window_blocks is None else jnp.maximum(
@@ -373,7 +482,15 @@ def _windowed_kernel(lens_ref, *refs, group: int, window_blocks: int | None,
             fold(*scores(kb))
             return carry
 
-        jax.lax.fori_loop(first, qi, whole, 0)
+        jax.lax.fori_loop(first, diagonal, whole, 0)
+
+        # the rest of a wide query block's diagonal: key j of the i-th
+        # further block is seen from position i * block + j of this one on
+        # (the rows before it fold nothing in: their maximum is a real
+        # score since the first diagonal block)
+        for i in range(1, span):
+            s, v = scores(diagonal + i)
+            fold(jnp.where(cols + i * block <= rows_, s, NEG_INF), v)
 
         if window_blocks is not None:
             # the window's edge: key j of that block is seen by the queries
@@ -385,7 +502,7 @@ def _windowed_kernel(lens_ref, *refs, group: int, window_blocks: int | None,
 
         out = acc_ref[...] / l_ref[...]
         for j in range(group):
-            mine = out[j * block:(j + 1) * block]
+            mine = out[j * block_q:(j + 1) * block_q]
             if gated:
                 mine = mine * gate_ref[0, 0, :, j:j + 1]
             o_ref[0, :, j * d:(j + 1) * d] = mine.astype(o_ref.dtype)
@@ -511,7 +628,8 @@ def windowed_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                        rope_shift: int | None = None,
                        gate: jax.Array | None = None,
                        norm: tuple | None = None,
-                       norm_eps: float | None = None, out_dtype=None,
+                       norm_eps: float | None = None,
+                       shared_key: tuple | None = None, out_dtype=None,
                        interpret: bool = False) -> jax.Array:
     """Fused causal core with grouped keys. ``q`` ``[B, T, H*128]``, ``k``
     and ``v`` ``[B, T, Hkv*128]`` (heads side by side, as the projections
@@ -539,10 +657,27 @@ def windowed_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     (``_whole_row_kernel``: every head of a row a program, ``seq_len`` one
     block, no window; it rotates too, and has no gate).
 
+    ``shared_key`` = ``(q_shared f32[B, T, H*R], k_shared f32[B, T, R])``,
+    the latent form (``models/joyai.py``): a head's score is ``q . k`` over
+    its own 128 PLUS ``q_shared . k_shared`` over ``R`` more dims against
+    ONE key every head shares, the sum scaled by ``(128 + R)^-1/2``. The
+    ``rope`` tables (``rope_pair_tables``) are then the SHARED term's and
+    the only rotation: ``q`` and ``k`` go to the contraction as they
+    arrive. One key head a query head, ``128 // R`` heads a step (their
+    shared parts fill one lane tile; the shared key arrives tiled that many
+    times), no window, no gate. A step takes ``latent_query_blocks(T)``
+    blocks of 128 queries against keys 128 at a time: a head's own keys
+    serve no other head, so a wider query block is the only way its matmuls
+    see more rows. A row's output is then zero from its first wholly padded
+    STEP on.
+
     ``interpret=True`` runs the kernel through the Pallas interpreter."""
     b, t, width = q.shape
-    refusal = windowed_refusal(t, width // num_heads, num_heads,
-                               num_kv_heads, window, qk_norm=norm is not None)
+    shared_dim = None if shared_key is None else shared_key[1].shape[-1]
+    refusal = windowed_refusal(
+        t, width // num_heads, num_heads, num_kv_heads, window,
+        qk_norm=norm is not None, shared_key_dim=shared_dim,
+        value_dim=v.shape[-1] // num_kv_heads)
     if refusal or width % num_heads:
         raise ValueError(refusal or "windowed_attention: ragged heads")
     if (rope is None) != (rope_shift is None):
@@ -560,35 +695,57 @@ def windowed_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             lengths, q, k, v, norm, rope, group=group, eps=norm_eps,
             rope_shift=rope_shift, out_dtype=out_dtype or q.dtype,
             interpret=interpret)
+    steps, kv_lanes, scale, span = num_kv_heads, LANES, LANES ** -0.5, 1
+    if shared_key is not None:
+        if rope is None or gate is not None:
+            raise ValueError("windowed_attention: the latent form rotates "
+                             "its shared term, and has no gate")
+        # the heads whose shared parts fill a lane tile go a step together,
+        # each with its own keys and values beside it
+        group = LANES // shared_dim
+        steps, kv_lanes = num_kv_heads // group, group * LANES
+        scale = (LANES + shared_dim) ** -0.5
+        span = latent_query_blocks(t)
     kernel = functools.partial(
         _windowed_kernel, group=group,
         window_blocks=None if window is None else window // block,
-        scale=LANES ** -0.5, rope_shift=rope_shift,
-        gated=gate is not None)
-    stacked = (group * block, LANES)
-    heads_block = pl.BlockSpec((1, block, group * LANES),
+        scale=scale, rope_shift=rope_shift,
+        gated=gate is not None, shared_key=shared_key is not None, span=span)
+    block_q = span * block
+    stacked = (group * block_q, LANES)
+    heads_block = pl.BlockSpec((1, block_q, group * LANES),
                                lambda i, g, qi, lens: (i, qi, g))
     # a row's keys and values of one head stay put while its query blocks
     # go by: fetched once a (row, head)
-    row_block = pl.BlockSpec((1, t, LANES), lambda i, g, qi, lens: (i, 0, g))
+    row_block = pl.BlockSpec((1, t, kv_lanes),
+                             lambda i, g, qi, lens: (i, 0, g))
     in_specs, operands = [heads_block, row_block, row_block], [q, k, v]
     scratch = [pltpu.VMEM(stacked, jnp.float32)] * 3
+    if shared_key is not None:
+        q_shared, k_shared = shared_key
+        in_specs += [
+            pl.BlockSpec((1, block_q, LANES),
+                         lambda i, g, qi, lens: (i, qi, g)),
+            pl.BlockSpec((1, t, LANES), lambda i, g, qi, lens: (i, 0, 0))]
+        operands += [q_shared.astype(jnp.float32),
+                     jnp.tile(k_shared.astype(jnp.float32), (1, 1, group))]
     if rope is not None:
         tables = [jnp.asarray(x, jnp.float32) for x in rope]
-        table = pl.BlockSpec((block, LANES), lambda i, g, qi, lens: (qi, 0))
+        table = pl.BlockSpec((block_q, LANES),
+                             lambda i, g, qi, lens: (qi, 0))
         whole = pl.BlockSpec((t, LANES), lambda i, g, qi, lens: (0, 0))
         in_specs += [table] * 3 + [whole] * 3
         operands += tables + tables
         scratch.append(pltpu.VMEM((t, LANES), v.dtype))
     if gate is not None:
         # [B, Hkv, T, G]: a group's gates are a block's last axis whole
-        in_specs.append(pl.BlockSpec((1, 1, block, group),
+        in_specs.append(pl.BlockSpec((1, 1, block_q, group),
                                      lambda i, g, qi, lens: (i, g, qi, 0)))
         operands.append(gate.astype(jnp.float32).reshape(
             b, t, num_kv_heads, group).transpose(0, 2, 1, 3))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, num_kv_heads, t // block),
+        grid=(b, steps, t // block_q),
         in_specs=in_specs,
         out_specs=heads_block,
         scratch_shapes=scratch,

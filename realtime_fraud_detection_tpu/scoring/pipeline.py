@@ -44,6 +44,11 @@ from realtime_fraud_detection_tpu.models.isolation_forest import (
     IsolationForest,
     iforest_predict,
 )
+from realtime_fraud_detection_tpu.models.joyai import (
+    JoyaiConfig,
+    init_joyai_params,
+    joyai_predict,
+)
 from realtime_fraud_detection_tpu.models.laguna import (
     LagunaConfig,
     init_laguna_params,
@@ -88,12 +93,14 @@ NUM_MODELS = len(MODEL_NAMES)
 
 # The text branch's configuration picks its encoder by its CLASS: a
 # ``BertConfig`` the dense DistilBERT-style one (models/bert.py), an
-# ``OlmoeConfig``, a ``ZayaConfig`` or a ``LagunaConfig`` a routed
-# sparse-expert one (models/olmoe.py, models/zaya.py, models/laguna.py). The
+# ``OlmoeConfig``, a ``ZayaConfig``, a ``LagunaConfig`` or a ``JoyaiConfig``
+# a routed sparse-expert one (models/olmoe.py, models/zaya.py,
+# models/laguna.py, models/joyai.py). The
 # argument, the static jit argument and the ``ScoringModels`` field keep the
 # name ``bert``: checkpoints, ``MODEL_NAMES`` and the benchmark's references
 # read them.
-TextConfig = Union[BertConfig, OlmoeConfig, ZayaConfig, LagunaConfig]
+TextConfig = Union[BertConfig, OlmoeConfig, ZayaConfig, LagunaConfig,
+                   JoyaiConfig]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,9 +121,12 @@ class RoutedText:
     grouped matmul's shape predicate read — and ``num_sparse_layers`` (the
     layers with a routed block). ``attention_refusal(config, seq_len)``
     names why a launch of ``seq_len`` positions holds no Pallas kernel at
-    its attention site even where asked (None where it holds one: OLMoE's
-    and Laguna's fused causal core, ZAYA1's fused mixing). A routed encoder
-    runs on one device and has no int8 or dequant plane."""
+    its attention site even where asked (None where it holds one: OLMoE's,
+    Laguna's and JoyAI's fused causal core, ZAYA1's fused mixing). Every
+    routed encoder here is causal: the scorer counts the (query, key) pairs
+    its real queries see from the rows' lengths, and under a window where
+    the class spells ``sliding_window``. A routed encoder runs on one device
+    and has no int8 or dequant plane."""
 
     init: Callable[..., Dict[str, Any]]
     predict: Callable[..., Any]
@@ -130,6 +140,8 @@ _ROUTED_TEXT = {
                            ZayaConfig.mix_refusal),
     LagunaConfig: RoutedText(init_laguna_params, laguna_predict,
                              LagunaConfig.core_refusal),
+    JoyaiConfig: RoutedText(init_joyai_params, joyai_predict,
+                            JoyaiConfig.core_refusal),
 }
 
 

@@ -916,9 +916,9 @@ class FraudScorer:
         reference, and the engagement counters say so), the grouped expert
         matmul of the MoE encoder (``ops.grouped_matmul``, same pattern)
         and, with it, ZAYA1's fused mixing (``ops/cca_mix.py``, guarded by
-        ``ZayaConfig.mix_refusal``) or OLMoE's and Laguna's fused causal
-        core (``ops.attention.windowed_attention``, guarded by
-        ``OlmoeConfig.core_refusal`` / ``LagunaConfig.core_refusal``). With
+        ``ZayaConfig.mix_refusal``) or OLMoE's, Laguna's and JoyAI's fused
+        causal core (``ops.attention.windowed_attention``, guarded by each
+        class's ``core_refusal``). With
         the kernel plane on,
         ``KernelSettings.attention`` decides — how a drill or an A/B forces
         either side. With it off, nothing a user sets does: the kernel runs
@@ -945,9 +945,10 @@ class FraudScorer:
         the fused core for the dense encoder (``flash_supported``), for a
         routed encoder what its row of ``pipeline.RoutedText`` names
         (``ZayaConfig.mix_refusal``: the predicate of ``ops/cca_mix.py``'s
-        fused mixing; ``OlmoeConfig.core_refusal`` and
-        ``LagunaConfig.core_refusal``: that of the fused causal core,
-        ``ops.attention.windowed_attention``). The same predicates the
+        fused mixing; ``OlmoeConfig.core_refusal``,
+        ``LagunaConfig.core_refusal`` and ``JoyaiConfig.core_refusal``: that
+        of the fused causal core, ``ops.attention.windowed_attention``). The
+        same predicates the
         traced guards consult."""
         from realtime_fraud_detection_tpu.ops import flash_supported
 
@@ -1598,9 +1599,11 @@ class FraudScorer:
     def _visible_pairs(self, lengths: np.ndarray) -> Tuple[int, int]:
         """The (query, key) pairs the real queries of rows of ``lengths``
         real tokens see in one causal layer, ``L(L+1)/2`` a row, and in one
-        layer under the encoder's ``sliding_window`` W (0 where it has
-        none): ``sum_i min(i+1, W)`` = the same less the ``(L-W)(L-W+1)/2``
-        pairs further back than the window."""
+        layer under the encoder's ``sliding_window`` W (0 where its
+        description spells none): ``sum_i min(i+1, W)`` = the same less the
+        ``(L-W)(L-W+1)/2`` pairs further back than the window. Counted for
+        every routed encoder — ``pipeline.RoutedText``: all are causal —
+        whatever its class."""
         lengths = lengths.astype(np.int64)
         full = int(np.sum(lengths * (lengths + 1) // 2))
         window = getattr(self.bert_config, "sliding_window", None)

@@ -523,3 +523,79 @@ def test_laguna_program_compiles_with_its_unlike_layers(one_chip):
     assert text.count("windowed_attention") >= 2
     assert " conditional(" not in text and "cond/branch_" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 6 << 30
+
+
+# ------------------------------------------------------------------ JoyAI
+def test_latent_attention_compiles_at_published_widths(one_chip):
+    """JoyAI-LLM-Flash's fused latent core as the cell launches it: 8 rows x
+    2,048 positions, 32 heads whose scores run over 128 dims of their own
+    plus 64 of ONE shared rotated key (two heads a step, their shared parts
+    one lane tile, rotated interleaved in VMEM), values of 128, bfloat16
+    operands as their projections wrote them, bfloat16 out."""
+    from realtime_fraud_detection_tpu.models.joyai import (
+        JoyaiConfig,
+        joyai_rope_tables,
+    )
+    from realtime_fraud_detection_tpu.ops import (
+        rope_pair_tables,
+        windowed_attention,
+    )
+
+    cfg, b, t = JoyaiConfig(), 8, 2048
+    assert cfg.core_refusal(t) is None
+    heads, pe = cfg.num_attention_heads, cfg.qk_rope_head_dim
+    *tables, shift = rope_pair_tables(
+        *joyai_rope_tables(t, pe, cfg.rope_theta))
+    fn = jax.jit(lambda q, k, v, lens, q_pe, k_pe: windowed_attention(
+        q, k, v, lens, num_heads=heads, num_kv_heads=heads,
+        rope=tuple(tables), rope_shift=shift, shared_key=(q_pe, k_pe),
+        out_dtype=jnp.bfloat16))
+    wide = _sds((b, t, heads * 128), jnp.bfloat16, one_chip)
+    compiled = fn.lower(
+        wide, wide, wide, _sds((b,), jnp.int32, one_chip),
+        _sds((b, t, heads * pe), jnp.float32, one_chip),
+        _sds((b, t, pe), jnp.float32, one_chip)).compile()
+    assert compiled.as_text().count(CUSTOM_CALL) == 1
+
+
+@pytest.mark.parametrize("capacity", [12288, None],
+                         ids=["three_quarters", "every_slot"])
+def test_joyai_program_compiles_with_all_256_experts(one_chip, capacity):
+    """The served packed program with a ``JoyaiConfig``: layer 0 (the dense
+    MLP) and one sparse layer holding all 256 experts, every width as
+    published, bucket 8 x 2,048 tokens at both capacities: two fused latent
+    cores and three grouped matmuls, a second small output, no conditional,
+    no ``[8, 32, 2048, 2048]`` scores, temporaries that leave room for the
+    cell's 10.6 GB of weights in 16 GB."""
+    from realtime_fraud_detection_tpu.core.packing import pack_tree
+    from realtime_fraud_detection_tpu.ensemble.combine import EnsembleParams
+    from realtime_fraud_detection_tpu.models.joyai import JoyaiConfig
+    from realtime_fraud_detection_tpu.scoring.pipeline import (
+        MODEL_NAMES,
+        ScorerConfig,
+        init_scoring_models,
+        make_example_batch,
+        score_fused_packed,
+    )
+    from realtime_fraud_detection_tpu.utils.config import Config
+
+    config = JoyaiConfig(num_hidden_layers=2)
+    models = jax.eval_shape(
+        lambda key: init_scoring_models(key, bert_config=config),
+        jax.random.PRNGKey(0))
+    blobs, spec = pack_tree(make_example_batch(
+        8, ScorerConfig(text_len=2048)))
+    compiled = score_fused_packed.lower(
+        _shapes_of(models, one_chip),
+        *(_shapes_of(blobs[k], one_chip) for k in ("f32", "i32", "u8")),
+        spec=spec,
+        params=EnsembleParams.from_config(Config(), list(MODEL_NAMES)),
+        model_valid=_sds((len(MODEL_NAMES),), jnp.bool_, one_chip),
+        blob_bf16=_shapes_of(blobs["bf16"], one_chip), bert_config=config,
+        use_pallas=True, text_capacity=capacity).compile()
+    text = compiled.as_text()
+    assert text.count(CUSTOM_CALL) == 2 + 3
+    assert text.count("windowed_attention") >= 2
+    assert " conditional(" not in text and "cond/branch_" not in text
+    assert "f32[8,32,2048,2048]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30

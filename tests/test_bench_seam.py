@@ -52,6 +52,12 @@ LAGUNA_CELL = "laguna-s2048-remit-saturated"
 ZAYA_FULL_CELL = "zaya1-s128-fullwindow-saturated"
 LAGUNA_CFG = json.loads(
     (ROOT / "benchmarks/configs/laguna-s-2.1-s2048.json").read_text())
+# PR 43: JoyAI-LLM-Flash's cell, on Laguna's traffic file
+JOYAI_CELL = "joyai-s2048-remit-saturated"
+JOYAI_CFG = json.loads(
+    (ROOT / "benchmarks/configs/joyai-llm-flash-s2048.json").read_text())
+ROUTED_CELLS = [OLMOE_CELL, ZAYA_CELL, FULL_CELL, LAGUNA_CELL,
+                ZAYA_FULL_CELL, JOYAI_CELL]
 
 
 # ------------------------------------------------------ configuration files
@@ -191,10 +197,73 @@ def test_the_laguna_file_is_the_sources_config_cut_in_depth_and_share():
         builder.laguna_config({**LAGUNA_CFG, "mlp_only_layers": [0, 1]})
 
 
+def test_the_joyai_file_is_the_sources_config_cut_in_depth_only():
+    from realtime_fraud_detection_tpu.models.joyai import JoyaiConfig
+
+    builder = spec.builder(JOYAI_CFG)
+    built = builder.joyai_config(JOYAI_CFG)
+    assert JOYAI_CFG["reduced"] == ["num_hidden_layers"]
+    assert built == JoyaiConfig(num_hidden_layers=5)    # defaults: published
+    assert JOYAI_CFG["published"]["num_hidden_layers"] == 40
+    # every key of the catalog row's config, under its own name, at its
+    # published value but for the depth
+    row = json.loads([
+        line for line in Path(
+            "/opt/skills/guides/model-configs/architectures.jsonl"
+        ).read_text().splitlines() if '"JoyAI-LLM-Flash"' in line][0]) \
+        if Path("/opt/skills/guides/model-configs/architectures.jsonl"
+                ).is_file() else {"config": JOYAI_CFG["published"],
+                                  "source_url": JOYAI_CFG["source"]}
+    assert JOYAI_CFG["source"] == row["source_url"]
+    assert JOYAI_CFG["published"] == row["config"]
+    for key, value in row["config"].items():
+        assert JOYAI_CFG[key] == (5 if key == "num_hidden_layers" else value)
+    # all 256 experts held, top-8, every width as published
+    assert (built.num_experts, built.num_experts_per_tok,
+            built.num_sparse_layers, built.intermediate_size) == (
+        256, 8, 4, 768)
+    assert (built.hidden_size, built.q_lora_rank, built.kv_lora_rank,
+            built.qk_head_dim, built.v_head_dim, built.qk_rope_head_dim,
+            built.dense_intermediate_size, built.vocab_size) == (
+        2048, 1536, 512, 192, 128, 64, 7168, 129280)
+    assert "expert_share" not in JOYAI_CFG and JOYAI_CFG["ep_size"] == 1
+    assert JOYAI_CFG["text_len"] == 2048 and JOYAI_CFG["chips"] == 1
+    assert JOYAI_CFG["job"]["max_batch"] == 8 and JOYAI_CFG["parity_rows"] == 4
+    for key in ("cut", "deployment", "not_run", "assumed", "compute_dtype",
+                "guarantee", "parity_atol_from", "job_from"):
+        assert JOYAI_CFG[key] and "TO BE WRITTEN" not in json.dumps(
+            JOYAI_CFG[key]), key
+    assert "eight pipeline stages of five layers" in JOYAI_CFG[
+        "deployment"].lower()
+    assert set(JOYAI_CFG["not_run"]) == {"num_nextn_predict_layers"}
+    for item in ("equations", "head", "weights", "tokenizer", "traffic",
+                 "routing_at_random_weights"):
+        assert JOYAI_CFG["assumed"][item], item
+    # the traffic file is Laguna's, byte for byte: one file, two cells
+    assert spec.cell(JOYAI_CELL)["traffic"] \
+        == spec.cell(LAGUNA_CELL)["traffic"] == "s2048-remit-saturated"
+    tiny = builder.joyai_config({**JOYAI_CFG, **builder.TINY})
+    assert tiny.hidden_size < 512 and tiny.num_experts == 16
+    # the head keeps three unlike widths at TINY
+    assert (tiny.qk_nope_head_dim, tiny.qk_rope_head_dim, tiny.v_head_dim,
+            tiny.qk_head_dim) == (32, 16, 32, 48)
+    with pytest.raises(ValueError, match="ep_size"):
+        builder.joyai_config({**JOYAI_CFG, "ep_size": 8})
+    with pytest.raises(ValueError, match="qk_head_dim"):
+        builder.joyai_config({**JOYAI_CFG, "qk_head_dim": 128})
+    with pytest.raises(ValueError, match="group-limited"):
+        builder.joyai_config({**JOYAI_CFG, "n_group": 8, "topk_group": 4})
+
+
 def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
     cells = [w["name"] for w in BM["workloads"]]
-    assert cells[2:] == [OLMOE_CELL, ZAYA_CELL, FULL_CELL, LAGUNA_CELL,
-                         ZAYA_FULL_CELL]
+    # the routed cells from the end: a DistilBERT cell parked or moved back
+    # ahead of them (PR 42 parked longtail) shifts no index here
+    assert cells[-6:] == ROUTED_CELLS
+    n_cells, n_routed = len(cells), len(ROUTED_CELLS)
+    n_distilbert = n_cells - n_routed
+    assert n_distilbert == len(
+        [w for w in BM["workloads"] if w["config"].startswith("distilbert")])
     by_name = {w["name"]: w for w in BM["workloads"]}
     assert (by_name[OLMOE_CELL]["config"], by_name[OLMOE_CELL]["traffic"]
             ) == ("olmoe-1b-7b-s128", "s128-memo-saturated")
@@ -223,9 +292,32 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
                    "laguna_attn_core_roofline_pct",
                    "laguna_expert_ffn_roofline_pct",
                    "shared_expert_ms_per_batch", "expert_local_share_pct"}
+    assert (by_name[JOYAI_CELL]["config"], by_name[JOYAI_CELL]["traffic"],
+            by_name[JOYAI_CELL]["chips"]) == (
+        "joyai-llm-flash-s2048", "s2048-remit-saturated", 1)
+    joyai_only = {"attn_latent_ms_per_batch", "joyai_attn_core_roofline_pct",
+                  "joyai_expert_ffn_roofline_pct",
+                  "joyai_attn_latent_roofline_pct"}
     reports = {cell: {m["name"] for m in spec.metrics_for(cell, "per_layer")}
-               for cell in (OLMOE_CELL, ZAYA_CELL, FULL_CELL, LAGUNA_CELL,
-                            ZAYA_FULL_CELL)}
+               for cell in ROUTED_CELLS}
+    # JoyAI's: the shared routed names, its dense layer 0's, the shared
+    # expert's and its own four; neither OLMoE's, ZAYA1's nor Laguna's own
+    joyai_reports = reports.pop(JOYAI_CELL)
+    assert joyai_reports == (
+        (reports[OLMOE_CELL] - {"expert_ffn_roofline_pct",
+                                "router_roofline_pct"})
+        | {"ffn_ms_per_batch", "shared_expert_ms_per_batch"} | joyai_only)
+    assert common | {"compact_batches_pct"} <= joyai_reports
+    assert not (olmoe_only | zaya_only | (
+        laguna_only - {"shared_expert_ms_per_batch"})) & joyai_reports
+    for m in BM["per_layer"][-4:]:
+        # PR 43 appended its four behind everything
+        assert m["name"] in joyai_only and m["moves"] == "txn_per_s"
+        assert m["workloads"] == [JOYAI_CELL] and m["layer"] == "kernels"
+    assert [c["name"] for c in BM["configs"]][-1] == "joyai-llm-flash-s2048"
+    assert len(cells) == 7 and not [w for w in BM["workloads"]
+                                    if w["chips"] != 1]
+    per_layer = BM["per_layer"][:-4]     # the checks below: what PR 42 left
     # ZAYA1's second cell reports exactly what its first does
     assert reports[ZAYA_FULL_CELL] == reports[ZAYA_CELL]
     # Laguna's: the shared names, its dense layer 0's, and its own six
@@ -238,22 +330,23 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
     # PR 36 appended seven behind them: the first per-layer metrics under
     # setup_s, two scopes no metric read, and the two launch-rule counters
     assert [(m["name"], m["moves"], len(m["workloads"]))
-            for m in BM["per_layer"][-7:]] == [
-        ("compile_setup_s", "setup_s", 7),
-        ("trace_lower_setup_s", "setup_s", 7),
-        ("setup_programs", "setup_s", 7),
-        ("attn_proj_ms_per_batch", "txn_per_s", 7),
-        ("ln_ms_per_batch", "txn_per_s", 7),
-        ("split_batches_pct", "txn_per_s", 2),
-        ("compact_batches_pct", "txn_per_s", 5)]
+            for m in per_layer[-7:]] == [
+        ("compile_setup_s", "setup_s", n_cells),
+        ("trace_lower_setup_s", "setup_s", n_cells),
+        ("setup_programs", "setup_s", n_cells),
+        ("attn_proj_ms_per_batch", "txn_per_s", n_cells),
+        ("ln_ms_per_batch", "txn_per_s", n_cells),
+        ("split_batches_pct", "txn_per_s", n_distilbert),
+        ("compact_batches_pct", "txn_per_s", n_routed)]
     assert [m["name"] for m in BM["per_layer"] if m["moves"] == "setup_s"
             ] == ["compile_setup_s", "trace_lower_setup_s", "setup_programs"]
     assert "compact_batches_pct" in reports[OLMOE_CELL] \
         and "split_batches_pct" not in reports[OLMOE_CELL]
-    for m in BM["per_layer"][-13:-7]:
+    for m in per_layer[-13:-7]:
         assert m["name"] in laguna_only
-        assert m["workloads"] == [LAGUNA_CELL] and m["moves"] == "txn_per_s"
-    earlier = BM["per_layer"][:-13]  # the checks below: what PR 30 left
+        assert [w for w in m["workloads"] if w != JOYAI_CELL] \
+            == [LAGUNA_CELL] and m["moves"] == "txn_per_s"
+    earlier = per_layer[:-13]        # the checks below: what PR 30 left
     # OLMoE's second cell reports exactly what its first does
     assert reports[FULL_CELL] == reports[OLMOE_CELL] >= common | olmoe_only
     # OLMoE's kernel files read OLMoE's keys and byte model
@@ -266,7 +359,8 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
         # PR 33 appended its two cells' names behind what was there
         assert m["workloads"][0] == OLMOE_CELL and m["moves"] == "txn_per_s"
         assert [w for w in m["workloads"]
-                if w not in (LAGUNA_CELL, ZAYA_FULL_CELL)][-1] == FULL_CELL
+                if w not in (LAGUNA_CELL, ZAYA_FULL_CELL, JOYAI_CELL)
+                ][-1] == FULL_CELL
     for m in earlier[-4:]:
         assert m["name"] in zaya_only
         assert m["workloads"] == [ZAYA_CELL, ZAYA_FULL_CELL] \
@@ -553,10 +647,133 @@ def test_the_laguna_metrics_on_a_hand_made_run():
                            "per_layer")(older) is None
 
 
+def test_joyai_matmul_flops_follow_the_latent_block():
+    builder = spec.builder(JOYAI_CFG)
+    parts = builder.text_matmul_flops_per_row(JOYAI_CFG)
+    t, h = 2048, 2048
+    assert parts["latent"] == 5 * 2.0 * t * h * (1536 + 512 + 64)
+    assert parts["projections"] == 5 * 2.0 * t * 32 * (
+        1536 * 192 + 512 * 256 + 128 * 2048)
+    # a visible pair: a score over 192 and a value of 128, a head
+    assert parts["cores"] == 5 * 2.0 * 32 * 320 * (t * (t + 1) // 2)
+    assert parts["dense_mlp"] == 6.0 * t * h * 7168          # layer 0 alone
+    assert parts["experts"] == 4 * 6.0 * t * h * 768 * 8
+    assert parts["shared_expert"] == 4 * 6.0 * t * h * 768
+    assert parts["router"] == 4 * 2.0 * t * h * 256
+    # ISSUE 43's per-token arithmetic: 52.7 MFLOP in the six attention
+    # matmuls, 75.5 in eight experts, 9.4 in the shared one, a sparse layer
+    assert (parts["latent"] + parts["projections"]) / (5 * t) \
+        == pytest.approx(52.7e6, rel=2e-3)
+    assert parts["experts"] / (4 * t) == pytest.approx(75.5e6, rel=2e-3)
+    assert parts["shared_expert"] / (4 * t) == pytest.approx(9.4e6, rel=5e-3)
+    total = builder.matmul_flops_per_batch(JOYAI_CFG)
+    assert 0.99 < 8 * sum(parts.values()) / total <= 1.0
+    assert "stale kind" in builder.matmul_flops_per_batch.__doc__
+
+
+def test_joyai_kernels_charge_what_the_program_counted():
+    slots, real = 3 * 8 * 2048, 30_000
+    counters = {"batches": 3, "token_slots": slots,
+                "attn_visible_pairs_full": 24_000_000,
+                "attn_visible_pairs_sliding": 0,
+                "expert_rows": real * 8 * 4, "routed_pairs": real * 8 * 4}
+    core = spec.kernel("joyai_attn_core").work(counters, JOYAI_CFG)
+    # visible pairs of real tokens: 2 x (192 + 128) FLOP a pair and head,
+    # 32 heads, five layers
+    assert core["flops"] == 2 * 320 * 32 * 5 * 24_000_000
+    assert core["hbm_bytes"] == 5 * slots * (
+        32 * 4 * 128 * 2 + 33 * 64 * 4)
+    ffn = spec.kernel("joyai_expert_ffn").work(counters, JOYAI_CFG)
+    assert ffn["flops"] == 6 * real * 8 * 4 * 2048 * 768
+    weights = 3 * 4 * 256 * 3 * 2048 * 768 * 2
+    # what any implementation moves: the weights once a launch and layer, a
+    # row's bfloat16 input read once, its float32 down result written; the
+    # three-call form's round trips between its calls are not charged
+    assert ffn["hbm_bytes"] == weights + real * 8 * 4 * (2048 * 2 + 2048 * 4)
+    # ~310 rows an expert: under the v5e's ridge of 240 FLOP a byte, so the
+    # metric file names the HBM's rate
+    assert 100 < ffn["flops"] / ffn["hbm_bytes"] < 240
+    assert json.loads((ROOT / "benchmarks/layer_metrics/"
+                       "joyai_expert_ffn_roofline_pct.json").read_text()
+                      )["args"]["peak"] == "hbm_bytes_per_s"
+    latent = spec.kernel("joyai_attn_latent").work(counters, JOYAI_CFG)
+    assert latent["flops"] == 2 * 2048 * (1536 + 576) * 5 * slots
+    assert latent["flops"] / latent["hbm_bytes"] > 240    # compute-bound
+    for kernel in ("joyai_attn_core", "joyai_expert_ffn",
+                   "joyai_attn_latent"):
+        none = spec.kernel(kernel).work({"batches": 3}, JOYAI_CFG)
+        assert none == {"flops": 0.0, "hbm_bytes": 0.0}, kernel
+
+
+def test_the_joyai_metrics_on_a_hand_made_run():
+    real = 10_000
+    counters = {"batches": 2, "scored": 16, "token_slots": 2 * 8 * 2048,
+                "real_tokens": 2 * real, "expert_token_slots": 2 * 12288,
+                "routed_pairs": 2 * real * 8 * 4,
+                "expert_rows": 2 * real * 8 * 4,
+                "expert_peak_rows": 4 * real * 8 * 4,
+                "compact_batches": 2,
+                "attn_visible_pairs_full": 16_000_000,
+                "attn_visible_pairs_sliding": 0}
+    scope_s = {"text": 0.25}
+    for i in range(5):
+        scope_s.update({f"text/layer{i}/attn_core": 0.005,
+                        f"text/layer{i}/attn_latent": 0.003,
+                        f"text/layer{i}/attn_proj": 0.009})
+    scope_s["text/layer0/ffn"] = 0.018
+    for i in range(1, 5):
+        scope_s.update({
+            f"text/layer{i}/experts": 0.024,
+            f"text/layer{i}/experts/matmul": 0.016,
+            f"text/layer{i}/router": 0.004,
+            f"text/layer{i}/shared_expert": 0.0015})
+    run = _fake_run(scope_s, counters, JOYAI_CFG)
+
+    def metric(name):
+        return spec.reader_for(name, "per_layer")(run)
+
+    assert metric("attn_latent_ms_per_batch") == pytest.approx(7.5)
+    assert metric("attn_core_ms_per_batch") == pytest.approx(12.5)
+    assert metric("attn_proj_ms_per_batch") == pytest.approx(22.5)
+    assert metric("ffn_ms_per_batch") == pytest.approx(9.0)
+    assert metric("shared_expert_ms_per_batch") == pytest.approx(3.0)
+    assert metric("expert_ffn_ms_per_batch") == pytest.approx(48.0)
+    assert metric("expert_matmul_ms_per_batch") == pytest.approx(32.0)
+    assert metric("router_ms_per_batch") == pytest.approx(8.0)
+    assert metric("expert_imbalance_x") == pytest.approx(2.0)
+    assert metric("compact_batches_pct") == pytest.approx(100.0)
+    for name, kernel, quantity, peak, seconds in (
+            ("joyai_attn_core_roofline_pct", "joyai_attn_core", "flops",
+             197e12, 0.025),
+            ("joyai_expert_ffn_roofline_pct", "joyai_expert_ffn",
+             "hbm_bytes", 819e9, 0.064),
+            ("joyai_attn_latent_roofline_pct", "joyai_attn_latent", "flops",
+             197e12, 0.015)):
+        needs = spec.kernel(kernel).work(counters, JOYAI_CFG)[quantity]
+        assert 0 < metric(name) < 100, name
+        assert metric(name) == pytest.approx(
+            100 * needs / peak / seconds), name
+    # against a program without the scope and the counters every new metric
+    # is left out and none raises
+    parent = _fake_run({"text": 0.9, "text/layer1/router": 0.1},
+                       {"batches": 2, "scored": 16}, JOYAI_CFG)
+    for name in ("attn_latent_ms_per_batch", "joyai_attn_core_roofline_pct",
+                 "joyai_expert_ffn_roofline_pct",
+                 "joyai_attn_latent_roofline_pct"):
+        assert spec.reader_for(name, "per_layer")(parent) is None, name
+    counted = _fake_run({"text": 0.9, "text/layer1/attn_core": 0.1,
+                         "text/layer1/experts/matmul": 0.1},
+                        {"batches": 2, "scored": 16}, JOYAI_CFG)
+    for name in ("joyai_attn_core_roofline_pct",
+                 "joyai_expert_ffn_roofline_pct"):
+        assert spec.reader_for(name, "per_layer")(counted) is None, name
+
+
 # ------------------------------------------------ a program without the module
 @pytest.mark.parametrize("cfg,module", [(OLMOE_CFG, "olmoe"),
                                         (ZAYA_CFG, "zaya"),
-                                        (LAGUNA_CFG, "laguna")])
+                                        (LAGUNA_CFG, "laguna"),
+                                        (JOYAI_CFG, "joyai")])
 def test_the_builder_stops_at_once_on_a_program_without_the_encoder(
         monkeypatch, cfg, module):
     import importlib.util
@@ -572,7 +789,8 @@ def test_the_builder_stops_at_once_on_a_program_without_the_encoder(
 
 @pytest.mark.parametrize("cell,module", [(OLMOE_CELL, "olmoe"),
                                          (ZAYA_CELL, "zaya"),
-                                         (LAGUNA_CELL, "laguna")])
+                                         (LAGUNA_CELL, "laguna"),
+                                         (JOYAI_CELL, "joyai")])
 def test_the_parent_exits_non_zero_within_seconds(tmp_path, cell, module):
     """A checkout of the benchmark without the program's new module — what
     the driver's parent run of a new configuration's cell is — prints no
@@ -612,7 +830,8 @@ def tiny_copy(tmp_path_factory):
 
 @pytest.mark.parametrize("cell,trace", [
     (OLMOE_CELL, 0), (OLMOE_CELL, 1), (ZAYA_CELL, 0), (ZAYA_CELL, 1),
-    (FULL_CELL, 1), (LAGUNA_CELL, 0), (LAGUNA_CELL, 1), (ZAYA_FULL_CELL, 1)])
+    (FULL_CELL, 1), (LAGUNA_CELL, 0), (LAGUNA_CELL, 1), (ZAYA_FULL_CELL, 1),
+    (JOYAI_CELL, 0), (JOYAI_CELL, 1)])
 def test_tiny_rehearsal_of_a_routed_cell(tiny_copy, cell, trace):
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(ROOT),
                XLA_FLAGS="--xla_force_host_platform_device_count=1")
@@ -631,7 +850,11 @@ def test_tiny_rehearsal_of_a_routed_cell(tiny_copy, cell, trace):
         # counters are read on any backend; device scopes need the chip
         assert out["metrics"]["expert_imbalance_x"]["value"] >= 1.0
         padding = out["metrics"]["token_padding_pct"]["value"]
-        if cell == LAGUNA_CELL:
+        if cell == JOYAI_CELL:
+            # every slot real, as Laguna's; every expert held: no share
+            assert padding == 0.0
+            assert "expert_local_share_pct" not in out["metrics"]
+        elif cell == LAGUNA_CELL:
             # the rehearsal's 128 positions are under the mix's shortest
             # text: every slot real; a quarter of the pairs held here
             assert padding == 0.0
@@ -644,7 +867,8 @@ def test_tiny_rehearsal_of_a_routed_cell(tiny_copy, cell, trace):
                 else (25 < padding < 60)
         for name in ("expert_ffn_ms_per_batch", "cca_mix_ms_per_batch",
                      "cca_mix_roofline_pct", "laguna_attn_core_roofline_pct",
-                     "shared_expert_ms_per_batch"):
+                     "shared_expert_ms_per_batch", "attn_latent_ms_per_batch",
+                     "joyai_attn_core_roofline_pct"):
             assert name not in out["metrics"]
     else:
         assert set(out["metrics"]) == {"txn_per_s", "setup_s"}

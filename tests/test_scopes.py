@@ -136,7 +136,8 @@ def _lowered_asm(bert_config):
 
 
 @pytest.mark.parametrize("builder", ["ensemble_builder", "olmoe_builder",
-                                     "zaya1_builder", "laguna_builder"])
+                                     "zaya1_builder", "laguna_builder",
+                                     "joyai_builder"])
 def test_every_name_in_a_builders_vocabulary_is_one_the_program_writes(
         builder):
     """A builder (``benchmarks/configs/<builder>.py``) writes the device
@@ -181,6 +182,14 @@ def test_every_name_in_a_builders_vocabulary_is_one_the_program_writes(
             config, layer_parts = TINY_LAGUNA, scopes.LAGUNA_LAYER_SCOPES
             assert set(layer_parts) == set(scopes.MOE_LAYER_SCOPES) | {
                 scopes.FFN, scopes.SHARED_EXPERT}
+        elif builder == "joyai_builder":
+            from realtime_fraud_detection_tpu.models.joyai import TINY_JOYAI
+
+            # Laguna's names and what latent attention puts in front of
+            # the projections
+            config, layer_parts = TINY_JOYAI, scopes.JOYAI_LAYER_SCOPES
+            assert set(layer_parts) == set(scopes.LAGUNA_LAYER_SCOPES) | {
+                scopes.ATTN_LATENT}
         else:
             from realtime_fraud_detection_tpu.models.zaya import TINY_ZAYA
 
