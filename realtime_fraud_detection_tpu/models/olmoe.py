@@ -22,9 +22,10 @@ The layer equations are those of ``allenai/OLMoE-1B-7B-0125-Instruct``'s
   the branch emits a class probability, not tokens).
 
 The expert layer is computed the dropless way: the (token, expert) pairs are
-sorted by expert, the tokens' rows gathered into that order, three grouped
-matmuls (``ops/grouped_matmul.py``) run over the ragged groups, and the rows
-are gathered home and summed with their router weights. ``route`` and
+sorted by expert, the tokens' rows gathered into that order, the experts run
+over the ragged groups as two grouped calls (``ops/grouped_matmul.py``: gate,
+up and the SiLU ⊙ product in one, then down), and the rows are gathered home
+and summed with their router weights. ``route`` and
 ``apply_experts`` are the two halves, held separately by the tests.
 
 Only the batch's REAL tokens are routed. Nothing a padding position computes
@@ -68,7 +69,10 @@ from realtime_fraud_detection_tpu.ops.attention import (
     windowed_attention,
     windowed_refusal,
 )
-from realtime_fraud_detection_tpu.ops.grouped_matmul import grouped_matmul
+from realtime_fraud_detection_tpu.ops.grouped_matmul import (
+    grouped_gated_matmul,
+    grouped_matmul,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -277,6 +281,13 @@ def apply_experts(layer: Dict, x: jax.Array, experts: jax.Array,
     None: every row) admits is computed; the other rows' pairs enter no
     group and their result is zero.
 
+    **Two grouped calls.** ``grouped_gated_matmul`` takes the sorted rows to
+    ``silu(gate) * up`` in ``down_proj``'s dtype — with ``use_pallas``, at a
+    shape ``grouped_matmul_supported`` admits, ONE kernel that reads the rows
+    once and keeps both float32 results in VMEM; else two ``ragged_dot``
+    calls and the product — and ``grouped_matmul`` takes that through
+    ``down_proj``. Neither writes a row past the last group.
+
     **A share of the experts.** The layer holds the experts its stacked
     weights hold: ``gate_proj.shape[0]`` of the ``router_width`` the router
     chose among (None: all of them), those numbered ``expert_offset`` on.
@@ -318,9 +329,10 @@ def apply_experts(layer: Dict, x: jax.Array, experts: jax.Array,
         rows = x.astype(layer["gate_proj"].dtype)[order // top_k]  # [N*k, H]
     with jax.named_scope(scopes.EXPERTS_MATMUL):
         gm = dict(use_pallas=use_pallas, interpret=kernel_interpret)
-        gate = grouped_matmul(rows, layer["gate_proj"], group_sizes, **gm)
-        up = grouped_matmul(rows, layer["up_proj"], group_sizes, **gm)
-        act = (jax.nn.silu(gate) * up).astype(layer["down_proj"].dtype)
+        # gate, up and SiLU ⊙ in one call, rounded once to what down reads
+        act = grouped_gated_matmul(
+            rows, layer["gate_proj"], layer["up_proj"], group_sizes,
+            out_dtype=layer["down_proj"].dtype, **gm)
         out = grouped_matmul(act, layer["down_proj"], group_sizes, **gm)
     with jax.named_scope(scopes.EXPERTS_COMBINE):
         if share:

@@ -20,9 +20,13 @@ a predicate admits holds the kernel, everything else the XLA form.
   and the shifted v out) against ``models.zaya.cca_mix``;
   ``cca_mix_refusal`` (None where the shape is taken, else why not), asked
   through ``ZayaConfig.mix_refusal``.
-- ``grouped_matmul``: ``grouped_matmul`` (the routed encoders' expert
-  matmuls, ``megablox.gmm`` with per-shape tilings) against
-  ``jax.lax.ragged_dot``; ``grouped_matmul_supported``.
+- ``grouped_matmul``: the routed encoders' expert matmuls, two kernels a
+  sparse layer. ``grouped_gated_matmul`` (gate, up and the SiLU ⊙ product
+  as ONE grouped kernel, ``gated_gmm``: two right-hand blocks a step, the
+  product rounded once in the epilogue) against two ``jax.lax.ragged_dot``
+  calls and the product; ``grouped_matmul`` (down: ``megablox.gmm`` with
+  per-shape tilings) against ``ragged_dot``; ``grouped_matmul_supported``,
+  the one predicate of both.
 - ``dequant_matmul``, ``epilogue``: the int8 text branch's fused
   dequant-matmul and the score-and-blend epilogue, behind ``KernelSettings``.
 """
@@ -58,6 +62,7 @@ from realtime_fraud_detection_tpu.ops.epilogue import (  # noqa: F401
     fused_epilogue,
 )
 from realtime_fraud_detection_tpu.ops.grouped_matmul import (  # noqa: F401
+    grouped_gated_matmul,
     grouped_matmul,
     grouped_matmul_reference,
     grouped_matmul_supported,

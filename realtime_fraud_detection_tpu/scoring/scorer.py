@@ -67,9 +67,10 @@ from realtime_fraud_detection_tpu.state.stores import (
     VelocityStore,
 )
 from realtime_fraud_detection_tpu.utils.config import (
+    EXPERT_GATE_UP_SITE,
+    VALID_KERNEL_SITES,
     Config,
     KernelSettings,
-    VALID_KERNEL_SITES,
 )
 
 
@@ -464,9 +465,13 @@ class FraudScorer:
                 f"(interpreted) mesh; this scorer's devices are {platform!r}")
         self._platform = platform
         self._kernel_interpret = platform == "cpu"
+        # (a routed encoder's launches are also counted at its experts'
+        # gate + up + SiLU site, which the dense encoder does not have)
+        sites = VALID_KERNEL_SITES + (
+            (EXPERT_GATE_UP_SITE,) if self._moe_text else ())
         self._kernel_counts: Dict[str, Dict[str, int]] = {
-            "dispatch": {s: 0 for s in VALID_KERNEL_SITES},
-            "fallback": {s: 0 for s in VALID_KERNEL_SITES},
+            "dispatch": {s: 0 for s in sites},
+            "fallback": {s: 0 for s in sites},
         }
         # memoized static-kwarg tuples (kernel_static/quant_static): the
         # hot dispatch path does a dict lookup instead of rebuilding the
@@ -1003,17 +1008,23 @@ class FraudScorer:
                 and grouped_matmul_supported(rows, c.intermediate_size,
                                              c.hidden_size))
 
-    def _record_kernel_dispatch(self, size: int, text_len: int) -> None:
+    def _record_kernel_dispatch(self, size: int, text_len: int,
+                                capacity: Optional[int] = None) -> None:
         """Host-side mirror of the per-site kernel engagement for one
         launch of ``size`` rows at ``text_len`` positions (a split batch
-        records each of its two). A site counts as dispatched when its mode asks
+        records each of its two), its routed blocks at ``capacity`` token
+        slots (None: every slot). A site counts as dispatched when its mode asks
         for the Pallas kernel, and as a fallback when the shape/layout
         guard the TRACED code consults (the shared supports() predicates)
         routes it back to the XLA path — so ``kernel_fallback_total``
         reports exactly what the compiled program did, without a device
         readback. The attention site counts EVERY launch, plane on or off:
         dispatched where the program holds the fused core, a fallback
-        where the selector or the guard sent it to the reference."""
+        where the selector or the guard sent it to the reference. So does
+        ``expert_gate_up`` for every launch of a routed encoder: dispatched
+        where its sparse layers hold the fused gate + up + SiLU kernel
+        (``ops.grouped_gated_matmul``), a fallback where they run the
+        three-call form."""
         disp, fall = (self._kernel_counts["dispatch"],
                       self._kernel_counts["fallback"])
         asked = self.effective_use_pallas(
@@ -1022,6 +1033,16 @@ class FraudScorer:
             disp["attention"] += 1
         else:
             fall["attention"] += 1
+        if self._moe_text:
+            from realtime_fraud_detection_tpu.ops import (
+                grouped_matmul_supported,
+            )
+
+            c = self.bert_config
+            rows = (capacity or size * text_len) * c.num_experts_per_tok
+            fused = asked and grouped_matmul_supported(
+                rows, c.hidden_size, c.intermediate_size)
+            (disp if fused else fall)[EXPERT_GATE_UP_SITE] += 1
         if not self.kernels.enabled:
             return
         from realtime_fraud_detection_tpu.models.quant import (
@@ -1371,7 +1392,8 @@ class FraudScorer:
             mv = self.effective_model_valid()
             rules_only = self._qos_rules_only
             for launch in launches:
-                self._record_kernel_dispatch(launch.size, launch.width)
+                self._record_kernel_dispatch(launch.size, launch.width,
+                                             launch.capacity)
             token = None
             if self._pool is not None:
                 # pooled mode: the whole microbatch runs on ONE replica

@@ -712,6 +712,12 @@ class QuantSettings:
 
 
 VALID_KERNEL_SITES = ("dequant_matmul", "epilogue", "attention")
+# one more site that ``FraudScorer.kernel_snapshot`` counts launches under,
+# for a routed text encoder alone, and that nothing sets: the experts' gate +
+# up + SiLU (``ops.grouped_gated_matmul``: the fused kernel wherever a routed
+# program is asked for its kernels and ``grouped_matmul_supported`` takes the
+# rows)
+EXPERT_GATE_UP_SITE = "expert_gate_up"
 VALID_KERNEL_MODES = ("off", "pallas")
 VALID_ATTENTION_KERNELS = ("reference", "flash")
 

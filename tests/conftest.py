@@ -53,3 +53,51 @@ def mesh8():
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture(scope="session")
+def experts_through_both_forms():
+    """``check(layer, top_k=, rung=, atol=, ...)``: one sparse layer's
+    ``models.olmoe.apply_experts`` with its kernels asked for (Pallas,
+    interpreted: the fused gate + up + SiLU kernel, then ``megablox.gmm``)
+    against its XLA form, at one of the two capacities of a launch of 4,096
+    slots (``text_split.capacities``: rung 0 three quarters, 1 every slot) on
+    random rows of which the first 2,800 are real (a rung's ``token_slots``),
+    each with ``top_k`` distinct experts of the router's width. The rows the
+    kernels never wrote (interpret mode leaves them NaN) must reach nothing.
+    Returns the group sizes."""
+    import jax.numpy as jnp
+
+    from realtime_fraud_detection_tpu.models.olmoe import apply_experts
+    from realtime_fraud_detection_tpu.ops import grouped_matmul_supported
+    from realtime_fraud_detection_tpu.scoring.text_split import capacities
+
+    real_tokens = 2800
+
+    def check(layer, *, top_k, rung, atol, router_width=None,
+              expert_offset=0):
+        slots = capacities(4096)[rung]
+        held, hidden, wide = layer["gate_proj"].shape
+        assert grouped_matmul_supported(slots * top_k, hidden, wide)
+        rng = np.random.default_rng(0)
+        x = jnp.asarray(rng.standard_normal((slots, hidden)), jnp.float32)
+        experts = jnp.asarray(np.argsort(rng.random(
+            (slots, router_width or held)), axis=-1)[:, :top_k], jnp.int32)
+        weights = jnp.asarray(rng.random((slots, top_k)), jnp.float32)
+        real = jnp.arange(slots) < real_tokens
+        share = dict(router_width=router_width, expert_offset=expert_offset)
+        want, sizes = apply_experts(layer, x, experts, weights, real=real,
+                                    **share)
+        got, sizes_k = apply_experts(layer, x, experts, weights, real=real,
+                                     use_pallas=True, kernel_interpret=True,
+                                     **share)
+        np.testing.assert_array_equal(sizes, sizes_k)
+        assert int(sizes.sum()) <= real_tokens * top_k < slots * top_k
+        assert np.isfinite(np.asarray(got)).all()
+        assert not np.asarray(got)[real_tokens:].any()
+        scale = float(np.abs(np.asarray(want)).max())
+        assert scale > 0
+        np.testing.assert_allclose(got, want, atol=atol * scale, rtol=0)
+        return np.asarray(sizes)
+
+    return check

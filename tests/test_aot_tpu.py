@@ -268,6 +268,45 @@ def test_grouped_matmul_compiles_at_published_widths(one_chip, rows, groups,
     assert 'op_name="ragged-dot' in xla
 
 
+# (rows at the 3/4 rung, at every slot; groups; K; N): what each routed cell
+# launches — OLMoE bucket 256 x 128 tokens x 8 experts, ZAYA1 the same slots
+# x 1, Laguna 8 x 2,048 x 10 with 64 of 256 experts held, JoyAI 8 x 2,048 x 8
+GATED_SITES = {"olmoe": (196608, 262144, 64, 2048, 1024),
+               "zaya1": (24576, 32768, 16, 2048, 2048),
+               "laguna": (122880, 163840, 64, 3072, 1024),
+               "joyai": (98304, 131072, 256, 2048, 768)}
+
+
+@pytest.mark.parametrize("rung", [0, 1], ids=["three_quarters", "every_slot"])
+@pytest.mark.parametrize("encoder", sorted(GATED_SITES))
+def test_gated_matmul_compiles_at_published_widths(one_chip, encoder, rung):
+    """gate, up and SiLU ⊙ as ONE Mosaic call at the tiles ``gmm_tiling``
+    picks, for all four routed encoders at both capacities: two right-hand
+    blocks a step double the weights' share of VMEM — at (512, 2048, 512)
+    past the 16 MB a call gets unasked — so the call names its own budget,
+    and what Mosaic refuses for VMEM it refuses here."""
+    from realtime_fraud_detection_tpu.ops.grouped_matmul import (
+        gmm_tiling,
+        grouped_gated_matmul,
+        grouped_matmul_supported,
+    )
+
+    *rungs, groups, k, n = GATED_SITES[encoder]
+    rows = rungs[rung]
+    assert grouped_matmul_supported(rows, k, n)
+    assert gmm_tiling(rows, k, n)[0] == 512
+    fn = jax.jit(lambda x, a, b, g: grouped_gated_matmul(
+        x, a, b, g, out_dtype=jnp.bfloat16, use_pallas=True))
+    weights = _sds((groups, k, n), jnp.bfloat16, one_chip)
+    text = fn.lower(_sds((rows, k), jnp.bfloat16, one_chip), weights, weights,
+                    _sds((groups,), jnp.int32, one_chip)).compile().as_text()
+    assert text.count(CUSTOM_CALL) == 1
+    assert "jit(gated_gmm)/gated_gmm/pallas_call" in text
+    # neither float32 product exists outside the kernel
+    assert f"f32[{rows},{n}]" not in text
+    assert f"bf16[{rows},{n}]" in text
+
+
 @pytest.mark.parametrize("bucket,text_len", [
     (BUCKET, 128), (8, 128), (1, 128), (32, DEPLOYED_TEXT_LEN)],
     ids=["bucket256", "parity_bucket8", "bucket1", "halo_at_512"])
@@ -317,7 +356,9 @@ def test_routed_program_compiles_with_every_large_pass_under_a_scope(
     (two of the published layers, every width as published, bucket 256 x 128
     tokens), at both capacities of that bucket's routed blocks
     (``scoring/text_split.capacities``):
-    four Mosaic calls a layer (the three grouped expert matmuls and, at the
+    three Mosaic calls a layer (the experts' two — gate, up and SiLU ⊙ as
+    ``gated_gmm``, down as ``megablox.gmm``, with no float32 ``[rows, I]``
+    between them — and, at the
     attention site, OLMoE's fused causal core ``windowed_attention`` or
     ZAYA1's fused mixing ``ops/cca_mix.py``), a second small output,
     temporaries that
@@ -359,11 +400,23 @@ def test_routed_program_compiles_with_every_large_pass_under_a_scope(
         blob_bf16=_shapes_of(blobs["bf16"], one_chip), bert_config=config,
         use_pallas=True, text_capacity=capacity).compile()
     text = compiled.as_text()
-    # the three grouped expert matmuls, and the attention site's kernel
-    assert text.count(CUSTOM_CALL) == 4 * config.num_hidden_layers
+    # the experts' two grouped kernels, and the attention site's kernel
+    assert text.count(CUSTOM_CALL) == 3 * config.num_hidden_layers
     site = "cca_mix" if encoder == "zaya1" else "windowed_attention"
-    assert len(re.findall(rf"%{site}\S* = .*custom-call\(", text)) == (
-        config.num_hidden_layers)
+    for kernel in (site, "gated_gmm"):
+        assert len(re.findall(rf"%{kernel}\S* = .*custom-call\(", text)) == (
+            config.num_hidden_layers)
+    # both under the scope the trace's attribution reads them by
+    for call in ("jit(gated_gmm)/gated_gmm/pallas_call",
+                 "jit(gmm)/pallas_call"):
+        assert len(re.findall(
+            rf'op_name="[^"]*/experts/matmul/{re.escape(call)}"', text)) == (
+                config.num_hidden_layers), call
+    if encoder == "olmoe":
+        # (ZAYA1's experts are as wide as its hidden state: down's result
+        # has that shape)
+        rows = (capacity or BUCKET * 128) * config.num_experts_per_tok
+        assert f"f32[{rows},{config.intermediate_size}]" not in text
     assert " conditional(" not in text and "cond/branch_" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 5 << 30
     entry = text[text.index("ENTRY "):]
@@ -480,8 +533,9 @@ def test_laguna_program_compiles_with_its_unlike_layers(one_chip):
     """The served packed program with a ``LagunaConfig``: layer 0 (full
     attention, the dense MLP) and one sliding sparse layer holding 64 of 256
     experts, every width as published, bucket 8 x 2,048 tokens at the
-    three-quarters capacity: two fused cores and three grouped matmuls, a
-    second small output, no conditional, temporaries that leave room for the
+    three-quarters capacity: two fused cores and the experts' two grouped
+    kernels, a second small output, no conditional, temporaries that leave
+    room for the
     cell's five layers of weights in 16 GB."""
     from realtime_fraud_detection_tpu.core.packing import pack_tree
     from realtime_fraud_detection_tpu.ensemble.combine import EnsembleParams
@@ -519,7 +573,8 @@ def test_laguna_program_compiles_with_its_unlike_layers(one_chip):
         blob_bf16=_shapes_of(blobs["bf16"], one_chip), bert_config=config,
         use_pallas=True, text_capacity=12288).compile()
     text = compiled.as_text()
-    assert text.count(CUSTOM_CALL) == 2 + 3
+    assert text.count(CUSTOM_CALL) == 2 + 2
+    assert text.count("jit(gated_gmm)/gated_gmm/pallas_call") == 1
     assert text.count("windowed_attention") >= 2
     assert " conditional(" not in text and "cond/branch_" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 6 << 30
@@ -564,7 +619,8 @@ def test_joyai_program_compiles_with_all_256_experts(one_chip, capacity):
     """The served packed program with a ``JoyaiConfig``: layer 0 (the dense
     MLP) and one sparse layer holding all 256 experts, every width as
     published, bucket 8 x 2,048 tokens at both capacities: two fused latent
-    cores and three grouped matmuls, a second small output, no conditional,
+    cores and the experts' two grouped kernels, a second small output, no
+    conditional,
     no ``[8, 32, 2048, 2048]`` scores, temporaries that leave room for the
     cell's 10.6 GB of weights in 16 GB."""
     from realtime_fraud_detection_tpu.core.packing import pack_tree
@@ -594,7 +650,8 @@ def test_joyai_program_compiles_with_all_256_experts(one_chip, capacity):
         blob_bf16=_shapes_of(blobs["bf16"], one_chip), bert_config=config,
         use_pallas=True, text_capacity=capacity).compile()
     text = compiled.as_text()
-    assert text.count(CUSTOM_CALL) == 2 + 3
+    assert text.count(CUSTOM_CALL) == 2 + 2
+    assert text.count("jit(gated_gmm)/gated_gmm/pallas_call") == 1
     assert text.count("windowed_attention") >= 2
     assert " conditional(" not in text and "cond/branch_" not in text
     assert "f32[8,32,2048,2048]" not in text

@@ -456,6 +456,21 @@ def test_the_grouped_matmul_takes_256_narrow_groups():
                                atol=2e-2, rtol=2e-2)
 
 
+@pytest.mark.parametrize("rung", [0, 1], ids=["three_quarters", "every_slot"])
+def test_apply_experts_is_the_same_through_the_kernels(
+        experts_through_both_forms, rung):
+    """256 groups of ~44 rows, each ending inside a row tile (a tile is
+    visited by a dozen groups), at both capacities of a launch of 4,096
+    slots: the fused gate + up + SiLU kernel and ``megablox.gmm``
+    (interpreted) against the XLA form."""
+    cfg = dataclasses.replace(LANE_CFG, n_routed_experts=256,
+                              num_experts_per_tok=4)
+    layer = init_joyai_params(jax.random.PRNGKey(2), cfg)["layers"][1]
+    assert layer["gate_proj"].shape == (256, 128, 128)
+    sizes = experts_through_both_forms(layer, top_k=4, rung=rung, atol=1e-2)
+    assert sizes.sum() == 2800 * 4 and (sizes % 128 != 0).all()
+
+
 def test_the_shared_term_is_in_the_scores_and_the_scale_is_192s():
     """With values that read the weights off: dropping the shared term, or
     scaling by 128^-1/2, is another softmax."""

@@ -531,6 +531,25 @@ def test_the_encoder_is_the_same_through_the_kernels():
     np.testing.assert_array_equal(np.asarray(stats)[1], np.asarray(stats_k)[1])
 
 
+@pytest.mark.parametrize("rung", [0, 1], ids=["three_quarters", "every_slot"])
+def test_apply_experts_is_the_same_through_the_kernels(
+        experts_through_both_forms, rung):
+    """A SHARE of the experts (four of the router's eight, numbered 4 on),
+    at both capacities of a launch of 4,096 slots: the pairs of absent
+    experts are keyed past the last group, so about half the launched rows
+    enter none and the fused gate + up + SiLU kernel and ``megablox.gmm``
+    (interpreted) never write them; against the XLA form."""
+    layer = init_laguna_params(jax.random.PRNGKey(2), KERNEL_CFG)["layers"][1]
+    assert layer["gate_proj"].shape == (4, 128, 128)
+    top_k = KERNEL_CFG.num_experts_per_tok
+    sizes = experts_through_both_forms(
+        layer, top_k=top_k, rung=rung, atol=1e-2,
+        router_width=KERNEL_CFG.router_experts,
+        expert_offset=KERNEL_CFG.expert_offset)
+    # the held half of the real tokens' pairs, give or take
+    assert 0.4 < sizes.sum() / (2800 * top_k) < 0.6 and sizes.min() > 0
+
+
 # ------------------------------------------------------ what must not move
 def test_a_padding_slot_changes_no_real_tokens_answer(params32, text):
     ids, mask = text

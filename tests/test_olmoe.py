@@ -29,6 +29,7 @@ from realtime_fraud_detection_tpu.models.olmoe import (
 )
 from realtime_fraud_detection_tpu.ops import (
     attention_reference,
+    grouped_gated_matmul,
     grouped_matmul,
     grouped_matmul_supported,
 )
@@ -314,6 +315,43 @@ def test_grouped_matmul_against_a_loop_over_experts(case, form):
                                atol=1e-4, rtol=0)
 
 
+# the SAME groups; the last case launches 64 rows more than the groups hold
+GATED_GROUPS = dict(GROUPS, fewer_rows_than_launched=[100, 0, 37, 55])
+LAUNCHED = 256
+
+
+@pytest.mark.parametrize("stored,atol", [("float32", 1e-4),
+                                         ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("form,k", [
+    ("xla", 128), ("pallas_interpret", 128), ("pallas_interpret", 384)],
+    ids=["xla", "pallas_one_k_step", "pallas_three_k_steps"])
+@pytest.mark.parametrize("case", sorted(GATED_GROUPS))
+def test_grouped_gated_matmul_against_a_loop_over_experts(case, form, k,
+                                                          stored, atol):
+    """gate, up and SiLU ⊙ as one call: both forms, whole K in one step and
+    accumulated over three, against ``silu(x @ gate_e) * (x @ up_e)`` expert
+    by expert in NumPy; rounded once to the weights' dtype."""
+    sizes = GATED_GROUPS[case]
+    held, n = sum(sizes), 256
+    dtype = jnp.dtype(stored)
+    keys = jax.random.split(jax.random.PRNGKey(len(case)), 3)
+    lhs = jax.random.normal(keys[0], (LAUNCHED, k), F32).astype(dtype)
+    gate_w, up_w = (
+        (jax.random.normal(key, (len(sizes), k, n), F32) * 0.1).astype(dtype)
+        for key in keys[1:])
+    assert grouped_matmul_supported(LAUNCHED, k, n)
+    assert gmm_tiling(LAUNCHED, k, n)[1] == 128       # K / 128 steps
+    got = grouped_gated_matmul(
+        lhs, gate_w, up_w, jnp.asarray(sizes, jnp.int32), out_dtype=dtype,
+        use_pallas=form != "xla", interpret=True)
+    assert got.dtype == dtype and got.shape == (LAUNCHED, n)
+    gate = _loop_over_experts(lhs, gate_w, sizes)
+    want = gate / (1.0 + np.exp(-gate)) * _loop_over_experts(lhs, up_w, sizes)
+    # the rows past the last group are whatever the buffer held
+    np.testing.assert_allclose(np.asarray(got, np.float32)[:held], want,
+                               atol=atol, rtol=atol)
+
+
 def test_the_kernel_declines_what_it_cannot_tile():
     assert not grouped_matmul_supported(100, 128, 128)     # rows off a tile
     assert not grouped_matmul_supported(256, 128, 64)      # N under a lane
@@ -325,9 +363,15 @@ def test_the_kernel_declines_what_it_cannot_tile():
     # an unsupported shape asked for the kernel runs the XLA form
     lhs = jnp.ones((100, 128), jnp.bfloat16)
     rhs = jnp.ones((2, 128, 64), jnp.bfloat16)
-    out = grouped_matmul(lhs, rhs, jnp.asarray([40, 60], jnp.int32),
-                         use_pallas=True, interpret=True)
+    sizes = jnp.asarray([40, 60], jnp.int32)
+    out = grouped_matmul(lhs, rhs, sizes, use_pallas=True, interpret=True)
     np.testing.assert_allclose(out, 128.0)
+    # ... and the gated call two of them and the product, by the same
+    # predicate: silu(128) * 128
+    act = grouped_gated_matmul(lhs, rhs, rhs, sizes, out_dtype=jnp.bfloat16,
+                               use_pallas=True, interpret=True)
+    assert act.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(act, np.float32), 128.0 * 128.0)
 
 
 def test_the_encoder_is_the_same_through_the_kernel(text):
@@ -434,13 +478,19 @@ def test_rows_the_kernel_never_wrote_reach_nothing(params32, ragged,
     real_pairs = int(mask.sum()) * CFG.num_experts_per_tok
     poisoned = []
 
-    def tail_poisoned(lhs, rhs, group_sizes, **kw):
-        out = grouped_matmul(lhs, rhs, group_sizes, **kw)
-        written = jnp.arange(out.shape[0])[:, None] < jnp.sum(group_sizes)
-        poisoned.append(out.shape[0] - real_pairs)
-        return jnp.where(written, out, jnp.nan)
+    def tail_poisoned(call):
+        def poisoning(*operands, **kw):
+            out, group_sizes = call(*operands, **kw), operands[-1]
+            written = jnp.arange(out.shape[0])[:, None] < jnp.sum(group_sizes)
+            poisoned.append(out.shape[0] - real_pairs)
+            return jnp.where(written, out, jnp.nan)
 
-    monkeypatch.setattr(olmoe, "grouped_matmul", tail_poisoned)
+        return poisoning
+
+    # the fused gate + up kernel leaves the same rows unwritten
+    monkeypatch.setattr(olmoe, "grouped_gated_matmul",
+                        tail_poisoned(grouped_gated_matmul))
+    monkeypatch.setattr(olmoe, "grouped_matmul", tail_poisoned(grouped_matmul))
     got_h, _ = olmoe_encode(params32, ids, mask, CFG, capacity=capacity)
     got_p = olmoe_predict(params32, ids, mask, CFG, capacity=capacity)
     assert poisoned and min(poisoned) >= 0 and max(poisoned) > 0
@@ -473,6 +523,24 @@ def test_compacted_through_the_kernel(capacity):
     with jax.default_matmul_precision("highest"):
         want = jax.nn.softmax(ref_logits(p, ids, mask, cfg), -1)[:, 1]
     np.testing.assert_allclose(got, want, atol=1e-5, rtol=0)
+
+
+@pytest.mark.parametrize("stored,atol", [("float32", 1e-5),
+                                         ("bfloat16", 1e-2)])
+@pytest.mark.parametrize("rung", [0, 1], ids=["three_quarters", "every_slot"])
+def test_apply_experts_is_the_same_through_the_kernels(
+        experts_through_both_forms, rung, stored, atol):
+    """Experts as wide as a lane tile, at both capacities of a launch of
+    4,096 slots: the fused gate + up + SiLU kernel and ``megablox.gmm``
+    (interpreted) against the XLA form."""
+    cfg = dataclasses.replace(CFG, intermediate_size=128, num_hidden_layers=1)
+    layer = jax.tree.map(
+        lambda a: a.astype(stored),
+        jax.jit(lambda k: init_olmoe_params(k, cfg))(jax.random.PRNGKey(9))
+    )["layers"][0]
+    sizes = experts_through_both_forms(
+        layer, top_k=cfg.num_experts_per_tok, rung=rung, atol=atol)
+    assert sizes.sum() == 2800 * cfg.num_experts_per_tok and sizes.min() > 0
 
 
 def test_empty_filler_rows_beside_real_ones_change_nothing(params32, ragged):
@@ -738,6 +806,55 @@ def test_fused_program_through_scorer_and_job_one_prediction_each():
     assert scorer.kernel_snapshot()["fallback"]["attention"] == c["batches"]
 
 
+@pytest.mark.parametrize("side", ["held", "declined_by_shape", "not_asked"])
+def test_the_gate_up_site_is_counted_and_entered_once_a_sparse_layer(side):
+    """``kernel_snapshot()`` counts every routed launch at ``expert_gate_up``
+    — dispatched where its program holds the fused gate + up + SiLU kernel,
+    a fallback where the predicate (experts 64 wide: under a lane tile) or
+    the selector (a CPU mesh, nothing asked) left it the three-call form —
+    and the compile ledger shows the program's trace entering ``gated_gmm``
+    once a sparse layer and ``gmm`` once (it was three times)."""
+    import time
+
+    from realtime_fraud_detection_tpu.scoring import FraudScorer, ScorerConfig
+    from realtime_fraud_detection_tpu.scoring.pipeline import (
+        score_fused_packed,
+    )
+    from realtime_fraud_detection_tpu.sim.simulator import (
+        TransactionGenerator,
+    )
+    from realtime_fraud_detection_tpu.utils.config import (
+        Config,
+        KernelSettings,
+    )
+
+    config = Config()
+    if side != "not_asked":
+        config.kernels = KernelSettings(enabled=True, attention="flash")
+    cfg = CFG if side == "declined_by_shape" else LANE_CFG
+    scorer = FraudScorer(bert_config=cfg, config=config,
+                         scorer_config=ScorerConfig(text_len=128),
+                         mesh=_one_device_mesh())
+    recs = TransactionGenerator(num_users=8, num_merchants=4).generate_batch(5)
+    # another test on this worker may have traced the same program
+    score_fused_packed.clear_cache()
+    t0 = time.time()
+    assert len(scorer.finalize(scorer.dispatch(recs))) == 5
+    snap = scorer.kernel_snapshot()
+    held = int(side == "held")
+    assert snap["dispatch"]["expert_gate_up"] == held
+    assert snap["fallback"]["expert_gate_up"] == 1 - held
+    traces = [r for r in scorer.host_stats()["compile"]["records"]
+              if r["phase"] == "trace" and r["start"] >= t0
+              and "score_fused_packed" in r["program"]]
+    assert len(traces) == 1                 # 8 x 128 slots: one rung
+    entered = {name: times for name, (times, _) in
+               traces[0].get("nested", {}).items()}
+    layers = cfg.num_hidden_layers
+    assert entered.get("gated_gmm", 0) == held * layers
+    assert entered.get("gmm", 0) == held * layers
+
+
 def test_the_dense_program_has_no_second_output_and_no_expert_rows():
     from realtime_fraud_detection_tpu.scoring import FraudScorer
     from realtime_fraud_detection_tpu.sim.simulator import (
@@ -752,6 +869,9 @@ def test_the_dense_program_has_no_second_output_and_no_expert_rows():
     assert not isinstance(pending.out, tuple)
     assert len(scorer.finalize(pending)) == 3
     assert pending.expert_peak_rows == 0
+    # nor has its snapshot the routed experts' site
+    snap = scorer.kernel_snapshot()
+    assert "expert_gate_up" not in {**snap["dispatch"], **snap["fallback"]}
 
 
 def test_the_seam_leaves_the_dense_program_as_it_was(monkeypatch):

@@ -202,10 +202,15 @@ def test_every_name_in_a_builders_vocabulary_is_one_the_program_writes(
         for part in scopes.EXPERTS_PARTS:
             assert getattr(scopes, f"EXPERTS_{part.upper()}") == (
                 f"{scopes.EXPERTS}/{part}")
-        assert bench.scope_path(
-            f"jit(f)/{scopes.TEXT}/{scopes.layer_scope(3)}/"
-            f"{scopes.EXPERTS_MATMUL}/jit(gmm)/pallas_call", vocabulary
-        ) == f"{scopes.TEXT}/{scopes.layer_scope(3)}/{scopes.EXPERTS_MATMUL}"
+        # the experts' two kernels: down (megablox's) and the fused gate +
+        # up + SiLU one, as a device trace names them
+        for kernel in ("jit(gmm)/pallas_call",
+                       "jit(gated_gmm)/gated_gmm/pallas_call"):
+            assert bench.scope_path(
+                f"jit(f)/{scopes.TEXT}/{scopes.layer_scope(3)}/"
+                f"{scopes.EXPERTS_MATMUL}/{kernel}", vocabulary
+            ) == (f"{scopes.TEXT}/{scopes.layer_scope(3)}/"
+                  f"{scopes.EXPERTS_MATMUL}")
     assert set(vocabulary) == set(scopes.BRANCH_SCOPES)
     assert set(vocabulary[scopes.TEXT]["layer*"]) == set(layer_parts)
     asm = _lowered_asm(config)

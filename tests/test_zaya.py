@@ -595,6 +595,19 @@ def test_the_encoder_is_the_same_through_the_kernel():
     np.testing.assert_allclose(got, want, atol=2e-3, rtol=0)
 
 
+@pytest.mark.parametrize("rung", [0, 1], ids=["three_quarters", "every_slot"])
+def test_apply_experts_is_the_same_through_the_kernels(
+        experts_through_both_forms, rung):
+    """One expert a token, four groups of 128-wide experts, at both
+    capacities of a launch of 4,096 slots: the fused gate + up + SiLU kernel
+    and ``megablox.gmm`` (interpreted) against the XLA form."""
+    layer = init_zaya_params(jax.random.PRNGKey(4), CFG)["layers"][1]
+    assert layer["gate_proj"].shape == (4, 128, 128)
+    sizes = experts_through_both_forms(
+        layer, top_k=CFG.num_experts_per_tok, rung=rung, atol=1e-2)
+    assert sizes.sum() == 2800 and sizes.min() > 0
+
+
 # ------------------------------------------------------ the configuration
 def test_published_config_is_the_default():
     c = ZayaConfig()
