@@ -44,7 +44,7 @@ the last layer that predicts token ``i + 2`` for a training loss and for
 speculative decoding; this path emits one probability a row and no token,
 and holds none of it.
 
-What the routed-encoder seam reads (``scoring/pipeline.RoutedText``):
+What the routed-encoder seam reads (``scoring/pipeline.CausalText``):
 ``num_experts`` = ``n_routed_experts`` (every expert is held here),
 ``intermediate_size`` = ONE expert's width ``moe_intermediate_size`` (the
 source's ``intermediate_size``, the dense layers' MLP, is

@@ -182,7 +182,7 @@ class LagunaConfig:
     @property
     def intermediate_size(self) -> int:
         """One routed expert's width, under the name the routed-encoder
-        seam reads (``scoring/pipeline.RoutedText``)."""
+        seam reads (``scoring/pipeline.CausalText``)."""
         return self.moe_intermediate_size
 
     @property

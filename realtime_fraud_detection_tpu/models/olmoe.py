@@ -118,7 +118,7 @@ class OlmoeConfig:
 
     @property
     def num_sparse_layers(self) -> int:
-        """Layers with a routed block (``scoring/pipeline.RoutedText``)."""
+        """Layers with a routed block (``scoring/pipeline.CausalText``)."""
         return self.num_hidden_layers
 
     def core_refusal(self, seq_len: int) -> Optional[str]:

@@ -140,7 +140,7 @@ class ZayaConfig:
 
     @property
     def num_sparse_layers(self) -> int:
-        """Layers with a routed block (``scoring/pipeline.RoutedText``)."""
+        """Layers with a routed block (``scoring/pipeline.CausalText``)."""
         return self.num_hidden_layers
 
     @property

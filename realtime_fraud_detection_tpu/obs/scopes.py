@@ -85,6 +85,20 @@ ATTN_LATENT = "attn_latent"  # what latent attention puts in front of the
                              # RMSNorms, the shared key's split
 JOYAI_LAYER_SCOPES: Tuple[str, ...] = LAGUNA_LAYER_SCOPES + (ATTN_LATENT,)
 
+# ---- device: under ``text`` where the encoder is models/falcon_h1.py: a
+# causal DENSE encoder (``attn_proj`` holds q, k with its multiplier, v and
+# o; ``attn_core`` Laguna's causal core with no window and no gate; ``ffn``
+# the SwiGLU MLP with its two multipliers; ``ln`` both RMSNorms and the
+# residual adds, the first of which sums the two mixers) and the Mamba-2
+# mixer that runs beside attention in every layer
+SSM_PROJ = "ssm_proj"        # W_in with the µP vector, the gate, the
+                             # grouped RMSNorm, W_out
+SSM_CONV = "ssm_conv"        # the depthwise causal convolution, its SiLU,
+                             # dt's softplus
+SSM_SCAN = "ssm_scan"        # the state-space scan alone (ops/ssd_scan.py)
+FALCON_H1_LAYER_SCOPES: Tuple[str, ...] = LAYER_SCOPES + (
+    SSM_PROJ, SSM_CONV, SSM_SCAN)
+
 
 def layer_scope(i: int) -> str:
     return f"{LAYER}{i}"

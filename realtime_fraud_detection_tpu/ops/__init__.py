@@ -27,6 +27,12 @@ a predicate admits holds the kernel, everything else the XLA form.
   calls and the product; ``grouped_matmul`` (down: ``megablox.gmm`` with
   per-shape tilings) against ``ragged_dot``; ``grouped_matmul_supported``,
   the one predicate of both.
+- ``ssd_scan``: the state-space scan of a Mamba-2 mixer
+  (``models/falcon_h1.py``), ``ssd_scan(..., use_pallas=True)`` — the chunked
+  algorithm as ONE kernel a layer, a grid over (row, group, chunk) with the
+  carried states in VMEM — against the same algorithm in ``jax.numpy``
+  (``use_pallas=False``); ``ssd_refusal``, asked through
+  ``FalconH1Config.scan_refusal``.
 - ``dequant_matmul``, ``epilogue``: the int8 text branch's fused
   dequant-matmul and the score-and-blend epilogue, behind ``KernelSettings``.
 """
@@ -66,4 +72,8 @@ from realtime_fraud_detection_tpu.ops.grouped_matmul import (  # noqa: F401
     grouped_matmul,
     grouped_matmul_reference,
     grouped_matmul_supported,
+)
+from realtime_fraud_detection_tpu.ops.ssd_scan import (  # noqa: F401
+    ssd_refusal,
+    ssd_scan,
 )

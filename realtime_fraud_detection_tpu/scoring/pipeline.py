@@ -39,6 +39,11 @@ from realtime_fraud_detection_tpu.models.bert import (
     bert_predict,
     init_bert_params,
 )
+from realtime_fraud_detection_tpu.models.falcon_h1 import (
+    FalconH1Config,
+    falcon_h1_predict,
+    init_falcon_h1_params,
+)
 from realtime_fraud_detection_tpu.models.gnn import gnn_logits, init_gnn_params
 from realtime_fraud_detection_tpu.models.isolation_forest import (
     IsolationForest,
@@ -92,74 +97,104 @@ MODEL_NAMES: tuple[str, ...] = (
 NUM_MODELS = len(MODEL_NAMES)
 
 # The text branch's configuration picks its encoder by its CLASS: a
-# ``BertConfig`` the dense DistilBERT-style one (models/bert.py), an
-# ``OlmoeConfig``, a ``ZayaConfig``, a ``LagunaConfig`` or a ``JoyaiConfig``
-# a routed sparse-expert one (models/olmoe.py, models/zaya.py,
-# models/laguna.py, models/joyai.py). The
+# ``BertConfig`` the dense bidirectional DistilBERT-style one
+# (models/bert.py); every other class a CAUSAL one, a row of ``_CAUSAL_TEXT``
+# — an ``OlmoeConfig``, a ``ZayaConfig``, a ``LagunaConfig`` or a
+# ``JoyaiConfig`` a routed sparse-expert one (models/olmoe.py,
+# models/zaya.py, models/laguna.py, models/joyai.py), a ``FalconH1Config``
+# a dense one whose layers run a state-space mixer beside attention
+# (models/falcon_h1.py). The
 # argument, the static jit argument and the ``ScoringModels`` field keep the
 # name ``bert``: checkpoints, ``MODEL_NAMES`` and the benchmark's references
 # read them.
 TextConfig = Union[BertConfig, OlmoeConfig, ZayaConfig, LagunaConfig,
-                   JoyaiConfig]
+                   JoyaiConfig, FalconH1Config]
 
 
 @dataclasses.dataclass(frozen=True)
-class RoutedText:
-    """A routed (sparse-expert) text encoder as the scorer sees one — the
-    ONE description every site asks (``routed_text``). ``predict`` takes
+class CausalText:
+    """A causal text encoder as the scorer sees one — the ONE description
+    every site asks (``causal_text``; ``routed_text`` where the question
+    is about routed blocks). Every such encoder reads its answer at a
+    row's last real token, so the scorer counts the (query, key) pairs its
+    real queries see from the rows' lengths (under a window where the
+    class spells ``sliding_window``), launches a batch as ONE program at
+    ``text_len`` (the narrow width of ``scoring/text_split.py`` is the
+    bidirectional encoder's kernel's), runs on one device, and has no int8
+    or dequant plane and no pool. Its configuration class spells, under the
+    Hugging Face names, ``num_hidden_layers`` and ``hidden_size``.
+    ``attention_refusal(config, seq_len)`` names why a launch of
+    ``seq_len`` positions holds no Pallas kernel at its attention site
+    even where asked (None where it holds one: OLMoE's, Laguna's, JoyAI's
+    and Falcon-H1's fused causal core, ZAYA1's fused mixing).
+
+    ``routed`` (the four sparse-expert encoders): ``predict`` takes
     ``capacity`` (the token slots its routed blocks are compiled for,
     ``scoring/text_split.py``) and, with ``with_stats``, also returns its
     launch's statistics: ``i32[layers]``, the largest expert group of each
     routed layer — or ``i32[2, layers]`` with, under it, the (token, expert)
     pairs that entered a held expert's group, where a layer holds a share
     of the experts its router chooses among (the scorer then counts
-    ``expert_rows`` from the device, not from the mask). Its configuration
-    class spells, under the Hugging Face names, ``num_experts`` (the experts
-    a layer HOLDS: the groups of the grouped matmul),
-    ``num_experts_per_tok``, ``num_hidden_layers``, ``hidden_size`` and
-    ``intermediate_size`` (ONE expert's width) — what the counters and the
-    grouped matmul's shape predicate read — and ``num_sparse_layers`` (the
-    layers with a routed block). ``attention_refusal(config, seq_len)``
-    names why a launch of ``seq_len`` positions holds no Pallas kernel at
-    its attention site even where asked (None where it holds one: OLMoE's,
-    Laguna's and JoyAI's fused causal core, ZAYA1's fused mixing). Every
-    routed encoder here is causal: the scorer counts the (query, key) pairs
-    its real queries see from the rows' lengths, and under a window where
-    the class spells ``sliding_window``. A routed encoder runs on one device
-    and has no int8 or dequant plane."""
+    ``expert_rows`` from the device, not from the mask). The class also
+    spells ``num_experts`` (the experts a layer HOLDS: the groups of the
+    grouped matmul), ``num_experts_per_tok``, ``intermediate_size`` (ONE
+    expert's width) — what the counters and the grouped matmul's shape
+    predicate read — and ``num_sparse_layers`` (the layers with a routed
+    block). The scorer counts each launch at the kernel site
+    ``expert_gate_up``.
+
+    Not ``routed`` (Falcon-H1's): no router, so no capacity (every slot is
+    computed), no second output, and the counters ``expert_*``,
+    ``routed_pairs`` and ``compact_batches`` stay 0. ``scan_refusal(config,
+    seq_len)``, where the encoder has a state-space mixer, is the same
+    question as ``attention_refusal`` of the mixer's scan
+    (``ops/ssd_scan.py``): the scorer then counts each launch at the kernel
+    site ``ssm_scan`` and its chunks in ``ssm_chunks``, from the class's
+    ``mamba_chunk_size``."""
 
     init: Callable[..., Dict[str, Any]]
     predict: Callable[..., Any]
     attention_refusal: Callable[[Any, int], Optional[str]]
+    routed: bool = True
+    scan_refusal: Optional[Callable[[Any, int], Optional[str]]] = None
 
 
-_ROUTED_TEXT = {
-    OlmoeConfig: RoutedText(init_olmoe_params, olmoe_predict,
+_CAUSAL_TEXT = {
+    OlmoeConfig: CausalText(init_olmoe_params, olmoe_predict,
                             OlmoeConfig.core_refusal),
-    ZayaConfig: RoutedText(init_zaya_params, zaya_predict,
+    ZayaConfig: CausalText(init_zaya_params, zaya_predict,
                            ZayaConfig.mix_refusal),
-    LagunaConfig: RoutedText(init_laguna_params, laguna_predict,
+    LagunaConfig: CausalText(init_laguna_params, laguna_predict,
                              LagunaConfig.core_refusal),
-    JoyaiConfig: RoutedText(init_joyai_params, joyai_predict,
+    JoyaiConfig: CausalText(init_joyai_params, joyai_predict,
                             JoyaiConfig.core_refusal),
+    FalconH1Config: CausalText(init_falcon_h1_params, falcon_h1_predict,
+                               FalconH1Config.core_refusal, routed=False,
+                               scan_refusal=FalconH1Config.scan_refusal),
 }
 
 
-def routed_text(config: TextConfig) -> Optional[RoutedText]:
-    """The routed encoder ``config``'s class names, or None (the dense
-    encoder)."""
-    return _ROUTED_TEXT.get(type(config))
+def causal_text(config: TextConfig) -> Optional[CausalText]:
+    """The causal encoder ``config``'s class names, or None (the dense
+    bidirectional encoder)."""
+    return _CAUSAL_TEXT.get(type(config))
+
+
+def routed_text(config: TextConfig) -> Optional[CausalText]:
+    """The same where that encoder has routed blocks, else None."""
+    causal = causal_text(config)
+    return causal if causal is not None and causal.routed else None
 
 
 def text_layers(config: TextConfig) -> int:
     """The text encoder's depth, however its source spells it."""
-    return (config.num_layers if routed_text(config) is None
+    return (config.num_layers if causal_text(config) is None
             else config.num_hidden_layers)
 
 
 def init_text_params(key: jax.Array, config: TextConfig) -> Dict[str, Any]:
-    routed = routed_text(config)
-    return (init_bert_params if routed is None else routed.init)(key, config)
+    causal = causal_text(config)
+    return (init_bert_params if causal is None else causal.init)(key, config)
 
 
 def text_predict(params: Dict[str, Any], input_ids: jax.Array,
@@ -170,25 +205,29 @@ def text_predict(params: Dict[str, Any], input_ids: jax.Array,
                  ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """The text branch's probability ``f32[B]`` from the encoder
     ``config``'s class names, and that encoder's per-launch statistics
-    (``RoutedText``: ``i32[layers]`` largest expert group for a routed
+    (``CausalText``: ``i32[layers]`` largest expert group for a routed
     encoder, ``i32[2, layers]`` where it holds a share of its experts),
-    ``None`` for the dense one (whose program is then what it was).
-    ``capacity`` is a routed encoder's: the token slots its routed blocks
-    are compiled for."""
-    routed = routed_text(config)
-    if routed is not None:
-        if dequant_kernel != "off":
-            raise ValueError(
-                "KernelSettings.dequant_matmul is DistilBERT's int8 plane; "
-                f"a {type(config).__name__} encoder has no quantized form")
-        return routed.predict(params, input_ids, attention_mask, config,
+    ``None`` for an encoder without routed blocks (whose program then has
+    the one result). ``capacity`` is a routed encoder's: the token slots
+    its routed blocks are compiled for."""
+    causal = causal_text(config)
+    if causal is not None and dequant_kernel != "off":
+        raise ValueError(
+            "KernelSettings.dequant_matmul is DistilBERT's int8 plane; "
+            f"a {type(config).__name__} encoder has no quantized form")
+    if causal is not None and causal.routed:
+        return causal.predict(params, input_ids, attention_mask, config,
                               capacity=capacity, use_pallas=use_pallas,
                               kernel_interpret=kernel_interpret,
                               with_stats=True)
     if capacity is not None:
         raise ValueError(
-            "text_capacity is a routed encoder's block's; the dense "
-            "encoder has nothing to compact")
+            "text_capacity is a routed encoder's block's; a "
+            f"{type(config).__name__} encoder has nothing to compact")
+    if causal is not None:
+        return causal.predict(params, input_ids, attention_mask, config,
+                              use_pallas=use_pallas,
+                              kernel_interpret=kernel_interpret), None
     return bert_predict(params, input_ids, attention_mask, config,
                         use_pallas=use_pallas, dequant_kernel=dequant_kernel,
                         kernel_interpret=kernel_interpret), None
@@ -421,7 +460,7 @@ def _score_fused_packed_impl(
     response fields as ONE f32[B, 8+M] matrix (one d2h payload) laid out per
     ``OUT_COLUMNS`` + model_predictions — and, with a routed text encoder
     only, a second small output beside it, ``(matrix, i32[layers])``: the largest
-    expert group of each layer (``RoutedText``: ``i32[2, layers]`` from an
+    expert group of each layer (``CausalText``: ``i32[2, layers]`` from an
     encoder that holds a share of its experts); ``text_capacity`` is that
     encoder's too (how many token slots its routed blocks run on: ``models/olmoe.py``;
     absent from a dense launch). XLA fuses the unpack slices into

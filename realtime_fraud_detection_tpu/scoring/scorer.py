@@ -53,6 +53,7 @@ from realtime_fraud_detection_tpu.scoring.pipeline import (
     ScoringModels,
     TextConfig,
     init_scoring_models,
+    causal_text,
     routed_text,
     score_fused,
     score_fused_packed,
@@ -68,6 +69,7 @@ from realtime_fraud_detection_tpu.state.stores import (
 )
 from realtime_fraud_detection_tpu.utils.config import (
     EXPERT_GATE_UP_SITE,
+    SSM_SCAN_SITE,
     VALID_KERNEL_SITES,
     Config,
     KernelSettings,
@@ -145,6 +147,10 @@ class PendingScore:
     text_stats: Optional[Any] = None
     expert_token_slots: int = 0
     compact_batches: int = 0
+    # An encoder with a state-space mixer only (pipeline.CausalText.
+    # scan_refusal; 0 otherwise): the chunks its scans walked, launched
+    # rows x text_len / mamba_chunk_size x layers, counted at dispatch.
+    ssm_chunks: int = 0
     # How the rows were launched (scoring/text_split.py): real rows in a
     # program narrower than ``text_len``, real rows at ``text_len`` (their
     # sum is ``n``), and 1 where the batch took two launches.
@@ -419,10 +425,14 @@ class FraudScorer:
         self.config = config or Config()
         self.sc = scorer_config or ScorerConfig()
         # the text branch's configuration picks its encoder by its class
-        # (scoring/pipeline.text_predict); what this scorer asks of a routed
-        # one is pipeline.RoutedText's contract
+        # (scoring/pipeline.text_predict); what this scorer asks of a causal
+        # one, and of one with routed blocks, is pipeline.CausalText's
+        # contract
         self.bert_config = bert_config
+        self._causal_text = causal_text(bert_config)
         self._moe_text = routed_text(bert_config) is not None
+        self._ssm_text = (self._causal_text is not None
+                          and self._causal_text.scan_refusal is not None)
         self.mesh = mesh if mesh is not None else build_mesh()
         self._refuse_bert_only_planes()
         # feature extraction needs JAX's CPU backend next to the accelerator:
@@ -466,9 +476,11 @@ class FraudScorer:
         self._platform = platform
         self._kernel_interpret = platform == "cpu"
         # (a routed encoder's launches are also counted at its experts'
-        # gate + up + SiLU site, which the dense encoder does not have)
+        # gate + up + SiLU site, those of an encoder with a state-space
+        # mixer at its scan's: sites the dense encoder does not have)
         sites = VALID_KERNEL_SITES + (
-            (EXPERT_GATE_UP_SITE,) if self._moe_text else ())
+            (EXPERT_GATE_UP_SITE,) if self._moe_text else ()) + (
+            (SSM_SCAN_SITE,) if self._ssm_text else ())
         self._kernel_counts: Dict[str, Dict[str, int]] = {
             "dispatch": {s: 0 for s in sites},
             "fallback": {s: 0 for s in sites},
@@ -670,9 +682,9 @@ class FraudScorer:
 
     def _refuse_bert_only_planes(self) -> None:
         """The planes written for the DistilBERT branch's parameter layout
-        refuse a routed encoder's configuration by name instead of
-        miscomputing."""
-        if not self._moe_text:
+        refuse a causal encoder's configuration (``pipeline.CausalText``)
+        by name instead of miscomputing."""
+        if self._causal_text is None:
             return
         quant = self.config.quant
         kernels = getattr(self.config, "kernels", None) or KernelSettings()
@@ -685,8 +697,11 @@ class FraudScorer:
                        "DistilBERT branch's kernel (ops/dequant_matmul.py)")
         elif self.mesh.devices.size > 1:
             refused = (f"a sharded mesh of {self.mesh.devices.size} devices "
-                       "would split the batch under the grouped expert "
-                       "matmul; a routed encoder runs on one device "
+                       "would split the batch under "
+                       + ("the grouped expert matmul; a routed"
+                          if self._moe_text else
+                          "the fused causal core and the scan; a causal")
+                       + " encoder runs on one device "
                        "(build_mesh(devices=jax.devices()[:1]))")
         if refused:
             raise ValueError(
@@ -703,10 +718,10 @@ class FraudScorer:
 
     def require_dense_text(self, plane: str) -> None:
         """Raise where ``plane`` (a pool class's name) is asked of a scorer
-        whose text branch is the MoE encoder: the pools dispatch the dense
-        program's single result, over devices the MoE program is not split
-        across."""
-        if self._moe_text:
+        whose text branch is a causal encoder (``pipeline.CausalText``):
+        the pools dispatch the DistilBERT program's single result, over
+        devices a causal encoder's program is not split across."""
+        if self._causal_text is not None:
             raise ValueError(
                 f"{plane} (DevicePool / MeshExecutor) dispatches the "
                 "DistilBERT program's single result; not available with a "
@@ -948,7 +963,7 @@ class FraudScorer:
         """Why a program launched at ``text_len`` holds no Pallas kernel at
         its attention site even where asked, or None where it holds one:
         the fused core for the dense encoder (``flash_supported``), for a
-        routed encoder what its row of ``pipeline.RoutedText`` names
+        routed encoder what its row of ``pipeline.CausalText`` names
         (``ZayaConfig.mix_refusal``: the predicate of ``ops/cca_mix.py``'s
         fused mixing; ``OlmoeConfig.core_refusal``,
         ``LagunaConfig.core_refusal`` and ``JoyaiConfig.core_refusal``: that
@@ -959,8 +974,8 @@ class FraudScorer:
 
         t = text_len or self.sc.text_len
         c = self.bert_config
-        if self._moe_text:
-            return routed_text(c).attention_refusal(c, t)
+        if self._causal_text is not None:
+            return self._causal_text.attention_refusal(c, t)
         if flash_supported(t, c.head_dim, c.num_heads):
             return None
         return (f"flash_attention takes seq_len a multiple of 128 and "
@@ -976,9 +991,16 @@ class FraudScorer:
         site, by name, or None where the program holds the Pallas kernel:
         the shape the kernel declines, else what kept the selector
         (``effective_use_pallas``) from asking."""
-        refusal = self._attention_shape_refusal(text_len)
-        if refusal or self.effective_use_pallas(devices, text_len):
-            return refusal
+        return (self._attention_shape_refusal(text_len)
+                or self._not_asked(devices, text_len))
+
+    def _not_asked(self, devices: Optional[int] = None,
+                   text_len: Optional[int] = None) -> Optional[str]:
+        """What keeps the selector (``effective_use_pallas``) from asking a
+        launch at ``text_len`` for its kernels, by name, or None where it
+        asks."""
+        if self.effective_use_pallas(devices, text_len):
+            return None
         if self.kernels.enabled:
             return f"KernelSettings.attention is {self.kernels.attention!r}"
         if self._platform != "tpu":
@@ -989,14 +1011,29 @@ class FraudScorer:
         if devices != 1:
             return (f"a program over {devices} devices: XLA cannot "
                     "partition a Mosaic call")
-        return "the grouped expert matmul declines the launch's rows"
+        return ("the grouped expert matmul declines the launch's rows"
+                if self._moe_text else
+                "neither the fused core nor the scan takes the shape")
+
+    def _scan_refusal(self, text_len: Optional[int] = None) -> Optional[str]:
+        """Why a launch at ``text_len`` runs the XLA form of its state-space
+        scan even where asked (``pipeline.CausalText.scan_refusal``), or
+        None where it holds the kernel. Asked only of an encoder that has a
+        scan."""
+        return self._causal_text.scan_refusal(
+            self.bert_config, text_len or self.sc.text_len)
 
     def _text_kernel_shape_ok(self, text_len: Optional[int] = None) -> bool:
         """Whether the text branch has a Pallas kernel for its shapes: the
         fused attention core for the dense encoder, the grouped expert
         matmul for the MoE one (the smallest bucket's rows decide: every
         larger bucket is a multiple of them, and a narrow capacity of the
-        routed blocks is whole tiles by ``text_split.CAPACITY_MULTIPLE``)."""
+        routed blocks is whole tiles by ``text_split.CAPACITY_MULTIPLE``),
+        the fused causal core or the scan's kernel for an encoder with a
+        state-space mixer (each site's own guard then decides for it)."""
+        if self._ssm_text:
+            return (self._attention_shape_ok(text_len)
+                    or self._scan_refusal(text_len) is None)
         if not self._moe_text:
             return self._attention_shape_ok(text_len)
         from realtime_fraud_detection_tpu.ops import grouped_matmul_supported
@@ -1024,7 +1061,10 @@ class FraudScorer:
         ``expert_gate_up`` for every launch of a routed encoder: dispatched
         where its sparse layers hold the fused gate + up + SiLU kernel
         (``ops.grouped_gated_matmul``), a fallback where they run the
-        three-call form."""
+        three-call form; and ``ssm_scan`` for every launch of an encoder
+        with a state-space mixer: dispatched where its layers hold the
+        scan's kernel (``ops.ssd_scan``), a fallback where they run the XLA
+        chunked form."""
         disp, fall = (self._kernel_counts["dispatch"],
                       self._kernel_counts["fallback"])
         asked = self.effective_use_pallas(
@@ -1043,6 +1083,9 @@ class FraudScorer:
             fused = asked and grouped_matmul_supported(
                 rows, c.hidden_size, c.intermediate_size)
             (disp if fused else fall)[EXPERT_GATE_UP_SITE] += 1
+        if self._ssm_text:
+            held = asked and self._scan_refusal(text_len) is None
+            (disp if held else fall)[SSM_SCAN_SITE] += 1
         if not self.kernels.enabled:
             return
         from realtime_fraud_detection_tpu.models.quant import (
@@ -1080,15 +1123,20 @@ class FraudScorer:
         effective per-site modes, whether the Pallas interpreter is
         serving (a CPU mesh), cumulative dispatch/fallback counts per
         site, and why a launch at ``text_len`` keeps the XLA form at its
-        attention site (None where it holds the kernel)."""
+        attention site (None where it holds the kernel) — and, of an
+        encoder with a state-space mixer, at its ``ssm_scan`` site."""
+        devices = getattr(self._pool, "program_devices", None)
+        refused = {"attention": self.attention_refusal(devices)}
+        if self._ssm_text:
+            refused[SSM_SCAN_SITE] = (self._scan_refusal()
+                                      or self._not_asked(devices))
         return {
             "modes": self.kernels.site_modes(),
             "interpret": bool(self.kernels.enabled
                               and self._kernel_interpret),
             "dispatch": dict(self._kernel_counts["dispatch"]),
             "fallback": dict(self._kernel_counts["fallback"]),
-            "refused": {"attention": self.attention_refusal(
-                getattr(self._pool, "program_devices", None))},
+            "refused": refused,
         }
 
     # ---------------------------------------------------------------- assembly
@@ -1375,12 +1423,13 @@ class FraudScorer:
                 # the batch's real tokens (text_split.capacity)
                 launches = [self._routed_launch(batch, n, size, full,
                                                 real_tokens)]
-                visible_full, visible_sliding = self._visible_pairs(
-                    np.count_nonzero(batch.token_mask, axis=1))
             else:
-                visible_full = visible_sliding = 0
                 launches = self._text_launches(batch, n, size, full,
                                                bucket_of)
+            visible_full = visible_sliding = 0
+            if self._causal_text is not None:
+                visible_full, visible_sliding = self._visible_pairs(
+                    np.count_nonzero(batch.token_mask, axis=1))
             for launch in launches:
                 self._pack_launch(batch, launch)
 
@@ -1442,6 +1491,10 @@ class FraudScorer:
         counts["expert_token_slots"] += expert_slots
         counts["compact_batches"] += compact
         routed_pairs = self._routed_pairs(launches[0].tokens)
+        ssm_chunks = 0
+        if self._ssm_text:
+            ssm_chunks = (token_slots // self.bert_config.mamba_chunk_size
+                          * self.bert_config.num_hidden_layers)
         return PendingScore(records=list(records), n=n, out=out,
                             # rtfd-lint: allow[d2h] batch.features is a host-assembled ndarray
                             features=np.asarray(batch.features),
@@ -1462,6 +1515,7 @@ class FraudScorer:
                             text_stats=text_stats,
                             expert_token_slots=expert_slots,
                             compact_batches=compact,
+                            ssm_chunks=ssm_chunks,
                             short_text_rows=short_rows,
                             long_text_rows=n - short_rows,
                             split_batches=split)
@@ -1470,10 +1524,17 @@ class FraudScorer:
     def text_split_refusal(self) -> Optional[str]:
         """Why this scorer keeps every batch in the one launch at
         ``text_len`` though its text kernel takes a narrower width, or
-        None. A plane that cannot take a second width says so by name."""
+        None. A plane that cannot take a second width says so by name, as
+        does a causal encoder that is no routed one (a routed one has its
+        own launch rule, the capacity of its routed blocks)."""
         if self._pool is not None:
             return (f"{type(self._pool).__name__}: every replica would "
                     "compile each bucket's family of programs")
+        if self._causal_text is not None and not self._moe_text:
+            return (f"a {type(self.bert_config).__name__} text branch: the "
+                    "narrow width is the bidirectional encoder's attention "
+                    "kernel's; a causal encoder's batch is one launch at "
+                    "text_len")
         return None
 
     def _narrow_text_len(self, full: int) -> Optional[int]:
@@ -1624,8 +1685,9 @@ class FraudScorer:
         layer under the encoder's ``sliding_window`` W (0 where its
         description spells none): ``sum_i min(i+1, W)`` = the same less the
         ``(L-W)(L-W+1)/2`` pairs further back than the window. Counted for
-        every routed encoder — ``pipeline.RoutedText``: all are causal —
-        whatever its class."""
+        every routed encoder — ``pipeline.CausalText``: all are causal —
+        whatever its class — and for a causal encoder without routed blocks
+        (``pipeline.CausalText``)."""
         lengths = lengths.astype(np.int64)
         full = int(np.sum(lengths * (lengths + 1) // 2))
         window = getattr(self.bert_config, "sliding_window", None)
@@ -1666,7 +1728,7 @@ class FraudScorer:
                 peaks = jax.device_get(pending.text_stats)
                 if peaks.ndim == 2:
                     # an encoder that holds a share of its experts
-                    # (pipeline.RoutedText): the held pairs under the peaks
+                    # (pipeline.CausalText): the held pairs under the peaks
                     peaks, held = peaks
                     pending.expert_rows = int(np.sum(held))
                 pending.expert_peak_rows = (

@@ -301,6 +301,10 @@ class StreamJob:
             # attn_visible_pairs_full / attn_visible_pairs_sliding)
             "routed_pairs": 0, "attn_visible_pairs_full": 0,
             "attn_visible_pairs_sliding": 0,
+            # an encoder with a state-space mixer only (0 otherwise): the
+            # chunks its scans walked, launched rows x text_len /
+            # mamba_chunk_size x layers (PendingScore.ssm_chunks)
+            "ssm_chunks": 0,
             # how the rows were launched (scoring/text_split.py): real rows
             # in a program narrower than ``text_len``, real rows at
             # ``text_len``, batches that took two launches
@@ -580,7 +584,7 @@ class StreamJob:
                             "expert_rows", "expert_peak_rows",
                             "expert_token_slots", "compact_batches",
                             "routed_pairs", "attn_visible_pairs_full",
-                            "attn_visible_pairs_sliding",
+                            "attn_visible_pairs_sliding", "ssm_chunks",
                             "short_text_rows", "long_text_rows",
                             "split_batches"):
                     # 0 from a stand-in scorer's pending without them

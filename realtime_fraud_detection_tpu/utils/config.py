@@ -718,6 +718,10 @@ VALID_KERNEL_SITES = ("dequant_matmul", "epilogue", "attention")
 # program is asked for its kernels and ``grouped_matmul_supported`` takes the
 # rows)
 EXPERT_GATE_UP_SITE = "expert_gate_up"
+# and one for an encoder with a state-space mixer alone: the mixer's scan
+# (``ops.ssd_scan``: the kernel wherever the program is asked for its kernels
+# and ``ssd_refusal`` has nothing against the shape)
+SSM_SCAN_SITE = "ssm_scan"
 VALID_KERNEL_MODES = ("off", "pallas")
 VALID_ATTENTION_KERNELS = ("reference", "flash")
 

@@ -656,3 +656,97 @@ def test_joyai_program_compiles_with_all_256_experts(one_chip, capacity):
     assert " conditional(" not in text and "cond/branch_" not in text
     assert "f32[8,32,2048,2048]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+# --------------------------------------------------------------- Falcon-H1
+@pytest.mark.parametrize("bucket", [8, 1])
+@pytest.mark.parametrize("carried", [False, True], ids=["from_zero",
+                                                        "state_in"])
+def test_ssd_scan_compiles_at_published_widths(one_chip, bucket, carried):
+    """Falcon-H1-34B's state-space scan as the cell launches it: rows of
+    2,048 positions in chunks of 128, 32 heads of 128 in two groups over a
+    256-wide state, bfloat16 ``x``, ``B``, ``C`` and float32 steps; ONE
+    kernel, with and without a state handed in."""
+    from realtime_fraud_detection_tpu.models.falcon_h1 import FalconH1Config
+    from realtime_fraud_detection_tpu.ops.ssd_scan import ssd_scan
+
+    cfg, t = FalconH1Config(), 2048
+    assert cfg.scan_refusal(t) is None
+    h, p, g, n = (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_n_groups,
+                  cfg.mamba_d_state)
+    args = [_sds((bucket, t, h, p), jnp.bfloat16, one_chip),
+            _sds((bucket, t, h), jnp.float32, one_chip),
+            _sds((h,), jnp.float32, one_chip),
+            _sds((bucket, t, g, n), jnp.bfloat16, one_chip),
+            _sds((bucket, t, g, n), jnp.bfloat16, one_chip),
+            _sds((h,), jnp.float32, one_chip)]
+    if carried:
+        args.append(_sds((bucket, h, n, p), jnp.float32, one_chip))
+    compiled = jax.jit(lambda x, dt, a, b_in, c_in, d, state=None: ssd_scan(
+        x, dt, a, b_in, c_in, d, chunk=cfg.mamba_chunk_size,
+        initial_state=state, use_pallas=True)).lower(*args).compile()
+    assert compiled.as_text().count(CUSTOM_CALL) == 1
+    # neither the [chunks, heads, 128, 128] masks nor the per-chunk states
+    # are temporaries of the program: the running sums and B's transpose are
+    assert compiled.memory_analysis().temp_size_in_bytes < bucket * (48 << 20)
+
+
+def test_falconh1_program_compiles_with_both_kernels_in_every_layer(one_chip):
+    """The served packed program with a ``FalconH1Config``: two of the
+    parallel hybrid layers, every width as published, bucket 8 x 2,048
+    tokens: the fused causal core (five query heads a key-value head) and
+    the scan's kernel in each layer, ONE result, and temporaries that leave
+    room for the cell's six layers of weights in 16 GB."""
+    from realtime_fraud_detection_tpu.core.packing import pack_tree
+    from realtime_fraud_detection_tpu.ensemble.combine import EnsembleParams
+    from realtime_fraud_detection_tpu.models.falcon_h1 import FalconH1Config
+    from realtime_fraud_detection_tpu.scoring.pipeline import (
+        MODEL_NAMES,
+        ScorerConfig,
+        init_scoring_models,
+        make_example_batch,
+        score_fused_packed,
+    )
+    from realtime_fraud_detection_tpu.utils.config import Config
+
+    config = FalconH1Config(num_hidden_layers=2)
+    models = jax.eval_shape(
+        lambda key: init_scoring_models(key, bert_config=config),
+        jax.random.PRNGKey(0))
+    blobs, spec = pack_tree(make_example_batch(
+        8, ScorerConfig(text_len=2048)))
+    compiled = score_fused_packed.lower(
+        _shapes_of(models, one_chip),
+        *(_shapes_of(blobs[k], one_chip) for k in ("f32", "i32", "u8")),
+        spec=spec,
+        params=EnsembleParams.from_config(Config(), list(MODEL_NAMES)),
+        model_valid=_sds((len(MODEL_NAMES),), jnp.bool_, one_chip),
+        blob_bf16=_shapes_of(blobs["bf16"], one_chip), bert_config=config,
+        use_pallas=True).compile()
+    text = compiled.as_text()
+    assert text.count(CUSTOM_CALL) == 2 + 2
+    assert text.count("ssm_scan/jit(_ssd_pallas)/ssd_scan/pallas_call") >= 2
+    assert text.count("attn_core/jit(windowed_attention)/"
+                      "windowed_attention/pallas_call") >= 2
+    assert " conditional(" not in text and "cond/branch_" not in text
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes <= 1 << 12    # the one small matrix
+    assert memory.temp_size_in_bytes < 4 << 30
+
+
+def test_falconh1_weights_are_made_without_a_float32_copy(one_chip):
+    """The builder's one jitted init at the published sizes: the 2.7 GB
+    embedding is drawn a block of rows at a time (drawn whole, its float32
+    normals stood beside 7.8 GB of weights and set-up's peak was the chip's
+    whole memory: my chip run, PR 46)."""
+    from realtime_fraud_detection_tpu.models.falcon_h1 import FalconH1Config
+    from realtime_fraud_detection_tpu.scoring.pipeline import (
+        init_scoring_models,
+    )
+
+    config = FalconH1Config(num_hidden_layers=6)
+    memory = jax.jit(
+        lambda key: init_scoring_models(key, bert_config=config)).lower(
+        _sds((2,), jnp.uint32, one_chip)).compile().memory_analysis()
+    assert 7.8e9 < memory.output_size_in_bytes < 7.9e9
+    assert memory.temp_size_in_bytes < 1 << 30

@@ -29,6 +29,15 @@ for p in (str(ROOT), str(ROOT / "benchmarks" / "tests")):
 import rehearsal  # noqa: E402  (benchmarks/tests/rehearsal.py)
 from benchmarks.harness import spec  # noqa: E402
 # PR 36's readers on hand-made runs: collected here as they stand there
+# PR 46's reference against the program, its lowering seam and its three
+# compilations: collected here as they stand there
+from test_falconh1_reference import (  # noqa: E402,F401
+    test_text_branch_is_the_programs_at_float32,
+    test_the_lowering_seam_reaches_every_matmul_and_the_scan,
+    test_the_reference_imports_nothing_from_the_package,
+    test_the_reference_refuses_what_its_equations_do_not_hold,
+    test_the_text_column_costs_three_compilations,
+)
 from test_setup_metrics import (  # noqa: E402,F401
     test_a_ledger_that_let_records_go_reads_none,
     test_a_program_without_the_ledger_reads_none,
@@ -58,6 +67,11 @@ JOYAI_CFG = json.loads(
     (ROOT / "benchmarks/configs/joyai-llm-flash-s2048.json").read_text())
 ROUTED_CELLS = [OLMOE_CELL, ZAYA_CELL, FULL_CELL, LAGUNA_CELL,
                 ZAYA_FULL_CELL, JOYAI_CELL]
+# PR 46: Falcon-H1's cell, a causal DENSE encoder with a state-space mixer,
+# on Laguna's and JoyAI's traffic file
+FALCON_CELL = "falconh1-s2048-remit-saturated"
+FALCON_CFG = json.loads(
+    (ROOT / "benchmarks/configs/falcon-h1-34b-s2048.json").read_text())
 
 
 # ------------------------------------------------------ configuration files
@@ -255,13 +269,79 @@ def test_the_joyai_file_is_the_sources_config_cut_in_depth_only():
         builder.joyai_config({**JOYAI_CFG, "n_group": 8, "topk_group": 4})
 
 
+def test_the_falconh1_file_is_the_sources_config_cut_in_depth_only():
+    from realtime_fraud_detection_tpu.models.falcon_h1 import FalconH1Config
+
+    builder = spec.builder(FALCON_CFG)
+    built = builder.falconh1_config(FALCON_CFG)
+    assert FALCON_CFG["reduced"] == ["num_hidden_layers"]
+    assert built == FalconH1Config(num_hidden_layers=6)  # defaults: published
+    assert FALCON_CFG["published"]["num_hidden_layers"] == 72
+    # every key of the catalog row's config, under its own name, at its
+    # published value but for the depth — in the file AND in the class
+    catalog = Path("/opt/skills/guides/model-configs/architectures.jsonl")
+    row = json.loads([
+        line for line in catalog.read_text().splitlines()
+        if '"Falcon-H1-34B-Instruct"' in line][0]) if catalog.is_file() \
+        else {"config": FALCON_CFG["published"],
+              "source_url": FALCON_CFG["source"]}
+    assert FALCON_CFG["source"] == row["source_url"]
+    assert FALCON_CFG["published"] == row["config"]
+    for key, value in row["config"].items():
+        want = 6 if key == "num_hidden_layers" else value
+        assert FALCON_CFG[key] == want, key
+        held = getattr(built, key)
+        assert (list(held) if isinstance(value, list) else held) == want, key
+    # every width as published
+    assert (built.hidden_size, built.intermediate_size, built.head_dim,
+            built.num_attention_heads, built.num_key_value_heads,
+            built.vocab_size) == (5120, 21504, 128, 20, 4, 261120)
+    assert (built.mamba_d_ssm, built.mamba_n_heads, built.mamba_d_head,
+            built.mamba_d_state, built.mamba_n_groups, built.mamba_d_conv,
+            built.mamba_chunk_size, built.conv_dim, built.in_proj_dim) == (
+        4096, 32, 128, 256, 2, 4, 128, 5120, 9248)
+    assert built.core_refusal(2048) is None is built.scan_refusal(2048)
+    assert FALCON_CFG["text_len"] == 2048 and FALCON_CFG["chips"] == 1
+    assert FALCON_CFG["job"]["max_batch"] == 8
+    assert FALCON_CFG["parity_rows"] == 4
+    for key in ("cut", "deployment", "not_run", "assumed", "compute_dtype",
+                "guarantee", "parity_atol_from", "job_from"):
+        assert FALCON_CFG[key] and "TO BE WRITTEN" not in json.dumps(
+            FALCON_CFG[key]), key
+    assert "twelve pipeline stages of six layers" in FALCON_CFG[
+        "deployment"].lower()
+    assert set(FALCON_CFG["not_run"]) == {
+        "lm_head", "lm_head_multiplier", "num_logits_to_keep"}
+    for item in ("equations", "head", "weights", "tokenizer", "traffic"):
+        assert FALCON_CFG["assumed"][item], item
+    assert "none under a tenth" in FALCON_CFG["assumed"]["weights"]
+    # the traffic file is Laguna's and JoyAI's, byte for byte: one file
+    assert spec.cell(FALCON_CELL)["traffic"] \
+        == spec.cell(LAGUNA_CELL)["traffic"] \
+        == spec.cell(JOYAI_CELL)["traffic"] == "s2048-remit-saturated"
+    tiny = builder.falconh1_config({**FALCON_CFG, **builder.TINY})
+    # two groups, more heads than groups, five query heads a key-value
+    # head, and four chunks in a rehearsal's 128 positions
+    assert tiny.hidden_size < 512 and tiny.mamba_n_groups == 2
+    assert tiny.mamba_n_heads > tiny.mamba_n_groups
+    assert tiny.num_attention_heads // tiny.num_key_value_heads == 5
+    assert 128 // tiny.mamba_chunk_size == 4
+    with pytest.raises(ValueError, match="attention_bias"):
+        builder.falconh1_config({**FALCON_CFG, "attention_bias": True})
+    with pytest.raises(ValueError, match="tied embeddings"):
+        builder.falconh1_config({**FALCON_CFG, "tie_word_embeddings": True})
+    with pytest.raises(ValueError, match="mamba_norm_before_gate"):
+        builder.falconh1_config(
+            {**FALCON_CFG, "mamba_norm_before_gate": True})
+
+
 def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
     cells = [w["name"] for w in BM["workloads"]]
     # the routed cells from the end: a DistilBERT cell parked or moved back
     # ahead of them (PR 42 parked longtail) shifts no index here
-    assert cells[-6:] == ROUTED_CELLS
+    assert cells[-7:] == ROUTED_CELLS + [FALCON_CELL]
     n_cells, n_routed = len(cells), len(ROUTED_CELLS)
-    n_distilbert = n_cells - n_routed
+    n_distilbert = n_cells - n_routed - 1
     assert n_distilbert == len(
         [w for w in BM["workloads"] if w["config"].startswith("distilbert")])
     by_name = {w["name"]: w for w in BM["workloads"]}
@@ -310,14 +390,46 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
     assert common | {"compact_batches_pct"} <= joyai_reports
     assert not (olmoe_only | zaya_only | (
         laguna_only - {"shared_expert_ms_per_batch"})) & joyai_reports
-    for m in BM["per_layer"][-4:]:
-        # PR 43 appended its four behind everything
+    # PR 46 appended its six behind everything, one cell and one
+    # configuration
+    falcon_only = ["ssm_proj_ms_per_batch", "ssm_conv_ms_per_batch",
+                   "ssm_scan_ms_per_batch", "falconh1_ssd_scan_roofline_pct",
+                   "falconh1_attn_core_roofline_pct",
+                   "falconh1_ffn_roofline_pct"]
+    assert [m["name"] for m in BM["per_layer"][-6:]] == falcon_only
+    for m in BM["per_layer"][-6:]:
+        assert m["workloads"] == [FALCON_CELL] and m["layer"] == "kernels"
+        assert m["moves"] == "txn_per_s" and m["source"] == "device_trace"
+        assert (m["unit"], m["better"]) == (
+            ("%", "higher") if m["name"].endswith("roofline_pct")
+            else ("ms", "lower"))
+    assert (by_name[FALCON_CELL]["config"], by_name[FALCON_CELL]["traffic"],
+            by_name[FALCON_CELL]["chips"]) == (
+        "falcon-h1-34b-s2048", "s2048-remit-saturated", 1)
+    falcon_reports = {m["name"]
+                      for m in spec.metrics_for(FALCON_CELL, "per_layer")}
+    # a dense encoder: what every cell reports, the dense MLP's time, and
+    # its own six; no expert's, no router's, neither launch rule's share
+    every_cell = {m["name"] for m in BM["per_layer"]
+                  if len(m["workloads"]) == n_cells}
+    assert falcon_reports == (every_cell | {"ffn_ms_per_batch"}
+                              | set(falcon_only))
+    assert len(every_cell) == 20 and not {
+        n for n in falcon_reports if n.startswith(("expert_", "router_"))}
+    assert not {"compact_batches_pct", "split_batches_pct",
+                "ffn_roofline_pct", "attn_core_roofline_pct"} & falcon_reports
+    for m in BM["per_layer"] + BM["end_to_end"]:
+        if FALCON_CELL in m.get("workloads", ()):
+            assert m["workloads"][-1] == FALCON_CELL, m["name"]
+    assert [c["name"] for c in BM["configs"]][-2:] == [
+        "joyai-llm-flash-s2048", "falcon-h1-34b-s2048"]
+    for m in BM["per_layer"][-10:-6]:
+        # PR 43 appended its four behind what was there
         assert m["name"] in joyai_only and m["moves"] == "txn_per_s"
         assert m["workloads"] == [JOYAI_CELL] and m["layer"] == "kernels"
-    assert [c["name"] for c in BM["configs"]][-1] == "joyai-llm-flash-s2048"
-    assert len(cells) == 7 and not [w for w in BM["workloads"]
+    assert len(cells) == 8 and not [w for w in BM["workloads"]
                                     if w["chips"] != 1]
-    per_layer = BM["per_layer"][:-4]     # the checks below: what PR 42 left
+    per_layer = BM["per_layer"][:-10]    # the checks below: what PR 42 left
     # ZAYA1's second cell reports exactly what its first does
     assert reports[ZAYA_FULL_CELL] == reports[ZAYA_CELL]
     # Laguna's: the shared names, its dense layer 0's, and its own six
@@ -346,6 +458,7 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
         assert m["name"] in laguna_only
         assert [w for w in m["workloads"] if w != JOYAI_CELL] \
             == [LAGUNA_CELL] and m["moves"] == "txn_per_s"
+        assert FALCON_CELL not in m["workloads"]
     earlier = per_layer[:-13]        # the checks below: what PR 30 left
     # OLMoE's second cell reports exactly what its first does
     assert reports[FULL_CELL] == reports[OLMOE_CELL] >= common | olmoe_only
@@ -359,7 +472,8 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
         # PR 33 appended its two cells' names behind what was there
         assert m["workloads"][0] == OLMOE_CELL and m["moves"] == "txn_per_s"
         assert [w for w in m["workloads"]
-                if w not in (LAGUNA_CELL, ZAYA_FULL_CELL, JOYAI_CELL)
+                if w not in (LAGUNA_CELL, ZAYA_FULL_CELL, JOYAI_CELL,
+                             FALCON_CELL)
                 ][-1] == FULL_CELL
     for m in earlier[-4:]:
         assert m["name"] in zaya_only
@@ -769,11 +883,128 @@ def test_the_joyai_metrics_on_a_hand_made_run():
         assert spec.reader_for(name, "per_layer")(counted) is None, name
 
 
+def test_falconh1_matmul_flops_follow_the_parallel_block():
+    builder = spec.builder(FALCON_CFG)
+    parts = builder.text_matmul_flops_per_row(FALCON_CFG)
+    t, h = 2048, 5120
+    assert parts["ssm_proj"] == 6 * 2.0 * t * h * (9248 + 4096)
+    assert parts["attn_proj"] == 6 * 2.0 * t * h * 128 * (2 * 20 + 2 * 4)
+    assert parts["cores"] == 6 * 4.0 * 20 * 128 * (t * (t + 1) // 2)
+    assert parts["mlp"] == 6 * 6.0 * t * h * 21504
+    # ISSUE 46's arithmetic: 5.37 MFLOP a slot and layer in the scan
+    per_slot = spec.kernel("falconh1_ssd_scan").flops_per_slot(FALCON_CFG)
+    assert per_slot == pytest.approx(5.37e6, rel=2e-3)
+    assert parts["ssm_scan"] == 6 * t * per_slot
+    # the mixer's projections are 16% of a layer's matmuls, the MLP 77%
+    per_layer = {k: v for k, v in parts.items() if k != "cores"}
+    whole = sum(per_layer.values())
+    assert parts["mlp"] / whole == pytest.approx(0.765, abs=0.01)
+    assert (parts["ssm_proj"] + parts["ssm_scan"]) / whole \
+        == pytest.approx(0.16, abs=0.01)
+    # ~5.2 GFLOP a token, 84-85 TFLOP a batch of 8 x 2,048 slots
+    total = builder.matmul_flops_per_batch(FALCON_CFG)
+    assert whole / t == pytest.approx(5.2e9, rel=0.02)
+    assert total == pytest.approx(86e12, rel=0.03)
+    assert 0.99 < 8 * sum(parts.values()) / total <= 1.0
+
+
+def test_falconh1_kernels_charge_what_the_program_counted():
+    slots = 3 * 8 * 2048
+    counters = {"batches": 3, "token_slots": slots,
+                "attn_visible_pairs_full": 20_000_000,
+                "attn_visible_pairs_sliding": 0,
+                "ssm_chunks": slots // 128 * 6}
+    scan = spec.kernel("falconh1_ssd_scan").work(counters, FALCON_CFG)
+    # every launched slot of every layer: C B^T a group, the masked product
+    # and the two state products a head
+    assert scan["flops"] == slots * 6 * (
+        2 * 128 * 256 * 2 + 2 * 128 * 128 * 32 + 2 * 2 * 256 * 128 * 32)
+    # x, B, C read in bfloat16, dt in float32, y written in float32
+    assert scan["hbm_bytes"] == slots * 6 * (
+        (4096 + 1024) * 2 + (32 + 4096) * 4)
+    # ~200 FLOP a byte: under the v5e's ridge of 240, so the metric file
+    # names the HBM's rate
+    assert 190 < scan["flops"] / scan["hbm_bytes"] < 240
+    assert json.loads((ROOT / "benchmarks/layer_metrics/"
+                       "falconh1_ssd_scan_roofline_pct.json").read_text()
+                      )["args"]["peak"] == "hbm_bytes_per_s"
+    core = spec.kernel("falconh1_attn_core").work(counters, FALCON_CFG)
+    assert core["flops"] == 4 * 128 * 20 * 6 * 20_000_000
+    assert core["hbm_bytes"] == 6 * slots * (2 * 20 + 2 * 4) * 128 * 2
+    ffn = spec.kernel("falconh1_ffn").work(counters, FALCON_CFG)
+    assert ffn["flops"] == 6 * slots * 5120 * 21504 * 6
+    assert ffn["flops"] / ffn["hbm_bytes"] > 1000        # compute-bound
+    for kernel in ("falconh1_ssd_scan", "falconh1_attn_core",
+                   "falconh1_ffn"):
+        none = spec.kernel(kernel).work({"batches": 3}, FALCON_CFG)
+        assert none == {"flops": 0.0, "hbm_bytes": 0.0}, kernel
+    # a program that counts its slots and no chunk has no mixer
+    assert spec.kernel("falconh1_ssd_scan").work(
+        {"batches": 3, "token_slots": slots}, FALCON_CFG)["flops"] == 0.0
+
+
+def test_the_falconh1_metrics_on_a_hand_made_run():
+    slots = 2 * 8 * 2048
+    counters = {"batches": 2, "scored": 16, "token_slots": slots,
+                "real_tokens": 20_000, "ssm_chunks": slots // 128 * 6,
+                "attn_visible_pairs_full": 14_000_000,
+                "attn_visible_pairs_sliding": 0}
+    scope_s = {"text": 1.14}
+    for i in range(6):
+        scope_s.update({f"text/layer{i}/attn_core": 0.003,
+                        f"text/layer{i}/attn_proj": 0.012,
+                        f"text/layer{i}/ffn": 0.130,
+                        f"text/layer{i}/ln": 0.0002,
+                        f"text/layer{i}/ssm_proj": 0.032,
+                        f"text/layer{i}/ssm_conv": 0.008,
+                        f"text/layer{i}/ssm_scan": 0.0033})
+    run = _fake_run(scope_s, counters, FALCON_CFG)
+
+    def metric(name):
+        return spec.reader_for(name, "per_layer")(run)
+
+    assert metric("ssm_proj_ms_per_batch") == pytest.approx(96.0)
+    assert metric("ssm_conv_ms_per_batch") == pytest.approx(24.0)
+    assert metric("ssm_scan_ms_per_batch") == pytest.approx(9.9)
+    assert metric("attn_core_ms_per_batch") == pytest.approx(9.0)
+    assert metric("attn_proj_ms_per_batch") == pytest.approx(36.0)
+    assert metric("ffn_ms_per_batch") == pytest.approx(390.0)
+    assert metric("token_padding_pct") == pytest.approx(
+        100 * (1 - 20_000 / slots))
+    for name, kernel, quantity, peak, seconds in (
+            ("falconh1_ssd_scan_roofline_pct", "falconh1_ssd_scan",
+             "hbm_bytes", 819e9, 0.0198),
+            ("falconh1_attn_core_roofline_pct", "falconh1_attn_core",
+             "flops", 197e12, 0.018),
+            ("falconh1_ffn_roofline_pct", "falconh1_ffn", "flops", 197e12,
+             0.78)):
+        needs = spec.kernel(kernel).work(counters, FALCON_CFG)[quantity]
+        assert 0 < metric(name) < 100, name
+        assert metric(name) == pytest.approx(
+            100 * needs / peak / seconds), name
+    # against a program without the scopes and the counter every new metric
+    # is left out and none raises
+    parent = _fake_run({"text": 0.9, "text/layer1/ffn": 0.1},
+                       {"batches": 2, "scored": 16}, FALCON_CFG)
+    for name in ("ssm_proj_ms_per_batch", "ssm_conv_ms_per_batch",
+                 "ssm_scan_ms_per_batch", "falconh1_ssd_scan_roofline_pct",
+                 "falconh1_attn_core_roofline_pct",
+                 "falconh1_ffn_roofline_pct"):
+        assert spec.reader_for(name, "per_layer")(parent) is None, name
+    # the scope there and the counter not: the share is left out
+    counted = _fake_run({"text": 0.9, "text/layer1/ssm_scan": 0.1},
+                        {"batches": 2, "scored": 16, "token_slots": slots},
+                        FALCON_CFG)
+    assert spec.reader_for("falconh1_ssd_scan_roofline_pct",
+                           "per_layer")(counted) is None
+
+
 # ------------------------------------------------ a program without the module
 @pytest.mark.parametrize("cfg,module", [(OLMOE_CFG, "olmoe"),
                                         (ZAYA_CFG, "zaya"),
                                         (LAGUNA_CFG, "laguna"),
-                                        (JOYAI_CFG, "joyai")])
+                                        (JOYAI_CFG, "joyai"),
+                                        (FALCON_CFG, "falcon_h1")])
 def test_the_builder_stops_at_once_on_a_program_without_the_encoder(
         monkeypatch, cfg, module):
     import importlib.util
@@ -790,7 +1021,8 @@ def test_the_builder_stops_at_once_on_a_program_without_the_encoder(
 @pytest.mark.parametrize("cell,module", [(OLMOE_CELL, "olmoe"),
                                          (ZAYA_CELL, "zaya"),
                                          (LAGUNA_CELL, "laguna"),
-                                         (JOYAI_CELL, "joyai")])
+                                         (JOYAI_CELL, "joyai"),
+                                         (FALCON_CELL, "falcon_h1")])
 def test_the_parent_exits_non_zero_within_seconds(tmp_path, cell, module):
     """A checkout of the benchmark without the program's new module — what
     the driver's parent run of a new configuration's cell is — prints no
@@ -831,7 +1063,7 @@ def tiny_copy(tmp_path_factory):
 @pytest.mark.parametrize("cell,trace", [
     (OLMOE_CELL, 0), (OLMOE_CELL, 1), (ZAYA_CELL, 0), (ZAYA_CELL, 1),
     (FULL_CELL, 1), (LAGUNA_CELL, 0), (LAGUNA_CELL, 1), (ZAYA_FULL_CELL, 1),
-    (JOYAI_CELL, 0), (JOYAI_CELL, 1)])
+    (JOYAI_CELL, 0), (JOYAI_CELL, 1), (FALCON_CELL, 0), (FALCON_CELL, 1)])
 def test_tiny_rehearsal_of_a_routed_cell(tiny_copy, cell, trace):
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(ROOT),
                XLA_FLAGS="--xla_force_host_platform_device_count=1")
@@ -846,7 +1078,20 @@ def test_tiny_rehearsal_of_a_routed_cell(tiny_copy, cell, trace):
     assert out["attempted"] > 0 and out["device"]["count"] == 1
     assert "check zero compilations inside the window: ok" in proc.stdout
     assert "check the backlog outlasted the window: ok" in proc.stdout
-    if trace:
+    if trace and cell == FALCON_CELL:
+        # a dense encoder: no expert metric, every slot real; the programs
+        # compiled before the window opened are the admitted cells' and the
+        # reference's three (ISSUE 46: at most 14, where PR 45's cell read
+        # 98 and was refused on setup_s)
+        assert out["metrics"]["token_padding_pct"]["value"] == 0.0
+        assert 0 < out["metrics"]["setup_programs"]["value"] <= 14
+        assert not [name for name in out["metrics"]
+                    if name.startswith(("expert_", "router_", "ssm_",
+                                        "falconh1_", "compact_"))]
+        assert "'ssm_chunks': " in proc.stdout \
+            and "'ssm_chunks': 0" not in proc.stdout
+        assert "'expert_rows': 0, 'expert_peak_rows': 0" in proc.stdout
+    elif trace:
         # counters are read on any backend; device scopes need the chip
         assert out["metrics"]["expert_imbalance_x"]["value"] >= 1.0
         padding = out["metrics"]["token_padding_pct"]["value"]

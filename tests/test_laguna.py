@@ -607,7 +607,7 @@ def test_published_config_is_the_default():
     assert c.rope_full.rope_type == "yarn" and c.rope_full.factor == 128
     assert c.rope_of(0) is c.rope_full and c.rope_of(1) is c.rope_sliding
     assert c.window_of(0) is None and c.window_of(2) == 512
-    # the routed-encoder seam's names (scoring/pipeline.RoutedText)
+    # the routed-encoder seam's names (scoring/pipeline.CausalText)
     assert c.intermediate_size == 1024 and c.num_sparse_layers == 47
     assert c.core_refusal(2048) is None
 

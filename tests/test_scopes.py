@@ -137,7 +137,7 @@ def _lowered_asm(bert_config):
 
 @pytest.mark.parametrize("builder", ["ensemble_builder", "olmoe_builder",
                                      "zaya1_builder", "laguna_builder",
-                                     "joyai_builder"])
+                                     "joyai_builder", "falconh1_builder"])
 def test_every_name_in_a_builders_vocabulary_is_one_the_program_writes(
         builder):
     """A builder (``benchmarks/configs/<builder>.py``) writes the device
@@ -167,6 +167,22 @@ def test_every_name_in_a_builders_vocabulary_is_one_the_program_writes(
         assert bench.scope_path(
             f"jit(f)/{scopes.TEXT}/{scopes.layer_scope(2)}/{scopes.FFN}/dot"
         ) == f"{scopes.TEXT}/{scopes.layer_scope(2)}/{scopes.FFN}"
+    elif builder == "falconh1_builder":
+        from realtime_fraud_detection_tpu.models.falcon_h1 import (
+            TINY_FALCON_H1,
+        )
+
+        # a causal DENSE encoder: DistilBERT's four names and the three of
+        # the state-space mixer; no router, no experts
+        config, layer_parts = TINY_FALCON_H1, scopes.FALCON_H1_LAYER_SCOPES
+        assert set(layer_parts) == set(scopes.LAYER_SCOPES) | {
+            scopes.SSM_PROJ, scopes.SSM_CONV, scopes.SSM_SCAN}
+        assert scopes.EXPERTS not in vocabulary[scopes.TEXT]["layer*"]
+        # the scan's kernel as a device trace names it
+        assert bench.scope_path(
+            f"jit(f)/{scopes.TEXT}/{scopes.layer_scope(5)}/{scopes.SSM_SCAN}"
+            "/jit(_ssd_pallas)/ssd_scan/pallas_call", vocabulary
+        ) == f"{scopes.TEXT}/{scopes.layer_scope(5)}/{scopes.SSM_SCAN}"
     else:
         if builder == "olmoe_builder":
             from realtime_fraud_detection_tpu.models.olmoe import TINY_OLMOE

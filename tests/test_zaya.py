@@ -650,7 +650,7 @@ def test_one_description_of_a_routed_encoder_serves_both():
         routed = pipeline.routed_text(cfg)
         assert routed.predict is predict
         assert pipeline.text_layers(cfg) == cfg.num_hidden_layers
-        # what RoutedText's contract says the scorer may read
+        # what CausalText's contract says the scorer may read
         for name in ("num_experts", "num_experts_per_tok",
                      "num_hidden_layers", "hidden_size", "intermediate_size"):
             assert isinstance(getattr(cfg, name), int), name
