@@ -71,8 +71,10 @@ import numpy as np
 from realtime_fraud_detection_tpu.models.laguna import swiglu
 from realtime_fraud_detection_tpu.models.olmoe import (
     _proj,
+    ExpertLoad,
     choose_experts,
     last_token_logits,
+    launch_stats,
     rms_norm,
     routed_block,
     token_slots,
@@ -414,10 +416,9 @@ def joyai_layer(layer: Dict, h: jax.Array, attention_mask: jax.Array,
                 cos, sin, *,
                 slots: Optional[Tuple[Optional[jax.Array], jax.Array]] = None,
                 use_pallas: bool = False, kernel_interpret: bool = False
-                ) -> Tuple[jax.Array, Optional[jax.Array]]:
-    """Layer ``index`` on ``h`` ``f32[B, T, hidden]``: ``(h, group_sizes)``,
-    ``group_sizes`` ``i32[experts]`` of a sparse layer, None of a dense
-    one."""
+                ) -> Tuple[jax.Array, Optional[ExpertLoad]]:
+    """Layer ``index`` on ``h`` ``f32[B, T, hidden]``: ``(h, load)``, the
+    ``ExpertLoad`` of a sparse layer, None of a dense one."""
     b, t, width = h.shape
     h = joyai_attention(layer, h, attention_mask, lengths, config, cos, sin,
                         use_pallas=use_pallas,
@@ -433,14 +434,14 @@ def joyai_layer(layer: Dict, h: jax.Array, attention_mask: jax.Array,
             return h + y, None
     if slots is None:
         slots = token_slots(attention_mask, None)
-    y, group_sizes, _ = routed_block(
+    y, load, _ = routed_block(
         layer, m.reshape(b * t, width), slots,
         lambda rows: joyai_route(layer, rows, config),
         shared=lambda rows: swiglu(rows, layer["shared_gate"],
                                    layer["shared_up"], layer["shared_down"]),
         use_pallas=use_pallas, kernel_interpret=kernel_interpret)
     with jax.named_scope(scopes.LN):
-        return h + y.reshape(b, t, width), group_sizes
+        return h + y.reshape(b, t, width), load
 
 
 def joyai_encode(params: Dict, input_ids: jax.Array,
@@ -449,25 +450,25 @@ def joyai_encode(params: Dict, input_ids: jax.Array,
                  use_pallas: bool = False, kernel_interpret: bool = False
                  ) -> Tuple[jax.Array, jax.Array]:
     """Hidden states before the final norm ``f32[B, T, hidden]`` and the
-    largest expert group of each sparse layer ``i32[sparse layers]``.
-    ``capacity``: the token slots the routed blocks are compiled for
-    (``models/olmoe.py``)."""
+    sparse layers' statistics ``i32[3, sparse layers]``
+    (``olmoe.launch_stats``). ``capacity``: the token slots the routed
+    blocks are compiled for (``models/olmoe.py``)."""
     cos, sin = joyai_rope_tables(input_ids.shape[1], config.qk_rope_head_dim,
                                  config.rope_theta)
     slots = token_slots(attention_mask, capacity)
     lengths = jnp.sum(attention_mask.astype(jnp.int32), axis=-1)
     with jax.named_scope(scopes.EMBED):
         h = params["embed_tokens"][input_ids].astype(jnp.float32)
-    peaks = []
+    loads = []
     for i, layer in enumerate(params["layers"]):
         with jax.named_scope(scopes.layer_scope(i)):
-            h, group_sizes = joyai_layer(
+            h, load = joyai_layer(
                 layer, h, attention_mask, lengths, config, i, cos, sin,
                 slots=slots, use_pallas=use_pallas,
                 kernel_interpret=kernel_interpret)
-        if group_sizes is not None:
-            peaks.append(jnp.max(group_sizes))
-    return h, jnp.stack(peaks)
+        if load is not None:
+            loads.append(load)
+    return h, launch_stats(loads)
 
 
 def joyai_predict(params: Dict, input_ids: jax.Array,
@@ -476,14 +477,13 @@ def joyai_predict(params: Dict, input_ids: jax.Array,
                   use_pallas: bool = False, kernel_interpret: bool = False,
                   with_stats: bool = False):
     """Fraud probability ``f32[B]`` = ``softmax(logits)[:, 1]`` from the
-    last real token; with ``with_stats`` also the ``i32[sparse layers]``
-    largest expert group per sparse layer (what
-    ``StreamJob.counters['expert_peak_rows']`` sums; every expert is held,
-    so ``expert_rows`` stays the host's mask count)."""
-    hidden, peaks = joyai_encode(
+    last real token; with ``with_stats`` also the sparse layers' statistics
+    ``i32[3, sparse layers]`` (``olmoe_predict``'s second output; every
+    expert is held, so the held pairs are the routers' pairs)."""
+    hidden, stats = joyai_encode(
         params, input_ids, attention_mask, config, capacity=capacity,
         use_pallas=use_pallas, kernel_interpret=kernel_interpret)
     logits = last_token_logits(params, hidden, attention_mask,
                                config.rms_norm_eps)
     p = jax.nn.softmax(logits, axis=-1)[:, 1]
-    return (p, peaks) if with_stats else p
+    return (p, stats) if with_stats else p

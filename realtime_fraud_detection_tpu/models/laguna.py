@@ -72,7 +72,9 @@ from realtime_fraud_detection_tpu.models.olmoe import (
     _proj,
     apply_rope,
     choose_experts,
+    ExpertLoad,
     last_token_logits,
+    launch_stats,
     rms_norm,
     rope_tables,
     routed_block,
@@ -416,10 +418,10 @@ def laguna_layer(layer: Dict, h: jax.Array, attention_mask: jax.Array,
                  cos, sin, *,
                  slots: Optional[Tuple[Optional[jax.Array], jax.Array]] = None,
                  use_pallas: bool = False, kernel_interpret: bool = False
-                 ) -> Tuple[jax.Array, Optional[jax.Array]]:
-    """Layer ``index`` on ``h`` ``f32[B, T, hidden]``: ``(h, group_sizes)``,
-    ``group_sizes`` ``i32[held experts]`` of a sparse layer, None of a
-    dense one."""
+                 ) -> Tuple[jax.Array, Optional[ExpertLoad]]:
+    """Layer ``index`` on ``h`` ``f32[B, T, hidden]``: ``(h, load)``, the
+    ``ExpertLoad`` of a sparse layer's held experts, None of a dense
+    one."""
     b, t, width = h.shape
     h = laguna_attention(layer, h, attention_mask, lengths, config, index,
                          cos, sin, use_pallas=use_pallas,
@@ -435,7 +437,7 @@ def laguna_layer(layer: Dict, h: jax.Array, attention_mask: jax.Array,
             return h + y, None
     if slots is None:
         slots = token_slots(attention_mask, None)
-    y, group_sizes, _ = routed_block(
+    y, load, _ = routed_block(
         layer, m.reshape(b * t, width), slots,
         lambda rows: laguna_route(layer, rows, config),
         shared=lambda rows: swiglu(rows, layer["shared_gate"],
@@ -444,7 +446,7 @@ def laguna_layer(layer: Dict, h: jax.Array, attention_mask: jax.Array,
         expert_offset=config.expert_offset,
         use_pallas=use_pallas, kernel_interpret=kernel_interpret)
     with jax.named_scope(scopes.LN):
-        return h + y.reshape(b, t, width), group_sizes
+        return h + y.reshape(b, t, width), load
 
 
 def laguna_encode(params: Dict, input_ids: jax.Array,
@@ -453,9 +455,11 @@ def laguna_encode(params: Dict, input_ids: jax.Array,
                   use_pallas: bool = False, kernel_interpret: bool = False
                   ) -> Tuple[jax.Array, jax.Array]:
     """Hidden states before the final norm ``f32[B, T, hidden]`` and the
-    sparse layers' statistics ``i32[2, sparse layers]``: the largest held
-    expert's group, and under it the pairs that entered a held expert's
-    group at all (what this chip's share of the routing came to).
+    sparse layers' statistics ``i32[3, sparse layers]``
+    (``olmoe.launch_stats``): the largest held expert's group, under it
+    the pairs that entered a held expert's group at all (what this chip's
+    share of the routing came to), and the rows the fused kernel's grid
+    visited for them.
     ``capacity``: the token slots the routed blocks are compiled for
     (``models/olmoe.py``)."""
     t = input_ids.shape[1]
@@ -466,17 +470,16 @@ def laguna_encode(params: Dict, input_ids: jax.Array,
     lengths = jnp.sum(attention_mask.astype(jnp.int32), axis=-1)
     with jax.named_scope(scopes.EMBED):
         h = params["embed_tokens"][input_ids].astype(jnp.float32)
-    peaks, held = [], []
+    loads = []
     for i, layer in enumerate(params["layers"]):
         with jax.named_scope(scopes.layer_scope(i)):
-            h, group_sizes = laguna_layer(
+            h, load = laguna_layer(
                 layer, h, attention_mask, lengths, config, i,
                 *tables[config.layer_types[i]], slots=slots,
                 use_pallas=use_pallas, kernel_interpret=kernel_interpret)
-        if group_sizes is not None:
-            peaks.append(jnp.max(group_sizes))
-            held.append(jnp.sum(group_sizes))
-    return h, jnp.stack([jnp.stack(peaks), jnp.stack(held)])
+        if load is not None:
+            loads.append(load)
+    return h, launch_stats(loads)
 
 
 def laguna_logits(params: Dict, input_ids: jax.Array,
@@ -499,9 +502,10 @@ def laguna_predict(params: Dict, input_ids: jax.Array,
                    use_pallas: bool = False, kernel_interpret: bool = False,
                    with_stats: bool = False):
     """Fraud probability ``f32[B]`` = ``softmax(logits)[:, 1]``; with
-    ``with_stats`` also ``i32[2, sparse layers]``: the largest held group
-    and the held pairs of each sparse layer (``StreamJob.counters``'
-    ``expert_peak_rows`` and ``expert_rows``)."""
+    ``with_stats`` also ``i32[3, sparse layers]``: the largest held group,
+    the held pairs and the visited rows of each sparse layer
+    (``StreamJob.counters``' ``expert_peak_rows``, ``expert_rows`` and
+    ``expert_tile_rows``)."""
     logits, stats = laguna_logits(params, input_ids, attention_mask, config,
                                   capacity=capacity, use_pallas=use_pallas,
                                   kernel_interpret=kernel_interpret)

@@ -54,7 +54,7 @@ rounding flips).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -70,6 +70,7 @@ from realtime_fraud_detection_tpu.ops.attention import (
     windowed_refusal,
 )
 from realtime_fraud_detection_tpu.ops.grouped_matmul import (
+    gated_tile_rows,
     grouped_gated_matmul,
     grouped_matmul,
 )
@@ -270,13 +271,33 @@ def token_slots(attention_mask: jax.Array, capacity: Optional[int]
                      ), real
 
 
+class ExpertLoad(NamedTuple):
+    """What one routed layer's grouped calls were handed: ``group_sizes``
+    ``i32[held experts]``, the (token, expert) pairs in each held expert's
+    group, and ``tile_rows`` ``i32[]``, the rows the fused gate / up
+    kernel's grid visited for them (``ops.grouped_matmul.gated_tile_rows``:
+    0 where the layer ran the XLA form)."""
+
+    group_sizes: jax.Array
+    tile_rows: jax.Array
+
+
+def launch_stats(loads) -> jax.Array:
+    """A routed launch's second output (``scoring/pipeline.CausalText``),
+    ``i32[3, routed layers]`` from each routed layer's ``ExpertLoad``: the
+    largest group, the pairs held, the rows visited."""
+    return jnp.stack([
+        jnp.stack([jnp.max(load.group_sizes), jnp.sum(load.group_sizes),
+                   load.tile_rows]) for load in loads], axis=1)
+
+
 def apply_experts(layer: Dict, x: jax.Array, experts: jax.Array,
                   weights: jax.Array, *, real: Optional[jax.Array] = None,
                   router_width: Optional[int] = None, expert_offset: int = 0,
                   use_pallas: bool = False, kernel_interpret: bool = False
-                  ) -> Tuple[jax.Array, jax.Array]:
+                  ) -> Tuple[jax.Array, ExpertLoad]:
     """``sum_e weights[n, e] * expert_e(x[n])`` for the routed ``experts``:
-    ``(f32[N, hidden], group_sizes i32[held experts])``. ``x`` is ``[N,
+    ``(f32[N, hidden], ExpertLoad)``. ``x`` is ``[N,
     hidden]``. Every (token, expert) pair of a row that ``real`` (``bool[N]``;
     None: every row) admits is computed; the other rows' pairs enter no
     group and their result is zero.
@@ -323,6 +344,9 @@ def apply_experts(layer: Dict, x: jax.Array, experts: jax.Array,
         # where each pair's row went: the inverse permutation
         home = jnp.zeros_like(order).at[order].set(
             jnp.arange(order.shape[0], dtype=jnp.int32))
+        tile_rows = gated_tile_rows(
+            group_sizes, order.shape[0], *layer["gate_proj"].shape[1:],
+            use_pallas=use_pallas)
     with jax.named_scope(scopes.EXPERTS_DISPATCH):
         # matmul operands take the stored dtype of the weights (bfloat16
         # as deployed; float32 weights make a float32 program, for tests)
@@ -354,7 +378,7 @@ def apply_experts(layer: Dict, x: jax.Array, experts: jax.Array,
             # the kernel never wrote the rows past the last group
             # (ops/grouped_matmul.py): whatever they hold, it stops here
             y = jnp.where(real[:, None], y, 0.0)
-    return y, group_sizes
+    return y, ExpertLoad(group_sizes, tile_rows)
 
 
 def olmoe_attention(layer: Dict, h: jax.Array, attention_mask: jax.Array,
@@ -422,7 +446,7 @@ def routed_block(layer: Dict, x: jax.Array,
                  expert_offset: int = 0,
                  use_pallas: bool = False, kernel_interpret: bool = False):
     """The routed half of a sparse block on the normed rows ``x`` ``f32[N,
-    hidden]`` of every slot of a launch: ``(y f32[N, hidden], group_sizes,
+    hidden]`` of every slot of a launch: ``(y f32[N, hidden], ExpertLoad,
     carry)``. ``slots`` is the launch's ``token_slots``; under a capacity
     the real slots' rows are gathered into ``[C, hidden]`` first and the
     result scattered home. ``router`` maps those rows to ``(experts,
@@ -441,7 +465,7 @@ def routed_block(layer: Dict, x: jax.Array,
             x = x.at[idx].get(mode="fill", fill_value=0.0)     # [C, width]
     with jax.named_scope(scopes.ROUTER):
         experts, weights, carry = router(x)
-    y, group_sizes = apply_experts(
+    y, load = apply_experts(
         layer, x, experts, weights, real=real, router_width=router_width,
         expert_offset=expert_offset, use_pallas=use_pallas,
         kernel_interpret=kernel_interpret)
@@ -453,7 +477,7 @@ def routed_block(layer: Dict, x: jax.Array,
             # home: the fillers' indices lie past the last slot and drop
             y = jnp.zeros((n, width), y.dtype).at[idx].set(
                 y, mode="drop", indices_are_sorted=True, unique_indices=True)
-    return y, group_sizes, carry
+    return y, load, carry
 
 
 def olmoe_layer(layer: Dict, h: jax.Array, attention_mask: jax.Array,
@@ -461,9 +485,9 @@ def olmoe_layer(layer: Dict, h: jax.Array, attention_mask: jax.Array,
                 slots: Optional[Tuple[Optional[jax.Array], jax.Array]] = None,
                 lengths: Optional[jax.Array] = None,
                 use_pallas: bool = False, kernel_interpret: bool = False
-                ) -> Tuple[jax.Array, jax.Array]:
-    """One pre-norm block on ``h`` ``f32[B, T, hidden]``; also the largest
-    expert group of the layer (``i32[]``). ``slots`` is the launch's
+                ) -> Tuple[jax.Array, ExpertLoad]:
+    """One pre-norm block on ``h`` ``f32[B, T, hidden]``; also the layer's
+    ``ExpertLoad``. ``slots`` is the launch's
     ``token_slots`` (None: every real slot, uncompacted), ``lengths`` its
     rows' real tokens (None: the mask's row sum)."""
     b, t, width = h.shape
@@ -475,14 +499,14 @@ def olmoe_layer(layer: Dict, h: jax.Array, attention_mask: jax.Array,
     with jax.named_scope(scopes.LN):
         x = rms_norm(h, layer["post_attention_layernorm"],
                      config.rms_norm_eps).reshape(b * t, width)
-    y, group_sizes, _ = routed_block(
+    y, load, _ = routed_block(
         layer, x, slots,
         lambda rows: (*route(rows, layer["router"],
                              config.num_experts_per_tok), None),
         use_pallas=use_pallas, kernel_interpret=kernel_interpret)
     with jax.named_scope(scopes.LN):
         h = h + y.reshape(b, t, width)
-    return h, jnp.max(group_sizes)
+    return h, load
 
 
 def olmoe_encode(params: Dict, input_ids: jax.Array,
@@ -491,7 +515,8 @@ def olmoe_encode(params: Dict, input_ids: jax.Array,
                  use_pallas: bool = False, kernel_interpret: bool = False
                  ) -> Tuple[jax.Array, jax.Array]:
     """Hidden states before the final norm ``f32[B, T, hidden]`` and the
-    largest expert group of each layer ``i32[layers]``. ``capacity``: the
+    launch's statistics ``i32[3, layers]`` (``launch_stats``: each layer's
+    largest expert group, held pairs and visited rows). ``capacity``: the
     token slots the routed blocks are compiled for (the module's
     docstring)."""
     t = input_ids.shape[1]
@@ -500,15 +525,15 @@ def olmoe_encode(params: Dict, input_ids: jax.Array,
     lengths = jnp.sum(attention_mask.astype(jnp.int32), axis=-1)
     with jax.named_scope(scopes.EMBED):
         h = params["embed_tokens"][input_ids].astype(jnp.float32)
-    peaks = []
+    loads = []
     for i, layer in enumerate(params["layers"]):
         with jax.named_scope(scopes.layer_scope(i)):
-            h, peak = olmoe_layer(layer, h, attention_mask, config, cos, sin,
-                                  slots=slots, lengths=lengths,
-                                  use_pallas=use_pallas,
-                                  kernel_interpret=kernel_interpret)
-        peaks.append(peak)
-    return h, jnp.stack(peaks)
+            h, load = olmoe_layer(
+                layer, h, attention_mask, config, cos, sin, slots=slots,
+                lengths=lengths, use_pallas=use_pallas,
+                kernel_interpret=kernel_interpret)
+        loads.append(load)
+    return h, launch_stats(loads)
 
 
 def last_token_logits(params: Dict, hidden: jax.Array,
@@ -531,12 +556,12 @@ def olmoe_logits(params: Dict, input_ids: jax.Array,
                  use_pallas: bool = False, kernel_interpret: bool = False
                  ) -> Tuple[jax.Array, jax.Array]:
     """Sequence-classification logits ``f32[B, num_labels]`` from the last
-    real token, and ``i32[layers]`` largest expert group per layer."""
-    hidden, peaks = olmoe_encode(params, input_ids, attention_mask, config,
+    real token, and the launch's statistics ``i32[3, layers]``."""
+    hidden, stats = olmoe_encode(params, input_ids, attention_mask, config,
                                  capacity=capacity, use_pallas=use_pallas,
                                  kernel_interpret=kernel_interpret)
     return last_token_logits(params, hidden, attention_mask,
-                             config.rms_norm_eps), peaks
+                             config.rms_norm_eps), stats
 
 
 def olmoe_predict(params: Dict, input_ids: jax.Array,
@@ -545,10 +570,12 @@ def olmoe_predict(params: Dict, input_ids: jax.Array,
                   use_pallas: bool = False, kernel_interpret: bool = False,
                   with_stats: bool = False):
     """Fraud probability ``f32[B]`` = ``softmax(logits)[:, 1]``; with
-    ``with_stats`` also the ``i32[layers]`` largest expert group per layer
-    (what ``StreamJob.counters['expert_peak_rows']`` sums)."""
-    logits, peaks = olmoe_logits(params, input_ids, attention_mask, config,
+    ``with_stats`` also the launch's statistics ``i32[3, layers]``: each
+    layer's largest expert group, held pairs and rows the fused kernel's
+    grid visited (what ``StreamJob.counters['expert_peak_rows']``,
+    ``['expert_rows']`` and ``['expert_tile_rows']`` sum)."""
+    logits, stats = olmoe_logits(params, input_ids, attention_mask, config,
                                  capacity=capacity, use_pallas=use_pallas,
                                  kernel_interpret=kernel_interpret)
     p = jax.nn.softmax(logits, axis=-1)[:, 1]
-    return (p, peaks) if with_stats else p
+    return (p, stats) if with_stats else p

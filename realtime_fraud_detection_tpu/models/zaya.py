@@ -75,10 +75,12 @@ import jax
 import jax.numpy as jnp
 
 from realtime_fraud_detection_tpu.models.olmoe import (
+    ExpertLoad,
     _proj,
     apply_rope,
     choose_experts,
     last_token_logits,
+    launch_stats,
     rms_norm,
     rope_tables,
     routed_block,
@@ -372,10 +374,10 @@ def zaya_layer(layer: Dict, h: jax.Array, r: Optional[jax.Array],
                attention_mask: jax.Array, config: ZayaConfig, cos, sin, *,
                slots: Optional[Tuple[Optional[jax.Array], jax.Array]] = None,
                use_pallas: bool = False, kernel_interpret: bool = False
-               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+               ) -> Tuple[jax.Array, jax.Array, ExpertLoad]:
     """One block on ``h`` ``f32[B, T, hidden]`` and the previous layer's
     router state ``r`` (None in the first layer; on the launch's routed
-    slots): ``(h, r, largest expert group i32[])``."""
+    slots): ``(h, r, the layer's ExpertLoad)``."""
     b, t, width = h.shape
     if slots is None:
         slots = token_slots(attention_mask, None)
@@ -385,12 +387,12 @@ def zaya_layer(layer: Dict, h: jax.Array, r: Optional[jax.Array],
     with jax.named_scope(scopes.LN):
         x = rms_norm(h, layer["post_attention_layernorm"],
                      config.rms_norm_eps).reshape(b * t, width)
-    y, group_sizes, r = routed_block(
+    y, load, r = routed_block(
         layer, x, slots, lambda rows: zaya_route(layer, rows, r, config),
         use_pallas=use_pallas, kernel_interpret=kernel_interpret)
     with jax.named_scope(scopes.LN):
         h = h + y.reshape(b, t, width)
-    return h, r, jnp.max(group_sizes)
+    return h, r, load
 
 
 def zaya_encode(params: Dict, input_ids: jax.Array,
@@ -400,22 +402,22 @@ def zaya_encode(params: Dict, input_ids: jax.Array,
                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Hidden states before the final norm ``f32[B, T, hidden]``, the last
     layer's router state on the routed slots ``f32[C, router_hidden]``, and
-    the largest expert group of each layer ``i32[layers]``. ``capacity``:
-    the token slots the routed blocks are compiled for
+    the launch's statistics ``i32[3, layers]`` (``olmoe.launch_stats``).
+    ``capacity``: the token slots the routed blocks are compiled for
     (``models/olmoe.py``)."""
     cos, sin = rope_tables(input_ids.shape[1], config.rotary_dim,
                            config.rope_theta)
     slots = token_slots(attention_mask, capacity)
     with jax.named_scope(scopes.EMBED):
         h = params["embed_tokens"][input_ids].astype(jnp.float32)
-    r, peaks = None, []
+    r, loads = None, []
     for i, layer in enumerate(params["layers"]):
         with jax.named_scope(scopes.layer_scope(i)):
-            h, r, peak = zaya_layer(layer, h, r, attention_mask, config, cos,
-                                    sin, slots=slots, use_pallas=use_pallas,
-                                    kernel_interpret=kernel_interpret)
-        peaks.append(peak)
-    return h, r, jnp.stack(peaks)
+            h, r, load = zaya_layer(
+                layer, h, r, attention_mask, config, cos, sin, slots=slots,
+                use_pallas=use_pallas, kernel_interpret=kernel_interpret)
+        loads.append(load)
+    return h, r, launch_stats(loads)
 
 
 def zaya_logits(params: Dict, input_ids: jax.Array,
@@ -424,12 +426,12 @@ def zaya_logits(params: Dict, input_ids: jax.Array,
                 use_pallas: bool = False, kernel_interpret: bool = False
                 ) -> Tuple[jax.Array, jax.Array]:
     """Sequence-classification logits ``f32[B, num_labels]`` from the last
-    real token, and ``i32[layers]`` largest expert group per layer."""
-    hidden, _, peaks = zaya_encode(
+    real token, and the launch's statistics ``i32[3, layers]``."""
+    hidden, _, stats = zaya_encode(
         params, input_ids, attention_mask, config, capacity=capacity,
         use_pallas=use_pallas, kernel_interpret=kernel_interpret)
     return last_token_logits(params, hidden, attention_mask,
-                             config.rms_norm_eps), peaks
+                             config.rms_norm_eps), stats
 
 
 def zaya_predict(params: Dict, input_ids: jax.Array,
@@ -438,10 +440,10 @@ def zaya_predict(params: Dict, input_ids: jax.Array,
                  use_pallas: bool = False, kernel_interpret: bool = False,
                  with_stats: bool = False):
     """Fraud probability ``f32[B]`` = ``softmax(logits)[:, 1]``; with
-    ``with_stats`` also the ``i32[layers]`` largest expert group per layer
+    ``with_stats`` also the launch's statistics ``i32[3, layers]``
     (``olmoe_predict``'s second output)."""
-    logits, peaks = zaya_logits(params, input_ids, attention_mask, config,
+    logits, stats = zaya_logits(params, input_ids, attention_mask, config,
                                 capacity=capacity, use_pallas=use_pallas,
                                 kernel_interpret=kernel_interpret)
     p = jax.nn.softmax(logits, axis=-1)[:, 1]
-    return (p, peaks) if with_stats else p
+    return (p, stats) if with_stats else p

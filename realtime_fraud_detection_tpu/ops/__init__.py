@@ -24,9 +24,10 @@ a predicate admits holds the kernel, everything else the XLA form.
   sparse layer. ``grouped_gated_matmul`` (gate, up and the SiLU ⊙ product
   as ONE grouped kernel, ``gated_gmm``: two right-hand blocks a step, the
   product rounded once in the epilogue) against two ``jax.lax.ragged_dot``
-  calls and the product; ``grouped_matmul`` (down: ``megablox.gmm`` with
-  per-shape tilings) against ``ragged_dot``; ``grouped_matmul_supported``,
-  the one predicate of both.
+  calls and the product; ``grouped_matmul`` (down: ``megablox.gmm``)
+  against ``ragged_dot``; ``grouped_matmul_supported``, the one predicate
+  of both, and ``gmm_tiling``, the one tile rule of both (from the call's
+  shapes and group count alone).
 - ``ssd_scan``: the state-space scan of a Mamba-2 mixer
   (``models/falcon_h1.py``), ``ssd_scan(..., use_pallas=True)`` — the chunked
   algorithm as ONE kernel a layer, a grid over (row, group, chunk) with the

@@ -14,9 +14,8 @@ Two entry points, each with two forms:
   and what any shape the kernel does not take runs on the chip. The Pallas
   form is ``jax.experimental.pallas.ops.tpu.megablox.gmm`` (a grid over row
   tiles, each tile multiplied by the matrix of the group that owns it, tiles
-  that straddle two groups visited once per group with a row mask), with the
-  tiling chosen here for the encoders' shapes, K 2048 / N 1024 and K 1024 /
-  N 2048 at thousands of rows a group. On the v5e it is a quarter to a third
+  that straddle two groups visited once per group with a row mask), at the
+  tile ``gmm_tiling`` gives its shapes. On the v5e it is a quarter to a third
   faster than XLA's own lowering of ``ragged_dot`` (itself a grouped kernel,
   not per-group dense work), and — unlike that lowering, whose custom calls
   are named ``ragged-dot-none`` whatever scope they were traced under — it
@@ -35,12 +34,15 @@ Two entry points, each with two forms:
 ``grouped_matmul_supported`` is the ONE predicate on shapes for both: the
 traced guards below, the scorer's selector
 (``FraudScorer.effective_use_pallas``) and the tests all ask it.
+``gmm_tiling`` is the ONE tile rule of both, a function of the call's
+``(m, k, n)`` and group count alone; ``gated_tile_rows`` counts what the
+fused kernel's grid then visits.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -48,22 +50,42 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
-# a row tile is one group's rows against one [tk, tn] block of that group's
-# matrix, so the larger the row tile the fewer times a matrix block crosses
-# HBM
-MAX_TM = 512
-# the whole contraction in one block where it fits (no accumulator passes),
-# and as wide a result block as keeps tk x tn at a million elements: with 512
-# rows that is 2 MB of lhs, 2 MB of rhs and 1-2 MB of f32 result, double
-# buffered, plus the f32 accumulator — inside the 16 MB a kernel may use on
-# the v5e ((512, 2048, 1024) is refused there: out of VMEM).
-# Measured on a v5e at 262,144 rows in 64 groups (PERF.md, PR 26), even /
-# skewed groups: K 2048 -> N 1024 with (512, 2048, 512) 7.2 / 7.3 ms against
-# 8.0 / 8.7 at (512, 1024, 1024) and XLA's own ragged_dot 9.7 / 10.5; K 1024
-# -> N 2048 with (512, 1024, 1024) 7.3 / 7.5 ms against 12.4 / 13.9 at
-# (512, 2048, 512) and ragged_dot 11.7 / 12.4.
-MAX_TK = 2048
-BLOCK_ELEMENTS = 1024 * 1024
+# --- the tile rule's constants, from the two kernels alone on a v5e at the
+# four routed encoders' shapes, both capacity rungs, even and skewed groups,
+# and at six shapes of the smaller buckets (tools/grouped_alone.py ->
+# tools/grouped_alone_pr47.json; PERF.md section 6, PR 47). One call of the
+# fused gate / up kernel at the 3/4 rung, ms, (tm, K, N) with K whole:
+#   OLMoE   196,608 rows / 64 groups,  2048 -> 1024: (256,.,1024) 8.42,
+#           (128,.,1024) 8.59, (512,.,1024) 8.98, (512,.,512) 9.08 [PR 46's]
+#   ZAYA1    24,576 / 16,  2048 -> 2048: (128,.,2048) 2.40 = (256,.,2048),
+#           (256,.,1024) 2.45, (512,.,2048) 2.70, (512,.,512) 2.71 [PR 46's]
+#   Laguna  122,880 / 64,  3072 -> 1024: (128,.,1024) 3.16 = (256,.,1024),
+#           (512,.,1024) 4.09, (512, 1024, 1024) 4.82 [PR 46's: K in three]
+#   JoyAI    98,304 / 256, 2048 -> 768:  (128,.,768) 5.16, (256,.,768) 5.19,
+#           (256,.,384) 5.41, (512,.,768) 7.00, (512,.,256) 7.18 [PR 46's]
+# and of down's megablox.gmm: OLMoE (256,.,2048) 4.58 against (256,.,1024)
+# 4.80 and PR 46's (512,.,1024) 4.89; JoyAI (128, 768, 2048) 3.23 against
+# (256, 768, 2048) 3.65 and PR 46's (512, 256, 2048) 4.87. Every tile with K
+# in one block gave the same bits on every real row (452 timings).
+#
+# A row tile: 512 rows lost at every shape timed (a tile straddles more
+# groups, and every group that touches a tile computes all of it), so 256 or
+# 128. 256 where a group holds at least ROWS_PER_TILE tiles of it by the
+# upper estimate m // groups, else 128: at 310-560 real rows a group (JoyAI,
+# and Laguna, whose chip holds a quarter of the pairs m counts) 128 ties in
+# the fused kernel and wins by 3-11% in down's; at 1,300-3,600 (ZAYA1,
+# OLMoE) 256 ties or wins by up to 2% and 7%; in the smaller buckets (64 to
+# 1,024 rows a group) 128 is within 1.2% of the best tile timed in the fused
+# kernel and 2.1% in down's, at 0.51-0.89 of PR 46's 512.
+ROW_TILES = (256, 128)
+ROWS_PER_TILE = 8
+# what the fused kernel's call may name: half the 128 MiB of a v5e core's
+# VMEM (ZAYA1's whole 2048 x 2048 pair of blocks at 256 rows names 52 MB)
+GATED_VMEM_CEILING = 64 << 20
+# down's call is megablox's and gets the 16 MB a call may use unasked; 3 MB
+# of it are left to Mosaic, which reported up to 2.3 MB of its own past
+# ``gmm_vmem_bytes`` where it refused a tile
+GMM_VMEM_BUDGET = 13 << 20
 
 
 def grouped_matmul_supported(m: int, k: int, n: int) -> bool:
@@ -73,21 +95,85 @@ def grouped_matmul_supported(m: int, k: int, n: int) -> bool:
             and n % LANES == 0)
 
 
-def _largest_tile(size: int, limit: int) -> int:
-    """The largest power-of-two multiple of a lane tile that divides ``size``
-    and is at most ``limit``."""
-    tile = LANES
-    while tile * 2 <= limit and size % (tile * 2) == 0:
-        tile *= 2
-    return tile
+def gated_vmem_bytes(tm: int, tk: int, tn: int, operand_bytes: int = 2,
+                     out_bytes: int = 2) -> int:
+    """The VMEM ``gated_gmm`` names for a call at ``(tm, tk, tn)``: the row
+    block, the two matrix blocks and the result block double-buffered, six
+    f32 ``[tm, tn]`` tiles (the two products, the two accumulators, the
+    epilogue's temporaries), and 4 MB for what Mosaic keeps itself."""
+    blocks = (tm * tk * operand_bytes + 2 * tk * tn * operand_bytes
+              + tm * tn * out_bytes)
+    return 2 * blocks + 6 * tm * tn * 4 + (4 << 20)
 
 
-def gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
-    """(tm, tk, tn) for a supported shape."""
-    tm = _largest_tile(m, MAX_TM)
-    tk = _largest_tile(k, MAX_TK)
-    tn = _largest_tile(n, BLOCK_ELEMENTS // tk)
-    return tm, tk, tn
+def gmm_vmem_bytes(tm: int, tk: int, tn: int, operand_bytes: int = 2) -> int:
+    """What ``megablox.gmm`` holds in VMEM at ``(tm, tk, tn)``, f32 result:
+    the row and matrix blocks double-buffered, the result block and the
+    accumulator (what Mosaic reports, to the byte, where it refuses a tile
+    for the 16 MB a call gets unasked: ``tools/grouped_alone.py --aot``)."""
+    return (2 * (tm * tk + tk * tn) * operand_bytes + 2 * tm * tn * 4)
+
+
+def _lane_divisors(size: int) -> List[int]:
+    """Every whole number of lane tiles that divides ``size``, widest
+    first."""
+    lanes = size // LANES
+    return [d * LANES for d in range(lanes, 0, -1) if lanes % d == 0]
+
+
+def gmm_tiling(m: int, k: int, n: int, groups: int, *, gated: bool = False
+               ) -> Tuple[int, int, int]:
+    """(tm, tk, tn) for a supported ``[m, k] x [groups, k, n]`` call, of the
+    fused gate / up kernel (``gated``) or of down's ``megablox.gmm``: a
+    function of the shapes and of nothing else, in this order.
+
+    1. K whole in one block wherever the call's budget holds it beside the
+       narrowest row and result tiles: no accumulator pass, and any two
+       tile choices give the same bits on the real rows. (Else the widest
+       divisor of K in lane tiles that does fit.)
+    2. N as wide as the budget then allows, any whole number of lane tiles
+       that divides N (768 whole, 1536 of 3072): each further block of N is
+       another pass of the rows through HBM.
+    3. The row tile: the largest of ``ROW_TILES`` that divides ``m``, still
+       fits beside (1) and (2), and of which a group holds at least
+       ``ROWS_PER_TILE`` — by ``m // groups``, which is an UPPER estimate
+       of a group's rows: padding and the pairs of experts held elsewhere
+       sort last and belong to no group (Laguna's layer holds a quarter of
+       the pairs ``m`` counts).
+
+    The budget is ``gated_vmem_bytes`` under ``GATED_VMEM_CEILING`` for the
+    fused kernel, which names it, and ``gmm_vmem_bytes`` under
+    ``GMM_VMEM_BUDGET`` for down's; both count bfloat16 operands, as
+    deployed."""
+    room, ceiling = ((gated_vmem_bytes, GATED_VMEM_CEILING) if gated
+                     else (gmm_vmem_bytes, GMM_VMEM_BUDGET))
+
+    def widest(sides, tile):
+        return next((t for t in sides if room(*tile(t)) <= ceiling), LANES)
+
+    tk = widest(_lane_divisors(k), lambda t: (LANES, t, LANES))
+    tn = widest(_lane_divisors(n), lambda t: (LANES, tk, t))
+    rows = [t for t in ROW_TILES
+            if m % t == 0 and t * ROWS_PER_TILE <= m // groups]
+    return widest(rows, lambda t: (t, tk, tn)), tk, tn
+
+
+def gated_tile_rows(group_sizes: jax.Array, m: int, k: int, n: int, *,
+                    use_pallas: bool) -> jax.Array:
+    """``i32[]``: the rows the grid of ``grouped_gated_matmul``'s kernel
+    visits for ``group_sizes`` (``i32[G]``) of an ``[m, k] x [G, k, n]``
+    call — over the non-empty groups, the row tiles a group's span of rows
+    touches, times the row tile (``megablox``'s schedule: a tile that
+    straddles groups is visited once for each). The real rows over it is
+    how full the visited tiles were. 0 where the call runs the XLA form,
+    which visits no tile. A few integer operations on ``i32[G]``."""
+    if not (use_pallas and grouped_matmul_supported(m, k, n)):
+        return jnp.zeros((), jnp.int32)
+    tm = gmm_tiling(m, k, n, group_sizes.shape[0], gated=True)[0]
+    sizes = group_sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    tiles = (ends + tm - 1) // tm - (ends - sizes) // tm
+    return jnp.sum(jnp.where(sizes > 0, tiles, 0)) * tm
 
 
 def grouped_matmul_reference(lhs: jax.Array, rhs: jax.Array,
@@ -110,7 +196,7 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
         return gmm(lhs, rhs, group_sizes.astype(jnp.int32), jnp.float32,
-                   gmm_tiling(m, k, n), interpret=interpret)
+                   gmm_tiling(m, k, n, rhs.shape[0]), interpret=interpret)
     return grouped_matmul_reference(lhs, rhs, group_sizes)
 
 
@@ -186,15 +272,11 @@ def gated_gmm(lhs: jax.Array, gate_w: jax.Array, up_w: jax.Array,
         return row_tiles[visit], n_i
 
     out_dtype = jnp.dtype(out_dtype)
-    tile = tm * tn * 4
     matrix = pl.BlockSpec((None, tk, tn), matrix_at)
-    # in and out blocks double-buffered, and six f32 tiles: the two
-    # products, the two accumulators and the epilogue's temporaries. At
-    # (512, 2048, 512) that is 13 + 6 MB, over the 16 MB a call may use on
-    # the v5e unasked, so the call names its own budget
-    vmem = (2 * (tm * tk * lhs.dtype.itemsize
-                 + 2 * tk * tn * gate_w.dtype.itemsize
-                 + tm * tn * out_dtype.itemsize) + 6 * tile + (4 << 20))
+    # over the 16 MB a call may use on the v5e unasked at every tile the
+    # rule picks for the encoders' shapes, so the call names its own budget
+    vmem = gated_vmem_bytes(tm, tk, tn, lhs.dtype.itemsize,
+                            out_dtype.itemsize)
     return pl.pallas_call(
         functools.partial(_gated_kernel, tm=tm, tn=tn, tiles_k=tiles_k),
         name="gated_gmm",
@@ -232,8 +314,9 @@ def grouped_gated_matmul(rows: jax.Array, gate_w: jax.Array,
     m, k = rows.shape
     n = gate_w.shape[-1]
     if use_pallas and grouped_matmul_supported(m, k, n):
+        tiling = gmm_tiling(m, k, n, gate_w.shape[0], gated=True)
         return gated_gmm(rows, gate_w, up_w, group_sizes.astype(jnp.int32),
-                         out_dtype=out_dtype, tiling=gmm_tiling(m, k, n),
+                         out_dtype=out_dtype, tiling=tiling,
                          interpret=interpret)
     gate = grouped_matmul_reference(rows, gate_w, group_sizes)
     up = grouped_matmul_reference(rows, up_w, group_sizes)

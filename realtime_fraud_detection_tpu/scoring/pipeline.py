@@ -131,11 +131,14 @@ class CausalText:
     ``routed`` (the four sparse-expert encoders): ``predict`` takes
     ``capacity`` (the token slots its routed blocks are compiled for,
     ``scoring/text_split.py``) and, with ``with_stats``, also returns its
-    launch's statistics: ``i32[layers]``, the largest expert group of each
-    routed layer — or ``i32[2, layers]`` with, under it, the (token, expert)
-    pairs that entered a held expert's group, where a layer holds a share
-    of the experts its router chooses among (the scorer then counts
-    ``expert_rows`` from the device, not from the mask). The class also
+    launch's statistics ``i32[3, layers]`` (``models/olmoe.launch_stats``),
+    of each routed layer: the largest expert group; the (token, expert)
+    pairs that entered a held expert's group (all the routers chose,
+    unless a layer holds a share of the experts its router chooses among);
+    and the rows the fused gate / up kernel's grid visited for them (0 in
+    the XLA form). The scorer reads them at finalize into
+    ``expert_peak_rows``, ``expert_rows`` and ``expert_tile_rows``. The
+    class also
     spells ``num_experts`` (the experts a layer HOLDS: the groups of the
     grouped matmul), ``num_experts_per_tok``, ``intermediate_size`` (ONE
     expert's width) — what the counters and the grouped matmul's shape
@@ -205,8 +208,7 @@ def text_predict(params: Dict[str, Any], input_ids: jax.Array,
                  ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """The text branch's probability ``f32[B]`` from the encoder
     ``config``'s class names, and that encoder's per-launch statistics
-    (``CausalText``: ``i32[layers]`` largest expert group for a routed
-    encoder, ``i32[2, layers]`` where it holds a share of its experts),
+    (``CausalText``: ``i32[3, layers]`` for a routed encoder),
     ``None`` for an encoder without routed blocks (whose program then has
     the one result). ``capacity`` is a routed encoder's: the token slots
     its routed blocks are compiled for."""
@@ -459,9 +461,9 @@ def _score_fused_packed_impl(
     ``core.packing.pack_tree`` (one h2d payload) and returns the §2.7
     response fields as ONE f32[B, 8+M] matrix (one d2h payload) laid out per
     ``OUT_COLUMNS`` + model_predictions — and, with a routed text encoder
-    only, a second small output beside it, ``(matrix, i32[layers])``: the largest
-    expert group of each layer (``CausalText``: ``i32[2, layers]`` from an
-    encoder that holds a share of its experts); ``text_capacity`` is that
+    only, a second small output beside it, ``(matrix, i32[3, layers])``:
+    each routed layer's largest expert group, held pairs and visited rows
+    (``CausalText``); ``text_capacity`` is that
     encoder's too (how many token slots its routed blocks run on: ``models/olmoe.py``;
     absent from a dense launch). XLA fuses the unpack slices into
     the branch consumers, so the repack costs nothing on-device. What the

@@ -124,15 +124,18 @@ class PendingScore:
     # A routed text encoder only (pipeline.routed_text; 0 / None otherwise):
     # ``routed_pairs`` = the (token, expert) pairs the routers chose (the
     # launch's real tokens x experts per token x sparse layers, counted at
-    # dispatch: padding is not routed); ``expert_rows`` = those that entered
-    # a held expert's group of the grouped matmuls: all of them where a
-    # layer holds every expert, else read from the device at finalize (the
-    # second row of ``text_stats``);
-    # ``text_stats`` = the program's second output, i32[layers] largest
-    # expert group, read at finalize into ``expert_peak_rows`` (sum over
-    # layers of largest group x num_experts: what the launch would cost if
-    # every group were as large as the largest). Their ratio is 1.0 under
-    # even routing. ``attn_visible_pairs_full`` / ``_sliding`` = the
+    # dispatch: padding is not routed). ``text_stats`` = the program's
+    # second output, i32[3, sparse layers] (pipeline.CausalText), read at
+    # finalize into three counters: ``expert_rows`` = the pairs that
+    # entered a held expert's group of the grouped matmuls (all of
+    # ``routed_pairs`` where a layer holds every expert);
+    # ``expert_peak_rows`` = sum over layers of largest group x
+    # num_experts, what the launch would cost if every group were as large
+    # as the largest (their ratio is 1.0 under even routing);
+    # ``expert_tile_rows`` = the rows the fused gate / up kernel's grid
+    # visited for them, visits x row tile (0 where the launch ran the XLA
+    # form: ``expert_rows`` over it is how full the visited tiles were).
+    # ``attn_visible_pairs_full`` / ``_sliding`` = the
     # (query, key) pairs a real query sees in ONE causal layer of each kind,
     # summed over the launched rows from their lengths L: L(L+1)/2, and
     # sum_i min(i+1, window) where the encoder has a ``sliding_window``.
@@ -142,6 +145,7 @@ class PendingScore:
     routed_pairs: int = 0
     expert_rows: int = 0
     expert_peak_rows: int = 0
+    expert_tile_rows: int = 0
     attn_visible_pairs_full: int = 0
     attn_visible_pairs_sliding: int = 0
     text_stats: Optional[Any] = None
@@ -1507,9 +1511,6 @@ class FraudScorer:
                                                for la in launches),
                             real_tokens=real_tokens,
                             routed_pairs=routed_pairs,
-                            # where a layer holds a share of its experts,
-                            # finalize reads what it took from the device
-                            expert_rows=routed_pairs,
                             attn_visible_pairs_full=visible_full,
                             attn_visible_pairs_sliding=visible_sliding,
                             text_stats=text_stats,
@@ -1612,7 +1613,8 @@ class FraudScorer:
                 token_mask=np.zeros_like(np.asarray(batch.token_mask)))
             rungs = text_split.capacities(slots)
             with self.spans.span(scopes.BUILD_PROGRAMS, rows=size,
-                                 programs=len(rungs)):
+                                 programs=len(rungs),
+                                 **self._expert_tiles(rungs, width)):
                 for rung in rungs:
                     warm = _Launch(None, n, size, width, capacity=rung)
                     self._pack_launch(empty, warm)
@@ -1622,6 +1624,37 @@ class FraudScorer:
         return _Launch(None, n, size, width,
                        capacity=text_split.capacity(real_tokens, slots),
                        tokens=real_tokens)
+
+    def _expert_tiles(self, rungs: Sequence[int], text_len: int
+                      ) -> Dict[str, str]:
+        """The id ``tiles`` of a routed bucket's ``build_programs`` span:
+        for each of its ``rungs`` (token slots), the (tm, tk, tn) its two
+        grouped calls run at, ``<slots>:<gate / up's>+<down's>`` joined by
+        commas, so that a trace and every compile-ledger record the span
+        caused say which tiles the programs hold. The host's mirror of what
+        the traced code asks (``ops.grouped_matmul.gmm_tiling``, by the
+        same shapes), as ``_record_kernel_dispatch`` mirrors the
+        predicates; nothing where the launch runs the XLA form."""
+        from realtime_fraud_detection_tpu.ops.grouped_matmul import (
+            gmm_tiling,
+            grouped_matmul_supported,
+        )
+
+        c = self.bert_config
+        hidden, width = c.hidden_size, c.intermediate_size
+        if not self.effective_use_pallas(
+                getattr(self._pool, "program_devices", None), text_len):
+            return {}
+        tiles = []
+        for rung in rungs:
+            rows = rung * c.num_experts_per_tok
+            if grouped_matmul_supported(rows, hidden, width):
+                tiles.append(f"{rung}:" + "+".join(
+                    "x".join(map(str, gmm_tiling(rows, k, n, c.num_experts,
+                                                 gated=gated)))
+                    for gated, k, n in ((True, hidden, width),
+                                        (False, width, hidden))))
+        return {"tiles": ",".join(tiles)} if tiles else {}
 
     def _pack_launch(self, batch: ScoreBatch, launch: "_Launch") -> None:
         """Pad ``launch``'s rows of ``batch`` to its bucket at its text
@@ -1725,14 +1758,16 @@ class FraudScorer:
             else:
                 out = jax.device_get(pending.out)  # blocks until done
             if pending.text_stats is not None:
-                peaks = jax.device_get(pending.text_stats)
-                if peaks.ndim == 2:
-                    # an encoder that holds a share of its experts
-                    # (pipeline.CausalText): the held pairs under the peaks
-                    peaks, held = peaks
-                    pending.expert_rows = int(np.sum(held))
+                # each sparse layer's largest group, held pairs and visited
+                # rows (pipeline.CausalText)
+                peaks, held, tile_rows = (
+                    int(total) for total in np.sum(
+                        jax.device_get(pending.text_stats), axis=1,
+                        dtype=np.int64))
+                pending.expert_rows = held
                 pending.expert_peak_rows = (
-                    int(np.sum(peaks)) * self.bert_config.num_experts)
+                    peaks * self.bert_config.num_experts)
+                pending.expert_tile_rows = tile_rows
         # processing time = assemble/dispatch + device wait; excludes any
         # pipeline queue wait between dispatch() returning and this call
         elapsed_ms = (pending.dispatch_ms

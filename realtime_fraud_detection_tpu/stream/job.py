@@ -287,11 +287,14 @@ class StreamJob:
             # the MoE text encoder only (0 otherwise): the (token, expert)
             # pairs that entered the grouped expert matmuls (real tokens:
             # padding is not routed), what they would be if every expert's
-            # group were as large as the layer's largest, the capacities
+            # group were as large as the layer's largest, the rows the
+            # fused gate / up kernel's grid visited for them (visits x row
+            # tile; 0 in the XLA form), the capacities
             # the routed blocks ran at (at most ``token_slots``) and the
             # batches that took a narrow one (PendingScore.expert_rows /
-            # expert_peak_rows / expert_token_slots / compact_batches)
-            "expert_rows": 0, "expert_peak_rows": 0,
+            # expert_peak_rows / expert_tile_rows / expert_token_slots /
+            # compact_batches)
+            "expert_rows": 0, "expert_peak_rows": 0, "expert_tile_rows": 0,
             "expert_token_slots": 0, "compact_batches": 0,
             # the (token, expert) pairs the routers chose (``expert_rows``
             # of them entered a held expert's group: all, unless a layer
@@ -582,7 +585,8 @@ class StreamJob:
                 scored_ok = True
                 for key in ("token_slots", "token_slots_sq", "real_tokens",
                             "expert_rows", "expert_peak_rows",
-                            "expert_token_slots", "compact_batches",
+                            "expert_tile_rows", "expert_token_slots",
+                            "compact_batches",
                             "routed_pairs", "attn_visible_pairs_full",
                             "attn_visible_pairs_sliding", "ssm_chunks",
                             "short_text_rows", "long_text_rows",

@@ -70,6 +70,7 @@ def experts_through_both_forms():
 
     from realtime_fraud_detection_tpu.models.olmoe import apply_experts
     from realtime_fraud_detection_tpu.ops import grouped_matmul_supported
+    from realtime_fraud_detection_tpu.ops.grouped_matmul import gmm_tiling
     from realtime_fraud_detection_tpu.scoring.text_split import capacities
 
     real_tokens = 2800
@@ -86,12 +87,18 @@ def experts_through_both_forms():
         weights = jnp.asarray(rng.random((slots, top_k)), jnp.float32)
         real = jnp.arange(slots) < real_tokens
         share = dict(router_width=router_width, expert_offset=expert_offset)
-        want, sizes = apply_experts(layer, x, experts, weights, real=real,
-                                    **share)
-        got, sizes_k = apply_experts(layer, x, experts, weights, real=real,
-                                     use_pallas=True, kernel_interpret=True,
-                                     **share)
+        want, (sizes, no_tiles) = apply_experts(
+            layer, x, experts, weights, real=real, **share)
+        got, (sizes_k, tile_rows) = apply_experts(
+            layer, x, experts, weights, real=real, use_pallas=True,
+            kernel_interpret=True, **share)
         np.testing.assert_array_equal(sizes, sizes_k)
+        # the XLA form visits no tile; the kernel's grid whole row tiles,
+        # at least the groups' rows and under a tile more a group
+        tm = gmm_tiling(slots * top_k, hidden, wide, held, gated=True)[0]
+        assert int(no_tiles) == 0 and int(tile_rows) % tm == 0
+        assert 0 <= int(tile_rows) - int(sizes.sum()) < (
+            2 * tm * np.count_nonzero(sizes))
         assert int(sizes.sum()) <= real_tokens * top_k < slots * top_k
         assert np.isfinite(np.asarray(got)).all()
         assert not np.asarray(got)[real_tokens:].any()

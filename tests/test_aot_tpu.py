@@ -234,77 +234,120 @@ def test_deployed_program_holds_the_fused_core_at_bucket_256(
 
 # ---- the routed text encoders (models/olmoe.py, models/zaya.py) at
 # published widths
-OLMOE_ROWS = BUCKET * 128 * 8      # bucket 256 x 128 tokens x 8 experts each
-ZAYA_ROWS = 24576                  # the 3/4 rung of that bucket, one expert
-
-
-@pytest.mark.parametrize("rows,groups,k,n", [
-    (OLMOE_ROWS, 64, 2048, 1024), (OLMOE_ROWS, 64, 1024, 2048),
-    (ZAYA_ROWS, 16, 2048, 2048)], ids=["olmoe_up", "olmoe_down", "zaya1"])
-def test_grouped_matmul_compiles_at_published_widths(one_chip, rows, groups,
-                                                     k, n):
-    """The Pallas grouped matmul at the tilings ``gmm_tiling`` picks for the
-    expert FFNs' shapes: OLMoE's two at 64 ragged groups and 262,144 rows,
-    ZAYA1's one (K = N = 2048) at 16 groups and 24,576. Mosaic refuses
-    (512, 2048, 1024) here for its VMEM; what is picked has to fit."""
-    from realtime_fraud_detection_tpu.ops.grouped_matmul import (
-        gmm_tiling,
-        grouped_matmul,
-    )
-
-    assert gmm_tiling(rows, k, n)[1] * gmm_tiling(rows, k, n)[2] \
-        == 1024 * 1024
-    fn = jax.jit(lambda a, b, g: grouped_matmul(a, b, g, use_pallas=True))
-    compiled = fn.lower(_sds((rows, k), jnp.bfloat16, one_chip),
-                        _sds((groups, k, n), jnp.bfloat16, one_chip),
-                        _sds((groups,), jnp.int32, one_chip)).compile()
-    assert compiled.as_text().count(CUSTOM_CALL) == 1
-    # the XLA form lowers to the compiler's own grouped kernel, whose custom
-    # calls carry no scope in their op_name: why the chip runs the Pallas one
-    xla = jax.jit(lambda a, b, g: grouped_matmul(a, b, g)).lower(
-        _sds((rows, k), jnp.bfloat16, one_chip),
-        _sds((groups, k, n), jnp.bfloat16, one_chip),
-        _sds((groups,), jnp.int32, one_chip)).compile().as_text()
-    assert 'op_name="ragged-dot' in xla
-
-
-# (rows at the 3/4 rung, at every slot; groups; K; N): what each routed cell
-# launches — OLMoE bucket 256 x 128 tokens x 8 experts, ZAYA1 the same slots
-# x 1, Laguna 8 x 2,048 x 10 with 64 of 256 experts held, JoyAI 8 x 2,048 x 8
+# (rows at the 3/4 rung, at every slot; groups; hidden; one expert's width):
+# what each routed cell launches — OLMoE bucket 256 x 128 tokens x 8 experts,
+# ZAYA1 the same slots x 1, Laguna 8 x 2,048 x 10 with 64 of 256 experts
+# held, JoyAI 8 x 2,048 x 8
 GATED_SITES = {"olmoe": (196608, 262144, 64, 2048, 1024),
                "zaya1": (24576, 32768, 16, 2048, 2048),
                "laguna": (122880, 163840, 64, 3072, 1024),
                "joyai": (98304, 131072, 256, 2048, 768)}
+# the grouped calls' rows in every OTHER program a deployment launches, and
+# the cells compile beside their bucket's (the parity sample's bucket 8):
+# core/batching.BATCH_BUCKETS 1, 8, 32, 128 x text_split.capacities x
+# experts a token for the 128-position encoders, bucket 1 of the
+# 2,048-position ones (tests/test_olmoe.py SMALL_LAUNCHES derives them)
+SMALL_ROWS = {"olmoe": (1024, 8192, 24576, 32768, 98304, 131072),
+              "zaya1": (128, 1024, 3072, 4096, 12288, 16384),
+              "laguna": (20480,), "joyai": (16384,)}
+
+
+def _grouped_call(one_chip, rows, groups, k, n, gated):
+    """The lowered text of one grouped call asked for its kernel at
+    ``[rows, k] x [groups, k, n]``, and the tile the rule gave it."""
+    from realtime_fraud_detection_tpu.ops.grouped_matmul import (
+        GATED_VMEM_CEILING,
+        GMM_VMEM_BUDGET,
+        gated_vmem_bytes,
+        gmm_tiling,
+        gmm_vmem_bytes,
+        grouped_gated_matmul,
+        grouped_matmul,
+        grouped_matmul_supported,
+    )
+
+    assert grouped_matmul_supported(rows, k, n)
+    tiling = gmm_tiling(rows, k, n, groups, gated=gated)
+    # K in one block, and the budget the call names (or is given) holds it
+    assert tiling[1] == k
+    if gated:
+        assert gated_vmem_bytes(*tiling) <= GATED_VMEM_CEILING
+        fn = jax.jit(lambda x, a, b, g: grouped_gated_matmul(
+            x, a, b, g, out_dtype=jnp.bfloat16, use_pallas=True))
+    else:
+        assert gmm_vmem_bytes(*tiling) <= GMM_VMEM_BUDGET
+        fn = jax.jit(lambda x, a, b, g: grouped_matmul(
+            x, a, g, use_pallas=True))
+    weights = _sds((groups, k, n), jnp.bfloat16, one_chip)
+    text = fn.lower(_sds((rows, k), jnp.bfloat16, one_chip), weights, weights,
+                    _sds((groups,), jnp.int32, one_chip)).compile().as_text()
+    assert text.count(CUSTOM_CALL) == 1
+    return text, tiling
+
+
+@pytest.mark.parametrize("rung", [0, 1], ids=["three_quarters", "every_slot"])
+@pytest.mark.parametrize("encoder", sorted(GATED_SITES))
+def test_grouped_matmul_compiles_at_published_widths(one_chip, encoder, rung):
+    """down's ``megablox.gmm`` as ONE Mosaic call at the tile ``gmm_tiling``
+    picks, for all four routed encoders at both capacities: the call names
+    no budget, so what is picked has to fit the 16 MB a call gets unasked
+    (Mosaic refuses OLMoE's (512, 1024, 2048) and ZAYA1's (256, 2048, 2048)
+    here: ``tools/grouped_alone.py --aot``)."""
+    *rungs, groups, hidden, width = GATED_SITES[encoder]
+    rows = rungs[rung]
+    text, _ = _grouped_call(one_chip, rows, groups, width, hidden, False)
+    assert f"f32[{rows},{hidden}]" in text
+    if (encoder, rung) != ("olmoe", 1):
+        return
+    # the XLA form lowers to the compiler's own grouped kernel, whose custom
+    # calls carry no scope in their op_name: why the chip runs the Pallas one
+    from realtime_fraud_detection_tpu.ops.grouped_matmul import (
+        grouped_matmul,
+    )
+
+    xla = jax.jit(lambda a, b, g: grouped_matmul(a, b, g)).lower(
+        _sds((rows, width), jnp.bfloat16, one_chip),
+        _sds((groups, width, hidden), jnp.bfloat16, one_chip),
+        _sds((groups,), jnp.int32, one_chip)).compile().as_text()
+    assert 'op_name="ragged-dot' in xla
 
 
 @pytest.mark.parametrize("rung", [0, 1], ids=["three_quarters", "every_slot"])
 @pytest.mark.parametrize("encoder", sorted(GATED_SITES))
 def test_gated_matmul_compiles_at_published_widths(one_chip, encoder, rung):
-    """gate, up and SiLU ⊙ as ONE Mosaic call at the tiles ``gmm_tiling``
-    picks, for all four routed encoders at both capacities: two right-hand
-    blocks a step double the weights' share of VMEM — at (512, 2048, 512)
-    past the 16 MB a call gets unasked — so the call names its own budget,
-    and what Mosaic refuses for VMEM it refuses here."""
-    from realtime_fraud_detection_tpu.ops.grouped_matmul import (
-        gmm_tiling,
-        grouped_gated_matmul,
-        grouped_matmul_supported,
-    )
-
+    """gate, up and SiLU ⊙ as ONE Mosaic call at the tile ``gmm_tiling``
+    picks, for all four routed encoders at both capacities: K and N whole
+    (768 in one block, 3072 in one block; ZAYA1's pair of 2048 x 2048
+    blocks), so two right-hand blocks a step take 12 to 32 MB double
+    buffered — past the 16 MB a call gets unasked — and the call names its
+    own budget, under the rule's ceiling; what Mosaic refuses for VMEM it
+    refuses here."""
     *rungs, groups, k, n = GATED_SITES[encoder]
     rows = rungs[rung]
-    assert grouped_matmul_supported(rows, k, n)
-    assert gmm_tiling(rows, k, n)[0] == 512
-    fn = jax.jit(lambda x, a, b, g: grouped_gated_matmul(
-        x, a, b, g, out_dtype=jnp.bfloat16, use_pallas=True))
-    weights = _sds((groups, k, n), jnp.bfloat16, one_chip)
-    text = fn.lower(_sds((rows, k), jnp.bfloat16, one_chip), weights, weights,
-                    _sds((groups,), jnp.int32, one_chip)).compile().as_text()
-    assert text.count(CUSTOM_CALL) == 1
+    text, tiling = _grouped_call(one_chip, rows, groups, k, n, True)
+    assert tiling[1:] == (k, n)
     assert "jit(gated_gmm)/gated_gmm/pallas_call" in text
     # neither float32 product exists outside the kernel
     assert f"f32[{rows},{n}]" not in text
     assert f"bf16[{rows},{n}]" in text
+
+
+@pytest.mark.parametrize("kernel", ["gated", "down"])
+@pytest.mark.parametrize("encoder,rows", [
+    (encoder, rows) for encoder in sorted(SMALL_ROWS)
+    for rows in SMALL_ROWS[encoder]])
+def test_the_small_buckets_grouped_calls_compile(one_chip, encoder, rows,
+                                                 kernel):
+    """No cell times the programs under its bucket, and a rule from ``rows
+    // groups`` narrows their row tile: each of their grouped calls is held
+    here, ONE Mosaic call inside its budget at the rule's tile (8 to 2,048
+    rows a group: a 128-row tile but for OLMoE's bucket 128 at every
+    slot)."""
+    *_, groups, hidden, width = GATED_SITES[encoder]
+    k, n = (hidden, width) if kernel == "gated" else (width, hidden)
+    _, tiling = _grouped_call(one_chip, rows, groups, k, n, kernel == "gated")
+    assert tiling[0] == (256 if (encoder, rows) == ("olmoe", 131072)
+                         else 128)
 
 
 @pytest.mark.parametrize("bucket,text_len", [

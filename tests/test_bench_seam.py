@@ -356,7 +356,7 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
     common = {"attn_core_ms_per_batch", "text_ms_per_batch",
               "unscoped_device_pct", "hbm_peak_gb", "expert_ffn_ms_per_batch",
               "expert_matmul_ms_per_batch", "router_ms_per_batch",
-              "expert_imbalance_x"}
+              "expert_imbalance_x", "expert_tile_fill_pct"}
     olmoe_only = {"expert_ffn_roofline_pct", "router_roofline_pct"}
     zaya_only = {"cca_mix_ms_per_batch", "cca_mix_roofline_pct",
                  "zaya1_expert_ffn_roofline_pct", "zaya1_router_roofline_pct"}
@@ -396,13 +396,24 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
                    "ssm_scan_ms_per_batch", "falconh1_ssd_scan_roofline_pct",
                    "falconh1_attn_core_roofline_pct",
                    "falconh1_ffn_roofline_pct"]
-    assert [m["name"] for m in BM["per_layer"][-6:]] == falcon_only
-    for m in BM["per_layer"][-6:]:
+    assert [m["name"] for m in BM["per_layer"][-7:-1]] == falcon_only
+    for m in BM["per_layer"][-7:-1]:
         assert m["workloads"] == [FALCON_CELL] and m["layer"] == "kernels"
         assert m["moves"] == "txn_per_s" and m["source"] == "device_trace"
         assert (m["unit"], m["better"]) == (
             ("%", "higher") if m["name"].endswith("roofline_pct")
             else ("ms", "lower"))
+    # PR 47 appended one behind those: a counter's share of the six routed
+    # cells, a data file over a reader the benchmark had
+    fill = BM["per_layer"][-1]
+    assert fill == {
+        "name": "expert_tile_fill_pct", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "kernels",
+        "moves": "txn_per_s", "workloads": ROUTED_CELLS}
+    assert json.loads((ROOT / "benchmarks/layer_metrics"
+                       / "expert_tile_fill_pct.json").read_text()) == {
+        "reader": "counter_share",
+        "args": {"num": "expert_rows", "den": "expert_tile_rows"}}
     assert (by_name[FALCON_CELL]["config"], by_name[FALCON_CELL]["traffic"],
             by_name[FALCON_CELL]["chips"]) == (
         "falcon-h1-34b-s2048", "s2048-remit-saturated", 1)
@@ -423,13 +434,13 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
             assert m["workloads"][-1] == FALCON_CELL, m["name"]
     assert [c["name"] for c in BM["configs"]][-2:] == [
         "joyai-llm-flash-s2048", "falcon-h1-34b-s2048"]
-    for m in BM["per_layer"][-10:-6]:
+    for m in BM["per_layer"][-11:-7]:
         # PR 43 appended its four behind what was there
         assert m["name"] in joyai_only and m["moves"] == "txn_per_s"
         assert m["workloads"] == [JOYAI_CELL] and m["layer"] == "kernels"
     assert len(cells) == 8 and not [w for w in BM["workloads"]
                                     if w["chips"] != 1]
-    per_layer = BM["per_layer"][:-10]    # the checks below: what PR 42 left
+    per_layer = BM["per_layer"][:-11]    # the checks below: what PR 42 left
     # ZAYA1's second cell reports exactly what its first does
     assert reports[ZAYA_FULL_CELL] == reports[ZAYA_CELL]
     # Laguna's: the shared names, its dense layer 0's, and its own six
@@ -577,6 +588,31 @@ def _fake_run(scope_s, counters, cfg=OLMOE_CFG):
         extra={"cfg": cfg, "device": {"kind": "TPU v5 lite"},
                "scope_trace": {"busy_s": 1.0, "scoped": True,
                                "scope_s": scope_s}})
+
+
+@pytest.mark.parametrize("cell", ROUTED_CELLS)
+def test_the_tile_fill_on_a_hand_made_run(cell):
+    """``expert_tile_fill_pct``: the real pairs over the rows the fused
+    kernel's grid visited, in every routed cell and in no other; left out,
+    not raised, against a program that counts no visited rows (the parent,
+    and any program in the XLA form, whose counter stays 0)."""
+    cfg = {OLMOE_CELL: OLMOE_CFG, FULL_CELL: OLMOE_CFG, ZAYA_CELL: ZAYA_CFG,
+           ZAYA_FULL_CELL: ZAYA_CFG, LAGUNA_CELL: LAGUNA_CFG,
+           JOYAI_CELL: JOYAI_CFG}[cell]
+    assert "expert_tile_fill_pct" in {
+        m["name"] for m in spec.metrics_for(cell, "per_layer")}
+    for other in (FALCON_CELL, "s512-fulltext-saturated"):
+        assert "expert_tile_fill_pct" not in {
+            m["name"] for m in spec.metrics_for(other, "per_layer")}
+    read = spec.reader_for("expert_tile_fill_pct", "per_layer")
+    counters = {"batches": 2, "scored": 16, "expert_rows": 79_000,
+                "expert_tile_rows": 112_000}
+    assert read(_fake_run({"text": 0.4}, counters, cfg)) == pytest.approx(
+        100 * 79 / 112)
+    for none in ({"expert_rows": 79_000},
+                 {"expert_rows": 79_000, "expert_tile_rows": 0}):
+        assert read(_fake_run({"text": 0.4}, {"batches": 2, **none},
+                              cfg)) is None
 
 
 def test_the_zaya1_metrics_on_a_hand_made_run():
@@ -1110,10 +1146,13 @@ def test_tiny_rehearsal_of_a_routed_cell(tiny_copy, cell, trace):
             # memo mix a third
             assert (5 < padding < 25) if cell in (FULL_CELL, ZAYA_FULL_CELL) \
                 else (25 < padding < 60)
+        # the CPU runs the XLA form, whose grid visits nothing: the counter
+        # stays 0 and the fill is left out
+        assert "'expert_tile_rows': 0" in proc.stdout
         for name in ("expert_ffn_ms_per_batch", "cca_mix_ms_per_batch",
                      "cca_mix_roofline_pct", "laguna_attn_core_roofline_pct",
                      "shared_expert_ms_per_batch", "attn_latent_ms_per_batch",
-                     "joyai_attn_core_roofline_pct"):
+                     "joyai_attn_core_roofline_pct", "expert_tile_fill_pct"):
             assert name not in out["metrics"]
     else:
         assert set(out["metrics"]) == {"txn_per_s", "setup_s"}

@@ -710,4 +710,4 @@ def test_the_new_encoders_program_has_the_one_result():
 
     assert _lowered(CFG).out_info.shape == (8, 13)
     matrix, stats = _lowered(TINY_OLMOE).out_info
-    assert matrix.shape == (8, 13) and stats.shape == (2,)
+    assert matrix.shape == (8, 13) and stats.shape == (3, 2)

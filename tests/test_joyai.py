@@ -181,11 +181,15 @@ def test_predict_is_the_softmax_of_the_head_and_the_stats_are_the_peaks(
     np.testing.assert_allclose(
         np.asarray(p), np.asarray(jax.nn.softmax(logits, -1)[:, 1]),
         atol=1e-7)
-    assert peaks.shape == (CFG.num_sparse_layers,) == (2,)
+    assert peaks.shape == (3, CFG.num_sparse_layers) == (3, 2)
     np.testing.assert_array_equal(np.asarray(peaks2), np.asarray(peaks))
     pairs = sum(LENGTHS) * CFG.num_experts_per_tok
-    assert (np.asarray(peaks) * CFG.num_experts >= pairs).all()
-    assert (np.asarray(peaks) <= sum(LENGTHS)).all()
+    peaks, held, tile_rows = np.asarray(peaks)
+    # every expert is held: the held pairs are the routers' pairs; the XLA
+    # form visits no tile
+    assert (held == pairs).all() and not tile_rows.any()
+    assert (peaks * CFG.num_experts >= pairs).all()
+    assert (peaks <= sum(LENGTHS)).all()
 
 
 # ------------------------------------------------------------- the router
@@ -428,23 +432,25 @@ def test_the_query_step_tiles_the_sequence():
 
 
 def test_the_grouped_matmul_takes_256_narrow_groups():
-    """256 groups of ~300 rows and a width of 768, a shape the tiling rule
-    had not been asked: 768 is six lane tiles, so the power-of-two tile is a
-    third of it (PERF.md section 7, PR 43, has what a row tile from the mean
-    group read alone on the chip: a ``perf_opt`` PR's, with its own pairs).
-    The kernel at the rule's tiling, interpreted, against ``ragged_dot``:
-    130 ragged groups, some empty, rows past the last group never read."""
+    """256 groups of ~300 rows and a width of 768, six lane tiles: the rule
+    gives both calls K and N whole — 768 in ONE block, where a power-of-two
+    tile was a third of it and the rows crossed HBM three times — and a row
+    tile of 128, under half a group (PERF.md section 6, PR 47: 7.2 -> 5.1
+    and 4.8 -> 3.2 ms a layer alone on the chip). The kernel at the rule's
+    tiling, interpreted, against ``ragged_dot``: 130 ragged groups of seven
+    rows on average, some empty, rows past the last group never read."""
     from realtime_fraud_detection_tpu.ops.grouped_matmul import (
         gmm_tiling,
         grouped_matmul,
         grouped_matmul_reference,
     )
 
-    assert gmm_tiling(98304, 2048, 768) == (512, 2048, 256)
-    assert gmm_tiling(98304, 768, 2048) == (512, 256, 2048)
-    assert gmm_tiling(131072, 2048, 768) == (512, 2048, 256)
+    for rows in (98304, 131072):
+        assert gmm_tiling(rows, 2048, 768, 256, gated=True) == (
+            128, 2048, 768)
+        assert gmm_tiling(rows, 768, 2048, 256) == (128, 768, 2048)
     m, k, n, groups = 1024, 256, 384, 130
-    assert gmm_tiling(m, k, n) == (512, 256, 128)
+    assert gmm_tiling(m, k, n, groups) == (128, 256, 384)
     rng = np.random.default_rng(3)
     sizes = rng.multinomial(900, rng.dirichlet(np.full(groups, 0.5)))
     lhs = jnp.asarray(rng.standard_normal((m, k)), jnp.bfloat16)
@@ -515,7 +521,8 @@ def test_the_encoder_is_the_same_through_the_kernels():
     np.testing.assert_allclose(np.asarray(fused)[real], np.asarray(xla)[real],
                                atol=3e-2)
     assert np.abs(np.asarray(fused) - np.asarray(xla))[real].mean() < 2e-3
-    assert peaks.shape == peaks_k.shape == (1,)
+    assert peaks.shape == peaks_k.shape == (3, 1)
+    np.testing.assert_array_equal(peaks[1], peaks_k[1])
 
 
 def test_the_attention_site_holds_one_custom_call_under_its_scope():

@@ -149,8 +149,9 @@ def test_predict_is_the_softmax_of_the_logits_and_stats_count_held_pairs(
     np.testing.assert_allclose(
         np.asarray(p), np.asarray(jax.nn.softmax(logits, -1)[:, 1]),
         atol=1e-7)
-    peaks, held = np.asarray(stats)
-    assert stats.shape == (2, CFG.num_sparse_layers) == (2, 4)
+    peaks, held, tile_rows = np.asarray(stats)
+    assert stats.shape == (3, CFG.num_sparse_layers) == (3, 4)
+    assert not tile_rows.any()             # the XLA form visits no tile
     np.testing.assert_array_equal(np.asarray(stats2), np.asarray(stats))
     pairs = sum(LENGTHS) * CFG.num_experts_per_tok
     assert (held > 0).all() and (held < pairs).all()
@@ -165,7 +166,7 @@ def _layer_parts(params32, layer_index, x, slots, config):
         lambda rows: laguna.laguna_route(layer, rows, config),
         router_width=config.router_experts,
         expert_offset=config.expert_offset)
-    return y, sizes
+    return y, sizes.group_sizes
 
 
 def test_the_four_shares_and_the_shared_expert_once_are_the_uncut_layer():
@@ -281,7 +282,7 @@ def test_routing_spreads_over_the_router_at_unit_scale_embeddings(params32,
     ids = jax.random.randint(jax.random.PRNGKey(5), (4, 64), 0, CFG.vocab_size)
     mask = jnp.ones((4, 64), bool)
     _, stats = laguna_encode(params32, ids, mask, CFG)
-    peaks, held = np.asarray(stats)
+    peaks, held, _ = np.asarray(stats)
     pairs = 4 * 64 * CFG.num_experts_per_tok
     assert (0.12 < held / pairs).all() and (held / pairs < 0.40).all()
     assert (peaks * CFG.num_experts < 2.5 * held).all()

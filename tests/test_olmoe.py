@@ -33,7 +33,19 @@ from realtime_fraud_detection_tpu.ops import (
     grouped_matmul,
     grouped_matmul_supported,
 )
-from realtime_fraud_detection_tpu.ops.grouped_matmul import gmm_tiling
+from realtime_fraud_detection_tpu.scoring.text_split import capacities
+from realtime_fraud_detection_tpu.ops.grouped_matmul import (
+    GATED_VMEM_CEILING,
+    GMM_VMEM_BUDGET,
+    LANES,
+    ROW_TILES,
+    gated_gmm,
+    gated_tile_rows,
+    gated_vmem_bytes,
+    gmm_tiling,
+    gmm_vmem_bytes,
+    grouped_matmul_reference,
+)
 
 F32 = jnp.float32
 # hidden 128, 2 layers, 2 heads of 64, 8 experts of width 64, 2 per token
@@ -200,7 +212,7 @@ def test_logits_match_the_plain_reference(stored, atol, seed, text):
     got, peaks = olmoe_logits(p, ids, mask, CFG)
     want = ref_logits(p, ids, mask, CFG, routing)
     assert got.shape == (B, CFG.num_labels) and peaks.shape == (
-        CFG.num_hidden_layers,)
+        3, CFG.num_hidden_layers)
     np.testing.assert_allclose(got, want, atol=atol, rtol=0)
     if stored == "float32":
         free = ref_logits(p, ids, mask, CFG)          # its own top-k
@@ -229,6 +241,10 @@ def test_predict_is_the_softmax_of_the_logits(params, text):
     p, stats = olmoe_predict(params, ids, mask, CFG, with_stats=True)
     np.testing.assert_allclose(p, jax.nn.softmax(logits, -1)[:, 1], atol=1e-7)
     np.testing.assert_array_equal(stats, peaks)
+    # each layer's largest group, held pairs, visited rows (XLA form: none)
+    largest, held, tile_rows = np.asarray(stats)
+    assert (held == sum(LENGTHS) * CFG.num_experts_per_tok).all()
+    assert (largest * CFG.num_experts >= held).all() and not tile_rows.any()
     assert olmoe_predict(params, ids, mask, CFG).shape == (B,)
 
 
@@ -270,8 +286,9 @@ def test_apply_experts_given_the_references_routing(params, params32, stored,
         want, probs, _ = _ref_moe(layer, x, CFG.num_experts_per_tok)
     experts = jnp.argsort(-probs, axis=-1)[:, :2].astype(jnp.int32)
     weights = jnp.take_along_axis(probs, experts, axis=-1)
-    got, sizes = apply_experts(layer, x, experts, weights)
+    got, (sizes, tile_rows) = apply_experts(layer, x, experts, weights)
     np.testing.assert_allclose(got, want, atol=atol, rtol=0)
+    assert int(tile_rows) == 0                 # the XLA form visits no tile
     assert int(sizes.sum()) == 96 * 2          # every pair computed: no drop
     np.testing.assert_array_equal(
         sizes, np.bincount(np.asarray(experts).ravel(),
@@ -323,7 +340,7 @@ LAUNCHED = 256
 @pytest.mark.parametrize("stored,atol", [("float32", 1e-4),
                                          ("bfloat16", 2e-2)])
 @pytest.mark.parametrize("form,k", [
-    ("xla", 128), ("pallas_interpret", 128), ("pallas_interpret", 384)],
+    ("xla", 128), ("pallas_interpret", 128), ("pallas_three_k_steps", 384)],
     ids=["xla", "pallas_one_k_step", "pallas_three_k_steps"])
 @pytest.mark.parametrize("case", sorted(GATED_GROUPS))
 def test_grouped_gated_matmul_against_a_loop_over_experts(case, form, k,
@@ -340,10 +357,17 @@ def test_grouped_gated_matmul_against_a_loop_over_experts(case, form, k,
         (jax.random.normal(key, (len(sizes), k, n), F32) * 0.1).astype(dtype)
         for key in keys[1:])
     assert grouped_matmul_supported(LAUNCHED, k, n)
-    assert gmm_tiling(LAUNCHED, k, n)[1] == 128       # K / 128 steps
-    got = grouped_gated_matmul(
-        lhs, gate_w, up_w, jnp.asarray(sizes, jnp.int32), out_dtype=dtype,
-        use_pallas=form != "xla", interpret=True)
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    # the rule keeps K in one block; the kernel's accumulator passes are
+    # what a K too long for the budget would run: held at a tile of its own
+    assert gmm_tiling(LAUNCHED, k, n, len(sizes), gated=True)[1:] == (k, n)
+    if form == "pallas_three_k_steps":
+        got = gated_gmm(lhs, gate_w, up_w, group_sizes, out_dtype=dtype,
+                        tiling=(128, 128, 256), interpret=True)
+    else:
+        got = grouped_gated_matmul(
+            lhs, gate_w, up_w, group_sizes, out_dtype=dtype,
+            use_pallas=form != "xla", interpret=True)
     assert got.dtype == dtype and got.shape == (LAUNCHED, n)
     gate = _loop_over_experts(lhs, gate_w, sizes)
     want = gate / (1.0 + np.exp(-gate)) * _loop_over_experts(lhs, up_w, sizes)
@@ -356,10 +380,6 @@ def test_the_kernel_declines_what_it_cannot_tile():
     assert not grouped_matmul_supported(100, 128, 128)     # rows off a tile
     assert not grouped_matmul_supported(256, 128, 64)      # N under a lane
     assert grouped_matmul_supported(262144, 2048, 1024)
-    # the published shapes get the tilings measured on the v5e
-    assert gmm_tiling(262144, 2048, 1024) == (512, 2048, 512)
-    assert gmm_tiling(262144, 1024, 2048) == (512, 1024, 1024)
-    assert gmm_tiling(384, 128, 384) == (128, 128, 128)
     # an unsupported shape asked for the kernel runs the XLA form
     lhs = jnp.ones((100, 128), jnp.bfloat16)
     rhs = jnp.ones((2, 128, 64), jnp.bfloat16)
@@ -372,6 +392,187 @@ def test_the_kernel_declines_what_it_cannot_tile():
                                use_pallas=True, interpret=True)
     assert act.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(act, np.float32), 128.0 * 128.0)
+
+
+# ------------------------------------------------------ the tile rule
+# (rows at the 3/4 rung, at every slot; held groups; hidden; one expert's
+# width) of each routed cell's bucket, as tests/test_aot_tpu.py GATED_SITES
+CELL_SITES = {"olmoe": (196608, 262144, 64, 2048, 1024),
+              "zaya1": (24576, 32768, 16, 2048, 2048),
+              "laguna": (122880, 163840, 64, 3072, 1024),
+              "joyai": (98304, 131072, 256, 2048, 768)}
+# what the rule gives them, (gated, down) at each rung: 256 rows where
+# rows // groups reaches 2,048, else 128
+SHIPPED = {
+    "olmoe": 2 * [((256, 2048, 1024), (256, 1024, 2048))],
+    "zaya1": [((128, 2048, 2048), (128, 2048, 1024)),
+              ((256, 2048, 2048), (256, 2048, 1024))],
+    "laguna": [((128, 3072, 1024), (128, 1024, 1536)),
+               ((256, 3072, 1024), (256, 1024, 1536))],
+    "joyai": 2 * [((128, 2048, 768), (128, 768, 2048))],
+}
+
+
+def _site(encoder, rung, kernel):
+    *rungs, groups, hidden, width = CELL_SITES[encoder]
+    k, n = (hidden, width) if kernel == "gated" else (width, hidden)
+    return rungs[rung], k, n, groups
+
+
+def _legal(tiling, m, k, n, gated):
+    """Every tile divides its side in whole lane tiles, the row tile is one
+    of the rule's three, and the call fits what it may name."""
+    tm, tk, tn = tiling
+    assert tm in ROW_TILES and m % tm == 0
+    assert k % tk == 0 and tk % LANES == 0 and n % tn == 0 and tn % LANES == 0
+    if gated:
+        assert gated_vmem_bytes(tm, tk, tn) <= GATED_VMEM_CEILING < 128 << 20
+    else:
+        assert gmm_vmem_bytes(tm, tk, tn) <= GMM_VMEM_BUDGET < 16 << 20
+
+
+@pytest.mark.parametrize("kernel", ["gated", "down"])
+@pytest.mark.parametrize("rung", [0, 1], ids=["three_quarters", "every_slot"])
+@pytest.mark.parametrize("encoder", sorted(CELL_SITES))
+def test_the_tile_rule_at_the_cells_shapes(encoder, rung, kernel):
+    """What every routed cell's grouped calls run at: legal tiles inside
+    the call's budget, K in ONE block at all sixteen (no accumulator pass:
+    the property that makes any two tile choices bit-equal on the real
+    rows), and the tiles the sweep timed fastest
+    (``tools/grouped_alone_pr47.json``)."""
+    m, k, n, groups = _site(encoder, rung, kernel)
+    gated = kernel == "gated"
+    tiling = gmm_tiling(m, k, n, groups, gated=gated)
+    _legal(tiling, m, k, n, gated)
+    assert tiling[1] == k
+    assert tiling == SHIPPED[encoder][rung][not gated]
+    # from the shapes alone: asked again, the same
+    assert gmm_tiling(m, k, n, groups, gated=gated) == tiling
+
+
+# the programs a deployment launches below its cell's bucket: (positions,
+# experts a token, row buckets under the cell's own) — core/batching.
+# BATCH_BUCKETS x text_split.capacities
+BELOW_THE_CELL = {"olmoe": (128, 8, (1, 8, 32, 128)),
+                  "zaya1": (128, 1, (1, 8, 32, 128)),
+                  "laguna": (2048, 10, (1,)), "joyai": (2048, 8, (1,))}
+SMALL_LAUNCHES = [
+    (encoder, rung * top_k)
+    for encoder, (positions, top_k, buckets) in sorted(BELOW_THE_CELL.items())
+    for bucket in buckets for rung in capacities(bucket * positions)]
+
+
+@pytest.mark.parametrize("kernel", ["gated", "down"])
+@pytest.mark.parametrize("encoder,m", SMALL_LAUNCHES)
+def test_the_tile_rule_at_the_small_buckets(encoder, m, kernel):
+    """Buckets 1, 8, 32 and 128 of the 128-position encoders (the last two
+    at both rungs) and bucket 1 of the 2,048-position ones: tens to
+    hundreds of rows a group, so the row tile narrows; still legal, still K
+    in one block, and never a wider row tile than the cell's own."""
+    *_, groups, hidden, width = CELL_SITES[encoder]
+    k, n = (hidden, width) if kernel == "gated" else (width, hidden)
+    assert grouped_matmul_supported(m, k, n)
+    tiling = gmm_tiling(m, k, n, groups, gated=kernel == "gated")
+    _legal(tiling, m, k, n, kernel == "gated")
+    assert tiling[1] == k
+    assert tiling[0] <= SHIPPED[encoder][1][kernel == "down"][0]
+    assert tiling[0] == (256 if m // groups >= 2048 else 128)
+
+
+@pytest.mark.parametrize("m,k,n,groups", [
+    (128, 128, 128, 1), (128, 128, 128, 64), (384, 128, 384, 3),
+    (640, 256, 128, 2), (1024, 256, 384, 130), (4096, 8192, 8192, 4),
+    (4096, 65536, 1024, 4)],
+    ids=["one_tile", "more_groups_than_rows_in_a_tile", "three_lane_tiles",
+         "five_row_tiles", "seven_rows_a_group", "wide_both_ways",
+         "a_k_no_budget_holds"])
+def test_the_tile_rule_is_legal_wherever_the_kernel_is_asked(m, k, n, groups):
+    """Shapes no cell launches: rows a group under a lane tile, a row count
+    only 128 divides, sides no budget holds whole (K is split only where
+    the narrowest row and result tiles do not fit beside it — the kernels'
+    accumulator passes then run — and N takes what is left)."""
+    for gated in (True, False):
+        tiling = gmm_tiling(m, k, n, groups, gated=gated)
+        _legal(tiling, m, k, n, gated)
+        assert tiling[1] == k or k == 65536
+    assert gmm_tiling(4096, 65536, 1024, 4, gated=True) == (128, 32768, 128)
+    assert gmm_tiling(4096, 65536, 1024, 4) == (128, 8192, 256)
+    assert gmm_tiling(4096, 8192, 8192, 4, gated=True) == (128, 8192, 512)
+    assert gmm_tiling(384, 128, 384, 3) == (128, 128, 384)
+
+
+TILE_LAYOUTS = {"ragged": [300, 0, 41, 260, 199],
+                "empty_groups": [0, 500, 0, 0, 300],
+                "one_holds_every_row": [0, 0, 800, 0, 0]}
+
+
+@pytest.mark.parametrize("kernel", ["gated", "down"])
+@pytest.mark.parametrize("layout", sorted(TILE_LAYOUTS))
+def test_any_two_tiles_are_bit_equal_on_the_real_rows(layout, kernel):
+    """With K in one block a tile choice moves no bit of a real row: three
+    row tiles against two widths of N, interpreted, equal each other
+    exactly and the XLA form to the order of a float32 sum (800 real rows
+    of 1,024 launched; the rows past the last group are nobody's)."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    sizes = jnp.asarray(TILE_LAYOUTS[layout], jnp.int32)
+    m, k, n, real = 1024, 256, 384, 800
+    keys = jax.random.split(jax.random.PRNGKey(47), 3)
+    lhs = jax.random.normal(keys[0], (m, k), F32).astype(jnp.bfloat16)
+    a, b = ((jax.random.normal(key, (5, k, n), F32) * 0.1
+             ).astype(jnp.bfloat16) for key in keys[1:])
+    if kernel == "gated":
+        def at(tiling):
+            return gated_gmm(lhs, a, b, sizes, out_dtype=jnp.bfloat16,
+                             tiling=tiling, interpret=True)
+        want = (jax.nn.silu(grouped_matmul_reference(lhs, a, sizes))
+                * grouped_matmul_reference(lhs, b, sizes)
+                ).astype(jnp.bfloat16)
+        atol = 2e-2
+    else:
+        def at(tiling):
+            return gmm(lhs, a, sizes, F32, tiling, interpret=True)
+        want, atol = grouped_matmul_reference(lhs, a, sizes), 1e-4
+    first = np.asarray(at((128, k, n)), np.float32)[:real]
+    for tiling in ((256, k, 128), (512, k, n)):
+        np.testing.assert_array_equal(
+            np.asarray(at(tiling), np.float32)[:real], first)
+    np.testing.assert_allclose(first, np.asarray(want, np.float32)[:real],
+                               atol=atol, rtol=atol)
+
+
+@pytest.mark.parametrize("case", sorted(TILE_LAYOUTS) + ["nothing_routed"])
+def test_the_visited_rows_are_the_visits_times_the_row_tile(case):
+    """``gated_tile_rows`` against a walk over the groups by hand: each
+    non-empty group visits every row tile its span of rows touches, a tile
+    two groups share once for each."""
+    sizes = dict(TILE_LAYOUTS, nothing_routed=[0] * 5)[case]
+    m, k, n = 1024, 256, 384
+    tm = gmm_tiling(m, k, n, len(sizes), gated=True)[0]
+    visits, start = 0, 0
+    for size in sizes:
+        if size:
+            visits += -(-(start + size) // tm) - start // tm
+        start += size
+    got = gated_tile_rows(jnp.asarray(sizes, jnp.int32), m, k, n,
+                          use_pallas=True)
+    assert got.dtype == jnp.int32 and int(got) == visits * tm
+    assert sum(sizes) <= int(got) <= sum(sizes) + 2 * tm * len(sizes)
+    # megablox's own schedule visits as many
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import (
+        make_group_metadata,
+    )
+
+    _, scheduled = make_group_metadata(
+        group_sizes=jnp.asarray(sizes, jnp.int32), m=m, tm=tm,
+        start_group=jnp.zeros((), jnp.int32), num_nonzero_groups=len(sizes),
+        visit_empty_groups=False)
+    assert int(scheduled) == visits
+    # the XLA form, asked or declined, visits nothing
+    assert int(gated_tile_rows(jnp.asarray(sizes, jnp.int32), m, k, n,
+                               use_pallas=False)) == 0
+    assert int(gated_tile_rows(jnp.asarray(sizes, jnp.int32), 1000, k, n,
+                               use_pallas=True)) == 0
 
 
 def test_the_encoder_is_the_same_through_the_kernel(text):
@@ -418,7 +619,7 @@ def test_only_the_real_tokens_are_routed(params32, ragged, case):
     # a padding position gets the attention half of the block and nothing
     # from the experts; the groups hold the real tokens' pairs alone
     assert np.isfinite(np.asarray(hidden)).all()
-    assert (np.asarray(peaks) <= 27 * CFG.num_experts_per_tok).all()
+    assert (np.asarray(peaks)[1] == 27 * CFG.num_experts_per_tok).all()
     got = olmoe_predict(params32, ids, mask, CFG, capacity=capacity)
     with jax.default_matmul_precision("highest"):
         want = jax.nn.softmax(ref_logits(params32, ids, mask, CFG), -1)[:, 1]
@@ -453,9 +654,9 @@ def test_unrouted_rows_enter_no_group_and_get_zero(params32):
     x = jax.random.normal(jax.random.PRNGKey(6), (48, CFG.hidden_size), F32)
     experts, weights = route(x, layer["router"], k)
     real = np.arange(48) % 3 != 1                          # 32 of 48
-    want, all_sizes = apply_experts(layer, x, experts, weights)
-    got, sizes = apply_experts(layer, x, experts, weights,
-                               real=jnp.asarray(real))
+    want, (all_sizes, _) = apply_experts(layer, x, experts, weights)
+    got, (sizes, _) = apply_experts(layer, x, experts, weights,
+                                    real=jnp.asarray(real))
     assert int(all_sizes.sum()) == 48 * k
     assert int(sizes.sum()) == 32 * k                  # real tokens x top-k
     np.testing.assert_array_equal(
@@ -606,8 +807,12 @@ def test_the_encoder_is_the_same_through_the_fused_core(lane, stored, atol):
     np.testing.assert_allclose(np.asarray(got)[mask], np.asarray(want)[mask],
                                atol=atol, rtol=0)
     assert np.isfinite(np.asarray(got)).all()
+    # the kernels' programs alone visit tiles, whole ones
+    assert not np.asarray(want_peaks)[2].any()
+    assert (np.asarray(peaks)[2] >= np.asarray(peaks)[1]).all()
+    assert not (np.asarray(peaks)[2] % 128).any()
     if stored == "float32":
-        np.testing.assert_array_equal(peaks, want_peaks)
+        np.testing.assert_array_equal(peaks[:2], want_peaks[:2])
         for a, b in zip(_every_slot_routed(p, ids, mask, LANE_CFG)[1],
                         _every_slot_routed(p, ids, mask, LANE_CFG,
                                            **KERNELS)[1]):
@@ -632,7 +837,7 @@ def test_the_fused_core_under_a_narrow_capacity(lane):
     np.testing.assert_allclose(np.asarray(got)[mask], np.asarray(want)[mask],
                                atol=1e-5, rtol=0)
     assert np.isfinite(np.asarray(got)).all()
-    assert (np.asarray(peaks) <= 166 * LANE_CFG.num_experts_per_tok).all()
+    assert (np.asarray(peaks)[1] == 166 * LANE_CFG.num_experts_per_tok).all()
 
 
 def test_the_qk_norm_statistic_is_taken_over_all_heads():
@@ -839,7 +1044,8 @@ def test_the_gate_up_site_is_counted_and_entered_once_a_sparse_layer(side):
     # another test on this worker may have traced the same program
     score_fused_packed.clear_cache()
     t0 = time.time()
-    assert len(scorer.finalize(scorer.dispatch(recs))) == 5
+    pending = scorer.dispatch(recs)
+    assert len(scorer.finalize(pending)) == 5
     snap = scorer.kernel_snapshot()
     held = int(side == "held")
     assert snap["dispatch"]["expert_gate_up"] == held
@@ -853,6 +1059,23 @@ def test_the_gate_up_site_is_counted_and_entered_once_a_sparse_layer(side):
     layers = cfg.num_hidden_layers
     assert entered.get("gated_gmm", 0) == held * layers
     assert entered.get("gmm", 0) == held * layers
+    # the span the bucket's programs were built under names the tiles of
+    # both grouped calls, and the launch counts the rows their grid
+    # visited: whole row tiles, over the real pairs; nothing of either
+    # where the launch holds the XLA form
+    rows = 8 * 128 * cfg.num_experts_per_tok
+    tiles = "x".join(map(str, gmm_tiling(
+        rows, cfg.hidden_size, cfg.intermediate_size, cfg.num_experts,
+        gated=True)))
+    assert (f" tiles={8 * 128}:{tiles}+" in traces[0]["caused_by"]) == held
+    assert ("tiles=" in traces[0]["caused_by"]) == held
+    assert pending.expert_rows == pending.routed_pairs > 0
+    if held:
+        assert pending.expert_tile_rows % 128 == 0
+        assert pending.expert_rows < pending.expert_tile_rows <= layers * (
+            rows + 128 * cfg.num_experts)
+    else:
+        assert pending.expert_tile_rows == 0
 
 
 def test_the_dense_program_has_no_second_output_and_no_expert_rows():

@@ -331,7 +331,10 @@ def test_predict_is_the_softmax_of_the_logits(params, text):
     p, stats = zaya_predict(params, ids, mask, CFG, with_stats=True)
     np.testing.assert_allclose(p, jax.nn.softmax(logits, -1)[:, 1], atol=1e-7)
     np.testing.assert_array_equal(stats, peaks)
-    assert peaks.shape == (CFG.num_hidden_layers,) and peaks.dtype == jnp.int32
+    assert peaks.shape == (3, CFG.num_hidden_layers)
+    assert peaks.dtype == jnp.int32
+    # one expert a token: the held pairs are the real tokens of each layer
+    assert (np.asarray(peaks)[1] == np.asarray(mask).sum()).all()
     np.testing.assert_array_equal(zaya_predict(params, ids, mask, CFG), p)
 
 
@@ -536,7 +539,9 @@ def test_compacted_program_equals_every_slot_routed(params32, ragged, case):
                                atol=2e-5, rtol=0)
     assert np.isfinite(np.asarray(hidden)).all()
     assert np.isfinite(np.asarray(r)).all()
-    assert (np.asarray(peaks) <= 27).all() and (np.asarray(peaks) > 0).all()
+    largest, pairs, _ = np.asarray(peaks)
+    assert (largest <= 27).all() and (largest > 0).all()
+    assert (pairs == 27).all()
     held = mask.any(axis=1)
     np.testing.assert_allclose(got[held], want[held], atol=2e-5, rtol=0)
     assert np.isfinite(np.asarray(got)).all()
