@@ -69,6 +69,7 @@ from realtime_fraud_detection_tpu.ops.attention import (
     windowed_attention,
     windowed_refusal,
 )
+from realtime_fraud_detection_tpu.ops.combine import weighted_combine
 from realtime_fraud_detection_tpu.ops.grouped_matmul import (
     gated_tile_rows,
     grouped_gated_matmul,
@@ -307,7 +308,14 @@ def apply_experts(layer: Dict, x: jax.Array, experts: jax.Array,
     shape ``grouped_matmul_supported`` admits, ONE kernel that reads the rows
     once and keeps both float32 results in VMEM; else two ``ragged_dot``
     calls and the product — and ``grouped_matmul`` takes that through
-    ``down_proj``. Neither writes a row past the last group.
+    ``down_proj``, each float32 result row left as one contiguous piece
+    (``[M, hidden / 128, 128]``). Neither writes a row past the last group.
+
+    **The way home** is ``ops.combine.weighted_combine``: each token's
+    weighted sum of the rows of its pairs that entered a group — with
+    ``use_pallas``, at a shape ``combine_supported`` admits, ONE kernel that
+    fetches each such row once and sums in VMEM; else a gather, a select
+    and the sum.
 
     **A share of the experts.** The layer holds the experts its stacked
     weights hold: ``gate_proj.shape[0]`` of the ``router_width`` the router
@@ -318,7 +326,7 @@ def apply_experts(layer: Dict, x: jax.Array, experts: jax.Array,
     last, never visited by the grouped matmul) and adds nothing: the result
     is this chip's part of the sum, with ``weights`` as the router made them
     over all of a token's experts. No pair of a held expert is ever left
-    out. A layer that holds every expert traces what it always did."""
+    out."""
     n, top_k = experts.shape
     num_experts = layer["gate_proj"].shape[0]
     share = router_width is not None and (
@@ -331,8 +339,8 @@ def apply_experts(layer: Dict, x: jax.Array, experts: jax.Array,
             # in the layer's own numbers; a pair of an expert that lives
             # elsewhere is keyed past the last held one
             flat = flat - expert_offset
-            mine = (flat >= 0) & (flat < num_experts)
-            flat = jnp.where(mine, flat, num_experts)
+            flat = jnp.where((flat >= 0) & (flat < num_experts), flat,
+                             num_experts)
         if real is not None:
             # keyed past the last expert, the other rows' pairs sort last
             # and are counted in no group
@@ -359,25 +367,11 @@ def apply_experts(layer: Dict, x: jax.Array, experts: jax.Array,
             out_dtype=layer["down_proj"].dtype, **gm)
         out = grouped_matmul(act, layer["down_proj"], group_sizes, **gm)
     with jax.named_scope(scopes.EXPERTS_COMBINE):
-        if share:
-            # a token's experts outermost, [top_k, N, H]: splitting the
-            # LEADING axis of the gathered rows moves nothing, where [N,
-            # top_k, H] with top_k no multiple of a sublane tile is a copy
-            # of every row (5.7 ms a layer at ten experts a token on the
-            # v5e: PERF.md, PR 33). The kernel never wrote an absent pair's
-            # row either: a select, not a zero weight (0 x whatever the row
-            # holds is not 0)
-            back = out[home.reshape(n, top_k).T.reshape(-1)].reshape(
-                top_k, n, -1)
-            back = jnp.where(mine.reshape(n, top_k).T[:, :, None], back, 0.0)
-            y = jnp.sum(back * weights.T[:, :, None], axis=0)
-        else:
-            back = out[home].reshape(n, top_k, -1)             # token order
-            y = jnp.sum(back * weights[:, :, None], axis=1)
-        if real is not None:
-            # the kernel never wrote the rows past the last group
-            # (ops/grouped_matmul.py): whatever they hold, it stops here
-            y = jnp.where(real[:, None], y, 0.0)
+        # a pair's row comes home only where it entered a group (its token
+        # real, its expert held here): the kernels never wrote the other
+        # rows (ops/grouped_matmul.py), and whatever they hold stops here
+        y = weighted_combine(out, home.reshape(n, top_k), weights,
+                             (flat < num_experts).reshape(n, top_k), **gm)
     return y, ExpertLoad(group_sizes, tile_rows)
 
 

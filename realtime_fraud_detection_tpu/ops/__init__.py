@@ -24,10 +24,17 @@ a predicate admits holds the kernel, everything else the XLA form.
   sparse layer. ``grouped_gated_matmul`` (gate, up and the SiLU ⊙ product
   as ONE grouped kernel, ``gated_gmm``: two right-hand blocks a step, the
   product rounded once in the epilogue) against two ``jax.lax.ragged_dot``
-  calls and the product; ``grouped_matmul`` (down: ``megablox.gmm``)
-  against ``ragged_dot``; ``grouped_matmul_supported``, the one predicate
-  of both, and ``gmm_tiling``, the one tile rule of both (from the call's
-  shapes and group count alone).
+  calls and the product; ``grouped_matmul`` (down: ``down_gmm``, on the
+  same scaffold, whose float32 result rows are ``[M, N / 128, 128]`` — each
+  one contiguous piece in HBM) against ``ragged_dot`` reshaped;
+  ``grouped_matmul_supported``, the one predicate of both, and
+  ``gmm_tiling``, the one tile rule of both (from the call's shapes and
+  group count alone).
+- ``combine``: the routed experts' way home. ``weighted_combine`` (each
+  token's weighted sum of its experts' result rows as ONE kernel,
+  ``combine_rows``: every row of a pair that entered a group fetched once
+  from HBM as one piece, the sum in VMEM, no ``[pairs, hidden]`` temporary)
+  against a gather, a select and the sum; ``combine_supported``.
 - ``ssd_scan``: the state-space scan of a Mamba-2 mixer
   (``models/falcon_h1.py``), ``ssd_scan(..., use_pallas=True)`` — the chunked
   algorithm as ONE kernel a layer, a grid over (row, group, chunk) with the
@@ -67,6 +74,11 @@ from realtime_fraud_detection_tpu.ops.epilogue import (  # noqa: F401
     epilogue_reference,
     epilogue_supported,
     fused_epilogue,
+)
+from realtime_fraud_detection_tpu.ops.combine import (  # noqa: F401
+    combine_supported,
+    weighted_combine,
+    weighted_combine_reference,
 )
 from realtime_fraud_detection_tpu.ops.grouped_matmul import (  # noqa: F401
     grouped_gated_matmul,

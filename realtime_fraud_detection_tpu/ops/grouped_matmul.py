@@ -9,27 +9,35 @@ f32 accumulation — the precision the routed configurations state.
 
 Two entry points, each with two forms:
 
-- ``grouped_matmul``: one grouped matmul, f32 result (an expert's ``down``).
-  The XLA form is ``jax.lax.ragged_dot``: what CPU hosts and the tests run,
-  and what any shape the kernel does not take runs on the chip. The Pallas
-  form is ``jax.experimental.pallas.ops.tpu.megablox.gmm`` (a grid over row
-  tiles, each tile multiplied by the matrix of the group that owns it, tiles
-  that straddle two groups visited once per group with a row mask), at the
-  tile ``gmm_tiling`` gives its shapes. On the v5e it is a quarter to a third
-  faster than XLA's own lowering of ``ragged_dot`` (itself a grouped kernel,
-  not per-group dense work), and — unlike that lowering, whose custom calls
-  are named ``ragged-dot-none`` whatever scope they were traced under — it
-  keeps the ``jax.named_scope`` path in its ``op_name``, so a device trace
-  can attribute it.
+- ``grouped_matmul``: one grouped matmul, f32 result (an expert's ``down``),
+  each result row as its own ``N / 128`` lane tiles: ``f32[M, N / 128,
+  128]``. In the tiled HBM layout a row of that array is ONE contiguous
+  piece of ``4 N`` bytes, where a row of ``f32[M, N]`` is ``N / 128`` pieces
+  of 512 B, 4 KB apart; the rows go home one at a time
+  (``ops/combine.py``), and a row fetched from HBM is paid by the piece. The
+  XLA form is ``jax.lax.ragged_dot`` and the reshape: what CPU hosts and
+  the tests run, and what any shape the kernel does not take runs on the
+  chip. The Pallas form is ``down_gmm``, below (a grid over row tiles, each
+  tile multiplied by the matrix of the group that owns it, tiles that
+  straddle two groups visited once per group with a row mask; the result
+  block is ``(tm, tn / 128, 128)``, stored a lane tile at a time), at the
+  tile ``gmm_tiling`` gives its shapes. Unlike XLA's own lowering of
+  ``ragged_dot``, whose custom calls are named ``ragged-dot-none`` whatever
+  scope they were traced under, it keeps the ``jax.named_scope`` path in
+  its ``op_name``, so a device trace can attribute it.
 - ``grouped_gated_matmul``: ``silu(rows @ gate) * (rows @ up)`` in the
   dtype the next matmul reads (an expert's ``gate``, ``up`` and SiLU ⊙). The
   XLA form is two ``ragged_dot`` calls and the product. The Pallas form is
-  ``gated_gmm``, below: megablox's grid and row masks with TWO right-hand
+  ``gated_gmm``, below: the same grid and row masks with TWO right-hand
   blocks a step — the group's ``[tk, tn]`` block of each of the two
   ``[G, K, N]`` operands, as the parameters hold them — and an epilogue that
   takes the SiLU and the product of the two f32 results in VMEM and writes
   them rounded once. The rows are read once, no f32 ``[rows, N]`` reaches
   HBM, and no pass stands between the matmuls.
+
+Both kernels are ``_grouped_call`` — megablox's schedule
+(``make_group_metadata``), the grid, the block specs, the budget the call
+names — round a body of their own.
 
 ``grouped_matmul_supported`` is the ONE predicate on shapes for both: the
 traced guards below, the scorer's selector
@@ -42,6 +50,7 @@ fused kernel's grid then visits.
 from __future__ import annotations
 
 import functools
+import math
 from typing import List, Tuple
 
 import jax
@@ -63,10 +72,16 @@ LANES = 128
 #           (512,.,1024) 4.09, (512, 1024, 1024) 4.82 [PR 46's: K in three]
 #   JoyAI    98,304 / 256, 2048 -> 768:  (128,.,768) 5.16, (256,.,768) 5.19,
 #           (256,.,384) 5.41, (512,.,768) 7.00, (512,.,256) 7.18 [PR 46's]
-# and of down's megablox.gmm: OLMoE (256,.,2048) 4.58 against (256,.,1024)
+# and of down's (then megablox.gmm): OLMoE (256,.,2048) 4.58 against (256,.,1024)
 # 4.80 and PR 46's (512,.,1024) 4.89; JoyAI (128, 768, 2048) 3.23 against
 # (256, 768, 2048) 3.65 and PR 46's (512, 256, 2048) 4.87. Every tile with K
 # in one block gave the same bits on every real row (452 timings).
+# Down as ``down_gmm`` (PR 48, tools/grouped_alone_pr48.json), beside
+# megablox.gmm at PR 47's tile, ms at the 3/4 rung: OLMoE 4.85 against 4.58,
+# JoyAI 3.38 against 3.25, Laguna (128, 1024, 3072) 1.94 against (.., 1536)
+# 1.96, ZAYA1 (128, 2048, 2048) 1.35 against (.., 1024) 1.37: the 3-D store
+# costs 4-6% where the tile was the same, N whole pays for it where the
+# budget now allows it; bit-equal on every real row.
 #
 # A row tile: 512 rows lost at every shape timed (a tile straddles more
 # groups, and every group that touches a tile computes all of it), so 256 or
@@ -79,13 +94,11 @@ LANES = 128
 # kernel and 2.1% in down's, at 0.51-0.89 of PR 46's 512.
 ROW_TILES = (256, 128)
 ROWS_PER_TILE = 8
-# what the fused kernel's call may name: half the 128 MiB of a v5e core's
-# VMEM (ZAYA1's whole 2048 x 2048 pair of blocks at 256 rows names 52 MB)
-GATED_VMEM_CEILING = 64 << 20
-# down's call is megablox's and gets the 16 MB a call may use unasked; 3 MB
-# of it are left to Mosaic, which reported up to 2.3 MB of its own past
-# ``gmm_vmem_bytes`` where it refused a tile
-GMM_VMEM_BUDGET = 13 << 20
+# what either kernel's call may name: half the 128 MiB of a v5e core's VMEM
+# (ZAYA1's whole 2048 x 2048 pair of blocks at 256 rows names 52 MB in the
+# fused kernel; down's widest, Laguna's (128, 1024, 3072), 26 MB)
+VMEM_CEILING = 64 << 20
+SUBLANES = 8
 
 
 def grouped_matmul_supported(m: int, k: int, n: int) -> bool:
@@ -106,12 +119,13 @@ def gated_vmem_bytes(tm: int, tk: int, tn: int, operand_bytes: int = 2,
     return 2 * blocks + 6 * tm * tn * 4 + (4 << 20)
 
 
-def gmm_vmem_bytes(tm: int, tk: int, tn: int, operand_bytes: int = 2) -> int:
-    """What ``megablox.gmm`` holds in VMEM at ``(tm, tk, tn)``, f32 result:
-    the row and matrix blocks double-buffered, the result block and the
-    accumulator (what Mosaic reports, to the byte, where it refuses a tile
-    for the 16 MB a call gets unasked: ``tools/grouped_alone.py --aot``)."""
-    return (2 * (tm * tk + tk * tn) * operand_bytes + 2 * tm * tn * 4)
+def down_vmem_bytes(tm: int, tk: int, tn: int, operand_bytes: int = 2) -> int:
+    """The VMEM ``down_gmm`` names for a call at ``(tm, tk, tn)``: the row
+    and matrix blocks and the f32 result block double-buffered, three f32
+    ``[tm, tn]`` tiles (the product, the accumulator, the store's
+    temporaries), and 4 MB for what Mosaic keeps itself."""
+    return (2 * ((tm * tk + tk * tn) * operand_bytes + tm * tn * 4)
+            + 3 * tm * tn * 4 + (4 << 20))
 
 
 def _lane_divisors(size: int) -> List[int]:
@@ -121,19 +135,28 @@ def _lane_divisors(size: int) -> List[int]:
     return [d * LANES for d in range(lanes, 0, -1) if lanes % d == 0]
 
 
+def down_widths(n: int) -> List[int]:
+    """The widths of N ``down_gmm``'s result block ``(tm, tn / 128, 128)``
+    may take, widest first: all of N, or whole sublane tiles of lane tiles
+    (1,024 columns) that divide it."""
+    return [t for t in _lane_divisors(n)
+            if t == n or t % (SUBLANES * LANES) == 0]
+
+
 def gmm_tiling(m: int, k: int, n: int, groups: int, *, gated: bool = False
                ) -> Tuple[int, int, int]:
     """(tm, tk, tn) for a supported ``[m, k] x [groups, k, n]`` call, of the
-    fused gate / up kernel (``gated``) or of down's ``megablox.gmm``: a
-    function of the shapes and of nothing else, in this order.
+    fused gate / up kernel (``gated``) or of down's ``down_gmm``: a function
+    of the shapes and of nothing else, in this order.
 
     1. K whole in one block wherever the call's budget holds it beside the
        narrowest row and result tiles: no accumulator pass, and any two
        tile choices give the same bits on the real rows. (Else the widest
        divisor of K in lane tiles that does fit.)
-    2. N as wide as the budget then allows, any whole number of lane tiles
-       that divides N (768 whole, 1536 of 3072): each further block of N is
-       another pass of the rows through HBM.
+    2. N as wide as the budget then allows: any whole number of lane tiles
+       that divides N in the fused kernel, ``down_widths`` in down's (whose
+       result block is 3-D). Each further block of N is another pass of
+       the rows through HBM.
     3. The row tile: the largest of ``ROW_TILES`` that divides ``m``, still
        fits beside (1) and (2), and of which a group holds at least
        ``ROWS_PER_TILE`` — by ``m // groups``, which is an UPPER estimate
@@ -141,20 +164,21 @@ def gmm_tiling(m: int, k: int, n: int, groups: int, *, gated: bool = False
        sort last and belong to no group (Laguna's layer holds a quarter of
        the pairs ``m`` counts).
 
-    The budget is ``gated_vmem_bytes`` under ``GATED_VMEM_CEILING`` for the
-    fused kernel, which names it, and ``gmm_vmem_bytes`` under
-    ``GMM_VMEM_BUDGET`` for down's; both count bfloat16 operands, as
-    deployed."""
-    room, ceiling = ((gated_vmem_bytes, GATED_VMEM_CEILING) if gated
-                     else (gmm_vmem_bytes, GMM_VMEM_BUDGET))
+    The budget is what the call names, ``gated_vmem_bytes`` or
+    ``down_vmem_bytes``, under ``VMEM_CEILING``; both count bfloat16
+    operands, as deployed."""
+    room = gated_vmem_bytes if gated else down_vmem_bytes
+    widths = (_lane_divisors(n) if gated else down_widths(n)) or [LANES]
 
     def widest(sides, tile):
-        return next((t for t in sides if room(*tile(t)) <= ceiling), LANES)
+        # (the narrowest where nothing fits: the compiler then says so)
+        return next((t for t in sides if room(*tile(t)) <= VMEM_CEILING),
+                    sides[-1] if sides else LANES)
 
-    tk = widest(_lane_divisors(k), lambda t: (LANES, t, LANES))
-    tn = widest(_lane_divisors(n), lambda t: (LANES, tk, t))
+    tk = widest(_lane_divisors(k), lambda t: (LANES, t, widths[-1]))
+    tn = widest(widths, lambda t: (LANES, tk, t))
     rows = [t for t in ROW_TILES
-            if m % t == 0 and t * ROWS_PER_TILE <= m // groups]
+            if m % t == 0 and t * ROWS_PER_TILE <= m // groups] + [LANES]
     return widest(rows, lambda t: (t, tk, tn)), tk, tn
 
 
@@ -186,77 +210,48 @@ def grouped_matmul_reference(lhs: jax.Array, rhs: jax.Array,
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
                    *, use_pallas: bool = False, interpret: bool = False
                    ) -> jax.Array:
-    """``f32[M, N]``: rows of ``lhs`` (``[M, K]``, sorted by group) times
-    their group's matrix of ``rhs`` (``[G, K, N]``); ``group_sizes``
-    ``i32[G]`` sums to ``M``. ``use_pallas`` asks for the kernel; a shape it
-    does not take runs the XLA form."""
+    """``f32[M, N / 128, 128]``: rows of ``lhs`` (``[M, K]``, sorted by
+    group) times their group's matrix of ``rhs`` (``[G, K, N]``), each
+    result row as ``N / 128`` lane tiles of its own — in the tiled HBM
+    layout ONE contiguous piece, which a row of ``f32[M, N]`` is not (it is
+    ``N / 128`` pieces of 512 B, 4 KB apart): the form
+    ``ops.combine.weighted_combine`` fetches single rows in. ``group_sizes``
+    ``i32[G]`` sums to ``M``. ``use_pallas`` asks for the kernel
+    (``down_gmm``); a shape it does not take runs the XLA form,
+    ``ragged_dot`` and the reshape."""
     m, k = lhs.shape
     n = rhs.shape[-1]
     if use_pallas and grouped_matmul_supported(m, k, n):
-        from jax.experimental.pallas.ops.tpu.megablox import gmm
-
-        return gmm(lhs, rhs, group_sizes.astype(jnp.int32), jnp.float32,
-                   gmm_tiling(m, k, n, rhs.shape[0]), interpret=interpret)
-    return grouped_matmul_reference(lhs, rhs, group_sizes)
-
-
-def _gated_kernel(offsets, group_ids, row_tiles, lhs, gate_w, up_w, out,
-                  *accs, tm: int, tn: int, tiles_k: int):
-    """One visit of a row tile by one group, one ``[tk, tn]`` block of each
-    matrix: both products, summed over the K steps, and on the last the
-    SiLU ⊙ of the group's own rows of the tile."""
-    visit, k_i = pl.program_id(1), pl.program_id(2)
-
-    def store(gate, up):
-        group = group_ids[visit]
-        row = row_tiles[visit] * tm + jax.lax.broadcasted_iota(
-            jnp.int32, (tm, tn), 0)
-        mine = (row >= offsets[group]) & (row < offsets[group + 1])
-        # the other rows of the tile keep what an earlier visit wrote
-        out[...] = jnp.where(mine, jax.nn.silu(gate) * up,
-                             out[...].astype(jnp.float32)).astype(out.dtype)
-
-    x = lhs[...]
-    gate = jnp.dot(x, gate_w[...], preferred_element_type=jnp.float32)
-    up = jnp.dot(x, up_w[...], preferred_element_type=jnp.float32)
-    if tiles_k == 1:
-        store(gate, up)
-        return
-    acc_gate, acc_up = accs
-
-    @pl.when(k_i == 0)
-    def _():
-        acc_gate[...] = jnp.zeros_like(acc_gate)
-        acc_up[...] = jnp.zeros_like(acc_up)
-
-    acc_gate[...] += gate
-    acc_up[...] += up
-
-    @pl.when(k_i == tiles_k - 1)
-    def _():
-        store(acc_gate[...], acc_up[...])
+        return down_gmm(lhs, rhs, group_sizes.astype(jnp.int32),
+                        tiling=gmm_tiling(m, k, n, rhs.shape[0]),
+                        interpret=interpret)
+    # (a width that is no whole number of lane tiles stays one piece)
+    return grouped_matmul_reference(lhs, rhs, group_sizes).reshape(
+        (m, n // LANES, LANES) if n % LANES == 0 else (m, 1, n))
 
 
-@functools.partial(jax.jit, static_argnames=("out_dtype", "tiling",
-                                             "interpret"))
-def gated_gmm(lhs: jax.Array, gate_w: jax.Array, up_w: jax.Array,
-              group_sizes: jax.Array, *, out_dtype,
-              tiling: Tuple[int, int, int], interpret: bool = False
-              ) -> jax.Array:
-    """The Pallas form of ``grouped_gated_matmul`` at ``tiling`` (tm, tk,
-    tn), whole tiles on every side. Jitted with static tiles: the layers of
-    a program share one trace and one lowering. Rows past the last group are
-    never written, as ``megablox.gmm`` leaves them."""
+def _grouped_call(body, name: str, lhs: jax.Array, matrices, group_sizes,
+                  tiling: Tuple[int, int, int], *, out_shape, out_block,
+                  out_index, vmem: int, flops_per_mkn: int,
+                  transcendentals: int, interpret: bool) -> jax.Array:
+    """The scaffold of both kernels: ``body`` run over megablox's grid for
+    ``lhs`` ``[M, K]`` against the group's ``[tk, tn]`` block of each of
+    ``matrices`` (``[G, K, N]`` each). The grid is ``(N tiles, visits, K
+    tiles)``; megablox's own schedule (``make_group_metadata``) says which
+    row tile and which group each visit holds — a tile that straddles
+    groups is visited once for each — and how many visits hold work. Rows
+    past the last group are never visited. ``body`` gets the three
+    prefetched schedules, the blocks, the result block (``out_block`` at
+    ``out_index(row tile, N tile)``) and, where K takes several steps, one
+    f32 ``[tm, tn]`` accumulator a matrix."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import (
         make_group_metadata,
     )
 
     m, k = lhs.shape
-    groups, _, n = gate_w.shape
+    groups, _, n = matrices[0].shape
     tm, tk, tn = tiling
     tiles_k, tiles_n = k // tk, n // tn
-    # megablox's own schedule: which row tile and which group each step of
-    # the grid's middle axis visits, and how many steps hold work
     metadata, visits = make_group_metadata(
         group_sizes=group_sizes, m=m, tm=tm,
         start_group=jnp.zeros((), jnp.int32), num_nonzero_groups=groups,
@@ -269,36 +264,150 @@ def gated_gmm(lhs: jax.Array, gate_w: jax.Array, up_w: jax.Array,
         return group_ids[visit], k_i, n_i
 
     def out_at(n_i, visit, k_i, offsets, group_ids, row_tiles):
-        return row_tiles[visit], n_i
+        return out_index(row_tiles[visit], n_i)
 
-    out_dtype = jnp.dtype(out_dtype)
-    matrix = pl.BlockSpec((None, tk, tn), matrix_at)
-    # over the 16 MB a call may use on the v5e unasked at every tile the
-    # rule picks for the encoders' shapes, so the call names its own budget
-    vmem = gated_vmem_bytes(tm, tk, tn, lhs.dtype.itemsize,
-                            out_dtype.itemsize)
     return pl.pallas_call(
-        functools.partial(_gated_kernel, tm=tm, tn=tn, tiles_k=tiles_k),
-        name="gated_gmm",
-        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        functools.partial(body, tm=tm, tn=tn, tiles_k=tiles_k),
+        name=name,
+        out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            in_specs=[pl.BlockSpec((tm, tk), rows_at), matrix, matrix],
-            out_specs=pl.BlockSpec((tm, tn), out_at),
+            in_specs=[pl.BlockSpec((tm, tk), rows_at)]
+            + [pl.BlockSpec((None, tk, tn), matrix_at)] * len(matrices),
+            out_specs=pl.BlockSpec(out_block, out_at),
             grid=(tiles_n, visits, tiles_k),
-            scratch_shapes=([pltpu.VMEM((tm, tn), jnp.float32)] * 2
-                            if tiles_k > 1 else [])),
+            scratch_shapes=([pltpu.VMEM((tm, tn), jnp.float32)]
+                            * len(matrices) if tiles_k > 1 else [])),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=vmem),
         cost_estimate=pl.CostEstimate(
-            flops=4 * m * k * n, transcendentals=m * n,
+            flops=flops_per_mkn * m * k * n, transcendentals=transcendentals,
             bytes_accessed=(tiles_n * m * k * lhs.dtype.itemsize
-                            + 2 * metadata[1].size * k * n
-                            * gate_w.dtype.itemsize
-                            + m * n * out_dtype.itemsize)),
+                            + len(matrices) * metadata[1].size * k * n
+                            * matrices[0].dtype.itemsize
+                            + math.prod(out_shape.shape)
+                            * out_shape.dtype.itemsize)),
         interpret=interpret,
-    )(*metadata, lhs, gate_w, up_w)
+    )(*metadata, lhs, *matrices)
+
+
+def _own_rows(visit, offsets, group_ids, row_tiles, tm: int, shape):
+    """``bool[shape]``: which rows (axis 0) of the row tile that ``visit``
+    (the grid's middle index, read outside any ``pl.when``) holds belong to
+    the visiting group. The other rows of the tile keep what an earlier
+    visit wrote."""
+    group = group_ids[visit]
+    row = row_tiles[visit] * tm + jax.lax.broadcasted_iota(
+        jnp.int32, shape, 0)
+    return (row >= offsets[group]) & (row < offsets[group + 1])
+
+
+def _over_k(products, accs, tiles_k: int, store):
+    """``store(*sums)`` of this step's ``products`` summed over the K steps
+    of a visit: at once where K is one block, else through the f32
+    accumulators on the last step."""
+    k_i = pl.program_id(2)
+    if tiles_k == 1:
+        store(*products)
+        return
+
+    @pl.when(k_i == 0)
+    def _():
+        for acc in accs:
+            acc[...] = jnp.zeros_like(acc)
+
+    for acc, product in zip(accs, products):
+        acc[...] += product
+
+    @pl.when(k_i == tiles_k - 1)
+    def _():
+        store(*(acc[...] for acc in accs))
+
+
+def _gated_kernel(offsets, group_ids, row_tiles, lhs, gate_w, up_w, out,
+                  *accs, tm: int, tn: int, tiles_k: int):
+    """One visit of a row tile by one group, one ``[tk, tn]`` block of each
+    matrix: both products, summed over the K steps, and on the last the
+    SiLU ⊙ of the group's own rows of the tile."""
+    visit = pl.program_id(1)
+
+    def store(gate, up):
+        mine = _own_rows(visit, offsets, group_ids, row_tiles, tm, (tm, tn))
+        out[...] = jnp.where(mine, jax.nn.silu(gate) * up,
+                             out[...].astype(jnp.float32)).astype(out.dtype)
+
+    x = lhs[...]
+    _over_k((jnp.dot(x, gate_w[...], preferred_element_type=jnp.float32),
+             jnp.dot(x, up_w[...], preferred_element_type=jnp.float32)),
+            accs, tiles_k, store)
+
+
+def _down_kernel(offsets, group_ids, row_tiles, lhs, w, out, *accs,
+                 tm: int, tn: int, tiles_k: int):
+    """One visit of a row tile by one group: the product summed over the K
+    steps, and on the last the group's own rows of the tile stored a lane
+    tile at a time into the ``[tm, tn / 128, 128]`` result block (row
+    ``r``'s lanes ``128 c ...`` become ``out[r, c]``: a store with a
+    sublane stride)."""
+    visit = pl.program_id(1)
+
+    def store(result):
+        mine = _own_rows(visit, offsets, group_ids, row_tiles, tm,
+                         (tm, LANES))
+        for c in range(tn // LANES):
+            out[:, c, :] = jnp.where(
+                mine, result[:, c * LANES:(c + 1) * LANES], out[:, c, :])
+
+    _over_k((jnp.dot(lhs[...], w[...],
+                     preferred_element_type=jnp.float32),),
+            accs, tiles_k, store)
+
+
+@functools.partial(jax.jit, static_argnames=("out_dtype", "tiling",
+                                             "interpret"))
+def gated_gmm(lhs: jax.Array, gate_w: jax.Array, up_w: jax.Array,
+              group_sizes: jax.Array, *, out_dtype,
+              tiling: Tuple[int, int, int], interpret: bool = False
+              ) -> jax.Array:
+    """The Pallas form of ``grouped_gated_matmul`` at ``tiling`` (tm, tk,
+    tn), whole tiles on every side. Jitted with static tiles: the layers of
+    a program share one trace and one lowering. Rows past the last group are
+    never written."""
+    m, k = lhs.shape
+    n = gate_w.shape[-1]
+    tm, tk, tn = tiling
+    out_dtype = jnp.dtype(out_dtype)
+    return _grouped_call(
+        _gated_kernel, "gated_gmm", lhs, (gate_w, up_w), group_sizes, tiling,
+        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        out_block=(tm, tn), out_index=lambda row_tile, n_i: (row_tile, n_i),
+        # over the 16 MB a call may use on the v5e unasked at every tile the
+        # rule picks for the encoders' shapes, so the call names its budget
+        vmem=gated_vmem_bytes(tm, tk, tn, lhs.dtype.itemsize,
+                              out_dtype.itemsize),
+        flops_per_mkn=4, transcendentals=m * n, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("tiling", "interpret"))
+def down_gmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,
+             tiling: Tuple[int, int, int], interpret: bool = False
+             ) -> jax.Array:
+    """The Pallas form of ``grouped_matmul`` at ``tiling`` (tm, tk, tn):
+    ``f32[M, N / 128, 128]``, the result block ``(tm, tn / 128, 128)`` — so
+    ``tn`` is all of N or whole sublane tiles of lane tiles
+    (``down_widths``). Jitted with static tiles, as ``gated_gmm``. Rows
+    past the last group are never written."""
+    m, k = lhs.shape
+    n = rhs.shape[-1]
+    tm, tk, tn = tiling
+    return _grouped_call(
+        _down_kernel, "down_gmm", lhs, (rhs,), group_sizes, tiling,
+        out_shape=jax.ShapeDtypeStruct((m, n // LANES, LANES), jnp.float32),
+        out_block=(tm, tn // LANES, LANES),
+        out_index=lambda row_tile, n_i: (row_tile, n_i, 0),
+        vmem=down_vmem_bytes(tm, tk, tn, lhs.dtype.itemsize),
+        flops_per_mkn=2, transcendentals=0, interpret=interpret)
 
 
 def grouped_gated_matmul(rows: jax.Array, gate_w: jax.Array,

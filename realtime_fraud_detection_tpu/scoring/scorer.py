@@ -68,6 +68,7 @@ from realtime_fraud_detection_tpu.state.stores import (
     VelocityStore,
 )
 from realtime_fraud_detection_tpu.utils.config import (
+    EXPERT_COMBINE_SITE,
     EXPERT_GATE_UP_SITE,
     SSM_SCAN_SITE,
     VALID_KERNEL_SITES,
@@ -480,10 +481,12 @@ class FraudScorer:
         self._platform = platform
         self._kernel_interpret = platform == "cpu"
         # (a routed encoder's launches are also counted at its experts'
-        # gate + up + SiLU site, those of an encoder with a state-space
-        # mixer at its scan's: sites the dense encoder does not have)
+        # gate + up + SiLU site and at their way home, those of an encoder
+        # with a state-space mixer at its scan's: sites the dense encoder
+        # does not have)
         sites = VALID_KERNEL_SITES + (
-            (EXPERT_GATE_UP_SITE,) if self._moe_text else ()) + (
+            (EXPERT_GATE_UP_SITE, EXPERT_COMBINE_SITE)
+            if self._moe_text else ()) + (
             (SSM_SCAN_SITE,) if self._ssm_text else ())
         self._kernel_counts: Dict[str, Dict[str, int]] = {
             "dispatch": {s: 0 for s in sites},
@@ -1040,14 +1043,20 @@ class FraudScorer:
                     or self._scan_refusal(text_len) is None)
         if not self._moe_text:
             return self._attention_shape_ok(text_len)
-        from realtime_fraud_detection_tpu.ops import grouped_matmul_supported
+        from realtime_fraud_detection_tpu.ops import (
+            combine_supported,
+            grouped_matmul_supported,
+        )
 
         c = self.bert_config
-        rows = (text_len or self.sc.text_len) * c.num_experts_per_tok
+        tokens = text_len or self.sc.text_len
+        rows = tokens * c.num_experts_per_tok
         return (grouped_matmul_supported(rows, c.hidden_size,
                                          c.intermediate_size)
                 and grouped_matmul_supported(rows, c.intermediate_size,
-                                             c.hidden_size))
+                                             c.hidden_size)
+                ) or combine_supported(tokens, c.num_experts_per_tok,
+                                       c.hidden_size)
 
     def _record_kernel_dispatch(self, size: int, text_len: int,
                                 capacity: Optional[int] = None) -> None:
@@ -1065,7 +1074,10 @@ class FraudScorer:
         ``expert_gate_up`` for every launch of a routed encoder: dispatched
         where its sparse layers hold the fused gate + up + SiLU kernel
         (``ops.grouped_gated_matmul``), a fallback where they run the
-        three-call form; and ``ssm_scan`` for every launch of an encoder
+        three-call form; ``expert_combine`` beside it: dispatched where
+        the experts' result rows come home through the one kernel
+        (``ops.weighted_combine``), a fallback where through XLA's gather
+        and sum; and ``ssm_scan`` for every launch of an encoder
         with a state-space mixer: dispatched where its layers hold the
         scan's kernel (``ops.ssd_scan``), a fallback where they run the XLA
         chunked form."""
@@ -1079,14 +1091,19 @@ class FraudScorer:
             fall["attention"] += 1
         if self._moe_text:
             from realtime_fraud_detection_tpu.ops import (
+                combine_supported,
                 grouped_matmul_supported,
             )
 
             c = self.bert_config
-            rows = (capacity or size * text_len) * c.num_experts_per_tok
+            tokens = capacity or size * text_len
+            rows = tokens * c.num_experts_per_tok
             fused = asked and grouped_matmul_supported(
                 rows, c.hidden_size, c.intermediate_size)
             (disp if fused else fall)[EXPERT_GATE_UP_SITE] += 1
+            home = asked and combine_supported(
+                tokens, c.num_experts_per_tok, c.hidden_size)
+            (disp if home else fall)[EXPERT_COMBINE_SITE] += 1
         if self._ssm_text:
             held = asked and self._scan_refusal(text_len) is None
             (disp if held else fall)[SSM_SCAN_SITE] += 1

@@ -718,6 +718,11 @@ VALID_KERNEL_SITES = ("dequant_matmul", "epilogue", "attention")
 # program is asked for its kernels and ``grouped_matmul_supported`` takes the
 # rows)
 EXPERT_GATE_UP_SITE = "expert_gate_up"
+# and the experts' way home, counted beside it (``ops.weighted_combine``: down
+# leaves each result row one contiguous piece and ONE kernel fetches the rows
+# of the pairs that entered a group and sums them, wherever a routed program
+# is asked for its kernels and ``combine_supported`` takes the tokens)
+EXPERT_COMBINE_SITE = "expert_combine"
 # and one for an encoder with a state-space mixer alone: the mixer's scan
 # (``ops.ssd_scan``: the kernel wherever the program is asked for its kernels
 # and ``ssd_refusal`` has nothing against the shape)

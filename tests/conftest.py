@@ -59,7 +59,7 @@ def rng():
 def experts_through_both_forms():
     """``check(layer, top_k=, rung=, atol=, ...)``: one sparse layer's
     ``models.olmoe.apply_experts`` with its kernels asked for (Pallas,
-    interpreted: the fused gate + up + SiLU kernel, then ``megablox.gmm``)
+    interpreted: the fused gate + up + SiLU kernel, down's, the combine)
     against its XLA form, at one of the two capacities of a launch of 4,096
     slots (``text_split.capacities``: rung 0 three quarters, 1 every slot) on
     random rows of which the first 2,800 are real (a rung's ``token_slots``),
@@ -69,7 +69,10 @@ def experts_through_both_forms():
     import jax.numpy as jnp
 
     from realtime_fraud_detection_tpu.models.olmoe import apply_experts
-    from realtime_fraud_detection_tpu.ops import grouped_matmul_supported
+    from realtime_fraud_detection_tpu.ops import (
+        combine_supported,
+        grouped_matmul_supported,
+    )
     from realtime_fraud_detection_tpu.ops.grouped_matmul import gmm_tiling
     from realtime_fraud_detection_tpu.scoring.text_split import capacities
 
@@ -80,6 +83,7 @@ def experts_through_both_forms():
         slots = capacities(4096)[rung]
         held, hidden, wide = layer["gate_proj"].shape
         assert grouped_matmul_supported(slots * top_k, hidden, wide)
+        assert combine_supported(slots, top_k, hidden)
         rng = np.random.default_rng(0)
         x = jnp.asarray(rng.standard_normal((slots, hidden)), jnp.float32)
         experts = jnp.asarray(np.argsort(rng.random(

@@ -256,11 +256,10 @@ def _grouped_call(one_chip, rows, groups, k, n, gated):
     """The lowered text of one grouped call asked for its kernel at
     ``[rows, k] x [groups, k, n]``, and the tile the rule gave it."""
     from realtime_fraud_detection_tpu.ops.grouped_matmul import (
-        GATED_VMEM_CEILING,
-        GMM_VMEM_BUDGET,
+        VMEM_CEILING,
+        down_vmem_bytes,
         gated_vmem_bytes,
         gmm_tiling,
-        gmm_vmem_bytes,
         grouped_gated_matmul,
         grouped_matmul,
         grouped_matmul_supported,
@@ -268,14 +267,14 @@ def _grouped_call(one_chip, rows, groups, k, n, gated):
 
     assert grouped_matmul_supported(rows, k, n)
     tiling = gmm_tiling(rows, k, n, groups, gated=gated)
-    # K in one block, and the budget the call names (or is given) holds it
+    # K in one block, and the budget the call names holds it
     assert tiling[1] == k
     if gated:
-        assert gated_vmem_bytes(*tiling) <= GATED_VMEM_CEILING
+        assert gated_vmem_bytes(*tiling) <= VMEM_CEILING
         fn = jax.jit(lambda x, a, b, g: grouped_gated_matmul(
             x, a, b, g, out_dtype=jnp.bfloat16, use_pallas=True))
     else:
-        assert gmm_vmem_bytes(*tiling) <= GMM_VMEM_BUDGET
+        assert down_vmem_bytes(*tiling) <= VMEM_CEILING
         fn = jax.jit(lambda x, a, b, g: grouped_matmul(
             x, a, g, use_pallas=True))
     weights = _sds((groups, k, n), jnp.bfloat16, one_chip)
@@ -288,15 +287,16 @@ def _grouped_call(one_chip, rows, groups, k, n, gated):
 @pytest.mark.parametrize("rung", [0, 1], ids=["three_quarters", "every_slot"])
 @pytest.mark.parametrize("encoder", sorted(GATED_SITES))
 def test_grouped_matmul_compiles_at_published_widths(one_chip, encoder, rung):
-    """down's ``megablox.gmm`` as ONE Mosaic call at the tile ``gmm_tiling``
-    picks, for all four routed encoders at both capacities: the call names
-    no budget, so what is picked has to fit the 16 MB a call gets unasked
-    (Mosaic refuses OLMoE's (512, 1024, 2048) and ZAYA1's (256, 2048, 2048)
-    here: ``tools/grouped_alone.py --aot``)."""
+    """down's ``down_gmm`` as ONE Mosaic call at the tile ``gmm_tiling``
+    picks, for all four routed encoders at both capacities: its result block
+    is ``(tm, tn / 128, 128)`` of ``f32[rows, hidden / 128, 128]`` — each
+    row one contiguous piece — and the call names its own budget."""
     *rungs, groups, hidden, width = GATED_SITES[encoder]
     rows = rungs[rung]
     text, _ = _grouped_call(one_chip, rows, groups, width, hidden, False)
-    assert f"f32[{rows},{hidden}]" in text
+    assert "jit(down_gmm)/down_gmm/pallas_call" in text
+    assert f"f32[{rows},{hidden // 128},128]" in text
+    assert f"f32[{rows},{hidden}]" not in text
     if (encoder, rung) != ("olmoe", 1):
         return
     # the XLA form lowers to the compiler's own grouped kernel, whose custom
@@ -350,6 +350,51 @@ def test_the_small_buckets_grouped_calls_compile(one_chip, encoder, rows,
                          else 128)
 
 
+# a token's experts, by encoder: the combine's tokens are the grouped calls'
+# rows over them
+TOP_K = {"olmoe": 8, "zaya1": 1, "laguna": 10, "joyai": 8}
+
+
+@pytest.mark.parametrize("encoder,rows", [
+    (encoder, rows) for encoder in sorted(GATED_SITES)
+    for rows in SMALL_ROWS[encoder] + GATED_SITES[encoder][:2]])
+def test_the_combine_compiles_at_every_bucket_and_rung(one_chip, encoder,
+                                                       rows):
+    """The experts' way home (``ops/combine.py``) as ONE Mosaic call at the
+    block of tokens ``combine_tokens`` picks, for all four routed encoders
+    at every (bucket, rung) a deployment launches — 128 tokens of ZAYA1's
+    bucket 1 to 32,768 — single rows fetched out of ``f32[rows, hidden /
+    128, 128]`` in HBM (out of ``f32[rows, hidden]`` Mosaic refuses a
+    one-row copy: "Slice shape along dimension 0 must be aligned to tiling
+    (8)"), inside the budget the call names; no ``[pairs, hidden]`` array
+    leaves it."""
+    from realtime_fraud_detection_tpu.ops.combine import (
+        combine_supported,
+        combine_tokens,
+        combine_vmem_bytes,
+        weighted_combine,
+    )
+    from realtime_fraud_detection_tpu.ops.grouped_matmul import VMEM_CEILING
+
+    hidden, top_k = GATED_SITES[encoder][3], TOP_K[encoder]
+    tokens = rows // top_k
+    assert combine_supported(tokens, top_k, hidden)
+    assert combine_vmem_bytes(combine_tokens(tokens, top_k, hidden), top_k,
+                              hidden) <= VMEM_CEILING
+    fn = jax.jit(lambda out3, home, weights, valid: weighted_combine(
+        out3, home, weights, valid, use_pallas=True))
+    text = fn.lower(
+        _sds((rows, hidden // 128, 128), jnp.float32, one_chip),
+        _sds((tokens, top_k), jnp.int32, one_chip),
+        _sds((tokens, top_k), jnp.float32, one_chip),
+        _sds((tokens, top_k), jnp.bool_, one_chip)).compile().as_text()
+    assert text.count(CUSTOM_CALL) == 1
+    assert "jit(combine_rows)/weighted_combine/pallas_call" in text
+    assert f"f32[{tokens},{hidden}]" in text
+    # (one expert a token: the pairs' shape is the tokens' own)
+    assert top_k == 1 or f"f32[{rows},{hidden}]" not in text
+
+
 @pytest.mark.parametrize("bucket,text_len", [
     (BUCKET, 128), (8, 128), (1, 128), (32, DEPLOYED_TEXT_LEN)],
     ids=["bucket256", "parity_bucket8", "bucket1", "halo_at_512"])
@@ -399,9 +444,11 @@ def test_routed_program_compiles_with_every_large_pass_under_a_scope(
     (two of the published layers, every width as published, bucket 256 x 128
     tokens), at both capacities of that bucket's routed blocks
     (``scoring/text_split.capacities``):
-    three Mosaic calls a layer (the experts' two — gate, up and SiLU ⊙ as
-    ``gated_gmm``, down as ``megablox.gmm``, with no float32 ``[rows, I]``
-    between them — and, at the
+    four Mosaic calls a layer (the experts' three — gate, up and SiLU ⊙ as
+    ``gated_gmm``, down as ``down_gmm``, with no float32 ``[rows, I]``
+    between them, and the way home as ``weighted_combine``, with no
+    ``[pairs, hidden]`` float32 array anywhere: no gather of one, no
+    relayout ``copy`` of one — and, at the
     attention site, OLMoE's fused causal core ``windowed_attention`` or
     ZAYA1's fused mixing ``ops/cca_mix.py``), a second small output,
     temporaries that
@@ -443,23 +490,38 @@ def test_routed_program_compiles_with_every_large_pass_under_a_scope(
         blob_bf16=_shapes_of(blobs["bf16"], one_chip), bert_config=config,
         use_pallas=True, text_capacity=capacity).compile()
     text = compiled.as_text()
-    # the experts' two grouped kernels, and the attention site's kernel
-    assert text.count(CUSTOM_CALL) == 3 * config.num_hidden_layers
+    # the experts' two grouped kernels and their combine, and the attention
+    # site's kernel
+    assert text.count(CUSTOM_CALL) == 4 * config.num_hidden_layers
     site = "cca_mix" if encoder == "zaya1" else "windowed_attention"
-    for kernel in (site, "gated_gmm"):
+    for kernel in (site, "gated_gmm", "down_gmm", "weighted_combine"):
         assert len(re.findall(rf"%{kernel}\S* = .*custom-call\(", text)) == (
             config.num_hidden_layers)
-    # both under the scope the trace's attribution reads them by
-    for call in ("jit(gated_gmm)/gated_gmm/pallas_call",
-                 "jit(gmm)/pallas_call"):
+    # each under the scope the trace's attribution reads it by
+    for part, call in (
+            ("matmul", "jit(gated_gmm)/gated_gmm/pallas_call"),
+            ("matmul", "jit(down_gmm)/down_gmm/pallas_call"),
+            ("combine", "jit(combine_rows)/weighted_combine/pallas_call")):
         assert len(re.findall(
-            rf'op_name="[^"]*/experts/matmul/{re.escape(call)}"', text)) == (
-                config.num_hidden_layers), call
+            rf' custom-call\(.*op_name="[^"]*/experts/{part}/'
+            rf'{re.escape(call)}"', text)) == config.num_hidden_layers, call
+    rows = (capacity or BUCKET * 128) * config.num_experts_per_tok
     if encoder == "olmoe":
         # (ZAYA1's experts are as wide as its hidden state: down's result
         # has that shape)
-        rows = (capacity or BUCKET * 128) * config.num_experts_per_tok
         assert f"f32[{rows},{config.intermediate_size}]" not in text
+        # the pairs' float32 rows exist in ONE form, the one down wrote
+        # them in: nothing gathers them into, or copies them as, a [pairs,
+        # hidden] array (at ZAYA1's one expert a token that shape is the
+        # tokens' own)
+        assert f"f32[{rows},{config.hidden_size}]" not in text
+    assert f"f32[{rows},{config.hidden_size // 128},128]" in text
+    for line in text.splitlines():
+        # (under the combine: at one expert a token the compaction gather
+        # of experts/dispatch has the pairs' shape)
+        if "/experts/combine/" in line and re.search(
+                rf" = f32\[{rows},[\d,]+\]\S* (copy|gather)\(", line):
+            raise AssertionError(line)
     assert " conditional(" not in text and "cond/branch_" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 5 << 30
     entry = text[text.index("ENTRY "):]
@@ -576,8 +638,8 @@ def test_laguna_program_compiles_with_its_unlike_layers(one_chip):
     """The served packed program with a ``LagunaConfig``: layer 0 (full
     attention, the dense MLP) and one sliding sparse layer holding 64 of 256
     experts, every width as published, bucket 8 x 2,048 tokens at the
-    three-quarters capacity: two fused cores and the experts' two grouped
-    kernels, a second small output, no conditional, temporaries that leave
+    three-quarters capacity: two fused cores, the experts' two grouped
+    kernels and their combine, a second small output, no conditional, temporaries that leave
     room for the
     cell's five layers of weights in 16 GB."""
     from realtime_fraud_detection_tpu.core.packing import pack_tree
@@ -616,8 +678,12 @@ def test_laguna_program_compiles_with_its_unlike_layers(one_chip):
         blob_bf16=_shapes_of(blobs["bf16"], one_chip), bert_config=config,
         use_pallas=True, text_capacity=12288).compile()
     text = compiled.as_text()
-    assert text.count(CUSTOM_CALL) == 2 + 2
-    assert text.count("jit(gated_gmm)/gated_gmm/pallas_call") == 1
+    assert text.count(CUSTOM_CALL) == 2 + 3
+    for call in ("jit(gated_gmm)/gated_gmm", "jit(down_gmm)/down_gmm",
+                 "jit(combine_rows)/weighted_combine"):
+        assert text.count(f"{call}/pallas_call") == 1
+    # ten experts a token: no [pairs, hidden] float32 array
+    assert "f32[122880,3072]" not in text and "f32[122880,24,128]" in text
     assert text.count("windowed_attention") >= 2
     assert " conditional(" not in text and "cond/branch_" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 6 << 30
@@ -662,8 +728,8 @@ def test_joyai_program_compiles_with_all_256_experts(one_chip, capacity):
     """The served packed program with a ``JoyaiConfig``: layer 0 (the dense
     MLP) and one sparse layer holding all 256 experts, every width as
     published, bucket 8 x 2,048 tokens at both capacities: two fused latent
-    cores and the experts' two grouped kernels, a second small output, no
-    conditional,
+    cores, the experts' two grouped kernels and their combine, a second
+    small output, no conditional, no ``[pairs, 2048]`` float32 array,
     no ``[8, 32, 2048, 2048]`` scores, temporaries that leave room for the
     cell's 10.6 GB of weights in 16 GB."""
     from realtime_fraud_detection_tpu.core.packing import pack_tree
@@ -693,8 +759,13 @@ def test_joyai_program_compiles_with_all_256_experts(one_chip, capacity):
         blob_bf16=_shapes_of(blobs["bf16"], one_chip), bert_config=config,
         use_pallas=True, text_capacity=capacity).compile()
     text = compiled.as_text()
-    assert text.count(CUSTOM_CALL) == 2 + 2
-    assert text.count("jit(gated_gmm)/gated_gmm/pallas_call") == 1
+    assert text.count(CUSTOM_CALL) == 2 + 3
+    for call in ("jit(gated_gmm)/gated_gmm", "jit(down_gmm)/down_gmm",
+                 "jit(combine_rows)/weighted_combine"):
+        assert text.count(f"{call}/pallas_call") == 1
+    pairs = (capacity or 8 * 2048) * 8
+    assert f"f32[{pairs},2048]" not in text
+    assert f"f32[{pairs},16,128]" in text
     assert text.count("windowed_attention") >= 2
     assert " conditional(" not in text and "cond/branch_" not in text
     assert "f32[8,32,2048,2048]" not in text

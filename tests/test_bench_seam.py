@@ -356,7 +356,8 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
     common = {"attn_core_ms_per_batch", "text_ms_per_batch",
               "unscoped_device_pct", "hbm_peak_gb", "expert_ffn_ms_per_batch",
               "expert_matmul_ms_per_batch", "router_ms_per_batch",
-              "expert_imbalance_x", "expert_tile_fill_pct"}
+              "expert_imbalance_x", "expert_tile_fill_pct",
+              "expert_combine_ms_per_batch"}
     olmoe_only = {"expert_ffn_roofline_pct", "router_roofline_pct"}
     zaya_only = {"cca_mix_ms_per_batch", "cca_mix_roofline_pct",
                  "zaya1_expert_ffn_roofline_pct", "zaya1_router_roofline_pct"}
@@ -396,8 +397,8 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
                    "ssm_scan_ms_per_batch", "falconh1_ssd_scan_roofline_pct",
                    "falconh1_attn_core_roofline_pct",
                    "falconh1_ffn_roofline_pct"]
-    assert [m["name"] for m in BM["per_layer"][-7:-1]] == falcon_only
-    for m in BM["per_layer"][-7:-1]:
+    assert [m["name"] for m in BM["per_layer"][-8:-2]] == falcon_only
+    for m in BM["per_layer"][-8:-2]:
         assert m["workloads"] == [FALCON_CELL] and m["layer"] == "kernels"
         assert m["moves"] == "txn_per_s" and m["source"] == "device_trace"
         assert (m["unit"], m["better"]) == (
@@ -405,7 +406,7 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
             else ("ms", "lower"))
     # PR 47 appended one behind those: a counter's share of the six routed
     # cells, a data file over a reader the benchmark had
-    fill = BM["per_layer"][-1]
+    fill = BM["per_layer"][-2]
     assert fill == {
         "name": "expert_tile_fill_pct", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "kernels",
@@ -414,6 +415,17 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
                        / "expert_tile_fill_pct.json").read_text()) == {
         "reader": "counter_share",
         "args": {"num": "expert_rows", "den": "expert_tile_rows"}}
+    # PR 48 one more: the way home's device time in the six routed cells,
+    # so that "rows moved" is a scope's time and no subtraction; a data
+    # file over a reader the benchmark had, a scope the parent has too
+    assert BM["per_layer"][-1] == {
+        "name": "expert_combine_ms_per_batch", "unit": "ms",
+        "better": "lower", "source": "device_trace", "layer": "kernels",
+        "moves": "txn_per_s", "workloads": ROUTED_CELLS}
+    assert json.loads((ROOT / "benchmarks/layer_metrics"
+                       / "expert_combine_ms_per_batch.json").read_text()) == {
+        "reader": "scope_time_per_batch",
+        "args": {"scopes": ["text/layer*/experts/combine"]}}
     assert (by_name[FALCON_CELL]["config"], by_name[FALCON_CELL]["traffic"],
             by_name[FALCON_CELL]["chips"]) == (
         "falcon-h1-34b-s2048", "s2048-remit-saturated", 1)
@@ -434,13 +446,13 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
             assert m["workloads"][-1] == FALCON_CELL, m["name"]
     assert [c["name"] for c in BM["configs"]][-2:] == [
         "joyai-llm-flash-s2048", "falcon-h1-34b-s2048"]
-    for m in BM["per_layer"][-11:-7]:
+    for m in BM["per_layer"][-12:-8]:
         # PR 43 appended its four behind what was there
         assert m["name"] in joyai_only and m["moves"] == "txn_per_s"
         assert m["workloads"] == [JOYAI_CELL] and m["layer"] == "kernels"
     assert len(cells) == 8 and not [w for w in BM["workloads"]
                                     if w["chips"] != 1]
-    per_layer = BM["per_layer"][:-11]    # the checks below: what PR 42 left
+    per_layer = BM["per_layer"][:-12]    # the checks below: what PR 42 left
     # ZAYA1's second cell reports exactly what its first does
     assert reports[ZAYA_FULL_CELL] == reports[ZAYA_CELL]
     # Laguna's: the shared names, its dense layer 0's, and its own six
@@ -680,6 +692,7 @@ def test_the_new_metrics_on_a_hand_made_run():
 
     assert metric("expert_ffn_ms_per_batch") == pytest.approx(320.0)
     assert metric("expert_matmul_ms_per_batch") == pytest.approx(200.0)
+    assert metric("expert_combine_ms_per_batch") == pytest.approx(80.0)
     assert metric("router_ms_per_batch") == pytest.approx(16.0)
     assert metric("attn_core_ms_per_batch") == pytest.approx(12.0)
     assert metric("expert_imbalance_x") == pytest.approx(1.25)
@@ -694,8 +707,9 @@ def test_the_new_metrics_on_a_hand_made_run():
     old = _fake_run({"text": 0.9, "text/layer0/ffn": 0.1},
                     {"batches": 2, "scored": 512, "token_slots": 65536})
     for name in ("expert_ffn_ms_per_batch", "expert_matmul_ms_per_batch",
-                 "expert_ffn_roofline_pct", "router_ms_per_batch",
-                 "router_roofline_pct", "expert_imbalance_x"):
+                 "expert_combine_ms_per_batch", "expert_ffn_roofline_pct",
+                 "router_ms_per_batch", "router_roofline_pct",
+                 "expert_imbalance_x"):
         assert spec.reader_for(name, "per_layer")(old) is None, name
 
 
@@ -1152,7 +1166,8 @@ def test_tiny_rehearsal_of_a_routed_cell(tiny_copy, cell, trace):
         for name in ("expert_ffn_ms_per_batch", "cca_mix_ms_per_batch",
                      "cca_mix_roofline_pct", "laguna_attn_core_roofline_pct",
                      "shared_expert_ms_per_batch", "attn_latent_ms_per_batch",
-                     "joyai_attn_core_roofline_pct", "expert_tile_fill_pct"):
+                     "joyai_attn_core_roofline_pct", "expert_tile_fill_pct",
+                     "expert_combine_ms_per_batch"):
             assert name not in out["metrics"]
     else:
         assert set(out["metrics"]) == {"txn_per_s", "setup_s"}

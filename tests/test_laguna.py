@@ -538,8 +538,9 @@ def test_apply_experts_is_the_same_through_the_kernels(
     """A SHARE of the experts (four of the router's eight, numbered 4 on),
     at both capacities of a launch of 4,096 slots: the pairs of absent
     experts are keyed past the last group, so about half the launched rows
-    enter none and the fused gate + up + SiLU kernel and ``megablox.gmm``
-    (interpreted) never write them; against the XLA form."""
+    enter none, the fused gate + up + SiLU kernel and down's (interpreted)
+    never write them and the combine never fetches them; against the XLA
+    form."""
     layer = init_laguna_params(jax.random.PRNGKey(2), KERNEL_CFG)["layers"][1]
     assert layer["gate_proj"].shape == (4, 128, 128)
     top_k = KERNEL_CFG.num_experts_per_tok

@@ -29,21 +29,23 @@ from realtime_fraud_detection_tpu.models.olmoe import (
 )
 from realtime_fraud_detection_tpu.ops import (
     attention_reference,
+    combine_supported,
     grouped_gated_matmul,
     grouped_matmul,
     grouped_matmul_supported,
 )
 from realtime_fraud_detection_tpu.scoring.text_split import capacities
 from realtime_fraud_detection_tpu.ops.grouped_matmul import (
-    GATED_VMEM_CEILING,
-    GMM_VMEM_BUDGET,
     LANES,
     ROW_TILES,
+    VMEM_CEILING,
+    down_gmm,
+    down_vmem_bytes,
+    down_widths,
     gated_gmm,
     gated_tile_rows,
     gated_vmem_bytes,
     gmm_tiling,
-    gmm_vmem_bytes,
     grouped_matmul_reference,
 )
 
@@ -326,9 +328,11 @@ def test_grouped_matmul_against_a_loop_over_experts(case, form):
     assert grouped_matmul_supported(m, k, n)
     got = grouped_matmul(lhs, rhs, jnp.asarray(sizes, jnp.int32),
                          use_pallas=form != "xla", interpret=True)
-    assert got.dtype == F32 and got.shape == (m, n)
+    # each result row as its own lane tiles: one contiguous piece in HBM
+    assert got.dtype == F32 and got.shape == (m, n // LANES, LANES)
     # bf16 operands are exact in f32; only the order of 128 f32 adds differs
-    np.testing.assert_allclose(got, _loop_over_experts(lhs, rhs, sizes),
+    np.testing.assert_allclose(got.reshape(m, n),
+                               _loop_over_experts(lhs, rhs, sizes),
                                atol=1e-4, rtol=0)
 
 
@@ -385,6 +389,7 @@ def test_the_kernel_declines_what_it_cannot_tile():
     rhs = jnp.ones((2, 128, 64), jnp.bfloat16)
     sizes = jnp.asarray([40, 60], jnp.int32)
     out = grouped_matmul(lhs, rhs, sizes, use_pallas=True, interpret=True)
+    assert out.shape == (100, 1, 64)   # under a lane tile: one piece a row
     np.testing.assert_allclose(out, 128.0)
     # ... and the gated call two of them and the product, by the same
     # predicate: silu(128) * 128
@@ -402,13 +407,15 @@ CELL_SITES = {"olmoe": (196608, 262144, 64, 2048, 1024),
               "laguna": (122880, 163840, 64, 3072, 1024),
               "joyai": (98304, 131072, 256, 2048, 768)}
 # what the rule gives them, (gated, down) at each rung: 256 rows where
-# rows // groups reaches 2,048, else 128
+# rows // groups reaches 2,048, else 128; down's N whole at all four since
+# its call names its own budget (PR 48: ZAYA1's was 1,024 and Laguna's
+# 1,536 inside megablox's 16 MB)
 SHIPPED = {
     "olmoe": 2 * [((256, 2048, 1024), (256, 1024, 2048))],
-    "zaya1": [((128, 2048, 2048), (128, 2048, 1024)),
-              ((256, 2048, 2048), (256, 2048, 1024))],
-    "laguna": [((128, 3072, 1024), (128, 1024, 1536)),
-               ((256, 3072, 1024), (256, 1024, 1536))],
+    "zaya1": [((128, 2048, 2048), (128, 2048, 2048)),
+              ((256, 2048, 2048), (256, 2048, 2048))],
+    "laguna": [((128, 3072, 1024), (128, 1024, 3072)),
+               ((256, 3072, 1024), (256, 1024, 3072))],
     "joyai": 2 * [((128, 2048, 768), (128, 768, 2048))],
 }
 
@@ -425,10 +432,11 @@ def _legal(tiling, m, k, n, gated):
     tm, tk, tn = tiling
     assert tm in ROW_TILES and m % tm == 0
     assert k % tk == 0 and tk % LANES == 0 and n % tn == 0 and tn % LANES == 0
-    if gated:
-        assert gated_vmem_bytes(tm, tk, tn) <= GATED_VMEM_CEILING < 128 << 20
-    else:
-        assert gmm_vmem_bytes(tm, tk, tn) <= GMM_VMEM_BUDGET < 16 << 20
+    room = gated_vmem_bytes if gated else down_vmem_bytes
+    assert room(tm, tk, tn) <= VMEM_CEILING < 128 << 20
+    # down's result block is (tm, tn / 128, 128): all of N or whole
+    # sublane tiles of lane tiles
+    assert gated or tn in down_widths(n)
 
 
 @pytest.mark.parametrize("kernel", ["gated", "down"])
@@ -496,7 +504,7 @@ def test_the_tile_rule_is_legal_wherever_the_kernel_is_asked(m, k, n, groups):
         _legal(tiling, m, k, n, gated)
         assert tiling[1] == k or k == 65536
     assert gmm_tiling(4096, 65536, 1024, 4, gated=True) == (128, 32768, 128)
-    assert gmm_tiling(4096, 65536, 1024, 4) == (128, 8192, 256)
+    assert gmm_tiling(4096, 65536, 1024, 4) == (128, 8192, 1024)
     assert gmm_tiling(4096, 8192, 8192, 4, gated=True) == (128, 8192, 512)
     assert gmm_tiling(384, 128, 384, 3) == (128, 128, 384)
 
@@ -512,11 +520,13 @@ def test_any_two_tiles_are_bit_equal_on_the_real_rows(layout, kernel):
     """With K in one block a tile choice moves no bit of a real row: three
     row tiles against two widths of N, interpreted, equal each other
     exactly and the XLA form to the order of a float32 sum (800 real rows
-    of 1,024 launched; the rows past the last group are nobody's)."""
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
-
+    of 1,024 launched; the rows past the last group are nobody's). Down's
+    widths are all of N or whole sublane tiles of lane tiles
+    (``down_widths``: 2,048 and 1,024 of 2,048), and its XLA form is
+    ``ragged_dot`` reshaped."""
     sizes = jnp.asarray(TILE_LAYOUTS[layout], jnp.int32)
-    m, k, n, real = 1024, 256, 384, 800
+    m, k, real = 1024, 256, 800
+    n = 384 if kernel == "gated" else 2048
     keys = jax.random.split(jax.random.PRNGKey(47), 3)
     lhs = jax.random.normal(keys[0], (m, k), F32).astype(jnp.bfloat16)
     a, b = ((jax.random.normal(key, (5, k, n), F32) * 0.1
@@ -531,10 +541,12 @@ def test_any_two_tiles_are_bit_equal_on_the_real_rows(layout, kernel):
         atol = 2e-2
     else:
         def at(tiling):
-            return gmm(lhs, a, sizes, F32, tiling, interpret=True)
-        want, atol = grouped_matmul_reference(lhs, a, sizes), 1e-4
+            return down_gmm(lhs, a, sizes, tiling=tiling, interpret=True)
+        assert down_widths(n) == [2048, 1024] and down_widths(384) == [384]
+        want, atol = grouped_matmul(lhs, a, sizes), 1e-4
+        assert want.shape == (m, n // LANES, LANES)
     first = np.asarray(at((128, k, n)), np.float32)[:real]
-    for tiling in ((256, k, 128), (512, k, n)):
+    for tiling in ((256, k, 128 if kernel == "gated" else 1024), (512, k, n)):
         np.testing.assert_array_equal(
             np.asarray(at(tiling), np.float32)[:real], first)
     np.testing.assert_allclose(first, np.asarray(want, np.float32)[:real],
@@ -670,8 +682,8 @@ def test_unrouted_rows_enter_no_group_and_get_zero(params32):
 @pytest.mark.parametrize("capacity", [None, 45, 32])
 def test_rows_the_kernel_never_wrote_reach_nothing(params32, ragged,
                                                    monkeypatch, capacity):
-    """``megablox.gmm`` visits only the tiles of a group: with the groups
-    summing to fewer rows than were launched, the rest of its result is
+    """The grouped kernels visit only the tiles of a group: with the groups
+    summing to fewer rows than were launched, the rest of their result is
     uninitialised. Poisoned here, it must change nothing."""
     ids, mask = ragged
     want_h, _ = olmoe_encode(params32, ids, mask, CFG, capacity=capacity)
@@ -682,7 +694,8 @@ def test_rows_the_kernel_never_wrote_reach_nothing(params32, ragged,
     def tail_poisoned(call):
         def poisoning(*operands, **kw):
             out, group_sizes = call(*operands, **kw), operands[-1]
-            written = jnp.arange(out.shape[0])[:, None] < jnp.sum(group_sizes)
+            written = (jnp.arange(out.shape[0]) < jnp.sum(group_sizes)
+                       ).reshape((-1,) + (1,) * (out.ndim - 1))
             poisoned.append(out.shape[0] - real_pairs)
             return jnp.where(written, out, jnp.nan)
 
@@ -732,8 +745,8 @@ def test_compacted_through_the_kernel(capacity):
 def test_apply_experts_is_the_same_through_the_kernels(
         experts_through_both_forms, rung, stored, atol):
     """Experts as wide as a lane tile, at both capacities of a launch of
-    4,096 slots: the fused gate + up + SiLU kernel and ``megablox.gmm``
-    (interpreted) against the XLA form."""
+    4,096 slots: the fused gate + up + SiLU kernel, down's and the
+    combine (interpreted) against the XLA form."""
     cfg = dataclasses.replace(CFG, intermediate_size=128, num_hidden_layers=1)
     layer = jax.tree.map(
         lambda a: a.astype(stored),
@@ -1018,7 +1031,11 @@ def test_the_gate_up_site_is_counted_and_entered_once_a_sparse_layer(side):
     a fallback where the predicate (experts 64 wide: under a lane tile) or
     the selector (a CPU mesh, nothing asked) left it the three-call form —
     and the compile ledger shows the program's trace entering ``gated_gmm``
-    once a sparse layer and ``gmm`` once (it was three times)."""
+    once a sparse layer and ``down_gmm`` once. ``expert_combine`` beside it
+    by ITS predicate (``combine_supported``: hidden 128 is a lane tile, so
+    the launch whose experts are too narrow for the grouped kernels still
+    brings its rows home through the one kernel, ``combine_rows`` once a
+    sparse layer)."""
     import time
 
     from realtime_fraud_detection_tpu.scoring import FraudScorer, ScorerConfig
@@ -1058,7 +1075,13 @@ def test_the_gate_up_site_is_counted_and_entered_once_a_sparse_layer(side):
                traces[0].get("nested", {}).items()}
     layers = cfg.num_hidden_layers
     assert entered.get("gated_gmm", 0) == held * layers
-    assert entered.get("gmm", 0) == held * layers
+    assert entered.get("down_gmm", 0) == held * layers
+    home = int(side != "not_asked")
+    assert combine_supported(8 * 128, cfg.num_experts_per_tok,
+                             cfg.hidden_size)
+    assert snap["dispatch"]["expert_combine"] == home
+    assert snap["fallback"]["expert_combine"] == 1 - home
+    assert entered.get("combine_rows", 0) == home * layers
     # the span the bucket's programs were built under names the tiles of
     # both grouped calls, and the launch counts the rows their grid
     # visited: whole row tiles, over the real pairs; nothing of either
@@ -1094,7 +1117,8 @@ def test_the_dense_program_has_no_second_output_and_no_expert_rows():
     assert pending.expert_peak_rows == 0
     # nor has its snapshot the routed experts' site
     snap = scorer.kernel_snapshot()
-    assert "expert_gate_up" not in {**snap["dispatch"], **snap["fallback"]}
+    assert not {"expert_gate_up", "expert_combine"} & {
+        *snap["dispatch"], *snap["fallback"]}
 
 
 def test_the_seam_leaves_the_dense_program_as_it_was(monkeypatch):

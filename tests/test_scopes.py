@@ -218,15 +218,18 @@ def test_every_name_in_a_builders_vocabulary_is_one_the_program_writes(
         for part in scopes.EXPERTS_PARTS:
             assert getattr(scopes, f"EXPERTS_{part.upper()}") == (
                 f"{scopes.EXPERTS}/{part}")
-        # the experts' two kernels: down (megablox's) and the fused gate +
-        # up + SiLU one, as a device trace names them
-        for kernel in ("jit(gmm)/pallas_call",
-                       "jit(gated_gmm)/gated_gmm/pallas_call"):
+        # the experts' two grouped kernels, down's and the fused gate + up
+        # + SiLU one, and the combine's, as a device trace names them
+        for part, kernel in (
+                (scopes.EXPERTS_MATMUL, "jit(down_gmm)/down_gmm/pallas_call"),
+                (scopes.EXPERTS_MATMUL,
+                 "jit(gated_gmm)/gated_gmm/pallas_call"),
+                (scopes.EXPERTS_COMBINE,
+                 "jit(combine_rows)/weighted_combine/pallas_call")):
             assert bench.scope_path(
                 f"jit(f)/{scopes.TEXT}/{scopes.layer_scope(3)}/"
-                f"{scopes.EXPERTS_MATMUL}/{kernel}", vocabulary
-            ) == (f"{scopes.TEXT}/{scopes.layer_scope(3)}/"
-                  f"{scopes.EXPERTS_MATMUL}")
+                f"{part}/{kernel}", vocabulary
+            ) == f"{scopes.TEXT}/{scopes.layer_scope(3)}/{part}"
     assert set(vocabulary) == set(scopes.BRANCH_SCOPES)
     assert set(vocabulary[scopes.TEXT]["layer*"]) == set(layer_parts)
     asm = _lowered_asm(config)

@@ -587,7 +587,7 @@ def test_causality_a_later_token_moves_no_earlier_position(params, text):
 
 
 def test_the_encoder_is_the_same_through_the_kernel():
-    """Whole tiles through ``megablox.gmm`` in interpret mode: top-1 rows
+    """Whole tiles through the grouped kernels in interpret mode: top-1 rows
     (128 slots = one row tile) against the XLA form."""
     cfg = dataclasses.replace(CFG, num_hidden_layers=2)
     p = init_zaya_params(jax.random.PRNGKey(4), cfg)
@@ -604,8 +604,8 @@ def test_the_encoder_is_the_same_through_the_kernel():
 def test_apply_experts_is_the_same_through_the_kernels(
         experts_through_both_forms, rung):
     """One expert a token, four groups of 128-wide experts, at both
-    capacities of a launch of 4,096 slots: the fused gate + up + SiLU kernel
-    and ``megablox.gmm`` (interpreted) against the XLA form."""
+    capacities of a launch of 4,096 slots: the fused gate + up + SiLU kernel,
+    down's and the combine (interpreted) against the XLA form."""
     layer = init_zaya_params(jax.random.PRNGKey(4), CFG)["layers"][1]
     assert layer["gate_proj"].shape == (4, 128, 128)
     sizes = experts_through_both_forms(

@@ -2,12 +2,13 @@
 tiles: the sweep ``ops/grouped_matmul.gmm_tiling``'s constants come from.
 
     chiprun -- python3 tools/grouped_alone.py --out chiprun_out/grouped_alone.json
+    chiprun -- python3 tools/grouped_alone.py --combine --out chiprun_out/x.json
     JAX_PLATFORMS=cpu python3 tools/grouped_alone.py --aot     # no chip
 
 For each routed encoder (OLMoE, ZAYA1, Laguna, JoyAI) at both capacity
 rungs of its cell's bucket (three quarters of the slots, every slot), for
 the fused gate + up + SiLU kernel (``gated_gmm``) and for down's
-``megablox.gmm``, under even and skewed groups of the cell's real rows: the
+``down_gmm``, under even and skewed groups of the cell's real rows: the
 time of one call at each candidate ``(tm, tk, tn)`` (host clock round
 ``REPEATS`` x ``CALLS`` calls that end in ``block_until_ready``, the
 fastest repeat; the kernels take 1-10 ms, a dispatch ~0.03), whether its
@@ -26,14 +27,29 @@ DESCRIBED v5e instead (nothing runs): which tiles Mosaic takes inside the
 budget their call names. ``--rehearse`` runs the script end to end on a
 CPU at a tiny shape, the kernels interpreted.
 
+``--combine`` (PR 48) times one layer's **down + way home** as a pair
+instead, alone on the chip, at the same sites with each encoder's ``top_k``
+(8 / 1 / 10 / 8) and Laguna's held share, ``home`` from a real stable sort:
+(a) the parent's form — ``megablox.gmm`` into ``f32[M, H]``, ``out[home]``,
+the weighted sum; (x) ``down_gmm`` into ``f32[M, H / 128, 128]`` and XLA's
+gather and sum on the 3-D array; (y) ``down_gmm`` and
+``ops.combine.combine_rows`` at 32 to 256 tokens a step; beside them down
+alone by spelling of its store (against ``megablox.gmm`` at the parent's
+tile and at the rule's) and the way home alone on a result that is already
+there (ns a fetched row, ns an issued pair). ``tools/grouped_alone_pr48.json``
+is its output; ``--aot`` and ``--rehearse`` work with it.
+
 Not part of the package's import graph and not under ``benchmarks/``: a
-builder's instrument (ROADMAP D18). The next user is S12's row movement,
-whose candidates want the same shapes, group layouts and clock.
+builder's instrument (ROADMAP D18). The next users are what is left of S12:
+the dispatch gathers (the every-slot program's ``bf16[262144, 2048]`` rows
+into expert order, ZAYA1's compaction gather) and ``routed_block``'s scatter
+home, whose candidates want the same shapes, group layouts and clock.
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import gc
 import json
 import os
@@ -48,12 +64,23 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental.pallas.ops.tpu.megablox import gmm
 
+from realtime_fraud_detection_tpu.ops.combine import (
+    TOKEN_TILES,
+    combine_rows,
+    combine_tokens,
+    weighted_combine_reference,
+)
 from realtime_fraud_detection_tpu.ops.grouped_matmul import (
     LANES,
+    down_gmm,
     gated_gmm,
     gmm_tiling,
     grouped_matmul_reference,
 )
+
+# the package exports the FUNCTION ``ops.grouped_matmul`` under its module's
+# name: the module itself, for the scaffold the spellings are built on
+gm = sys.modules["realtime_fraud_detection_tpu.ops.grouped_matmul"]
 
 # encoder: (rows at the 3/4 rung, at every slot), held groups, hidden,
 # one expert's width, (real rows at each rung: what the cell's traffic
@@ -109,7 +136,9 @@ def candidates(m: int, k: int, n: int, groups: int, gated: bool):
         for tm in ROW_TILES:
             if m % tm == 0:
                 out.append((tm, k, tn))
-    return list(dict.fromkeys(out))
+    # down's result block is 3-D: all of N, or whole sublane tiles of it
+    return [t for t in dict.fromkeys(out)
+            if gated or t[2] in gm.down_widths(n)]
 
 
 def group_sizes(rng, groups: int, real: int, concentration: float):
@@ -122,8 +151,8 @@ def make_call(gated: bool, tiling, interpret: bool = False):
         return jax.jit(lambda x, a, b, s: gated_gmm(
             x, a, b, s, out_dtype=jnp.dtype(jnp.bfloat16), tiling=tiling,
             interpret=interpret))
-    return jax.jit(lambda x, a, b, s: gmm(x, a, s, jnp.float32, tiling,
-                                          interpret=interpret))
+    return jax.jit(lambda x, a, b, s: down_gmm(x, a, s, tiling=tiling,
+                                               interpret=interpret))
 
 
 def xla_form(gated: bool):
@@ -131,7 +160,8 @@ def xla_form(gated: bool):
         return jax.jit(lambda x, a, b, s: (
             jax.nn.silu(grouped_matmul_reference(x, a, s))
             * grouped_matmul_reference(x, b, s)).astype(jnp.bfloat16))
-    return jax.jit(lambda x, a, b, s: grouped_matmul_reference(x, a, s))
+    return jax.jit(lambda x, a, b, s: grouped_matmul_reference(
+        x, a, s).reshape(x.shape[0], -1, LANES))
 
 
 def timed_ms(fn, *args) -> float:
@@ -234,15 +264,326 @@ def sweep(encoders, out_path, interpret=False):
     return result
 
 
-def aot(encoders):
-    """Each candidate compiled for a described v5e: ``ok`` or the
-    compiler's refusal."""
+# ------------------------------------------------------------------ --combine
+# encoder: a token's experts, the router's width (the held groups of SITES
+# are numbered from 0), and the real tokens at each rung
+ROUTING = {
+    "olmoe": (8, 64, (21300, 28700)),
+    "zaya1": (1, 16, (21300, 28700)),
+    "laguna": (10, 256, (11500, 15400)),
+    "joyai": (8, 256, (9900, 13500)),
+    "tiny": (2, 8, (400, 750)),
+}
+
+
+def pr47_down_tiling(m: int, k: int, n: int, groups: int):
+    """The parent's rule for ``megablox.gmm`` (PR 47): K whole, N as wide
+    as 13 MB hold, 256 rows where ``m // groups`` reaches 2,048."""
+    def room(tm, tk, tn):
+        return 2 * (tm * tk + tk * tn) * 2 + 2 * tm * tn * 4
+
+    def widest(sides, tile):
+        return next((t for t in sides if room(*tile(t)) <= 13 << 20), LANES)
+
+    tk = widest(gm._lane_divisors(k), lambda t: (LANES, t, LANES))
+    tn = widest(gm._lane_divisors(n), lambda t: (LANES, tk, t))
+    rows = [t for t in (256, 128) if m % t == 0 and t * 8 <= m // groups]
+    return widest(rows, lambda t: (t, tk, tn)), tk, tn
+
+
+def routed(key, tokens: int, real: int, top_k: int, width: int, held: int,
+           concentration: float):
+    """What ``models.olmoe.apply_experts`` computes under its ``router``
+    scope for ``tokens`` slots of which the first ``real`` are real, each
+    with ``top_k`` distinct experts of ``width`` drawn by a popularity from
+    ``dirichlet(concentration)``, the experts numbered under ``held`` held
+    here: ``(group_sizes i32[held], home i32[tokens, top_k], weights,
+    valid, real)`` from a real stable sort."""
+    k_pop, k_draw, k_w = jax.random.split(key, 3)
+    popularity = jnp.log(jax.random.dirichlet(
+        k_pop, jnp.full((width,), concentration)))
+    # Gumbel top-k: top_k distinct experts a token by the popularity
+    noise = jax.random.gumbel(k_draw, (tokens, width))
+    experts = jax.lax.top_k(popularity[None] + noise, top_k)[1].astype(
+        jnp.int32)
+    weights = jax.random.uniform(k_w, (tokens, top_k), jnp.float32)
+    valid = (experts < held) & (jnp.arange(tokens) < real)[:, None]
+    flat = jnp.where(valid, experts, held).reshape(-1)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    sizes = jnp.sum(flat[:, None] == jnp.arange(held, dtype=jnp.int32)[None],
+                    axis=0, dtype=jnp.int32)
+    home = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=jnp.int32))
+    return (sizes, home.reshape(tokens, top_k), weights, valid,
+            jnp.arange(tokens) < real)
+
+
+def parent_way_home(out, home, weights, valid, real, share: bool):
+    """``models.olmoe.apply_experts``' combine as the parent traces it, on
+    ``megablox.gmm``'s ``f32[M, H]``: ``out[home]``, the weights, the sum
+    (a share of the experts: expert-major, absent pairs selected out), the
+    other rows selected to zero."""
+    n, top_k = home.shape
+    if share:
+        back = out[home.T.reshape(-1)].reshape(top_k, n, -1)
+        back = jnp.where(valid.T[:, :, None], back, 0.0)
+        y = jnp.sum(back * weights.T[:, :, None], axis=0)
+    else:
+        back = out[home.reshape(-1)].reshape(n, top_k, -1)
+        y = jnp.sum(back * weights[:, :, None], axis=1)
+    return jnp.where(real[:, None], y, 0.0)
+
+
+def _down_body(spelling: str):
+    """down's kernel body in the spellings ISSUE 48 names, on the package's
+    scaffold: ``strided`` (what ships: a lane tile at a time, a store with
+    a sublane stride), ``reshape`` (one 3-D store of the reshaped result),
+    ``dots`` (a 128-wide dot a lane tile), ``whole`` (strided, and a tile
+    that one group owns whole is stored without reading what it held)."""
+    from jax.experimental import pallas as pl
+
+    if spelling == "strided":
+        return gm._down_kernel
+
+    def body(offsets, group_ids, row_tiles, lhs, w, out, *accs, tm, tn,
+             tiles_k):
+        chunks = tn // LANES
+        visit = pl.program_id(1)
+        if spelling == "dots":
+            assert tiles_k == 1
+            mine = gm._own_rows(visit, offsets, group_ids, row_tiles, tm,
+                                (tm, LANES))
+            x = lhs[...]
+            for c in range(chunks):
+                out[:, c, :] = jnp.where(mine, jnp.dot(
+                    x, w[:, c * LANES:(c + 1) * LANES],
+                    preferred_element_type=jnp.float32), out[:, c, :])
+            return
+
+        def store(result):
+            if spelling == "reshape":
+                mine = gm._own_rows(visit, offsets, group_ids, row_tiles,
+                                    tm, (tm, chunks, LANES))
+                out[...] = jnp.where(
+                    mine, result.reshape(tm, chunks, LANES), out[...])
+                return
+            group = group_ids[visit]
+            first = row_tiles[visit] * tm
+            whole = (offsets[group] <= first) & (
+                first + tm <= offsets[group + 1])
+
+            @pl.when(whole)
+            def _():
+                for c in range(chunks):
+                    out[:, c, :] = result[:, c * LANES:(c + 1) * LANES]
+
+            @pl.when(jnp.logical_not(whole))
+            def _():
+                mine = gm._own_rows(visit, offsets, group_ids, row_tiles,
+                                    tm, (tm, LANES))
+                for c in range(chunks):
+                    out[:, c, :] = jnp.where(
+                        mine, result[:, c * LANES:(c + 1) * LANES],
+                        out[:, c, :])
+
+        gm._over_k((jnp.dot(lhs[...], w[...],
+                            preferred_element_type=jnp.float32),),
+                   accs, tiles_k, store)
+    return body
+
+
+SPELLINGS = ("strided", "reshape", "dots", "whole")
+
+
+def down_spelled(spelling: str, tiling, interpret: bool = False):
+    def call(lhs, rhs, sizes):
+        m, k = lhs.shape
+        n = rhs.shape[-1]
+        tm, tk, tn = tiling
+        return gm._grouped_call(
+            _down_body(spelling), "down_gmm", lhs, (rhs,), sizes, tiling,
+            out_shape=jax.ShapeDtypeStruct((m, n // LANES, LANES),
+                                           jnp.float32),
+            out_block=(tm, tn // LANES, LANES),
+            out_index=lambda row_tile, n_i: (row_tile, n_i, 0),
+            vmem=gm.down_vmem_bytes(tm, tk, tn), flops_per_mkn=2,
+            transcendentals=0, interpret=interpret)
+    return jax.jit(call)
+
+
+def combine_programs(encoder, rung_index, interpret, spellings):
+    """One (encoder, rung)'s programs: ``(site, pair, alone, home_alone)``
+    — the pair by form ``(act, w, sizes, home, weights, valid, real) -> y``,
+    down alone ``(act, w, sizes) -> out`` by spelling beside
+    ``megablox.gmm``, and the way home alone ``(out, home, weights, valid,
+    real) -> y`` by form."""
+    rungs, groups, hidden, width, _ = SHAPES[encoder]
+    top_k, router_width, reals = ROUTING[encoder]
+    m, real = rungs[rung_index], reals[rung_index]
+    share = router_width != groups
+    tokens = m // top_k
+    token_tiles = [t for t in sorted(TOKEN_TILES) if tokens % t == 0
+                   and 2 * top_k * t * hidden * 4 <= 48 << 20]
+    parent_tile = pr47_down_tiling(m, width, hidden, groups)
+    tile = gmm_tiling(m, width, hidden, groups)
+    site = {"rows": m, "tokens": tokens, "top_k": top_k, "hidden": hidden,
+            "width": width, "groups": groups, "router_width": router_width,
+            "real_tokens": real, "parent_tile": list(parent_tile),
+            "tile": list(tile),
+            "rule_tokens": combine_tokens(tokens, top_k, hidden)}
+
+    def megablox(tiling):
+        return lambda act, w, sizes: gmm(act, w, sizes, jnp.float32, tiling,
+                                         interpret=interpret)
+
+    def down(act, w, sizes):
+        return down_gmm(act, w, sizes, tiling=tile, interpret=interpret)
+
+    home_alone = {
+        "a_parent": functools.partial(parent_way_home, share=share),
+        "x_gather3d": lambda out, home, weights, valid, real:
+            weighted_combine_reference(out, home, weights, valid)}
+    for tm in token_tiles:
+        home_alone[f"y_tm{tm}"] = (
+            lambda out, home, weights, valid, real, tm=tm: combine_rows(
+                out, jnp.where(valid, home, -1),
+                jnp.where(valid, weights, 0.0), tokens=tm,
+                interpret=interpret))
+    pair = {name: (lambda act, w, sizes, *rest, name=name, fn=fn: fn(
+        (megablox(parent_tile) if name == "a_parent" else down)(
+            act, w, sizes), *rest)) for name, fn in home_alone.items()}
+    alone = {"megablox": megablox(parent_tile)}
+    if tile != parent_tile:
+        alone["megablox_at_tile"] = megablox(tile)
+    for spelling in spellings:
+        alone[f"down_{spelling}"] = down_spelled(spelling, tile, interpret)
+    jit = lambda fns: {name: jax.jit(fn) for name, fn in fns.items()}
+    return site, jit(pair), jit(alone), jit(home_alone)
+
+
+def combine_sweep(encoders, out_path, interpret=False, spellings=SPELLINGS):
+    """One layer's down + way home, alone on the chip: the pair by form,
+    down alone by spelling beside ``megablox.gmm``, the way home alone (on
+    a result that is already there) by form; under even and skewed groups,
+    ``home`` from a real stable sort."""
+    device = jax.devices()[0]
+    result = {"device": {"platform": device.platform,
+                         "kind": device.device_kind},
+              "clock": f"host, fastest of {REPEATS} x {CALLS} calls",
+              "sites": {}}
+    draw = jax.jit(routed, static_argnums=(1, 2, 3, 4, 5, 6))
+
+    def timed(rows, fns, args, note):
+        first = None
+        for name, fn in fns.items():
+            try:
+                ms = timed_ms(fn, *args(name))
+                got = fn(*args(name))
+                first = got if first is None else first
+                rows[name] = {"ms": ms, **note(got, first)}
+                del got
+            except Exception as e:  # noqa: BLE001 — refused
+                rows[name] = {"error": str(e)[-300:]}
+
+    for encoder in encoders:
+        for index, rung in enumerate(RUNGS[-len(SHAPES[encoder][0]):]):
+            site, pair, alone, home_alone = combine_programs(
+                encoder, index, interpret, spellings)
+            m, tokens = site["rows"], site["tokens"]
+            act, w, _ = operands(False, m, site["groups"], site["width"],
+                                 site["hidden"])
+            site["layouts"] = {}
+            result["sites"][f"{encoder}.{rung}"] = site
+            for layout, concentration in (("even", 1e6), ("skewed", 8.0)):
+                sizes, home, weights, valid, real = draw(
+                    jax.random.PRNGKey(48), tokens, site["real_tokens"],
+                    site["top_k"], site["router_width"], site["groups"],
+                    concentration)
+                fetched, held = int(jnp.sum(valid)), int(jnp.sum(sizes))
+                assert fetched == held
+                row = site["layouts"][layout] = {
+                    "fetched_rows": fetched,
+                    "largest_over_mean": float(
+                        jnp.max(sizes) / jnp.maximum(jnp.mean(sizes), 1)),
+                    "pair": {}, "down_alone": {}, "home_alone": {}}
+                way = (home, weights, valid, real)
+                timed(row["pair"], pair, lambda name: (act, w, sizes, *way),
+                      lambda got, first: {"max_abs_from_parent": float(
+                          jnp.max(jnp.abs(got - first)))})
+                timed(row["down_alone"], alone,
+                      lambda name: (act, w, sizes),
+                      lambda got, first: {"bit_equal_to_megablox": bool(
+                          jnp.all(got.reshape(m, -1)[:held]
+                                  == first.reshape(m, -1)[:held]))})
+                outs = {"a_parent": alone["megablox"](act, w, sizes)}
+                out3 = down_gmm(act, w, sizes, tiling=tuple(site["tile"]),
+                                interpret=interpret)
+                timed(row["home_alone"], home_alone,
+                      lambda name: (outs.get(name, out3), *way),
+                      lambda got, first: {})
+                for name, cell in row["home_alone"].items():
+                    if "ms" in cell:
+                        cell["ns_per_fetched_row"] = (
+                            cell["ms"] * 1e6 / max(fetched, 1))
+                        cell["ns_per_issued_row"] = cell["ms"] * 1e6 / m
+                del outs, out3
+                print(encoder, rung, layout, json.dumps(row), flush=True)
+                os.makedirs(os.path.dirname(out_path) or ".", exist_ok=True)
+                with open(out_path, "w") as f:
+                    json.dump(result, f, indent=1)
+            del act, w, pair, alone, home_alone
+            gc.collect()
+    return result
+
+
+def combine_aot(encoders, spellings=SPELLINGS):
+    """Every program of ``combine_sweep`` compiled for a described v5e."""
+    chip = described_chip()
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    for encoder in encoders:
+        for index, rung in enumerate(RUNGS[-len(SHAPES[encoder][0]):]):
+            site, pair, alone, home_alone = combine_programs(
+                encoder, index, False, spellings)
+            m, tokens, top_k = site["rows"], site["tokens"], site["top_k"]
+            chunks = site["hidden"] // LANES
+            down = (sds((m, site["width"]), jnp.bfloat16),
+                    sds((site["groups"], site["width"], site["hidden"]),
+                        jnp.bfloat16), sds((site["groups"],), jnp.int32))
+            way = (sds((tokens, top_k), jnp.int32),
+                   sds((tokens, top_k), jnp.float32),
+                   sds((tokens, top_k), jnp.bool_), sds((tokens,), jnp.bool_))
+            for kind, fns, args in (
+                    ("pair", pair, lambda name: down + way),
+                    ("down_alone", alone, lambda name: down),
+                    ("home_alone", home_alone, lambda name: (
+                        sds((m, site["hidden"]) if name == "a_parent"
+                            else (m, chunks, LANES), jnp.float32),) + way)):
+                for name, fn in fns.items():
+                    try:
+                        fn.lower(*args(name)).compile()
+                        verdict = "ok"
+                    except Exception as e:  # noqa: BLE001
+                        verdict = "REFUSED " + str(e).strip()[-160:].replace(
+                            "\n", " ")
+                    print(encoder, rung, kind, name, verdict, flush=True)
+
+
+def described_chip():
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
-    chip = SingleDeviceSharding(topo.devices[0])
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def aot(encoders):
+    """Each candidate compiled for a described v5e: ``ok`` or the
+    compiler's refusal."""
+    chip = described_chip()
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
@@ -274,10 +615,22 @@ def main():
     ap.add_argument("--out", default="chiprun_out/grouped_alone.json")
     ap.add_argument("--aot", action="store_true")
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--combine", action="store_true",
+                    help="down + the way home as a pair, by form (PR 48)")
+    ap.add_argument("--spellings", nargs="*", default=list(SPELLINGS))
     args = ap.parse_args()
     if args.encoders is None:
-        args.encoders = sorted(SITES) + (sorted(SMALL) if args.small else [])
-    if args.rehearse:
+        args.encoders = sorted(SITES) + (
+            sorted(SMALL) if args.small and not args.combine else [])
+    if args.combine:
+        if args.rehearse:
+            combine_sweep(sorted(TINY), args.out, interpret=True,
+                          spellings=args.spellings)
+        elif args.aot:
+            combine_aot(args.encoders, args.spellings)
+        else:
+            combine_sweep(args.encoders, args.out, spellings=args.spellings)
+    elif args.rehearse:
         sweep(sorted(TINY), args.out, interpret=True)
     elif args.aot:
         aot(args.encoders)
