@@ -23,12 +23,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from realtime_fraud_detection_tpu.models.text_encoder import (
+    EVERY_PLANE,
+    KernelSite,
+    TextEncoder,
+)
 from realtime_fraud_detection_tpu.obs import scopes
 from realtime_fraud_detection_tpu.ops.attention import (
     attention_reference,
     flash_attention,
     flash_supported,
     merge_heads,
+    narrowest_supported_len,
     split_heads,
 )
 from realtime_fraud_detection_tpu.ops.dequant_matmul import (
@@ -315,3 +321,29 @@ def bert_predict(
                          dequant_kernel=dequant_kernel,
                          kernel_interpret=kernel_interpret)
     return jax.nn.softmax(logits, axis=-1)[:, 1]
+
+
+def _attention_refusal(config: BertConfig, width: int, slots: int):
+    if flash_supported(width, config.head_dim, config.num_heads):
+        return None
+    return (f"flash_attention takes seq_len a multiple of 128 and pairs of "
+            f"64-wide heads: seq_len {width}, head_dim {config.head_dim}")
+
+
+def _text_predict(params, input_ids, attention_mask, config, *, use_pallas,
+                  kernel_interpret, capacity, dequant_kernel):
+    return bert_predict(params, input_ids, attention_mask, config,
+                        use_pallas=use_pallas, dequant_kernel=dequant_kernel,
+                        kernel_interpret=kernel_interpret), None
+
+
+# the dense bidirectional encoder: the planes were written for its
+# parameter layout, and the narrow width of a split batch is its attention
+# kernel's (models/text_encoder.py)
+TEXT_ENCODER = TextEncoder(
+    config_class=BertConfig, init=init_bert_params, predict=_text_predict,
+    depth=lambda config: config.num_layers,
+    sites=(KernelSite("attention", _attention_refusal),),
+    planes=EVERY_PLANE,
+    narrow_width=lambda config: narrowest_supported_len(config.head_dim,
+                                                        config.num_heads))

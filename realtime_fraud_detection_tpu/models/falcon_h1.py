@@ -67,6 +67,11 @@ from realtime_fraud_detection_tpu.models.olmoe import (
     rms_norm,
     rope_tables,
 )
+from realtime_fraud_detection_tpu.models.text_encoder import (
+    KernelSite,
+    TextEncoder,
+    causal_counters,
+)
 from realtime_fraud_detection_tpu.obs import scopes
 from realtime_fraud_detection_tpu.ops.attention import (
     attention_reference,
@@ -487,3 +492,32 @@ def falcon_h1_predict(params: Dict, input_ids: jax.Array,
                               use_pallas=use_pallas,
                               kernel_interpret=kernel_interpret)
     return jax.nn.softmax(logits, axis=-1)[:, 1]
+
+
+def _text_predict(params, input_ids, attention_mask, config, *, use_pallas,
+                  kernel_interpret, capacity, dequant_kernel):
+    return falcon_h1_predict(params, input_ids, attention_mask, config,
+                             use_pallas=use_pallas,
+                             kernel_interpret=kernel_interpret), None
+
+
+def _dispatch_counters(config, launches, lengths):
+    slots = sum(la.size * la.width for la in launches)
+    return dict(causal_counters(config, launches, lengths),
+                ssm_chunks=slots // config.mamba_chunk_size
+                * config.num_hidden_layers)
+
+
+# causal and dense: one launch at text_len on one device, every slot
+# computed, and a second kernel site, the mixer's scan
+# (models/text_encoder.py)
+TEXT_ENCODER = TextEncoder(
+    config_class=FalconH1Config, init=init_falcon_h1_params,
+    predict=_text_predict,
+    depth=lambda config: config.num_hidden_layers,
+    sites=(KernelSite("attention",
+                      lambda c, width, slots: c.core_refusal(width)),
+           KernelSite("ssm_scan",
+                      lambda c, width, slots: c.scan_refusal(width))),
+    one_device="the fused causal core and the scan; a causal",
+    dispatch_counters=_dispatch_counters)

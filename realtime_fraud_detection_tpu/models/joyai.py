@@ -44,7 +44,7 @@ the last layer that predicts token ``i + 2`` for a training loss and for
 speculative decoding; this path emits one probability a row and no token,
 and holds none of it.
 
-What the routed-encoder seam reads (``scoring/pipeline.CausalText``):
+What the routed-encoder seam reads (``models/text_encoder.py``):
 ``num_experts`` = ``n_routed_experts`` (every expert is held here),
 ``intermediate_size`` = ONE expert's width ``moe_intermediate_size`` (the
 source's ``intermediate_size``, the dense layers' MLP, is
@@ -79,6 +79,7 @@ from realtime_fraud_detection_tpu.models.olmoe import (
     routed_block,
     token_slots,
 )
+from realtime_fraud_detection_tpu.models.text_encoder import routed_encoder
 from realtime_fraud_detection_tpu.obs import scopes
 from realtime_fraud_detection_tpu.ops.attention import (
     attention_reference,
@@ -487,3 +488,7 @@ def joyai_predict(params: Dict, input_ids: jax.Array,
                                config.rms_norm_eps)
     p = jax.nn.softmax(logits, axis=-1)[:, 1]
     return (p, stats) if with_stats else p
+
+
+TEXT_ENCODER = routed_encoder(JoyaiConfig, init_joyai_params, joyai_predict,
+                              JoyaiConfig.core_refusal)

@@ -81,6 +81,7 @@ from realtime_fraud_detection_tpu.models.olmoe import (
     router_probs,
     token_slots,
 )
+from realtime_fraud_detection_tpu.models.text_encoder import routed_encoder
 from realtime_fraud_detection_tpu.obs import scopes
 from realtime_fraud_detection_tpu.ops.attention import (
     attention_reference,
@@ -184,7 +185,7 @@ class LagunaConfig:
     @property
     def intermediate_size(self) -> int:
         """One routed expert's width, under the name the routed-encoder
-        seam reads (``scoring/pipeline.CausalText``)."""
+        seam reads (``models/text_encoder.py``)."""
         return self.moe_intermediate_size
 
     @property
@@ -511,3 +512,7 @@ def laguna_predict(params: Dict, input_ids: jax.Array,
                                   kernel_interpret=kernel_interpret)
     p = jax.nn.softmax(logits, axis=-1)[:, 1]
     return (p, stats) if with_stats else p
+
+
+TEXT_ENCODER = routed_encoder(LagunaConfig, init_laguna_params, laguna_predict,
+                              LagunaConfig.core_refusal)

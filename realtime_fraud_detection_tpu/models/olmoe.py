@@ -60,6 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from realtime_fraud_detection_tpu.models.text_encoder import routed_encoder
 from realtime_fraud_detection_tpu.obs import scopes
 from realtime_fraud_detection_tpu.ops.attention import (
     attention_reference,
@@ -120,7 +121,7 @@ class OlmoeConfig:
 
     @property
     def num_sparse_layers(self) -> int:
-        """Layers with a routed block (``scoring/pipeline.CausalText``)."""
+        """Layers with a routed block (``models/text_encoder.py``)."""
         return self.num_hidden_layers
 
     def core_refusal(self, seq_len: int) -> Optional[str]:
@@ -284,7 +285,7 @@ class ExpertLoad(NamedTuple):
 
 
 def launch_stats(loads) -> jax.Array:
-    """A routed launch's second output (``scoring/pipeline.CausalText``),
+    """A routed launch's second output (``models/text_encoder.py``),
     ``i32[3, routed layers]`` from each routed layer's ``ExpertLoad``: the
     largest group, the pairs held, the rows visited."""
     return jnp.stack([
@@ -573,3 +574,7 @@ def olmoe_predict(params: Dict, input_ids: jax.Array,
                                  kernel_interpret=kernel_interpret)
     p = jax.nn.softmax(logits, axis=-1)[:, 1]
     return (p, stats) if with_stats else p
+
+
+TEXT_ENCODER = routed_encoder(OlmoeConfig, init_olmoe_params, olmoe_predict,
+                              OlmoeConfig.core_refusal)

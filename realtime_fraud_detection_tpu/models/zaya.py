@@ -86,6 +86,7 @@ from realtime_fraud_detection_tpu.models.olmoe import (
     routed_block,
     token_slots,
 )
+from realtime_fraud_detection_tpu.models.text_encoder import routed_encoder
 from realtime_fraud_detection_tpu.obs import scopes
 from realtime_fraud_detection_tpu.ops.attention import (
     attention_reference,
@@ -137,12 +138,12 @@ class ZayaConfig:
     @property
     def intermediate_size(self) -> int:
         """One expert's width, under the name the routed-encoder seam
-        reads (``scoring/pipeline.routed_text``)."""
+        reads (``models/text_encoder.py``)."""
         return self.moe_intermediate_size
 
     @property
     def num_sparse_layers(self) -> int:
-        """Layers with a routed block (``scoring/pipeline.CausalText``)."""
+        """Layers with a routed block (``models/text_encoder.py``)."""
         return self.num_hidden_layers
 
     @property
@@ -447,3 +448,7 @@ def zaya_predict(params: Dict, input_ids: jax.Array,
                                 kernel_interpret=kernel_interpret)
     p = jax.nn.softmax(logits, axis=-1)[:, 1]
     return (p, stats) if with_stats else p
+
+
+TEXT_ENCODER = routed_encoder(ZayaConfig, init_zaya_params, zaya_predict,
+                              ZayaConfig.mix_refusal)

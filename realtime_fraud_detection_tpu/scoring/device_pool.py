@@ -123,7 +123,7 @@ class DevicePool:
                  inflight_depth: int = 2, donate: Optional[bool] = None):
         import jax
 
-        scorer.require_dense_text(type(self).__name__)
+        scorer.require_plane("pool", type(self).__name__)
         self.scorer = scorer
         devs = list(devices) if devices is not None else list(jax.devices())
         if not devs:

@@ -234,7 +234,7 @@ class MeshExecutor:
                  mesh=None):
         import jax
 
-        scorer.require_dense_text(type(self).__name__)
+        scorer.require_plane("pool", type(self).__name__)
         from realtime_fraud_detection_tpu.core.mesh import (
             DATA_AXIS,
             MODEL_AXIS,

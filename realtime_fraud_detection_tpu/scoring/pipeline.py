@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Any, Callable, Dict, Optional, Tuple, Union
+from typing import Any, Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -33,46 +33,28 @@ from realtime_fraud_detection_tpu.ensemble.combine import (
 from realtime_fraud_detection_tpu.features.extract import extract_features
 from realtime_fraud_detection_tpu.features.rules import rule_score
 from realtime_fraud_detection_tpu.features.schema import TransactionBatch
-from realtime_fraud_detection_tpu.models.bert import (
-    BertConfig,
-    TINY_CONFIG,
-    bert_predict,
-    init_bert_params,
+from realtime_fraud_detection_tpu.models import (
+    bert,
+    falcon_h1,
+    joyai,
+    laguna,
+    olmoe,
+    zaya,
 )
-from realtime_fraud_detection_tpu.models.falcon_h1 import (
-    FalconH1Config,
-    falcon_h1_predict,
-    init_falcon_h1_params,
-)
+from realtime_fraud_detection_tpu.models.bert import TINY_CONFIG, bert_predict
 from realtime_fraud_detection_tpu.models.gnn import gnn_logits, init_gnn_params
 from realtime_fraud_detection_tpu.models.isolation_forest import (
     IsolationForest,
     iforest_predict,
 )
-from realtime_fraud_detection_tpu.models.joyai import (
-    JoyaiConfig,
-    init_joyai_params,
-    joyai_predict,
-)
-from realtime_fraud_detection_tpu.models.laguna import (
-    LagunaConfig,
-    init_laguna_params,
-    laguna_predict,
-)
 from realtime_fraud_detection_tpu.models.lstm import init_lstm_params, lstm_logits
-from realtime_fraud_detection_tpu.models.olmoe import (
-    OlmoeConfig,
-    init_olmoe_params,
-    olmoe_predict,
+from realtime_fraud_detection_tpu.models.text_encoder import (
+    DEQUANT,
+    TextEncoder,
 )
 from realtime_fraud_detection_tpu.models.trees import (
     TreeEnsemble,
     tree_ensemble_predict,
-)
-from realtime_fraud_detection_tpu.models.zaya import (
-    ZayaConfig,
-    init_zaya_params,
-    zaya_predict,
 )
 from realtime_fraud_detection_tpu.obs import scopes
 from realtime_fraud_detection_tpu.obs.profiling import compile_ledger
@@ -96,108 +78,42 @@ MODEL_NAMES: tuple[str, ...] = (
 )
 NUM_MODELS = len(MODEL_NAMES)
 
-# The text branch's configuration picks its encoder by its CLASS: a
-# ``BertConfig`` the dense bidirectional DistilBERT-style one
-# (models/bert.py); every other class a CAUSAL one, a row of ``_CAUSAL_TEXT``
-# — an ``OlmoeConfig``, a ``ZayaConfig``, a ``LagunaConfig`` or a
-# ``JoyaiConfig`` a routed sparse-expert one (models/olmoe.py,
-# models/zaya.py, models/laguna.py, models/joyai.py), a ``FalconH1Config``
-# a dense one whose layers run a state-space mixer beside attention
-# (models/falcon_h1.py). The
-# argument, the static jit argument and the ``ScoringModels`` field keep the
-# name ``bert``: checkpoints, ``MODEL_NAMES`` and the benchmark's references
+def _bert_text_predict(params, input_ids, attention_mask, config, *,
+                       use_pallas, kernel_interpret, capacity,
+                       dequant_kernel):
+    # ``bert_predict`` read from this module when the program is traced,
+    # not captured when the row was made: the benchmark's rehearsal
+    # replaces the attribute to make a wrong program its harness must catch
+    return bert_predict(params, input_ids, attention_mask, config,
+                        use_pallas=use_pallas, dequant_kernel=dequant_kernel,
+                        kernel_interpret=kernel_interpret), None
+
+
+# The text branch's configuration picks its encoder by its CLASS: one row
+# each (models/text_encoder.py says what a row holds). The argument, the
+# static jit argument and the ``ScoringModels`` field keep the name
+# ``bert``: checkpoints, ``MODEL_NAMES`` and the benchmark's references
 # read them.
-TextConfig = Union[BertConfig, OlmoeConfig, ZayaConfig, LagunaConfig,
-                   JoyaiConfig, FalconH1Config]
+TEXT_ENCODERS: Dict[type, TextEncoder] = {
+    row.config_class: row for row in (
+        dataclasses.replace(bert.TEXT_ENCODER, predict=_bert_text_predict),
+        olmoe.TEXT_ENCODER, zaya.TEXT_ENCODER, laguna.TEXT_ENCODER,
+        joyai.TEXT_ENCODER, falcon_h1.TEXT_ENCODER)}
+TextConfig = Union[tuple(TEXT_ENCODERS)]
 
 
-@dataclasses.dataclass(frozen=True)
-class CausalText:
-    """A causal text encoder as the scorer sees one — the ONE description
-    every site asks (``causal_text``; ``routed_text`` where the question
-    is about routed blocks). Every such encoder reads its answer at a
-    row's last real token, so the scorer counts the (query, key) pairs its
-    real queries see from the rows' lengths (under a window where the
-    class spells ``sliding_window``), launches a batch as ONE program at
-    ``text_len`` (the narrow width of ``scoring/text_split.py`` is the
-    bidirectional encoder's kernel's), runs on one device, and has no int8
-    or dequant plane and no pool. Its configuration class spells, under the
-    Hugging Face names, ``num_hidden_layers`` and ``hidden_size``.
-    ``attention_refusal(config, seq_len)`` names why a launch of
-    ``seq_len`` positions holds no Pallas kernel at its attention site
-    even where asked (None where it holds one: OLMoE's, Laguna's, JoyAI's
-    and Falcon-H1's fused causal core, ZAYA1's fused mixing).
-
-    ``routed`` (the four sparse-expert encoders): ``predict`` takes
-    ``capacity`` (the token slots its routed blocks are compiled for,
-    ``scoring/text_split.py``) and, with ``with_stats``, also returns its
-    launch's statistics ``i32[3, layers]`` (``models/olmoe.launch_stats``),
-    of each routed layer: the largest expert group; the (token, expert)
-    pairs that entered a held expert's group (all the routers chose,
-    unless a layer holds a share of the experts its router chooses among);
-    and the rows the fused gate / up kernel's grid visited for them (0 in
-    the XLA form). The scorer reads them at finalize into
-    ``expert_peak_rows``, ``expert_rows`` and ``expert_tile_rows``. The
-    class also
-    spells ``num_experts`` (the experts a layer HOLDS: the groups of the
-    grouped matmul), ``num_experts_per_tok``, ``intermediate_size`` (ONE
-    expert's width) — what the counters and the grouped matmul's shape
-    predicate read — and ``num_sparse_layers`` (the layers with a routed
-    block). The scorer counts each launch at the kernel site
-    ``expert_gate_up``.
-
-    Not ``routed`` (Falcon-H1's): no router, so no capacity (every slot is
-    computed), no second output, and the counters ``expert_*``,
-    ``routed_pairs`` and ``compact_batches`` stay 0. ``scan_refusal(config,
-    seq_len)``, where the encoder has a state-space mixer, is the same
-    question as ``attention_refusal`` of the mixer's scan
-    (``ops/ssd_scan.py``): the scorer then counts each launch at the kernel
-    site ``ssm_scan`` and its chunks in ``ssm_chunks``, from the class's
-    ``mamba_chunk_size``."""
-
-    init: Callable[..., Dict[str, Any]]
-    predict: Callable[..., Any]
-    attention_refusal: Callable[[Any, int], Optional[str]]
-    routed: bool = True
-    scan_refusal: Optional[Callable[[Any, int], Optional[str]]] = None
-
-
-_CAUSAL_TEXT = {
-    OlmoeConfig: CausalText(init_olmoe_params, olmoe_predict,
-                            OlmoeConfig.core_refusal),
-    ZayaConfig: CausalText(init_zaya_params, zaya_predict,
-                           ZayaConfig.mix_refusal),
-    LagunaConfig: CausalText(init_laguna_params, laguna_predict,
-                             LagunaConfig.core_refusal),
-    JoyaiConfig: CausalText(init_joyai_params, joyai_predict,
-                            JoyaiConfig.core_refusal),
-    FalconH1Config: CausalText(init_falcon_h1_params, falcon_h1_predict,
-                               FalconH1Config.core_refusal, routed=False,
-                               scan_refusal=FalconH1Config.scan_refusal),
-}
-
-
-def causal_text(config: TextConfig) -> Optional[CausalText]:
-    """The causal encoder ``config``'s class names, or None (the dense
-    bidirectional encoder)."""
-    return _CAUSAL_TEXT.get(type(config))
-
-
-def routed_text(config: TextConfig) -> Optional[CausalText]:
-    """The same where that encoder has routed blocks, else None."""
-    causal = causal_text(config)
-    return causal if causal is not None and causal.routed else None
+def text_encoder(config: TextConfig) -> TextEncoder:
+    """The row of the encoder ``config``'s class names."""
+    return TEXT_ENCODERS[type(config)]
 
 
 def text_layers(config: TextConfig) -> int:
     """The text encoder's depth, however its source spells it."""
-    return (config.num_layers if causal_text(config) is None
-            else config.num_hidden_layers)
+    return text_encoder(config).depth(config)
 
 
 def init_text_params(key: jax.Array, config: TextConfig) -> Dict[str, Any]:
-    causal = causal_text(config)
-    return (init_bert_params if causal is None else causal.init)(key, config)
+    return text_encoder(config).init(key, config)
 
 
 def text_predict(params: Dict[str, Any], input_ids: jax.Array,
@@ -208,31 +124,22 @@ def text_predict(params: Dict[str, Any], input_ids: jax.Array,
                  ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """The text branch's probability ``f32[B]`` from the encoder
     ``config``'s class names, and that encoder's per-launch statistics
-    (``CausalText``: ``i32[3, layers]`` for a routed encoder),
-    ``None`` for an encoder without routed blocks (whose program then has
-    the one result). ``capacity`` is a routed encoder's: the token slots
-    its routed blocks are compiled for."""
-    causal = causal_text(config)
-    if causal is not None and dequant_kernel != "off":
+    (``i32[3, sparse layers]`` of an encoder with routed blocks), ``None``
+    of one without (whose program then has the one result). ``capacity``
+    is the token slots routed blocks are compiled for."""
+    encoder = text_encoder(config)
+    if dequant_kernel != "off" and DEQUANT not in encoder.planes:
         raise ValueError(
             "KernelSettings.dequant_matmul is DistilBERT's int8 plane; "
             f"a {type(config).__name__} encoder has no quantized form")
-    if causal is not None and causal.routed:
-        return causal.predict(params, input_ids, attention_mask, config,
-                              capacity=capacity, use_pallas=use_pallas,
-                              kernel_interpret=kernel_interpret,
-                              with_stats=True)
-    if capacity is not None:
+    if capacity is not None and encoder.capacities(input_ids.size) is None:
         raise ValueError(
             "text_capacity is a routed encoder's block's; a "
             f"{type(config).__name__} encoder has nothing to compact")
-    if causal is not None:
-        return causal.predict(params, input_ids, attention_mask, config,
-                              use_pallas=use_pallas,
-                              kernel_interpret=kernel_interpret), None
-    return bert_predict(params, input_ids, attention_mask, config,
-                        use_pallas=use_pallas, dequant_kernel=dequant_kernel,
-                        kernel_interpret=kernel_interpret), None
+    return encoder.predict(params, input_ids, attention_mask, config,
+                           use_pallas=use_pallas,
+                           kernel_interpret=kernel_interpret,
+                           capacity=capacity, dequant_kernel=dequant_kernel)
 
 
 @struct.dataclass
@@ -463,7 +370,7 @@ def _score_fused_packed_impl(
     ``OUT_COLUMNS`` + model_predictions — and, with a routed text encoder
     only, a second small output beside it, ``(matrix, i32[3, layers])``:
     each routed layer's largest expert group, held pairs and visited rows
-    (``CausalText``); ``text_capacity`` is that
+    (``models/text_encoder.py``); ``text_capacity`` is that
     encoder's too (how many token slots its routed blocks run on: ``models/olmoe.py``;
     absent from a dense launch). XLA fuses the unpack slices into
     the branch consumers, so the repack costs nothing on-device. What the

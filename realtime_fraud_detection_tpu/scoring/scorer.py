@@ -40,6 +40,15 @@ from realtime_fraud_detection_tpu.features.rules import (
 from realtime_fraud_detection_tpu.features.schema import encode_transactions
 from realtime_fraud_detection_tpu.models.bert import TINY_CONFIG
 from realtime_fraud_detection_tpu.models.text import combined_text
+from realtime_fraud_detection_tpu.models.text_encoder import (
+    DEQUANT,
+    INT8,
+    LAUNCH_COUNTERS,
+    MESH,
+    POOL,
+    TEXT_SPLIT,
+    launch_counters,
+)
 from realtime_fraud_detection_tpu.models.tokenizer import FraudTokenizer
 from realtime_fraud_detection_tpu.obs import scopes
 from realtime_fraud_detection_tpu.core.packing import pack_tree
@@ -53,10 +62,9 @@ from realtime_fraud_detection_tpu.scoring.pipeline import (
     ScoringModels,
     TextConfig,
     init_scoring_models,
-    causal_text,
-    routed_text,
     score_fused,
     score_fused_packed,
+    text_encoder,
 )
 from realtime_fraud_detection_tpu.state.history import (
     EntityGraphStore,
@@ -68,9 +76,6 @@ from realtime_fraud_detection_tpu.state.stores import (
     VelocityStore,
 )
 from realtime_fraud_detection_tpu.utils.config import (
-    EXPERT_COMBINE_SITE,
-    EXPERT_GATE_UP_SITE,
-    SSM_SCAN_SITE,
     VALID_KERNEL_SITES,
     Config,
     KernelSettings,
@@ -113,55 +118,13 @@ class PendingScore:
     # the owner (stream job / serving app) finishes it after fan-out.
     # None = tracing off (the default no-op fast path).
     trace: Optional[Any] = None
-    # What the text branch was launched with, counted at dispatch (exact
-    # integers, no clock), summed over the launches made for the batch:
-    # bucket rows x launched width, bucket rows x width^2 (what
-    # attention's cost follows), and the real tokens (token_mask.sum()
-    # over the real rows). StreamJob sums them into its counters beside
-    # ``batches``.
-    token_slots: int = 0
-    token_slots_sq: int = 0
-    real_tokens: int = 0
-    # A routed text encoder only (pipeline.routed_text; 0 / None otherwise):
-    # ``routed_pairs`` = the (token, expert) pairs the routers chose (the
-    # launch's real tokens x experts per token x sparse layers, counted at
-    # dispatch: padding is not routed). ``text_stats`` = the program's
-    # second output, i32[3, sparse layers] (pipeline.CausalText), read at
-    # finalize into three counters: ``expert_rows`` = the pairs that
-    # entered a held expert's group of the grouped matmuls (all of
-    # ``routed_pairs`` where a layer holds every expert);
-    # ``expert_peak_rows`` = sum over layers of largest group x
-    # num_experts, what the launch would cost if every group were as large
-    # as the largest (their ratio is 1.0 under even routing);
-    # ``expert_tile_rows`` = the rows the fused gate / up kernel's grid
-    # visited for them, visits x row tile (0 where the launch ran the XLA
-    # form: ``expert_rows`` over it is how full the visited tiles were).
-    # ``attn_visible_pairs_full`` / ``_sliding`` = the
-    # (query, key) pairs a real query sees in ONE causal layer of each kind,
-    # summed over the launched rows from their lengths L: L(L+1)/2, and
-    # sum_i min(i+1, window) where the encoder has a ``sliding_window``.
-    # ``expert_token_slots`` = the capacity the routed blocks
-    # ran at (scoring/text_split.py; at most ``token_slots``), and
-    # ``compact_batches`` is 1 where that was a narrow rung.
-    routed_pairs: int = 0
-    expert_rows: int = 0
-    expert_peak_rows: int = 0
-    expert_tile_rows: int = 0
-    attn_visible_pairs_full: int = 0
-    attn_visible_pairs_sliding: int = 0
+    # The counters of the batch's launches (models/text_encoder.py says what
+    # each counts), by the names ``StreamJob.counters`` sums them under:
+    # every name of ``LAUNCH_COUNTERS`` from dispatch, the encoder's
+    # finalize-time ones over them once ``text_stats`` — the program's second
+    # output, where the encoder returns statistics — has been read.
+    counters: Dict[str, int] = dataclasses.field(default_factory=dict)
     text_stats: Optional[Any] = None
-    expert_token_slots: int = 0
-    compact_batches: int = 0
-    # An encoder with a state-space mixer only (pipeline.CausalText.
-    # scan_refusal; 0 otherwise): the chunks its scans walked, launched
-    # rows x text_len / mamba_chunk_size x layers, counted at dispatch.
-    ssm_chunks: int = 0
-    # How the rows were launched (scoring/text_split.py): real rows in a
-    # program narrower than ``text_len``, real rows at ``text_len`` (their
-    # sum is ``n``), and 1 where the batch took two launches.
-    short_text_rows: int = 0
-    long_text_rows: int = 0
-    split_batches: int = 0
 
 
 @dataclasses.dataclass
@@ -174,8 +137,8 @@ class _Launch:
     width: int                      # text positions
     blobs: Any = None               # core.packing.pack_tree's output
     spec: Any = None
-    # the MoE text encoder only: the token slots its routed blocks are
-    # compiled for (text_split.capacity), and the real tokens it holds
+    # where the encoder has routed blocks: the token slots they are compiled
+    # for (text_split.capacity), and the real tokens the launch holds
     capacity: Optional[int] = None
     tokens: int = 0
 
@@ -429,17 +392,25 @@ class FraudScorer:
     ):
         self.config = config or Config()
         self.sc = scorer_config or ScorerConfig()
-        # the text branch's configuration picks its encoder by its class
-        # (scoring/pipeline.text_predict); what this scorer asks of a causal
-        # one, and of one with routed blocks, is pipeline.CausalText's
-        # contract
+        # the text branch's configuration picks its encoder by its class;
+        # everything this scorer asks of it is its row's to answer
+        # (models/text_encoder.py)
         self.bert_config = bert_config
-        self._causal_text = causal_text(bert_config)
-        self._moe_text = routed_text(bert_config) is not None
-        self._ssm_text = (self._causal_text is not None
-                          and self._causal_text.scan_refusal is not None)
+        self._text = text_encoder(bert_config)
         self.mesh = mesh if mesh is not None else build_mesh()
-        self._refuse_bert_only_planes()
+        # device-pool scoring plane (scoring/device_pool.py): when attached,
+        # dispatch_assembled routes whole microbatches round-robin across
+        # per-device model replicas instead of sharding one batch over the
+        # mesh — see DevicePool for the ordering/equality contract
+        self._pool = None
+        self.kernels = getattr(self.config, "kernels", None) or KernelSettings()
+        for plane, asked in (
+                (INT8, self.config.quant.bert_mode() == "int8"),
+                (DEQUANT, self.kernels.enabled
+                 and self.kernels.dequant_matmul == "pallas"),
+                (MESH, self.mesh.devices.size > 1)):
+            if asked:
+                self.require_plane(plane)
         # feature extraction needs JAX's CPU backend next to the accelerator:
         # a process without one fails HERE, with a message, not as all-ERROR
         # results inside the stream job's degradation path
@@ -472,7 +443,6 @@ class FraudScorer:
         # rather than silently interpreted. Dispatch/fallback counters are
         # kept host-side using the SAME supports() predicates the traced
         # code consults (obs.metrics.sync_kernels mirrors them).
-        self.kernels = getattr(self.config, "kernels", None) or KernelSettings()
         platform = self.mesh.devices.flat[0].platform
         if self.kernels.enabled and platform not in ("tpu", "cpu"):
             raise ValueError(
@@ -480,14 +450,10 @@ class FraudScorer:
                 f"(interpreted) mesh; this scorer's devices are {platform!r}")
         self._platform = platform
         self._kernel_interpret = platform == "cpu"
-        # (a routed encoder's launches are also counted at its experts'
-        # gate + up + SiLU site and at their way home, those of an encoder
-        # with a state-space mixer at its scan's: sites the dense encoder
-        # does not have)
-        sites = VALID_KERNEL_SITES + (
-            (EXPERT_GATE_UP_SITE, EXPERT_COMBINE_SITE)
-            if self._moe_text else ()) + (
-            (SSM_SCAN_SITE,) if self._ssm_text else ())
+        # (the three sites the plane has a mode for, then the encoder's own)
+        sites = VALID_KERNEL_SITES + tuple(
+            site.name for site in self._text.sites
+            if site.name not in VALID_KERNEL_SITES)
         self._kernel_counts: Dict[str, Dict[str, int]] = {
             "dispatch": {s: 0 for s in sites},
             "fallback": {s: 0 for s in sites},
@@ -651,19 +617,13 @@ class FraudScorer:
 
         self._join_cache = EntityRowCache()
         self._staging = _StagingBuffers()
-        # text widths (scoring/text_split.py): the programs compiled for
-        # each row bucket that has left the unsplit launch, and how the
-        # rows have been launched since construction
+        # text shapes (scoring/text_split.py): the programs compiled for
+        # each row bucket that has left the plain launch, and the launch
+        # counters summed since construction
         self._text_families: Dict[int, tuple] = {}
-        self._text_split_counts: Dict[str, int] = {
-            "short_text_rows": 0, "long_text_rows": 0, "split_batches": 0,
-            "expert_token_slots": 0, "compact_batches": 0}
+        self._launch_totals: Dict[str, int] = dict.fromkeys(
+            LAUNCH_COUNTERS, 0)
         self.spans = SpanTimer()
-        # device-pool scoring plane (scoring/device_pool.py): when attached,
-        # dispatch_assembled routes whole microbatches round-robin across
-        # per-device model replicas instead of sharding one batch over the
-        # mesh — see DevicePool for the ordering/equality contract
-        self._pool = None
         self.last_features = np.zeros((0, self.sc.feature_dim), np.float32)
         self.stats: Dict[str, float] = {"scored": 0, "batches": 0, "total_time_s": 0.0}
         # top-10 global feature importances (reference explanation field,
@@ -687,53 +647,52 @@ class FraudScorer:
             self._mv_cache = (mv.copy(), jax.device_put(mv))
         return self._mv_cache[1]
 
-    def _refuse_bert_only_planes(self) -> None:
-        """The planes written for the DistilBERT branch's parameter layout
-        refuse a causal encoder's configuration (``pipeline.CausalText``)
-        by name instead of miscomputing."""
-        if self._causal_text is None:
-            return
-        quant = self.config.quant
-        kernels = getattr(self.config, "kernels", None) or KernelSettings()
-        refused = None
-        if quant.bert_mode() == "int8":
-            refused = ("QuantSettings(bert_weights='int8') quantizes "
-                       "DistilBERT's dense layers (models/quant.py)")
-        elif kernels.enabled and kernels.dequant_matmul == "pallas":
-            refused = ("KernelSettings.dequant_matmul is the int8 "
-                       "DistilBERT branch's kernel (ops/dequant_matmul.py)")
-        elif self.mesh.devices.size > 1:
-            refused = (f"a sharded mesh of {self.mesh.devices.size} devices "
-                       "would split the batch under "
-                       + ("the grouped expert matmul; a routed"
-                          if self._moe_text else
-                          "the fused causal core and the scan; a causal")
-                       + " encoder runs on one device "
-                       "(build_mesh(devices=jax.devices()[:1]))")
+    def plane_refusal(self, plane: str, asked_by: str = "") -> Optional[str]:
+        """Why this scorer's text branch does not run under ``plane`` (a
+        name of models/text_encoder.py; ``asked_by`` is the pool's class
+        where the plane is one), or None where it does: the ONE check of
+        what the encoder's row admits. The planes were written for the
+        DistilBERT branch's parameter layout and its single result; an
+        encoder whose row does not name one is refused by name instead of
+        miscomputed."""
+        if plane == TEXT_SPLIT and self._pool is not None:
+            return (f"{type(self._pool).__name__}: every replica would "
+                    "compile each bucket's family of programs")
+        if plane in self._text.planes:
+            return None
+        name = type(self.bert_config).__name__
+        if plane == TEXT_SPLIT:
+            return (f"a {name} text branch: the narrow width is the "
+                    "bidirectional encoder's attention kernel's; a causal "
+                    "encoder's batch is one launch at text_len")
+        if plane == POOL:
+            return (f"{asked_by} (DevicePool / MeshExecutor) dispatches the "
+                    "DistilBERT program's single result; not available with "
+                    f"a {name} text branch, which runs on one chip")
+        refused = {
+            INT8: "QuantSettings(bert_weights='int8') quantizes "
+                  "DistilBERT's dense layers (models/quant.py)",
+            DEQUANT: "KernelSettings.dequant_matmul is the int8 DistilBERT "
+                     "branch's kernel (ops/dequant_matmul.py)",
+            MESH: f"a sharded mesh of {self.mesh.devices.size} devices would "
+                  f"split the batch under {self._text.one_device} encoder "
+                  "runs on one device "
+                  "(build_mesh(devices=jax.devices()[:1]))",
+        }[plane]
+        return f"{refused}: not available with a {name} text branch"
+
+    def require_plane(self, plane: str, asked_by: str = "") -> None:
+        refused = self.plane_refusal(plane, asked_by)
         if refused:
-            raise ValueError(
-                f"{refused}: not available with a "
-                f"{type(self.bert_config).__name__} text branch")
+            raise ValueError(refused)
 
     # ------------------------------------------------------------- pooling
     def attach_pool(self, pool) -> None:
         """Adopt a DevicePool: subsequent dispatches route through it.
         Called by DevicePool.__init__ — construct the scorer first, then
         the pool around it."""
-        self.require_dense_text(type(pool).__name__)
+        self.require_plane(POOL, type(pool).__name__)
         self._pool = pool
-
-    def require_dense_text(self, plane: str) -> None:
-        """Raise where ``plane`` (a pool class's name) is asked of a scorer
-        whose text branch is a causal encoder (``pipeline.CausalText``):
-        the pools dispatch the DistilBERT program's single result, over
-        devices a causal encoder's program is not split across."""
-        if self._causal_text is not None:
-            raise ValueError(
-                f"{plane} (DevicePool / MeshExecutor) dispatches the "
-                "DistilBERT program's single result; not available with a "
-                f"{type(self.bert_config).__name__} text branch, which runs "
-                "on one chip")
 
     # --------------------------------------------------------- graph plane
     def attach_graph_fetch(self, client) -> None:
@@ -937,24 +896,18 @@ class FraudScorer:
 
     def effective_use_pallas(self, devices: Optional[int] = None,
                              text_len: Optional[int] = None) -> bool:
-        """Whether the text branch is ASKED to run its Pallas kernel: the
-        fused attention core of the dense encoder (``bert_layer``'s traced
-        guard still sends a shape ``flash_supported`` declines to the
-        reference, and the engagement counters say so), the grouped expert
-        matmul of the MoE encoder (``ops.grouped_matmul``, same pattern)
-        and, with it, ZAYA1's fused mixing (``ops/cca_mix.py``, guarded by
-        ``ZayaConfig.mix_refusal``) or OLMoE's, Laguna's and JoyAI's fused
-        causal core (``ops.attention.windowed_attention``, guarded by each
-        class's ``core_refusal``). With
-        the kernel plane on,
-        ``KernelSettings.attention`` decides — how a drill or an A/B forces
-        either side. With it off, nothing a user sets does: the kernel runs
-        where the devices are TPUs, the shape is one it takes
-        (``ops.attention.flash_supported``) and the program is one
-        device's — XLA cannot partition a Mosaic call, so a program whose
-        batch is sharded over a mesh keeps the reference. ``devices`` is how
-        many the program spans: the scorer's own mesh by default, one for
-        a ``DevicePool`` replica, a ``MeshExecutor`` replica's sub-mesh.
+        """Whether the text branch is ASKED to run its Pallas kernels: the
+        traced guard of each of the encoder's kernel sites
+        (``models/text_encoder.KernelSite``) still sends a shape its
+        predicate declines to the XLA form, and the engagement counters say
+        so. With the kernel plane on, ``KernelSettings.attention`` decides —
+        how a drill or an A/B forces either side. With it off, nothing a
+        user sets does: the kernels run where the devices are TPUs, one of
+        the sites takes the shape and the program is one device's — XLA
+        cannot partition a Mosaic call, so a program whose batch is sharded
+        over a mesh keeps the XLA forms. ``devices`` is how many the
+        program spans: the scorer's own mesh by default, one for a
+        ``DevicePool`` replica, a ``MeshExecutor`` replica's sub-mesh.
         ``text_len`` is the width the program is launched at:
         ``ScorerConfig.text_len`` by default, the narrower one for the
         short rows of a split batch (``scoring/text_split.py``)."""
@@ -964,42 +917,6 @@ class FraudScorer:
             devices = self.mesh.devices.size
         return (self._platform == "tpu" and devices == 1
                 and self._text_kernel_shape_ok(text_len))
-
-    def _attention_shape_refusal(self, text_len: Optional[int] = None
-                                 ) -> Optional[str]:
-        """Why a program launched at ``text_len`` holds no Pallas kernel at
-        its attention site even where asked, or None where it holds one:
-        the fused core for the dense encoder (``flash_supported``), for a
-        routed encoder what its row of ``pipeline.CausalText`` names
-        (``ZayaConfig.mix_refusal``: the predicate of ``ops/cca_mix.py``'s
-        fused mixing; ``OlmoeConfig.core_refusal``,
-        ``LagunaConfig.core_refusal`` and ``JoyaiConfig.core_refusal``: that
-        of the fused causal core, ``ops.attention.windowed_attention``). The
-        same predicates the
-        traced guards consult."""
-        from realtime_fraud_detection_tpu.ops import flash_supported
-
-        t = text_len or self.sc.text_len
-        c = self.bert_config
-        if self._causal_text is not None:
-            return self._causal_text.attention_refusal(c, t)
-        if flash_supported(t, c.head_dim, c.num_heads):
-            return None
-        return (f"flash_attention takes seq_len a multiple of 128 and "
-                f"pairs of 64-wide heads: seq_len {t}, head_dim "
-                f"{c.head_dim}")
-
-    def _attention_shape_ok(self, text_len: Optional[int] = None) -> bool:
-        return self._attention_shape_refusal(text_len) is None
-
-    def attention_refusal(self, devices: Optional[int] = None,
-                          text_len: Optional[int] = None) -> Optional[str]:
-        """Why a launch at ``text_len`` runs the XLA form at its attention
-        site, by name, or None where the program holds the Pallas kernel:
-        the shape the kernel declines, else what kept the selector
-        (``effective_use_pallas``) from asking."""
-        return (self._attention_shape_refusal(text_len)
-                or self._not_asked(devices, text_len))
 
     def _not_asked(self, devices: Optional[int] = None,
                    text_len: Optional[int] = None) -> Optional[str]:
@@ -1018,95 +935,42 @@ class FraudScorer:
         if devices != 1:
             return (f"a program over {devices} devices: XLA cannot "
                     "partition a Mosaic call")
-        return ("the grouped expert matmul declines the launch's rows"
-                if self._moe_text else
-                "neither the fused core nor the scan takes the shape")
-
-    def _scan_refusal(self, text_len: Optional[int] = None) -> Optional[str]:
-        """Why a launch at ``text_len`` runs the XLA form of its state-space
-        scan even where asked (``pipeline.CausalText.scan_refusal``), or
-        None where it holds the kernel. Asked only of an encoder that has a
-        scan."""
-        return self._causal_text.scan_refusal(
-            self.bert_config, text_len or self.sc.text_len)
+        return "none of the encoder's kernel sites takes the shape"
 
     def _text_kernel_shape_ok(self, text_len: Optional[int] = None) -> bool:
-        """Whether the text branch has a Pallas kernel for its shapes: the
-        fused attention core for the dense encoder, the grouped expert
-        matmul for the MoE one (the smallest bucket's rows decide: every
-        larger bucket is a multiple of them, and a narrow capacity of the
-        routed blocks is whole tiles by ``text_split.CAPACITY_MULTIPLE``),
-        the fused causal core or the scan's kernel for an encoder with a
-        state-space mixer (each site's own guard then decides for it)."""
-        if self._ssm_text:
-            return (self._attention_shape_ok(text_len)
-                    or self._scan_refusal(text_len) is None)
-        if not self._moe_text:
-            return self._attention_shape_ok(text_len)
-        from realtime_fraud_detection_tpu.ops import (
-            combine_supported,
-            grouped_matmul_supported,
-        )
-
-        c = self.bert_config
-        tokens = text_len or self.sc.text_len
-        rows = tokens * c.num_experts_per_tok
-        return (grouped_matmul_supported(rows, c.hidden_size,
-                                         c.intermediate_size)
-                and grouped_matmul_supported(rows, c.intermediate_size,
-                                             c.hidden_size)
-                ) or combine_supported(tokens, c.num_experts_per_tok,
-                                       c.hidden_size)
+        """Whether any of the encoder's kernel sites has a Pallas kernel
+        for a launch at ``text_len`` (each site's own guard then decides
+        for it). The smallest bucket's one row decides for routed blocks:
+        every larger bucket is a multiple of it, and a narrow capacity is
+        whole tiles by ``text_split.CAPACITY_MULTIPLE``."""
+        t = text_len or self.sc.text_len
+        return any(site.refusal(self.bert_config, t, t) is None
+                   for site in self._text.sites)
 
     def _record_kernel_dispatch(self, size: int, text_len: int,
                                 capacity: Optional[int] = None) -> None:
         """Host-side mirror of the per-site kernel engagement for one
         launch of ``size`` rows at ``text_len`` positions (a split batch
         records each of its two), its routed blocks at ``capacity`` token
-        slots (None: every slot). A site counts as dispatched when its mode asks
-        for the Pallas kernel, and as a fallback when the shape/layout
-        guard the TRACED code consults (the shared supports() predicates)
-        routes it back to the XLA path — so ``kernel_fallback_total``
-        reports exactly what the compiled program did, without a device
-        readback. The attention site counts EVERY launch, plane on or off:
-        dispatched where the program holds the fused core, a fallback
-        where the selector or the guard sent it to the reference. So does
-        ``expert_gate_up`` for every launch of a routed encoder: dispatched
-        where its sparse layers hold the fused gate + up + SiLU kernel
-        (``ops.grouped_gated_matmul``), a fallback where they run the
-        three-call form; ``expert_combine`` beside it: dispatched where
-        the experts' result rows come home through the one kernel
-        (``ops.weighted_combine``), a fallback where through XLA's gather
-        and sum; and ``ssm_scan`` for every launch of an encoder
-        with a state-space mixer: dispatched where its layers hold the
-        scan's kernel (``ops.ssd_scan``), a fallback where they run the XLA
-        chunked form."""
+        slots (None: every slot). The encoder's own sites
+        (``models/text_encoder.KernelSite``) count EVERY launch, plane on
+        or off: dispatched where the program is asked for its kernels and
+        the shape guard the TRACED code consults (the site's ``refusal``)
+        lets the launch through, a fallback where the selector or the guard
+        sent it to the XLA form. The plane's other two sites count as
+        dispatched when their mode asks for the Pallas kernel, and as a
+        fallback when the guard routes them back — so
+        ``kernel_fallback_total`` reports exactly what the compiled program
+        did, without a device readback."""
         disp, fall = (self._kernel_counts["dispatch"],
                       self._kernel_counts["fallback"])
         asked = self.effective_use_pallas(
             getattr(self._pool, "program_devices", None), text_len)
-        if asked and self._attention_shape_ok(text_len):
-            disp["attention"] += 1
-        else:
-            fall["attention"] += 1
-        if self._moe_text:
-            from realtime_fraud_detection_tpu.ops import (
-                combine_supported,
-                grouped_matmul_supported,
-            )
-
-            c = self.bert_config
-            tokens = capacity or size * text_len
-            rows = tokens * c.num_experts_per_tok
-            fused = asked and grouped_matmul_supported(
-                rows, c.hidden_size, c.intermediate_size)
-            (disp if fused else fall)[EXPERT_GATE_UP_SITE] += 1
-            home = asked and combine_supported(
-                tokens, c.num_experts_per_tok, c.hidden_size)
-            (disp if home else fall)[EXPERT_COMBINE_SITE] += 1
-        if self._ssm_text:
-            held = asked and self._scan_refusal(text_len) is None
-            (disp if held else fall)[SSM_SCAN_SITE] += 1
+        slots = capacity or size * text_len
+        for site in self._text.sites:
+            held = asked and site.refusal(self.bert_config, text_len,
+                                          slots) is None
+            (disp if held else fall)[site.name] += 1
         if not self.kernels.enabled:
             return
         from realtime_fraud_detection_tpu.models.quant import (
@@ -1143,14 +1007,16 @@ class FraudScorer:
         """Kernel-plane observability payload (obs.metrics.sync_kernels):
         effective per-site modes, whether the Pallas interpreter is
         serving (a CPU mesh), cumulative dispatch/fallback counts per
-        site, and why a launch at ``text_len`` keeps the XLA form at its
-        attention site (None where it holds the kernel) — and, of an
-        encoder with a state-space mixer, at its ``ssm_scan`` site."""
+        site, and why a launch at ``text_len`` keeps the XLA form at each of
+        the encoder's sites whose reason follows from the width, by name
+        (None where it holds the kernel): the shape the kernel declines —
+        the predicate the traced guard consults — else what kept the
+        selector (``effective_use_pallas``) from asking."""
         devices = getattr(self._pool, "program_devices", None)
-        refused = {"attention": self.attention_refusal(devices)}
-        if self._ssm_text:
-            refused[SSM_SCAN_SITE] = (self._scan_refusal()
-                                      or self._not_asked(devices))
+        t = self.sc.text_len
+        refused = {site.name: (site.refusal(self.bert_config, t, t)
+                               or self._not_asked(devices))
+                   for site in self._text.sites if site.by_width}
         return {
             "modes": self.kernels.site_modes(),
             "interpret": bool(self.kernels.enabled
@@ -1359,8 +1225,8 @@ class FraudScorer:
         series — and ``text_split``: the narrower width short rows are
         launched at (None where there is none), why it is refused if it
         is, rows launched at either width, batches that took two launches,
-        the programs compiled per bucket (``(rows, width)``; with the MoE
-        encoder ``(rows, width, capacity)``), and that encoder's
+        the programs compiled per bucket (``(rows, width)``; of an encoder
+        with routed blocks ``(rows, width, capacity)``), and
         ``expert_token_slots`` (the capacities launched, summed) and
         ``compact_batches`` (launches at a narrow one) — and ``compile``:
         the process's compile ledger (``obs/profiling.CompileLedger``) as
@@ -1373,10 +1239,12 @@ class FraudScorer:
         cache_stats = getattr(self.tokenizer, "cache_stats", None)
         if cache_stats is not None:
             caches["tokens"] = cache_stats()
-        full = self.sc.text_len
         text_split_stats = dict(
-            self._text_split_counts, width=self._narrow_text_len(full),
-            refused=self.text_split_refusal(),
+            {key: self._launch_totals[key] for key in (
+                "short_text_rows", "long_text_rows", "split_batches",
+                "expert_token_slots", "compact_batches")},
+            width=self._narrow_text_len(self.sc.text_len),
+            refused=self.plane_refusal(TEXT_SPLIT),
             families={size: list(programs) for size, programs
                       in self._text_families.items()})
         return {"stages": self.spans.stats(), "caches": caches,
@@ -1423,7 +1291,8 @@ class FraudScorer:
             # rtfd-lint: allow[wall-clock] dispatch_ms / processing_time_ms of the §2.7 response, not scoring control flow
             t0 = time.perf_counter()
         n = len(records)
-        real_tokens = int(np.count_nonzero(batch.token_mask))
+        # (a sum over the rows: a third of count_nonzero(axis=1)'s time)
+        lengths = np.add.reduce(batch.token_mask, axis=1, dtype=np.int32)
         with self.spans.span(scopes.PACK, trace=trace):
             # an attached mesh executor (scoring/mesh_executor.py) shards
             # the batch over ITS data axis, which may differ from this
@@ -1437,20 +1306,8 @@ class FraudScorer:
 
             size = bucket_of(n)
             full = int(batch.token_ids.shape[1])
-            # one launch at ``text_len``, or the rows whose text fits a
-            # narrower program apart from the long ones (text_split.py)
-            if self._moe_text:
-                # one launch; its routed blocks at the capacity that holds
-                # the batch's real tokens (text_split.capacity)
-                launches = [self._routed_launch(batch, n, size, full,
-                                                real_tokens)]
-            else:
-                launches = self._text_launches(batch, n, size, full,
-                                               bucket_of)
-            visible_full = visible_sliding = 0
-            if self._causal_text is not None:
-                visible_full, visible_sliding = self._visible_pairs(
-                    np.count_nonzero(batch.token_mask, axis=1))
+            launches = self._plan_launches(batch, lengths, size, full,
+                                           bucket_of)
             for launch in launches:
                 self._pack_launch(batch, launch)
 
@@ -1469,7 +1326,7 @@ class FraudScorer:
                 # pooled mode: the whole microbatch runs on ONE replica
                 # (model replication, not batch sharding) picked
                 # round-robin by the pool; in-flight depth and retry live
-                # there. A pool keeps the one launch (text_split_refusal).
+                # there. A pool keeps the one launch (plane_refusal).
                 token = self._pool.dispatch_packed(
                     launches[0].blobs, launches[0].spec,
                     self.ensemble_params, mv)
@@ -1493,29 +1350,18 @@ class FraudScorer:
             # overlaps the next batch's assemble instead of serializing
             # after it.
             text_stats = None
-            if self._moe_text:
-                # the MoE program's second, small output (pipeline.py)
+            if isinstance(out, tuple):
+                # the program's second, small output, where the encoder
+                # returns statistics (pipeline.py)
                 out, text_stats = out
             if self.sc.async_d2h:
                 out.copy_to_host_async()
                 if text_stats is not None:
                     text_stats.copy_to_host_async()
-        token_slots = sum(la.size * la.width for la in launches)
-        short_rows = n - sum(la.n for la in launches if la.width == full)
-        split = int(len(launches) > 1)
-        expert_slots = launches[0].capacity or 0
-        compact = int(0 < expert_slots < token_slots)
-        counts = self._text_split_counts
-        counts["short_text_rows"] += short_rows
-        counts["long_text_rows"] += n - short_rows
-        counts["split_batches"] += split
-        counts["expert_token_slots"] += expert_slots
-        counts["compact_batches"] += compact
-        routed_pairs = self._routed_pairs(launches[0].tokens)
-        ssm_chunks = 0
-        if self._ssm_text:
-            ssm_chunks = (token_slots // self.bert_config.mamba_chunk_size
-                          * self.bert_config.num_hidden_layers)
+        counters = launch_counters(self._text, self.bert_config, launches,
+                                   lengths, full)
+        for key, value in counters.items():
+            self._launch_totals[key] += value
         return PendingScore(records=list(records), n=n, out=out,
                             # rtfd-lint: allow[d2h] batch.features is a host-assembled ndarray
                             features=np.asarray(batch.features),
@@ -1523,155 +1369,95 @@ class FraudScorer:
                             dispatch_ms=(time.perf_counter() - t0) * 1000.0,
                             model_valid=mv, rules_only=rules_only,
                             pool_token=token, trace=trace,
-                            token_slots=token_slots,
-                            token_slots_sq=sum(la.size * la.width * la.width
-                                               for la in launches),
-                            real_tokens=real_tokens,
-                            routed_pairs=routed_pairs,
-                            attn_visible_pairs_full=visible_full,
-                            attn_visible_pairs_sliding=visible_sliding,
-                            text_stats=text_stats,
-                            expert_token_slots=expert_slots,
-                            compact_batches=compact,
-                            ssm_chunks=ssm_chunks,
-                            short_text_rows=short_rows,
-                            long_text_rows=n - short_rows,
-                            split_batches=split)
+                            counters=counters, text_stats=text_stats)
 
-    # ------------------------------------------------ text widths of a batch
-    def text_split_refusal(self) -> Optional[str]:
-        """Why this scorer keeps every batch in the one launch at
-        ``text_len`` though its text kernel takes a narrower width, or
-        None. A plane that cannot take a second width says so by name, as
-        does a causal encoder that is no routed one (a routed one has its
-        own launch rule, the capacity of its routed blocks)."""
-        if self._pool is not None:
-            return (f"{type(self._pool).__name__}: every replica would "
-                    "compile each bucket's family of programs")
-        if self._causal_text is not None and not self._moe_text:
-            return (f"a {type(self.bert_config).__name__} text branch: the "
-                    "narrow width is the bidirectional encoder's attention "
-                    "kernel's; a causal encoder's batch is one launch at "
-                    "text_len")
-        return None
-
+    # ------------------------------------------------ text shapes of a batch
     def _narrow_text_len(self, full: int) -> Optional[int]:
-        """The width short rows are launched at: the narrowest the dense
-        encoder's attention kernel admits, where that is under the
-        ``full`` width the batch was tokenised to; else None."""
-        from realtime_fraud_detection_tpu.ops import narrowest_supported_len
-
-        if self._moe_text or self.text_split_refusal() is not None:
+        """The width short rows are launched at: the encoder's narrow width
+        (``TextEncoder.narrow_width``) where the scorer takes the text split
+        and that width is under the ``full`` one the batch was tokenised
+        to; else None."""
+        if self.plane_refusal(TEXT_SPLIT) is not None:
             return None
-        narrow = narrowest_supported_len(self.bert_config.head_dim,
-                                         self.bert_config.num_heads)
+        narrow = self._text.narrow_width(self.bert_config)
         return narrow if narrow is not None and narrow < full else None
 
-    def _text_launches(self, batch: ScoreBatch, n: int, size: int,
-                       full: int, bucket_of) -> List["_Launch"]:
-        """The launches of an assembled batch (``text_split.plan``): rows
-        with no real token past the narrow width are short. The first time
-        a bucket leaves the unsplit launch, every program of its family is
-        compiled and run here, under span ``build_programs`` (``rows=``,
-        ``programs=``), so that none first appears under load (each costs
-        0.6-1.1 s with a warm compile cache, 4-10 s cold, nearly all of it
-        the interpreter's: a thread made it slower on the v5e; the compile
-        ledger has each phase, ``host_stats()["compile"]``)."""
+    def _plan_launches(self, batch: ScoreBatch, lengths: np.ndarray,
+                       size: int, full: int, bucket_of) -> List["_Launch"]:
+        """The launches of an assembled batch (``lengths``: its rows' real
+        tokens), by the encoder's launch rule (``models/text_encoder.py``):
+        one at ``full`` width, or the rows with no real token past the
+        narrow width apart from the long ones (``text_split.plan``); the
+        routed blocks of each, where the encoder has any, at the narrowest
+        capacity that holds the launch's real tokens
+        (``text_split.capacity``: a shape of the program, every real token
+        is routed at any rung). The first time a bucket takes a shape
+        either rule chose, its family is built (``_build_family``)."""
+        n = len(lengths)
         narrow = self._narrow_text_len(full)
-        if narrow is None:
-            return [_Launch(None, n, size, full)]
-        is_long = np.asarray(batch.token_mask)[:, narrow:].any(axis=1)
-        n_long = int(np.count_nonzero(is_long))
-        launches = []
-        for which, rows, width in text_split.plan(
-                n - n_long, n_long, size, narrow, full, bucket_of):
-            if which == text_split.LONG:
-                launches.append(
-                    _Launch(np.flatnonzero(is_long), n_long, rows, width))
-            else:
-                # a narrow launch holds the long rows too, cut short, in
-                # the places its bucket would pad anyway: no row is
-                # gathered, and the long launch's answers replace theirs
-                launches.append(_Launch(None, n, rows, width))
-        if launches[0].width != full and size not in self._text_families:
-            # every member here, from this one place, whichever of them the
-            # batch needs itself: a program's entry in the persistent
-            # compile cache follows the call stack it was traced under
-            # (utils/compile_cache.py), and which members a bucket's first
-            # batch launches differs from run to run
-            programs = text_split.family(size, narrow, full, bucket_of)
-            mv = self.effective_model_valid()
-            with self.spans.span(scopes.BUILD_PROGRAMS, rows=size,
-                                 programs=len(programs)):
-                for rows, width in programs:
-                    # the batch's first rows stand in; results are dropped
-                    k = min(n, rows)
-                    warm = _Launch(np.arange(k), k, rows, width)
-                    self._pack_launch(batch, warm)
-                    jax.block_until_ready(self._launch_packed(warm, mv))
-            self._text_families[size] = programs
+        launches = [_Launch(None, n, size, full)]
+        if narrow is not None:
+            is_long = np.asarray(batch.token_mask)[:, narrow:].any(axis=1)
+            n_long = int(np.count_nonzero(is_long))
+            # a narrow launch holds the long rows too, cut short, in the
+            # places its bucket would pad anyway: no row is gathered, and
+            # the long launch's answers replace theirs
+            launches = [
+                _Launch(np.flatnonzero(is_long), n_long, rows, width)
+                if which == text_split.LONG else _Launch(None, n, rows, width)
+                for which, rows, width in text_split.plan(
+                    n - n_long, n_long, size, narrow, full, bucket_of)]
+        for launch in launches:
+            slots = launch.size * launch.width
+            if self._text.capacities(slots) is not None:
+                # (the tokenizer pads on the right: a cut keeps a prefix)
+                held = lengths if launch.rows is None else lengths[launch.rows]
+                launch.tokens = int(np.minimum(held, launch.width).sum())
+                launch.capacity = text_split.capacity(launch.tokens, slots)
+        first = launches[0]
+        if size not in self._text_families and (
+                first.width != full or first.capacity is not None):
+            self._build_family(batch, n, size, narrow, full, bucket_of)
         return launches
 
-    def _routed_launch(self, batch: ScoreBatch, n: int, size: int,
-                       width: int, real_tokens: int) -> "_Launch":
-        """The one launch of a batch under the MoE text encoder, its routed
-        blocks compiled for the narrowest capacity that holds the batch's
-        ``real_tokens`` (``text_split.capacity``: a shape of the program,
-        every real token is routed at any rung). The first time a bucket is
-        launched, the program of each of its rungs is compiled and run
-        here, as a split bucket's family is and under the same span
-        (``build_programs``), so that none first appears under load."""
-        slots = size * width
-        if size not in self._text_families:
-            mv = self.effective_model_valid()
-            # the batch's rows with no token stand in (nothing to hold, so
-            # every rung takes them): the results are dropped
-            empty = batch.replace(
-                token_mask=np.zeros_like(np.asarray(batch.token_mask)))
-            rungs = text_split.capacities(slots)
-            with self.spans.span(scopes.BUILD_PROGRAMS, rows=size,
-                                 programs=len(rungs),
-                                 **self._expert_tiles(rungs, width)):
-                for rung in rungs:
-                    warm = _Launch(None, n, size, width, capacity=rung)
-                    self._pack_launch(empty, warm)
-                    jax.block_until_ready(self._launch_packed(warm, mv))
-            self._text_families[size] = tuple(
-                (size, width, rung) for rung in rungs)
-        return _Launch(None, n, size, width,
-                       capacity=text_split.capacity(real_tokens, slots),
-                       tokens=real_tokens)
-
-    def _expert_tiles(self, rungs: Sequence[int], text_len: int
-                      ) -> Dict[str, str]:
-        """The id ``tiles`` of a routed bucket's ``build_programs`` span:
-        for each of its ``rungs`` (token slots), the (tm, tk, tn) its two
-        grouped calls run at, ``<slots>:<gate / up's>+<down's>`` joined by
-        commas, so that a trace and every compile-ledger record the span
-        caused say which tiles the programs hold. The host's mirror of what
-        the traced code asks (``ops.grouped_matmul.gmm_tiling``, by the
-        same shapes), as ``_record_kernel_dispatch`` mirrors the
-        predicates; nothing where the launch runs the XLA form."""
-        from realtime_fraud_detection_tpu.ops.grouped_matmul import (
-            gmm_tiling,
-            grouped_matmul_supported,
-        )
-
-        c = self.bert_config
-        hidden, width = c.hidden_size, c.intermediate_size
-        if not self.effective_use_pallas(
-                getattr(self._pool, "program_devices", None), text_len):
-            return {}
-        tiles = []
-        for rung in rungs:
-            rows = rung * c.num_experts_per_tok
-            if grouped_matmul_supported(rows, hidden, width):
-                tiles.append(f"{rung}:" + "+".join(
-                    "x".join(map(str, gmm_tiling(rows, k, n, c.num_experts,
-                                                 gated=gated)))
-                    for gated, k, n in ((True, hidden, width),
-                                        (False, width, hidden))))
-        return {"tiles": ",".join(tiles)} if tiles else {}
+    def _build_family(self, batch: ScoreBatch, n: int, size: int,
+                      narrow: Optional[int], full: int, bucket_of) -> None:
+        """Compile and run every program the launch rule can ask of bucket
+        ``size`` — each ``(rows, width)`` of ``text_split.family`` at each
+        rung of ``TextEncoder.capacities`` — under span ``build_programs``
+        (``rows=``, ``programs=``, and the encoder's own ids), so that none
+        first appears under load (each costs 0.6-1.1 s with a warm compile
+        cache, 4-10 s cold, nearly all of it the interpreter's: a thread
+        made it slower on the v5e; the compile ledger has each phase,
+        ``host_stats()["compile"]``). Every member here, from this one
+        place, whichever of them the batch needs itself: a program's entry
+        in the persistent compile cache follows the call stack it was traced
+        under (utils/compile_cache.py), and which members a bucket's first
+        batch launches differs from run to run."""
+        shapes = (text_split.family(size, narrow, full, bucket_of)
+                  if narrow is not None else ((size, full),))
+        programs = [(rows, width, rung) for rows, width in shapes
+                    for rung in self._text.capacities(rows * width) or (None,)]
+        ids = {}
+        if self.effective_use_pallas(
+                getattr(self._pool, "program_devices", None), full):
+            ids = self._text.build_ids(self.bert_config, programs)
+        mv = self.effective_model_valid()
+        # rows with no token stand in where a rung has to hold them (nothing
+        # to hold, so every rung takes them), else the batch's first rows:
+        # the results are dropped
+        empty = batch.replace(
+            token_mask=np.zeros_like(np.asarray(batch.token_mask)))
+        with self.spans.span(scopes.BUILD_PROGRAMS, rows=size,
+                             programs=len(programs), **ids):
+            for rows, width, rung in programs:
+                k = min(n, rows)
+                warm = _Launch(np.arange(k), k, rows, width, capacity=rung)
+                self._pack_launch(batch if rung is None else empty, warm)
+                jax.block_until_ready(self._launch_packed(warm, mv))
+        self._text_families[size] = tuple(
+            program if program[2] is not None else program[:2]
+            for program in programs)
 
     def _pack_launch(self, batch: ScoreBatch, launch: "_Launch") -> None:
         """Pad ``launch``'s rows of ``batch`` to its bucket at its text
@@ -1720,32 +1506,6 @@ class FraudScorer:
             **self.quant_static(), **self.kernel_static(), **routed,
         )
 
-    def _routed_pairs(self, tokens: int) -> int:
-        """The (token, expert) pairs the routers of a launch of ``tokens``
-        real tokens choose, all sparse layers: what enters the grouped
-        expert matmuls where every layer holds every expert."""
-        if not self._moe_text:
-            return 0
-        c = self.bert_config
-        return tokens * c.num_experts_per_tok * c.num_sparse_layers
-
-    def _visible_pairs(self, lengths: np.ndarray) -> Tuple[int, int]:
-        """The (query, key) pairs the real queries of rows of ``lengths``
-        real tokens see in one causal layer, ``L(L+1)/2`` a row, and in one
-        layer under the encoder's ``sliding_window`` W (0 where its
-        description spells none): ``sum_i min(i+1, W)`` = the same less the
-        ``(L-W)(L-W+1)/2`` pairs further back than the window. Counted for
-        every routed encoder — ``pipeline.CausalText``: all are causal —
-        whatever its class — and for a causal encoder without routed blocks
-        (``pipeline.CausalText``)."""
-        lengths = lengths.astype(np.int64)
-        full = int(np.sum(lengths * (lengths + 1) // 2))
-        window = getattr(self.bert_config, "sliding_window", None)
-        if not window:
-            return full, 0
-        beyond = np.maximum(lengths - window, 0)
-        return full, full - int(np.sum(beyond * (beyond + 1) // 2))
-
     def finalize(self, pending: "PendingScore", now: Optional[float] = None,
                  lock=None) -> List[Dict[str, Any]]:
         """Block on a dispatched batch, build responses, write back state.
@@ -1775,16 +1535,8 @@ class FraudScorer:
             else:
                 out = jax.device_get(pending.out)  # blocks until done
             if pending.text_stats is not None:
-                # each sparse layer's largest group, held pairs and visited
-                # rows (pipeline.CausalText)
-                peaks, held, tile_rows = (
-                    int(total) for total in np.sum(
-                        jax.device_get(pending.text_stats), axis=1,
-                        dtype=np.int64))
-                pending.expert_rows = held
-                pending.expert_peak_rows = (
-                    peaks * self.bert_config.num_experts)
-                pending.expert_tile_rows = tile_rows
+                pending.counters.update(self._text.finalize_counters(
+                    self.bert_config, jax.device_get(pending.text_stats)))
         # processing time = assemble/dispatch + device wait; excludes any
         # pipeline queue wait between dispatch() returning and this call
         elapsed_ms = (pending.dispatch_ms

@@ -27,7 +27,10 @@ from typing import Any, Dict, List, Optional
 
 from realtime_fraud_detection_tpu.obs import scopes
 from realtime_fraud_detection_tpu.obs.profiling import GcSpans, SpanTimer
-from realtime_fraud_detection_tpu.scoring.scorer import FraudScorer
+from realtime_fraud_detection_tpu.scoring.scorer import (
+    LAUNCH_COUNTERS,
+    FraudScorer,
+)
 from realtime_fraud_detection_tpu.serving.validation import sanitize_for_stream
 from realtime_fraud_detection_tpu.state.stores import _event_time_ms
 from realtime_fraud_detection_tpu.stream import topics as T
@@ -277,42 +280,13 @@ class StreamJob:
         self._host_gc = contextlib.nullcontext()
         if self.tracer is not None:
             self._host_gc = self.tracer.host_gc = GcSpans()
-        self.counters: Dict[str, int] = {
-            "scored": 0, "alerts": 0, "batches": 0, "duplicates_skipped": 0,
-            "errors": 0, "shed": 0,
-            # what the text branch was launched with, summed where
-            # ``batches`` is (PendingScore.token_slots): padded slots,
-            # rows x text_len^2, and the real tokens among the slots
-            "token_slots": 0, "token_slots_sq": 0, "real_tokens": 0,
-            # the MoE text encoder only (0 otherwise): the (token, expert)
-            # pairs that entered the grouped expert matmuls (real tokens:
-            # padding is not routed), what they would be if every expert's
-            # group were as large as the layer's largest, the rows the
-            # fused gate / up kernel's grid visited for them (visits x row
-            # tile; 0 in the XLA form), the capacities
-            # the routed blocks ran at (at most ``token_slots``) and the
-            # batches that took a narrow one (PendingScore.expert_rows /
-            # expert_peak_rows / expert_tile_rows / expert_token_slots /
-            # compact_batches)
-            "expert_rows": 0, "expert_peak_rows": 0, "expert_tile_rows": 0,
-            "expert_token_slots": 0, "compact_batches": 0,
-            # the (token, expert) pairs the routers chose (``expert_rows``
-            # of them entered a held expert's group: all, unless a layer
-            # holds a share of its experts) and the (query, key) pairs the
-            # real queries saw in one causal layer, and in one layer under
-            # the encoder's sliding window (PendingScore.routed_pairs /
-            # attn_visible_pairs_full / attn_visible_pairs_sliding)
-            "routed_pairs": 0, "attn_visible_pairs_full": 0,
-            "attn_visible_pairs_sliding": 0,
-            # an encoder with a state-space mixer only (0 otherwise): the
-            # chunks its scans walked, launched rows x text_len /
-            # mamba_chunk_size x layers (PendingScore.ssm_chunks)
-            "ssm_chunks": 0,
-            # how the rows were launched (scoring/text_split.py): real rows
-            # in a program narrower than ``text_len``, real rows at
-            # ``text_len``, batches that took two launches
-            "short_text_rows": 0, "long_text_rows": 0, "split_batches": 0,
-        }
+        # the job's own, then the counters of the text branch's launches,
+        # summed where ``batches`` is from ``PendingScore.counters``
+        # (models/text_encoder.py says what each counts): all of them read
+        # 0 from the first batch, whichever the encoder fills
+        self.counters: Dict[str, int] = dict.fromkeys(
+            ("scored", "alerts", "batches", "duplicates_skipped", "errors",
+             "shed") + LAUNCH_COUNTERS, 0)
         self._batch_seq = 0
         # transaction_ids dispatched but not yet written back: the pipelined
         # loop dedupes batch N+1 against these before batch N lands in the
@@ -583,16 +557,9 @@ class StreamJob:
                     else None)
                 feats = pending.features
                 scored_ok = True
-                for key in ("token_slots", "token_slots_sq", "real_tokens",
-                            "expert_rows", "expert_peak_rows",
-                            "expert_tile_rows", "expert_token_slots",
-                            "compact_batches",
-                            "routed_pairs", "attn_visible_pairs_full",
-                            "attn_visible_pairs_sliding", "ssm_chunks",
-                            "short_text_rows", "long_text_rows",
-                            "split_batches"):
-                    # 0 from a stand-in scorer's pending without them
-                    self.counters[key] += getattr(pending, key, 0)
+                # (nothing from a stand-in scorer's pending without them)
+                for key, value in getattr(pending, "counters", {}).items():
+                    self.counters[key] += value
             except Exception as e:  # noqa: BLE001 — boundary: keep streaming
                 self._log_batch_error("finalize", len(fresh), e)
                 results = None

@@ -712,21 +712,8 @@ class QuantSettings:
 
 
 VALID_KERNEL_SITES = ("dequant_matmul", "epilogue", "attention")
-# one more site that ``FraudScorer.kernel_snapshot`` counts launches under,
-# for a routed text encoder alone, and that nothing sets: the experts' gate +
-# up + SiLU (``ops.grouped_gated_matmul``: the fused kernel wherever a routed
-# program is asked for its kernels and ``grouped_matmul_supported`` takes the
-# rows)
-EXPERT_GATE_UP_SITE = "expert_gate_up"
-# and the experts' way home, counted beside it (``ops.weighted_combine``: down
-# leaves each result row one contiguous piece and ONE kernel fetches the rows
-# of the pairs that entered a group and sums them, wherever a routed program
-# is asked for its kernels and ``combine_supported`` takes the tokens)
-EXPERT_COMBINE_SITE = "expert_combine"
-# and one for an encoder with a state-space mixer alone: the mixer's scan
-# (``ops.ssd_scan``: the kernel wherever the program is asked for its kernels
-# and ``ssd_refusal`` has nothing against the shape)
-SSM_SCAN_SITE = "ssm_scan"
+# (an encoder's row may name further sites that ``kernel_snapshot`` counts
+# launches under and nothing sets: models/text_encoder.KernelSite)
 VALID_KERNEL_MODES = ("off", "pallas")
 VALID_ATTENTION_KERNELS = ("reference", "flash")
 

@@ -485,20 +485,25 @@ def test_the_class_picks_the_encoder_and_answers_the_seams_questions():
     from realtime_fraud_detection_tpu.models.olmoe import TINY_OLMOE
     from realtime_fraud_detection_tpu.scoring import pipeline
 
-    causal = pipeline.causal_text(CFG)
-    assert causal is pipeline._CAUSAL_TEXT[FalconH1Config]
-    assert not causal.routed and pipeline.routed_text(CFG) is None
-    assert causal.init is init_falcon_h1_params
-    assert causal.predict is falcon_h1_predict
-    assert causal.attention_refusal(CFG, 256) == CFG.core_refusal(256)
-    assert causal.scan_refusal(CFG, 256) == CFG.scan_refusal(256)
+    row = pipeline.text_encoder(CFG)
+    assert row is pipeline.TEXT_ENCODERS[FalconH1Config] \
+        is falcon_h1.TEXT_ENCODER
+    assert row.capacities(4096) is None and row.narrow_width(CFG) is None
+    assert row.init is init_falcon_h1_params and not row.planes
+    attention, scan = row.sites
+    assert (attention.name, scan.name) == ("attention", "ssm_scan")
+    assert attention.refusal(CFG, 256, 256) == CFG.core_refusal(256)
+    assert scan.refusal(CFG, 256, 256) == CFG.scan_refusal(256)
     assert pipeline.text_layers(CFG) == 2
-    # the four routed rows are what they were; the dense encoder has none
-    assert pipeline.causal_text(TINY_CONFIG) is None
-    routed = pipeline.routed_text(TINY_OLMOE)
-    assert routed is pipeline.causal_text(TINY_OLMOE) and routed.routed
-    assert routed.scan_refusal is None
-    assert sum(row.routed for row in pipeline._CAUSAL_TEXT.values()) == 4
+    # the four routed rows are what they were; the dense encoder's takes
+    # every plane and has the narrow width
+    dense = pipeline.text_encoder(TINY_CONFIG)
+    assert dense.narrow_width(TINY_CONFIG) == 128 and len(dense.planes) == 5
+    routed = pipeline.text_encoder(TINY_OLMOE)
+    assert [site.name for site in routed.sites] == [
+        "attention", "expert_gate_up", "expert_combine"]
+    assert sum(row.capacities(4096) is not None
+               for row in pipeline.TEXT_ENCODERS.values()) == 4
 
 
 def test_text_predict_refuses_a_capacity_and_the_dequant_plane(params, text):
@@ -554,16 +559,17 @@ def test_the_scorers_packed_path_matches_the_reference(scorer32, rows):
     np.testing.assert_allclose(got, want[:rows], atol=5e-6)
     lengths = np.count_nonzero(np.asarray(batch.token_mask), axis=1)
     assert lengths.max() > 3 * CFG.mamba_chunk_size or rows == 1
-    assert pending.token_slots == rows * 64
-    assert pending.ssm_chunks == rows * 64 // 16 * 2
-    assert pending.attn_visible_pairs_full == int(
+    c = pending.counters
+    assert c["token_slots"] == rows * 64
+    assert c["ssm_chunks"] == rows * 64 // 16 * 2
+    assert c["attn_visible_pairs_full"] == int(
         (lengths * (lengths + 1) // 2).sum())
-    assert pending.attn_visible_pairs_sliding == 0
+    assert c["attn_visible_pairs_sliding"] == 0
     assert pending.text_stats is None
-    assert (pending.routed_pairs, pending.expert_rows,
-            pending.expert_peak_rows, pending.expert_token_slots,
-            pending.compact_batches, pending.split_batches) == (0,) * 6
-    assert pending.long_text_rows == rows and pending.short_text_rows == 0
+    assert (c["routed_pairs"], c["expert_rows"], c["expert_peak_rows"],
+            c["expert_token_slots"], c["compact_batches"],
+            c["split_batches"]) == (0,) * 6
+    assert c["long_text_rows"] == rows and c["short_text_rows"] == 0
 
 
 def test_the_scorer_counts_the_scan_site_and_names_its_refusals(scorer32):
@@ -634,7 +640,7 @@ def test_the_planes_written_for_distilbert_refuse_it_by_name():
                                          "device"):
         _scorer(mesh=build_mesh())
     with pytest.raises(ValueError, match="DevicePool.*FalconH1Config"):
-        _scorer().require_dense_text("DevicePool")
+        _scorer().require_plane("pool", "DevicePool")
 
 
 # ------------------------------ the other encoders' programs are left alone
@@ -698,8 +704,8 @@ def test_the_five_other_encoders_trace_no_line_of_it(monkeypatch, encoder):
                          (scan_module, "_ssd_xla"), (falcon_h1, "mup_vector")):
         monkeypatch.setattr(module, name, poisoned)
     monkeypatch.setitem(
-        pipeline._CAUSAL_TEXT, FalconH1Config, dataclasses.replace(
-            pipeline._CAUSAL_TEXT[FalconH1Config], predict=poisoned))
+        pipeline.TEXT_ENCODERS, FalconH1Config, dataclasses.replace(
+            pipeline.TEXT_ENCODERS[FalconH1Config], predict=poisoned))
     assert _lowered(config).as_text() == whole
 
 

@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from realtime_fraud_detection_tpu.core.mesh import build_mesh
-from realtime_fraud_detection_tpu.models import olmoe
+from realtime_fraud_detection_tpu.models import joyai, olmoe
 from realtime_fraud_detection_tpu.models.joyai import (
     TINY_JOYAI,
     JoyaiConfig,
@@ -28,6 +28,7 @@ from realtime_fraud_detection_tpu.models.joyai import (
     joyai_route,
     rotate_pairs,
 )
+from realtime_fraud_detection_tpu.models.text_encoder import visible_pairs
 from realtime_fraud_detection_tpu.ops import (
     attention_reference,
     rope_lane_tables,
@@ -159,16 +160,16 @@ def test_the_scorers_packed_path_matches_the_reference_at_each_rung(
     batch = scorer.assemble(recs)
     pending = scorer.dispatch(recs)
     results = scorer.finalize(pending)
-    assert pending.expert_token_slots == rung
-    assert pending.compact_batches == int(rung == 3072)
+    assert pending.counters["expert_token_slots"] == rung
+    assert pending.counters["compact_batches"] == int(rung == 3072)
     want = _reference(scorer.models.bert, batch.token_ids, batch.token_mask)
     got = np.array([r["model_predictions"]["bert_text"] for r in results])
     assert np.abs(got - want[:128]).max() < 3e-3
     # every expert is held: what entered the groups is what was routed
-    assert pending.expert_rows == pending.routed_pairs \
-        == pending.real_tokens * 4 * 2
-    assert pending.expert_peak_rows % 16 == 0
-    assert pending.expert_rows <= pending.expert_peak_rows
+    c = pending.counters
+    assert c["expert_rows"] == c["routed_pairs"] == c["real_tokens"] * 4 * 2
+    assert c["expert_peak_rows"] % 16 == 0
+    assert c["expert_rows"] <= c["expert_peak_rows"]
 
 
 def test_predict_is_the_softmax_of_the_head_and_the_stats_are_the_peaks(
@@ -724,10 +725,10 @@ def test_one_description_of_a_routed_encoder_serves_all_four():
     from realtime_fraud_detection_tpu.models.zaya import TINY_ZAYA
     from realtime_fraud_detection_tpu.scoring import pipeline
 
-    routed = pipeline.routed_text(CFG)
-    assert routed.predict is joyai_predict
+    routed = pipeline.text_encoder(CFG)
+    assert routed is joyai.TEXT_ENCODER
     assert routed.init is init_joyai_params
-    assert routed.attention_refusal is JoyaiConfig.core_refusal
+    assert routed.sites[0].refusal(CFG, 256, 256) == CFG.core_refusal(256)
     assert pipeline.text_layers(CFG) == 3
     for cfg in (olmoe.TINY_OLMOE, TINY_ZAYA, TINY_LAGUNA, CFG):
         for name in ("num_experts", "num_experts_per_tok",
@@ -778,7 +779,7 @@ def test_through_scorer_and_job_the_counters_count_every_pair():
     assert c["attn_visible_pairs_full"] >= c["real_tokens"]
     assert c["attn_visible_pairs_sliding"] == 0
     lengths = np.array([0, 1, 7, 128])
-    assert scorer._visible_pairs(lengths) == (
+    assert visible_pairs(CFG, lengths) == (
         sum(n * (n + 1) // 2 for n in lengths), 0)
     refused = scorer.kernel_snapshot()["refused"]["attention"]
     assert "head_dim 16" in refused and "value_dim 16" in refused

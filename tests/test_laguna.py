@@ -17,6 +17,7 @@ import pytest
 
 from realtime_fraud_detection_tpu.core.mesh import build_mesh
 from realtime_fraud_detection_tpu.models import laguna, olmoe
+from realtime_fraud_detection_tpu.models.text_encoder import visible_pairs
 from realtime_fraud_detection_tpu.models.laguna import (
     LAGUNA_ROPE_FULL,
     LAGUNA_ROPE_SLIDING,
@@ -609,7 +610,7 @@ def test_published_config_is_the_default():
     assert c.rope_full.rope_type == "yarn" and c.rope_full.factor == 128
     assert c.rope_of(0) is c.rope_full and c.rope_of(1) is c.rope_sliding
     assert c.window_of(0) is None and c.window_of(2) == 512
-    # the routed-encoder seam's names (scoring/pipeline.CausalText)
+    # the routed-encoder seam's names (models/text_encoder.py)
     assert c.intermediate_size == 1024 and c.num_sparse_layers == 47
     assert c.core_refusal(2048) is None
 
@@ -661,11 +662,12 @@ def test_stored_dtypes_and_per_layer_shapes():
 
 # ------------------------------------------------- the seam into the scorer
 def test_one_description_of_a_routed_encoder_serves_all_three():
-    from realtime_fraud_detection_tpu.models.zaya import TINY_ZAYA, ZayaConfig
+    from realtime_fraud_detection_tpu.models.zaya import TINY_ZAYA
     from realtime_fraud_detection_tpu.scoring import pipeline
 
-    routed = pipeline.routed_text(CFG)
-    assert routed.predict is laguna_predict
+    routed = pipeline.text_encoder(CFG)
+    assert routed is laguna.TEXT_ENCODER
+    assert routed.init is init_laguna_params
     assert pipeline.text_layers(CFG) == 5
     for cfg in (olmoe.TINY_OLMOE, TINY_ZAYA, CFG):
         for name in ("num_experts", "num_experts_per_tok",
@@ -674,12 +676,14 @@ def test_one_description_of_a_routed_encoder_serves_all_three():
             assert isinstance(getattr(cfg, name), int), name
     assert CFG.num_sparse_layers == 4 and CFG.intermediate_size == 64
     assert olmoe.TINY_OLMOE.num_sparse_layers == 2
-    # the attention site's refusal comes from the table, for every encoder
-    assert pipeline.routed_text(olmoe.TINY_OLMOE).attention_refusal \
-        is olmoe.OlmoeConfig.core_refusal
-    assert pipeline.routed_text(TINY_ZAYA).attention_refusal \
-        is ZayaConfig.mix_refusal
-    assert routed.attention_refusal is LagunaConfig.core_refusal
+    # the attention site's refusal comes from the row, for every encoder
+    for cfg, refusal in ((olmoe.TINY_OLMOE, olmoe.TINY_OLMOE.core_refusal),
+                         (TINY_ZAYA, TINY_ZAYA.mix_refusal),
+                         (CFG, CFG.core_refusal)):
+        site = pipeline.text_encoder(cfg).sites[0]
+        assert site.name == "attention" and site.by_width
+        for width in (32, 128, 256):
+            assert site.refusal(cfg, width, width) == refusal(width)
 
 
 def _scorer(cfg=CFG, text_len=32, **kw):
@@ -737,7 +741,7 @@ def test_through_scorer_and_job_the_counters_follow_the_share():
     full = sum(n * (n + 1) // 2 for n in lengths)
     sliding = sum(sum(min(i + 1, CFG.sliding_window) for i in range(n))
                   for n in lengths)
-    assert scorer._visible_pairs(lengths) == (full, sliding)
+    assert visible_pairs(CFG, lengths) == (full, sliding)
     assert "head_dim 16" in scorer.kernel_snapshot()["refused"]["attention"]
 
 
@@ -750,11 +754,11 @@ def test_the_other_routed_encoders_count_every_routed_pair_as_entered():
     recs = TransactionGenerator(num_users=8, num_merchants=4).generate_batch(5)
     pending = scorer.dispatch(recs)
     scorer.finalize(pending)
-    assert pending.routed_pairs == pending.expert_rows \
-        == pending.real_tokens * 2 * 2
+    c = pending.counters
+    assert c["routed_pairs"] == c["expert_rows"] == c["real_tokens"] * 2 * 2
     # causal, no window: the full count, nothing under a window
-    assert pending.attn_visible_pairs_full > 0
-    assert pending.attn_visible_pairs_sliding == 0
+    assert c["attn_visible_pairs_full"] > 0
+    assert c["attn_visible_pairs_sliding"] == 0
 
 
 def test_the_scorers_answer_is_the_encoders(params):

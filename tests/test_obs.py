@@ -998,10 +998,15 @@ def test_trace_drill_fast_smoke(capsys):
 
 def test_tracing_overhead_guard_real_scorer():
     """Tier-1 CI overhead guard: a fixed fake-Kafka workload on the REAL
-    scorer, tracing off vs on — the per-txn wall-clock ratio must stay
-    under the pinned bound (the plane is admissible on the hot path, not
-    just in the virtual drill). Batch 16 reuses the bucket other tier-1
-    suites already compiled in-process, so the guard costs seconds."""
+    scorer, tracing off vs on — the job thread's per-txn host cost must
+    stay under the pinned ratio (the plane is admissible on the hot path,
+    not just in the virtual drill). The cost is the thread's CPU time, not
+    the wall: the job runs on the calling thread and the tracer's work with
+    it, while the suite's other xdist workers take the cores away for
+    whole scheduler slices at a time — a wall-clock ratio of two ~1 s soaks
+    failed under six workers and passed alone. Batch 16 reuses the bucket
+    other tier-1 suites already compiled in-process, so the guard costs
+    seconds."""
     import time
 
     from realtime_fraud_detection_tpu.obs.tracing import Tracer as _Tracer
@@ -1034,15 +1039,19 @@ def test_tracing_overhead_guard_real_scorer():
         broker.produce_batch(T.TRANSACTIONS, gen.generate_batch(n),
                              key_fn=lambda r: str(r["user_id"]))
         s.score_batch(gen.generate_batch(batch))     # compile outside
-        t0 = time.perf_counter()
+        t0 = time.thread_time()
         job.run_until_drained(now=1000.0)
-        return time.perf_counter() - t0
+        assert job.counters["scored"] == n
+        return time.thread_time() - t0
 
-    # interleaved best-of-2 per arm damps scheduler noise; the bound is
+    # interleaved, the least of three per arm: what is left of the noise
+    # (cache and frequency effects) only ever adds. The bound is
     # deliberately generous (tracing measures ~1.01x) so only a real
     # hot-path regression trips it
-    off = min(soak(False), soak(False))
-    on = min(soak(True), soak(True))
+    runs = [(soak(False), soak(True)) for _ in range(3)]
+    off = min(run[0] for run in runs)
+    on = min(run[1] for run in runs)
+    assert off > 0.01, f"the job thread's CPU time reads {off:.4f} s"
     assert on / off < 1.5, f"tracing overhead ratio {on / off:.3f} >= 1.5"
 
 

@@ -1092,13 +1092,14 @@ def test_the_gate_up_site_is_counted_and_entered_once_a_sparse_layer(side):
         gated=True)))
     assert (f" tiles={8 * 128}:{tiles}+" in traces[0]["caused_by"]) == held
     assert ("tiles=" in traces[0]["caused_by"]) == held
-    assert pending.expert_rows == pending.routed_pairs > 0
+    c = pending.counters
+    assert c["expert_rows"] == c["routed_pairs"] > 0
     if held:
-        assert pending.expert_tile_rows % 128 == 0
-        assert pending.expert_rows < pending.expert_tile_rows <= layers * (
+        assert c["expert_tile_rows"] % 128 == 0
+        assert c["expert_rows"] < c["expert_tile_rows"] <= layers * (
             rows + 128 * cfg.num_experts)
     else:
-        assert pending.expert_tile_rows == 0
+        assert c["expert_tile_rows"] == 0
 
 
 def test_the_dense_program_has_no_second_output_and_no_expert_rows():
@@ -1110,11 +1111,12 @@ def test_the_dense_program_has_no_second_output_and_no_expert_rows():
     scorer = FraudScorer(mesh=_one_device_mesh())
     recs = TransactionGenerator(num_users=8, num_merchants=4).generate_batch(3)
     pending = scorer.dispatch(recs)
-    assert pending.text_stats is None and pending.expert_rows == 0
-    assert pending.expert_token_slots == 0 and pending.compact_batches == 0
+    c = pending.counters
+    assert pending.text_stats is None and c["expert_rows"] == 0
+    assert c["expert_token_slots"] == 0 and c["compact_batches"] == 0
     assert not isinstance(pending.out, tuple)
     assert len(scorer.finalize(pending)) == 3
-    assert pending.expert_peak_rows == 0
+    assert pending.counters["expert_peak_rows"] == 0
     # nor has its snapshot the routed experts' site
     snap = scorer.kernel_snapshot()
     assert not {"expert_gate_up", "expert_combine"} & {

@@ -233,14 +233,14 @@ def test_split_launches_equal_the_unsplit_program(models, case):
     b = bucket_for(n, multiple_of=multiple)
     launches = [(b if rows == "b" else bucket_for(rows, multiple_of=multiple),
                  width) for rows, width in want_launches]
-    assert pending.token_slots == sum(r * w for r, w in launches)
-    assert pending.token_slots_sq == sum(r * w * w for r, w in launches)
-    assert pending.real_tokens == sum(lengths)
+    c = pending.counters
+    assert c["token_slots"] == sum(r * w for r, w in launches)
+    assert c["token_slots_sq"] == sum(r * w * w for r, w in launches)
+    assert c["real_tokens"] == sum(lengths)
     short = sum(1 for t in lengths if t <= NARROW) \
         if launches[0][1] < text_len else 0
-    assert (pending.short_text_rows, pending.long_text_rows,
-            pending.split_batches) == (short, n - short,
-                                       int(len(launches) > 1))
+    assert (c["short_text_rows"], c["long_text_rows"],
+            c["split_batches"]) == (short, n - short, int(len(launches) > 1))
     if len(launches) == 1:
         assert isinstance(pending.out, jax.Array)     # the parent's output
         assert pending.out.shape[0] == b
@@ -273,14 +273,14 @@ def test_split_launches_equal_the_unsplit_program(models, case):
 def test_planes_that_cannot_take_a_second_width_keep_the_unsplit_launch(
         models):
     s = FraudScorer(models=models, scorer_config=ScorerConfig(text_len=256))
-    assert s.text_split_refusal() is None
+    assert s.plane_refusal("text_split") is None
     assert s.host_stats()["text_split"]["width"] == NARROW
 
     class StandInPool:
         batch_multiple = None
 
     s._pool = StandInPool()
-    assert "StandInPool" in s.text_split_refusal()
+    assert "StandInPool" in s.plane_refusal("text_split")
     assert s._narrow_text_len(256) is None
     assert s.host_stats()["text_split"]["width"] is None
     s._pool = None

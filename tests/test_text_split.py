@@ -132,7 +132,8 @@ def test_one_warming_batch_compiles_everything_longtail_traffic_launches():
         compiles.count = 0
         for i in range(1, batches + 1):
             p = s.dispatch(batch(i), now=1000.0 + i)
-            launched.add((p.token_slots, p.split_batches))
+            launched.add((p.counters["token_slots"],
+                          p.counters["split_batches"]))
             s.finalize(p, now=1000.0 + i)
     finally:
         jax.monitoring.unregister_event_duration_listener(compiles)
@@ -249,12 +250,13 @@ def test_both_rungs_are_compiled_by_a_buckets_first_batch(monkeypatch):
     assert compiles.count == 0
     assert passed == [narrow, full, narrow, full]
     for p, rung in zip(pendings, passed):
-        assert p.real_tokens <= rung == p.expert_token_slots
-        assert p.token_slots == full
-        assert p.compact_batches == int(rung == narrow)
-        assert p.expert_rows == p.real_tokens * 2 * 2     # top-2, 2 layers
+        c = p.counters
+        assert c["real_tokens"] <= rung == c["expert_token_slots"]
+        assert c["token_slots"] == full
+        assert c["compact_batches"] == int(rung == narrow)
+        assert c["expert_rows"] == c["real_tokens"] * 2 * 2  # top-2, 2 layers
     # all-full rows take every slot
-    assert pendings[1].real_tokens == full
+    assert pendings[1].counters["real_tokens"] == full
     counts = s.host_stats()["text_split"]
     assert counts["compact_batches"] == 3
     assert counts["expert_token_slots"] == 3 * narrow + 2 * full
@@ -350,12 +352,14 @@ def test_a_short_bucket_has_empty_filler_rows_and_the_same_answers(
     recs = _worded(gen, 90, 40)
     batch = s.assemble(recs, now=1000.0)
     p = s.dispatch_assembled(batch, recs)
-    assert p.real_tokens == int(batch.token_mask.sum()) == 90 * 32 <= 3072
-    assert (p.expert_token_slots, p.compact_batches) == (3072, 1)
+    c = p.counters
+    assert c["real_tokens"] == int(batch.token_mask.sum()) == 90 * 32 <= 3072
+    assert (c["expert_token_slots"], c["compact_batches"]) == (3072, 1)
     compact = np.asarray(p.out)[:90]
     monkeypatch.setattr(text_split, "capacity", lambda tokens, slots: slots)
     q = s.dispatch_assembled(batch, recs)
-    assert (q.expert_token_slots, q.compact_batches) == (4096, 0)
+    assert (q.counters["expert_token_slots"],
+            q.counters["compact_batches"]) == (4096, 0)
     np.testing.assert_allclose(compact, np.asarray(q.out)[:90],
                                atol=1e-6, rtol=0)
 

@@ -645,17 +645,19 @@ def test_olmoe_still_refuses_grouped_query_attention():
 
 # ------------------------------------------------- the seam into the scorer
 def test_one_description_of_a_routed_encoder_serves_both():
+    from realtime_fraud_detection_tpu.models import zaya
     from realtime_fraud_detection_tpu.models.bert import TINY_CONFIG
     from realtime_fraud_detection_tpu.scoring import pipeline
 
-    assert pipeline.routed_text(TINY_CONFIG) is None
+    assert pipeline.text_encoder(TINY_CONFIG).capacities(4096) is None
     assert pipeline.text_layers(TINY_CONFIG) == TINY_CONFIG.num_layers
-    for cfg, predict in ((olmoe.TINY_OLMOE, olmoe.olmoe_predict),
-                         (CFG, zaya_predict)):
-        routed = pipeline.routed_text(cfg)
-        assert routed.predict is predict
+    for cfg, module in ((olmoe.TINY_OLMOE, olmoe), (CFG, zaya)):
+        routed = pipeline.text_encoder(cfg)
+        assert routed is module.TEXT_ENCODER
+        assert routed.capacities(4096) == (3072, 4096)
         assert pipeline.text_layers(cfg) == cfg.num_hidden_layers
-        # what CausalText's contract says the scorer may read
+        # what the routed rows' contract says the seam may read
+        # (models/text_encoder.py)
         for name in ("num_experts", "num_experts_per_tok",
                      "num_hidden_layers", "hidden_size", "intermediate_size"):
             assert isinstance(getattr(cfg, name), int), name
