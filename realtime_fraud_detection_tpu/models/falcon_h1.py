@@ -346,28 +346,32 @@ def group_rms_norm(x: jax.Array, weight: jax.Array, groups: int, eps: float
     return parts.reshape(x.shape) * weight
 
 
-def falcon_mixer(layer: Dict, u: jax.Array, config: FalconH1Config, *,
-                 use_pallas: bool = False, kernel_interpret: bool = False
-                 ) -> jax.Array:
-    """The Mamba-2 mixer on the normed ``u`` ``f32[B, T, hidden]``:
-    ``f32[B, T, hidden]`` ahead of ``ssm_out_multiplier``. ``use_pallas``
-    asks for the scan's kernel; a shape it does not take
-    (``FalconH1Config.scan_refusal``) runs the XLA form."""
-    b, t, _ = u.shape
+def mamba2_mix(layer: Dict, p: jax.Array, *, heads: int, head_dim: int,
+               groups: int, state: int, chunk: int, eps: float,
+               scan_kernel: bool = False, kernel_interpret: bool = False
+               ) -> jax.Array:
+    """A Mamba-2 mixer from ``W_in``'s result on: ``p`` ``f32[B, T, d_inner
+    + (d_inner + 2 groups state) + heads]`` (``z`` | ``xBC`` | ``dt``,
+    whatever multiplied it already applied) through the convolution, the
+    scan, the gate and the grouped norm to ``y W_out`` ``f32[B, T,
+    hidden]``; ``d_inner`` = ``heads x head_dim``. The layer's own
+    parameters under the names both encoders with such a mixer store them
+    by (this one and ``models/nemotron_h.py``). ``scan_kernel`` asks for
+    the scan's Pallas form: the caller answers for the shape
+    (``ops.ssd_scan.ssd_refusal``)."""
+    b, t, _ = p.shape
     operand = layer["in_proj"].dtype
-    d_ssm, heads = config.mamba_d_ssm, config.mamba_n_heads
-    groups, state = config.mamba_n_groups, config.mamba_d_state
+    d_ssm = heads * head_dim
+    conv_dim = d_ssm + 2 * groups * state
     with jax.named_scope(scopes.SSM_PROJ):
-        p = _proj(u * config.ssm_in_multiplier, layer["in_proj"]) \
-            * mup_vector(config)
         z = p[..., :d_ssm]
-        dt = p[..., d_ssm + config.conv_dim:]
+        dt = p[..., d_ssm + conv_dim:]
     with jax.named_scope(scopes.SSM_CONV):
         xbc = jax.nn.silu(causal_conv(
-            p[..., d_ssm:d_ssm + config.conv_dim], layer["conv_weight"],
+            p[..., d_ssm:d_ssm + conv_dim], layer["conv_weight"],
             layer["conv_bias"])).astype(operand)
         dt = jax.nn.softplus(dt + layer["dt_bias"])
-        x = xbc[..., :d_ssm].reshape(b, t, heads, config.mamba_d_head)
+        x = xbc[..., :d_ssm].reshape(b, t, heads, head_dim)
         b_in = xbc[..., d_ssm:d_ssm + groups * state].reshape(
             b, t, groups, state)
         c_in = xbc[..., d_ssm + groups * state:].reshape(
@@ -375,13 +379,29 @@ def falcon_mixer(layer: Dict, u: jax.Array, config: FalconH1Config, *,
     with jax.named_scope(scopes.SSM_SCAN):
         y, _ = ssd_scan(
             x, dt, -jnp.exp(layer["A_log"]), b_in, c_in, layer["D"],
-            chunk=config.mamba_chunk_size,
-            use_pallas=use_pallas and config.scan_refusal(t) is None,
-            interpret=kernel_interpret)
+            chunk=chunk, use_pallas=scan_kernel, interpret=kernel_interpret)
     with jax.named_scope(scopes.SSM_PROJ):
         y = group_rms_norm(y.reshape(b, t, d_ssm) * jax.nn.silu(z),
-                           layer["mixer_norm"], groups, config.rms_norm_eps)
+                           layer["mixer_norm"], groups, eps)
         return _proj(y, layer["out_proj"])
+
+
+def falcon_mixer(layer: Dict, u: jax.Array, config: FalconH1Config, *,
+                 use_pallas: bool = False, kernel_interpret: bool = False
+                 ) -> jax.Array:
+    """The Mamba-2 mixer on the normed ``u`` ``f32[B, T, hidden]``:
+    ``f32[B, T, hidden]`` ahead of ``ssm_out_multiplier``. ``use_pallas``
+    asks for the scan's kernel; a shape it does not take
+    (``FalconH1Config.scan_refusal``) runs the XLA form."""
+    with jax.named_scope(scopes.SSM_PROJ):
+        p = _proj(u * config.ssm_in_multiplier, layer["in_proj"]) \
+            * mup_vector(config)
+    return mamba2_mix(
+        layer, p, heads=config.mamba_n_heads, head_dim=config.mamba_d_head,
+        groups=config.mamba_n_groups, state=config.mamba_d_state,
+        chunk=config.mamba_chunk_size, eps=config.rms_norm_eps,
+        scan_kernel=use_pallas and config.scan_refusal(u.shape[1]) is None,
+        kernel_interpret=kernel_interpret)
 
 
 def falcon_attention(layer: Dict, u: jax.Array, attention_mask: jax.Array,

@@ -75,6 +75,7 @@ from realtime_fraud_detection_tpu.ops.grouped_matmul import (
     gated_tile_rows,
     grouped_gated_matmul,
     grouped_matmul,
+    grouped_relu2_matmul,
 )
 
 
@@ -311,6 +312,10 @@ def apply_experts(layer: Dict, x: jax.Array, experts: jax.Array,
     calls and the product — and ``grouped_matmul`` takes that through
     ``down_proj``, each float32 result row left as one contiguous piece
     (``[M, hidden / 128, 128]``). Neither writes a row past the last group.
+    A layer that holds NO ``gate_proj`` has experts without a gate,
+    ``down(relu(up(x))^2)`` (``models/nemotron_h.py``): its first call is
+    ``grouped_relu2_matmul``, the same kernel with one matrix and that
+    epilogue. What the layer holds chooses; there is no flag.
 
     **The way home** is ``ops.combine.weighted_combine``: each token's
     weighted sum of the rows of its pairs that entered a group — with
@@ -319,7 +324,7 @@ def apply_experts(layer: Dict, x: jax.Array, experts: jax.Array,
     and the sum.
 
     **A share of the experts.** The layer holds the experts its stacked
-    weights hold: ``gate_proj.shape[0]`` of the ``router_width`` the router
+    weights hold: ``down_proj.shape[0]`` of the ``router_width`` the router
     chose among (None: all of them), those numbered ``expert_offset`` on.
     ``experts`` counts in the router's numbers. Where the layer holds fewer
     than the router's width, a pair whose expert lives elsewhere enters no
@@ -329,7 +334,8 @@ def apply_experts(layer: Dict, x: jax.Array, experts: jax.Array,
     over all of a token's experts. No pair of a held expert is ever left
     out."""
     n, top_k = experts.shape
-    num_experts = layer["gate_proj"].shape[0]
+    num_experts = layer["down_proj"].shape[0]
+    gated = "gate_proj" in layer
     share = router_width is not None and (
         router_width != num_experts or expert_offset != 0)
     with jax.named_scope(scopes.ROUTER):
@@ -354,18 +360,22 @@ def apply_experts(layer: Dict, x: jax.Array, experts: jax.Array,
         home = jnp.zeros_like(order).at[order].set(
             jnp.arange(order.shape[0], dtype=jnp.int32))
         tile_rows = gated_tile_rows(
-            group_sizes, order.shape[0], *layer["gate_proj"].shape[1:],
-            use_pallas=use_pallas)
+            group_sizes, order.shape[0], *layer["up_proj"].shape[1:],
+            use_pallas=use_pallas, matrices=1 + gated)
     with jax.named_scope(scopes.EXPERTS_DISPATCH):
         # matmul operands take the stored dtype of the weights (bfloat16
         # as deployed; float32 weights make a float32 program, for tests)
-        rows = x.astype(layer["gate_proj"].dtype)[order // top_k]  # [N*k, H]
+        rows = x.astype(layer["up_proj"].dtype)[order // top_k]    # [N*k, H]
     with jax.named_scope(scopes.EXPERTS_MATMUL):
         gm = dict(use_pallas=use_pallas, interpret=kernel_interpret)
-        # gate, up and SiLU ⊙ in one call, rounded once to what down reads
-        act = grouped_gated_matmul(
-            rows, layer["gate_proj"], layer["up_proj"], group_sizes,
-            out_dtype=layer["down_proj"].dtype, **gm)
+        # the expert's first half in one call (gate, up and SiLU ⊙, or up
+        # and relu^2), rounded once to what down reads
+        first = dict(out_dtype=layer["down_proj"].dtype, **gm)
+        act = (grouped_gated_matmul(rows, layer["gate_proj"],
+                                    layer["up_proj"], group_sizes, **first)
+               if gated else
+               grouped_relu2_matmul(rows, layer["up_proj"], group_sizes,
+                                    **first))
         out = grouped_matmul(act, layer["down_proj"], group_sizes, **gm)
     with jax.named_scope(scopes.EXPERTS_COMBINE):
         # a pair's row comes home only where it entered a group (its token

@@ -41,6 +41,12 @@ sum of ``L(L+1)/2``, the (query, key) pairs the real queries see in one
 causal layer, and ``attn_visible_pairs_sliding`` = sum of ``sum_i min(i+1,
 window)`` where the class spells a ``sliding_window`` (else 0).
 
+Where a counter is "x layers" it is times the layers OF THAT KIND: an
+encoder whose layers are one mixer each (``models/nemotron_h.py``) counts
+its routed layers in ``routed_pairs`` and ``expert_*``, its state-space
+layers in ``ssm_chunks``, and ``attn_visible_pairs_full`` is one causal
+layer's whatever the number of attention layers.
+
 Of an encoder with routed blocks: ``routed_pairs`` at dispatch, the (token,
 expert) pairs its routers chose (real tokens x experts a token x sparse
 layers: padding is not routed); and at finalize, from the program's second
@@ -56,7 +62,8 @@ HOLDS), ``num_experts_per_tok``, ``hidden_size``, ``intermediate_size``
 (ONE expert's width) and ``num_sparse_layers``.
 
 Of an encoder with a state-space mixer: ``ssm_chunks`` at dispatch, the
-chunks its scans walked (``token_slots`` / ``mamba_chunk_size`` x layers).
+chunks its scans walked (``token_slots`` / the scan's chunk x the layers
+that have such a mixer).
 """
 
 from __future__ import annotations
@@ -230,13 +237,15 @@ def _routed_finalize_counters(config: Any, stats: np.ndarray
             "expert_tile_rows": tile_rows}
 
 
-def expert_tiles(config: Any, programs: Sequence[tuple]) -> Dict[str, str]:
+def expert_tiles(config: Any, programs: Sequence[tuple], matrices: int = 2
+                 ) -> Dict[str, str]:
     """The id ``tiles`` of a routed bucket's ``build_programs`` span: for
     each of its programs' capacities, the (tm, tk, tn) its two grouped
     calls run at, ``<slots>:<gate / up's>+<down's>`` joined by commas, so
     that a trace and every compile-ledger record the span caused say which
     tiles the programs hold. The host's mirror of what the traced code asks
-    (``ops.grouped_matmul.gmm_tiling``, by the same shapes); nothing where
+    (``ops.grouped_matmul.gmm_tiling``, by the same shapes; ``matrices``:
+    the right-hand blocks a step of the experts' first call); nothing where
     a capacity runs the XLA form."""
     hidden, width = config.hidden_size, config.intermediate_size
     tiles = []
@@ -245,7 +254,7 @@ def expert_tiles(config: Any, programs: Sequence[tuple]) -> Dict[str, str]:
         if grouped_matmul_supported(rows, hidden, width):
             tiles.append(f"{rung}:" + "+".join(
                 "x".join(map(str, gmm_tiling(rows, k, n, config.num_experts,
-                                             gated=gated)))
+                                             gated=gated, matrices=matrices)))
                 for gated, k, n in ((True, hidden, width),
                                     (False, width, hidden))))
     return {"tiles": ",".join(tiles)} if tiles else {}
@@ -253,13 +262,21 @@ def expert_tiles(config: Any, programs: Sequence[tuple]) -> Dict[str, str]:
 
 def routed_encoder(config_class: type, init: Callable[..., Dict[str, Any]],
                    predict: Callable[..., Any],
-                   attention_refusal: Callable[[Any, int], Optional[str]]
-                   ) -> TextEncoder:
+                   attention_refusal: Callable[[Any, int], Optional[str]],
+                   *, sites: Tuple[KernelSite, ...] = (),
+                   dispatch_counters: Callable[..., Dict[str, int]] = _empty,
+                   expert_matrices: int = 2) -> TextEncoder:
     """The row of a causal encoder with routed sparse-expert blocks over
     ``models/olmoe.py``'s machinery: ``predict`` takes ``capacity`` and,
     with ``with_stats``, returns the launch's statistics beside the
     probability; ``attention_refusal(config, width)`` is its attention
-    site's predicate (a fused causal core, or ZAYA1's fused mixing)."""
+    site's predicate (a fused causal core, or ZAYA1's fused mixing).
+    ``sites`` and ``dispatch_counters`` are what the encoder has BESIDE its
+    attention and its routed blocks (a state-space mixer's scan and the
+    chunks it walked): further kernel sites, and counters of a batch at
+    dispatch added to the routed ones. ``expert_matrices``: what an
+    expert's first grouped call streams a step — gate and up, or 1 where
+    the experts have no gate."""
 
     def routed_predict(params, ids, mask, config, *, use_pallas,
                        kernel_interpret, capacity, dequant_kernel):
@@ -274,9 +291,13 @@ def routed_encoder(config_class: type, init: Callable[..., Dict[str, Any]],
                           lambda c, width, slots: attention_refusal(c, width)),
                KernelSite("expert_gate_up", _gate_up_refusal, by_width=False),
                KernelSite("expert_combine", _combine_refusal,
-                          by_width=False)),
+                          by_width=False)) + sites,
         planes=frozenset((TEXT_SPLIT,)),
         one_device="the grouped expert matmul; a routed",
-        capacities=_routed_capacities, build_ids=expert_tiles,
-        dispatch_counters=_routed_dispatch_counters,
+        capacities=_routed_capacities,
+        build_ids=lambda config, programs: expert_tiles(
+            config, programs, expert_matrices),
+        dispatch_counters=lambda config, launches, lengths: dict(
+            _routed_dispatch_counters(config, launches, lengths),
+            **dispatch_counters(config, launches, lengths)),
         finalize_counters=_routed_finalize_counters)

@@ -99,6 +99,18 @@ SSM_SCAN = "ssm_scan"        # the state-space scan alone (ops/ssd_scan.py)
 FALCON_H1_LAYER_SCOPES: Tuple[str, ...] = LAYER_SCOPES + (
     SSM_PROJ, SSM_CONV, SSM_SCAN)
 
+# ---- device: under ``text`` where the encoder is models/nemotron_h.py: a
+# layer is ONE mixer, so ``layer<i>`` holds ``ln`` (its one RMSNorm and the
+# residual add) and the scopes of its KIND alone — a Mamba-2 layer
+# ``ssm_proj`` / ``ssm_conv`` / ``ssm_scan`` (Falcon-H1's mixer, without
+# multipliers), an attention layer ``attn_proj`` (q, k, v, o: no rotation)
+# and ``attn_core``, a routed layer ``router`` / ``experts`` /
+# ``shared_expert`` (experts without a gate: ``experts/matmul`` is up,
+# relu^2 and down)
+NEMOTRON_H_LAYER_SCOPES: Tuple[str, ...] = (
+    LN, SSM_PROJ, SSM_CONV, SSM_SCAN, ATTN_PROJ, ATTN_CORE, ROUTER, EXPERTS,
+    SHARED_EXPERT)
+
 
 def layer_scope(i: int) -> str:
     return f"{LAYER}{i}"

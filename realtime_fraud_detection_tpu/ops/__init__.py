@@ -27,20 +27,24 @@ a predicate admits holds the kernel, everything else the XLA form.
   calls and the product; ``grouped_matmul`` (down: ``down_gmm``, on the
   same scaffold, whose float32 result rows are ``[M, N / 128, 128]`` — each
   one contiguous piece in HBM) against ``ragged_dot`` reshaped;
-  ``grouped_matmul_supported``, the one predicate of both, and
-  ``gmm_tiling``, the one tile rule of both (from the call's shapes and
-  group count alone).
+  ``grouped_relu2_matmul`` (the first half of an expert with NO gate,
+  ``relu(rows @ up)^2``: ``relu2_gmm``, the fused kernel with one
+  right-hand block a step); ``grouped_matmul_supported``, the one predicate
+  of all three (a side that is no whole number of lane tiles goes whole in
+  one block), and ``gmm_tiling``, their one tile rule (from the call's
+  shapes, group count and right-hand blocks a step alone).
 - ``combine``: the routed experts' way home. ``weighted_combine`` (each
   token's weighted sum of its experts' result rows as ONE kernel,
   ``combine_rows``: every row of a pair that entered a group fetched once
   from HBM as one piece, the sum in VMEM, no ``[pairs, hidden]`` temporary)
   against a gather, a select and the sum; ``combine_supported``.
 - ``ssd_scan``: the state-space scan of a Mamba-2 mixer
-  (``models/falcon_h1.py``), ``ssd_scan(..., use_pallas=True)`` — the chunked
-  algorithm as ONE kernel a layer, a grid over (row, group, chunk) with the
-  carried states in VMEM — against the same algorithm in ``jax.numpy``
-  (``use_pallas=False``); ``ssd_refusal``, asked through
-  ``FalconH1Config.scan_refusal``.
+  (``models/falcon_h1.py``, ``models/nemotron_h.py``), ``ssd_scan(...,
+  use_pallas=True)`` — the chunked algorithm as ONE kernel a layer, a grid
+  over (row, group, chunk) with the carried states in VMEM, heads of 128 one
+  a lane tile or heads of 64 two a tile — against the same algorithm in
+  ``jax.numpy`` (``use_pallas=False``); ``ssd_refusal``, asked through the
+  configuration's ``scan_refusal``.
 - ``dequant_matmul``, ``epilogue``: the int8 text branch's fused
   dequant-matmul and the score-and-blend epilogue, behind ``KernelSettings``.
 """
@@ -85,6 +89,7 @@ from realtime_fraud_detection_tpu.ops.grouped_matmul import (  # noqa: F401
     grouped_matmul,
     grouped_matmul_reference,
     grouped_matmul_supported,
+    grouped_relu2_matmul,
 )
 from realtime_fraud_detection_tpu.ops.ssd_scan import (  # noqa: F401
     ssd_refusal,

@@ -1,5 +1,5 @@
 """Grouped matmuls for routed experts: ``[rows sorted by group, K] x [G, K,
-N]``, and an expert's gated first half in one call.
+N]``, and an expert's first half — gated or not — in one call.
 
 Row ``i`` of ``lhs`` belongs to the group whose span of
 ``cumsum(group_sizes)`` holds ``i``; each group's rows are multiplied by
@@ -7,7 +7,7 @@ that group's own ``[K, N]`` matrix. Groups are ragged (sizes are data: what
 the router chose), may be empty, and one may hold every row. bf16 operands,
 f32 accumulation — the precision the routed configurations state.
 
-Two entry points, each with two forms:
+Three entry points, each with two forms:
 
 - ``grouped_matmul``: one grouped matmul, f32 result (an expert's ``down``),
   each result row as its own ``N / 128`` lane tiles: ``f32[M, N / 128,
@@ -34,17 +34,27 @@ Two entry points, each with two forms:
   takes the SiLU and the product of the two f32 results in VMEM and writes
   them rounded once. The rows are read once, no f32 ``[rows, N]`` reaches
   HBM, and no pass stands between the matmuls.
+- ``grouped_relu2_matmul``: ``relu(rows @ up)^2`` in the dtype the next
+  matmul reads — the first half of an expert that has NO gate matrix
+  (``models/nemotron_h.py``). The XLA form is one ``ragged_dot``, the
+  ReLU and the square. The Pallas form is ``relu2_gmm``: the fused
+  kernel's grid with ONE right-hand block a step and that epilogue.
 
-Both kernels are ``_grouped_call`` — megablox's schedule
+**A side that is no whole number of lane tiles** (Nemotron's experts are
+1,856 = 14 1/2 tiles wide) goes whole in one block: a Pallas block equal to
+the array's whole side needs no lane multiple, and the tile rule takes K
+and N whole wherever the budget holds them anyway.
+
+All three kernels are ``_grouped_call`` — megablox's schedule
 (``make_group_metadata``), the grid, the block specs, the budget the call
 names — round a body of their own.
 
-``grouped_matmul_supported`` is the ONE predicate on shapes for both: the
+``grouped_matmul_supported`` is the ONE predicate on shapes for all: the
 traced guards below, the scorer's selector
 (``FraudScorer.effective_use_pallas``) and the tests all ask it.
-``gmm_tiling`` is the ONE tile rule of both, a function of the call's
-``(m, k, n)`` and group count alone; ``gated_tile_rows`` counts what the
-fused kernel's grid then visits.
+``gmm_tiling`` is the ONE tile rule of all, a function of the call's
+``(m, k, n)``, group count and right-hand blocks a step alone;
+``gated_tile_rows`` counts what the first call's grid then visits.
 """
 
 from __future__ import annotations
@@ -101,22 +111,43 @@ VMEM_CEILING = 64 << 20
 SUBLANES = 8
 
 
+# a side that is no whole number of lane tiles is whole sublane tiles of
+# bfloat16 at least (two rows a sublane): it is a matrix's second-to-last
+# side in one of an expert's two calls
+PACKED_SUBLANES = 16
+
+
 def grouped_matmul_supported(m: int, k: int, n: int) -> bool:
-    """Whole lane tiles on every side (the kernel's row tiling must divide
-    ``m``)."""
-    return (m >= LANES and m % LANES == 0 and k % LANES == 0
-            and n % LANES == 0)
+    """Whole lane tiles of rows (the kernel's row tiling must divide
+    ``m``); K and N whole lane tiles too, or — a block equal to the array's
+    whole side needs no lane multiple — a side of at least one lane tile
+    and of whole packed sublane tiles that the tile rule takes WHOLE in one
+    block, K and N both, inside the budget of either call (an expert's
+    width is N of its first call and K of its second)."""
+    if m < LANES or m % LANES:
+        return False
+    if k % LANES == 0 and n % LANES == 0:
+        return True
+    if min(k, n) < LANES or k % PACKED_SUBLANES or n % PACKED_SUBLANES:
+        return False
+    for gated, room in ((True, gated_vmem_bytes), (False, down_vmem_bytes)):
+        tile = gmm_tiling(m, k, n, 1, gated=gated)
+        if tile[1:] != (k, n) or room(*tile) > VMEM_CEILING:
+            return False
+    return True
 
 
 def gated_vmem_bytes(tm: int, tk: int, tn: int, operand_bytes: int = 2,
-                     out_bytes: int = 2) -> int:
-    """The VMEM ``gated_gmm`` names for a call at ``(tm, tk, tn)``: the row
-    block, the two matrix blocks and the result block double-buffered, six
-    f32 ``[tm, tn]`` tiles (the two products, the two accumulators, the
-    epilogue's temporaries), and 4 MB for what Mosaic keeps itself."""
-    blocks = (tm * tk * operand_bytes + 2 * tk * tn * operand_bytes
+                     out_bytes: int = 2, matrices: int = 2) -> int:
+    """The VMEM the experts' first call names at ``(tm, tk, tn)`` with
+    ``matrices`` right-hand blocks a step (``gated_gmm`` two, ``relu2_gmm``
+    one): the row block, the matrix blocks and the result block
+    double-buffered, three f32 ``[tm, tn]`` tiles a matrix (its product,
+    its accumulator, the epilogue's temporaries), and 4 MB for what Mosaic
+    keeps itself."""
+    blocks = (tm * tk * operand_bytes + matrices * tk * tn * operand_bytes
               + tm * tn * out_bytes)
-    return 2 * blocks + 6 * tm * tn * 4 + (4 << 20)
+    return 2 * blocks + 3 * matrices * tm * tn * 4 + (4 << 20)
 
 
 def down_vmem_bytes(tm: int, tk: int, tn: int, operand_bytes: int = 2) -> int:
@@ -130,7 +161,10 @@ def down_vmem_bytes(tm: int, tk: int, tn: int, operand_bytes: int = 2) -> int:
 
 def _lane_divisors(size: int) -> List[int]:
     """Every whole number of lane tiles that divides ``size``, widest
-    first."""
+    first; of a size that is no whole number of lane tiles, itself alone
+    (a block may be the array's whole side)."""
+    if size % LANES:
+        return [size]
     lanes = size // LANES
     return [d * LANES for d in range(lanes, 0, -1) if lanes % d == 0]
 
@@ -143,11 +177,13 @@ def down_widths(n: int) -> List[int]:
             if t == n or t % (SUBLANES * LANES) == 0]
 
 
-def gmm_tiling(m: int, k: int, n: int, groups: int, *, gated: bool = False
-               ) -> Tuple[int, int, int]:
+def gmm_tiling(m: int, k: int, n: int, groups: int, *, gated: bool = False,
+               matrices: int = 2) -> Tuple[int, int, int]:
     """(tm, tk, tn) for a supported ``[m, k] x [groups, k, n]`` call, of the
-    fused gate / up kernel (``gated``) or of down's ``down_gmm``: a function
-    of the shapes and of nothing else, in this order.
+    experts' first call (``gated``: the fused kernel, with ``matrices``
+    right-hand blocks a step — gate and up, or up alone where the expert
+    has no gate) or of down's ``down_gmm``: a function of the shapes and of
+    nothing else, in this order.
 
     1. K whole in one block wherever the call's budget holds it beside the
        narrowest row and result tiles: no accumulator pass, and any two
@@ -167,7 +203,8 @@ def gmm_tiling(m: int, k: int, n: int, groups: int, *, gated: bool = False
     The budget is what the call names, ``gated_vmem_bytes`` or
     ``down_vmem_bytes``, under ``VMEM_CEILING``; both count bfloat16
     operands, as deployed."""
-    room = gated_vmem_bytes if gated else down_vmem_bytes
+    room = (functools.partial(gated_vmem_bytes, matrices=matrices)
+            if gated else down_vmem_bytes)
     widths = (_lane_divisors(n) if gated else down_widths(n)) or [LANES]
 
     def widest(sides, tile):
@@ -183,9 +220,10 @@ def gmm_tiling(m: int, k: int, n: int, groups: int, *, gated: bool = False
 
 
 def gated_tile_rows(group_sizes: jax.Array, m: int, k: int, n: int, *,
-                    use_pallas: bool) -> jax.Array:
-    """``i32[]``: the rows the grid of ``grouped_gated_matmul``'s kernel
-    visits for ``group_sizes`` (``i32[G]``) of an ``[m, k] x [G, k, n]``
+                    use_pallas: bool, matrices: int = 2) -> jax.Array:
+    """``i32[]``: the rows the grid of the experts' first call's kernel
+    (``gated_gmm``, or ``relu2_gmm`` with ``matrices`` 1) visits for
+    ``group_sizes`` (``i32[G]``) of an ``[m, k] x [G, k, n]``
     call — over the non-empty groups, the row tiles a group's span of rows
     touches, times the row tile (``megablox``'s schedule: a tile that
     straddles groups is visited once for each). The real rows over it is
@@ -193,7 +231,8 @@ def gated_tile_rows(group_sizes: jax.Array, m: int, k: int, n: int, *,
     which visits no tile. A few integer operations on ``i32[G]``."""
     if not (use_pallas and grouped_matmul_supported(m, k, n)):
         return jnp.zeros((), jnp.int32)
-    tm = gmm_tiling(m, k, n, group_sizes.shape[0], gated=True)[0]
+    tm = gmm_tiling(m, k, n, group_sizes.shape[0], gated=True,
+                    matrices=matrices)[0]
     sizes = group_sizes.astype(jnp.int32)
     ends = jnp.cumsum(sizes)
     tiles = (ends + tm - 1) // tm - (ends - sizes) // tm
@@ -221,7 +260,9 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
     ``ragged_dot`` and the reshape."""
     m, k = lhs.shape
     n = rhs.shape[-1]
-    if use_pallas and grouped_matmul_supported(m, k, n):
+    # (a result row is lane tiles: K may be a side of no lane multiple, N
+    # may not)
+    if use_pallas and n % LANES == 0 and grouped_matmul_supported(m, k, n):
         return down_gmm(lhs, rhs, group_sizes.astype(jnp.int32),
                         tiling=gmm_tiling(m, k, n, rhs.shape[0]),
                         interpret=interpret)
@@ -343,6 +384,23 @@ def _gated_kernel(offsets, group_ids, row_tiles, lhs, gate_w, up_w, out,
             accs, tiles_k, store)
 
 
+def _relu2_kernel(offsets, group_ids, row_tiles, lhs, up_w, out, *accs,
+                  tm: int, tn: int, tiles_k: int):
+    """``_gated_kernel`` with one matrix: the product, summed over the K
+    steps, and on the last the squared ReLU of the group's own rows of the
+    tile."""
+    visit = pl.program_id(1)
+
+    def store(up):
+        mine = _own_rows(visit, offsets, group_ids, row_tiles, tm, (tm, tn))
+        out[...] = jnp.where(mine, jnp.square(jnp.maximum(up, 0.0)),
+                             out[...].astype(jnp.float32)).astype(out.dtype)
+
+    _over_k((jnp.dot(lhs[...], up_w[...],
+                     preferred_element_type=jnp.float32),),
+            accs, tiles_k, store)
+
+
 def _down_kernel(offsets, group_ids, row_tiles, lhs, w, out, *accs,
                  tm: int, tn: int, tiles_k: int):
     """One visit of a row tile by one group: the product summed over the K
@@ -389,6 +447,27 @@ def gated_gmm(lhs: jax.Array, gate_w: jax.Array, up_w: jax.Array,
         flops_per_mkn=4, transcendentals=m * n, interpret=interpret)
 
 
+@functools.partial(jax.jit, static_argnames=("out_dtype", "tiling",
+                                             "interpret"))
+def relu2_gmm(lhs: jax.Array, up_w: jax.Array, group_sizes: jax.Array, *,
+              out_dtype, tiling: Tuple[int, int, int],
+              interpret: bool = False) -> jax.Array:
+    """The Pallas form of ``grouped_relu2_matmul`` at ``tiling`` (tm, tk,
+    tn): ``gated_gmm``'s call with one matrix. Rows past the last group are
+    never written."""
+    m, k = lhs.shape
+    n = up_w.shape[-1]
+    tm, tk, tn = tiling
+    out_dtype = jnp.dtype(out_dtype)
+    return _grouped_call(
+        _relu2_kernel, "relu2_gmm", lhs, (up_w,), group_sizes, tiling,
+        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        out_block=(tm, tn), out_index=lambda row_tile, n_i: (row_tile, n_i),
+        vmem=gated_vmem_bytes(tm, tk, tn, lhs.dtype.itemsize,
+                              out_dtype.itemsize, matrices=1),
+        flops_per_mkn=2, transcendentals=0, interpret=interpret)
+
+
 @functools.partial(jax.jit, static_argnames=("tiling", "interpret"))
 def down_gmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,
              tiling: Tuple[int, int, int], interpret: bool = False
@@ -430,3 +509,24 @@ def grouped_gated_matmul(rows: jax.Array, gate_w: jax.Array,
     gate = grouped_matmul_reference(rows, gate_w, group_sizes)
     up = grouped_matmul_reference(rows, up_w, group_sizes)
     return (jax.nn.silu(gate) * up).astype(out_dtype)
+
+
+def grouped_relu2_matmul(rows: jax.Array, up_w: jax.Array,
+                         group_sizes: jax.Array, *, out_dtype,
+                         use_pallas: bool = False, interpret: bool = False
+                         ) -> jax.Array:
+    """``out_dtype[M, N]``: ``relu(rows @ up)^2`` with each row of ``rows``
+    (``[M, K]``, sorted by group) against its group's matrix of ``up_w``
+    (``[G, K, N]``): the first half of an expert with no gate; f32
+    accumulation, ReLU and square in f32, one rounding to ``out_dtype``.
+    ``use_pallas`` asks for the kernel; a shape it does not take runs the
+    XLA form, ``ragged_dot``, the ReLU and the square."""
+    m, k = rows.shape
+    n = up_w.shape[-1]
+    if use_pallas and grouped_matmul_supported(m, k, n):
+        tiling = gmm_tiling(m, k, n, up_w.shape[0], gated=True, matrices=1)
+        return relu2_gmm(rows, up_w, group_sizes.astype(jnp.int32),
+                         out_dtype=out_dtype, tiling=tiling,
+                         interpret=interpret)
+    up = grouped_matmul_reference(rows, up_w, group_sizes)
+    return jnp.square(jnp.maximum(up, 0.0)).astype(out_dtype)

@@ -33,6 +33,13 @@ every product is a plain ``[m, k] x [k, n]``; the running sums ``cum`` and
 the one thing a head needs along the other axis, ``cum`` down the queries,
 is a ``chunk x chunk`` transpose of its broadcast (``head_dim`` = ``chunk``
 = one lane tile: the same square scales the rows of ``[chunk, head_dim]``).
+Heads of 64 (``models/nemotron_h.py``) go TWO a lane tile
+(``_ssd_pair_kernel``): a pair's ``x``, ``y`` and states lie side by side
+in one tile of 128 lanes, each head's masked product and closing state are formed against the whole
+tile (the MXU is 128 wide: a product 64 wide would cost the same) and a
+select by lane keeps each head's half; ``C . S`` is ONE product for the
+pair. The states and ``D`` arrive and leave pair by pair (``[N, 2 x 64]``),
+paired and parted by XLA outside the call.
 
 Precision, both forms: float32 running sums, decays, masks, state and
 accumulation; the matmul operands (``x``, ``B``, ``C``, the masked scores,
@@ -63,9 +70,10 @@ def ssd_refusal(seq_len: int, head_dim: int, d_state: int, chunk: int,
                 num_heads: int, num_groups: int) -> Optional[str]:
     """Why the Pallas form does not take a shape, by name, or None where it
     does (the XLA form takes any)."""
-    if head_dim != LANES or chunk != LANES:
-        return (f"ssd_scan's kernel takes heads and chunks of {LANES} (one "
-                f"lane tile each): head_dim {head_dim}, chunk {chunk}")
+    if head_dim not in (LANES, LANES // 2) or chunk != LANES:
+        return (f"ssd_scan's kernel takes heads of {LANES} or {LANES // 2} "
+                f"(one or two a lane tile) and chunks of {LANES}: head_dim "
+                f"{head_dim}, chunk {chunk}")
     if d_state < LANES or d_state % LANES:
         return (f"ssd_scan's kernel takes a state of whole lane tiles of "
                 f"{LANES}: d_state {d_state}")
@@ -145,18 +153,18 @@ def _ssd_xla(x, dt, a, B, C, D, chunk: int, initial_state
     return y, final.reshape(b, h, n, p)
 
 
-def _ssd_kernel(*refs, heads: int, carried: bool):
-    """One (row, group, chunk) step: ``heads`` heads of one group over one
-    chunk of one row, the group's states in ``state`` (scratch) since the
-    row's first chunk."""
+def _open_step(refs, carried: bool):
+    """What both kernel bodies do ahead of their loop over a group's heads:
+    the refs by name, the group's states set on a row's first chunk (to
+    ``s0_ref``'s or zero), ``B`` (transposed) and ``C`` of the chunk, ``C
+    B^T`` and the causal mask; and ``close()``, which hands the states out
+    on the row's last chunk."""
     refs = list(refs)
     x_ref, bt_ref, c_ref, dt_ref, cum_ref, d_ref = (refs.pop(0)
                                                     for _ in range(6))
     s0_ref = refs.pop(0) if carried else None
     y_ref, final_ref, state = refs
     chunk_i, chunks = pl.program_id(2), pl.num_programs(2)
-    lanes = LANES
-    operand = x_ref.dtype
 
     @pl.when(chunk_i == 0)
     def _first_chunk():
@@ -164,8 +172,26 @@ def _ssd_kernel(*refs, heads: int, carried: bool):
 
     b_t, c_m = bt_ref[0], c_ref[0]                  # [N, L] and [L, N]
     scores = jnp.dot(c_m, b_t, preferred_element_type=jnp.float32)  # [L, L]
-    causal = (jax.lax.broadcasted_iota(jnp.int32, (lanes, lanes), 1)
-              <= jax.lax.broadcasted_iota(jnp.int32, (lanes, lanes), 0))
+    causal = (jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
+              <= jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0))
+
+    def close():
+        @pl.when(chunk_i == chunks - 1)
+        def _last_chunk():
+            final_ref[0] = state[...]
+
+    return (x_ref, dt_ref, cum_ref, d_ref, y_ref, state, b_t, c_m, scores,
+            causal, close)
+
+
+def _ssd_kernel(*refs, heads: int, carried: bool):
+    """One (row, group, chunk) step: ``heads`` heads of one group over one
+    chunk of one row, the group's states in ``state`` (scratch) since the
+    row's first chunk."""
+    (x_ref, dt_ref, cum_ref, d_ref, y_ref, state, b_t, c_m, scores, causal,
+     close) = _open_step(refs, carried)
+    lanes = LANES
+    operand = x_ref.dtype
 
     # unrolled where it is lowered, not in Python: the body is traced once
     # (``ops/attention._whole_row_kernel`` says what a Python loop cost)
@@ -195,10 +221,62 @@ def _ssd_kernel(*refs, heads: int, carried: bool):
         return carry
 
     jax.lax.fori_loop(0, heads, head, 0, unroll=True)
+    close()
 
-    @pl.when(chunk_i == chunks - 1)
-    def _last_chunk():
-        final_ref[0] = state[...]
+
+def _ssd_pair_kernel(*refs, heads: int, carried: bool):
+    """``_ssd_kernel``'s step for heads of 64, two a lane tile: ``heads``
+    heads of one group as ``heads / 2`` pairs, ``state`` ``[pairs, N, 128]``
+    (head ``2 j`` in a tile's first 64 lanes, ``2 j + 1`` in the rest), and
+    ``d_ref`` ``[1, pairs, 128]`` likewise."""
+    (x_ref, dt_ref, cum_ref, d_ref, y_ref, state, b_t, c_m, scores, causal,
+     close) = _open_step(refs, carried)
+    lanes = LANES
+    operand = x_ref.dtype
+
+    def halves(first, second):
+        # the pair's first head in a tile's first 64 lanes, the second's
+        # in the rest
+        return jnp.where(jax.lax.broadcasted_iota(
+            jnp.int32, first.shape, 1) < lanes // 2, first, second)
+
+    def pair(j, carry):
+        at = pl.ds(pl.multiple_of(j * lanes, lanes), lanes)
+        x = x_ref[0, :, at]                     # [L, 2 x 64]: both heads
+        s_in = state[j]                         # [N, 2 x 64]
+
+        def one(head):
+            # against the pair's whole tile: the other head's lanes are
+            # dropped by ``halves``
+            cum_s = jnp.broadcast_to(cum_ref[0, pl.ds(head, 1), :],
+                                     (lanes, lanes))
+            cum_t = cum_s.T
+            dt_s = dt_ref[0, pl.ds(head, 1), :]                   # [1, L]
+            decay = jnp.where(
+                causal, jnp.exp(jnp.where(causal, cum_t - cum_s, 0.0)), 0.0)
+            y = jnp.dot((scores * decay * dt_s).astype(operand), x,
+                        preferred_element_type=jnp.float32)
+            end = cum_t[lanes - 1:]                               # [1, L]
+            to_end = jnp.exp(end - cum_s[:1]) * dt_s
+            closing = jnp.dot(
+                (b_t.astype(jnp.float32) * to_end).astype(operand), x,
+                preferred_element_type=jnp.float32)
+            return y, jnp.exp(cum_t), jnp.exp(end), closing
+
+        first, second = one(2 * j), one(2 * j + 1)
+        y, since, whole, closing = (halves(a, b)
+                                    for a, b in zip(first, second))
+        # what the earlier chunks contribute, ONE product for the pair
+        y = y + since * jnp.dot(c_m, s_in.astype(operand),
+                                preferred_element_type=jnp.float32)
+        y_ref[0, :, at] = (
+            y + d_ref[0, pl.ds(j, 1), :] * x.astype(jnp.float32)
+        ).astype(y_ref.dtype)
+        state[j] = whole * s_in + closing
+        return carry
+
+    jax.lax.fori_loop(0, heads // 2, pair, 0, unroll=True)
+    close()
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -211,6 +289,11 @@ def _ssd_pallas(x, dt, a, B, C, D, initial_state, *, chunk: int,
     if refusal:
         raise ValueError(refusal)
     dt_, cum = _chunk_sums(dt, a, chunk)
+    # heads of 64 go two a lane tile: a group's heads are ``tiles`` tiles
+    # of ``LANES``, the states and D paired by XLA on the way in and parted
+    # on the way out (nothing where a head is a tile)
+    side = LANES // p
+    tiles = hg // side
     operands = [
         x.reshape(b, t, h * p),
         B.reshape(b, t, g * n).transpose(0, 2, 1),          # [B, G*N, T]
@@ -218,34 +301,48 @@ def _ssd_pallas(x, dt, a, B, C, D, initial_state, *, chunk: int,
         dt_, cum,
         jnp.broadcast_to(D.astype(jnp.float32)[:, None], (h, p)),
     ]
+    skip = pl.BlockSpec((hg, p), lambda i, k, c: (k, 0))
+    if side > 1:
+        # a group's pairs as a block's whole side: four pairs are no
+        # sublane tile
+        operands[-1] = operands[-1].reshape(g, tiles, LANES)
+        skip = pl.BlockSpec((1, tiles, LANES), lambda i, k, c: (k, 0, 0))
     rows = pl.BlockSpec((1, chunk, hg * p), lambda i, k, c: (i, c, k))
     per_head = pl.BlockSpec((1, hg, chunk), lambda i, k, c: (i, k, c))
-    states = pl.BlockSpec((1, hg, n, p), lambda i, k, c: (i, k, 0, 0))
+    states = pl.BlockSpec((1, tiles, n, LANES), lambda i, k, c: (i, k, 0, 0))
     in_specs = [
         rows,
         pl.BlockSpec((1, n, chunk), lambda i, k, c: (i, k, c)),
         pl.BlockSpec((1, chunk, n), lambda i, k, c: (i, c, k)),
         per_head, per_head,
-        pl.BlockSpec((hg, p), lambda i, k, c: (k, 0)),
+        skip,
     ]
     if initial_state is not None:
         in_specs.append(states)
-        operands.append(initial_state.astype(jnp.float32))
+        s0 = initial_state.astype(jnp.float32)
+        if side > 1:
+            s0 = s0.reshape(b, h // side, side, n, p).transpose(
+                0, 1, 3, 2, 4).reshape(b, h // side, n, LANES)
+        operands.append(s0)
     y, final = pl.pallas_call(
-        functools.partial(_ssd_kernel, heads=hg,
-                          carried=initial_state is not None),
+        functools.partial(_ssd_kernel if side == 1 else _ssd_pair_kernel,
+                          heads=hg, carried=initial_state is not None),
         name="ssd_scan",
         grid=(b, g, t // chunk),
         in_specs=in_specs,
         out_specs=(rows, states),
         out_shape=(jax.ShapeDtypeStruct((b, t, h * p), jnp.float32),
-                   jax.ShapeDtypeStruct((b, h, n, p), jnp.float32)),
-        scratch_shapes=[pltpu.VMEM((hg, n, p), jnp.float32)],
+                   jax.ShapeDtypeStruct((b, h // side, n, LANES),
+                                        jnp.float32)),
+        scratch_shapes=[pltpu.VMEM((tiles, n, LANES), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=48 << 20),
         interpret=interpret,
     )(*operands)
+    if side > 1:
+        final = final.reshape(b, h // side, n, side, p).transpose(
+            0, 1, 3, 2, 4).reshape(b, h, n, p)
     return y.reshape(b, t, h, p), final
 
 

@@ -38,6 +38,7 @@ from realtime_fraud_detection_tpu.models import (
     falcon_h1,
     joyai,
     laguna,
+    nemotron_h,
     olmoe,
     zaya,
 )
@@ -98,7 +99,8 @@ TEXT_ENCODERS: Dict[type, TextEncoder] = {
     row.config_class: row for row in (
         dataclasses.replace(bert.TEXT_ENCODER, predict=_bert_text_predict),
         olmoe.TEXT_ENCODER, zaya.TEXT_ENCODER, laguna.TEXT_ENCODER,
-        joyai.TEXT_ENCODER, falcon_h1.TEXT_ENCODER)}
+        joyai.TEXT_ENCODER, falcon_h1.TEXT_ENCODER,
+        nemotron_h.TEXT_ENCODER)}
 TextConfig = Union[tuple(TEXT_ENCODERS)]
 
 

@@ -60,6 +60,7 @@ def experts_through_both_forms():
     """``check(layer, top_k=, rung=, atol=, ...)``: one sparse layer's
     ``models.olmoe.apply_experts`` with its kernels asked for (Pallas,
     interpreted: the fused gate + up + SiLU kernel, down's, the combine)
+    (or, of a layer without a gate, ``relu2_gmm``)
     against its XLA form, at one of the two capacities of a launch of 4,096
     slots (``text_split.capacities``: rung 0 three quarters, 1 every slot) on
     random rows of which the first 2,800 are real (a rung's ``token_slots``),
@@ -81,7 +82,7 @@ def experts_through_both_forms():
     def check(layer, *, top_k, rung, atol, router_width=None,
               expert_offset=0):
         slots = capacities(4096)[rung]
-        held, hidden, wide = layer["gate_proj"].shape
+        held, hidden, wide = layer["up_proj"].shape
         assert grouped_matmul_supported(slots * top_k, hidden, wide)
         assert combine_supported(slots, top_k, hidden)
         rng = np.random.default_rng(0)
@@ -99,7 +100,8 @@ def experts_through_both_forms():
         np.testing.assert_array_equal(sizes, sizes_k)
         # the XLA form visits no tile; the kernel's grid whole row tiles,
         # at least the groups' rows and under a tile more a group
-        tm = gmm_tiling(slots * top_k, hidden, wide, held, gated=True)[0]
+        tm = gmm_tiling(slots * top_k, hidden, wide, held, gated=True,
+                        matrices=1 + ("gate_proj" in layer))[0]
         assert int(no_tiles) == 0 and int(tile_rows) % tm == 0
         assert 0 <= int(tile_rows) - int(sizes.sum()) < (
             2 * tm * np.count_nonzero(sizes))

@@ -864,3 +864,217 @@ def test_falconh1_weights_are_made_without_a_float32_copy(one_chip):
         _sds((2,), jnp.uint32, one_chip)).compile().memory_analysis()
     assert 7.8e9 < memory.output_size_in_bytes < 7.9e9
     assert memory.temp_size_in_bytes < 1 << 30
+
+
+# --------------------------------------------------------- Nemotron-3-Nano
+# an E layer's grouped calls: 8 x 2,048 slots at the 3/4 rung and at every
+# slot, and bucket 1's one launch, x 6 experts a token over 128 groups
+NEMOTRON_ROWS = (73728, 98304, 12288)
+
+
+@pytest.mark.parametrize("bucket", [8, 1])
+@pytest.mark.parametrize("carried", [False, True], ids=["from_zero",
+                                                        "state_in"])
+def test_ssd_scan_compiles_at_heads_of_64(one_chip, bucket, carried):
+    """Nemotron-3-Nano's state-space scan as the cell launches it: rows of
+    2,048 positions in chunks of 128, 64 heads of 64 — TWO a lane tile — in
+    eight groups of eight over a 128-wide state; ONE kernel, with and
+    without a state handed in (paired and parted outside the call)."""
+    from realtime_fraud_detection_tpu.models.nemotron_h import NemotronHConfig
+    from realtime_fraud_detection_tpu.ops.ssd_scan import ssd_scan
+
+    cfg, t = NemotronHConfig(), 2048
+    assert cfg.scan_refusal(t) is None
+    h, p, g, n = (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups,
+                  cfg.ssm_state_size)
+    assert (h, p, g, n) == (64, 64, 8, 128)
+    args = [_sds((bucket, t, h, p), jnp.bfloat16, one_chip),
+            _sds((bucket, t, h), jnp.float32, one_chip),
+            _sds((h,), jnp.float32, one_chip),
+            _sds((bucket, t, g, n), jnp.bfloat16, one_chip),
+            _sds((bucket, t, g, n), jnp.bfloat16, one_chip),
+            _sds((h,), jnp.float32, one_chip)]
+    if carried:
+        args.append(_sds((bucket, h, n, p), jnp.float32, one_chip))
+    compiled = jax.jit(lambda x, dt, a, b_in, c_in, d, state=None: ssd_scan(
+        x, dt, a, b_in, c_in, d, chunk=cfg.chunk_size,
+        initial_state=state, use_pallas=True)).lower(*args).compile()
+    assert compiled.as_text().count(CUSTOM_CALL) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < bucket * (48 << 20)
+
+
+@pytest.mark.parametrize("rows", NEMOTRON_ROWS,
+                         ids=["three_quarters", "every_slot", "bucket1"])
+@pytest.mark.parametrize("kernel", ["relu2", "down"])
+def test_the_ungated_experts_compile_at_the_published_1856(one_chip, kernel,
+                                                           rows):
+    """An expert 1,856 = 14 1/2 lane tiles wide, run AS PUBLISHED: the
+    width whole in one block as N of ``relu2_gmm`` (one right-hand block a
+    step, ``relu^2`` in the epilogue) and as K of ``down_gmm``, whose
+    result rows are ``hidden / 128`` = 21 lane tiles; ONE Mosaic call each
+    inside the budget it names, at a row tile of 128."""
+    from realtime_fraud_detection_tpu.ops.grouped_matmul import (
+        VMEM_CEILING,
+        down_vmem_bytes,
+        gated_vmem_bytes,
+        gmm_tiling,
+        grouped_matmul,
+        grouped_matmul_supported,
+        grouped_relu2_matmul,
+    )
+
+    hidden, width, groups = 2688, 1856, 128
+    weights = _sds((groups, hidden, width) if kernel == "relu2"
+                   else (groups, width, hidden), jnp.bfloat16, one_chip)
+    sizes = _sds((groups,), jnp.int32, one_chip)
+    if kernel == "relu2":
+        assert grouped_matmul_supported(rows, hidden, width)
+        tiling = gmm_tiling(rows, hidden, width, groups, gated=True,
+                            matrices=1)
+        assert tiling == (128, hidden, width)
+        assert gated_vmem_bytes(*tiling, matrices=1) <= VMEM_CEILING
+        text = jax.jit(lambda x, w, g: grouped_relu2_matmul(
+            x, w, g, out_dtype=jnp.bfloat16, use_pallas=True)).lower(
+            _sds((rows, hidden), jnp.bfloat16, one_chip), weights,
+            sizes).compile().as_text()
+        assert "jit(relu2_gmm)/relu2_gmm/pallas_call" in text
+        assert f"f32[{rows},{width}]" not in text
+        assert f"bf16[{rows},{width}]" in text
+    else:
+        assert grouped_matmul_supported(rows, width, hidden)
+        tiling = gmm_tiling(rows, width, hidden, groups)
+        assert tiling == (128, width, hidden)
+        assert down_vmem_bytes(*tiling) <= VMEM_CEILING
+        text = jax.jit(lambda x, w, g: grouped_matmul(
+            x, w, g, use_pallas=True)).lower(
+            _sds((rows, width), jnp.bfloat16, one_chip), weights,
+            sizes).compile().as_text()
+        assert "jit(down_gmm)/down_gmm/pallas_call" in text
+        assert f"f32[{rows},21,128]" in text
+        assert f"f32[{rows},{hidden}]" not in text
+    assert text.count(CUSTOM_CALL) == 1
+
+
+@pytest.mark.parametrize("rows", NEMOTRON_ROWS,
+                         ids=["three_quarters", "every_slot", "bucket1"])
+def test_the_combine_compiles_at_rows_of_21_lane_tiles(one_chip, rows):
+    """``hidden`` 2,688 = 21 lane tiles, not a whole sublane tile of them:
+    a fetched row lands ``21 n`` sublanes into its plane. ONE Mosaic call
+    at 128 tokens a step, inside the budget the call names."""
+    from realtime_fraud_detection_tpu.ops.combine import (
+        combine_supported,
+        combine_tokens,
+        combine_vmem_bytes,
+        weighted_combine,
+    )
+    from realtime_fraud_detection_tpu.ops.grouped_matmul import VMEM_CEILING
+
+    hidden, top_k = 2688, 6
+    tokens = rows // top_k
+    assert combine_supported(tokens, top_k, hidden)
+    assert combine_tokens(tokens, top_k, hidden) == 128
+    assert combine_vmem_bytes(128, top_k, hidden) <= VMEM_CEILING
+    text = jax.jit(lambda out3, home, weights, valid: weighted_combine(
+        out3, home, weights, valid, use_pallas=True)).lower(
+        _sds((rows, 21, 128), jnp.float32, one_chip),
+        _sds((tokens, top_k), jnp.int32, one_chip),
+        _sds((tokens, top_k), jnp.float32, one_chip),
+        _sds((tokens, top_k), jnp.bool_, one_chip)).compile().as_text()
+    assert text.count(CUSTOM_CALL) == 1
+    assert "jit(combine_rows)/weighted_combine/pallas_call" in text
+    assert f"f32[{rows},{hidden}]" not in text
+
+
+@pytest.mark.parametrize("bucket", [8, 1])
+def test_windowed_attention_compiles_with_no_rotation(one_chip, bucket):
+    """Nemotron-3-Nano's ``*`` layer: 32 query heads over 2 key-value heads
+    of 128 (sixteen heads a step), no window, no gate and NO tables — q and
+    k go to the contraction as their projections wrote them, bfloat16."""
+    from realtime_fraud_detection_tpu.models.nemotron_h import NemotronHConfig
+    from realtime_fraud_detection_tpu.ops import windowed_attention
+
+    cfg, t = NemotronHConfig(), 2048
+    assert cfg.core_refusal(t) is None
+    heads, kv = cfg.num_attention_heads, cfg.num_key_value_heads
+    fn = jax.jit(lambda q, k, v, lens: windowed_attention(
+        q, k, v, lens, num_heads=heads, num_kv_heads=kv,
+        out_dtype=jnp.bfloat16))
+    narrow = _sds((bucket, t, kv * 128), jnp.bfloat16, one_chip)
+    compiled = fn.lower(
+        _sds((bucket, t, heads * 128), jnp.bfloat16, one_chip), narrow,
+        narrow, _sds((bucket,), jnp.int32, one_chip)).compile()
+    assert compiled.as_text().count(CUSTOM_CALL) == 1
+
+
+@pytest.mark.parametrize("bucket,capacity", [(8, 12288), (8, None),
+                                             (1, None)],
+                         ids=["three_quarters", "every_slot", "bucket1"])
+def test_nemotron3_program_compiles_with_a_kernel_for_every_kind(
+        one_chip, bucket, capacity):
+    """The served packed program with a ``NemotronHConfig``: one layer of
+    each KIND (``ME*``), every width as published, bucket 8 x 2,048 tokens at
+    both capacities and bucket 1: the scan's pair kernel, the ungated
+    grouped call, down's, the combine and the fused causal core — five
+    Mosaic calls — a second small output, no conditional, no float32
+    ``[pairs, 2688]`` array, and temporaries that leave room for the cell's
+    11.44 GB of weights in 16 GB."""
+    from realtime_fraud_detection_tpu.core.packing import pack_tree
+    from realtime_fraud_detection_tpu.ensemble.combine import EnsembleParams
+    from realtime_fraud_detection_tpu.models.nemotron_h import NemotronHConfig
+    from realtime_fraud_detection_tpu.scoring.pipeline import (
+        MODEL_NAMES,
+        ScorerConfig,
+        init_scoring_models,
+        make_example_batch,
+        score_fused_packed,
+    )
+    from realtime_fraud_detection_tpu.utils.config import Config
+
+    config = NemotronHConfig(num_hidden_layers=3,
+                             hybrid_override_pattern="ME*")
+    models = jax.eval_shape(
+        lambda key: init_scoring_models(key, bert_config=config),
+        jax.random.PRNGKey(0))
+    blobs, spec = pack_tree(make_example_batch(
+        bucket, ScorerConfig(text_len=2048)))
+    compiled = score_fused_packed.lower(
+        _shapes_of(models, one_chip),
+        *(_shapes_of(blobs[k], one_chip) for k in ("f32", "i32", "u8")),
+        spec=spec,
+        params=EnsembleParams.from_config(Config(), list(MODEL_NAMES)),
+        model_valid=_sds((len(MODEL_NAMES),), jnp.bool_, one_chip),
+        blob_bf16=_shapes_of(blobs["bf16"], one_chip), bert_config=config,
+        use_pallas=True, text_capacity=capacity).compile()
+    text = compiled.as_text()
+    assert text.count(CUSTOM_CALL) == 5
+    for call in ("ssm_scan/jit(_ssd_pallas)/ssd_scan",
+                 "attn_core/jit(windowed_attention)/windowed_attention",
+                 "jit(relu2_gmm)/relu2_gmm", "jit(down_gmm)/down_gmm",
+                 "jit(combine_rows)/weighted_combine"):
+        assert f"{call}/pallas_call" in text, call
+    assert "gated_gmm/pallas_call" not in text
+    pairs = (capacity or bucket * 2048) * 6
+    assert f"f32[{pairs},2688]" not in text
+    assert f"f32[{pairs},21,128]" in text
+    assert " conditional(" not in text and "cond/branch_" not in text
+    assert f"f32[{bucket},32,2048,2048]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
+
+
+def test_nemotron3_weights_are_made_without_a_float32_copy(one_chip):
+    """The builder's one jitted init at the cell's nine layers: 11.44 GB of
+    arguments (5.72 B parameters, bfloat16 but for the norms, the
+    convolutions, the mixers' vectors, the routers' biases and the head),
+    drawn tensor by tensor with no float32 copy standing beside them."""
+    from realtime_fraud_detection_tpu.models.nemotron_h import NemotronHConfig
+    from realtime_fraud_detection_tpu.scoring.pipeline import (
+        init_scoring_models,
+    )
+
+    config = NemotronHConfig(num_hidden_layers=9,
+                             hybrid_override_pattern="MEMEM*EME")
+    memory = jax.jit(
+        lambda key: init_scoring_models(key, bert_config=config)).lower(
+        _sds((2,), jnp.uint32, one_chip)).compile().memory_analysis()
+    assert 11.40e9 < memory.output_size_in_bytes < 11.48e9
+    assert memory.temp_size_in_bytes < 1 << 30

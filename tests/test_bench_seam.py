@@ -38,6 +38,21 @@ from test_falconh1_reference import (  # noqa: E402,F401
     test_the_reference_refuses_what_its_equations_do_not_hold,
     test_the_text_column_costs_three_compilations,
 )
+# PR 50's reference against the program, its lowering seam by site and its
+# four compilations, and its control at TINY: collected here as they stand
+from test_nemotron3_control import (  # noqa: E402,F401
+    nemotron3_cell,
+    test_nemotron3_float8_operands_read_further_than_the_program,
+    test_nemotron3_sound_only_and_reference_only_leave_their_halves_out,
+)
+from test_nemotron3_reference import (  # noqa: E402,F401
+    test_nemotron3_lowering_seam_reaches_the_site_it_is_told,
+    test_nemotron3_reference_imports_nothing_from_the_package,
+    test_nemotron3_reference_refuses_what_its_equations_do_not_hold,
+    test_nemotron3_score_composes_the_branches_and_reads_the_file,
+    test_nemotron3_text_branch_is_the_programs_at_float32,
+    test_nemotron3_text_column_costs_four_compilations,
+)
 from test_setup_metrics import (  # noqa: E402,F401
     test_a_ledger_that_let_records_go_reads_none,
     test_a_program_without_the_ledger_reads_none,
@@ -72,6 +87,14 @@ ROUTED_CELLS = [OLMOE_CELL, ZAYA_CELL, FULL_CELL, LAGUNA_CELL,
 FALCON_CELL = "falconh1-s2048-remit-saturated"
 FALCON_CFG = json.loads(
     (ROOT / "benchmarks/configs/falcon-h1-34b-s2048.json").read_text())
+# PR 50: Nemotron-3-Nano's cell, layers of ONE mixer each (routed AND
+# state-space), on the same traffic file
+NEMOTRON_CELL = "nemotron3-s2048-remit-saturated"
+NEMOTRON_CFG = json.loads(
+    (ROOT / "benchmarks/configs/nemotron-3-nano-30b-s2048.json").read_text())
+NEMOTRON_ONLY = ["nemotron3_ssd_scan_roofline_pct",
+                 "nemotron3_expert_ffn_roofline_pct",
+                 "nemotron3_attn_core_roofline_pct"]
 
 
 # ------------------------------------------------------ configuration files
@@ -335,16 +358,150 @@ def test_the_falconh1_file_is_the_sources_config_cut_in_depth_only():
             {**FALCON_CFG, "mamba_norm_before_gate": True})
 
 
+def test_the_nemotron3_file_is_the_sources_config_cut_in_depth_only():
+    from realtime_fraud_detection_tpu.models.nemotron_h import (
+        PUBLISHED_PATTERN,
+        NemotronHConfig,
+    )
+
+    builder = spec.builder(NEMOTRON_CFG)
+    built = builder.nemotron3_config(NEMOTRON_CFG)
+    assert NEMOTRON_CFG["reduced"] == ["num_hidden_layers",
+                                       "hybrid_override_pattern"]
+    # the class's defaults are the published values: the cell runs the
+    # first nine characters of the published pattern and nothing else cut
+    assert built == NemotronHConfig(num_hidden_layers=9,
+                                    hybrid_override_pattern="MEMEM*EME")
+    assert NEMOTRON_CFG["published"]["num_hidden_layers"] == 52
+    assert NEMOTRON_CFG["published"]["hybrid_override_pattern"] \
+        == PUBLISHED_PATTERN
+    assert PUBLISHED_PATTERN.startswith(built.hybrid_override_pattern)
+    assert (built.num_ssm_layers, built.num_sparse_layers,
+            built.layer_kinds.count("*")) == (4, 4, 1)
+    # every key of the catalog row's config, under its own name, at its
+    # published value but for the two of the cut — in the file AND the class
+    catalog = Path("/opt/skills/guides/model-configs/architectures.jsonl")
+    row = json.loads([
+        line for line in catalog.read_text().splitlines()
+        if '"NVIDIA-Nemotron-3-Nano-30B-A3B-BF16"' in line][0]) \
+        if catalog.is_file() else {"config": NEMOTRON_CFG["published"],
+                                   "source_url": NEMOTRON_CFG["source"]}
+    assert NEMOTRON_CFG["source"] == row["source_url"]
+    assert NEMOTRON_CFG["published"] == row["config"]
+    cut = {"num_hidden_layers": 9, "hybrid_override_pattern": "MEMEM*EME"}
+    for key, value in row["config"].items():
+        want = cut.get(key, value)
+        assert NEMOTRON_CFG[key] == want, key
+        assert getattr(built, key) == want, key
+    # every width as published
+    assert (built.hidden_size, built.head_dim, built.num_attention_heads,
+            built.num_key_value_heads, built.vocab_size) == (
+        2688, 128, 32, 2, 131072)
+    assert (built.mamba_num_heads, built.mamba_head_dim, built.d_inner,
+            built.ssm_state_size, built.n_groups, built.conv_kernel,
+            built.chunk_size, built.conv_dim, built.in_proj_dim) == (
+        64, 64, 4096, 128, 8, 4, 128, 6144, 10304)
+    assert (built.n_routed_experts, built.num_experts_per_tok,
+            built.moe_intermediate_size, built.routed_scaling_factor,
+            built.moe_shared_expert_intermediate_size) == (
+        128, 6, 1856, 2.5, 3712)
+    assert built.core_refusal(2048) is None is built.scan_refusal(2048)
+    assert NEMOTRON_CFG["text_len"] == 2048 and NEMOTRON_CFG["chips"] == 1
+    assert NEMOTRON_CFG["job"]["max_batch"] == 8
+    assert NEMOTRON_CFG["parity_rows"] == 4
+    for key in ("cut", "deployment", "not_run", "assumed", "compute_dtype",
+                "guarantee", "parity_atol_from", "job_from"):
+        assert NEMOTRON_CFG[key] and "TO BE WRITTEN" not in json.dumps(
+            NEMOTRON_CFG[key]), key
+    assert "six pipeline stages" in NEMOTRON_CFG["deployment"].lower()
+    assert set(NEMOTRON_CFG["not_run"]) == {
+        "lm_head", "num_logits_to_keep", "rope_theta",
+        "partial_rotary_factor", "use_mamba_kernels",
+        "rescale_prenorm_residual", "time_step_min", "time_step_max",
+        "time_step_floor"}
+    for item in ("equations", "head", "weights", "tokenizer", "traffic"):
+        assert NEMOTRON_CFG["assumed"][item], item
+    assert "ASSUMED" in NEMOTRON_CFG["assumed"]["equations"]
+    assert "residual" in NEMOTRON_CFG["compute_dtype"]["text_branch"]
+    # the traffic file is Laguna's, JoyAI's and Falcon-H1's, byte for byte
+    assert spec.cell(NEMOTRON_CELL)["traffic"] \
+        == spec.cell(FALCON_CELL)["traffic"] == "s2048-remit-saturated"
+    tiny = builder.nemotron3_config({**NEMOTRON_CFG, **builder.TINY})
+    # the odd shapes stay odd: heads of 64 in groups of 8, an expert width
+    # and a hidden size of no whole lane or sublane tiles, sixteen query
+    # heads a key-value head, four chunks in a rehearsal's 128 positions
+    assert tiny.hidden_size < 512 and tiny.hidden_size // 128 % 8
+    assert tiny.mamba_head_dim == 64
+    assert tiny.mamba_num_heads // tiny.n_groups == 8
+    assert tiny.moe_intermediate_size % 128 and tiny.moe_intermediate_size \
+        % 16 == 0
+    assert tiny.num_attention_heads // tiny.num_key_value_heads == 16
+    assert 128 // tiny.chunk_size == 4
+    with pytest.raises(ValueError, match="attention_bias"):
+        builder.nemotron3_config({**NEMOTRON_CFG, "attention_bias": True})
+    with pytest.raises(ValueError, match="tied embeddings"):
+        builder.nemotron3_config(
+            {**NEMOTRON_CFG, "tie_word_embeddings": True})
+    with pytest.raises(ValueError, match="dense MLP layer"):
+        builder.nemotron3_config(
+            {**NEMOTRON_CFG, "hybrid_override_pattern": "MEMEM-EME"})
+
+
+def _before_pr50():
+    """``BENCHMARK.json`` with what PR 50 appended checked BY NAME and taken
+    off: one configuration, one cell, the cell's name at the end of the
+    ``workloads`` list of every metric it reports, and three metrics of its
+    own at the end of ``per_layer``. What is left is the benchmark PR 48
+    left, which the asserts below count from the end of."""
+    bm = json.loads(json.dumps(BM))
+    assert bm["configs"].pop()["name"] == "nemotron-3-nano-30b-s2048"
+    assert bm["workloads"].pop() == {
+        "name": NEMOTRON_CELL, "config": "nemotron-3-nano-30b-s2048",
+        "traffic": "s2048-remit-saturated", "chips": 1,
+        "why": BM["workloads"][-1]["why"]}
+    own = [bm["per_layer"].pop() for _ in NEMOTRON_ONLY][::-1]
+    assert [m["name"] for m in own] == NEMOTRON_ONLY
+    for m in own:
+        assert m == {"name": m["name"], "unit": "%", "better": "higher",
+                     "source": "device_trace", "layer": "kernels",
+                     "moves": "txn_per_s", "workloads": [NEMOTRON_CELL]}
+    listed = set()
+    for m in bm["per_layer"] + bm["end_to_end"]:
+        if NEMOTRON_CELL in m.get("workloads", ()):
+            assert m["workloads"].pop() == NEMOTRON_CELL, m["name"]
+            assert NEMOTRON_CELL not in m["workloads"]
+            listed.add(m["name"])
+    assert listed | set(NEMOTRON_ONLY) | {"setup_s"} == {
+        m["name"] for kind in ("end_to_end", "per_layer")
+        for m in spec.metrics_for(NEMOTRON_CELL, kind)}
+    return bm, listed
+
+
 def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
-    cells = [w["name"] for w in BM["workloads"]]
+    bm, nemotron_lists = _before_pr50()
+    # routed AND state-space: what every cell reports, the routed cells'
+    # shared names (with the shared expert's), and Falcon-H1's three times
+    # of the mixer; no dense MLP's, no other configuration's own
+    every = {m["name"] for m in BM["per_layer"]
+             if len(m["workloads"]) == len(BM["workloads"])}
+    assert nemotron_lists == every | {"txn_per_s"} | {
+        "expert_ffn_ms_per_batch", "expert_matmul_ms_per_batch",
+        "expert_combine_ms_per_batch", "router_ms_per_batch",
+        "shared_expert_ms_per_batch", "expert_imbalance_x",
+        "expert_tile_fill_pct", "compact_batches_pct",
+        "ssm_proj_ms_per_batch", "ssm_conv_ms_per_batch",
+        "ssm_scan_ms_per_batch"}
+    assert {"attn_core_ms_per_batch", "attn_proj_ms_per_batch",
+            "ln_ms_per_batch", "setup_programs", "hbm_peak_gb"} <= every
+    cells = [w["name"] for w in bm["workloads"]]
     # the routed cells from the end: a DistilBERT cell parked or moved back
     # ahead of them (PR 42 parked longtail) shifts no index here
     assert cells[-7:] == ROUTED_CELLS + [FALCON_CELL]
     n_cells, n_routed = len(cells), len(ROUTED_CELLS)
     n_distilbert = n_cells - n_routed - 1
     assert n_distilbert == len(
-        [w for w in BM["workloads"] if w["config"].startswith("distilbert")])
-    by_name = {w["name"]: w for w in BM["workloads"]}
+        [w for w in bm["workloads"] if w["config"].startswith("distilbert")])
+    by_name = {w["name"]: w for w in bm["workloads"]}
     assert (by_name[OLMOE_CELL]["config"], by_name[OLMOE_CELL]["traffic"]
             ) == ("olmoe-1b-7b-s128", "s128-memo-saturated")
     assert (by_name[ZAYA_CELL]["config"], by_name[ZAYA_CELL]["traffic"],
@@ -397,8 +554,8 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
                    "ssm_scan_ms_per_batch", "falconh1_ssd_scan_roofline_pct",
                    "falconh1_attn_core_roofline_pct",
                    "falconh1_ffn_roofline_pct"]
-    assert [m["name"] for m in BM["per_layer"][-8:-2]] == falcon_only
-    for m in BM["per_layer"][-8:-2]:
+    assert [m["name"] for m in bm["per_layer"][-8:-2]] == falcon_only
+    for m in bm["per_layer"][-8:-2]:
         assert m["workloads"] == [FALCON_CELL] and m["layer"] == "kernels"
         assert m["moves"] == "txn_per_s" and m["source"] == "device_trace"
         assert (m["unit"], m["better"]) == (
@@ -406,7 +563,7 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
             else ("ms", "lower"))
     # PR 47 appended one behind those: a counter's share of the six routed
     # cells, a data file over a reader the benchmark had
-    fill = BM["per_layer"][-2]
+    fill = bm["per_layer"][-2]
     assert fill == {
         "name": "expert_tile_fill_pct", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "kernels",
@@ -418,7 +575,7 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
     # PR 48 one more: the way home's device time in the six routed cells,
     # so that "rows moved" is a scope's time and no subtraction; a data
     # file over a reader the benchmark had, a scope the parent has too
-    assert BM["per_layer"][-1] == {
+    assert bm["per_layer"][-1] == {
         "name": "expert_combine_ms_per_batch", "unit": "ms",
         "better": "lower", "source": "device_trace", "layer": "kernels",
         "moves": "txn_per_s", "workloads": ROUTED_CELLS}
@@ -433,7 +590,7 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
                       for m in spec.metrics_for(FALCON_CELL, "per_layer")}
     # a dense encoder: what every cell reports, the dense MLP's time, and
     # its own six; no expert's, no router's, neither launch rule's share
-    every_cell = {m["name"] for m in BM["per_layer"]
+    every_cell = {m["name"] for m in bm["per_layer"]
                   if len(m["workloads"]) == n_cells}
     assert falcon_reports == (every_cell | {"ffn_ms_per_batch"}
                               | set(falcon_only))
@@ -441,18 +598,18 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
         n for n in falcon_reports if n.startswith(("expert_", "router_"))}
     assert not {"compact_batches_pct", "split_batches_pct",
                 "ffn_roofline_pct", "attn_core_roofline_pct"} & falcon_reports
-    for m in BM["per_layer"] + BM["end_to_end"]:
+    for m in bm["per_layer"] + bm["end_to_end"]:
         if FALCON_CELL in m.get("workloads", ()):
             assert m["workloads"][-1] == FALCON_CELL, m["name"]
-    assert [c["name"] for c in BM["configs"]][-2:] == [
+    assert [c["name"] for c in bm["configs"]][-2:] == [
         "joyai-llm-flash-s2048", "falcon-h1-34b-s2048"]
-    for m in BM["per_layer"][-12:-8]:
+    for m in bm["per_layer"][-12:-8]:
         # PR 43 appended its four behind what was there
         assert m["name"] in joyai_only and m["moves"] == "txn_per_s"
         assert m["workloads"] == [JOYAI_CELL] and m["layer"] == "kernels"
-    assert len(cells) == 8 and not [w for w in BM["workloads"]
+    assert len(cells) == 8 and not [w for w in bm["workloads"]
                                     if w["chips"] != 1]
-    per_layer = BM["per_layer"][:-12]    # the checks below: what PR 42 left
+    per_layer = bm["per_layer"][:-12]    # the checks below: what PR 42 left
     # ZAYA1's second cell reports exactly what its first does
     assert reports[ZAYA_FULL_CELL] == reports[ZAYA_CELL]
     # Laguna's: the shared names, its dense layer 0's, and its own six
@@ -473,7 +630,7 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
         ("ln_ms_per_batch", "txn_per_s", n_cells),
         ("split_batches_pct", "txn_per_s", n_distilbert),
         ("compact_batches_pct", "txn_per_s", n_routed)]
-    assert [m["name"] for m in BM["per_layer"] if m["moves"] == "setup_s"
+    assert [m["name"] for m in bm["per_layer"] if m["moves"] == "setup_s"
             ] == ["compile_setup_s", "trace_lower_setup_s", "setup_programs"]
     assert "compact_batches_pct" in reports[OLMOE_CELL] \
         and "split_batches_pct" not in reports[OLMOE_CELL]
@@ -1049,12 +1206,159 @@ def test_the_falconh1_metrics_on_a_hand_made_run():
                            "per_layer")(counted) is None
 
 
+def test_nemotron3_matmul_flops_follow_the_layers_by_kind():
+    builder = spec.builder(NEMOTRON_CFG)
+    parts = builder.text_matmul_flops_per_row(NEMOTRON_CFG)
+    t, h = 2048, 2688
+    # each part times the layers of ITS kind: 4 M, 4 E, 1 *
+    assert parts["ssm_proj"] == 4 * 2.0 * t * h * (10304 + 4096)
+    assert parts["attn_proj"] == 1 * 2.0 * t * h * 128 * (2 * 32 + 2 * 2)
+    assert parts["cores"] == 1 * 4.0 * 32 * 128 * (t * (t + 1) // 2)
+    assert parts["router"] == 4 * 2.0 * t * h * 128
+    # two matrices an expert: no gate
+    assert parts["experts"] == 4 * 4.0 * t * h * 1856 * 6
+    assert parts["shared_expert"] == 4 * 4.0 * t * h * 3712
+    # ISSUE 50's arithmetic: 3.41 MFLOP a slot and layer in the scan
+    per_slot = spec.kernel("nemotron3_ssd_scan").flops_per_slot(NEMOTRON_CFG)
+    assert per_slot == pytest.approx(3.41e6, rel=2e-3)
+    assert parts["ssm_scan"] == 4 * t * per_slot
+    # ~1.0 GFLOP a token outside the core: the experts (routed and shared)
+    # 63%, the mixers' projections and scans 32%
+    rest = {k: v for k, v in parts.items() if k != "cores"}
+    whole = sum(rest.values())
+    assert whole / t == pytest.approx(1.01e9, rel=0.02)
+    assert (parts["experts"] + parts["shared_expert"]) / whole \
+        == pytest.approx(0.63, abs=0.02)
+    assert (parts["ssm_proj"] + parts["ssm_scan"]) / whole \
+        == pytest.approx(0.32, abs=0.02)
+    total = builder.matmul_flops_per_batch(NEMOTRON_CFG)
+    assert 0.99 < 8 * sum(parts.values()) / total <= 1.0
+
+
+def test_nemotron3_kernels_charge_what_the_program_counted():
+    slots = 3 * 8 * 2048
+    counters = {"batches": 3, "token_slots": slots,
+                "attn_visible_pairs_full": 20_000_000,
+                "attn_visible_pairs_sliding": 0,
+                "ssm_chunks": slots // 128 * 4,
+                "expert_rows": 3 * 4 * 60_000, "routed_pairs": 3 * 4 * 60_000}
+    scan = spec.kernel("nemotron3_ssd_scan").work(counters, NEMOTRON_CFG)
+    # every launched slot of the four M layers: C B^T a group, the masked
+    # product and the two state products a head of 64
+    assert scan["flops"] == slots * 4 * (
+        2 * 128 * 128 * 8 + 2 * 128 * 64 * 64 + 2 * 2 * 128 * 64 * 64)
+    # x, B, C read in bfloat16, dt in float32, y written in float32
+    assert scan["hbm_bytes"] == slots * 4 * (
+        (4096 + 2048) * 2 + (64 + 4096) * 4)
+    assert scan["hbm_bytes"] / (slots * 4) == 28_928
+    # ~118 FLOP a byte: half the v5e's ridge of 240, so the metric file
+    # names the HBM's rate
+    assert 110 < scan["flops"] / scan["hbm_bytes"] < 125
+    metrics = ROOT / "benchmarks/layer_metrics"
+    assert json.loads((metrics / "nemotron3_ssd_scan_roofline_pct.json"
+                       ).read_text())["args"]["peak"] == "hbm_bytes_per_s"
+    experts = spec.kernel("nemotron3_expert_ffn").work(counters, NEMOTRON_CFG)
+    # up and down of every routed row at the PUBLISHED 1,856, whatever the
+    # kernels pad it to
+    assert experts["flops"] == 4 * 3 * 4 * 60_000 * 2688 * 1856
+    assert experts["hbm_bytes"] == (
+        3 * 4 * 128 * 2 * 2688 * 1856 * 2 + 3 * 4 * 60_000 * 2688 * 6)
+    # ~340 FLOP a byte at ~470 rows an expert: over the ridge, so the metric
+    # file names no peak (the reader's default is the bf16 peak)
+    assert 300 < experts["flops"] / experts["hbm_bytes"] < 380
+    assert "peak" not in json.loads(
+        (metrics / "nemotron3_expert_ffn_roofline_pct.json").read_text()
+    )["args"]
+    core = spec.kernel("nemotron3_attn_core").work(counters, NEMOTRON_CFG)
+    # one causal layer's pairs x the ONE * layer of the nine
+    assert core["flops"] == 4 * 128 * 32 * 1 * 20_000_000
+    assert core["hbm_bytes"] == 1 * slots * (2 * 32 + 2 * 2) * 128 * 2
+    for kernel in ("nemotron3_ssd_scan", "nemotron3_expert_ffn",
+                   "nemotron3_attn_core"):
+        none = spec.kernel(kernel).work({"batches": 3}, NEMOTRON_CFG)
+        assert none == {"flops": 0.0, "hbm_bytes": 0.0}, kernel
+
+
+def test_the_nemotron3_metrics_on_a_hand_made_run():
+    slots = 2 * 8 * 2048
+    counters = {"batches": 2, "scored": 16, "token_slots": slots,
+                "real_tokens": 20_000, "ssm_chunks": slots // 128 * 4,
+                "attn_visible_pairs_full": 14_000_000,
+                "attn_visible_pairs_sliding": 0,
+                "routed_pairs": 480_000, "expert_rows": 480_000,
+                "expert_peak_rows": 600_000, "expert_tile_rows": 560_000,
+                "expert_token_slots": 2 * 12288, "compact_batches": 2}
+    scope_s = {"text": 0.32}
+    for i, kind in enumerate("MEMEM*EME"):
+        scope_s[f"text/layer{i}/ln"] = 0.0004
+        if kind == "M":
+            scope_s.update({f"text/layer{i}/ssm_proj": 0.020,
+                            f"text/layer{i}/ssm_conv": 0.008,
+                            f"text/layer{i}/ssm_scan": 0.004})
+        elif kind == "*":
+            scope_s.update({f"text/layer{i}/attn_proj": 0.010,
+                            f"text/layer{i}/attn_core": 0.004})
+        else:
+            scope_s.update({f"text/layer{i}/router": 0.004,
+                            f"text/layer{i}/experts": 0.027,
+                            f"text/layer{i}/experts/dispatch": 0.003,
+                            f"text/layer{i}/experts/matmul": 0.020,
+                            f"text/layer{i}/experts/combine": 0.004,
+                            f"text/layer{i}/shared_expert": 0.007})
+    run = _fake_run(scope_s, counters, NEMOTRON_CFG)
+
+    def metric(name):
+        return spec.reader_for(name, "per_layer")(run)
+
+    assert metric("ssm_proj_ms_per_batch") == pytest.approx(40.0)
+    assert metric("ssm_conv_ms_per_batch") == pytest.approx(16.0)
+    assert metric("ssm_scan_ms_per_batch") == pytest.approx(8.0)
+    assert metric("attn_core_ms_per_batch") == pytest.approx(2.0)
+    assert metric("attn_proj_ms_per_batch") == pytest.approx(5.0)
+    assert metric("ln_ms_per_batch") == pytest.approx(1.8)
+    assert metric("router_ms_per_batch") == pytest.approx(8.0)
+    assert metric("expert_matmul_ms_per_batch") == pytest.approx(40.0)
+    assert metric("expert_combine_ms_per_batch") == pytest.approx(8.0)
+    assert metric("shared_expert_ms_per_batch") == pytest.approx(14.0)
+    assert metric("expert_ffn_ms_per_batch") == pytest.approx(54.0)
+    assert metric("expert_imbalance_x") == pytest.approx(1.25)
+    assert metric("expert_tile_fill_pct") == pytest.approx(100 * 48 / 56)
+    assert metric("compact_batches_pct") == pytest.approx(100.0)
+    for name, kernel, quantity, peak, seconds in (
+            ("nemotron3_ssd_scan_roofline_pct", "nemotron3_ssd_scan",
+             "hbm_bytes", 819e9, 0.016),
+            ("nemotron3_expert_ffn_roofline_pct", "nemotron3_expert_ffn",
+             "flops", 197e12, 0.080),
+            ("nemotron3_attn_core_roofline_pct", "nemotron3_attn_core",
+             "flops", 197e12, 0.004)):
+        needs = spec.kernel(kernel).work(counters, NEMOTRON_CFG)[quantity]
+        assert 0 < metric(name) < 100, name
+        assert metric(name) == pytest.approx(
+            100 * needs / peak / seconds), name
+    assert NEMOTRON_ONLY == [
+        m["name"] for m in spec.metrics_for(NEMOTRON_CELL, "per_layer")
+        if m["name"].startswith("nemotron3_")]
+    # against a program without the scopes and the counters every new
+    # metric is left out and none raises
+    parent = _fake_run({"text": 0.9, "text/layer1/ffn": 0.1},
+                       {"batches": 2, "scored": 16}, NEMOTRON_CFG)
+    for name in NEMOTRON_ONLY:
+        assert spec.reader_for(name, "per_layer")(parent) is None, name
+    # the scope there and the counter not: the share is left out
+    counted = _fake_run({"text": 0.9, "text/layer1/experts/matmul": 0.1},
+                        {"batches": 2, "scored": 16, "token_slots": slots},
+                        NEMOTRON_CFG)
+    assert spec.reader_for("nemotron3_expert_ffn_roofline_pct",
+                           "per_layer")(counted) is None
+
+
 # ------------------------------------------------ a program without the module
 @pytest.mark.parametrize("cfg,module", [(OLMOE_CFG, "olmoe"),
                                         (ZAYA_CFG, "zaya"),
                                         (LAGUNA_CFG, "laguna"),
                                         (JOYAI_CFG, "joyai"),
-                                        (FALCON_CFG, "falcon_h1")])
+                                        (FALCON_CFG, "falcon_h1"),
+                                        (NEMOTRON_CFG, "nemotron_h")])
 def test_the_builder_stops_at_once_on_a_program_without_the_encoder(
         monkeypatch, cfg, module):
     import importlib.util
@@ -1072,7 +1376,8 @@ def test_the_builder_stops_at_once_on_a_program_without_the_encoder(
                                          (ZAYA_CELL, "zaya"),
                                          (LAGUNA_CELL, "laguna"),
                                          (JOYAI_CELL, "joyai"),
-                                         (FALCON_CELL, "falcon_h1")])
+                                         (FALCON_CELL, "falcon_h1"),
+                                         (NEMOTRON_CELL, "nemotron_h")])
 def test_the_parent_exits_non_zero_within_seconds(tmp_path, cell, module):
     """A checkout of the benchmark without the program's new module — what
     the driver's parent run of a new configuration's cell is — prints no
@@ -1113,7 +1418,8 @@ def tiny_copy(tmp_path_factory):
 @pytest.mark.parametrize("cell,trace", [
     (OLMOE_CELL, 0), (OLMOE_CELL, 1), (ZAYA_CELL, 0), (ZAYA_CELL, 1),
     (FULL_CELL, 1), (LAGUNA_CELL, 0), (LAGUNA_CELL, 1), (ZAYA_FULL_CELL, 1),
-    (JOYAI_CELL, 0), (JOYAI_CELL, 1), (FALCON_CELL, 0), (FALCON_CELL, 1)])
+    (JOYAI_CELL, 0), (JOYAI_CELL, 1), (FALCON_CELL, 0), (FALCON_CELL, 1),
+    (NEMOTRON_CELL, 0), (NEMOTRON_CELL, 1)])
 def test_tiny_rehearsal_of_a_routed_cell(tiny_copy, cell, trace):
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(ROOT),
                XLA_FLAGS="--xla_force_host_platform_device_count=1")
@@ -1145,7 +1451,20 @@ def test_tiny_rehearsal_of_a_routed_cell(tiny_copy, cell, trace):
         # counters are read on any backend; device scopes need the chip
         assert out["metrics"]["expert_imbalance_x"]["value"] >= 1.0
         padding = out["metrics"]["token_padding_pct"]["value"]
-        if cell == JOYAI_CELL:
+        if cell == NEMOTRON_CELL:
+            # every slot real, every expert held; routed AND state-space:
+            # the chunks of the four M layers are counted, and the programs
+            # compiled before the window opened are the admitted cells' and
+            # the reference's four (14 on the chip; the CPU rehearsal has
+            # the parity sample's bucket as a program more)
+            assert padding == 0.0
+            assert "expert_local_share_pct" not in out["metrics"]
+            assert 0 < out["metrics"]["setup_programs"]["value"] <= 15
+            assert "'ssm_chunks': " in proc.stdout \
+                and "'ssm_chunks': 0" not in proc.stdout
+            assert not [name for name in out["metrics"]
+                        if name.startswith(("ssm_", "nemotron3_", "ffn_"))]
+        elif cell == JOYAI_CELL:
             # every slot real, as Laguna's; every expert held: no share
             assert padding == 0.0
             assert "expert_local_share_pct" not in out["metrics"]
@@ -1171,3 +1490,24 @@ def test_tiny_rehearsal_of_a_routed_cell(tiny_copy, cell, trace):
             assert name not in out["metrics"]
     else:
         assert set(out["metrics"]) == {"txn_per_s", "setup_s"}
+
+
+def test_the_nemotron3_run_with_the_timed_path_broken_is_not_correct(
+        tiny_copy):
+    """The cell's run with a part of each batch left out underneath the
+    harness (``rehearsal.BREAKS['fan-out']``: the last prediction of every
+    microbatch scored and counted, never produced) prints a result whose
+    ``correct`` is false: the comparison is of what the timed path itself
+    produced."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(ROOT),
+               XLA_FLAGS="--xla_force_host_platform_device_count=1")
+    proc = subprocess.run(
+        [sys.executable, str(ROOT / "benchmarks/tests/rehearsal.py"),
+         str(tiny_copy), "--break", "fan-out", "--workload", NEMOTRON_CELL,
+         "--seed", "5000000023", "--seconds", "3", "--trace", "0"],
+        capture_output=True, text=True, env=env, timeout=600, cwd=tiny_copy)
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["correct"] is False and out["attempted"] > 0
+    assert "check parity with the plain float32 reference: ok" in proc.stdout
+    assert "every attempted transaction accounted for: FAILED" in proc.stdout \
+        or "FAILED" in proc.stdout

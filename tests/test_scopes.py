@@ -137,7 +137,8 @@ def _lowered_asm(bert_config):
 
 @pytest.mark.parametrize("builder", ["ensemble_builder", "olmoe_builder",
                                      "zaya1_builder", "laguna_builder",
-                                     "joyai_builder", "falconh1_builder"])
+                                     "joyai_builder", "falconh1_builder",
+                                     "nemotron3_builder"])
 def test_every_name_in_a_builders_vocabulary_is_one_the_program_writes(
         builder):
     """A builder (``benchmarks/configs/<builder>.py``) writes the device
@@ -184,7 +185,24 @@ def test_every_name_in_a_builders_vocabulary_is_one_the_program_writes(
             "/jit(_ssd_pallas)/ssd_scan/pallas_call", vocabulary
         ) == f"{scopes.TEXT}/{scopes.layer_scope(5)}/{scopes.SSM_SCAN}"
     else:
-        if builder == "olmoe_builder":
+        gate_up = "jit(gated_gmm)/gated_gmm/pallas_call"
+        if builder == "nemotron3_builder":
+            from realtime_fraud_detection_tpu.models.nemotron_h import (
+                TINY_NEMOTRON_H,
+            )
+
+            # a layer is ONE mixer: its norm and the scopes of its kind —
+            # Falcon-H1's three of the mixer, attention's two, a routed
+            # block's three; no ``ffn`` (no layer has a dense MLP); the
+            # experts' first call has no gate
+            config, layer_parts = (TINY_NEMOTRON_H,
+                                   scopes.NEMOTRON_H_LAYER_SCOPES)
+            assert set(layer_parts) == {
+                scopes.LN, scopes.SSM_PROJ, scopes.SSM_CONV, scopes.SSM_SCAN,
+                scopes.ATTN_PROJ, scopes.ATTN_CORE, scopes.ROUTER,
+                scopes.EXPERTS, scopes.SHARED_EXPERT}
+            gate_up = "jit(relu2_gmm)/relu2_gmm/pallas_call"
+        elif builder == "olmoe_builder":
             from realtime_fraud_detection_tpu.models.olmoe import TINY_OLMOE
 
             config, layer_parts = TINY_OLMOE, scopes.MOE_LAYER_SCOPES
@@ -222,8 +240,7 @@ def test_every_name_in_a_builders_vocabulary_is_one_the_program_writes(
         # + SiLU one, and the combine's, as a device trace names them
         for part, kernel in (
                 (scopes.EXPERTS_MATMUL, "jit(down_gmm)/down_gmm/pallas_call"),
-                (scopes.EXPERTS_MATMUL,
-                 "jit(gated_gmm)/gated_gmm/pallas_call"),
+                (scopes.EXPERTS_MATMUL, gate_up),
                 (scopes.EXPERTS_COMBINE,
                  "jit(combine_rows)/weighted_combine/pallas_call")):
             assert bench.scope_path(

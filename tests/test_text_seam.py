@@ -1,4 +1,4 @@
-"""The seam between the scorer, the stream job and the six text encoders
+"""The seam between the scorer, the stream job and the seven text encoders
 (models/text_encoder.py): what the benchmark reads through it — the job's
 counter names, the snapshot's kernel sites, the counters' values, the
 programs a bucket's first batch builds — pinned for every encoder at its
@@ -16,6 +16,7 @@ from realtime_fraud_detection_tpu.models.bert import TINY_CONFIG
 from realtime_fraud_detection_tpu.models.falcon_h1 import TINY_FALCON_H1
 from realtime_fraud_detection_tpu.models.joyai import TINY_JOYAI
 from realtime_fraud_detection_tpu.models.laguna import TINY_LAGUNA
+from realtime_fraud_detection_tpu.models.nemotron_h import TINY_NEMOTRON_H
 from realtime_fraud_detection_tpu.models.olmoe import TINY_OLMOE
 from realtime_fraud_detection_tpu.models.text_encoder import (
     LAUNCH_COUNTERS,
@@ -37,8 +38,9 @@ from realtime_fraud_detection_tpu.stream import (
 )
 
 # (configuration, text_len, rows of the first batch): DistilBERT at a width
-# that splits, the routed four at the smallest launch with two rungs,
-# Falcon-H1 at a width that crosses chunk boundaries
+# that splits, the routed five at the smallest launch with two rungs
+# (Nemotron-3-Nano is routed AND state-space: a chunk of 128 over rows of
+# 32), Falcon-H1 at a width that crosses chunk boundaries
 ENCODERS = {
     "distilbert": (TINY_CONFIG, 256, 32),
     "olmoe": (TINY_OLMOE, 32, 128),
@@ -46,8 +48,10 @@ ENCODERS = {
     "laguna": (TINY_LAGUNA, 32, 128),
     "joyai": (TINY_JOYAI, 32, 128),
     "falconh1": (TINY_FALCON_H1, 64, 8),
+    "nemotron3": (TINY_NEMOTRON_H, 32, 128),
 }
-ROUTED = ("olmoe", "zaya1", "laguna", "joyai")
+ROUTED = ("olmoe", "zaya1", "laguna", "joyai", "nemotron3")
+SCANNED = ("falconh1", "nemotron3")
 
 # what StreamJob.counters held at the parent (PR 48), letter for letter:
 # benchmarks/kernels/*.py and benchmarks/readers/*.py read these names
@@ -114,7 +118,7 @@ def test_the_snapshot_counts_each_encoders_own_sites(first_batch):
     sites = {"dequant_matmul", "epilogue", "attention"}
     if name in ROUTED:
         sites |= {"expert_gate_up", "expert_combine"}
-    if name == "falconh1":
+    if name in SCANNED:
         sites |= {"ssm_scan"}
     snap = scorer.kernel_snapshot()
     assert set(snap) == {"modes", "interpret", "dispatch", "fallback",
@@ -123,7 +127,7 @@ def test_the_snapshot_counts_each_encoders_own_sites(first_batch):
     assert set(snap["modes"]) == {"dequant_matmul", "epilogue", "attention"}
     # the reasons the snapshot can name with no launch in hand
     assert set(snap["refused"]) == {"attention"} | (sites & {"ssm_scan"})
-    assert all("cpu mesh" in why or "head_dim" in why
+    assert all("cpu mesh" in why or "head_dim" in why or "seq_len" in why
                for why in snap["refused"].values())
     # a CPU mesh is never asked for a kernel: every launch of the first
     # batch is a fallback at each of the encoder's own sites
@@ -172,6 +176,9 @@ def test_a_batchs_counters_against_a_hand_count(first_batch):
         want.update(expert_peak_rows=c["expert_peak_rows"])
     if name == "falconh1":
         want.update(ssm_chunks=rows * width // 16 * 2)
+    if name == "nemotron3":
+        # the two M layers of MEM*E, over every slot of the launch
+        want.update(ssm_chunks=rows * width // 128 * 2)
     assert c == want
     assert visible_pairs(config, lengths)[0] == sum(
         int(n) * (int(n) + 1) // 2 for n in lengths)
