@@ -29,10 +29,11 @@ a predicate admits holds the kernel, everything else the XLA form.
   one contiguous piece in HBM) against ``ragged_dot`` reshaped;
   ``grouped_relu2_matmul`` (the first half of an expert with NO gate,
   ``relu(rows @ up)^2``: ``relu2_gmm``, the fused kernel with one
-  right-hand block a step); ``grouped_matmul_supported``, the one predicate
-  of all three (a side that is no whole number of lane tiles goes whole in
-  one block), and ``gmm_tiling``, their one tile rule (from the call's
-  shapes, group count and right-hand blocks a step alone).
+  right-hand block a step, its matrices taken ``[G, N, K]`` — where a
+  ragged-N parameter lies on the TPU); ``grouped_matmul_supported``, the
+  one predicate of all three (a side that is no whole number of lane tiles
+  goes whole in one block), and ``gmm_tiling``, their one tile rule (from
+  the call's shapes, group count and right-hand blocks a step alone).
 - ``combine``: the routed experts' way home. ``weighted_combine`` (each
   token's weighted sum of its experts' result rows as ONE kernel,
   ``combine_rows``: every row of a pair that entered a group fetched once

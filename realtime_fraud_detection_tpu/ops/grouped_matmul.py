@@ -38,16 +38,29 @@ Three entry points, each with two forms:
   matmul reads — the first half of an expert that has NO gate matrix
   (``models/nemotron_h.py``). The XLA form is one ``ragged_dot``, the
   ReLU and the square. The Pallas form is ``relu2_gmm``: the fused
-  kernel's grid with ONE right-hand block a step and that epilogue.
+  kernel's grid with ONE right-hand block a step and that epilogue — and
+  its matrices taken ``[G, N, K]``, the block ``[tn, tk]``, the product
+  contracting both last axes: the wrapper hands it ``swapaxes`` of the
+  ``[G, K, N]`` parameter, which inside a program moves no byte (below).
 
 **A side that is no whole number of lane tiles** (Nemotron's experts are
 1,856 = 14 1/2 tiles wide) goes whole in one block: a Pallas block equal to
 the array's whole side needs no lane multiple, and the tile rule takes K
-and N whole wherever the budget holds them anyway.
+and N whole wherever the budget holds them anyway. **Where that side is a
+stored matrix's LAST** (N of the ungated call: ``bf16[128, 2688, 1856]``)
+the TPU does not pad it to lane tiles: it lays the array out with its
+lane-multiple side, K, innermost (``{1,2,0}``). A Mosaic call takes its
+operands row-major, so a kernel that asks for that matrix ``[G, K, N]`` is
+handed a transposing copy of it on every launch (1.28 GB read and written a
+layer, 4 ms: 9% of Nemotron's period, PERF.md section 6, PR 51), and one that
+asks for ``[G, N, K]`` is handed the bytes where they lie. A hidden size is
+always whole lane tiles, so K innermost is copy-free whatever the expert's
+width. The gated call's and down's matrices end in whole lane tiles (768 to
+3,072) and are held row-major: they stay ``[G, K, N]``.
 
 All three kernels are ``_grouped_call`` — megablox's schedule
-(``make_group_metadata``), the grid, the block specs, the budget the call
-names — round a body of their own.
+(``make_group_metadata``), the grid, the block specs (the right-hand one
+either way round), the budget the call names — round a body of their own.
 
 ``grouped_matmul_supported`` is the ONE predicate on shapes for all: the
 traced guards below, the scorer's selector
@@ -274,10 +287,12 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
 def _grouped_call(body, name: str, lhs: jax.Array, matrices, group_sizes,
                   tiling: Tuple[int, int, int], *, out_shape, out_block,
                   out_index, vmem: int, flops_per_mkn: int,
-                  transcendentals: int, interpret: bool) -> jax.Array:
-    """The scaffold of both kernels: ``body`` run over megablox's grid for
-    ``lhs`` ``[M, K]`` against the group's ``[tk, tn]`` block of each of
-    ``matrices`` (``[G, K, N]`` each). The grid is ``(N tiles, visits, K
+                  transcendentals: int, interpret: bool,
+                  transposed: bool = False) -> jax.Array:
+    """The scaffold of the three kernels: ``body`` run over megablox's grid
+    for ``lhs`` ``[M, K]`` against the group's ``[tk, tn]`` block of each of
+    ``matrices`` (``[G, K, N]`` each; ``transposed``: ``[G, N, K]`` each,
+    the group's block ``[tn, tk]``). The grid is ``(N tiles, visits, K
     tiles)``; megablox's own schedule (``make_group_metadata``) says which
     row tile and which group each visit holds — a tile that straddles
     groups is visited once for each — and how many visits hold work. Rows
@@ -290,7 +305,8 @@ def _grouped_call(body, name: str, lhs: jax.Array, matrices, group_sizes,
     )
 
     m, k = lhs.shape
-    groups, _, n = matrices[0].shape
+    groups = matrices[0].shape[0]
+    n = matrices[0].shape[1 if transposed else 2]
     tm, tk, tn = tiling
     tiles_k, tiles_n = k // tk, n // tn
     metadata, visits = make_group_metadata(
@@ -302,7 +318,8 @@ def _grouped_call(body, name: str, lhs: jax.Array, matrices, group_sizes,
         return row_tiles[visit], k_i
 
     def matrix_at(n_i, visit, k_i, offsets, group_ids, row_tiles):
-        return group_ids[visit], k_i, n_i
+        return ((group_ids[visit], n_i, k_i) if transposed
+                else (group_ids[visit], k_i, n_i))
 
     def out_at(n_i, visit, k_i, offsets, group_ids, row_tiles):
         return out_index(row_tiles[visit], n_i)
@@ -314,7 +331,8 @@ def _grouped_call(body, name: str, lhs: jax.Array, matrices, group_sizes,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             in_specs=[pl.BlockSpec((tm, tk), rows_at)]
-            + [pl.BlockSpec((None, tk, tn), matrix_at)] * len(matrices),
+            + [pl.BlockSpec((None, tn, tk) if transposed else (None, tk, tn),
+                            matrix_at)] * len(matrices),
             out_specs=pl.BlockSpec(out_block, out_at),
             grid=(tiles_n, visits, tiles_k),
             scratch_shapes=([pltpu.VMEM((tm, tn), jnp.float32)]
@@ -386,9 +404,9 @@ def _gated_kernel(offsets, group_ids, row_tiles, lhs, gate_w, up_w, out,
 
 def _relu2_kernel(offsets, group_ids, row_tiles, lhs, up_w, out, *accs,
                   tm: int, tn: int, tiles_k: int):
-    """``_gated_kernel`` with one matrix: the product, summed over the K
-    steps, and on the last the squared ReLU of the group's own rows of the
-    tile."""
+    """``_gated_kernel`` with one matrix, whose block is ``[tn, tk]``: the
+    product contracting both last axes, summed over the K steps, and on the
+    last the squared ReLU of the group's own rows of the tile."""
     visit = pl.program_id(1)
 
     def store(up):
@@ -396,8 +414,9 @@ def _relu2_kernel(offsets, group_ids, row_tiles, lhs, up_w, out, *accs,
         out[...] = jnp.where(mine, jnp.square(jnp.maximum(up, 0.0)),
                              out[...].astype(jnp.float32)).astype(out.dtype)
 
-    _over_k((jnp.dot(lhs[...], up_w[...],
-                     preferred_element_type=jnp.float32),),
+    _over_k((jax.lax.dot_general(lhs[...], up_w[...],
+                                 (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32),),
             accs, tiles_k, store)
 
 
@@ -453,10 +472,12 @@ def relu2_gmm(lhs: jax.Array, up_w: jax.Array, group_sizes: jax.Array, *,
               out_dtype, tiling: Tuple[int, int, int],
               interpret: bool = False) -> jax.Array:
     """The Pallas form of ``grouped_relu2_matmul`` at ``tiling`` (tm, tk,
-    tn): ``gated_gmm``'s call with one matrix. Rows past the last group are
-    never written."""
+    tn): ``gated_gmm``'s call with one matrix, taken ``[G, N, K]`` — K
+    innermost, which is how the TPU holds a ``[G, K, N]`` parameter whose N
+    is no whole number of lane tiles (the module docstring). Rows past the
+    last group are never written."""
     m, k = lhs.shape
-    n = up_w.shape[-1]
+    n = up_w.shape[1]
     tm, tk, tn = tiling
     out_dtype = jnp.dtype(out_dtype)
     return _grouped_call(
@@ -465,7 +486,8 @@ def relu2_gmm(lhs: jax.Array, up_w: jax.Array, group_sizes: jax.Array, *,
         out_block=(tm, tn), out_index=lambda row_tile, n_i: (row_tile, n_i),
         vmem=gated_vmem_bytes(tm, tk, tn, lhs.dtype.itemsize,
                               out_dtype.itemsize, matrices=1),
-        flops_per_mkn=2, transcendentals=0, interpret=interpret)
+        flops_per_mkn=2, transcendentals=0, interpret=interpret,
+        transposed=True)
 
 
 @functools.partial(jax.jit, static_argnames=("tiling", "interpret"))
@@ -517,16 +539,19 @@ def grouped_relu2_matmul(rows: jax.Array, up_w: jax.Array,
                          ) -> jax.Array:
     """``out_dtype[M, N]``: ``relu(rows @ up)^2`` with each row of ``rows``
     (``[M, K]``, sorted by group) against its group's matrix of ``up_w``
-    (``[G, K, N]``): the first half of an expert with no gate; f32
-    accumulation, ReLU and square in f32, one rounding to ``out_dtype``.
-    ``use_pallas`` asks for the kernel; a shape it does not take runs the
-    XLA form, ``ragged_dot``, the ReLU and the square."""
+    (``[G, K, N]``, as the tree stores it): the first half of an expert
+    with no gate; f32 accumulation, ReLU and square in f32, one rounding to
+    ``out_dtype``. ``use_pallas`` asks for the kernel, which takes the
+    matrices ``[G, N, K]``; a shape it does not take runs the XLA form,
+    ``ragged_dot``, the ReLU and the square."""
     m, k = rows.shape
     n = up_w.shape[-1]
     if use_pallas and grouped_matmul_supported(m, k, n):
         tiling = gmm_tiling(m, k, n, up_w.shape[0], gated=True, matrices=1)
-        return relu2_gmm(rows, up_w, group_sizes.astype(jnp.int32),
-                         out_dtype=out_dtype, tiling=tiling,
-                         interpret=interpret)
+        # (inside a program the swap moves no byte of a matrix that lies
+        # with K innermost, as one of a ragged N does: the module docstring)
+        return relu2_gmm(rows, jnp.swapaxes(up_w, 1, 2),
+                         group_sizes.astype(jnp.int32), out_dtype=out_dtype,
+                         tiling=tiling, interpret=interpret)
     up = grouped_matmul_reference(rows, up_w, group_sizes)
     return jnp.square(jnp.maximum(up, 0.0)).astype(out_dtype)

@@ -472,6 +472,101 @@ def test_the_ungated_call_in_interpret_mode_is_ragged_dot_and_relu2():
         atol=2e-2, rtol=2e-2)
 
 
+def _plain_relu2_gmm(rows, up, sizes, tiling):
+    """PR 50's form of the ungated call, on the same scaffold at the same
+    tile: the matrices ``[G, K, N]``, the group's ``[tk, tn]`` block,
+    ``jnp.dot``. What ``relu2_gmm`` must equal to the bit."""
+    from jax.experimental import pallas as pl
+
+    gmm = sys.modules["realtime_fraud_detection_tpu.ops.grouped_matmul"]
+    tm, tk, tn = tiling
+
+    def body(offsets, group_ids, row_tiles, lhs, up_w, out, *accs, tm, tn,
+             tiles_k):
+        visit = pl.program_id(1)
+
+        def store(product):
+            mine = gmm._own_rows(visit, offsets, group_ids, row_tiles, tm,
+                                 (tm, tn))
+            out[...] = jnp.where(
+                mine, jnp.square(jnp.maximum(product, 0.0)),
+                out[...].astype(jnp.float32)).astype(out.dtype)
+
+        gmm._over_k((jnp.dot(lhs[...], up_w[...],
+                             preferred_element_type=jnp.float32),),
+                    accs, tiles_k, store)
+
+    return gmm._grouped_call(
+        body, "relu2_gmm", rows, (up,), sizes, tiling,
+        out_shape=jax.ShapeDtypeStruct((rows.shape[0], up.shape[-1]),
+                                       jnp.bfloat16),
+        out_block=(tm, tn), out_index=lambda row_tile, n_i: (row_tile, n_i),
+        vmem=gmm.gated_vmem_bytes(tm, tk, tn, matrices=1), flops_per_mkn=2,
+        transcendentals=0, interpret=True)
+
+
+# 512 rows at a row tile of 128, four groups: whole tiles a group; one group
+# with most of the rows; an empty group between two others; tiles that
+# straddle two groups and three (rows 128 and 256 fall inside a group, tile
+# 1 holds the end of group 0, all of group 1 and the start of group 2)
+GROUP_LAYOUTS = {"even": (128, 128, 128, 128), "skewed": (400, 10, 30, 50),
+                 "empty_group": (200, 0, 150, 100),
+                 "straddling": (100, 60, 200, 90)}
+
+
+@pytest.mark.parametrize("tk", [384, 128], ids=["k_whole", "k_in_three"])
+@pytest.mark.parametrize("layout", sorted(GROUP_LAYOUTS))
+@pytest.mark.parametrize("width", [176, 256], ids=["ragged_n", "lane_n"])
+def test_relu2_gmm_takes_its_matrices_k_innermost(width, layout, tk):
+    """``relu2_gmm`` is handed the up matrices ``[G, N, K]`` — how the TPU
+    holds a ``[G, K, N]`` parameter whose N is no lane multiple — and
+    contracts both last axes: ``ragged_dot`` and ``relu^2`` on the real
+    rows, and PR 50's ``[G, K, N]`` form at the same tile TO THE BIT (same
+    operands, same float32 accumulation, one rounding), at an N of 1 3/8
+    lane tiles and of two, K in one block and in three."""
+    from realtime_fraud_detection_tpu.ops.grouped_matmul import relu2_gmm
+
+    m, hidden, groups = 512, 384, 4
+    tiling = (128, tk, width)
+    rng = np.random.default_rng(51)
+    sizes = jnp.asarray(GROUP_LAYOUTS[layout], jnp.int32)
+    real = int(sizes.sum())
+    rows = jnp.asarray(rng.standard_normal((m, hidden)), jnp.bfloat16)
+    up = jnp.asarray(rng.standard_normal((groups, hidden, width)) * 0.05,
+                     jnp.bfloat16)
+    act = relu2_gmm(rows, jnp.swapaxes(up, 1, 2), sizes,
+                    out_dtype=jnp.bfloat16, tiling=tiling, interpret=True)
+    assert act.shape == (m, width) and act.dtype == jnp.bfloat16
+    want = np.asarray(jnp.square(jnp.maximum(
+        grouped_matmul_reference(rows, up, sizes), 0.0)))
+    assert np.abs(want[:real]).max() > 1.0
+    np.testing.assert_allclose(np.asarray(act, np.float32)[:real],
+                               want[:real], atol=3e-2, rtol=2e-2)
+    plain = _plain_relu2_gmm(rows, up, sizes, tiling)
+    np.testing.assert_array_equal(np.asarray(act, np.float32)[:real],
+                                  np.asarray(plain, np.float32)[:real])
+
+
+def test_the_ungated_call_hands_the_kernel_the_swapped_matrices(monkeypatch):
+    """``grouped_relu2_matmul`` takes what the tree stores, ``[experts,
+    hidden, width]``, and swaps inside the program: the kernel sees ``[G,
+    N, K]`` at the rule's tile, and the stored tree does not change."""
+    gmm = sys.modules["realtime_fraud_detection_tpu.ops.grouped_matmul"]
+    seen = {}
+
+    def kernel(rows, up_w, sizes, **kw):
+        seen.update(up=up_w.shape, tiling=kw["tiling"])
+        return jnp.zeros((rows.shape[0], up_w.shape[1]), kw["out_dtype"])
+
+    monkeypatch.setattr(gmm, "relu2_gmm", kernel)
+    layer = init_nemotron_h_params(jax.random.PRNGKey(2), CFG)["layers"][1]
+    assert layer["up_proj"].shape == (16, 384, 144)
+    grouped_relu2_matmul(jnp.zeros((1024, 384), jnp.bfloat16),
+                         layer["up_proj"], jnp.full((16,), 64, jnp.int32),
+                         out_dtype=jnp.bfloat16, use_pallas=True)
+    assert seen == {"up": (16, 144, 384), "tiling": (128, 384, 144)}
+
+
 @pytest.mark.parametrize("rung", [0, 1], ids=["three_quarters", "every_slot"])
 def test_apply_experts_without_a_gate_is_the_same_through_the_kernels(
         experts_through_both_forms, rung):

@@ -458,6 +458,49 @@ def test_the_tile_rule_at_the_cells_shapes(encoder, rung, kernel):
     assert gmm_tiling(m, k, n, groups, gated=gated) == tiling
 
 
+@pytest.mark.parametrize("kernel", ["gated", "down", "relu2"])
+@pytest.mark.parametrize("encoder", sorted(CELL_SITES))
+def test_which_way_round_each_call_takes_its_matrices(encoder, kernel):
+    """The scaffold serves three kernels and one of them, the ungated
+    ``relu2_gmm`` (PR 51), takes its matrices ``[G, N, K]``. The gated
+    call's and down's traced calls are what they were: each right-hand
+    block the group's ``[tk, tn]`` of a ``[G, K, N]`` operand as the
+    parameters hold them (their N are whole lane tiles, which the TPU keeps
+    row-major), the product contracting the rows' last side with the
+    block's FIRST; the ungated call's block is ``[tn, tk]`` and its product
+    contracts both last sides. (Their jaxprs hashed equal to the parent's
+    at these shapes: PERF.md section 6, PR 51.)"""
+    from realtime_fraud_detection_tpu.ops.grouped_matmul import relu2_gmm
+
+    m, k, n, groups = _site(encoder, 0, "down" if kernel == "down"
+                            else "gated")
+    gated = kernel != "down"
+    matrices = {"gated": 2, "down": 1, "relu2": 1}[kernel]
+    tm, tk, tn = tiling = gmm_tiling(m, k, n, groups, gated=gated,
+                                     matrices=matrices)
+    sds = jax.ShapeDtypeStruct
+    rows, sizes = sds((m, k), jnp.bfloat16), sds((groups,), jnp.int32)
+    first = dict(out_dtype=jnp.dtype(jnp.bfloat16), tiling=tiling)
+    if kernel == "relu2":
+        text = str(jax.make_jaxpr(
+            lambda x, w, s: relu2_gmm(x, w, s, **first))(
+            rows, sds((groups, n, k), jnp.bfloat16), sizes))
+        block, contracts = (tn, tk), "(([1], [1]), ([], []))"
+    else:
+        w = sds((groups, k, n), jnp.bfloat16)
+        text = str(jax.make_jaxpr(
+            (lambda x, w, s: gated_gmm(x, w, w, s, **first)) if gated else
+            (lambda x, w, s: down_gmm(x, w, s, tiling=tiling)))(
+            rows, w, sizes))
+        block, contracts = (tk, tn), "(([1], [0]), ([], []))"
+    right = ("BlockMapping(block_shape=(Squeezed(), Blocked(block_size=%d), "
+             "Blocked(block_size=%d)))" % block)
+    assert text.count(right) == matrices, text[:2000]
+    assert text.count("Squeezed()") == matrices
+    assert text.count("dimension_numbers=" + contracts) == matrices
+    assert text.count("dot_general[") == matrices
+
+
 # the programs a deployment launches below its cell's bucket: (positions,
 # experts a token, row buckets under the cell's own) — core/batching.
 # BATCH_BUCKETS x text_split.capacities

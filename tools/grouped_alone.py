@@ -3,6 +3,7 @@ tiles: the sweep ``ops/grouped_matmul.gmm_tiling``'s constants come from.
 
     chiprun -- python3 tools/grouped_alone.py --out chiprun_out/grouped_alone.json
     chiprun -- python3 tools/grouped_alone.py --combine --out chiprun_out/x.json
+    chiprun -- python3 tools/grouped_alone.py --relu2 --out chiprun_out/y.json
     JAX_PLATFORMS=cpu python3 tools/grouped_alone.py --aot     # no chip
 
 For each routed encoder (OLMoE, ZAYA1, Laguna, JoyAI) at both capacity
@@ -38,6 +39,18 @@ alone by spelling of its store (against ``megablox.gmm`` at the parent's
 tile and at the rule's) and the way home alone on a result that is already
 there (ns a fetched row, ns an issued pair). ``tools/grouped_alone_pr48.json``
 is its output; ``--aot`` and ``--rehearse`` work with it.
+
+``--relu2`` (PR 51) times the UNGATED first half (``relu2_gmm``,
+Nemotron-3-Nano's experts: 128 groups, 2,688 -> 1,856 = 14 1/2 lane tiles)
+alone at the cell's two rungs in three forms: the parent's ``[G, K, N]``
+kernel fed a ROW-MAJOR array (``plain_row_major``: the kernel with nothing
+before it), the same fed the array as the device holds a ``[G, K, N]``
+parameter of that shape — its lane-multiple side, K, innermost — so that
+the program re-lays it out first (``plain_as_held``: the copy is timed), and
+the shipped ``[G, N, K]`` form fed ``swapaxes`` of that array
+(``transposed``). ``tools/grouped_alone_pr51.json`` is its output; ``--aot``
+(with each form's temporaries and whether the compiled text holds a ``copy``
+of the matrices) and ``--rehearse`` work with it.
 
 Not part of the package's import graph and not under ``benchmarks/``: a
 builder's instrument (ROADMAP D18). The next users are what is left of S12:
@@ -76,6 +89,7 @@ from realtime_fraud_detection_tpu.ops.grouped_matmul import (
     gated_gmm,
     gmm_tiling,
     grouped_matmul_reference,
+    relu2_gmm,
 )
 
 # the package exports the FUNCTION ``ops.grouped_matmul`` under its module's
@@ -571,6 +585,194 @@ def combine_aot(encoders, spellings=SPELLINGS):
                     print(encoder, rung, kind, name, verdict, flush=True)
 
 
+# ------------------------------------------------------------------- --relu2
+# encoder: (pairs at the 3/4 rung, at every slot), groups, hidden, one
+# expert's width, (real pairs at each rung: ~10,100 / ~13,500 real tokens of
+# 12,288 / 16,384 slots, six experts each)
+RELU2_SITES = {"nemotron3": ((73728, 98304), 128, 2688, 1856,
+                             (60600, 81000))}
+RELU2_TINY = {"tiny": ((1024, 2048), 4, 256, 176, (800, 1500))}
+# the cell's ``expert_imbalance_x`` (largest group over the mean: ledger,
+# PR 50); 128 groups drawn from dirichlet(1) until they read it within 4%
+CELL_IMBALANCE = 5.0
+
+
+def skewed_to(rng, groups: int, real: int, imbalance: float):
+    for _ in range(1000):
+        sizes = group_sizes(rng, groups, real, 1.0)
+        if abs(sizes.max() / sizes.mean() / imbalance - 1) < 0.04:
+            break
+    return sizes
+
+
+def _plain_relu2_kernel(offsets, group_ids, row_tiles, lhs, up_w, out, *accs,
+                        tm, tn, tiles_k):
+    """The parent's body (PR 50): the group's ``[tk, tn]`` block of a
+    ``[G, K, N]`` matrix, ``jnp.dot``."""
+    from jax.experimental import pallas as pl
+
+    visit = pl.program_id(1)
+
+    def store(up):
+        mine = gm._own_rows(visit, offsets, group_ids, row_tiles, tm,
+                            (tm, tn))
+        out[...] = jnp.where(mine, jnp.square(jnp.maximum(up, 0.0)),
+                             out[...].astype(jnp.float32)).astype(out.dtype)
+
+    gm._over_k((jnp.dot(lhs[...], up_w[...],
+                        preferred_element_type=jnp.float32),),
+               accs, tiles_k, store)
+
+
+def relu2_forms(tiling, interpret: bool = False):
+    """``{form: (x, w, sizes) -> bf16[M, N]``, ``w`` ``[G, K, N]`` in each:
+    what the stored tree holds."""
+    tm, tk, tn = tiling
+
+    def plain(x, w, sizes):
+        return gm._grouped_call(
+            _plain_relu2_kernel, "relu2_gmm", x, (w,), sizes, tiling,
+            out_shape=jax.ShapeDtypeStruct((x.shape[0], w.shape[-1]),
+                                           jnp.bfloat16),
+            out_block=(tm, tn),
+            out_index=lambda row_tile, n_i: (row_tile, n_i),
+            vmem=gm.gated_vmem_bytes(tm, tk, tn, matrices=1),
+            flops_per_mkn=2, transcendentals=0, interpret=interpret)
+
+    def transposed(x, w, sizes):
+        return relu2_gmm(x, jnp.swapaxes(w, 1, 2), sizes,
+                         out_dtype=jnp.dtype(jnp.bfloat16), tiling=tiling,
+                         interpret=interpret)
+
+    return {"plain_row_major": plain, "plain_as_held": plain,
+            "transposed": transposed}
+
+
+def row_major(sharding):
+    """``[G, K, N]`` with N innermost whatever its size: what a Mosaic call
+    asks of its operand."""
+    from jax.experimental.layout import Format, Layout
+
+    return Format(Layout(major_to_minor=(0, 1, 2)), sharding)
+
+
+def relu2_programs(tiling, sharding, interpret: bool = False):
+    """``{form: (timed, whole)}``: ``timed`` returns one sublane tile of the
+    result's rows (a ``bf16[M, 1856]`` program RESULT is re-laid out with M
+    innermost, 0.36 GB each way, which no caller of the kernel pays: its
+    result goes to down's call row-major), ``whole`` all of it for the
+    comparison."""
+    out = {}
+    for form, fn in relu2_forms(tiling, interpret).items():
+        kw = ({"in_shardings": (sharding, row_major(sharding), sharding)}
+              if form == "plain_row_major" else {})
+        out[form] = (jax.jit(lambda x, w, s, fn=fn: fn(x, w, s)[:gm.SUBLANES],
+                             **kw), jax.jit(fn, **kw))
+    return out
+
+
+def relu2_tiles(m: int, k: int, n: int, groups: int):
+    rule = gmm_tiling(m, k, n, groups, gated=True, matrices=1)
+    return list(dict.fromkeys([rule] + [
+        (tm, k, n) for tm in ROW_TILES[:2] if m % tm == 0]))
+
+
+def relu2_sweep(sites, out_path, interpret=False):
+    rng = np.random.default_rng(51)
+    device = jax.devices()[0]
+    sharding = jax.sharding.SingleDeviceSharding(device)
+    result = {"device": {"platform": device.platform,
+                         "kind": device.device_kind},
+              "clock": f"host, fastest of {REPEATS} x {CALLS} calls",
+              "sites": {}}
+    for encoder, (rungs, groups, k, n, reals) in sites.items():
+        for rung, m, real in zip(RUNGS[-len(rungs):], rungs, reals):
+            site = result["sites"][f"{encoder}.{rung}.relu2"] = {
+                "m": m, "k": k, "n": n, "groups": groups, "real": real,
+                "rule": list(gmm_tiling(m, k, n, groups, gated=True,
+                                        matrices=1)), "layouts": {}}
+            x, w = operands(True, m, groups, k, n)[:2]
+            try:
+                w_rows = jax.device_put(w, row_major(sharding))
+                site["row_major_layout"] = str(w_rows.format.layout)
+            except Exception as e:  # noqa: BLE001 — no such layout here
+                w_rows, site["row_major_layout"] = None, str(e)[-300:]
+            site["held_layout"] = str(w.format.layout)
+            want_fn = jax.jit(lambda x, w, s: jnp.square(jnp.maximum(
+                grouped_matmul_reference(x, w, s), 0.0)).astype(jnp.bfloat16))
+            same = compare(real)
+            programs = {t: relu2_programs(t, sharding, interpret)
+                        for t in relu2_tiles(m, k, n, groups)}
+            for layout, sizes in (
+                    ("even", group_sizes(rng, groups, real, 1e6)),
+                    ("skewed", skewed_to(rng, groups, real, CELL_IMBALANCE))):
+                s = jnp.asarray(sizes)
+                rows = site["layouts"][layout] = {
+                    "largest_over_mean": float(sizes.max() / sizes.mean()),
+                    "tiles": {}}
+                xla = want_fn(x, w, s)
+                for tiling, forms in programs.items():
+                    cell = rows["tiles"]["x".join(map(str, tiling))] = {}
+                    parent = None
+                    for form, (timed, whole) in forms.items():
+                        arg = w_rows if form == "plain_row_major" else w
+                        try:
+                            if arg is None:
+                                raise ValueError(site["row_major_layout"])
+                            ms = timed_ms(timed, x, arg, s)
+                            got = whole(x, arg, s)
+                            parent = got if parent is None else parent
+                            cell[form] = {
+                                "ms": ms,
+                                "bit_equal_to_plain": bool(
+                                    same(got, parent)[0]),
+                                "max_abs_from_xla": float(same(got, xla)[1])}
+                            del got
+                        except Exception as e:  # noqa: BLE001 — refused
+                            cell[form] = {"error": str(e)[-300:]}
+                    del parent
+                print(encoder, rung, layout, json.dumps(rows["tiles"]),
+                      flush=True)
+                os.makedirs(os.path.dirname(out_path) or ".", exist_ok=True)
+                with open(out_path, "w") as f:
+                    json.dump(result, f, indent=1)
+            del x, w, w_rows, programs
+            gc.collect()
+    return result
+
+
+def relu2_aot(sites):
+    """Each form compiled for a described v5e: its temporaries, and whether
+    the compiled text holds a ``copy`` that produces the matrices."""
+    chip = described_chip()
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    for encoder, (rungs, groups, k, n, _) in sites.items():
+        copies = [f" = bf16[{groups},{a},{b}]" for a, b in ((k, n), (n, k))]
+        for rung, m in zip(RUNGS[-len(rungs):], rungs):
+            for tiling in relu2_tiles(m, k, n, groups):
+                for form, (_, whole) in relu2_programs(tiling, chip).items():
+                    try:
+                        compiled = whole.lower(
+                            sds((m, k), jnp.bfloat16),
+                            sds((groups, k, n), jnp.bfloat16),
+                            sds((groups,), jnp.int32)).compile()
+                        held = [line.strip()[:120]
+                                for line in compiled.as_text().splitlines()
+                                if " copy(" in line
+                                and any(c in line for c in copies)]
+                        verdict = "ok, temporaries %.2f GB, %s" % (
+                            compiled.memory_analysis().temp_size_in_bytes
+                            / 1e9, f"COPIES {held}" if held
+                            else "no copy of the matrices")
+                    except Exception as e:  # noqa: BLE001
+                        verdict = "REFUSED " + str(e).strip()[-160:].replace(
+                            "\n", " ")
+                    print(encoder, rung, tiling, form, verdict, flush=True)
+
+
 def described_chip():
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
@@ -618,7 +820,17 @@ def main():
     ap.add_argument("--combine", action="store_true",
                     help="down + the way home as a pair, by form (PR 48)")
     ap.add_argument("--spellings", nargs="*", default=list(SPELLINGS))
+    ap.add_argument("--relu2", action="store_true",
+                    help="the ungated first half in three forms (PR 51)")
     args = ap.parse_args()
+    if args.relu2:
+        if args.rehearse:
+            relu2_sweep(RELU2_TINY, args.out, interpret=True)
+        elif args.aot:
+            relu2_aot(RELU2_SITES)
+        else:
+            relu2_sweep(RELU2_SITES, args.out)
+        return
     if args.encoders is None:
         args.encoders = sorted(SITES) + (
             sorted(SMALL) if args.small and not args.combine else [])
