@@ -71,6 +71,7 @@ from realtime_fraud_detection_tpu.ops.attention import (
     windowed_refusal,
 )
 from realtime_fraud_detection_tpu.ops.combine import weighted_combine
+from realtime_fraud_detection_tpu.ops.dispatch import rows_to_experts
 from realtime_fraud_detection_tpu.ops.grouped_matmul import (
     gated_tile_rows,
     grouped_gated_matmul,
@@ -317,6 +318,13 @@ def apply_experts(layer: Dict, x: jax.Array, experts: jax.Array,
     ``grouped_relu2_matmul``, the same kernel with one matrix and that
     epilogue. What the layer holds chooses; there is no flag.
 
+    **The way out** is ``ops.dispatch.rows_to_experts``: the tokens' rows
+    cast and gathered into expert order — with ``use_pallas``, at a shape
+    ``dispatch_supported`` admits (a source XLA's gather no longer reads
+    about once), one pass that casts the rows and lays each as a contiguous
+    piece and ONE kernel that fetches the row of every pair that entered a
+    group; else the cast and XLA's gather.
+
     **The way home** is ``ops.combine.weighted_combine``: each token's
     weighted sum of the rows of its pairs that entered a group — with
     ``use_pallas``, at a shape ``combine_supported`` admits, ONE kernel that
@@ -362,12 +370,15 @@ def apply_experts(layer: Dict, x: jax.Array, experts: jax.Array,
         tile_rows = gated_tile_rows(
             group_sizes, order.shape[0], *layer["up_proj"].shape[1:],
             use_pallas=use_pallas, matrices=1 + gated)
+    gm = dict(use_pallas=use_pallas, interpret=kernel_interpret)
     with jax.named_scope(scopes.EXPERTS_DISPATCH):
         # matmul operands take the stored dtype of the weights (bfloat16
-        # as deployed; float32 weights make a float32 program, for tests)
-        rows = x.astype(layer["up_proj"].dtype)[order // top_k]    # [N*k, H]
+        # as deployed; float32 weights make a float32 program, for tests);
+        # the rows past the last group belong to no expert and may hold
+        # anything: the kernel fetches none of them
+        rows = rows_to_experts(x, order // top_k, jnp.sum(group_sizes),
+                               layer["up_proj"].dtype, **gm)       # [N*k, H]
     with jax.named_scope(scopes.EXPERTS_MATMUL):
-        gm = dict(use_pallas=use_pallas, interpret=kernel_interpret)
         # the expert's first half in one call (gate, up and SiLU ⊙, or up
         # and relu^2), rounded once to what down reads
         first = dict(out_dtype=layer["down_proj"].dtype, **gm)

@@ -49,7 +49,12 @@ layer's whatever the number of attention layers.
 
 Of an encoder with routed blocks: ``routed_pairs`` at dispatch, the (token,
 expert) pairs its routers chose (real tokens x experts a token x sparse
-layers: padding is not routed); and at finalize, from the program's second
+layers: padding is not routed); from the launch plan alone,
+``dispatch_rows``, the pair rows its routed layers gather into expert order
+(the slots the routed blocks ran at x experts a token x sparse layers), and
+``dispatch_kernel_rows``, those of them that a launch asked for its kernels
+at a shape ``ops.dispatch.dispatch_supported`` takes sent through the row
+fetch (0 under XLA's gather); and at finalize, from the program's second
 output ``i32[3, sparse layers]`` (``models/olmoe.launch_stats``: each
 layer's largest expert group, the pairs that entered a held expert's group,
 the rows the fused gate / up kernel's grid visited): ``expert_rows`` = the
@@ -74,6 +79,10 @@ from typing import Any, Callable, Dict, FrozenSet, Optional, Sequence, Tuple
 import numpy as np
 
 from realtime_fraud_detection_tpu.ops.combine import combine_supported
+from realtime_fraud_detection_tpu.ops.dispatch import (
+    dispatch_supported,
+    dispatch_takes,
+)
 from realtime_fraud_detection_tpu.ops.grouped_matmul import (
     gmm_tiling,
     grouped_matmul_supported,
@@ -83,6 +92,7 @@ LAUNCH_COUNTERS: Tuple[str, ...] = (
     "token_slots", "token_slots_sq", "real_tokens",
     "expert_rows", "expert_peak_rows", "expert_tile_rows",
     "expert_token_slots", "compact_batches",
+    "dispatch_rows", "dispatch_kernel_rows",
     "routed_pairs", "attn_visible_pairs_full", "attn_visible_pairs_sliding",
     "ssm_chunks",
     "short_text_rows", "long_text_rows", "split_batches",
@@ -136,7 +146,8 @@ class TextEncoder:
     ``build_programs`` span from its ``(rows, width, capacity)`` members.
     ``dispatch_counters(config, launches, lengths)`` and
     ``finalize_counters(config, stats)`` return the encoder's own counters
-    of a batch (``launches`` have ``size``, ``width`` and ``capacity``;
+    of a batch (``launches`` have ``size``, ``width``, ``capacity`` and
+    ``kernels``, whether the program was asked for its kernels;
     ``lengths`` are the real rows' token counts; ``stats`` is the program's
     second output on the host)."""
 
@@ -214,6 +225,23 @@ def _combine_refusal(config: Any, width: int, slots: int) -> Optional[str]:
         f"the experts' combine takes whole blocks of tokens: {slots}")
 
 
+def _dispatch_shape(config: Any, slots: int) -> Tuple[int, int, int, int]:
+    """What ``dispatch_supported`` is asked of a launch's routed blocks, at
+    the checkpoint's bfloat16 (float32 weights are the tests')."""
+    return (slots, slots * config.num_experts_per_tok, config.hidden_size, 2)
+
+
+def _dispatch_refusal(config: Any, width: int, slots: int) -> Optional[str]:
+    n, pairs, hidden, itemsize = _dispatch_shape(config, slots)
+    if dispatch_supported(n, pairs, hidden, itemsize):
+        return None
+    if not dispatch_takes(pairs, hidden, itemsize):
+        return ("the experts' row fetch takes rows of whole 32-bit lane "
+                f"tiles in whole blocks: {pairs} rows of {hidden}")
+    return ("XLA's gather reads a source of "
+            f"{n * hidden * itemsize >> 20} MiB about once: {slots} slots")
+
+
 def _routed_capacities(slots: int) -> Tuple[int, ...]:
     # scoring/ imports models/: the rule is reached when it is asked
     from realtime_fraud_detection_tpu.scoring import text_split
@@ -223,9 +251,15 @@ def _routed_capacities(slots: int) -> Tuple[int, ...]:
 
 def _routed_dispatch_counters(config: Any, launches: Sequence[Any],
                               lengths: np.ndarray) -> Dict[str, int]:
+    a_slot = config.num_experts_per_tok * config.num_sparse_layers
+    slots = [(la, la.capacity or la.size * la.width) for la in launches]
     return dict(causal_counters(config, launches, lengths),
-                routed_pairs=int(lengths.sum()) * config.num_experts_per_tok
-                * config.num_sparse_layers)
+                routed_pairs=int(lengths.sum()) * a_slot,
+                dispatch_rows=sum(n for _, n in slots) * a_slot,
+                dispatch_kernel_rows=sum(
+                    n for la, n in slots if la.kernels
+                    and dispatch_supported(*_dispatch_shape(config, n))
+                ) * a_slot)
 
 
 def _routed_finalize_counters(config: Any, stats: np.ndarray
@@ -290,6 +324,8 @@ def routed_encoder(config_class: type, init: Callable[..., Dict[str, Any]],
         sites=(KernelSite("attention",
                           lambda c, width, slots: attention_refusal(c, width)),
                KernelSite("expert_gate_up", _gate_up_refusal, by_width=False),
+               KernelSite("expert_dispatch", _dispatch_refusal,
+                          by_width=False),
                KernelSite("expert_combine", _combine_refusal,
                           by_width=False)) + sites,
         planes=frozenset((TEXT_SPLIT,)),

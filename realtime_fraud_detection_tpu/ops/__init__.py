@@ -39,6 +39,13 @@ a predicate admits holds the kernel, everything else the XLA form.
   ``combine_rows``: every row of a pair that entered a group fetched once
   from HBM as one piece, the sum in VMEM, no ``[pairs, hidden]`` temporary)
   against a gather, a select and the sum; ``combine_supported``.
+- ``dispatch``: the routed experts' way out. ``rows_to_experts`` (the
+  tokens' rows cast and gathered into expert order as ONE kernel,
+  ``dispatch_rows``: the cast source laid a row a contiguous piece, every
+  row of a pair that entered a group fetched once by DMA, the block written
+  dense) against the cast and XLA's gather; ``dispatch_supported`` — a
+  shape the kernel can run at whose source XLA's gather no longer reads
+  about once (the every-slot programs of 32,768 slots of 2,048).
 - ``ssd_scan``: the state-space scan of a Mamba-2 mixer
   (``models/falcon_h1.py``, ``models/nemotron_h.py``), ``ssd_scan(...,
   use_pallas=True)`` — the chunked algorithm as ONE kernel a layer, a grid
@@ -84,6 +91,11 @@ from realtime_fraud_detection_tpu.ops.combine import (  # noqa: F401
     combine_supported,
     weighted_combine,
     weighted_combine_reference,
+)
+from realtime_fraud_detection_tpu.ops.dispatch import (  # noqa: F401
+    dispatch_reference,
+    dispatch_supported,
+    rows_to_experts,
 )
 from realtime_fraud_detection_tpu.ops.grouped_matmul import (  # noqa: F401
     grouped_gated_matmul,

@@ -141,6 +141,9 @@ class _Launch:
     # for (text_split.capacity), and the real tokens the launch holds
     capacity: Optional[int] = None
     tokens: int = 0
+    # whether the program was asked for its Pallas kernels
+    # (effective_use_pallas at the launch's width; set at dispatch)
+    kernels: bool = False
 
 
 class _SplitResult:
@@ -948,7 +951,7 @@ class FraudScorer:
                    for site in self._text.sites)
 
     def _record_kernel_dispatch(self, size: int, text_len: int,
-                                capacity: Optional[int] = None) -> None:
+                                capacity: Optional[int] = None) -> bool:
         """Host-side mirror of the per-site kernel engagement for one
         launch of ``size`` rows at ``text_len`` positions (a split batch
         records each of its two), its routed blocks at ``capacity`` token
@@ -961,7 +964,8 @@ class FraudScorer:
         dispatched when their mode asks for the Pallas kernel, and as a
         fallback when the guard routes them back — so
         ``kernel_fallback_total`` reports exactly what the compiled program
-        did, without a device readback."""
+        did, without a device readback. Returns whether the launch is asked
+        for the encoder's kernels."""
         disp, fall = (self._kernel_counts["dispatch"],
                       self._kernel_counts["fallback"])
         asked = self.effective_use_pallas(
@@ -972,7 +976,7 @@ class FraudScorer:
                                           slots) is None
             (disp if held else fall)[site.name] += 1
         if not self.kernels.enabled:
-            return
+            return asked
         from realtime_fraud_detection_tpu.models.quant import (
             is_quantized_bert,
         )
@@ -1002,6 +1006,7 @@ class FraudScorer:
             disp["epilogue"] += 1
             if not epilogue_supported(size, NUM_MODELS):
                 fall["epilogue"] += 1
+        return asked
 
     def kernel_snapshot(self) -> Dict[str, Any]:
         """Kernel-plane observability payload (obs.metrics.sync_kernels):
@@ -1319,8 +1324,8 @@ class FraudScorer:
             mv = self.effective_model_valid()
             rules_only = self._qos_rules_only
             for launch in launches:
-                self._record_kernel_dispatch(launch.size, launch.width,
-                                             launch.capacity)
+                launch.kernels = self._record_kernel_dispatch(
+                    launch.size, launch.width, launch.capacity)
             token = None
             if self._pool is not None:
                 # pooled mode: the whole microbatch runs on ONE replica

@@ -395,6 +395,76 @@ def test_the_combine_compiles_at_every_bucket_and_rung(one_chip, encoder,
     assert top_k == 1 or f"f32[{rows},{hidden}]" not in text
 
 
+# the programs whose way out is the row fetch: a cell's every-slot program
+# of 32,768 slots of 2,048 (slots, a token's experts, hidden)
+DISPATCH_SITES = {"olmoe_every_slot": (32768, 8, 2048),
+                  "zaya1_every_slot": (32768, 1, 2048)}
+
+
+@pytest.mark.parametrize("site", sorted(DISPATCH_SITES))
+def test_the_row_fetch_compiles_at_the_every_slot_programs(one_chip, site):
+    """The experts' way out (``ops/dispatch.py``) as TWO Mosaic calls at
+    OLMoE's and ZAYA1's every-slot shapes: ``lay_rows`` (the cast and the
+    re-laying, one pass: the slots' bfloat16 rows as ``u32[slots, hidden /
+    256, 128]``, a row one contiguous piece) and ``dispatch_rows`` at the
+    block of rows ``dispatch_tile`` picks (single rows fetched out of that
+    array in HBM, written as the dense ``bf16[pairs, hidden]`` the grouped
+    kernels read), inside the budget the call names; XLA adds no pass of
+    its own between them: the source exists once."""
+    import re
+
+    from realtime_fraud_detection_tpu.ops.dispatch import (
+        dispatch_supported,
+        dispatch_tile,
+        dispatch_vmem_bytes,
+        rows_to_experts,
+    )
+    from realtime_fraud_detection_tpu.ops.grouped_matmul import VMEM_CEILING
+
+    slots, top_k, hidden = DISPATCH_SITES[site]
+    pairs = slots * top_k
+    assert dispatch_supported(slots, pairs, hidden, 2)
+    assert dispatch_vmem_bytes(dispatch_tile(pairs), hidden,
+                               2) <= VMEM_CEILING
+    fn = jax.jit(lambda x, src, held: rows_to_experts(
+        x, src, held, jnp.bfloat16, use_pallas=True))
+    compiled = fn.lower(_sds((slots, hidden), jnp.float32, one_chip),
+                        _sds((pairs,), jnp.int32, one_chip),
+                        _sds((), jnp.int32, one_chip)).compile()
+    text = compiled.as_text()
+    assert text.count(CUSTOM_CALL) == 2
+    assert "jit(lay_rows)/lay_rows/pallas_call" in text
+    assert "jit(dispatch_rows)/dispatch_rows/pallas_call" in text
+    assert f"u32[{slots},{hidden // 256},128]" in text
+    entry = text[text.index("ENTRY "):]
+    assert not re.search(rf" = \w+\[{slots},[\d,]+\]\S* (copy|fusion)\(",
+                         entry)
+    # the call's result is the array itself, row-major: nothing re-lays it
+    assert re.search(rf"ROOT %\S+ = bf16\[{pairs},{hidden}\]\S* "
+                     r"custom-call\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * (
+        slots * hidden * 2)
+
+
+def test_a_program_that_keeps_xlas_gather_traces_no_row_fetch(one_chip):
+    """OLMoE's compact program (24,576 slots: a source of 96 MiB) asked for
+    its kernels: the predicate declines, the way out is XLA's gather and no
+    Mosaic call."""
+    from realtime_fraud_detection_tpu.ops.dispatch import (
+        dispatch_rows,
+        rows_to_experts,
+    )
+
+    before = dispatch_rows._cache_size()
+    text = jax.jit(lambda x, src, held: rows_to_experts(
+        x, src, held, jnp.bfloat16, use_pallas=True)).lower(
+            _sds((24576, 2048), jnp.float32, one_chip),
+            _sds((196608,), jnp.int32, one_chip),
+            _sds((), jnp.int32, one_chip)).compile().as_text()
+    assert CUSTOM_CALL not in text and " gather(" in text
+    assert dispatch_rows._cache_size() == before
+
+
 @pytest.mark.parametrize("bucket,text_len", [
     (BUCKET, 128), (8, 128), (1, 128), (32, DEPLOYED_TEXT_LEN)],
     ids=["bucket256", "parity_bucket8", "bucket1", "halo_at_512"])
@@ -448,7 +518,12 @@ def test_routed_program_compiles_with_every_large_pass_under_a_scope(
     ``gated_gmm``, down as ``down_gmm``, with no float32 ``[rows, I]``
     between them, and the way home as ``weighted_combine``, with no
     ``[pairs, hidden]`` float32 array anywhere: no gather of one, no
-    relayout ``copy`` of one — and, at the
+    relayout ``copy`` of one — two more in the every-slot program, whose way
+    out is ``lay_rows`` and the row fetch ``dispatch_rows`` under
+    ``experts/dispatch`` (a source of 128 MiB:
+    ``ops.dispatch.dispatch_supported``), where the
+    three-quarters program keeps XLA's gather and holds nothing of that
+    kernel — and, at the
     attention site, OLMoE's fused causal core ``windowed_attention`` or
     ZAYA1's fused mixing ``ops/cca_mix.py``), a second small output,
     temporaries that
@@ -492,16 +567,25 @@ def test_routed_program_compiles_with_every_large_pass_under_a_scope(
     text = compiled.as_text()
     # the experts' two grouped kernels and their combine, and the attention
     # site's kernel
-    assert text.count(CUSTOM_CALL) == 4 * config.num_hidden_layers
+    fetch = capacity is None
+    assert text.count(CUSTOM_CALL) == (
+        4 + 2 * fetch) * config.num_hidden_layers
     site = "cca_mix" if encoder == "zaya1" else "windowed_attention"
     for kernel in (site, "gated_gmm", "down_gmm", "weighted_combine"):
         assert len(re.findall(rf"%{kernel}\S* = .*custom-call\(", text)) == (
             config.num_hidden_layers)
+    for kernel in ("lay_rows", "dispatch_rows"):
+        assert len(re.findall(rf"%{kernel}\S* = .*custom-call\(",
+                              text)) == fetch * config.num_hidden_layers
+        assert (f"jit({kernel})" in text) == fetch
     # each under the scope the trace's attribution reads it by
     for part, call in (
             ("matmul", "jit(gated_gmm)/gated_gmm/pallas_call"),
             ("matmul", "jit(down_gmm)/down_gmm/pallas_call"),
-            ("combine", "jit(combine_rows)/weighted_combine/pallas_call")):
+            ("combine", "jit(combine_rows)/weighted_combine/pallas_call"),
+            *([("dispatch", "jit(lay_rows)/lay_rows/pallas_call"),
+               ("dispatch", "jit(dispatch_rows)/dispatch_rows/pallas_call")]
+              if fetch else [])):
         assert len(re.findall(
             rf' custom-call\(.*op_name="[^"]*/experts/{part}/'
             rf'{re.escape(call)}"', text)) == config.num_hidden_layers, call

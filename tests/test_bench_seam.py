@@ -95,6 +95,18 @@ NEMOTRON_CFG = json.loads(
 NEMOTRON_ONLY = ["nemotron3_ssd_scan_roofline_pct",
                  "nemotron3_expert_ffn_roofline_pct",
                  "nemotron3_attn_core_roofline_pct"]
+# PR 53: the routed experts' way out, in the seven routed cells — a scope's
+# time and a counter's share, each a data file over a reader the benchmark
+# had
+DISPATCH_METRICS = {
+    "expert_dispatch_ms_per_batch": (
+        "ms", "lower", "device_trace",
+        {"reader": "scope_time_per_batch",
+         "args": {"scopes": ["text/layer*/experts/dispatch"]}}),
+    "dispatch_kernel_pct": (
+        "%", "higher", "program_counter",
+        {"reader": "counter_share",
+         "args": {"num": "dispatch_kernel_rows", "den": "dispatch_rows"}})}
 
 
 # ------------------------------------------------------ configuration files
@@ -454,6 +466,15 @@ def _before_pr50():
     own at the end of ``per_layer``. What is left is the benchmark PR 48
     left, which the asserts below count from the end of."""
     bm = json.loads(json.dumps(BM))
+    # (and behind PR 50's, what PR 53 appended: two metrics, nothing else)
+    for name in reversed(DISPATCH_METRICS):
+        unit, better, source, data = DISPATCH_METRICS[name]
+        assert bm["per_layer"].pop() == {
+            "name": name, "unit": unit, "better": better, "source": source,
+            "layer": "kernels", "moves": "txn_per_s",
+            "workloads": ROUTED_CELLS + [NEMOTRON_CELL]}
+        assert json.loads((ROOT / "benchmarks/layer_metrics"
+                           / f"{name}.json").read_text()) == data
     assert bm["configs"].pop()["name"] == "nemotron-3-nano-30b-s2048"
     assert bm["workloads"].pop() == {
         "name": NEMOTRON_CELL, "config": "nemotron-3-nano-30b-s2048",
@@ -471,7 +492,8 @@ def _before_pr50():
             assert m["workloads"].pop() == NEMOTRON_CELL, m["name"]
             assert NEMOTRON_CELL not in m["workloads"]
             listed.add(m["name"])
-    assert listed | set(NEMOTRON_ONLY) | {"setup_s"} == {
+    assert listed | set(NEMOTRON_ONLY) | set(DISPATCH_METRICS) | {
+            "setup_s"} == {
         m["name"] for kind in ("end_to_end", "per_layer")
         for m in spec.metrics_for(NEMOTRON_CELL, kind)}
     return bm, listed

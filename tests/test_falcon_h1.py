@@ -503,7 +503,7 @@ def test_the_class_picks_the_encoder_and_answers_the_seams_questions():
     assert dense.narrow_width(TINY_CONFIG) == 128 and len(dense.planes) == 5
     routed = pipeline.text_encoder(TINY_OLMOE)
     assert [site.name for site in routed.sites] == [
-        "attention", "expert_gate_up", "expert_combine"]
+        "attention", "expert_gate_up", "expert_dispatch", "expert_combine"]
     assert sum(row.capacities(4096) is not None
                for row in pipeline.TEXT_ENCODERS.values()) == 5
 

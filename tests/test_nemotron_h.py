@@ -772,7 +772,8 @@ def test_one_row_of_the_seam_is_routed_and_state_space_at_once(rung_scorer):
     row = pipeline.text_encoder(CFG)
     assert row is nemotron_h.TEXT_ENCODER
     assert [site.name for site in row.sites] == [
-        "attention", "expert_gate_up", "expert_combine", "ssm_scan"]
+        "attention", "expert_gate_up", "expert_dispatch", "expert_combine",
+        "ssm_scan"]
     assert pipeline.text_layers(CFG) == 5
     assert row.capacities(4096) == (3072, 4096)
     assert NemotronHConfig in pipeline.TextConfig.__args__
@@ -784,7 +785,7 @@ def test_one_row_of_the_seam_is_routed_and_state_space_at_once(rung_scorer):
     snap = scorer.kernel_snapshot()
     # a CPU mesh is never asked for its kernels: a fallback at every site
     for site in ("attention", "ssm_scan", "expert_gate_up",
-                 "expert_combine"):
+                 "expert_dispatch", "expert_combine"):
         assert snap["fallback"][site] == before["fallback"][site] + 1, site
         assert snap["dispatch"][site] == 0
     assert "head_dim 16" in snap["refused"]["attention"]

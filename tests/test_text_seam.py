@@ -55,11 +55,13 @@ SCANNED = ("falconh1", "nemotron3")
 
 # what StreamJob.counters held at the parent (PR 48), letter for letter:
 # benchmarks/kernels/*.py and benchmarks/readers/*.py read these names
+# (and since PR 53 the two of the experts' way out)
 JOB_COUNTERS = (
     "scored", "alerts", "batches", "duplicates_skipped", "errors", "shed",
     "token_slots", "token_slots_sq", "real_tokens",
     "expert_rows", "expert_peak_rows", "expert_tile_rows",
     "expert_token_slots", "compact_batches",
+    "dispatch_rows", "dispatch_kernel_rows",
     "routed_pairs", "attn_visible_pairs_full", "attn_visible_pairs_sliding",
     "ssm_chunks", "short_text_rows", "long_text_rows", "split_batches")
 
@@ -117,7 +119,7 @@ def test_the_snapshot_counts_each_encoders_own_sites(first_batch):
     name, scorer = first_batch["name"], first_batch["scorer"]
     sites = {"dequant_matmul", "epilogue", "attention"}
     if name in ROUTED:
-        sites |= {"expert_gate_up", "expert_combine"}
+        sites |= {"expert_gate_up", "expert_dispatch", "expert_combine"}
     if name in SCANNED:
         sites |= {"ssm_scan"}
     snap = scorer.kernel_snapshot()
@@ -160,8 +162,12 @@ def test_a_batchs_counters_against_a_hand_count(first_batch):
         # the mix fits three quarters of the bucket's 4,096 slots
         assert tokens <= 3072
         pairs = tokens * config.num_experts_per_tok * config.num_sparse_layers
+        # every pair row of the rung goes out through XLA's gather: a CPU
+        # mesh is never asked for the row fetch
         want.update(expert_token_slots=3072, compact_batches=1,
-                    routed_pairs=pairs, expert_rows=pairs)
+                    routed_pairs=pairs, expert_rows=pairs,
+                    dispatch_rows=3072 * config.num_experts_per_tok
+                    * config.num_sparse_layers)
         if name == "laguna":
             # a window of 8 positions; a quarter of the router's experts
             # are held here, so fewer pairs entered a group than were chosen
@@ -220,7 +226,7 @@ def test_the_jobs_counters_are_the_same_names_for_every_encoder(first_batch):
     # (after the tests of the first batch alone: this one launches more)
     scorer, gen = first_batch["scorer"], first_batch["gen"]
     assert scorer_mod.LAUNCH_COUNTERS is LAUNCH_COUNTERS
-    assert len(JOB_COUNTERS) == 21
+    assert len(JOB_COUNTERS) == 23
     broker = InMemoryBroker()
     job = StreamJob(broker, scorer, JobConfig(max_batch=8))
     # every name from the start, at 0, whichever the encoder fills

@@ -4,6 +4,7 @@ tiles: the sweep ``ops/grouped_matmul.gmm_tiling``'s constants come from.
     chiprun -- python3 tools/grouped_alone.py --out chiprun_out/grouped_alone.json
     chiprun -- python3 tools/grouped_alone.py --combine --out chiprun_out/x.json
     chiprun -- python3 tools/grouped_alone.py --relu2 --out chiprun_out/y.json
+    chiprun -- python3 tools/grouped_alone.py --dispatch --out chiprun_out/z.json
     JAX_PLATFORMS=cpu python3 tools/grouped_alone.py --aot     # no chip
 
 For each routed encoder (OLMoE, ZAYA1, Laguna, JoyAI) at both capacity
@@ -52,11 +53,27 @@ the shipped ``[G, N, K]`` form fed ``swapaxes`` of that array
 (with each form's temporaries and whether the compiled text holds a ``copy``
 of the matrices) and ``--rehearse`` work with it.
 
+``--dispatch`` (PR 53) times the way OUT alone — the tokens' rows gathered
+into expert order — at the (source rows, pairs, hidden, ``top_k``) of the
+seven routed cells' programs, both rungs: XLA's cast and gather
+(``x.astype(bf16)[src]``, what the parent traces), the gather alone from a
+source that is already bfloat16, the cast and the re-laying alone in XLA
+(``ops.dispatch.row_pieces``) and as one kernel (``lay_rows``), and
+``ops.dispatch.dispatch_rows`` behind ``lay_rows`` at 256 to
+1,024 output rows a step, fetching every row and fetching only the rows
+under ``sum(group_sizes)``; ``src`` from a real stable sort, bit-equality on
+the rows of a group beside each time. For the record and the next issue,
+XLA's gather of 262,144 rows out of ``bf16[32768, 1024]`` beside
+``bf16[32768, 2048]`` and ``bf16[24576, 2048]`` (and sources of 112 and
+114 MiB): whether its pace follows the source's size.
+``tools/grouped_alone_pr53.json`` is its output, and
+``ops.dispatch.XLA_KEEPS_BYTES`` is read off it; ``--aot`` and
+``--rehearse`` work with it.
+
 Not part of the package's import graph and not under ``benchmarks/``: a
 builder's instrument (ROADMAP D18). The next users are what is left of S12:
-the dispatch gathers (the every-slot program's ``bf16[262144, 2048]`` rows
-into expert order, ZAYA1's compaction gather) and ``routed_block``'s scatter
-home, whose candidates want the same shapes, group layouts and clock.
+ZAYA1's compaction gather and ``routed_block``'s scatter home, whose
+candidates want the same shapes, group layouts and clock.
 """
 
 from __future__ import annotations
@@ -82,6 +99,14 @@ from realtime_fraud_detection_tpu.ops.combine import (
     combine_rows,
     combine_tokens,
     weighted_combine_reference,
+)
+from realtime_fraud_detection_tpu.ops.dispatch import (
+    dispatch_reference,
+    dispatch_rows,
+    dispatch_supported,
+    dispatch_takes,
+    lay_rows,
+    row_pieces,
 )
 from realtime_fraud_detection_tpu.ops.grouped_matmul import (
     LANES,
@@ -773,6 +798,167 @@ def relu2_aot(sites):
                     print(encoder, rung, tiling, form, verdict, flush=True)
 
 
+# ----------------------------------------------------------------- --dispatch
+# cell's program: (source rows, a token's experts, hidden, the router's
+# width, the experts held, real tokens) — a cell's two rungs; the real tokens
+# are what the cell's traffic leaves of a rung (token_padding_pct: ledger,
+# PR 51)
+DISPATCH_SITES = {
+    "olmoe.three_quarters": (24576, 8, 2048, 64, 64, 21300),
+    "olmoe.every_slot": (32768, 8, 2048, 64, 64, 28150),
+    "zaya1.three_quarters": (24576, 1, 2048, 16, 16, 21300),
+    "zaya1.every_slot": (32768, 1, 2048, 16, 16, 28150),
+    "laguna.three_quarters": (12288, 10, 3072, 256, 64, 11500),
+    "laguna.every_slot": (16384, 10, 3072, 256, 64, 15400),
+    "joyai.three_quarters": (12288, 8, 2048, 256, 256, 9900),
+    "joyai.every_slot": (16384, 8, 2048, 256, 256, 13500),
+    "nemotron3.three_quarters": (12288, 6, 2688, 128, 128, 10100),
+    "nemotron3.every_slot": (16384, 6, 2688, 128, 128, 13500),
+}
+DISPATCH_TINY = {"tiny.every_slot": (256, 2, 256, 8, 8, 200)}
+DISPATCH_ROWS = (256, 512, 1024)
+TURNS = (4, 16)
+# XLA's gather of OLMoE's every-slot pairs by the source's size: (source
+# rows, hidden), 262,144 rows out of each
+GATHER_SOURCES = ((32768, 1024), (32768, 2048), (24576, 2048),
+                  # where the line falls (112 and 114 MiB)
+                  (28672, 2048), (29184, 2048))
+
+
+def dispatch_programs(site, interpret: bool = False):
+    """``{form: (x, src, held) -> bf16[pairs, H]}`` for one program's
+    shapes; ``x`` float32, as ``apply_experts`` holds it."""
+    n, top_k, hidden, _, _, _ = site
+    dtype = jnp.dtype(jnp.bfloat16)
+    forms = {
+        "xla_cast_and_gather": lambda x, src, held: dispatch_reference(
+            x, src, dtype),
+        "xla_gather_alone": lambda xb, src, held: xb[src]}
+    if dispatch_takes(n * top_k, hidden, dtype.itemsize):
+        forms["row_pieces_alone"] = lambda x, src, held: row_pieces(x, dtype)
+        forms["lay_rows_alone"] = lambda x, src, held: lay_rows(
+            x, dtype=dtype, interpret=interpret)
+    if dispatch_takes(n * top_k, hidden, dtype.itemsize):
+        for rows in DISPATCH_ROWS:
+            if (n * top_k) % rows:
+                continue
+            for skip in (False, True):
+                forms[f"kernel_tm{rows}" + ("_held" if skip else "_all")] = (
+                    lambda x, src, held, rows=rows, skip=skip: dispatch_rows(
+                        lay_rows(x, dtype=dtype, interpret=interpret), src,
+                        held if skip else jnp.int32(src.shape[0]),
+                        dtype=dtype, rows=rows, interpret=interpret))
+        # the copies started a turn of the scalar loop (the module's
+        # constant is read when the body is traced)
+        for turn in TURNS:
+            forms[f"kernel_tm1024_held_turn{turn}"] = functools.partial(
+                _at_turn, turn, dtype, interpret)
+    return {name: jax.jit(fn) for name, fn in forms.items()}
+
+
+def _at_turn(turn, dtype, interpret, x, src, held):
+    dm = sys.modules["realtime_fraud_detection_tpu.ops.dispatch"]
+    shipped, dm.ROWS_A_TURN = dm.ROWS_A_TURN, turn
+    try:
+        return dispatch_rows.__wrapped__(
+            lay_rows(x, dtype=dtype, interpret=interpret), src, held,
+            dtype=dtype, rows=1024 if src.shape[0] % 1024 == 0 else 256,
+            interpret=interpret)
+    finally:
+        dm.ROWS_A_TURN = shipped
+
+
+def dispatch_sweep(sites, out_path, interpret=False, sources=GATHER_SOURCES):
+    device = jax.devices()[0]
+    result = {"device": {"platform": device.platform,
+                         "kind": device.device_kind},
+              "clock": f"host, fastest of {REPEATS} x {CALLS} calls",
+              "sites": {}, "xla_gather_by_source": {}}
+    draw = jax.jit(routed, static_argnums=(1, 2, 3, 4, 5, 6))
+
+    def save():
+        os.makedirs(os.path.dirname(out_path) or ".", exist_ok=True)
+        with open(out_path, "w") as f:
+            json.dump(result, f, indent=1)
+
+    for name, site in sites.items():
+        n, top_k, hidden, width, groups, real = site
+        pairs = n * top_k
+        sizes, home, _, _, _ = draw(jax.random.PRNGKey(53), n, real, top_k,
+                                    width, groups, 8.0)
+        # the token each sorted pair reads: the inverse of ``home``
+        src = jnp.zeros((pairs,), jnp.int32).at[home.reshape(-1)].set(
+            jnp.arange(pairs, dtype=jnp.int32) // top_k)
+        held = jnp.sum(sizes)
+        x = jax.random.normal(jax.random.PRNGKey(54), (n, hidden),
+                              jnp.float32)
+        row = result["sites"][name] = {
+            "source_rows": n, "pairs": pairs, "hidden": hidden,
+            "top_k": top_k, "held_pairs": int(held),
+            "source_mib": n * hidden * 2 / 2 ** 20,
+            "dispatch_supported": dispatch_supported(n, pairs, hidden, 2),
+            "forms": {}}
+        want = None
+        for form, fn in dispatch_programs(site, interpret).items():
+            arg = x.astype(jnp.bfloat16) if form == "xla_gather_alone" else x
+            try:
+                ms = timed_ms(fn, arg, src, held)
+                cell = row["forms"][form] = {"ms": ms}
+                if not form.endswith("_alone") or form.startswith("xla"):
+                    cell["ns_per_row"] = ms * 1e6 / pairs
+                    got = fn(arg, src, held)
+                    want = got if want is None else want
+                    cell["bit_equal_on_held_rows"] = bool(
+                        compare(int(held))(got, want)[0])
+                    del got
+            except Exception as e:  # noqa: BLE001 — refused
+                row["forms"][form] = {"error": str(e)[-300:]}
+        del want, x
+        print(name, json.dumps(row), flush=True)
+        save()
+        gc.collect()
+    # for the record: does XLA's pace a row follow the source's size?
+    rows = 262144 if not interpret else 512
+    for n, hidden in sources:
+        src = jax.random.randint(jax.random.PRNGKey(55), (rows,), 0, n)
+        src = jnp.sort(src.reshape(64, -1), axis=1).reshape(-1)
+        xb = jax.random.normal(jax.random.PRNGKey(56), (n, hidden),
+                               jnp.float32).astype(jnp.bfloat16)
+        ms = timed_ms(jax.jit(lambda xb, src: xb[src]), xb, src)
+        result["xla_gather_by_source"][f"bf16[{n},{hidden}]"] = {
+            "rows": rows, "source_mib": n * hidden * 2 / 2 ** 20, "ms": ms,
+            "ns_per_row": ms * 1e6 / rows,
+            "gb_per_s_written": rows * hidden * 2 / ms / 1e6}
+        print(n, hidden, result["xla_gather_by_source"], flush=True)
+        save()
+        del xb
+        gc.collect()
+    return result
+
+
+def dispatch_aot(sites):
+    """Every program of ``dispatch_sweep`` compiled for a described v5e."""
+    chip = described_chip()
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    for name, site in sites.items():
+        n, top_k, hidden = site[:3]
+        for form, fn in dispatch_programs(site).items():
+            x = sds((n, hidden), jnp.bfloat16 if form == "xla_gather_alone"
+                    else jnp.float32)
+            try:
+                compiled = fn.lower(x, sds((n * top_k,), jnp.int32),
+                                    sds((), jnp.int32)).compile()
+                verdict = "ok, temporaries %.2f GB" % (
+                    compiled.memory_analysis().temp_size_in_bytes / 1e9)
+            except Exception as e:  # noqa: BLE001
+                verdict = "REFUSED " + str(e).strip()[-160:].replace(
+                    "\n", " ")
+            print(name, form, verdict, flush=True)
+
+
 def described_chip():
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
@@ -822,7 +1008,21 @@ def main():
     ap.add_argument("--spellings", nargs="*", default=list(SPELLINGS))
     ap.add_argument("--relu2", action="store_true",
                     help="the ungated first half in three forms (PR 51)")
+    ap.add_argument("--dispatch", action="store_true",
+                    help="the way out: XLA's gather beside the row fetch "
+                         "(PR 53)")
     args = ap.parse_args()
+    if args.dispatch:
+        if args.rehearse:
+            dispatch_sweep(DISPATCH_TINY, args.out, interpret=True,
+                           sources=((64, 128), (64, 256)))
+        elif args.aot:
+            dispatch_aot(DISPATCH_SITES)
+        else:
+            dispatch_sweep({k: v for k, v in DISPATCH_SITES.items()
+                            if not args.encoders
+                            or k.split(".")[0] in args.encoders}, args.out)
+        return
     if args.relu2:
         if args.rehearse:
             relu2_sweep(RELU2_TINY, args.out, interpret=True)
