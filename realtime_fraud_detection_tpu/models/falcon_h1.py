@@ -328,13 +328,18 @@ def init_falcon_h1_params(key: jax.Array, config: FalconH1Config) -> Dict:
     }
 
 
-def causal_conv(x: jax.Array, taps: jax.Array, bias: jax.Array) -> jax.Array:
+def causal_conv(x: jax.Array, taps: jax.Array,
+                bias: Optional[jax.Array] = None) -> jax.Array:
     """Depthwise causal convolution over positions: ``x`` ``f32[B, T, C]``,
     ``taps`` ``f32[K, C]`` (tap ``K - 1`` weighs position t itself, tap 0
-    position ``t - K + 1``), zeros before the row."""
+    position ``t - K + 1``), zeros before the row; ``bias`` ``f32[C]`` or
+    None (a mixer whose convolution has none: ``models/qwen3_next.py``).
+    The channels are the caller's: every mixer with such a convolution
+    calls this."""
     k, t = taps.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    return bias + sum(padded[:, i:i + t] * taps[i] for i in range(k))
+    conv = sum(padded[:, i:i + t] * taps[i] for i in range(k))
+    return conv if bias is None else bias + conv
 
 
 def group_rms_norm(x: jax.Array, weight: jax.Array, groups: int, eps: float

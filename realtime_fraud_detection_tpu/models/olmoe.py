@@ -470,8 +470,10 @@ def routed_block(layer: Dict, x: jax.Array,
     matmul for OLMoE, an MLP with state for ZAYA1), and ``carry`` is
     whatever it hands its next layer, on the same C rows. ``shared``
     (None: the encoder has none) maps the same rows to what an expert
-    every token passes through adds, under a scope of its own; it is added
-    on the real rows before the result goes home. ``router_width`` and
+    every token passes through adds, under a scope of its own — under
+    whatever gate the encoder's own callback applies
+    (``models/qwen3_next.gated_shared_expert``: a scalar sigmoid a token);
+    it is added on the real rows before the result goes home. ``router_width`` and
     ``expert_offset`` are ``apply_experts``': which of the router's experts
     this layer holds."""
     n, width = x.shape

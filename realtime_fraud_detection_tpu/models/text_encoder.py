@@ -63,12 +63,14 @@ held pairs (all of ``routed_pairs`` where a layer holds every expert),
 (what the launch would cost were every group as large as the largest),
 ``expert_tile_rows`` = the visited rows (0 in the XLA form). Its class
 spells, under the Hugging Face names, ``num_experts`` (the experts a layer
-HOLDS), ``num_experts_per_tok``, ``hidden_size``, ``intermediate_size``
-(ONE expert's width) and ``num_sparse_layers``.
+HOLDS), ``num_experts_per_tok``, ``hidden_size``, ONE expert's width
+(``moe_intermediate_size`` where the source spells one, else
+``intermediate_size``: ``expert_width``) and ``num_sparse_layers``.
 
 Of an encoder with a state-space mixer: ``ssm_chunks`` at dispatch, the
 chunks its scans walked (``token_slots`` / the scan's chunk x the layers
-that have such a mixer).
+that have such a mixer); of one with a delta-rule mixer
+(``models/qwen3_next.py``) ``delta_chunks``, the same of its scans.
 """
 
 from __future__ import annotations
@@ -96,6 +98,7 @@ LAUNCH_COUNTERS: Tuple[str, ...] = (
     "routed_pairs", "attn_visible_pairs_full", "attn_visible_pairs_sliding",
     "ssm_chunks",
     "short_text_rows", "long_text_rows", "split_batches",
+    "delta_chunks",
 )
 
 # the planes a deployment may ask the text branch to run under; a row names
@@ -212,10 +215,18 @@ def causal_counters(config: Any, launches: Sequence[Any],
 
 
 # ------------------------------------------- what the routed encoders share
+def expert_width(config: Any) -> int:
+    """ONE routed expert's width: the source's ``moe_intermediate_size``
+    where it spells one (its ``intermediate_size`` may then be a dense
+    layer's: ``models/qwen3_next.py``), else ``intermediate_size``."""
+    return getattr(config, "moe_intermediate_size", None) \
+        or config.intermediate_size
+
+
 def _gate_up_refusal(config: Any, width: int, slots: int) -> Optional[str]:
     rows = slots * config.num_experts_per_tok
     return None if grouped_matmul_supported(
-        rows, config.hidden_size, config.intermediate_size) else (
+        rows, config.hidden_size, expert_width(config)) else (
         f"the grouped expert matmul takes whole lane tiles: {rows} rows")
 
 
@@ -281,7 +292,7 @@ def expert_tiles(config: Any, programs: Sequence[tuple], matrices: int = 2
     (``ops.grouped_matmul.gmm_tiling``, by the same shapes; ``matrices``:
     the right-hand blocks a step of the experts' first call); nothing where
     a capacity runs the XLA form."""
-    hidden, width = config.hidden_size, config.intermediate_size
+    hidden, width = config.hidden_size, expert_width(config)
     tiles = []
     for _, _, rung in programs:
         rows = rung * config.num_experts_per_tok

@@ -111,6 +111,21 @@ NEMOTRON_H_LAYER_SCOPES: Tuple[str, ...] = (
     LN, SSM_PROJ, SSM_CONV, SSM_SCAN, ATTN_PROJ, ATTN_CORE, ROUTER, EXPERTS,
     SHARED_EXPERT)
 
+# ---- device: under ``text`` where the encoder is models/qwen3_next.py:
+# every layer holds ``ln`` (both zero-centred RMSNorms and the residual
+# adds), ``router`` / ``experts`` / ``shared_expert`` (the shared one under
+# its scalar gate) and the scopes of its mixer's KIND — a softmax-attention
+# layer ``attn_proj`` (q with its gate, k, v, o; in the XLA form also the
+# per-head norms and the rotation) and ``attn_core``, a Gated-DeltaNet layer
+DELTA_PROJ = "delta_proj"    # in_proj_qkvz and in_proj_ba by part, the
+                             # gated per-head RMSNorm, out_proj
+DELTA_CONV = "delta_conv"    # the depthwise causal convolution, its SiLU,
+                             # q's and k's L2 norms, beta and the log-decay
+DELTA_SCAN = "delta_scan"    # the delta-rule scan alone (ops/delta_scan.py)
+QWEN3_NEXT_LAYER_SCOPES: Tuple[str, ...] = (
+    LN, DELTA_PROJ, DELTA_CONV, DELTA_SCAN, ATTN_PROJ, ATTN_CORE, ROUTER,
+    EXPERTS, SHARED_EXPERT)
+
 
 def layer_scope(i: int) -> str:
     return f"{LAYER}{i}"

@@ -7,7 +7,10 @@ a predicate admits holds the kernel, everything else the XLA form.
   in VMEM) against ``attention_reference``; ``flash_supported``.
   ``windowed_attention`` (the causal core at ``head_dim`` 128 with grouped
   keys, an optional sliding window and the rows' lengths: online softmax
-  over the key blocks a query block sees; handed the weights of a QK-norm
+  over the key blocks a query block sees; handed the weights of a norm a
+  HEAD, the form that takes heads of 128 or 256 — two lane tiles — with the
+  rotation inside a head's first tile and a gate a lane,
+  ``models/qwen3_next.py``; handed the weights of a QK-norm
   over all heads, its one-block form, every head of a row a program;
   handed a shared key, its latent form: a second score term from ONE
   rotated key every head shares, ``models/joyai.py``) against
@@ -53,6 +56,14 @@ a predicate admits holds the kernel, everything else the XLA form.
   a lane tile or heads of 64 two a tile — against the same algorithm in
   ``jax.numpy`` (``use_pallas=False``); ``ssd_refusal``, asked through the
   configuration's ``scan_refusal``.
+- ``delta_scan``: the gated delta-rule scan of a Gated-DeltaNet mixer
+  (``models/qwen3_next.py``), ``gated_delta_scan(..., use_pallas=True)`` —
+  the chunked WY form (a unit-lower-triangular solve a chunk, as a product
+  of ``I + A^(2^k)``, ahead of the products against the carried state) as
+  ONE kernel a layer, a grid over (row, group of key heads, chunk) with the
+  value heads' 128 x 128 states in VMEM — against the same algorithm in
+  ``jax.numpy`` (``use_pallas=False``); ``delta_refusal``, asked through
+  ``Qwen3NextConfig.scan_refusal``.
 - ``dequant_matmul``, ``epilogue``: the int8 text branch's fused
   dequant-matmul and the score-and-blend epilogue, behind ``KernelSettings``.
 """
@@ -72,6 +83,10 @@ from realtime_fraud_detection_tpu.ops.attention import (  # noqa: F401
 from realtime_fraud_detection_tpu.ops.cca_mix import (  # noqa: F401
     cca_mix_fused,
     cca_mix_refusal,
+)
+from realtime_fraud_detection_tpu.ops.delta_scan import (  # noqa: F401
+    delta_refusal,
+    gated_delta_scan,
 )
 from realtime_fraud_detection_tpu.ops.dequant_matmul import (  # noqa: F401
     dequant_matmul,

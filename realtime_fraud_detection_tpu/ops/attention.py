@@ -242,7 +242,8 @@ WINDOW_BLOCK = LANES     # queries and keys a block: one lane tile of scores
 def windowed_refusal(seq_len: int, head_dim: int, num_heads: int,
                      num_kv_heads: int, window: int | None,
                      qk_norm: bool = False, shared_key_dim: int | None = None,
-                     value_dim: int | None = None) -> str | None:
+                     value_dim: int | None = None,
+                     head_norm: bool = False) -> str | None:
     """Why ``windowed_attention`` does not take a shape, by name, or None
     where it does. The ONE predicate: the traced guards in
     ``models/laguna.py``, ``models/olmoe.py`` and ``models/joyai.py`` and
@@ -251,8 +252,22 @@ def windowed_refusal(seq_len: int, head_dim: int, num_heads: int,
     call hands it a second score term that wide from ONE key head all query
     heads share (the latent form: ``head_dim`` is then the per-head part of
     a score, ``value_dim`` the values' width where it is not
-    ``head_dim``)."""
-    if head_dim != LANES or (value_dim or head_dim) != LANES:
+    ``head_dim``). ``head_norm``: the call hands it a norm over each HEAD
+    (``models/qwen3_next.py``), the form that also takes heads of TWO lane
+    tiles (scores summed over both, values two tiles wide), with no window,
+    no whole-projection norm and no shared key."""
+    if head_norm:
+        if head_dim not in (LANES, 2 * LANES) \
+                or (value_dim or head_dim) != head_dim:
+            return (f"windowed_attention takes heads of {LANES} or "
+                    f"{2 * LANES} (one or two lane tiles a head) under a "
+                    f"per-head norm: head_dim {head_dim}"
+                    + (f", value_dim {value_dim}" if value_dim else ""))
+        if window is not None or qk_norm or shared_key_dim is not None:
+            return ("windowed_attention norms q and k a head at a time with "
+                    "no window, no norm over the whole projection and no "
+                    f"shared key: window {window}")
+    elif head_dim != LANES or (value_dim or head_dim) != LANES:
         return (f"windowed_attention takes heads of {LANES} (one lane tile "
                 f"a head): head_dim {head_dim}"
                 + (f", value_dim {value_dim}" if value_dim else ""))
@@ -343,10 +358,14 @@ def latent_query_blocks(seq_len: int) -> int:
 
 def _windowed_kernel(lens_ref, *refs, group: int, window_blocks: int | None,
                      scale: float, rope_shift: int | None, gated: bool,
-                     shared_key: bool = False, span: int = 1):
+                     shared_key: bool = False, span: int = 1,
+                     head_dim: int = LANES, head_norm_eps: float | None = None):
     # ``span`` key blocks a query block: queries go by ``span * block`` a
-    # step against keys ``block`` at a time (1 but for the latent form)
-    block, d = WINDOW_BLOCK, LANES
+    # step against keys ``block`` at a time (1 but for the latent form).
+    # ``head_dim`` lanes a head (one lane tile, or two under
+    # ``head_norm_eps``: q and k then normed a head at a time ahead of the
+    # rotation, the gates one a LANE)
+    block, d = WINDOW_BLOCK, head_dim
     block_q = span * block
     # inputs: q, k, v, [the queries' and the keys' shared score term], [this
     # query block's three tables, the whole row's three], [the gates];
@@ -361,6 +380,8 @@ def _windowed_kernel(lens_ref, *refs, group: int, window_blocks: int | None,
         c_ref, up_ref, down_ref, kc_ref, kup_ref, kdown_ref = (
             refs.pop(0) for _ in range(6))
         keys_ref = refs.pop()       # this (row, head)'s rotated keys
+    if head_norm_eps is not None:
+        qw_ref, kw_ref = refs.pop(0), refs.pop(0)
     if gated:
         gate_ref = refs.pop(0)
     o_ref, m_ref, l_ref, acc_ref = refs
@@ -368,8 +389,28 @@ def _windowed_kernel(lens_ref, *refs, group: int, window_blocks: int | None,
 
     def rotated(x, c, up, down):
         x = x.astype(jnp.float32)
-        return (x * c + pltpu.roll(x, rope_shift, 1) * up
-                + pltpu.roll(x, d - rope_shift, 1) * down)
+        if d == LANES:
+            return (x * c + pltpu.roll(x, rope_shift, 1) * up
+                    + pltpu.roll(x, d - rope_shift, 1) * down)
+        # the tables are one lane tile wide: the rotated dims lie in a
+        # head's first tile, the rest of it passes
+        first = x[:, :LANES]
+        return jnp.concatenate([
+            first * c + pltpu.roll(first, rope_shift, 1) * up
+            + pltpu.roll(first, LANES - rope_shift, 1) * down,
+            x[:, LANES:]], axis=1)
+
+    def normed(x, w_ref):
+        # RMS norm over ONE head's dims, float32, ahead of the rotation
+        x = x.astype(jnp.float32)
+        return x * jax.lax.rsqrt(
+            jnp.mean(x * x, axis=1, keepdims=True) + head_norm_eps
+        ) * w_ref[...]
+
+    def head_wide(x):
+        # a statistic kept lane tile wide, against a head's ``d`` lanes
+        return x if d == LANES else jnp.concatenate(
+            [x] * (d // LANES), axis=1)
 
     if rope_shift is not None:
         # a (row, head)'s query blocks go by in order: its keys are rotated
@@ -378,9 +419,11 @@ def _windowed_kernel(lens_ref, *refs, group: int, window_blocks: int | None,
         def _rotate_keys():
             def one(kb, carry):
                 at = pl.ds(pl.multiple_of(kb * block, block), block)
+                keys = (ks_ref if shared_key else k_ref)[0, at, :]
+                if head_norm_eps is not None:
+                    keys = normed(keys, kw_ref)
                 keys_ref[at, :] = rotated(
-                    (ks_ref if shared_key else k_ref)[0, at, :],
-                    kc_ref[at, :], kup_ref[at, :],
+                    keys, kc_ref[at, :], kup_ref[at, :],
                     kdown_ref[at, :]).astype(keys_ref.dtype)
                 return carry
 
@@ -394,6 +437,8 @@ def _windowed_kernel(lens_ref, *refs, group: int, window_blocks: int | None,
     def _real():
         def head(j):
             x = q_ref[0, :, j * d:(j + 1) * d]
+            if head_norm_eps is not None:
+                x = normed(x, qw_ref)
             if rope_shift is not None and not shared_key:
                 x = rotated(x, c_ref[...], up_ref[...], down_ref[...])
             return x.astype(v_ref.dtype)
@@ -456,7 +501,7 @@ def _windowed_kernel(lens_ref, *refs, group: int, window_blocks: int | None,
             alpha = jnp.exp(m_prev - m_next)
             p = jnp.exp(s - m_next)
             l_ref[...] = alpha * l_ref[...] + p.sum(axis=1, keepdims=True)
-            acc_ref[...] = alpha * acc_ref[...] + weighted(p, v)
+            acc_ref[...] = head_wide(alpha) * acc_ref[...] + weighted(p, v)
             m_ref[...] = m_next
 
         # the diagonal block first: every query sees its own key there, so
@@ -500,10 +545,12 @@ def _windowed_kernel(lens_ref, *refs, group: int, window_blocks: int | None,
                 s, v = scores(qi - window_blocks)
                 fold(jnp.where(cols > rows_, s, NEG_INF), v)
 
-        out = acc_ref[...] / l_ref[...]
+        out = acc_ref[...] / head_wide(l_ref[...])
         for j in range(group):
             mine = out[j * block_q:(j + 1) * block_q]
-            if gated:
+            if gated and head_norm_eps is not None:
+                mine = mine * gate_ref[0, :, j * d:(j + 1) * d]
+            elif gated:
                 mine = mine * gate_ref[0, 0, :, j:j + 1]
             o_ref[0, :, j * d:(j + 1) * d] = mine.astype(o_ref.dtype)
 
@@ -629,7 +676,8 @@ def windowed_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                        gate: jax.Array | None = None,
                        norm: tuple | None = None,
                        norm_eps: float | None = None,
-                       shared_key: tuple | None = None, out_dtype=None,
+                       shared_key: tuple | None = None,
+                       head_norm: tuple | None = None, out_dtype=None,
                        interpret: bool = False) -> jax.Array:
     """Fused causal core with grouped keys. ``q`` ``[B, T, H*128]``, ``k``
     and ``v`` ``[B, T, Hkv*128]`` (heads side by side, as the projections
@@ -671,21 +719,37 @@ def windowed_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     see more rows. A row's output is then zero from its first wholly padded
     STEP on.
 
+    ``head_norm`` = ``(f32[D], f32[D])`` with ``norm_eps``
+    (``models/qwen3_next.py``): the weights of an RMS norm of q and of k
+    over each HEAD's ``D`` dims (one weight the heads share, as it
+    multiplies: a zero-centred norm hands ``1 + w``), ahead of the rotation,
+    in the blocked kernel. In this form a head is ``D`` = 128 or 256 wide
+    (two lane tiles: scores sum both, values are two tiles wide, the scale
+    is ``D^-1/2``), the ``rope`` tables stay ONE lane tile wide
+    (``rope_lane_tables(cos, sin, 128)``: the rotated dims lie in a head's
+    first tile), and ``gate`` is ``f32[B, T, H*D]``, one multiplier a LANE
+    of the context. No window, no shared key.
+
     ``interpret=True`` runs the kernel through the Pallas interpreter."""
     b, t, width = q.shape
     shared_dim = None if shared_key is None else shared_key[1].shape[-1]
     refusal = windowed_refusal(
         t, width // num_heads, num_heads, num_kv_heads, window,
         qk_norm=norm is not None, shared_key_dim=shared_dim,
-        value_dim=v.shape[-1] // num_kv_heads)
+        value_dim=v.shape[-1] // num_kv_heads,
+        head_norm=head_norm is not None)
     if refusal or width % num_heads:
         raise ValueError(refusal or "windowed_attention: ragged heads")
     if (rope is None) != (rope_shift is None):
         raise ValueError("windowed_attention: rope tables and rope_shift "
                          "come together")
-    if (norm is None) != (norm_eps is None):
+    if (norm is None and head_norm is None) != (norm_eps is None):
         raise ValueError("windowed_attention: norm weights and norm_eps "
                          "come together")
+    if head_norm is not None and (
+            rope is None or gate is None or gate.shape != q.shape):
+        raise ValueError("windowed_attention: the per-head norm comes with "
+                         "a rotation and a gate a lane")
     group, block = num_heads // num_kv_heads, WINDOW_BLOCK
     if norm is not None:
         if rope is None or gate is not None:
@@ -695,7 +759,8 @@ def windowed_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             lengths, q, k, v, norm, rope, group=group, eps=norm_eps,
             rope_shift=rope_shift, out_dtype=out_dtype or q.dtype,
             interpret=interpret)
-    steps, kv_lanes, scale, span = num_kv_heads, LANES, LANES ** -0.5, 1
+    d = width // num_heads              # 128 but under ``head_norm``
+    steps, kv_lanes, scale, span = num_kv_heads, d, d ** -0.5, 1
     if shared_key is not None:
         if rope is None or gate is not None:
             raise ValueError("windowed_attention: the latent form rotates "
@@ -710,17 +775,20 @@ def windowed_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         _windowed_kernel, group=group,
         window_blocks=None if window is None else window // block,
         scale=scale, rope_shift=rope_shift,
-        gated=gate is not None, shared_key=shared_key is not None, span=span)
+        gated=gate is not None, shared_key=shared_key is not None, span=span,
+        **({} if head_norm is None
+           else dict(head_dim=d, head_norm_eps=norm_eps)))
     block_q = span * block
     stacked = (group * block_q, LANES)
-    heads_block = pl.BlockSpec((1, block_q, group * LANES),
+    heads_block = pl.BlockSpec((1, block_q, group * d),
                                lambda i, g, qi, lens: (i, qi, g))
     # a row's keys and values of one head stay put while its query blocks
     # go by: fetched once a (row, head)
     row_block = pl.BlockSpec((1, t, kv_lanes),
                              lambda i, g, qi, lens: (i, 0, g))
     in_specs, operands = [heads_block, row_block, row_block], [q, k, v]
-    scratch = [pltpu.VMEM(stacked, jnp.float32)] * 3
+    scratch = [pltpu.VMEM(stacked, jnp.float32)] * 2 + [
+        pltpu.VMEM((group * block_q, d), jnp.float32)]
     if shared_key is not None:
         q_shared, k_shared = shared_key
         in_specs += [
@@ -736,8 +804,14 @@ def windowed_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         whole = pl.BlockSpec((t, LANES), lambda i, g, qi, lens: (0, 0))
         in_specs += [table] * 3 + [whole] * 3
         operands += tables + tables
-        scratch.append(pltpu.VMEM((t, LANES), v.dtype))
-    if gate is not None:
+        scratch.append(pltpu.VMEM((t, d), v.dtype))
+    if head_norm is not None:
+        in_specs += [pl.BlockSpec((1, d), lambda i, g, qi, lens: (0, 0))] * 2
+        operands += [jnp.asarray(w, jnp.float32).reshape(1, d)
+                     for w in head_norm]
+        in_specs.append(heads_block)
+        operands.append(gate.astype(jnp.float32))
+    elif gate is not None:
         # [B, Hkv, T, G]: a group's gates are a block's last axis whole
         in_specs.append(pl.BlockSpec((1, 1, block_q, group),
                                      lambda i, g, qi, lens: (i, g, qi, 0)))

@@ -39,6 +39,7 @@ from realtime_fraud_detection_tpu.models import (
     joyai,
     laguna,
     nemotron_h,
+    qwen3_next,
     olmoe,
     zaya,
 )
@@ -100,7 +101,7 @@ TEXT_ENCODERS: Dict[type, TextEncoder] = {
         dataclasses.replace(bert.TEXT_ENCODER, predict=_bert_text_predict),
         olmoe.TEXT_ENCODER, zaya.TEXT_ENCODER, laguna.TEXT_ENCODER,
         joyai.TEXT_ENCODER, falcon_h1.TEXT_ENCODER,
-        nemotron_h.TEXT_ENCODER)}
+        nemotron_h.TEXT_ENCODER, qwen3_next.TEXT_ENCODER)}
 TextConfig = Union[tuple(TEXT_ENCODERS)]
 
 

@@ -1183,3 +1183,192 @@ def test_nemotron3_weights_are_made_without_a_float32_copy(one_chip):
         _sds((2,), jnp.uint32, one_chip)).compile().memory_analysis()
     assert 11.40e9 < memory.output_size_in_bytes < 11.48e9
     assert memory.temp_size_in_bytes < 1 << 30
+
+
+# ---------------------------------------------------------------------------
+# Qwen3-Next (PR 54): the delta-rule scan, the core at heads of 256, the
+# grouped calls at 256 groups of experts of 512, and the bucket's program
+
+QWEN_ROWS = (122880, 163840, 20480)     # 12,288 / 16,384 / 2,048 slots x 10
+
+
+@pytest.mark.parametrize("bucket", [8, 4, 2, 1])
+@pytest.mark.parametrize("carried", [False, True], ids=["from_zero",
+                                                        "state_in"])
+def test_delta_scan_compiles_at_published_widths(one_chip, bucket, carried):
+    """Qwen3-Next's Gated-DeltaNet scan (``ops/delta_scan.py``): 2,048
+    positions, 16 key heads under 32 value heads of 128 over a 128 x 128
+    state, bfloat16 operands, at the chunk the configuration assumes, at
+    every bucket of rows the job launches, from zero and from a state
+    handed in — ONE Mosaic call inside its VMEM budget."""
+    from realtime_fraud_detection_tpu.ops.delta_scan import (
+        delta_refusal,
+        gated_delta_scan,
+    )
+
+    t, chunk = 2048, 64
+    assert delta_refusal(t, 128, 128, chunk, 16, 32) is None
+    keys = _sds((bucket, t, 16, 128), jnp.bfloat16, one_chip)
+    steps = _sds((bucket, t, 32), jnp.float32, one_chip)
+    args = [keys, keys, _sds((bucket, t, 32, 128), jnp.bfloat16, one_chip),
+            steps, steps]
+    if carried:
+        args.append(_sds((bucket, 32, 128, 128), jnp.float32, one_chip))
+    compiled = jax.jit(lambda q, k, v, g, beta, state=None: gated_delta_scan(
+        q, k, v, g, beta, chunk=chunk, initial_state=state,
+        use_pallas=True)).lower(*args).compile()
+    assert compiled.as_text().count(CUSTOM_CALL) == 1
+
+
+@pytest.mark.parametrize("bucket", [8, 1])
+def test_windowed_attention_compiles_at_heads_of_256(one_chip, bucket):
+    """Qwen3-Next's ``F`` layer's core: 16 query heads of 256 — two lane
+    tiles — over 2 key-value heads, per-head norms, 64 of 256 dims rotated
+    inside a head's first tile, a gate a lane; float32 q, k and gates as
+    projected, bfloat16 v and context. ONE Mosaic call."""
+    from realtime_fraud_detection_tpu.models.olmoe import rope_tables
+    from realtime_fraud_detection_tpu.ops.attention import (
+        rope_lane_tables,
+        windowed_attention,
+        windowed_refusal,
+    )
+
+    t, heads, kv, d = 2048, 16, 2, 256
+    assert windowed_refusal(t, d, heads, kv, None, head_norm=True) is None
+    *tables, shift = rope_lane_tables(*rope_tables(t, 64, 1e7), 128)
+    weights = (np.ones(d, np.float32), np.ones(d, np.float32))
+    fn = jax.jit(lambda q, k, v, lengths, gate: windowed_attention(
+        q, k, v, lengths, num_heads=heads, num_kv_heads=kv,
+        rope=tuple(tables), rope_shift=shift, gate=gate, head_norm=weights,
+        norm_eps=1e-6, out_dtype=jnp.bfloat16))
+    wide = _sds((bucket, t, heads * d), jnp.float32, one_chip)
+    compiled = fn.lower(
+        wide, _sds((bucket, t, kv * d), jnp.float32, one_chip),
+        _sds((bucket, t, kv * d), jnp.bfloat16, one_chip),
+        _sds((bucket,), jnp.int32, one_chip), wide).compile()
+    assert compiled.as_text().count(CUSTOM_CALL) == 1
+
+
+@pytest.mark.parametrize("rows", QWEN_ROWS,
+                         ids=["three_quarters", "every_slot", "bucket1"])
+@pytest.mark.parametrize("kernel", ["gated", "down", "combine"])
+def test_the_experts_compile_at_256_groups_of_512(one_chip, kernel, rows):
+    """The most groups and the narrowest experts of any configuration: 256
+    held experts of 512 at ~195 rows a group, ten pairs a token — the fused
+    gate / up call, down's and the combine, ONE Mosaic call each at both
+    rungs of bucket 8 and at bucket 1."""
+    from realtime_fraud_detection_tpu.ops.combine import (
+        combine_supported,
+        weighted_combine,
+    )
+    from realtime_fraud_detection_tpu.ops.grouped_matmul import (
+        grouped_gated_matmul,
+        grouped_matmul,
+        grouped_matmul_supported,
+    )
+
+    groups, h, width, top_k = 256, 2048, 512, 10
+    sizes = _sds((groups,), jnp.int32, one_chip)
+    if kernel == "gated":
+        assert grouped_matmul_supported(rows, h, width)
+        matrix = _sds((groups, h, width), jnp.bfloat16, one_chip)
+        compiled = jax.jit(lambda x, g, u, s: grouped_gated_matmul(
+            x, g, u, s, out_dtype=jnp.bfloat16, use_pallas=True)).lower(
+            _sds((rows, h), jnp.bfloat16, one_chip), matrix, matrix,
+            sizes).compile()
+    elif kernel == "down":
+        compiled = jax.jit(lambda x, w, s: grouped_matmul(
+            x, w, s, use_pallas=True)).lower(
+            _sds((rows, width), jnp.bfloat16, one_chip),
+            _sds((groups, width, h), jnp.bfloat16, one_chip),
+            sizes).compile()
+    else:
+        slots = rows // top_k
+        assert combine_supported(slots, top_k, h)
+        pairs = _sds((slots, top_k), jnp.int32, one_chip)
+        compiled = jax.jit(lambda out, home, w, ok: weighted_combine(
+            out, home, w, ok, use_pallas=True)).lower(
+            _sds((rows, h // 128, 128), jnp.float32, one_chip), pairs,
+            _sds((slots, top_k), jnp.float32, one_chip),
+            _sds((slots, top_k), jnp.bool_, one_chip)).compile()
+    assert compiled.as_text().count(CUSTOM_CALL) == 1
+
+
+@pytest.mark.parametrize("bucket,capacity,temporaries",
+                         [(8, 12288, 1.50e9), (8, None, 1.95e9),
+                          (1, None, 0.47e9)],
+                         ids=["three_quarters", "every_slot", "bucket1"])
+def test_qwen3next_program_compiles_with_a_kernel_at_every_site(
+        one_chip, bucket, capacity, temporaries):
+    """The served packed program with a ``Qwen3NextConfig``: one whole
+    period (``LLLF``), every width as published, half of each layer's
+    experts held, bucket 8 x 2,048 tokens at both capacities and bucket 1:
+    three delta scans, the fused core at heads of 256 and three Mosaic
+    calls a sparse half — sixteen — a second small output, no conditional,
+    no float32 ``[pairs, 2048]`` array, no ``[8, 16, 2048, 2048]`` of
+    scores, no copy of a parameter, and temporaries that leave room for the
+    cell's 10.73 GB of weights in 16 GB: 1.38 / 1.79 / 0.43 GB read here at
+    six layers (PR 54), held under 10% over."""
+    from realtime_fraud_detection_tpu.core.packing import pack_tree
+    from realtime_fraud_detection_tpu.ensemble.combine import EnsembleParams
+    from realtime_fraud_detection_tpu.models.qwen3_next import Qwen3NextConfig
+    from realtime_fraud_detection_tpu.scoring.pipeline import (
+        MODEL_NAMES,
+        ScorerConfig,
+        init_scoring_models,
+        make_example_batch,
+        score_fused_packed,
+    )
+    from realtime_fraud_detection_tpu.utils.config import Config
+
+    config = Qwen3NextConfig(num_hidden_layers=4, num_experts=256)
+    models = jax.eval_shape(
+        lambda key: init_scoring_models(key, bert_config=config),
+        jax.random.PRNGKey(0))
+    blobs, spec = pack_tree(make_example_batch(
+        bucket, ScorerConfig(text_len=2048)))
+    compiled = score_fused_packed.lower(
+        _shapes_of(models, one_chip),
+        *(_shapes_of(blobs[k], one_chip) for k in ("f32", "i32", "u8")),
+        spec=spec,
+        params=EnsembleParams.from_config(Config(), list(MODEL_NAMES)),
+        model_valid=_sds((len(MODEL_NAMES),), jnp.bool_, one_chip),
+        blob_bf16=_shapes_of(blobs["bf16"], one_chip), bert_config=config,
+        use_pallas=True, text_capacity=capacity).compile()
+    text = compiled.as_text()
+    assert text.count(CUSTOM_CALL) == 3 + 1 + 4 * 3
+    for call in ("delta_scan/jit(_delta_pallas)/gated_delta_scan",
+                 "attn_core/jit(windowed_attention)/windowed_attention",
+                 "jit(gated_gmm)/gated_gmm", "jit(down_gmm)/down_gmm",
+                 "jit(combine_rows)/weighted_combine"):
+        assert f"{call}/pallas_call" in text, call
+    pairs = (capacity or bucket * 2048) * 10
+    assert f"f32[{pairs},2048]" not in text
+    assert f"f32[{pairs},16,128]" in text
+    assert " conditional(" not in text and "cond/branch_" not in text
+    assert f"f32[{bucket},16,2048,2048]" not in text
+    # no launch re-lays a parameter out: the weights' parts are sliced under
+    # their scopes, the experts reach their calls as they are held
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if " copy(" in line and "parameter" in line]
+    assert copies == []
+    assert compiled.memory_analysis().temp_size_in_bytes < temporaries
+
+
+def test_qwen3next_weights_are_made_without_a_float32_copy(one_chip):
+    """The builder's one jitted init at the cell's six layers: 10.73 GB of
+    arguments (5,364,067,776 parameters, bfloat16 but for the norms, the
+    convolutions' taps, the mixers' vectors and the head: the byte count
+    the configuration file states, within 1%), drawn tensor by tensor with
+    no float32 copy standing beside them."""
+    from realtime_fraud_detection_tpu.models.qwen3_next import Qwen3NextConfig
+    from realtime_fraud_detection_tpu.scoring.pipeline import (
+        init_scoring_models,
+    )
+
+    config = Qwen3NextConfig(num_hidden_layers=6, num_experts=256)
+    memory = jax.jit(
+        lambda key: init_scoring_models(key, bert_config=config)).lower(
+        _sds((2,), jnp.uint32, one_chip)).compile().memory_analysis()
+    assert abs(memory.output_size_in_bytes / 10_728_527_616 - 1.0) < 0.01
+    assert memory.temp_size_in_bytes < 1 << 30

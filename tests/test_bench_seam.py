@@ -13,12 +13,14 @@ alone shrunk). No test reports a device number.
 """
 
 import json
+import math
 import os
 import subprocess
 import sys
 import types
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -52,6 +54,22 @@ from test_nemotron3_reference import (  # noqa: E402,F401
     test_nemotron3_score_composes_the_branches_and_reads_the_file,
     test_nemotron3_text_branch_is_the_programs_at_float32,
     test_nemotron3_text_column_costs_four_compilations,
+)
+# PR 54's reference against the program, its lowering seam by site and its
+# four compilations, and its control at TINY: collected here as they stand
+from test_qwen3next_control import (  # noqa: E402,F401
+    qwen3next_cell,
+    test_qwen3next_float8_operands_read_further_than_the_program,
+    test_qwen3next_sound_only_and_reference_only_leave_their_halves_out,
+    test_qwen3next_tail_reads_each_row_and_its_last_tokens_held_mass,
+)
+from test_qwen3next_reference import (  # noqa: E402,F401
+    test_qwen3next_lowering_seam_reaches_the_site_it_is_told,
+    test_qwen3next_reference_imports_nothing_from_the_package,
+    test_qwen3next_reference_refuses_what_its_equations_do_not_hold,
+    test_qwen3next_score_composes_the_branches_and_reads_the_file,
+    test_qwen3next_text_branch_is_the_programs_at_float32,
+    test_qwen3next_text_column_costs_four_compilations,
 )
 from test_setup_metrics import (  # noqa: E402,F401
     test_a_ledger_that_let_records_go_reads_none,
@@ -95,6 +113,15 @@ NEMOTRON_CFG = json.loads(
 NEMOTRON_ONLY = ["nemotron3_ssd_scan_roofline_pct",
                  "nemotron3_expert_ffn_roofline_pct",
                  "nemotron3_attn_core_roofline_pct"]
+# PR 54: Qwen3-Next's cell, delta-rule layers to one gated attention layer,
+# every layer routed over a SHARE of its experts, on the same traffic file
+QWEN_CELL = "qwen3next-s2048-remit-saturated"
+QWEN_CFG = json.loads(
+    (ROOT / "benchmarks/configs/qwen3-next-80b-a3b-s2048.json").read_text())
+QWEN_ONLY = ["delta_proj_ms_per_batch", "delta_conv_ms_per_batch",
+             "delta_scan_ms_per_batch", "qwen3next_delta_scan_roofline_pct",
+             "qwen3next_attn_core_roofline_pct",
+             "qwen3next_expert_ffn_roofline_pct"]
 # PR 53: the routed experts' way out, in the seven routed cells — a scope's
 # time and a counter's share, each a data file over a reader the benchmark
 # had
@@ -459,13 +486,246 @@ def test_the_nemotron3_file_is_the_sources_config_cut_in_depth_only():
             {**NEMOTRON_CFG, "hybrid_override_pattern": "MEMEM-EME"})
 
 
+def _before_pr54():
+    """``BENCHMARK.json`` with what PR 54 appended checked BY NAME and taken
+    off: one configuration, one cell, the cell's name at the end of the
+    ``workloads`` list of every metric it reports, and six metrics of its
+    own at the end of ``per_layer``. What is left is the benchmark PR 53
+    left."""
+    bm = json.loads(json.dumps(BM))
+    own = [bm["per_layer"].pop() for _ in QWEN_ONLY][::-1]
+    assert [m["name"] for m in own] == QWEN_ONLY
+    for m in own:
+        assert m == {"name": m["name"],
+                     "unit": "%" if m["name"].endswith("_pct") else "ms",
+                     "better": "higher" if m["name"].endswith("_pct")
+                     else "lower",
+                     "source": "device_trace", "layer": "kernels",
+                     "moves": "txn_per_s", "workloads": [QWEN_CELL]}
+    assert bm["configs"].pop() == {
+        "name": "qwen3-next-80b-a3b-s2048", "source": QWEN_CFG["source"],
+        "file": "benchmarks/configs/qwen3-next-80b-a3b-s2048.json",
+        "reduced": ["num_hidden_layers", "num_experts"],
+        "why": BM["configs"][-1]["why"]}
+    assert bm["workloads"].pop() == {
+        "name": QWEN_CELL, "config": "qwen3-next-80b-a3b-s2048",
+        "traffic": "s2048-remit-saturated", "chips": 1,
+        "why": BM["workloads"][-1]["why"]}
+    assert len(BM["workloads"][-1]["why"]) <= 200 \
+        and len(BM["configs"][-1]["why"]) <= 200
+    listed = set()
+    for m in bm["per_layer"] + bm["end_to_end"]:
+        if QWEN_CELL in m.get("workloads", ()):
+            assert m["workloads"].pop() == QWEN_CELL, m["name"]
+            assert QWEN_CELL not in m["workloads"]
+            listed.add(m["name"])
+    assert listed | set(QWEN_ONLY) | {"setup_s"} == {
+        m["name"] for kind in ("end_to_end", "per_layer")
+        for m in spec.metrics_for(QWEN_CELL, kind)}
+    return bm, listed
+
+
+def test_pr54_appended_one_cell_six_metrics_and_its_name_to_lists():
+    bm, qwen_lists = _before_pr54()
+    # routed over a share of the experts beside a shared one: what every
+    # cell reports, the routed cells' shared names, the shared expert's
+    # time and the share's; none of the state-space mixer's, no dense MLP's
+    every = {m["name"] for m in BM["per_layer"]
+             if len(m["workloads"]) == len(BM["workloads"])}
+    assert qwen_lists == every | {"txn_per_s"} | {
+        "expert_ffn_ms_per_batch", "expert_matmul_ms_per_batch",
+        "expert_combine_ms_per_batch", "expert_dispatch_ms_per_batch",
+        "router_ms_per_batch", "shared_expert_ms_per_batch",
+        "expert_imbalance_x", "expert_tile_fill_pct", "compact_batches_pct",
+        "dispatch_kernel_pct", "expert_local_share_pct"}
+    assert len(bm["workloads"]) == len(BM["workloads"]) - 1 == 9
+    assert [w["name"] for w in bm["workloads"]][-1] == NEMOTRON_CELL
+    assert not [w for w in BM["workloads"] if w["chips"] != 1]
+    # the traffic file is the four other 2,048-token cells', unedited
+    assert spec.cell(QWEN_CELL)["traffic"] == spec.cell(LAGUNA_CELL)[
+        "traffic"] == "s2048-remit-saturated"
+    metrics = ROOT / "benchmarks/layer_metrics"
+    for name, scope in (("delta_proj_ms_per_batch", "delta_proj"),
+                        ("delta_conv_ms_per_batch", "delta_conv"),
+                        ("delta_scan_ms_per_batch", "delta_scan")):
+        assert json.loads((metrics / f"{name}.json").read_text()) == {
+            "reader": "scope_time_per_batch",
+            "args": {"scopes": [f"text/layer*/{scope}"]}}
+    for name, scope, peak in (
+            ("qwen3next_delta_scan_roofline_pct", "delta_scan",
+             {"peak": "hbm_bytes_per_s"}),
+            ("qwen3next_attn_core_roofline_pct", "attn_core", {}),
+            ("qwen3next_expert_ffn_roofline_pct", "experts/matmul",
+             {"peak": "hbm_bytes_per_s"})):
+        assert json.loads((metrics / f"{name}.json").read_text()) == {
+            "reader": "scope_roofline",
+            "args": {"scope": f"text/layer*/{scope}",
+                     "kernel": name[:-len("_roofline_pct")], **peak}}
+
+
+def test_the_qwen3next_file_is_the_sources_config_cut_in_depth_and_share():
+    from realtime_fraud_detection_tpu.models.qwen3_next import (
+        Qwen3NextConfig,
+    )
+
+    builder = spec.builder(QWEN_CFG)
+    built = builder.qwen3next_config(QWEN_CFG)
+    assert QWEN_CFG["reduced"] == ["num_hidden_layers", "num_experts"]
+    # the class's defaults are the published values: the cell runs six
+    # layers and holds half of each layer's experts, and nothing else cut
+    assert built == Qwen3NextConfig(num_hidden_layers=6, num_experts=256)
+    assert (built.router_experts, built.expert_offset) == (512, 0)
+    assert QWEN_CFG["published"]["num_hidden_layers"] == 48
+    assert QWEN_CFG["published"]["num_experts"] == 512
+    assert QWEN_CFG["expert_share"] == {"chips": 2, "index": 0}
+    assert "".join(built.layer_kinds) == builder.layer_kinds(QWEN_CFG) \
+        == "LLLFLL"
+    # every key of the catalog row's config, under its own name, at its
+    # published value but for the two of the cut — in the file AND the class
+    catalog = Path("/opt/skills/guides/model-configs/architectures.jsonl")
+    row = json.loads([
+        line for line in catalog.read_text().splitlines()
+        if '"Qwen3-Next-80B-A3B-Instruct"' in line][0]) \
+        if catalog.is_file() else {"config": QWEN_CFG["published"],
+                                   "source_url": QWEN_CFG["source"]}
+    assert QWEN_CFG["source"] == row["source_url"]
+    assert QWEN_CFG["published"] == row["config"]
+    cut = {"num_hidden_layers": 6, "num_experts": 256}
+    for key, value in row["config"].items():
+        want = cut.get(key, value)
+        assert QWEN_CFG[key] == want, key
+        got = getattr(built, key)
+        assert got == (tuple(want) if isinstance(want, list) else want), key
+    # every width as published
+    assert (built.hidden_size, built.head_dim, built.num_attention_heads,
+            built.num_key_value_heads, built.vocab_size) == (
+        2048, 256, 16, 2, 151936)
+    assert (built.linear_num_key_heads, built.linear_num_value_heads,
+            built.linear_key_head_dim, built.linear_value_head_dim,
+            built.linear_conv_kernel_dim, built.conv_dim, built.rotary_dim,
+            built.delta_chunk) == (16, 32, 128, 128, 4, 8192, 64, 64)
+    assert (built.num_experts_per_tok, built.moe_intermediate_size,
+            built.shared_expert_intermediate_size) == (10, 512, 512)
+    assert built.core_refusal(2048) is None is built.scan_refusal(2048)
+    assert QWEN_CFG["text_len"] == 2048 and QWEN_CFG["chips"] == 1
+    assert QWEN_CFG["job"]["max_batch"] == 8
+    assert QWEN_CFG["parity_rows"] == 4
+    for key in ("cut", "deployment", "not_run", "assumed", "compute_dtype",
+                "guarantee", "parity_atol_from", "job_from"):
+        assert QWEN_CFG[key] and "PLACEHOLDER" not in json.dumps(
+            QWEN_CFG[key]), key
+    for words in ("sixteen chips", "eight pipeline stages", "two chips"):
+        assert words in QWEN_CFG["deployment"].lower(), words
+    assert "512 x 3 x 2048 x 512" in QWEN_CFG["cut"]
+    assert set(QWEN_CFG["not_run"]) == {
+        "lm_head", "mtp", "intermediate_size", "max_position_embeddings",
+        "other_experts"}
+    for item in ("equations", "head", "weights", "tokenizer", "traffic",
+                 "delta_chunk"):
+        assert QWEN_CFG["assumed"][item], item
+    assert "ASSUMED" in QWEN_CFG["assumed"]["equations"]
+    assert "residual" in QWEN_CFG["compute_dtype"]["text_branch"]
+    # the byte count the file states is the init's own (AOT: the argument
+    # size of the bucket's program agrees to 0.01%, tests/test_aot_tpu.py)
+    import jax
+
+    from realtime_fraud_detection_tpu.models.qwen3_next import (
+        init_qwen3_next_params,
+    )
+
+    shapes = jax.eval_shape(lambda k: init_qwen3_next_params(k, built),
+                            jax.random.PRNGKey(0))
+    leaves = jax.tree.leaves(shapes)
+    assert sum(math.prod(x.shape) for x in leaves) == 5_364_067_776
+    stated = sum(math.prod(x.shape) * x.dtype.itemsize for x in leaves)
+    assert stated == 10_728_527_616
+    assert "5,364,067,776 parameters = 10,728,527,616 B" in QWEN_CFG["cut"]
+    tiny = builder.qwen3next_config({**QWEN_CFG, **builder.TINY})
+    # the ratios stay: two value heads a key head, eight query heads a
+    # key-value head, a quarter of a head rotated, half the router's experts
+    # held, eight chunks in a rehearsal's 128 positions
+    assert tiny.hidden_size < 512
+    assert tiny.linear_num_value_heads // tiny.linear_num_key_heads == 2
+    assert tiny.num_attention_heads // tiny.num_key_value_heads == 8
+    assert tiny.rotary_dim * 4 == tiny.head_dim
+    assert tiny.router_experts == 2 * tiny.num_experts == 32
+    assert 128 // tiny.delta_chunk == 8
+    with pytest.raises(ValueError, match="decoder_sparse_step"):
+        builder.qwen3next_config({**QWEN_CFG, "decoder_sparse_step": 2})
+    with pytest.raises(ValueError, match="tied embeddings"):
+        builder.qwen3next_config({**QWEN_CFG, "tie_word_embeddings": True})
+    with pytest.raises(ValueError, match="are not the published 512"):
+        builder.qwen3next_config({**QWEN_CFG, "num_experts": 128})
+
+
+@pytest.fixture(scope="module")
+def qwen3next_draw():
+    """The cell's own draw — the builder's config, every width as published
+    — at two layers (``LF``), eight held experts and a short vocabulary."""
+    import dataclasses
+
+    import jax
+
+    from realtime_fraud_detection_tpu.models.qwen3_next import (
+        init_qwen3_next_params,
+    )
+
+    built = spec.builder(QWEN_CFG).qwen3next_config(QWEN_CFG)
+    small = dataclasses.replace(built, num_hidden_layers=2,
+                                full_attention_interval=2, num_experts=8,
+                                vocab_size=1024)
+    params = init_qwen3_next_params(jax.random.PRNGKey(54), small)
+    return built, {**params["layers"][1], **params["layers"][0]}
+
+
+def test_the_qwen3next_file_names_the_draw_the_cell_is_fed(qwen3next_draw):
+    """``assumed.weights`` is a description of ``init_qwen3_next_params`` as
+    the builder calls it: each knob of the draw under its name and value in
+    ``weights_draw`` AND in the prose, the routed experts correlated."""
+    built, layer = qwen3next_draw
+    assumed = QWEN_CFG["assumed"]
+    assert assumed["weights_draw"] == {
+        key: getattr(built, key) for key in (
+            "embedding_range", "norm_range", "router_logit_rms",
+            "expert_spread", "update_rms", "context_rms")}
+    for quoted in ("embedding normal(1.0)", "norm_range 0.1",
+                   "router_logit_rms 2 /", "logits of RMS 2",
+                   "expert_spread 1/64 = 0.015625", "update_rms 0.5",
+                   "context_rms 0.13", "CORRELATED"):
+        assert quoted in assumed["weights"], quoted
+    for stale in ("router_logit_rms 4", "PEAKED", "experts independent"):
+        assert stale not in assumed["weights"], stale
+    first, second = (np.asarray(layer["down_proj"][i], np.float32).ravel()
+                     for i in (0, 1))
+    assert np.corrcoef(first, second)[0, 1] > 0.99
+    assert not np.array_equal(first, second)
+
+
+@pytest.mark.parametrize("tensor,quoted", [
+    ("in_proj_qkvz", "1 / sqrt(2048) = 0.0221"),
+    ("out_proj", "sqrt(4096)) = 0.0130"),
+    ("o_proj", "sqrt(4096)) = 0.0601"),
+    ("router", "/ sqrt(2048) = 0.0442"),
+    ("gate_proj", "gate and up normal(0.0221)"),
+    ("down_proj", "sqrt(512)) = 0.0449"),
+    ("shared_down", "sqrt(512)) = 0.0482"),
+])
+def test_the_qwen3next_file_quotes_the_scale_each_matrix_is_drawn_at(
+        qwen3next_draw, tensor, quoted):
+    text = QWEN_CFG["assumed"]["weights"]
+    assert quoted in text, quoted
+    stated = float(quoted.rstrip(")")[-6:])
+    drawn = float(np.asarray(qwen3next_draw[1][tensor], np.float32).std())
+    assert drawn == pytest.approx(stated, rel=0.02), (tensor, drawn)
+
+
 def _before_pr50():
     """``BENCHMARK.json`` with what PR 50 appended checked BY NAME and taken
     off: one configuration, one cell, the cell's name at the end of the
     ``workloads`` list of every metric it reports, and three metrics of its
     own at the end of ``per_layer``. What is left is the benchmark PR 48
     left, which the asserts below count from the end of."""
-    bm = json.loads(json.dumps(BM))
+    bm, _ = _before_pr54()
     # (and behind PR 50's, what PR 53 appended: two metrics, nothing else)
     for name in reversed(DISPATCH_METRICS):
         unit, better, source, data = DISPATCH_METRICS[name]
@@ -479,7 +739,7 @@ def _before_pr50():
     assert bm["workloads"].pop() == {
         "name": NEMOTRON_CELL, "config": "nemotron-3-nano-30b-s2048",
         "traffic": "s2048-remit-saturated", "chips": 1,
-        "why": BM["workloads"][-1]["why"]}
+        "why": {w["name"]: w for w in BM["workloads"]}[NEMOTRON_CELL]["why"]}
     own = [bm["per_layer"].pop() for _ in NEMOTRON_ONLY][::-1]
     assert [m["name"] for m in own] == NEMOTRON_ONLY
     for m in own:
@@ -1374,13 +1634,147 @@ def test_the_nemotron3_metrics_on_a_hand_made_run():
                            "per_layer")(counted) is None
 
 
+def test_qwen3next_matmul_flops_follow_the_layers_by_kind():
+    builder = spec.builder(QWEN_CFG)
+    parts = builder.text_matmul_flops_per_row(QWEN_CFG)
+    t, h = 2048, 2048
+    # each part times the layers of ITS kind: 5 L, 1 F, 6 sparse halves
+    assert parts["delta_proj"] == 5 * 2.0 * t * h * (12288 + 64 + 4096)
+    assert parts["attn_proj"] == 1 * 2.0 * t * h * 256 * (3 * 16 + 2 * 2)
+    assert parts["cores"] == 1 * 4.0 * 16 * 256 * (t * (t + 1) // 2)
+    # the router runs whole; five of a token's ten experts live here
+    assert parts["router"] == 6 * 2.0 * t * h * 512
+    assert parts["experts"] == 6 * 6.0 * t * h * 512 * 10 / 2
+    assert parts["shared_expert"] == 6 * 2.0 * t * h * (3 * 512 + 1)
+    # the chunked algorithm's count: 7.86 MFLOP a slot and layer
+    per_slot = spec.kernel("qwen3next_delta_scan").flops_per_slot(QWEN_CFG)
+    assert per_slot == pytest.approx(7.86e6, rel=2e-3)
+    assert parts["delta_scan"] == 5 * t * per_slot
+    total = builder.matmul_flops_per_batch(QWEN_CFG)
+    assert 0.99 < 8 * sum(parts.values()) / total <= 1.0
+
+
+def test_qwen3next_kernels_charge_what_the_program_counted():
+    slots = 3 * 8 * 2048
+    counters = {"batches": 3, "token_slots": slots,
+                "attn_visible_pairs_full": 20_000_000,
+                "attn_visible_pairs_sliding": 0,
+                "delta_chunks": slots // 64 * 5,
+                "expert_rows": 3 * 6 * 50_000, "routed_pairs": 3 * 6 * 100_000}
+    scan = spec.kernel("qwen3next_delta_scan").work(counters, QWEN_CFG)
+    # every launched slot of the five L layers, a chunk of 64 at a time: K
+    # K^T and Q K^T a key head; the solve (ten 64^3 products), U and W, the
+    # three products against the state and the masked one a value head
+    a_chunk = 16 * 2 * 2 * 64 * 64 * 128 + 32 * (
+        10 * 2 * 64 ** 3 + 2 * 2 * 64 * 64 * 128 + 3 * 2 * 64 * 128 * 128
+        + 2 * 64 * 64 * 128)
+    assert scan["flops"] == slots * 5 * a_chunk / 64
+    # q, k, v read in bfloat16, g and beta in float32, o written in float32
+    assert scan["hbm_bytes"] == slots * 5 * (
+        (2048 + 2048 + 4096) * 2 + (64 + 4096) * 4)
+    assert scan["hbm_bytes"] / (slots * 5) == 33_024
+    # 238 FLOP a byte: at the v5e's ridge of 240, the bytes' bound the
+    # larger, so the metric file names the HBM's rate
+    assert 230 < scan["flops"] / scan["hbm_bytes"] < 240
+    experts = spec.kernel("qwen3next_expert_ffn").work(counters, QWEN_CFG)
+    # gate, up and down of every HELD row; the held experts' matrices once
+    # a launch and layer
+    assert experts["flops"] == 6 * 3 * 6 * 50_000 * 2048 * 512
+    assert experts["hbm_bytes"] == (
+        3 * 6 * 256 * 3 * 2048 * 512 * 2 + 3 * 6 * 50_000 * 2048 * 6)
+    # ~140 FLOP a byte at ~195 rows an expert: under the ridge
+    assert 130 < experts["flops"] / experts["hbm_bytes"] < 150
+    core = spec.kernel("qwen3next_attn_core").work(counters, QWEN_CFG)
+    # one causal layer's pairs x the ONE F layer of the six
+    assert core["flops"] == 4 * 256 * 16 * 1 * 20_000_000
+    assert core["hbm_bytes"] == 1 * slots * 256 * (16 * 10 + 2 * 6)
+    for kernel in ("qwen3next_delta_scan", "qwen3next_expert_ffn",
+                   "qwen3next_attn_core"):
+        none = spec.kernel(kernel).work({"batches": 3}, QWEN_CFG)
+        assert none == {"flops": 0.0, "hbm_bytes": 0.0}, kernel
+
+
+def test_the_qwen3next_metrics_on_a_hand_made_run():
+    slots = 2 * 8 * 2048
+    counters = {"batches": 2, "scored": 16, "token_slots": slots,
+                "real_tokens": 20_000, "delta_chunks": slots // 64 * 5,
+                "attn_visible_pairs_full": 14_000_000,
+                "attn_visible_pairs_sliding": 0,
+                "routed_pairs": 1_200_000, "expert_rows": 600_000,
+                "expert_peak_rows": 900_000, "expert_tile_rows": 800_000,
+                "expert_token_slots": 2 * 12288, "compact_batches": 2,
+                "dispatch_rows": 2 * 12288 * 60, "dispatch_kernel_rows": 0}
+    scope_s = {"text": 0.40}
+    for i, kind in enumerate("LLLFLL"):
+        scope_s.update({f"text/layer{i}/ln": 0.001,
+                        f"text/layer{i}/router": 0.004,
+                        f"text/layer{i}/experts": 0.018,
+                        f"text/layer{i}/experts/dispatch": 0.002,
+                        f"text/layer{i}/experts/matmul": 0.010,
+                        f"text/layer{i}/experts/combine": 0.006,
+                        f"text/layer{i}/shared_expert": 0.001})
+        if kind == "L":
+            scope_s.update({f"text/layer{i}/delta_proj": 0.012,
+                            f"text/layer{i}/delta_conv": 0.014,
+                            f"text/layer{i}/delta_scan": 0.016})
+        else:
+            scope_s.update({f"text/layer{i}/attn_proj": 0.010,
+                            f"text/layer{i}/attn_core": 0.004})
+    run = _fake_run(scope_s, counters, QWEN_CFG)
+
+    def metric(name):
+        return spec.reader_for(name, "per_layer")(run)
+
+    assert metric("delta_proj_ms_per_batch") == pytest.approx(30.0)
+    assert metric("delta_conv_ms_per_batch") == pytest.approx(35.0)
+    assert metric("delta_scan_ms_per_batch") == pytest.approx(40.0)
+    assert metric("attn_core_ms_per_batch") == pytest.approx(2.0)
+    assert metric("attn_proj_ms_per_batch") == pytest.approx(5.0)
+    assert metric("ln_ms_per_batch") == pytest.approx(3.0)
+    assert metric("router_ms_per_batch") == pytest.approx(12.0)
+    assert metric("expert_matmul_ms_per_batch") == pytest.approx(30.0)
+    assert metric("expert_combine_ms_per_batch") == pytest.approx(18.0)
+    assert metric("expert_dispatch_ms_per_batch") == pytest.approx(6.0)
+    assert metric("shared_expert_ms_per_batch") == pytest.approx(3.0)
+    assert metric("expert_local_share_pct") == pytest.approx(50.0)
+    assert metric("expert_tile_fill_pct") == pytest.approx(75.0)
+    assert metric("dispatch_kernel_pct") == pytest.approx(0.0)
+    for name, kernel, quantity, peak, seconds in (
+            ("qwen3next_delta_scan_roofline_pct", "qwen3next_delta_scan",
+             "hbm_bytes", 819e9, 0.080),
+            ("qwen3next_expert_ffn_roofline_pct", "qwen3next_expert_ffn",
+             "hbm_bytes", 819e9, 0.060),
+            ("qwen3next_attn_core_roofline_pct", "qwen3next_attn_core",
+             "flops", 197e12, 0.004)):
+        needs = spec.kernel(kernel).work(counters, QWEN_CFG)[quantity]
+        assert 0 < metric(name) < 100, name
+        assert metric(name) == pytest.approx(
+            100 * needs / peak / seconds), name
+    assert QWEN_ONLY == [
+        m["name"] for m in spec.metrics_for(QWEN_CELL, "per_layer")
+        if m["name"].startswith(("delta_", "qwen3next_"))]
+    # against a program without the scopes and the counters every new
+    # metric is left out and none raises
+    parent = _fake_run({"text": 0.9, "text/layer1/ffn": 0.1},
+                       {"batches": 2, "scored": 16}, QWEN_CFG)
+    for name in QWEN_ONLY:
+        assert spec.reader_for(name, "per_layer")(parent) is None, name
+    # the scope there and the counter not: the share is left out
+    counted = _fake_run({"text": 0.9, "text/layer1/delta_scan": 0.1},
+                        {"batches": 2, "scored": 16, "token_slots": slots},
+                        QWEN_CFG)
+    assert spec.reader_for("qwen3next_delta_scan_roofline_pct",
+                           "per_layer")(counted) is None
+
+
 # ------------------------------------------------ a program without the module
 @pytest.mark.parametrize("cfg,module", [(OLMOE_CFG, "olmoe"),
                                         (ZAYA_CFG, "zaya"),
                                         (LAGUNA_CFG, "laguna"),
                                         (JOYAI_CFG, "joyai"),
                                         (FALCON_CFG, "falcon_h1"),
-                                        (NEMOTRON_CFG, "nemotron_h")])
+                                        (NEMOTRON_CFG, "nemotron_h"),
+                                        (QWEN_CFG, "qwen3_next")])
 def test_the_builder_stops_at_once_on_a_program_without_the_encoder(
         monkeypatch, cfg, module):
     import importlib.util
@@ -1399,7 +1793,8 @@ def test_the_builder_stops_at_once_on_a_program_without_the_encoder(
                                          (LAGUNA_CELL, "laguna"),
                                          (JOYAI_CELL, "joyai"),
                                          (FALCON_CELL, "falcon_h1"),
-                                         (NEMOTRON_CELL, "nemotron_h")])
+                                         (NEMOTRON_CELL, "nemotron_h"),
+                                         (QWEN_CELL, "qwen3_next")])
 def test_the_parent_exits_non_zero_within_seconds(tmp_path, cell, module):
     """A checkout of the benchmark without the program's new module — what
     the driver's parent run of a new configuration's cell is — prints no
@@ -1441,7 +1836,7 @@ def tiny_copy(tmp_path_factory):
     (OLMOE_CELL, 0), (OLMOE_CELL, 1), (ZAYA_CELL, 0), (ZAYA_CELL, 1),
     (FULL_CELL, 1), (LAGUNA_CELL, 0), (LAGUNA_CELL, 1), (ZAYA_FULL_CELL, 1),
     (JOYAI_CELL, 0), (JOYAI_CELL, 1), (FALCON_CELL, 0), (FALCON_CELL, 1),
-    (NEMOTRON_CELL, 0), (NEMOTRON_CELL, 1)])
+    (NEMOTRON_CELL, 0), (NEMOTRON_CELL, 1), (QWEN_CELL, 0), (QWEN_CELL, 1)])
 def test_tiny_rehearsal_of_a_routed_cell(tiny_copy, cell, trace):
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(ROOT),
                XLA_FLAGS="--xla_force_host_platform_device_count=1")
@@ -1486,6 +1881,20 @@ def test_tiny_rehearsal_of_a_routed_cell(tiny_copy, cell, trace):
                 and "'ssm_chunks': 0" not in proc.stdout
             assert not [name for name in out["metrics"]
                         if name.startswith(("ssm_", "nemotron3_", "ffn_"))]
+        elif cell == QWEN_CELL:
+            # every slot real; half the router's experts held here: about
+            # half the pairs; routed AND recurrent: the chunks of the five
+            # L layers are counted, and no device scope is read on a CPU
+            assert padding == 0.0
+            share = out["metrics"]["expert_local_share_pct"]["value"]
+            assert 35 < share < 65
+            assert 0 < out["metrics"]["setup_programs"]["value"] <= 16
+            assert "'delta_chunks': " in proc.stdout \
+                and "'delta_chunks': 0" not in proc.stdout
+            assert "'ssm_chunks': 0" in proc.stdout
+            assert not [name for name in out["metrics"]
+                        if name.startswith(("delta_", "qwen3next_", "ssm_",
+                                            "ffn_"))]
         elif cell == JOYAI_CELL:
             # every slot real, as Laguna's; every expert held: no share
             assert padding == 0.0

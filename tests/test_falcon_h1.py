@@ -496,8 +496,8 @@ def test_the_class_picks_the_encoder_and_answers_the_seams_questions():
     assert attention.refusal(CFG, 256, 256) == CFG.core_refusal(256)
     assert scan.refusal(CFG, 256, 256) == CFG.scan_refusal(256)
     assert pipeline.text_layers(CFG) == 2
-    # the routed rows (five since Nemotron-3-Nano's, which is routed AND
-    # has this scan site) are what they were; the dense encoder's takes
+    # the routed rows (six since Qwen3-Next's; Nemotron-3-Nano's is routed
+    # AND has this scan site) are what they were; the dense encoder's takes
     # every plane and has the narrow width
     dense = pipeline.text_encoder(TINY_CONFIG)
     assert dense.narrow_width(TINY_CONFIG) == 128 and len(dense.planes) == 5
@@ -505,7 +505,7 @@ def test_the_class_picks_the_encoder_and_answers_the_seams_questions():
     assert [site.name for site in routed.sites] == [
         "attention", "expert_gate_up", "expert_dispatch", "expert_combine"]
     assert sum(row.capacities(4096) is not None
-               for row in pipeline.TEXT_ENCODERS.values()) == 5
+               for row in pipeline.TEXT_ENCODERS.values()) == 6
 
 
 def test_text_predict_refuses_a_capacity_and_the_dequant_plane(params, text):

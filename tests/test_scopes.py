@@ -138,7 +138,8 @@ def _lowered_asm(bert_config):
 @pytest.mark.parametrize("builder", ["ensemble_builder", "olmoe_builder",
                                      "zaya1_builder", "laguna_builder",
                                      "joyai_builder", "falconh1_builder",
-                                     "nemotron3_builder"])
+                                     "nemotron3_builder",
+                                     "qwen3next_builder"])
 def test_every_name_in_a_builders_vocabulary_is_one_the_program_writes(
         builder):
     """A builder (``benchmarks/configs/<builder>.py``) writes the device
@@ -202,6 +203,26 @@ def test_every_name_in_a_builders_vocabulary_is_one_the_program_writes(
                 scopes.ATTN_PROJ, scopes.ATTN_CORE, scopes.ROUTER,
                 scopes.EXPERTS, scopes.SHARED_EXPERT}
             gate_up = "jit(relu2_gmm)/relu2_gmm/pallas_call"
+        elif builder == "qwen3next_builder":
+            from realtime_fraud_detection_tpu.models.qwen3_next import (
+                TINY_QWEN3_NEXT,
+            )
+
+            # every layer has both norms and a routed block beside a shared
+            # expert; its mixer is a Gated-DeltaNet's three scopes or
+            # attention's two; no ``ffn`` (no layer has a dense MLP)
+            config, layer_parts = (TINY_QWEN3_NEXT,
+                                   scopes.QWEN3_NEXT_LAYER_SCOPES)
+            assert set(layer_parts) == {
+                scopes.LN, scopes.DELTA_PROJ, scopes.DELTA_CONV,
+                scopes.DELTA_SCAN, scopes.ATTN_PROJ, scopes.ATTN_CORE,
+                scopes.ROUTER, scopes.EXPERTS, scopes.SHARED_EXPERT}
+            # the scan's kernel as a device trace names it
+            assert bench.scope_path(
+                f"jit(f)/{scopes.TEXT}/{scopes.layer_scope(4)}/"
+                f"{scopes.DELTA_SCAN}/jit(_delta_pallas)/gated_delta_scan"
+                "/pallas_call", vocabulary
+            ) == f"{scopes.TEXT}/{scopes.layer_scope(4)}/{scopes.DELTA_SCAN}"
         elif builder == "olmoe_builder":
             from realtime_fraud_detection_tpu.models.olmoe import TINY_OLMOE
 

@@ -17,6 +17,7 @@ from realtime_fraud_detection_tpu.models.falcon_h1 import TINY_FALCON_H1
 from realtime_fraud_detection_tpu.models.joyai import TINY_JOYAI
 from realtime_fraud_detection_tpu.models.laguna import TINY_LAGUNA
 from realtime_fraud_detection_tpu.models.nemotron_h import TINY_NEMOTRON_H
+from realtime_fraud_detection_tpu.models.qwen3_next import TINY_QWEN3_NEXT
 from realtime_fraud_detection_tpu.models.olmoe import TINY_OLMOE
 from realtime_fraud_detection_tpu.models.text_encoder import (
     LAUNCH_COUNTERS,
@@ -49,8 +50,9 @@ ENCODERS = {
     "joyai": (TINY_JOYAI, 32, 128),
     "falconh1": (TINY_FALCON_H1, 64, 8),
     "nemotron3": (TINY_NEMOTRON_H, 32, 128),
+    "qwen3next": (TINY_QWEN3_NEXT, 32, 128),
 }
-ROUTED = ("olmoe", "zaya1", "laguna", "joyai", "nemotron3")
+ROUTED = ("olmoe", "zaya1", "laguna", "joyai", "nemotron3", "qwen3next")
 SCANNED = ("falconh1", "nemotron3")
 
 # what StreamJob.counters held at the parent (PR 48), letter for letter:
@@ -63,7 +65,8 @@ JOB_COUNTERS = (
     "expert_token_slots", "compact_batches",
     "dispatch_rows", "dispatch_kernel_rows",
     "routed_pairs", "attn_visible_pairs_full", "attn_visible_pairs_sliding",
-    "ssm_chunks", "short_text_rows", "long_text_rows", "split_batches")
+    "ssm_chunks", "short_text_rows", "long_text_rows", "split_batches",
+    "delta_chunks")
 
 
 def _scorer(name):
@@ -122,14 +125,18 @@ def test_the_snapshot_counts_each_encoders_own_sites(first_batch):
         sites |= {"expert_gate_up", "expert_dispatch", "expert_combine"}
     if name in SCANNED:
         sites |= {"ssm_scan"}
+    if name == "qwen3next":
+        sites |= {"delta_scan"}
     snap = scorer.kernel_snapshot()
     assert set(snap) == {"modes", "interpret", "dispatch", "fallback",
                          "refused"}
     assert set(snap["dispatch"]) == set(snap["fallback"]) == sites
     assert set(snap["modes"]) == {"dequant_matmul", "epilogue", "attention"}
     # the reasons the snapshot can name with no launch in hand
-    assert set(snap["refused"]) == {"attention"} | (sites & {"ssm_scan"})
+    assert set(snap["refused"]) == {"attention"} | (
+        sites & {"ssm_scan", "delta_scan"})
     assert all("cpu mesh" in why or "head_dim" in why or "seq_len" in why
+               or "key_dim" in why
                for why in snap["refused"].values())
     # a CPU mesh is never asked for a kernel: every launch of the first
     # batch is a fallback at each of the encoder's own sites
@@ -175,6 +182,10 @@ def test_a_batchs_counters_against_a_hand_count(first_batch):
                 sum(min(i + 1, 8) for i in range(n)) for n in lengths))
             assert 0 < c["expert_rows"] < pairs
             want.update(expert_rows=c["expert_rows"])
+        if name == "qwen3next":
+            # a quarter of the router's experts are held here, as Laguna's
+            assert 0 < c["expert_rows"] < pairs
+            want.update(expert_rows=c["expert_rows"])
         # the largest group of each layer, times the experts held; nothing
         # visited by a kernel's grid in the XLA form
         assert c["expert_peak_rows"] % config.num_experts == 0
@@ -185,6 +196,9 @@ def test_a_batchs_counters_against_a_hand_count(first_batch):
     if name == "nemotron3":
         # the two M layers of MEM*E, over every slot of the launch
         want.update(ssm_chunks=rows * width // 128 * 2)
+    if name == "qwen3next":
+        # the four L layers of LLLFL, over every slot of the launch
+        want.update(delta_chunks=rows * width // 8 * 4)
     assert c == want
     assert visible_pairs(config, lengths)[0] == sum(
         int(n) * (int(n) + 1) // 2 for n in lengths)
@@ -226,7 +240,7 @@ def test_the_jobs_counters_are_the_same_names_for_every_encoder(first_batch):
     # (after the tests of the first batch alone: this one launches more)
     scorer, gen = first_batch["scorer"], first_batch["gen"]
     assert scorer_mod.LAUNCH_COUNTERS is LAUNCH_COUNTERS
-    assert len(JOB_COUNTERS) == 23
+    assert len(JOB_COUNTERS) == 24
     broker = InMemoryBroker()
     job = StreamJob(broker, scorer, JobConfig(max_batch=8))
     # every name from the start, at 0, whichever the encoder fills
