@@ -81,6 +81,11 @@ from realtime_fraud_detection_tpu.ops.attention import (
     windowed_attention,
     windowed_refusal,
 )
+from realtime_fraud_detection_tpu.ops.causal_conv import (
+    LANES,
+    causal_conv_silu,
+    conv_refusal,
+)
 from realtime_fraud_detection_tpu.ops.ssd_scan import ssd_refusal, ssd_scan
 
 
@@ -196,6 +201,13 @@ class FalconH1Config:
         return ssd_refusal(seq_len, self.mamba_d_head, self.mamba_d_state,
                            self.mamba_chunk_size, self.mamba_n_heads,
                            self.mamba_n_groups)
+
+    def conv_refusal(self, seq_len: int) -> Optional[str]:
+        """The same of the mixer's convolution
+        (``ops.causal_conv.conv_refusal``) over ``x | B | C``."""
+        gn = self.mamba_n_groups * self.mamba_d_state
+        return conv_refusal(seq_len, (self.mamba_d_ssm, gn, gn),
+                            self.mamba_d_conv, offset=self.mamba_d_ssm)
 
 
 # two groups, more heads than groups, five query heads a key-value head; a
@@ -342,6 +354,33 @@ def causal_conv(x: jax.Array, taps: jax.Array,
     return conv if bias is None else bias + conv
 
 
+def conv_silu_parts(x: jax.Array, taps: jax.Array,
+                    bias: Optional[jax.Array], parts: Tuple[int, ...],
+                    dtypes: Tuple, *, offset: int = 0, kernel: bool = False,
+                    interpret: bool = False) -> Tuple[jax.Array, ...]:
+    """``silu(causal_conv(x[..., offset:offset + sum(parts)], taps,
+    bias))`` cut along the channels into ``parts``, each rounded once to
+    its reader's dtype. ``kernel`` asks for the Pallas form
+    (``ops.causal_conv.causal_conv_silu``, which reads the channels out of
+    ``x`` where it lies and writes the parts itself): the caller answers
+    for the shape (``conv_refusal``)."""
+    if kernel:
+        # an array whose last side is no whole number of lane tiles lies
+        # positions-minor on the TPU: the kernel reads that, a bitcast away
+        last = x.shape[-1] % LANES != 0
+        out = causal_conv_silu(
+            jnp.swapaxes(x, 1, 2) if last else x, taps, bias, parts=parts,
+            dtypes=dtypes, offset=offset, positions_last=last,
+            interpret=interpret)
+        return tuple(jnp.swapaxes(part, 1, 2) for part in out) if last \
+            else out
+    y = jax.nn.silu(causal_conv(x[..., offset:offset + sum(parts)], taps,
+                                bias))
+    edges = np.cumsum((0,) + tuple(parts))
+    return tuple(y[..., lo:hi].astype(dtype)
+                 for lo, hi, dtype in zip(edges, edges[1:], dtypes))
+
+
 def group_rms_norm(x: jax.Array, weight: jax.Array, groups: int, eps: float
                    ) -> jax.Array:
     """RMSNorm over each of ``groups`` equal parts of the last axis."""
@@ -353,17 +392,18 @@ def group_rms_norm(x: jax.Array, weight: jax.Array, groups: int, eps: float
 
 def mamba2_mix(layer: Dict, p: jax.Array, *, heads: int, head_dim: int,
                groups: int, state: int, chunk: int, eps: float,
-               scan_kernel: bool = False, kernel_interpret: bool = False
-               ) -> jax.Array:
+               scan_kernel: bool = False, conv_kernel: bool = False,
+               kernel_interpret: bool = False) -> jax.Array:
     """A Mamba-2 mixer from ``W_in``'s result on: ``p`` ``f32[B, T, d_inner
     + (d_inner + 2 groups state) + heads]`` (``z`` | ``xBC`` | ``dt``,
     whatever multiplied it already applied) through the convolution, the
     scan, the gate and the grouped norm to ``y W_out`` ``f32[B, T,
     hidden]``; ``d_inner`` = ``heads x head_dim``. The layer's own
     parameters under the names both encoders with such a mixer store them
-    by (this one and ``models/nemotron_h.py``). ``scan_kernel`` asks for
-    the scan's Pallas form: the caller answers for the shape
-    (``ops.ssd_scan.ssd_refusal``)."""
+    by (this one and ``models/nemotron_h.py``). ``scan_kernel`` and
+    ``conv_kernel`` ask for the scan's and the convolution's Pallas forms:
+    the caller answers for the shape (``ops.ssd_scan.ssd_refusal``,
+    ``ops.causal_conv.conv_refusal``)."""
     b, t, _ = p.shape
     operand = layer["in_proj"].dtype
     d_ssm = heads * head_dim
@@ -372,15 +412,14 @@ def mamba2_mix(layer: Dict, p: jax.Array, *, heads: int, head_dim: int,
         z = p[..., :d_ssm]
         dt = p[..., d_ssm + conv_dim:]
     with jax.named_scope(scopes.SSM_CONV):
-        xbc = jax.nn.silu(causal_conv(
-            p[..., d_ssm:d_ssm + conv_dim], layer["conv_weight"],
-            layer["conv_bias"])).astype(operand)
+        x, b_in, c_in = conv_silu_parts(
+            p, layer["conv_weight"], layer["conv_bias"],
+            (d_ssm, groups * state, groups * state), (operand,) * 3,
+            offset=d_ssm, kernel=conv_kernel, interpret=kernel_interpret)
         dt = jax.nn.softplus(dt + layer["dt_bias"])
-        x = xbc[..., :d_ssm].reshape(b, t, heads, head_dim)
-        b_in = xbc[..., d_ssm:d_ssm + groups * state].reshape(
-            b, t, groups, state)
-        c_in = xbc[..., d_ssm + groups * state:].reshape(
-            b, t, groups, state)
+        x = x.reshape(b, t, heads, head_dim)
+        b_in = b_in.reshape(b, t, groups, state)
+        c_in = c_in.reshape(b, t, groups, state)
     with jax.named_scope(scopes.SSM_SCAN):
         y, _ = ssd_scan(
             x, dt, -jnp.exp(layer["A_log"]), b_in, c_in, layer["D"],
@@ -396,16 +435,19 @@ def falcon_mixer(layer: Dict, u: jax.Array, config: FalconH1Config, *,
                  ) -> jax.Array:
     """The Mamba-2 mixer on the normed ``u`` ``f32[B, T, hidden]``:
     ``f32[B, T, hidden]`` ahead of ``ssm_out_multiplier``. ``use_pallas``
-    asks for the scan's kernel; a shape it does not take
-    (``FalconH1Config.scan_refusal``) runs the XLA form."""
+    asks for the scan's and the convolution's kernels; a shape one does not
+    take (``FalconH1Config.scan_refusal`` / ``conv_refusal``) runs its XLA
+    form."""
     with jax.named_scope(scopes.SSM_PROJ):
         p = _proj(u * config.ssm_in_multiplier, layer["in_proj"]) \
             * mup_vector(config)
+    t = u.shape[1]
     return mamba2_mix(
         layer, p, heads=config.mamba_n_heads, head_dim=config.mamba_d_head,
         groups=config.mamba_n_groups, state=config.mamba_d_state,
         chunk=config.mamba_chunk_size, eps=config.rms_norm_eps,
-        scan_kernel=use_pallas and config.scan_refusal(u.shape[1]) is None,
+        scan_kernel=use_pallas and config.scan_refusal(t) is None,
+        conv_kernel=use_pallas and config.conv_refusal(t) is None,
         kernel_interpret=kernel_interpret)
 
 
@@ -534,7 +576,7 @@ def _dispatch_counters(config, launches, lengths):
 
 
 # causal and dense: one launch at text_len on one device, every slot
-# computed, and a second kernel site, the mixer's scan
+# computed, and two more kernel sites, the mixer's scan and its convolution
 # (models/text_encoder.py)
 TEXT_ENCODER = TextEncoder(
     config_class=FalconH1Config, init=init_falcon_h1_params,
@@ -543,6 +585,8 @@ TEXT_ENCODER = TextEncoder(
     sites=(KernelSite("attention",
                       lambda c, width, slots: c.core_refusal(width)),
            KernelSite("ssm_scan",
-                      lambda c, width, slots: c.scan_refusal(width))),
+                      lambda c, width, slots: c.scan_refusal(width)),
+           KernelSite("causal_conv",
+                      lambda c, width, slots: c.conv_refusal(width))),
     one_device="the fused causal core and the scan; a causal",
     dispatch_counters=_dispatch_counters)

@@ -96,6 +96,7 @@ from realtime_fraud_detection_tpu.ops.attention import (
     windowed_attention,
     windowed_refusal,
 )
+from realtime_fraud_detection_tpu.ops.causal_conv import conv_refusal
 from realtime_fraud_detection_tpu.ops.ssd_scan import ssd_refusal
 
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
@@ -258,6 +259,13 @@ class NemotronHConfig:
                            self.chunk_size, self.mamba_num_heads,
                            self.n_groups)
 
+    def conv_refusal(self, seq_len: int) -> Optional[str]:
+        """The same of the mixer's convolution
+        (``ops.causal_conv.conv_refusal``) over ``x | B | C``."""
+        gn = self.n_groups * self.ssm_state_size
+        return conv_refusal(seq_len, (self.d_inner, gn, gn),
+                            self.conv_kernel, offset=self.d_inner)
+
 
 # the odd shapes kept: heads of 64 in groups of 8 over a state of 128, an
 # expert width that is no whole number of lane tiles, hidden / 128 no whole
@@ -404,16 +412,19 @@ def nemotron_mixer(layer: Dict, u: jax.Array, config: NemotronHConfig, *,
                    use_pallas: bool = False, kernel_interpret: bool = False
                    ) -> jax.Array:
     """An ``M`` layer's mixer on the normed ``u`` ``f32[B, T, hidden]``.
-    ``use_pallas`` asks for the scan's kernel; a shape it does not take
-    (``NemotronHConfig.scan_refusal``) runs the XLA form."""
+    ``use_pallas`` asks for the scan's and the convolution's kernels; a
+    shape one does not take (``NemotronHConfig.scan_refusal`` /
+    ``conv_refusal``) runs its XLA form."""
     with jax.named_scope(scopes.SSM_PROJ):
         p = _proj(u, layer["in_proj"])
+    t = u.shape[1]
     return mamba2_mix(
         layer, p, heads=config.mamba_num_heads,
         head_dim=config.mamba_head_dim, groups=config.n_groups,
         state=config.ssm_state_size, chunk=config.chunk_size,
         eps=config.layer_norm_epsilon,
-        scan_kernel=use_pallas and config.scan_refusal(u.shape[1]) is None,
+        scan_kernel=use_pallas and config.scan_refusal(t) is None,
+        conv_kernel=use_pallas and config.conv_refusal(t) is None,
         kernel_interpret=kernel_interpret)
 
 
@@ -557,5 +568,7 @@ TEXT_ENCODER = routed_encoder(
     NemotronHConfig, init_nemotron_h_params, nemotron_h_predict,
     NemotronHConfig.core_refusal,
     sites=(KernelSite("ssm_scan",
-                      lambda c, width, slots: c.scan_refusal(width)),),
+                      lambda c, width, slots: c.scan_refusal(width)),
+           KernelSite("causal_conv",
+                      lambda c, width, slots: c.conv_refusal(width))),
     dispatch_counters=_ssm_chunks, expert_matrices=1)

@@ -74,7 +74,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from realtime_fraud_detection_tpu.models.falcon_h1 import causal_conv
+from realtime_fraud_detection_tpu.models.falcon_h1 import conv_silu_parts
 from realtime_fraud_detection_tpu.models.laguna import _rotate, swiglu
 from realtime_fraud_detection_tpu.models.olmoe import (
     _proj,
@@ -102,6 +102,7 @@ from realtime_fraud_detection_tpu.ops.attention import (
     windowed_attention,
     windowed_refusal,
 )
+from realtime_fraud_detection_tpu.ops.causal_conv import conv_refusal
 from realtime_fraud_detection_tpu.ops.delta_scan import (
     delta_refusal,
     gated_delta_scan,
@@ -247,6 +248,13 @@ class Qwen3NextConfig:
                              self.linear_value_head_dim, self.delta_chunk,
                              self.linear_num_key_heads,
                              self.linear_num_value_heads)
+
+    def conv_refusal(self, seq_len: int) -> Optional[str]:
+        """The same of the mixer's convolution
+        (``ops.causal_conv.conv_refusal``) over ``q | k | v``."""
+        return conv_refusal(seq_len,
+                            (self.key_dim, self.key_dim, self.value_dim),
+                            self.linear_conv_kernel_dim)
 
 
 # a whole period and one layer more (LLLFL), a quarter of the router's
@@ -429,8 +437,9 @@ def delta_mixer(layer: Dict, u: jax.Array, config: Qwen3NextConfig, *,
                 use_pallas: bool = False, kernel_interpret: bool = False
                 ) -> jax.Array:
     """An ``L`` layer's mixer on the normed ``u`` ``f32[B, T, hidden]``.
-    ``use_pallas`` asks for the scan's kernel; a shape it does not take
-    (``Qwen3NextConfig.scan_refusal``) runs the XLA form."""
+    ``use_pallas`` asks for the scan's and the convolution's kernels; a
+    shape one does not take (``Qwen3NextConfig.scan_refusal`` /
+    ``conv_refusal``) runs its XLA form."""
     b, t, h = u.shape
     hk, hv = config.linear_num_key_heads, config.linear_num_value_heads
     dk, dv = config.linear_key_head_dim, config.linear_value_head_dim
@@ -449,16 +458,22 @@ def delta_mixer(layer: Dict, u: jax.Array, config: Qwen3NextConfig, *,
         z = _proj(u, part(v_end, v_end + ratio * dv))          # [B, T, Hv*D]
         ba = _proj(u, layer["in_proj_ba"]).reshape(b, t, hk, 2 * ratio)
     with jax.named_scope(scopes.DELTA_CONV):
-        qkv = jax.nn.silu(causal_conv(qkv, layer["conv_weight"]))
-        q = l2_norm(qkv[..., :hk * dk].reshape(b, t, hk, dk)) * dk ** -0.5
-        k = l2_norm(qkv[..., hk * dk:2 * hk * dk].reshape(b, t, hk, dk))
-        v = qkv[..., 2 * hk * dk:].reshape(b, t, hv, dv)
+        # q and k stay float32 for their norms; v is rounded as the scan
+        # reads it
+        q, k, v = conv_silu_parts(
+            qkv, layer["conv_weight"], None, (hk * dk, hk * dk, hv * dv),
+            (jnp.float32, jnp.float32, operand),
+            kernel=use_pallas and config.conv_refusal(t) is None,
+            interpret=kernel_interpret)
+        q = l2_norm(q.reshape(b, t, hk, dk)) * dk ** -0.5
+        k = l2_norm(k.reshape(b, t, hk, dk))
+        v = v.reshape(b, t, hv, dv)
         beta = jax.nn.sigmoid(ba[..., :ratio].reshape(b, t, hv))
         g = -jnp.exp(layer["A_log"]) * jax.nn.softplus(
             ba[..., ratio:].reshape(b, t, hv) + layer["dt_bias"])
     with jax.named_scope(scopes.DELTA_SCAN):
         o, _ = gated_delta_scan(
-            q.astype(operand), k.astype(operand), v.astype(operand), g, beta,
+            q.astype(operand), k.astype(operand), v, g, beta,
             chunk=config.delta_chunk,
             use_pallas=use_pallas and config.scan_refusal(t) is None,
             interpret=kernel_interpret)
@@ -625,5 +640,7 @@ TEXT_ENCODER = routed_encoder(
     Qwen3NextConfig, init_qwen3_next_params, qwen3_next_predict,
     Qwen3NextConfig.core_refusal,
     sites=(KernelSite("delta_scan",
-                      lambda c, width, slots: c.scan_refusal(width)),),
+                      lambda c, width, slots: c.scan_refusal(width)),
+           KernelSite("causal_conv",
+                      lambda c, width, slots: c.conv_refusal(width))),
     dispatch_counters=_delta_chunks)

@@ -64,6 +64,15 @@ a predicate admits holds the kernel, everything else the XLA form.
   value heads' 128 x 128 states in VMEM — against the same algorithm in
   ``jax.numpy`` (``use_pallas=False``); ``delta_refusal``, asked through
   ``Qwen3NextConfig.scan_refusal``.
+- ``causal_conv``: the depthwise causal convolution of a mixer with its
+  SiLU (``models/falcon_h1.py``'s Mamba-2 mixer, which
+  ``models/nemotron_h.py`` shares, and ``models/qwen3_next.py``'s
+  Gated-DeltaNet one), ``causal_conv_silu`` — ONE kernel a layer that reads
+  the convolved channels out of the array where the TPU holds it (positions
+  down the sublanes or, ``positions_last``, along the lanes), shifts by
+  ``pltpu.roll`` and writes the parts its caller cuts, each in its
+  reader's dtype — against ``jax.nn.silu`` of ``falcon_h1.causal_conv``;
+  ``conv_refusal``, asked through the configuration's ``conv_refusal``.
 - ``dequant_matmul``, ``epilogue``: the int8 text branch's fused
   dequant-matmul and the score-and-blend epilogue, behind ``KernelSettings``.
 """
@@ -79,6 +88,10 @@ from realtime_fraud_detection_tpu.ops.attention import (  # noqa: F401
     split_heads,
     windowed_attention,
     windowed_refusal,
+)
+from realtime_fraud_detection_tpu.ops.causal_conv import (  # noqa: F401
+    causal_conv_silu,
+    conv_refusal,
 )
 from realtime_fraud_detection_tpu.ops.cca_mix import (  # noqa: F401
     cca_mix_fused,

@@ -892,8 +892,10 @@ def test_ssd_scan_compiles_at_published_widths(one_chip, bucket, carried):
 def test_falconh1_program_compiles_with_both_kernels_in_every_layer(one_chip):
     """The served packed program with a ``FalconH1Config``: two of the
     parallel hybrid layers, every width as published, bucket 8 x 2,048
-    tokens: the fused causal core (five query heads a key-value head) and
-    the scan's kernel in each layer, ONE result, and temporaries that leave
+    tokens: the fused causal core (five query heads a key-value head), the
+    scan's kernel and (PR 55) the convolution's in each layer — which reads
+    x | B | C out of ``W_in``'s result where it lies, positions last: no
+    float32 copy of a slice of it — ONE result, and temporaries that leave
     room for the cell's six layers of weights in 16 GB."""
     from realtime_fraud_detection_tpu.core.packing import pack_tree
     from realtime_fraud_detection_tpu.ensemble.combine import EnsembleParams
@@ -922,8 +924,13 @@ def test_falconh1_program_compiles_with_both_kernels_in_every_layer(one_chip):
         blob_bf16=_shapes_of(blobs["bf16"], one_chip), bert_config=config,
         use_pallas=True).compile()
     text = compiled.as_text()
-    assert text.count(CUSTOM_CALL) == 2 + 2
+    assert text.count(CUSTOM_CALL) == 2 + 2 + 2
     assert text.count("ssm_scan/jit(_ssd_pallas)/ssd_scan/pallas_call") >= 2
+    assert text.count("ssm_conv/jit(_conv_pallas)/causal_conv/"
+                      "pallas_call") >= 2
+    assert "f32[8,9248,2048]{2,1,0}" in text       # the operand as it lies
+    assert _float32_copies(text, "f32[8,2048,5120]",
+                           "f32[8,2048,9248]") == []
     assert text.count("attn_core/jit(windowed_attention)/"
                       "windowed_attention/pallas_call") >= 2
     assert " conditional(" not in text and "cond/branch_" not in text
@@ -954,6 +961,14 @@ def test_falconh1_weights_are_made_without_a_float32_copy(one_chip):
 # an E layer's grouped calls: 8 x 2,048 slots at the 3/4 rung and at every
 # slot, and bucket 1's one launch, x 6 experts a token over 128 groups
 NEMOTRON_ROWS = (73728, 98304, 12288)
+
+
+def _float32_copies(text: str, *shapes: str):
+    """The program's ``copy`` instructions that write one of ``shapes``:
+    a Mosaic call's operand re-laid for it."""
+    return [line.strip()[:160] for line in text.splitlines()
+            if " copy(" in line and any(
+                f" = {shape}{{" in line for shape in shapes)]
 
 
 def _copies_of_up_matrices(text: str, experts: int = 128):
@@ -1113,9 +1128,12 @@ def test_nemotron3_program_compiles_with_a_kernel_for_every_kind(
         one_chip, bucket, capacity, temporaries):
     """The served packed program with a ``NemotronHConfig``: one layer of
     each KIND (``ME*``), every width as published, bucket 8 x 2,048 tokens at
-    both capacities and bucket 1: the scan's pair kernel, the ungated
-    grouped call, down's, the combine and the fused causal core — five
-    Mosaic calls — a second small output, no conditional, no float32
+    both capacities and bucket 1: the scan's pair kernel, the convolution's
+    kernel (PR 55: x | B | C read out of ``W_in``'s result positions last,
+    and the row-major float32 copy of them that XLA made for its own
+    fusion is gone), the ungated grouped call, down's, the combine and the
+    fused causal core — six Mosaic calls — a second small output, no
+    conditional, no float32
     ``[pairs, 2688]`` array, no copy of a parameter, and temporaries that
     leave room for the cell's 11.44 GB of weights in 16 GB: 1.57 / 2.01 /
     0.24 GB read here (2.26 / 2.51 with the copy's destination, PR 50), held
@@ -1148,8 +1166,11 @@ def test_nemotron3_program_compiles_with_a_kernel_for_every_kind(
         blob_bf16=_shapes_of(blobs["bf16"], one_chip), bert_config=config,
         use_pallas=True, text_capacity=capacity).compile()
     text = compiled.as_text()
-    assert text.count(CUSTOM_CALL) == 5
+    assert text.count(CUSTOM_CALL) == 6
+    assert _float32_copies(text, f"f32[{bucket},2048,6144]",
+                           f"f32[{bucket},2048,10304]") == []
     for call in ("ssm_scan/jit(_ssd_pallas)/ssd_scan",
+                 "ssm_conv/jit(_conv_pallas)/causal_conv",
                  "attn_core/jit(windowed_attention)/windowed_attention",
                  "jit(relu2_gmm)/relu2_gmm", "jit(down_gmm)/down_gmm",
                  "jit(combine_rows)/weighted_combine"):
@@ -1218,6 +1239,52 @@ def test_delta_scan_compiles_at_published_widths(one_chip, bucket, carried):
         q, k, v, g, beta, chunk=chunk, initial_state=state,
         use_pallas=True)).lower(*args).compile()
     assert compiled.as_text().count(CUSTOM_CALL) == 1
+
+
+CONV_SITES = {
+    # parts, their dtypes, bias, the array's width, the first channel
+    "falconh1": ((4096, 512, 512), ("bfloat16",) * 3, True, 9248, 4096),
+    "nemotron3": ((4096, 1024, 1024), ("bfloat16",) * 3, True, 10304, 4096),
+    "qwen3next": ((2048, 2048, 4096), ("float32", "float32", "bfloat16"),
+                  False, 8192, 0),
+}
+
+
+@pytest.mark.parametrize("bucket", [8, 1])
+@pytest.mark.parametrize("encoder", sorted(CONV_SITES))
+def test_causal_conv_compiles_where_each_mixer_holds_its_input(
+        one_chip, encoder, bucket):
+    """The convolution's kernel at the three cells' shapes, 2,048 positions:
+    positions last out of ``W_in``'s 9,248 / 10,304 channels (no whole
+    number of lane tiles: the TPU holds such an array positions-minor),
+    positions first on Qwen3-Next's 8,192; ONE Mosaic call, the parts its
+    only outputs."""
+    from realtime_fraud_detection_tpu.ops.causal_conv import (
+        causal_conv_silu,
+        conv_refusal,
+    )
+
+    parts, dtypes, biased, wide, offset = CONV_SITES[encoder]
+    last = wide % 128 != 0
+    assert conv_refusal(2048, parts, 4, offset) is None
+    c = sum(parts)
+    args = [_sds((bucket, wide, 2048) if last else (bucket, 2048, wide),
+                 jnp.float32, one_chip),
+            _sds((4, c), jnp.float32, one_chip)]
+    if biased:
+        args.append(_sds((c,), jnp.float32, one_chip))
+    compiled = jax.jit(lambda x, taps, bias=None: causal_conv_silu(
+        x, taps, bias, parts=parts, dtypes=dtypes, offset=offset,
+        positions_last=last)).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count(CUSTOM_CALL) == 1
+    assert [line for line in text.splitlines()
+            if " copy(" in line and f"{bucket},2048" in line] == []
+    out = sum(bucket * 2048 * width * jnp.dtype(d).itemsize
+              for width, d in zip(parts, dtypes))
+    memory = compiled.memory_analysis()
+    assert out <= memory.output_size_in_bytes <= out + 4096
+    assert memory.temp_size_in_bytes <= 1 << 20   # taps turned, no more
 
 
 @pytest.mark.parametrize("bucket", [8, 1])
@@ -1303,8 +1370,10 @@ def test_qwen3next_program_compiles_with_a_kernel_at_every_site(
     """The served packed program with a ``Qwen3NextConfig``: one whole
     period (``LLLF``), every width as published, half of each layer's
     experts held, bucket 8 x 2,048 tokens at both capacities and bucket 1:
-    three delta scans, the fused core at heads of 256 and three Mosaic
-    calls a sparse half — sixteen — a second small output, no conditional,
+    three delta scans, their three convolutions (PR 55: ``qkv`` read as
+    the projection wrote it, q | k | v written apart), the fused core at
+    heads of 256 and three Mosaic calls a sparse half — nineteen — a second
+    small output, no conditional,
     no float32 ``[pairs, 2048]`` array, no ``[8, 16, 2048, 2048]`` of
     scores, no copy of a parameter, and temporaries that leave room for the
     cell's 10.73 GB of weights in 16 GB: 1.38 / 1.79 / 0.43 GB read here at
@@ -1336,8 +1405,10 @@ def test_qwen3next_program_compiles_with_a_kernel_at_every_site(
         blob_bf16=_shapes_of(blobs["bf16"], one_chip), bert_config=config,
         use_pallas=True, text_capacity=capacity).compile()
     text = compiled.as_text()
-    assert text.count(CUSTOM_CALL) == 3 + 1 + 4 * 3
+    assert text.count(CUSTOM_CALL) == 3 + 3 + 1 + 4 * 3
+    assert _float32_copies(text, f"f32[{bucket},2048,8192]") == []
     for call in ("delta_scan/jit(_delta_pallas)/gated_delta_scan",
+                 "delta_conv/jit(_conv_pallas)/causal_conv",
                  "attn_core/jit(windowed_attention)/windowed_attention",
                  "jit(gated_gmm)/gated_gmm", "jit(down_gmm)/down_gmm",
                  "jit(combine_rows)/weighted_combine"):

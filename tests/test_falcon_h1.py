@@ -30,6 +30,7 @@ from realtime_fraud_detection_tpu.models.falcon_h1 import (
     mup_vector,
 )
 from realtime_fraud_detection_tpu.ops.attention import windowed_refusal
+from realtime_fraud_detection_tpu.ops.causal_conv import conv_refusal
 from realtime_fraud_detection_tpu.ops.ssd_scan import ssd_refusal, ssd_scan
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -418,6 +419,13 @@ def test_a_declined_shape_asked_for_the_kernel_runs_the_xla_form():
 def test_the_published_shapes_hold_both_kernels_and_tiny_names_why_not():
     full = FalconH1Config()
     assert full.core_refusal(2048) is None is full.scan_refusal(2048)
+    # the convolution's kernel over x | B | C out of W_in's 9,248, from
+    # channel 4,096 on (TINY's parts of 64 are no lane tile)
+    assert full.conv_refusal(2048) is None
+    assert full.conv_refusal(2048) == conv_refusal(
+        2048, (4096, 512, 512), 4, offset=4096)
+    assert "seq_len 100" in full.conv_refusal(100)
+    assert "parts (64, 64, 64) from channel 64" in CFG.conv_refusal(2048)
     # five query heads a key-value head: a count no other encoder has
     assert full.num_attention_heads // full.num_key_value_heads == 5
     assert windowed_refusal(2048, 128, 20, 4, None) is None
@@ -433,19 +441,32 @@ def test_the_published_shapes_hold_both_kernels_and_tiny_names_why_not():
 def test_the_encoder_with_both_kernels_interpreted_is_the_xla_form(
         monkeypatch):
     """At ``head_dim`` 128 and chunks of 128 the program asked for its
-    kernels holds the fused causal core AND the scan's kernel in every
-    layer; through the interpreter it answers what the XLA forms answer."""
+    kernels holds the fused causal core, the scan's kernel AND the
+    convolution's (since PR 55: x | B | C of 2,048 | 256 | 256 read out of
+    ``W_in``'s 4,624-wide result positions last) in every layer; through
+    the interpreter it answers what the XLA forms answer."""
     params = init_falcon_h1_params(jax.random.PRNGKey(3), LANE_CFG)
     t, lengths = 256, (256, 130, 97)
     assert LANE_CFG.core_refusal(t) is None is LANE_CFG.scan_refusal(t)
+    assert LANE_CFG.conv_refusal(t) is None
     ids = jax.random.randint(jax.random.PRNGKey(4), (3, t), 0, 512)
     mask = jnp.arange(t)[None, :] < jnp.array(lengths)[:, None]
     plain = falcon_h1_predict(params, ids, mask, LANE_CFG)
-    asked = {"scan": 0, "core": 0}
+    asked = {"scan": 0, "core": 0, "conv": 0}
 
     def scan(*a, **kw):
         asked["scan"] += bool(kw["use_pallas"] and kw["interpret"])
         return ssd_scan(*a, **kw)
+
+    whole_conv = falcon_h1.causal_conv_silu
+
+    def conv(*a, **kw):
+        asked["conv"] += bool(kw["interpret"] and kw["positions_last"])
+        return whole_conv(*a, **kw)
+
+    def no_xla_conv(*a, **kw):
+        raise AssertionError("the XLA convolution in a program that holds "
+                             "the kernel")
 
     whole_core = falcon_h1.windowed_attention
 
@@ -455,11 +476,13 @@ def test_the_encoder_with_both_kernels_interpreted_is_the_xla_form(
 
     monkeypatch.setattr(falcon_h1, "ssd_scan", scan)
     monkeypatch.setattr(falcon_h1, "windowed_attention", core)
+    monkeypatch.setattr(falcon_h1, "causal_conv_silu", conv)
+    monkeypatch.setattr(falcon_h1, "causal_conv", no_xla_conv)
     fused = np.asarray(jax.jit(lambda i, m: falcon_h1_predict(
         params, i, m, LANE_CFG, use_pallas=True, kernel_interpret=True))(
         ids, mask))
-    assert asked == {"scan": LANE_CFG.num_hidden_layers,
-                     "core": LANE_CFG.num_hidden_layers}
+    assert asked == dict.fromkeys(("scan", "core", "conv"),
+                                  LANE_CFG.num_hidden_layers)
     assert np.abs(fused - np.asarray(plain)).max() < 3e-3
     want = _reference(params, ids, mask, LANE_CFG)
     assert np.abs(fused - want).max() < LIMIT / 2
@@ -491,10 +514,13 @@ def test_the_class_picks_the_encoder_and_answers_the_seams_questions():
         is falcon_h1.TEXT_ENCODER
     assert row.capacities(4096) is None and row.narrow_width(CFG) is None
     assert row.init is init_falcon_h1_params and not row.planes
-    attention, scan = row.sites
-    assert (attention.name, scan.name) == ("attention", "ssm_scan")
+    attention, scan, conv = row.sites
+    assert (attention.name, scan.name, conv.name) == (
+        "attention", "ssm_scan", "causal_conv")
     assert attention.refusal(CFG, 256, 256) == CFG.core_refusal(256)
     assert scan.refusal(CFG, 256, 256) == CFG.scan_refusal(256)
+    assert conv.refusal(CFG, 256, 256) == CFG.conv_refusal(256)
+    assert conv.refusal(LANE_CFG, 256, 256) is None
     assert pipeline.text_layers(CFG) == 2
     # the routed rows (six since Qwen3-Next's; Nemotron-3-Nano's is routed
     # AND has this scan site) are what they were; the dense encoder's takes
@@ -579,13 +605,14 @@ def test_the_scorer_counts_the_scan_site_and_names_its_refusals(scorer32):
     before = scorer.kernel_snapshot()
     scorer.finalize(scorer.dispatch(gen.generate_batch(3)))
     snap = scorer.kernel_snapshot()
-    # a CPU mesh is never asked for its kernels: a fallback at both sites
-    for site in ("attention", "ssm_scan"):
+    # a CPU mesh is never asked for its kernels: a fallback at every site
+    for site in ("attention", "ssm_scan", "causal_conv"):
         assert snap["fallback"][site] == before["fallback"][site] + 1
         assert snap["dispatch"][site] == 0
     assert "expert_gate_up" not in snap["dispatch"]
     assert "head_dim 16" in snap["refused"]["attention"]
     assert "head_dim 16, chunk 16" in snap["refused"]["ssm_scan"]
+    assert "parts (64, 64, 64)" in snap["refused"]["causal_conv"]
     split = scorer.host_stats()["text_split"]
     assert split["width"] is None and "FalconH1Config" in split["refused"]
     assert split["families"] == {} and split["compact_batches"] == 0
@@ -597,6 +624,7 @@ def test_a_lane_shaped_scorer_on_a_cpu_mesh_names_the_platform():
     refused = scorer.kernel_snapshot()["refused"]
     assert "cpu mesh" in refused["attention"]
     assert "cpu mesh" in refused["ssm_scan"]
+    assert "cpu mesh" in refused["causal_conv"]
     assert scorer._text_kernel_shape_ok(256)
     assert not _scorer(CFG)._text_kernel_shape_ok(64)
 
@@ -719,3 +747,29 @@ def test_the_new_encoders_program_has_the_one_result():
     assert _lowered(CFG).out_info.shape == (8, 13)
     matrix, stats = _lowered(TINY_OLMOE).out_info
     assert matrix.shape == (8, 13) and stats.shape == (3, 2)
+
+
+def test_a_tap_shifted_in_the_kernels_program_is_caught_too(monkeypatch):
+    """``conv_shifted_a_tap`` planted where the LANE program computes its
+    convolution — in the kernel's input, positions last: the program asked
+    for its kernels reads over the cell's limit against the sound one, as
+    the XLA form's does above."""
+    params = jax.tree.map(lambda x: x.astype(F32), init_falcon_h1_params(
+        jax.random.PRNGKey(3), LANE_CFG))
+    t, lengths = 128, (128, 97, 60, 110)
+    ids = jax.random.randint(jax.random.PRNGKey(4), (len(lengths), t), 0, 512)
+    mask = jnp.arange(t)[None, :] < jnp.array(lengths)[:, None]
+    kernels = dict(use_pallas=True, kernel_interpret=True)
+    sound = _predict32(params, ids, mask, config=LANE_CFG, **kernels)
+    whole = falcon_h1.causal_conv_silu
+
+    def shifted(x, *a, positions_last, **kw):
+        assert positions_last
+        return whole(jnp.pad(x, ((0, 0), (0, 0), (1, 0)))[..., :-1], *a,
+                     positions_last=positions_last, **kw)
+
+    monkeypatch.setattr(falcon_h1, "causal_conv_silu", shifted)
+    gap = np.abs(_predict32(params, ids, mask, config=LANE_CFG, **kernels)
+                 - sound)
+    assert gap.max() > LIMIT, gap
+    assert (gap > LIMIT / 2).sum() >= 2, gap

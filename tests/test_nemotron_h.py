@@ -33,6 +33,7 @@ from realtime_fraud_detection_tpu.models.nemotron_h import (
     nemotron_h_predict,
 )
 from realtime_fraud_detection_tpu.ops.attention import attention_reference
+from realtime_fraud_detection_tpu.ops.causal_conv import conv_refusal
 from realtime_fraud_detection_tpu.ops.grouped_matmul import (
     gmm_tiling,
     grouped_matmul,
@@ -610,18 +611,31 @@ def test_the_encoder_with_its_kernels_interpreted_is_the_xla_form(
     """At attention heads of 128 the program asked for its kernels holds
     the scan's pair kernel, the fused causal core handed NO rotation, the
     ungated grouped call, down's and the combine; through the interpreter
-    it answers what the XLA forms answer."""
+    it answers what the XLA forms answer. Since PR 55 the ``M`` layer's
+    convolution is the kernel too, reading x | B | C out of ``W_in``'s
+    2,576-wide result positions last."""
     params = init_nemotron_h_params(jax.random.PRNGKey(3), LANE_CFG)
     t, lengths = 256, (256, 130)
     assert LANE_CFG.core_refusal(t) is None is LANE_CFG.scan_refusal(t)
+    assert LANE_CFG.conv_refusal(t) is None
     ids = jax.random.randint(jax.random.PRNGKey(4), (2, t), 0, 512)
     mask = jnp.arange(t)[None, :] < jnp.array(lengths)[:, None]
     plain = nemotron_h_predict(params, ids, mask, LANE_CFG)
-    asked = {"scan": 0, "core": 0, "relu2": 0}
+    asked = {"scan": 0, "core": 0, "relu2": 0, "conv": 0}
 
     def scan(*a, **kw):
         asked["scan"] += bool(kw["use_pallas"] and kw["interpret"])
         return ssd_scan(*a, **kw)
+
+    whole_conv = falcon_h1.causal_conv_silu
+
+    def conv(*a, **kw):
+        asked["conv"] += bool(kw["interpret"] and kw["positions_last"])
+        return whole_conv(*a, **kw)
+
+    def no_xla_conv(*a, **kw):
+        raise AssertionError("the XLA convolution in a program that holds "
+                             "the kernel")
 
     whole_core = nemotron_h.windowed_attention
 
@@ -634,12 +648,14 @@ def test_the_encoder_with_its_kernels_interpreted_is_the_xla_form(
         return grouped_relu2_matmul(*a, **kw)
 
     monkeypatch.setattr(falcon_h1, "ssd_scan", scan)
+    monkeypatch.setattr(falcon_h1, "causal_conv_silu", conv)
+    monkeypatch.setattr(falcon_h1, "causal_conv", no_xla_conv)
     monkeypatch.setattr(nemotron_h, "windowed_attention", core)
     monkeypatch.setattr(olmoe, "grouped_relu2_matmul", relu2)
     fused, stats = jax.jit(lambda i, m: nemotron_h_predict(
         params, i, m, LANE_CFG, capacity=None, use_pallas=True,
         kernel_interpret=True, with_stats=True))(ids, mask)
-    assert asked == {"scan": 1, "core": 1, "relu2": 1}
+    assert asked == {"scan": 1, "core": 1, "relu2": 1, "conv": 1}
     assert np.abs(np.asarray(fused) - np.asarray(plain)).max() < 3e-3
     want = _reference(params, ids, mask, LANE_CFG)
     assert np.abs(np.asarray(fused) - want).max() < LIMIT / 2
@@ -663,6 +679,12 @@ def test_published_config_is_the_default_and_the_pattern_is_parsed_once():
     assert full.core_refusal(2048) is None is full.scan_refusal(2048)
     assert "head_dim 16" in CFG.core_refusal(2048)
     assert CFG.scan_refusal(2048) is None           # TINY keeps the mixer
+    # the convolution's kernel over x | B | C out of W_in's 10,304 from
+    # channel 4,096 on; TINY's parts are whole lane tiles too
+    assert full.conv_refusal(2048) is None is CFG.conv_refusal(2048)
+    assert full.conv_refusal(2048) == conv_refusal(
+        2048, (4096, 1024, 1024), 4, offset=4096)
+    assert "seq_len 32" in CFG.conv_refusal(32)
     # the cell's cut: the first nine layers of the published fifty-two
     assert FILE["hybrid_override_pattern"] == PUBLISHED_PATTERN[:9]
 
@@ -773,7 +795,8 @@ def test_one_row_of_the_seam_is_routed_and_state_space_at_once(rung_scorer):
     assert row is nemotron_h.TEXT_ENCODER
     assert [site.name for site in row.sites] == [
         "attention", "expert_gate_up", "expert_dispatch", "expert_combine",
-        "ssm_scan"]
+        "ssm_scan", "causal_conv"]
+    assert row.sites[-1].refusal(CFG, 32, 32) == CFG.conv_refusal(32)
     assert pipeline.text_layers(CFG) == 5
     assert row.capacities(4096) == (3072, 4096)
     assert NemotronHConfig in pipeline.TextConfig.__args__
@@ -784,12 +807,13 @@ def test_one_row_of_the_seam_is_routed_and_state_space_at_once(rung_scorer):
     scorer.finalize(scorer.dispatch(gen.generate_batch(3)))
     snap = scorer.kernel_snapshot()
     # a CPU mesh is never asked for its kernels: a fallback at every site
-    for site in ("attention", "ssm_scan", "expert_gate_up",
+    for site in ("attention", "ssm_scan", "causal_conv", "expert_gate_up",
                  "expert_dispatch", "expert_combine"):
         assert snap["fallback"][site] == before["fallback"][site] + 1, site
         assert snap["dispatch"][site] == 0
     assert "head_dim 16" in snap["refused"]["attention"]
     assert "seq_len 32" in snap["refused"]["ssm_scan"]
+    assert "seq_len 32" in snap["refused"]["causal_conv"]
 
 
 def test_the_stream_job_sums_pairs_and_chunks_by_kind():
@@ -871,3 +895,29 @@ def test_the_six_other_encoders_trace_none_of_what_this_one_added(
     monkeypatch.setattr(gmm_module, "relu2_gmm", poisoned)
     monkeypatch.setattr(olmoe, "grouped_relu2_matmul", poisoned)
     assert _lowered(config).as_text() == whole
+
+
+def test_a_tap_shifted_in_the_kernels_program_is_caught(monkeypatch):
+    """Falcon-H1's planted fault ``conv_shifted_a_tap`` where the LANE
+    program computes its ``M`` layer's convolution — in the kernel's input,
+    positions last: the program asked for its kernels reads over the
+    cell's limit against the sound one."""
+    params = jax.tree.map(lambda x: x.astype(F32), init_nemotron_h_params(
+        jax.random.PRNGKey(3), LANE_CFG))
+    t, lengths = 128, (128, 97, 60, 110)
+    ids = jax.random.randint(jax.random.PRNGKey(4), (len(lengths), t), 0, 512)
+    mask = jnp.arange(t)[None, :] < jnp.array(lengths)[:, None]
+    kernels = dict(use_pallas=True, kernel_interpret=True)
+    sound = _predict32(params, ids, mask, config=LANE_CFG, **kernels)
+    whole = falcon_h1.causal_conv_silu
+
+    def shifted(x, *a, positions_last, **kw):
+        assert positions_last
+        return whole(jnp.pad(x, ((0, 0), (0, 0), (1, 0)))[..., :-1], *a,
+                     positions_last=positions_last, **kw)
+
+    monkeypatch.setattr(falcon_h1, "causal_conv_silu", shifted)
+    gap = np.abs(_predict32(params, ids, mask, config=LANE_CFG, **kernels)
+                 - sound)
+    assert gap.max() > LIMIT, gap
+    assert (gap > LIMIT / 2).sum() >= 2, gap

@@ -34,6 +34,7 @@ from realtime_fraud_detection_tpu.models.qwen3_next import (
     qwen3_next_predict,
 )
 from realtime_fraud_detection_tpu.ops.attention import windowed_refusal
+from realtime_fraud_detection_tpu.ops.causal_conv import conv_refusal
 from realtime_fraud_detection_tpu.ops.delta_scan import (
     delta_refusal,
     gated_delta_scan,
@@ -560,12 +561,20 @@ def test_the_core_at_heads_of_256_in_interpret_mode_is_the_xla_form():
 
 def test_the_encoder_with_its_kernels_interpreted_is_the_xla_form(
         monkeypatch):
-    """LLLF at lane shapes: the delta scan's kernel in the ``L`` layers and
-    the fused core in the ``F`` layer, through the interpreter (as are the
-    experts' kernels where they take the shape), against the XLA forms."""
+    """LLLF at lane shapes: the delta scan's kernel and, since PR 55, the
+    convolution's (q | k | v of 512 | 512 | 1,024, positions first, ``v``
+    in the operands' dtype) in the ``L`` layers and the fused core in the
+    ``F`` layer, through the interpreter (as are the experts' kernels where
+    they take the shape), against the XLA forms."""
     seen = []
     scan, core = (qwen3_next.gated_delta_scan,
                   qwen3_next.windowed_attention)
+    conv = falcon_h1.causal_conv_silu
+    assert LANE_CFG.conv_refusal(128) is None
+
+    def counted_conv(*a, **kw):
+        seen.append(("conv", not kw["positions_last"] and kw["interpret"]))
+        return conv(*a, **kw)
 
     def counted_scan(*a, use_pallas, **kw):
         seen.append(("scan", use_pallas))
@@ -577,6 +586,7 @@ def test_the_encoder_with_its_kernels_interpreted_is_the_xla_form(
 
     monkeypatch.setattr(qwen3_next, "gated_delta_scan", counted_scan)
     monkeypatch.setattr(qwen3_next, "windowed_attention", counted_core)
+    monkeypatch.setattr(falcon_h1, "causal_conv_silu", counted_conv)
     params32 = jax.tree.map(
         lambda x: x.astype(F32),
         init_qwen3_next_params(jax.random.PRNGKey(9), LANE_CFG))
@@ -587,7 +597,8 @@ def test_the_encoder_with_its_kernels_interpreted_is_the_xla_form(
         assert seen == [("scan", False)] * 3
         got = qwen3_next_predict(params32, ids, mask, LANE_CFG,
                                  use_pallas=True, kernel_interpret=True)
-    assert seen[3:] == [("scan", True)] * 3 + [("core", True)]
+    assert seen[3:] == [("conv", True), ("scan", True)] * 3 + [
+        ("core", True)]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
@@ -606,6 +617,9 @@ def test_published_config_is_the_default_and_the_kinds_follow_the_interval():
     assert (config.num_sparse_layers, config.num_delta_layers) == (48, 36)
     assert config.core_refusal(2048) is None
     assert config.scan_refusal(2048) is None
+    assert config.conv_refusal(2048) is None
+    assert config.conv_refusal(2048) == conv_refusal(
+        2048, (2048, 2048, 4096), 4)
     cut = dataclasses.replace(config, num_hidden_layers=6, num_experts=256)
     assert "".join(cut.layer_kinds) == "LLLFLL"
     assert "".join(CFG.layer_kinds) == "LLLFL"
@@ -630,6 +644,8 @@ def test_config_refuses_what_the_equations_cannot_hold(change, message):
 def test_the_refusals_name_their_shapes():
     assert "head_dim 32" in CFG.core_refusal(128)
     assert "key_dim 16" in CFG.scan_refusal(128)
+    assert "parts (32, 32, 64) from channel 0" in CFG.conv_refusal(128)
+    assert "seq_len 96" in LANE_CFG.conv_refusal(96)
     assert windowed_refusal(2048, 256, 16, 2, None, head_norm=True) is None
     assert windowed_refusal(2048, 128, 16, 2, None, head_norm=True) is None
     # two lane tiles a head come with the per-head norm alone
@@ -742,7 +758,8 @@ def test_one_row_of_the_seam_is_routed_and_recurrent_at_once(rung_scorer):
     assert row is qwen3_next.TEXT_ENCODER
     assert [site.name for site in row.sites] == [
         "attention", "expert_gate_up", "expert_dispatch", "expert_combine",
-        "delta_scan"]
+        "delta_scan", "causal_conv"]
+    assert row.sites[-1].refusal(CFG, 128, 128) == CFG.conv_refusal(128)
     assert pipeline.text_layers(CFG) == 5
     assert row.capacities(4096) == (3072, 4096)
     assert Qwen3NextConfig in pipeline.TextConfig.__args__
@@ -758,12 +775,13 @@ def test_one_row_of_the_seam_is_routed_and_recurrent_at_once(rung_scorer):
     scorer.finalize(scorer.dispatch(gen.generate_batch(3)))
     snap = scorer.kernel_snapshot()
     # a CPU mesh is never asked for its kernels: a fallback at every site
-    for site in ("attention", "delta_scan", "expert_gate_up",
+    for site in ("attention", "delta_scan", "causal_conv", "expert_gate_up",
                  "expert_dispatch", "expert_combine"):
         assert snap["fallback"][site] == before["fallback"][site] + 1, site
         assert snap["dispatch"][site] == 0
     assert "head_dim 32" in snap["refused"]["attention"]
     assert "key_dim 16" in snap["refused"]["delta_scan"]
+    assert "parts (32, 32, 64)" in snap["refused"]["causal_conv"]
     # at the published shapes every site of a launch of 8 x 2,048 holds its
     # kernel but the way out, which keeps XLA's gather at this size
     full = dataclasses.replace(Qwen3NextConfig(), num_hidden_layers=6,
@@ -891,3 +909,29 @@ def test_the_shared_convolution_leaves_the_mamba_programs_as_they_were(
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         cc.reset_cache()
+
+
+def test_a_tap_shifted_in_the_kernels_program_is_caught(monkeypatch):
+    """Falcon-H1's planted fault ``conv_shifted_a_tap`` where the LANE
+    program computes its ``L`` layers' convolutions — in the kernel's
+    input, positions first: the program asked for its kernels reads over
+    the cell's limit against the sound one."""
+    params = jax.tree.map(lambda x: x.astype(F32), init_qwen3_next_params(
+        jax.random.PRNGKey(9), LANE_CFG))
+    t, lengths = 128, (128, 97, 60, 110)
+    ids = jax.random.randint(jax.random.PRNGKey(4), (len(lengths), t), 0, 512)
+    mask = jnp.arange(t)[None, :] < jnp.array(lengths)[:, None]
+    kernels = dict(use_pallas=True, kernel_interpret=True)
+    sound = _predict32(params, ids, mask, config=LANE_CFG, **kernels)
+    whole = falcon_h1.causal_conv_silu
+
+    def shifted(x, *a, positions_last, **kw):
+        assert not positions_last
+        return whole(jnp.pad(x, ((0, 0), (1, 0), (0, 0)))[:, :-1], *a,
+                     positions_last=positions_last, **kw)
+
+    monkeypatch.setattr(falcon_h1, "causal_conv_silu", shifted)
+    gap = np.abs(_predict32(params, ids, mask, config=LANE_CFG, **kernels)
+                 - sound)
+    assert gap.max() > LIMIT, gap
+    assert (gap > LIMIT / 2).sum() >= 2, gap

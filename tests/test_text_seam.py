@@ -124,9 +124,9 @@ def test_the_snapshot_counts_each_encoders_own_sites(first_batch):
     if name in ROUTED:
         sites |= {"expert_gate_up", "expert_dispatch", "expert_combine"}
     if name in SCANNED:
-        sites |= {"ssm_scan"}
+        sites |= {"ssm_scan", "causal_conv"}
     if name == "qwen3next":
-        sites |= {"delta_scan"}
+        sites |= {"delta_scan", "causal_conv"}
     snap = scorer.kernel_snapshot()
     assert set(snap) == {"modes", "interpret", "dispatch", "fallback",
                          "refused"}
@@ -134,10 +134,14 @@ def test_the_snapshot_counts_each_encoders_own_sites(first_batch):
     assert set(snap["modes"]) == {"dequant_matmul", "epilogue", "attention"}
     # the reasons the snapshot can name with no launch in hand
     assert set(snap["refused"]) == {"attention"} | (
-        sites & {"ssm_scan", "delta_scan"})
+        sites & {"ssm_scan", "delta_scan", "causal_conv"})
     assert all("cpu mesh" in why or "head_dim" in why or "seq_len" in why
-               or "key_dim" in why
+               or "key_dim" in why or "lane tiles" in why
                for why in snap["refused"].values())
+    # the convolution's site is theirs alone: the seven encoders without a
+    # causal mixer count nothing at it
+    assert ("causal_conv" in snap["dispatch"]) == (
+        name in SCANNED or name == "qwen3next")
     # a CPU mesh is never asked for a kernel: every launch of the first
     # batch is a fallback at each of the encoder's own sites
     own = sites - {"dequant_matmul", "epilogue"}
