@@ -408,7 +408,8 @@ def mamba2_mix(layer: Dict, p: jax.Array, *, heads: int, head_dim: int,
     operand = layer["in_proj"].dtype
     d_ssm = heads * head_dim
     conv_dim = d_ssm + 2 * groups * state
-    with jax.named_scope(scopes.SSM_PROJ):
+    with jax.named_scope(scopes.SSM_PROJ), \
+            jax.named_scope(scopes.SSM_IN_PROJ):
         z = p[..., :d_ssm]
         dt = p[..., d_ssm + conv_dim:]
     with jax.named_scope(scopes.SSM_CONV):
@@ -425,9 +426,11 @@ def mamba2_mix(layer: Dict, p: jax.Array, *, heads: int, head_dim: int,
             x, dt, -jnp.exp(layer["A_log"]), b_in, c_in, layer["D"],
             chunk=chunk, use_pallas=scan_kernel, interpret=kernel_interpret)
     with jax.named_scope(scopes.SSM_PROJ):
-        y = group_rms_norm(y.reshape(b, t, d_ssm) * jax.nn.silu(z),
-                           layer["mixer_norm"], groups, eps)
-        return _proj(y, layer["out_proj"])
+        with jax.named_scope(scopes.SSM_GATE_NORM):
+            y = group_rms_norm(y.reshape(b, t, d_ssm) * jax.nn.silu(z),
+                               layer["mixer_norm"], groups, eps)
+        with jax.named_scope(scopes.SSM_OUT_PROJ):
+            return _proj(y, layer["out_proj"])
 
 
 def falcon_mixer(layer: Dict, u: jax.Array, config: FalconH1Config, *,
@@ -438,7 +441,8 @@ def falcon_mixer(layer: Dict, u: jax.Array, config: FalconH1Config, *,
     asks for the scan's and the convolution's kernels; a shape one does not
     take (``FalconH1Config.scan_refusal`` / ``conv_refusal``) runs its XLA
     form."""
-    with jax.named_scope(scopes.SSM_PROJ):
+    with jax.named_scope(scopes.SSM_PROJ), \
+            jax.named_scope(scopes.SSM_IN_PROJ):
         p = _proj(u * config.ssm_in_multiplier, layer["in_proj"]) \
             * mup_vector(config)
     t = u.shape[1]
@@ -512,8 +516,17 @@ def falcon_layer(layer: Dict, h: jax.Array, attention_mask: jax.Array,
         n = rms_norm(h, layer["pre_ff_layernorm"], config.rms_norm_eps)
     gate_mult, down_mult = config.mlp_multipliers
     with jax.named_scope(scopes.FFN):
-        y = _proj(jax.nn.silu(gate_mult * _proj(n, layer["mlp_gate"]))
-                  * _proj(n, layer["mlp_up"]), layer["mlp_down"])
+        # one part a matmul (obs/scopes.SCOPE_PARTS); the SiLU, the product
+        # and the rounding to what down reads are written where the TPU's
+        # compiler fuses them, into up's matmul (gate's fusion writes its
+        # float32 result, up's reads it: PERF.md §5)
+        with jax.named_scope(scopes.FFN_GATE):
+            gate = gate_mult * _proj(n, layer["mlp_gate"])
+        with jax.named_scope(scopes.FFN_UP):
+            act = (jax.nn.silu(gate) * _proj(n, layer["mlp_up"])).astype(
+                layer["mlp_down"].dtype)
+        with jax.named_scope(scopes.FFN_DOWN):
+            y = _proj(act, layer["mlp_down"])
     with jax.named_scope(scopes.LN):
         return h + down_mult * y
 

@@ -415,7 +415,8 @@ def nemotron_mixer(layer: Dict, u: jax.Array, config: NemotronHConfig, *,
     ``use_pallas`` asks for the scan's and the convolution's kernels; a
     shape one does not take (``NemotronHConfig.scan_refusal`` /
     ``conv_refusal``) runs its XLA form."""
-    with jax.named_scope(scopes.SSM_PROJ):
+    with jax.named_scope(scopes.SSM_PROJ), \
+            jax.named_scope(scopes.SSM_IN_PROJ):
         p = _proj(u, layer["in_proj"])
     t = u.shape[1]
     return mamba2_mix(

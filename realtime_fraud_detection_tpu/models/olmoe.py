@@ -346,7 +346,8 @@ def apply_experts(layer: Dict, x: jax.Array, experts: jax.Array,
     gated = "gate_proj" in layer
     share = router_width is not None and (
         router_width != num_experts or expert_offset != 0)
-    with jax.named_scope(scopes.ROUTER):
+    with jax.named_scope(scopes.ROUTER), \
+            jax.named_scope(scopes.ROUTER_ORDER):
         # the (token, expert) pairs in expert order; a stable sort keeps a
         # group's rows in token order
         flat = experts.reshape(-1)
@@ -481,7 +482,8 @@ def routed_block(layer: Dict, x: jax.Array,
     if idx is not None:
         with jax.named_scope(scopes.EXPERTS_DISPATCH):
             x = x.at[idx].get(mode="fill", fill_value=0.0)     # [C, width]
-    with jax.named_scope(scopes.ROUTER):
+    with jax.named_scope(scopes.ROUTER), \
+            jax.named_scope(scopes.ROUTER_CHOOSE):
         experts, weights, carry = router(x)
     y, load = apply_experts(
         layer, x, experts, weights, real=real, router_width=router_width,

@@ -465,12 +465,14 @@ def delta_mixer(layer: Dict, u: jax.Array, config: Qwen3NextConfig, *,
             (jnp.float32, jnp.float32, operand),
             kernel=use_pallas and config.conv_refusal(t) is None,
             interpret=kernel_interpret)
-        q = l2_norm(q.reshape(b, t, hk, dk)) * dk ** -0.5
-        k = l2_norm(k.reshape(b, t, hk, dk))
+        with jax.named_scope(scopes.DELTA_QK_NORM):
+            q = l2_norm(q.reshape(b, t, hk, dk)) * dk ** -0.5
+            k = l2_norm(k.reshape(b, t, hk, dk))
         v = v.reshape(b, t, hv, dv)
-        beta = jax.nn.sigmoid(ba[..., :ratio].reshape(b, t, hv))
-        g = -jnp.exp(layer["A_log"]) * jax.nn.softplus(
-            ba[..., ratio:].reshape(b, t, hv) + layer["dt_bias"])
+        with jax.named_scope(scopes.DELTA_GATES):
+            beta = jax.nn.sigmoid(ba[..., :ratio].reshape(b, t, hv))
+            g = -jnp.exp(layer["A_log"]) * jax.nn.softplus(
+                ba[..., ratio:].reshape(b, t, hv) + layer["dt_bias"])
     with jax.named_scope(scopes.DELTA_SCAN):
         o, _ = gated_delta_scan(
             q.astype(operand), k.astype(operand), v, g, beta,

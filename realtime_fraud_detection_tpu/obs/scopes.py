@@ -13,11 +13,28 @@ first, as ``FraudScorer`` and ``StreamJob`` open them
 
 ``benchmarks/harness/scopes.py`` matches on these strings; ``PERF.md`` §3
 says which metric reads which.
+
+Parts: four of the layer scopes are cut once more, by a ``named_scope``
+nested INSIDE the scope (``SCOPE_PARTS``: ``ssm_proj`` -> ``in_proj`` /
+``gate_norm`` / ``out_proj``, ``delta_conv`` -> ``qk_norm`` / ``gates``,
+``router`` -> ``choose`` / ``order``, Falcon-H1's ``ffn`` -> ``gate`` / ``up``
+/ ``down``). An operation's path grows by one component
+(``text/layer3/ssm_proj/in_proj/dot_general``), so whatever reads the parent
+reads what it read; a part's own metric is
+``benchmarks/readers/scope_part_time_per_batch.py``. A part never holds a
+custom call: a kernel's ``op_name`` is spelt by tests, kernels' files and
+documents (``.../delta_conv/jit(_conv_pallas)/causal_conv/pallas_call``) and
+stays directly under its scope. A part exists where a queued issue needs
+the number, not wherever one could be cut. To add one: a name in
+``SCOPE_PARTS``, a ``named_scope`` in the model's file, a
+``benchmarks/layer_metrics/<metric>.json`` naming ``scope`` and ``part``, a
+``per_layer`` entry of ``BENCHMARK.json`` with its ``workloads``, a row of
+``PERF.md`` §3 (``docs/tracing.md`` "Parts of a scope").
 """
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Dict, Tuple
 
 # ---- device: branches of scoring/pipeline._score_fused_impl
 TREES = "trees"
@@ -48,6 +65,11 @@ LAYER_SCOPES: Tuple[str, ...] = (ATTN_PROJ, ATTN_CORE, FFN, LN)
 # QK-norm and RoPE, ``ln`` the RMSNorms and residual adds; the sparse block
 # stands where ``ffn`` does
 ROUTER = "router"            # gate matmul, softmax, top-k, sort and offsets
+ROUTER_CHOOSE = "choose"     # part: the encoder's own choice (gate matmul,
+                             # softmax / sigmoid, top-k; ZAYA1's router MLP
+                             # and the state it carries)
+ROUTER_ORDER = "order"       # part: the pairs' sort, group sizes, inverse
+                             # permutation and the fused kernel's tile count
 EXPERTS = "experts"
 EXPERTS_DISPATCH_PART = "dispatch"   # rows gathered into expert order
 EXPERTS_MATMUL_PART = "matmul"       # grouped gate, up, SiLU*, down
@@ -93,11 +115,22 @@ JOYAI_LAYER_SCOPES: Tuple[str, ...] = LAGUNA_LAYER_SCOPES + (ATTN_LATENT,)
 # mixer that runs beside attention in every layer
 SSM_PROJ = "ssm_proj"        # W_in with the µP vector, the gate, the
                              # grouped RMSNorm, W_out
+SSM_IN_PROJ = "in_proj"      # part: W_in with its multipliers, the cuts of
+                             # z and dt from its result
+SSM_GATE_NORM = "gate_norm"  # part: y * SiLU(z) and the grouped RMSNorm
+SSM_OUT_PROJ = "out_proj"    # part: W_out
 SSM_CONV = "ssm_conv"        # the depthwise causal convolution, its SiLU,
                              # dt's softplus
 SSM_SCAN = "ssm_scan"        # the state-space scan alone (ops/ssd_scan.py)
 FALCON_H1_LAYER_SCOPES: Tuple[str, ...] = LAYER_SCOPES + (
     SSM_PROJ, SSM_CONV, SSM_SCAN)
+# parts of Falcon-H1's ``ffn`` alone (DistilBERT's, Laguna's and JoyAI's are
+# left whole): one matmul each; ``gate`` holds its multiplier, ``up`` the
+# SiLU, the product and the rounding to what ``down`` reads, where the TPU's
+# compiler fuses them
+FFN_GATE = "gate"
+FFN_UP = "up"
+FFN_DOWN = "down"
 
 # ---- device: under ``text`` where the encoder is models/nemotron_h.py: a
 # layer is ONE mixer, so ``layer<i>`` holds ``ln`` (its one RMSNorm and the
@@ -121,10 +154,26 @@ DELTA_PROJ = "delta_proj"    # in_proj_qkvz and in_proj_ba by part, the
                              # gated per-head RMSNorm, out_proj
 DELTA_CONV = "delta_conv"    # the depthwise causal convolution, its SiLU,
                              # q's and k's L2 norms, beta and the log-decay
+DELTA_QK_NORM = "qk_norm"    # part of ``delta_conv``: q's and k's L2 norms,
+                             # q's ``dk ** -0.5``, the reshapes round them
+DELTA_GATES = "gates"        # part of ``delta_conv``: beta and the log-decay
+                             # g; the convolution (and v's reshape to
+                             # heads) stays directly under ``delta_conv``
 DELTA_SCAN = "delta_scan"    # the delta-rule scan alone (ops/delta_scan.py)
 QWEN3_NEXT_LAYER_SCOPES: Tuple[str, ...] = (
     LN, DELTA_PROJ, DELTA_CONV, DELTA_SCAN, ATTN_PROJ, ATTN_CORE, ROUTER,
     EXPERTS, SHARED_EXPERT)
+
+# ---- device: the parts written INSIDE a layer scope (module docstring).
+# Under ``ssm_proj``, ``router`` and Falcon-H1's ``ffn`` every operation lies
+# in exactly one part; under ``delta_conv`` what lies in none is the
+# convolution (tests/test_scopes.py)
+SCOPE_PARTS: Dict[str, Tuple[str, ...]] = {
+    SSM_PROJ: (SSM_IN_PROJ, SSM_GATE_NORM, SSM_OUT_PROJ),
+    DELTA_CONV: (DELTA_QK_NORM, DELTA_GATES),
+    ROUTER: (ROUTER_CHOOSE, ROUTER_ORDER),
+    FFN: (FFN_GATE, FFN_UP, FFN_DOWN),
+}
 
 
 def layer_scope(i: int) -> str:

@@ -71,6 +71,14 @@ from test_qwen3next_reference import (  # noqa: E402,F401
     test_qwen3next_text_branch_is_the_programs_at_float32,
     test_qwen3next_text_column_costs_four_compilations,
 )
+# PR 56's reader of a scope's parts on hand-made events, and its ten metric
+# files against the program's names: collected here as they stand there
+from test_scope_parts import (  # noqa: E402,F401
+    PART_METRICS,
+    test_a_parts_seconds_and_the_parent_unchanged,
+    test_a_trace_without_parts_reads_none_never_zero,
+    test_every_part_metric_names_a_part_the_program_writes,
+)
 from test_setup_metrics import (  # noqa: E402,F401
     test_a_ledger_that_let_records_go_reads_none,
     test_a_program_without_the_ledger_reads_none,
@@ -486,13 +494,64 @@ def test_the_nemotron3_file_is_the_sources_config_cut_in_depth_only():
             {**NEMOTRON_CFG, "hybrid_override_pattern": "MEMEM-EME"})
 
 
+def _reported(cell, kind="per_layer"):
+    """The names ``cell`` reports, less the ten of PR 56 (``_before_pr56``
+    holds those): what the asserts on the older PRs' additions count."""
+    return {m["name"] for m in spec.metrics_for(cell, kind)} - set(
+        PART_METRICS)
+
+
+def _before_pr56():
+    """``BENCHMARK.json`` with what PR 56 appended checked BY NAME and taken
+    off: ten metrics at the end of ``per_layer``, each a part of a scope
+    whose own metric the benchmark had, listing the cells that metric lists
+    in which the program writes the part — no configuration, no cell, no
+    name added to any list that was there. What is left is the benchmark
+    PR 54 left."""
+    bm = json.loads(json.dumps(BM))
+    for name in reversed(list(PART_METRICS)):
+        _scope, _part, cells = PART_METRICS[name]
+        assert bm["per_layer"].pop() == {
+            "name": name, "unit": "ms", "better": "lower",
+            "source": "device_trace", "layer": "kernels",
+            "moves": "txn_per_s", "workloads": cells}
+    assert not set(PART_METRICS) & {
+        m["name"] for m in bm["per_layer"] + bm["end_to_end"]}
+    return bm
+
+
+def test_pr56_appended_ten_part_metrics_and_nothing_else():
+    bm = _before_pr56()
+    assert len(BM["per_layer"]) - len(bm["per_layer"]) == 10
+    assert [w["name"] for w in bm["workloads"]] == [
+        w["name"] for w in BM["workloads"]]
+    assert bm["configs"] == BM["configs"]
+    assert bm["end_to_end"] == BM["end_to_end"]
+    # each cell reports the parts of the scopes its program cuts, no other
+    parts_of = {
+        FALCON_CELL: {"ssm_in_proj", "ssm_gate_norm", "ssm_out_proj",
+                      "falconh1_ffn_gate", "falconh1_ffn_up",
+                      "falconh1_ffn_down"},
+        NEMOTRON_CELL: {"ssm_in_proj", "ssm_gate_norm", "ssm_out_proj",
+                        "router_choose", "router_order"},
+        QWEN_CELL: {"delta_qk_norm", "delta_gates", "router_choose",
+                    "router_order"},
+        **{cell: {"router_choose", "router_order"}
+           for cell in ROUTED_CELLS},
+        "s512-fulltext-saturated": set()}
+    assert set(parts_of) == {w["name"] for w in BM["workloads"]}
+    for cell, parts in parts_of.items():
+        assert {m["name"] for m in spec.metrics_for(cell, "per_layer")} & set(
+            PART_METRICS) == {f"{p}_ms_per_batch" for p in parts}, cell
+
+
 def _before_pr54():
     """``BENCHMARK.json`` with what PR 54 appended checked BY NAME and taken
     off: one configuration, one cell, the cell's name at the end of the
     ``workloads`` list of every metric it reports, and six metrics of its
     own at the end of ``per_layer``. What is left is the benchmark PR 53
     left."""
-    bm = json.loads(json.dumps(BM))
+    bm = _before_pr56()
     own = [bm["per_layer"].pop() for _ in QWEN_ONLY][::-1]
     assert [m["name"] for m in own] == QWEN_ONLY
     for m in own:
@@ -519,9 +578,8 @@ def _before_pr54():
             assert m["workloads"].pop() == QWEN_CELL, m["name"]
             assert QWEN_CELL not in m["workloads"]
             listed.add(m["name"])
-    assert listed | set(QWEN_ONLY) | {"setup_s"} == {
-        m["name"] for kind in ("end_to_end", "per_layer")
-        for m in spec.metrics_for(QWEN_CELL, kind)}
+    assert listed | set(QWEN_ONLY) | {"setup_s"} == _reported(
+        QWEN_CELL) | _reported(QWEN_CELL, "end_to_end")
     return bm, listed
 
 
@@ -753,9 +811,8 @@ def _before_pr50():
             assert NEMOTRON_CELL not in m["workloads"]
             listed.add(m["name"])
     assert listed | set(NEMOTRON_ONLY) | set(DISPATCH_METRICS) | {
-            "setup_s"} == {
-        m["name"] for kind in ("end_to_end", "per_layer")
-        for m in spec.metrics_for(NEMOTRON_CELL, kind)}
+            "setup_s"} == _reported(NEMOTRON_CELL) | _reported(
+        NEMOTRON_CELL, "end_to_end")
     return bm, listed
 
 
@@ -818,8 +875,7 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
     joyai_only = {"attn_latent_ms_per_batch", "joyai_attn_core_roofline_pct",
                   "joyai_expert_ffn_roofline_pct",
                   "joyai_attn_latent_roofline_pct"}
-    reports = {cell: {m["name"] for m in spec.metrics_for(cell, "per_layer")}
-               for cell in ROUTED_CELLS}
+    reports = {cell: _reported(cell) for cell in ROUTED_CELLS}
     # JoyAI's: the shared routed names, its dense layer 0's, the shared
     # expert's and its own four; neither OLMoE's, ZAYA1's nor Laguna's own
     joyai_reports = reports.pop(JOYAI_CELL)
@@ -868,8 +924,7 @@ def test_the_new_cells_and_their_metrics_are_appended_not_inserted():
     assert (by_name[FALCON_CELL]["config"], by_name[FALCON_CELL]["traffic"],
             by_name[FALCON_CELL]["chips"]) == (
         "falcon-h1-34b-s2048", "s2048-remit-saturated", 1)
-    falcon_reports = {m["name"]
-                      for m in spec.metrics_for(FALCON_CELL, "per_layer")}
+    falcon_reports = _reported(FALCON_CELL)
     # a dense encoder: what every cell reports, the dense MLP's time, and
     # its own six; no expert's, no router's, neither launch rule's share
     every_cell = {m["name"] for m in bm["per_layer"]
@@ -1752,7 +1807,8 @@ def test_the_qwen3next_metrics_on_a_hand_made_run():
             100 * needs / peak / seconds), name
     assert QWEN_ONLY == [
         m["name"] for m in spec.metrics_for(QWEN_CELL, "per_layer")
-        if m["name"].startswith(("delta_", "qwen3next_"))]
+        if m["name"].startswith(("delta_", "qwen3next_"))
+        and m["name"] not in PART_METRICS]
     # against a program without the scopes and the counters every new
     # metric is left out and none raises
     parent = _fake_run({"text": 0.9, "text/layer1/ffn": 0.1},

@@ -24,10 +24,11 @@ from realtime_fraud_detection_tpu.scoring.pipeline import (
 from realtime_fraud_detection_tpu.utils.config import Config
 
 
-def _compile_packed():
+def _compile_packed(bert_config=TINY_CONFIG):
     """A fresh jit each time (the module's own would answer the second
     lowering from its trace cache, scopes and all)."""
-    models = init_scoring_models(jax.random.PRNGKey(0))
+    models = init_scoring_models(jax.random.PRNGKey(0),
+                                 bert_config=bert_config)
     blobs, spec = pack_tree(
         make_example_batch(8, ScorerConfig(), rng=np.random.default_rng(7)))
     fn = jax.jit(lambda *a, **k: _score_fused_packed_impl(*a, **k),
@@ -36,7 +37,7 @@ def _compile_packed():
         models, blobs["f32"], blobs["i32"], blobs["u8"], spec=spec,
         params=EnsembleParams.from_config(Config(), list(MODEL_NAMES)),
         model_valid=jax.numpy.ones((len(MODEL_NAMES),), bool),
-        bert_config=TINY_CONFIG)
+        bert_config=bert_config)
     return lowered, lowered.compile().as_text()
 
 
@@ -273,6 +274,138 @@ def test_every_name_in_a_builders_vocabulary_is_one_the_program_writes(
     asm = _lowered_asm(config)
     for path in _vocabulary_paths(vocabulary):
         assert re.search(rf'"jit\([^"]*\)/{path}/', asm), path
+
+
+# ---- the parts written inside four of the layer scopes (ISSUE 56)
+
+def _part_config(encoder):
+    """``models/<encoder>.py``'s TINY configuration."""
+    import importlib
+
+    module = importlib.import_module(
+        f"realtime_fraud_detection_tpu.models.{encoder}")
+    return getattr(module, f"TINY_{encoder.upper()}")
+
+
+# which of the parted scopes each touched encoder's program writes
+PARTED = {
+    "falcon_h1": (scopes.SSM_PROJ, scopes.FFN),
+    "nemotron_h": (scopes.SSM_PROJ, scopes.ROUTER),
+    "qwen3_next": (scopes.DELTA_CONV, scopes.ROUTER),
+    "olmoe": (scopes.ROUTER,),
+}
+PART_CASES = [(encoder, parent, part) for encoder, parents in PARTED.items()
+              for parent in parents for part in scopes.SCOPE_PARTS[parent]]
+PART_NAMES = {part for parts in scopes.SCOPE_PARTS.values() for part in parts}
+# test-only: the name the coverage test puts round the convolution, which
+# the program leaves directly under ``delta_conv`` (a kernel's ``op_name``
+# is never moved by a part)
+CONVOLUTION = "convolution"
+
+
+@pytest.fixture(scope="module")
+def parted():
+    """``encoder -> (lowered, optimised text)`` at TINY, compiled once."""
+    made = {}
+
+    def get(encoder):
+        if encoder not in made:
+            made[encoder] = _compile_packed(_part_config(encoder))
+        return made[encoder]
+    return get
+
+
+def _asm(lowered):
+    return lowered.compiler_ir().operation.get_asm(enable_debug_info=True)
+
+
+def _below(asm, parent):
+    """The names written directly under ``parent`` in any layer."""
+    return set(re.findall(
+        rf'"jit\([^"]*\)/{scopes.TEXT}/{scopes.LAYER}\d+/{parent}/'
+        rf'(?:jit\([^/"]*\)/)*([^/"]+)', asm))
+
+
+@pytest.mark.parametrize("encoder,parent,part", PART_CASES)
+def test_part_reaches_the_lowered_module(parted, encoder, parent, part):
+    lowered, _ = parted(encoder)
+    assert re.search(
+        rf'"jit\([^"]*\)/{scopes.TEXT}/{scopes.LAYER}\d+/{parent}/{part}/',
+        _asm(lowered)), (encoder, parent, part)
+
+
+@pytest.mark.parametrize("encoder,parent,part", PART_CASES)
+def test_part_survives_optimisation(parted, encoder, parent, part):
+    """Each part still names an instruction of the OPTIMISED program: what
+    a trace reads, and ``scope_part_time_per_batch`` with it."""
+    _, text = parted(encoder)
+    assert re.search(
+        rf'op_name="jit\([^"]*\)/{scopes.TEXT}/{scopes.LAYER}\d+/{parent}/'
+        rf'{part}/', text), (encoder, parent, part)
+
+
+@pytest.mark.parametrize("encoder", list(PARTED))
+def test_no_operation_under_a_parent_lies_outside_its_parts(
+        parted, monkeypatch, encoder):
+    """Under ``ssm_proj``, ``router`` and Falcon-H1's ``ffn`` every
+    operation the program writes lies in exactly one part, so the parts'
+    metrics add up to the parent's. Under ``delta_conv`` what lies in none
+    is the convolution (named here, for the test alone) and the reshape of
+    its third result, v, to heads."""
+    if scopes.DELTA_CONV in PARTED[encoder]:
+        from realtime_fraud_detection_tpu.models import qwen3_next
+
+        real = qwen3_next.conv_silu_parts
+
+        def named(*args, **kwargs):
+            with jax.named_scope(CONVOLUTION):
+                return real(*args, **kwargs)
+        monkeypatch.setattr(qwen3_next, "conv_silu_parts", named)
+        lowered, _ = _compile_packed(_part_config(encoder))
+    else:
+        lowered, _ = parted(encoder)
+    asm = _asm(lowered)
+    for parent in PARTED[encoder]:
+        below = _below(asm, parent)
+        if parent == scopes.DELTA_CONV:
+            assert CONVOLUTION in below
+            below -= {CONVOLUTION, "reshape"}
+        assert below == set(scopes.SCOPE_PARTS[parent]), (parent, below)
+
+
+def test_scope_parts_holds_exactly_the_parted_scopes():
+    """One mapping: every parted scope is one some touched encoder writes,
+    no part is named twice, and the other encoders' ``ffn`` (DistilBERT's,
+    Laguna's, JoyAI's) are left whole."""
+    assert set(scopes.SCOPE_PARTS) == {
+        parent for parents in PARTED.values() for parent in parents}
+    assert len(PART_NAMES) == sum(map(len, scopes.SCOPE_PARTS.values()))
+    from realtime_fraud_detection_tpu.models.laguna import TINY_LAGUNA
+
+    for config in (TINY_CONFIG, TINY_LAGUNA):
+        below = _below(_lowered_asm(config), scopes.FFN)
+        assert below and not below & PART_NAMES, below
+
+
+@pytest.mark.parametrize("encoder", list(PARTED))
+def test_parts_do_not_change_the_compiled_program(parted, monkeypatch,
+                                                  encoder):
+    """The optimised program with the parts replaced by ``nullcontext`` is
+    the program with them, metadata apart (the parents stay)."""
+    _, with_parts = parted(encoder)
+    real = jax.named_scope
+    monkeypatch.setattr(
+        jax, "named_scope", lambda name: contextlib.nullcontext()
+        if name in PART_NAMES else real(name))
+    _, without = _compile_packed(_part_config(encoder))
+    for parent in PARTED[encoder]:
+        for part in scopes.SCOPE_PARTS[parent]:
+            assert f"/{parent}/{part}/" in with_parts
+            assert f"/{parent}/{part}/" not in without      # the patch took
+        assert f"/{parent}/" in without
+    a, b = _program_body(with_parts), _program_body(without)
+    assert a.count(" fusion(") > 10
+    assert a == b
 
 
 def test_the_family_build_is_a_span_of_the_microbatch_under_pack():
